@@ -1,18 +1,11 @@
-"""Small compatibility shims for optional/new dependencies.
+"""Shim for the one optional dependency the launchers share.
 
-The framework targets recent jax (vma-typed shard_map) and a full
-container image, but must degrade on leaner environments instead of
-failing at import time:
-
-- ``pick_unused_port``: portpicker when installed, else a socket-based
-  fallback (bind port 0, read back the assignment). The fallback has a
-  marginally wider race window than portpicker's reservation protocol,
-  which is acceptable for the local-runner/test uses it serves.
-- ``pcast``: ``jax.lax.pcast`` on jax versions with the varying-manual-
-  axes type system; identity on older jax, where every value inside
-  shard_map is already implicitly varying over the manual axes so the
-  cast has nothing to record. Resolved lazily on first call so
-  importing this module stays jax-free.
+``pick_unused_port``: portpicker when installed, else a socket-based
+fallback (bind port 0, read back the assignment). The fallback has a
+marginally wider race window than portpicker's reservation protocol,
+which is acceptable for the local-runner/test uses it serves. The
+module stays jax-free: runners and the forked test harness import it
+from a parent that must not initialise a JAX backend.
 """
 
 from __future__ import annotations
@@ -30,78 +23,3 @@ def pick_unused_port() -> int:
             sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
             sock.bind(("127.0.0.1", 0))
             return sock.getsockname()[1]
-
-
-_pcast_impl = None
-
-
-def pcast(x, axes, to):
-    """Lazy resolver: jax is only imported on first use, so consumers
-    that need nothing but ``pick_unused_port`` (runners, the forked
-    test harness — which must keep the forking parent jax-free) never
-    pay the jax import.
-
-    On pre-vma jax the replicated->varying cast is numerically the
-    identity, but its TRANSPOSE is not: the cotangent of a varying
-    output w.r.t. a replicated input is the psum over the manual
-    axes. The fallback is therefore a custom-vjp identity whose
-    backward psums — without it, differentiating through a pipeline /
-    zero3 carry scales gradients by the axis size (the old-jax "vma
-    gap" tier-1 failures)."""
-    global _pcast_impl
-    if _pcast_impl is None:
-        import jax
-
-        try:
-            _pcast_impl = jax.lax.pcast
-        except AttributeError:  # pragma: no cover - older jax
-            from functools import partial
-
-            @partial(jax.custom_vjp, nondiff_argnums=(1,))
-            def _cast_leaf(leaf, axes):
-                return leaf
-
-            def _cast_fwd(leaf, axes):
-                return leaf, None
-
-            def _cast_bwd(axes, _res, ct):
-                return (jax.lax.psum(ct, axes),)
-
-            _cast_leaf.defvjp(_cast_fwd, _cast_bwd)
-
-            def _r2v(x, axes, to):
-                if to != "varying":
-                    return x
-                return jax.tree.map(
-                    lambda leaf: _cast_leaf(leaf, axes), x
-                )
-
-            _pcast_impl = _r2v
-    return _pcast_impl(x, axes, to)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` where it exists; on older jax (0.4.x)
-    fall back to ``lax.psum(1, axis_name)``, which the tracer
-    constant-folds to the same static Python int inside
-    pmap/shard_map. Keeping the result static matters: callers use it
-    for schedule lengths (``jnp.arange(ticks)``) and permutation
-    tables, which must be concrete at trace time."""
-    import jax
-
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:  # pragma: no cover - older jax
-        return jax.lax.psum(1, axis_name)
-
-
-def shard_map_kwargs() -> dict:
-    """Extra shard_map kwargs for the running jax version: on pre-vma
-    jax the replication checker predates the pcast-typed carries this
-    codebase uses, so it must be disabled (``check_rep=False``); on
-    vma-era jax there is nothing to add."""
-    import jax
-
-    if hasattr(jax.lax, "pcast"):
-        return {}
-    return {"check_rep": False}  # pragma: no cover - older jax

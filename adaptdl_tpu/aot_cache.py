@@ -37,7 +37,10 @@ Scope and safety:
   the coordination risk).
 
 A cache hit or a corrupt entry can never break training: any failure
-deserializing or executing falls back to the ordinary jitted path.
+deserializing or executing falls back to the ordinary jitted path
+(``ElasticTrainer._aot_wrap`` awaits a deserialized executable's first
+run inside its handler, because dispatch is asynchronous), logs a
+WARNING with the traceback, and drops the entry.
 """
 
 from __future__ import annotations
@@ -192,11 +195,20 @@ def load(fp: str) -> Any | None:
         return compiled
     except Exception:  # noqa: BLE001 - a stale/corrupt entry
         LOG.warning("unreadable AOT cache entry %s", fp[:12], exc_info=True)
-        try:
-            os.remove(path)
-        except OSError:
-            pass
+        discard(fp)
         return None
+
+
+def discard(fp: str) -> None:
+    """Drop an entry that could not be read or run, so the next
+    incarnation recompiles instead of tripping over it again."""
+    directory = cache_dir()
+    if directory is None:
+        return
+    try:
+        os.remove(os.path.join(directory, fp))
+    except OSError:
+        pass
 
 
 # In-flight background writers, so tests and the bench can wait for
@@ -246,7 +258,7 @@ def save_async(fp: str, compiled: Any) -> threading.Thread | None:
 
         entry = serialize(compiled)
     except Exception:  # noqa: BLE001 - cache is an optimization
-        LOG.debug("AOT executable serialization failed", exc_info=True)
+        LOG.warning("AOT executable serialization failed", exc_info=True)
         return None
 
     def _write() -> None:
@@ -262,7 +274,7 @@ def save_async(fp: str, compiled: Any) -> threading.Thread | None:
             os.replace(tmp, os.path.join(directory, fp))
             _prune(directory)
         except Exception:  # noqa: BLE001 - cache is an optimization
-            LOG.debug("AOT cache write failed", exc_info=True)
+            LOG.warning("AOT cache write failed", exc_info=True)
 
     thread = threading.Thread(
         target=_write, name="adaptdl-aot-writer", daemon=True
@@ -296,10 +308,35 @@ def _prune(directory: str) -> None:
         pass
 
 
+# Compiles that jax's persistent compilation cache served in this
+# process, counted through jax.monitoring (see load_or_compile).
+_PC_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_pc_hits = 0
+_pc_listener_registered = False
+
+
+def _persistent_cache_hits() -> int:
+    global _pc_listener_registered
+    if not _pc_listener_registered:
+        _pc_listener_registered = True
+        import jax.monitoring
+
+        def on_event(event: str, **_kwargs) -> None:
+            global _pc_hits
+            if event == _PC_HIT_EVENT:
+                _pc_hits += 1
+
+        jax.monitoring.register_event_listener(on_event)
+    return _pc_hits
+
+
 def load_or_compile(trainer: Any, key: tuple, jitted: Any, args: tuple):
     """The train step's first-call path: return a cached executable if
     the fingerprint hits, else AOT-compile through ``jitted`` and
-    persist the result in the background."""
+    persist the result in the background. Returns ``(compiled,
+    hit_fingerprint)``; the fingerprint is None on a miss, and on a
+    hit names the entry the caller should :func:`discard` if the
+    deserialized executable turns out not to run."""
     from adaptdl_tpu import trace
 
     fp = fingerprint(trainer, key, args)
@@ -308,9 +345,26 @@ def load_or_compile(trainer: Any, key: tuple, jitted: Any, args: tuple):
         attrs["hit"] = compiled is not None
     if compiled is not None:
         trace.event("aot.hit")
-        return compiled
+        return compiled, fp
     trace.event("aot.miss")
-    with trace.span("aot.compile", fingerprint=fp[:12]):
+    pc_hits = _persistent_cache_hits()
+    with trace.span("aot.compile", fingerprint=fp[:12]) as attrs:
         compiled = jitted.lower(*args).compile()
-    save_async(fp, compiled)
-    return compiled
+        from_pc = _persistent_cache_hits() != pc_hits
+        attrs["persistent_cache_hit"] = from_pc
+    import jax
+
+    if from_pc and jax.default_backend() == "cpu":
+        # XLA:CPU (jaxlib 0.9.0) cannot serialize an executable that
+        # the persistent cache deserialized: the entry it writes loads
+        # and then fails at run time with "Function ... not found". On
+        # the CPU only an executable this process compiled is cached;
+        # the persistent cache serves the next incarnation anyway. (A
+        # TPU v5e round-trips such an executable correctly.)
+        LOG.info(
+            "step %s came from the persistent compile cache; not "
+            "re-serializing it into the AOT cache", key,
+        )
+    else:
+        save_async(fp, compiled)
+    return compiled, None

@@ -238,31 +238,39 @@ def _enable_compilation_cache() -> None:
     Every rescale is a process restart, and without a cache each
     incarnation pays full recompilation (tens of seconds per step
     configuration on TPU) before its first step — a direct tax on the
-    rescale latency the goodput model's restart penalty prices. The
-    cache directory lives on the job's shared storage
-    (``ADAPTDL_SHARE_PATH``, the cross-restart volume — the analog of
-    the reference's checkpoint PVC, reference:
-    cli/adaptdl_cli/pvc.py:37-78) or beside the checkpoints, so a
-    restarted incarnation with the same topology re-loads its
-    executables instead of rebuilding them. ``ADAPTDL_COMPILE_CACHE``
-    overrides the location; ``off`` disables.
+    rescale latency the goodput model's restart penalty prices.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, the deployment has
+    placed the cache and this function sets no directory of its own
+    (jax reads the variable itself). Otherwise the directory is
+    ``.jax_compile_cache`` under the first of: ``ADAPTDL_COMPILE_CACHE``,
+    the job's shared storage (``ADAPTDL_SHARE_PATH``, the cross-restart
+    volume — the analog of the reference's checkpoint PVC, reference:
+    cli/adaptdl_cli/pvc.py:37-78), the checkpoint directory, and last
+    the checkout holding this package — always a fixed path, because
+    the path is part of the cache key and a directory that moves never
+    hits. ``ADAPTDL_COMPILE_CACHE=off`` disables.
     """
     import os
 
     knob = env.compile_cache_knob()
     if knob.lower() in ("off", "0", "false", "none"):
         return
-    path = knob or env.share_path() or env.checkpoint_path()
-    if not path:
-        return
-    cache_dir = os.path.join(
-        os.path.abspath(path), ".jax_compile_cache"
-    )
     try:
-        os.makedirs(cache_dir, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            path = (
+                knob
+                or env.share_path()
+                or env.checkpoint_path()
+                or env.checkout_root()
+            )
+            cache_dir = os.path.join(
+                os.path.abspath(path), ".jax_compile_cache"
+            )
+            os.makedirs(cache_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # Cache EVERY compile: the default entry-size / compile-time
         # gates would skip the small-but-many configurations the
         # adaptive batch-size loop generates, which are exactly the

@@ -115,13 +115,14 @@ def _cmd_submit(args) -> int:
         print(manifest)
         return 0
 
-    from adaptdl_tpu.sched.local_runner import LocalElasticRunner
+    from adaptdl_tpu.sched.local_runner import (
+        LocalElasticRunner,
+        count_local_chips,
+    )
 
-    chips = args.chips
-    if chips is None:
-        import jax
-
-        chips = len(jax.devices())
+    # The launcher itself stays off the JAX backend: the worker it
+    # starts needs the chip, and a chip serves one process at a time.
+    chips = args.chips if args.chips is not None else count_local_chips()
     extra_env = {}
     if args.log_file:
         # The runner inherits stdio; redirect ourselves when asked.

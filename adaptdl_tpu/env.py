@@ -378,6 +378,15 @@ def compile_cache_knob() -> str:
     return os.environ.get("ADAPTDL_COMPILE_CACHE", "")
 
 
+def checkout_root() -> str:
+    """Directory holding the ``adaptdl_tpu`` package: the last-resort
+    home of the compile cache (``<root>/.jax_compile_cache``), and what
+    ``chip_smoke.py`` / ``bench.py`` name through
+    ``ADAPTDL_COMPILE_CACHE`` so their throw-away checkpoint
+    directories never become the cache path."""
+    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
 def trace_enabled() -> bool:
     """Whether the graftscope tracing subsystem records spans
     (``off``/``0``/``false``/``none`` disables — every ``trace.span``

@@ -776,11 +776,16 @@ def fit_perf_params(
         out[:n] = a
         return out
 
-    try:  # jax >= 0.5 exposes enable_x64 at top level
-        _enable_x64 = jax.enable_x64
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental import enable_x64 as _enable_x64
-    with _enable_x64():
+    # Fifteen scalars of float64 host arithmetic: keep the solve off
+    # the accelerator where a host backend exists. On a TPU float64 is
+    # emulated (compiling this objective took 25.6 s on a v5e against
+    # 0.2 s on its host) and every evaluation would queue behind the
+    # train steps the main thread keeps dispatching to the same chip.
+    try:
+        host = jax.devices("cpu")[0]
+    except RuntimeError:  # JAX_PLATFORMS names no cpu backend
+        host = None
+    with jax.default_device(host), jax.enable_x64():
         args64 = tuple(
             jnp.asarray(a, dtype=jnp.float64)
             for a in (

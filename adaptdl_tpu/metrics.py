@@ -625,14 +625,7 @@ def _maybe_fit_and_report(
     if env.replica_rank() != 0:
         return
     # Fit in the background: the refit compiles/solves on the host and
-    # must never stall the training step loop. Pre-vma jax (no
-    # jax.lax.pcast) has a CPU runtime that is not safe for concurrent
-    # dispatch from a second thread — run the fit inline there.
-    import jax as _jax
-
-    if not hasattr(_jax.lax, "pcast"):  # pragma: no cover - older jax
-        fit_and_report_now()
-        return
+    # must never stall the training step loop.
     global _fit_thread
     if _fit_thread is None or not _fit_thread.is_alive():
         _fit_thread = threading.Thread(

@@ -141,16 +141,10 @@ def make_generator_step(generator, discriminator, optimizer, mesh=None):
 
     from jax.sharding import PartitionSpec as P
 
-    from adaptdl_tpu._compat import pcast as _pcast
     from adaptdl_tpu.parallel.mesh import DATA_AXIS
 
-    try:  # jax >= 0.6
-        shard_map = jax.shard_map
-    except AttributeError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     def per_replica(g_params, g_opt_state, d_params, z_local):
-        g_v = _pcast(g_params, DATA_AXIS, to="varying")
+        g_v = jax.lax.pcast(g_params, DATA_AXIS, to="varying")
         loss, grads = jax.value_and_grad(loss_of)(
             g_v, d_params, z_local
         )
@@ -165,14 +159,11 @@ def make_generator_step(generator, discriminator, optimizer, mesh=None):
             loss,
         )
 
-    from adaptdl_tpu._compat import shard_map_kwargs as _sm_kwargs
-
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_replica,
             mesh=mesh,
             in_specs=(P(), P(), P(), P(DATA_AXIS)),
             out_specs=(P(), P(), P()),
-            **_sm_kwargs(),
         )
     )

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from adaptdl_tpu._compat import axis_size as _axis_size
 from adaptdl_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -184,7 +183,7 @@ def switch_moe(
         its top-capacity tokens — arXiv:2202.09368).
     """
     my_rank = lax.axis_index(axis_name)
-    num_devices = _axis_size(axis_name)
+    num_devices = lax.axis_size(axis_name)
     local_e = params["w_up"].shape[0]
     num_experts = num_devices * local_e
     assert params["router"].shape[-1] == num_experts, (

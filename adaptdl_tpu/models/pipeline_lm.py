@@ -39,7 +39,6 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from adaptdl_tpu._compat import axis_size as _axis_size
 from adaptdl_tpu.models.transformer import Block, TransformerConfig
 from adaptdl_tpu.parallel.mesh import STAGE_AXIS
 from adaptdl_tpu.parallel.pipeline import (
@@ -360,7 +359,7 @@ def init_pipeline_lm(
             outs = gpipe(chunk_fn, blocks_local, micro)
         final = outs.reshape(x.shape)
         stage = lax.axis_index(STAGE_AXIS)
-        num_stages_ = _axis_size(STAGE_AXIS)
+        num_stages_ = lax.axis_size(STAGE_AXIS)
         is_last = stage == num_stages_ - 1
         # Garbage intermediates off the last stage would feed the
         # softmax; neutralize them BEFORE the head (0 * NaN is NaN in

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from adaptdl_tpu._compat import axis_size as _axis_size
 
 
 @dataclass(frozen=True)
@@ -213,7 +212,7 @@ class MoEFFN(nn.Module):
         # declared vs received shapes at apply time).
         local_experts = num_experts
         if cfg.moe_axis is not None:
-            ep = _axis_size(cfg.moe_axis)
+            ep = jax.lax.axis_size(cfg.moe_axis)
             assert num_experts % ep == 0, (
                 f"{num_experts} experts cannot shard over {ep} devices"
                 " (each shard owns a whole number of experts)"

@@ -19,8 +19,10 @@ too. XLA fuses the backward scan well; the forward is where a custom
 kernel beats the default lowering (no [seq, seq] intermediate).
 
 On CPU the kernel runs in interpret mode (bit-accurate semantics,
-Python speed) so the whole path is testable without hardware; the
-mesh-sharded long-context path still uses
+Python speed) so the whole path is testable without hardware — and a
+program lowered that way carries no ``MOSAIC_CALL``, which is what the
+chip-path checks look for. The mesh-sharded long-context path still
+uses
 ``adaptdl_tpu.parallel.ring_attention`` — this kernel is the
 *within-chip* block engine.
 """
@@ -38,20 +40,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# How the Mosaic-compiled kernel appears in a lowered or compiled
+# program's text. Interpret mode leaves no such call, so code that
+# measures or proves the chip path (chip_smoke.py, bench.py, the chip
+# compile tests) asserts this string is present.
+MOSAIC_CALL = "tpu_custom_call"
+
 
 def _use_interpret() -> bool:
+    """Interpret mode (Python-speed reference semantics) off the TPU,
+    so the CPU tests can run the kernel; the compiled kernel on it."""
     return jax.default_backend() != "tpu"
-
-
-def _vma_kwargs(x) -> dict:
-    """``{"vma": ...}`` for ShapeDtypeStruct: inside a shard_map (the
-    trainer's data/seq axes) pallas outputs must declare how they
-    vary. On jax versions without the vma system the kwarg must be
-    OMITTED entirely (passing vma=None would TypeError)."""
-    try:
-        return {"vma": jax.typeof(x).vma}
-    except Exception:  # noqa: BLE001 - older jax without vma
-        return {}
 
 
 def _fwd_kernel(
@@ -145,6 +144,9 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k):
         block_q=block_q,
         block_k=block_k,
     )
+    # Inside a shard_map (the trainer's data/seq axes) pallas outputs
+    # must declare how they vary: the same way q does.
+    vma = jax.typeof(q).vma
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -168,11 +170,9 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k):
             ),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
             jax.ShapeDtypeStruct(
-                q.shape, q.dtype, **_vma_kwargs(q)
-            ),
-            jax.ShapeDtypeStruct(
-                (bh, seq_len, 128), jnp.float32, **_vma_kwargs(q)
+                (bh, seq_len, 128), jnp.float32, vma=vma
             ),
         ],
         scratch_shapes=[

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from adaptdl_tpu._compat import axis_size as _axis_size
-from adaptdl_tpu._compat import pcast as _pcast
 from adaptdl_tpu.parallel.mesh import STAGE_AXIS
 
 
@@ -64,7 +62,7 @@ def gpipe(
       ``where``/psum keyed on ``lax.axis_index``).
     """
     stage = lax.axis_index(axis_name)
-    num_stages = _axis_size(axis_name)
+    num_stages = lax.axis_size(axis_name)
     num_micro = micro_inputs.shape[0]
     ticks = num_micro + num_stages - 1
     perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
@@ -73,7 +71,7 @@ def gpipe(
     # stage's activations), while micro_inputs is replicated across
     # the stage group — pcast the init so the scan carry types line up
     # under shard_map's vma tracking.
-    zero_act = _pcast(
+    zero_act = lax.pcast(
         micro_inputs[0] * 0.0, axis_name, to="varying"
     )
 
@@ -170,7 +168,7 @@ def interleaved_pipeline(
     buffering window; the scheduler's topology search respects this).
     """
     stage = lax.axis_index(axis_name)
-    num_stages = _axis_size(axis_name)
+    num_stages = lax.axis_size(axis_name)
     num_micro = micro_inputs.shape[0]
     if num_micro < num_stages:
         # With M < S the wrap-hop activation lands AFTER its read
@@ -185,7 +183,7 @@ def interleaved_pipeline(
     ticks = v * num_micro + num_stages - 1
     perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
-    zero_act = _pcast(
+    zero_act = lax.pcast(
         micro_inputs[0] * 0.0, axis_name, to="varying"
     )
     # buffer[m] = activation for microbatch m at this device's
@@ -272,7 +270,7 @@ def interleaved_loss(
         )
         final = outs.reshape(x.shape)
         stage = lax.axis_index(axis_name)
-        num_stages = _axis_size(axis_name)
+        num_stages = lax.axis_size(axis_name)
         is_last = stage == num_stages - 1
         final = jnp.where(is_last, final, jnp.ones_like(final))
         loss = loss_head(final, batch)
@@ -313,7 +311,7 @@ def gpipe_loss(
         outs = gpipe(stage_fn, stage_params_local, micro, axis_name)
         final = outs.reshape(x.shape)
         stage = lax.axis_index(axis_name)
-        num_stages = _axis_size(axis_name)
+        num_stages = lax.axis_size(axis_name)
         is_last = stage == num_stages - 1
         # Non-final stages hold garbage intermediates here. Replace
         # them with ones BEFORE loss_head: a head with a

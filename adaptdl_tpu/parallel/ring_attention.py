@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from adaptdl_tpu._compat import axis_size as _axis_size
 from adaptdl_tpu.parallel.mesh import SEQ_AXIS
 
 NEG_INF = -1e30
@@ -44,7 +43,7 @@ def ring_attention(q, k, v, axis_name: str = SEQ_AXIS, causal: bool = True):
     Returns:
       ``[batch, heads, seq_local, head_dim]`` local attention output.
     """
-    ring_size = _axis_size(axis_name)
+    ring_size = lax.axis_size(axis_name)
     my_block = lax.axis_index(axis_name)
     seq_local = q.shape[2]
     scale = q.shape[-1] ** -0.5

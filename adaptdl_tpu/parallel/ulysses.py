@@ -40,7 +40,6 @@ from functools import partial
 import jax.numpy as jnp
 from jax import lax
 
-from adaptdl_tpu._compat import axis_size as _axis_size
 from adaptdl_tpu.parallel.mesh import SEQ_AXIS
 
 
@@ -67,7 +66,7 @@ def ulysses_attention(
     Returns:
       ``[batch, heads, seq_local, head_dim]`` local attention output.
     """
-    shards = _axis_size(axis_name)
+    shards = lax.axis_size(axis_name)
     heads = q.shape[1]
     if heads % shards != 0:
         raise ValueError(

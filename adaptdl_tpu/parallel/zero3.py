@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
-from adaptdl_tpu._compat import pcast as _pcast
 from adaptdl_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -145,16 +144,12 @@ def _ensure_varying(tree: Any, axes) -> Any:
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
 
     def cast(leaf):
-        if not hasattr(jax, "typeof"):  # pragma: no cover - older jax
-            # No vma type system: every value inside shard_map is
-            # already implicitly varying, nothing to cast.
-            return leaf
         missing = tuple(
             a for a in axes if a not in jax.typeof(leaf).vma
         )
         if not missing:
             return leaf
-        return _pcast(leaf, missing, to="varying")
+        return jax.lax.pcast(leaf, missing, to="varying")
 
     return jax.tree.map(cast, tree)
 

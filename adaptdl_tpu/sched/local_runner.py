@@ -423,6 +423,35 @@ class LocalElasticRunner:
             time.sleep(0.2)
 
 
+def count_local_chips(timeout: float = 120.0) -> int:
+    """Chip count for launchers that were not given ``--chips``, taken
+    in a short-lived child that has exited before any worker starts.
+    A chip belongs to one process at a time: a launcher that asked
+    ``jax.devices()`` itself would hold the chip for its whole life
+    and every worker it launched would fail or hang at backend
+    start-up."""
+    try:
+        out = subprocess.run(
+            [
+                sys.executable,
+                "-c",
+                "import jax; print(len(jax.devices()))",
+            ],
+            capture_output=True,
+            text=True,
+            timeout=timeout,
+            check=True,
+        )
+        return int(out.stdout.strip().splitlines()[-1])
+    except (
+        subprocess.SubprocessError, ValueError, IndexError
+    ) as exc:
+        detail = getattr(exc, "stderr", None) or exc
+        raise SystemExit(
+            f"could not count the local chips ({detail}); pass --chips"
+        ) from exc
+
+
 def main() -> int:
     import argparse
 
@@ -441,14 +470,11 @@ def main() -> int:
         "never shrinks or moves it to make room for other jobs)",
     )
     args = parser.parse_args()
-    chips = args.chips
-    if chips is None:
-        import jax
-
-        chips = len(jax.devices())
     runner = LocalElasticRunner(
         args.script,
-        num_chips=chips,
+        num_chips=(
+            args.chips if args.chips is not None else count_local_chips()
+        ),
         checkpoint_dir=args.checkpoint_dir,
         min_replicas=args.min_replicas,
         max_replicas=args.max_replicas,

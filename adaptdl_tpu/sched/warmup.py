@@ -30,6 +30,15 @@ The file-based ready/cutover channel keeps the protocol transport-free
 on the one-box runners: both ends share a filesystem by construction
 (they share a checkpoint dir), and a killed runner leaves nothing a
 successor could mistake for a go signal.
+
+Limit: the warm successor runs "jax init" while the incumbent still
+trains, i.e. two JAX processes on the job's chips at once. A chip
+serves one process at a time, so where successor and incumbent would
+share a chip (the one-box runners on directly attached TPUs) the
+successor fails or hangs at backend start-up and the rescale takes the
+cold path. Warm-up only helps where the successor gets chips the
+incumbent does not hold; it is off by default
+(``ADAPTDL_WARMUP_ENABLED``) and exercised on the CPU backend only.
 """
 
 from __future__ import annotations

@@ -41,7 +41,6 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from adaptdl_tpu import checkpoint, gns
-from adaptdl_tpu._compat import pcast as _pcast, shard_map_kwargs as _sm_kwargs
 
 _LOG = logging.getLogger(__name__)
 from adaptdl_tpu.parallel.mesh import (
@@ -53,11 +52,6 @@ from adaptdl_tpu.parallel.mesh import (
     STAGE_AXIS,
 )
 from adaptdl_tpu.scaling_rules import RuleContext, ScalingRule
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 
 class TrainState(NamedTuple):
@@ -513,7 +507,7 @@ class ElasticTrainer:
             (self.num_replicas * self._zero1_shard,),
             rows_local.dtype,
         )
-        full = _pcast(full, DATA_AXIS, to="varying")
+        full = jax.lax.pcast(full, DATA_AXIS, to="varying")
         rank = jax.lax.axis_index(DATA_AXIS)
         full = jax.lax.dynamic_update_slice(
             full, rows_local[0], (rank * self._zero1_shard,)
@@ -980,7 +974,18 @@ class ElasticTrainer:
                 self._init_opt_state, out_shardings=out_sh
             )(params)
         else:
-            opt_state = self._init_opt_state(params)
+            # Leaves the optimizer creates itself (Adam's step count)
+            # land on the default device only: replicate them over the
+            # mesh like every other leaf, so that a fresh state has
+            # exactly the placement a restored one gets — the AOT
+            # executable cache keys on it, and incarnation 0's entry
+            # must serve incarnation 1.
+            opt_state = jax.tree.map(
+                lambda x: x
+                if isinstance(x.sharding, NamedSharding)
+                else put(x, P()),
+                self._init_opt_state(params),
+            )
         gns_state = gns.init(params, self.num_param_groups)
         if self.zero1 and self.num_replicas > 1:
             gns_state = gns_state._replace(
@@ -1183,7 +1188,7 @@ class ElasticTrainer:
                 lambda p: (p * 0.0).astype(jnp.float32), rows
             )
             lsqr_init = jnp.zeros((1,))
-            loss_init = _pcast(
+            loss_init = jax.lax.pcast(
                 jnp.zeros(()), varying_axes, to="varying"
             )
             init = (grad_init, lsqr_init, loss_init)
@@ -1258,12 +1263,11 @@ class ElasticTrainer:
         if seq_shards > 1:
             manual.add(SEQ_AXIS)
         state_specs = self._manual_state_specs(manual)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_replica_step,
             mesh=self.mesh,
             in_specs=(state_specs, batch_spec, P()),
             out_specs=(state_specs, P()),
-            **_sm_kwargs(),
         )
         return self._finalize_step(sharded, (atomic_bsz, accum_steps))
 
@@ -1273,27 +1277,48 @@ class ElasticTrainer:
         restarted same-topology incarnation skips tracing + lowering +
         compiling entirely; on a miss, AOT-compile once and persist
         the executable in the background. Any failure — disabled
-        cache, stale entry, aval drift — falls back to the ordinary
-        jitted path, permanently for this step."""
+        cache, stale entry, aval drift, an entry that deserializes but
+        cannot run — falls back to the ordinary jitted path,
+        permanently for this step, with a WARNING that carries the
+        traceback (chip_smoke.py treats that warning as a failure)."""
         from adaptdl_tpu import aot_cache
 
         jitted, cacheable = stepped_pair
         if not aot_cache.enabled():
             return jitted
-        cell: dict[str, Any] = {"compiled": None, "tried": False}
+        # "unverified": a DESERIALIZED executable has not run yet. Its
+        # first execution is awaited inside the try below — dispatch
+        # is asynchronous, so a runtime failure of a bad entry would
+        # otherwise surface at some later block_until_ready, outside
+        # any handler, and kill the incarnation (and every restart
+        # that finds the same entry).
+        cell: dict[str, Any] = {
+            "compiled": None, "tried": False, "unverified": None,
+        }
 
         def stepped(state, batch, aux):
             if not cell["tried"]:
                 cell["tried"] = True
                 try:
-                    cell["compiled"] = aot_cache.load_or_compile(
+                    cell["compiled"], hit_fp = aot_cache.load_or_compile(
                         self, key, cacheable, (state, batch, aux)
                     )
+                    cell["unverified"] = hit_fp
                 except Exception:  # noqa: BLE001 - cache best-effort
+                    _LOG.warning(
+                        "AOT executable cache failed for step %s; "
+                        "using the jitted path",
+                        key,
+                        exc_info=True,
+                    )
                     cell["compiled"] = None
             if cell["compiled"] is not None:
                 try:
-                    return cell["compiled"](state, batch, aux)
+                    out = cell["compiled"](state, batch, aux)
+                    if cell["unverified"] is not None:
+                        jax.block_until_ready(out)
+                        cell["unverified"] = None
+                    return out
                 except Exception:  # noqa: BLE001 - aval/sharding drift
                     _LOG.warning(
                         "cached AOT executable for step %s failed; "
@@ -1302,6 +1327,8 @@ class ElasticTrainer:
                         exc_info=True,
                     )
                     cell["compiled"] = None
+                    if cell["unverified"] is not None:
+                        aot_cache.discard(cell["unverified"])
             return jitted(state, batch, aux)
 
         return stepped
@@ -1459,7 +1486,7 @@ class ElasticTrainer:
             varying_axes = (
                 (DATA_AXIS, SEQ_AXIS) if seq_shards > 1 else DATA_AXIS
             )
-            params_v = _pcast(params, varying_axes, to="varying")
+            params_v = jax.lax.pcast(params, varying_axes, to="varying")
             precond = (
                 self._zero1_precond(state.opt_state)
                 if self.zero1
@@ -1470,7 +1497,7 @@ class ElasticTrainer:
             precond_v = (
                 None
                 if precond is None
-                else _pcast(precond, DATA_AXIS, to="varying")
+                else jax.lax.pcast(precond, DATA_AXIS, to="varying")
             )
             # Per-replica, per-step rng; microbatch rngs split below.
             rng = jax.random.fold_in(state.rng, state.step)
@@ -1522,15 +1549,15 @@ class ElasticTrainer:
             zeros = jax.tree.map(
                 lambda p: (p * 0.0).astype(jnp.float32), params
             )
-            grad_init = _pcast(zeros, DATA_AXIS, to="varying")
+            grad_init = jax.lax.pcast(zeros, DATA_AXIS, to="varying")
             # lsqr is already psum'd over the sharded axes inside
             # stat_normsqr, so the carry varies over data only.
-            lsqr_init = _pcast(
+            lsqr_init = jax.lax.pcast(
                 jnp.zeros((self.num_param_groups,)),
                 DATA_AXIS,
                 to="varying",
             )
-            loss_init = _pcast(
+            loss_init = jax.lax.pcast(
                 jnp.zeros(()), DATA_AXIS, to="varying"
             )
             init = (grad_init, lsqr_init, loss_init)
@@ -1635,13 +1662,12 @@ class ElasticTrainer:
         # pure data parallelism; stage-sharded params (and their
         # optimizer/GNS mirrors) under pipeline parallelism.
         state_specs = self._manual_state_specs(manual)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_replica_step,
             mesh=self.mesh,
             in_specs=(state_specs, batch_spec, P()),
             out_specs=(state_specs, P()),
             **extra,
-            **_sm_kwargs(),
         )
         return self._finalize_step(sharded, (atomic_bsz, accum_steps))
 
@@ -1750,13 +1776,12 @@ class ElasticTrainer:
             param_specs = self._restrict_specs(
                 self._param_spec_tree(self._init_params), manual
             )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_replica,
             mesh=self.mesh,
             in_specs=(param_specs, batch_spec),
             out_specs=P(),
             **extra,
-            **_sm_kwargs(),
         )
         jitted = jax.jit(sharded)
         fn = lambda state, batch: jitted(state.params, batch)  # noqa: E731
@@ -1846,7 +1871,7 @@ class ElasticTrainer:
                 params = self._zero1_unravel(
                     self._rows_to_flat(params)
                 )
-            params_v = _pcast(params, varying_axes, to="varying")
+            params_v = jax.lax.pcast(params, varying_axes, to="varying")
             rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
             loss, grads = jax.value_and_grad(self.loss_fn)(
                 params_v, local_batch, rng, *extra
@@ -1878,13 +1903,12 @@ class ElasticTrainer:
             param_specs = self._restrict_specs(
                 self._param_spec_tree(self._init_params), manual
             )
-        sharded = shard_map(
+        sharded = jax.shard_map(
             per_replica,
             mesh=self.mesh,
             in_specs=(param_specs, batch_spec, P(), P()),
             out_specs=P(DATA_AXIS),
             **extra,
-            **_sm_kwargs(),
         )
         return jax.jit(sharded)
 

@@ -21,8 +21,138 @@ import argparse
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(__file__))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from _data import force_cpu_devices, synthetic_tokens  # noqa: E402
+
+# The batch-size recipe: adamw(3e-4) is tuned at 32 sequences; the
+# goodput model may grow the global batch to 1024 with 4..64 sequences
+# per chip per microbatch, accumulating beyond that. The upper bound
+# is the job's memory contract, and the goodput model does take it
+# when throughput says so. For the flagship preset on a 16 GB chip
+# (TPU v5e) 64 is what fits with room to spare: compiled for that chip,
+# the accumulating step needs 9.9 GiB at 64 sequences, 13.1 GiB at 96
+# and over 16.4 GiB at 128 (the dense head's float32 logits alone are
+# 128 * 512 * 32000 * 4 B = 8.4 GB).
+LEARNING_RATE = 3e-4
+INIT_BATCH_SIZE = 32
+MAX_BATCH_SIZE = 1024
+LOCAL_BSZ_BOUNDS = (4, 64)
+
+
+def lm_config(on_cpu: bool, seq_len: int | None = None, **overrides):
+    """The example's model preset: a CPU demo size, or the flagship
+    this repo runs on the chip — 12 layers, d_model 768, 12 heads of
+    64, d_ff 3072, vocab 32000, seq 512, bf16, remat. The one copy:
+    ``chip_smoke.py`` drives the chip with exactly this."""
+    import jax.numpy as jnp
+
+    from adaptdl_tpu.models import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=256 if on_cpu else 32000,
+        num_layers=2 if on_cpu else 12,
+        num_heads=2 if on_cpu else 12,
+        d_model=64 if on_cpu else 768,
+        d_ff=128 if on_cpu else 3072,
+        max_seq_len=seq_len or (32 if on_cpu else 512),
+        dtype=jnp.float32 if on_cpu else jnp.bfloat16,
+        remat=True,
+        **overrides,
+    )
+
+
+def flash_attention_fn(seq_len: int):
+    """``--flash``: the Pallas kernel as the within-chip attention."""
+    import functools
+
+    from adaptdl_tpu.ops.flash_attention import flash_attention
+
+    block = min(128, seq_len)
+    return functools.partial(
+        flash_attention, block_q=block, block_k=block
+    )
+
+
+def dense_lm_loss(model, chunked_xent: int = 0):
+    """Next-token loss over ``{"inputs", "targets"}`` batches, plus the
+    MoE aux loss; ``chunked_xent`` > 0 streams the output head in
+    vocab chunks of that size (ops/chunked_xent.py)."""
+    import optax
+
+    from adaptdl_tpu.models.transformer import apply_with_moe_aux
+
+    if chunked_xent > 0:
+        from adaptdl_tpu.ops.chunked_xent import chunked_softmax_xent
+
+        def loss_fn(params, batch, rng):
+            hidden, aux = apply_with_moe_aux(
+                model, params, batch["inputs"], rng,
+                return_hidden=True,
+            )
+            flat = hidden.reshape(-1, hidden.shape[-1])
+            losses = chunked_softmax_xent(
+                flat,
+                params["embed"]["embedding"],
+                batch["targets"].reshape(-1),
+                chunked_xent,
+            )
+            return losses.mean() + aux
+
+    else:
+
+        def loss_fn(params, batch, rng):
+            logits, aux = apply_with_moe_aux(
+                model, params, batch["inputs"], rng
+            )
+            return (
+                optax.softmax_cross_entropy_with_integer_labels(
+                    logits, batch["targets"]
+                ).mean()
+                + aux
+            )
+
+    return loss_fn
+
+
+def make_trainer(loss_fn, params, mesh, **layout):
+    """The example's optimizer and scaling recipe around a loss;
+    ``layout`` carries the parallelism arguments (sharding fn,
+    pipeline micro, zero modes)."""
+    import optax
+
+    from adaptdl_tpu.scaling_rules import AdamScale
+    from adaptdl_tpu.trainer import ElasticTrainer
+
+    return ElasticTrainer(
+        loss_fn=loss_fn,
+        params=params,
+        optimizer=optax.adamw(LEARNING_RATE),
+        init_batch_size=INIT_BATCH_SIZE,
+        scaling_rule=AdamScale(),
+        precondition="adam",
+        mesh=mesh,
+        **layout,
+    )
+
+
+def make_loader(dataset):
+    from adaptdl_tpu.data import AdaptiveDataLoader
+
+    loader = AdaptiveDataLoader(dataset, batch_size=INIT_BATCH_SIZE)
+    loader.autoscale_batch_size(
+        MAX_BATCH_SIZE,
+        local_bsz_bounds=LOCAL_BSZ_BOUNDS,
+        gradient_accumulation=True,
+    )
+    return loader
+
+
+def shifted(raw):
+    """Token rows -> the ``{"inputs", "targets"}`` next-token pair."""
+    return {
+        "inputs": raw[:, :-1].copy(),
+        "targets": raw[:, 1:].copy(),
+    }
 
 
 def main():
@@ -94,16 +224,11 @@ def main():
         force_cpu_devices()
 
     import jax
-    import jax.numpy as jnp
-    import optax
 
     import adaptdl_tpu
     from adaptdl_tpu import checkpoint, env, epoch, metrics
-    from adaptdl_tpu.data import AdaptiveDataLoader
-    from adaptdl_tpu.models import TransformerConfig, init_transformer
+    from adaptdl_tpu.models import init_transformer
     from adaptdl_tpu.parallel import create_mesh
-    from adaptdl_tpu.scaling_rules import AdamScale
-    from adaptdl_tpu.trainer import ElasticTrainer
 
     adaptdl_tpu.initialize_job()
     on_cpu = args.cpu
@@ -120,14 +245,7 @@ def main():
             "--seq-mode ulysses (full sequence gathered per head "
             "slice); ring attention owns its blocked softmax"
         )
-        import functools
-
-        from adaptdl_tpu.ops.flash_attention import flash_attention
-
-        block = min(128, seq_len)
-        flash_inner = functools.partial(
-            flash_attention, block_q=block, block_k=block
-        )
+        flash_inner = flash_attention_fn(seq_len)
         if seq_shards > 1:
             from adaptdl_tpu.parallel.ulysses import (
                 make_ulysses_attention,
@@ -190,15 +308,9 @@ def main():
         # Export NOW: env.pipeline_micro()'s stage-aware default and
         # the trainer's topology registration both read it.
         os.environ["ADAPTDL_STAGE_SHARDS"] = str(stage_shards)
-    config = TransformerConfig(
-        vocab_size=256 if on_cpu else 32000,
-        num_layers=2 if on_cpu else 12,
-        num_heads=2 if on_cpu else 12,
-        d_model=64 if on_cpu else 768,
-        d_ff=128 if on_cpu else 3072,
-        max_seq_len=seq_len,
-        dtype=jnp.float32 if on_cpu else jnp.bfloat16,
-        remat=True,
+    config = lm_config(
+        on_cpu,
+        seq_len,
         remat_policy=args.remat_policy,
         seq_axis="seq" if seq_shards > 1 else None,
         seq_attention=args.seq_mode,
@@ -265,39 +377,7 @@ def main():
                 dense_lm_checkpoint_transforms(config.num_layers)
             )
 
-        from adaptdl_tpu.models.transformer import apply_with_moe_aux
-
-        if args.chunked_xent > 0:
-            from adaptdl_tpu.ops.chunked_xent import (
-                chunked_softmax_xent,
-            )
-
-            def loss_fn(params, batch, rng):
-                hidden, aux = apply_with_moe_aux(
-                    model, params, batch["inputs"], rng,
-                    return_hidden=True,
-                )
-                flat = hidden.reshape(-1, hidden.shape[-1])
-                losses = chunked_softmax_xent(
-                    flat,
-                    params["embed"]["embedding"],
-                    batch["targets"].reshape(-1),
-                    args.chunked_xent,
-                )
-                return losses.mean() + aux
-
-        else:
-
-            def loss_fn(params, batch, rng):
-                logits, aux = apply_with_moe_aux(
-                    model, params, batch["inputs"], rng
-                )
-                return (
-                    optax.softmax_cross_entropy_with_integer_labels(
-                        logits, batch["targets"]
-                    ).mean()
-                    + aux
-                )
+        loss_fn = dense_lm_loss(model, args.chunked_xent)
 
     # ADAPTDL_NUM_REPLICAS counts CHIPS at launch; a seq-, tensor- or
     # expert-sharded group of chips forms one data-parallel replica,
@@ -363,14 +443,10 @@ def main():
             if spec != P():
                 return spec
             return tp_fn(path, leaf) if tp_fn is not None else P()
-    trainer = ElasticTrainer(
-        loss_fn=loss_fn,
-        params=params,
-        optimizer=optax.adamw(3e-4),
-        init_batch_size=32,
-        scaling_rule=AdamScale(),
-        precondition="adam",
-        mesh=mesh,
+    trainer = make_trainer(
+        loss_fn,
+        params,
+        mesh,
         param_sharding_fn=param_sharding_fn,
         # The M the pipelined loss_fn was actually built with — the
         # dataloader sizes per-replica batches to divide by it.
@@ -398,23 +474,14 @@ def main():
     if args.zero3_blocks and seq_shards > 1:
         # Long-context zero3_blocks: pre-split so the seq dim shards
         # cleanly (models/zero3_lm.py's seq contract).
-        dataset = {
-            "inputs": raw[:, :-1].copy(),
-            "targets": raw[:, 1:].copy(),
-        }
+        dataset = shifted(raw)
     elif stage_shards > 1 or args.zero3_blocks:
         # The pipelined and zero3-blocks losses consume raw token rows
         # and shift internally (models/{pipeline_lm,zero3_lm}.py).
         dataset = {"tokens": raw}
     else:
-        dataset = {
-            "inputs": raw[:, :-1].copy(),
-            "targets": raw[:, 1:].copy(),
-        }
-    loader = AdaptiveDataLoader(dataset, batch_size=32)
-    loader.autoscale_batch_size(
-        1024, local_bsz_bounds=(4, 128), gradient_accumulation=True
-    )
+        dataset = shifted(raw)
+    loader = make_loader(dataset)
     # Advertise how far this model can shard each sample: the largest
     # power of two dividing seq_len (the scheduler only picks
     # power-of-two factorizations, and a non-dividing choice would

@@ -59,7 +59,7 @@ TRAIN_SCRIPT = textwrap.dedent(
                                 gradient_accumulation=True)
     import time as _time
 
-    for e in epoch.remaining_epochs_until(60):
+    for e in epoch.remaining_epochs_until(40):
         for batch in loader:
             holder["state"], m = trainer.run_step(
                 holder["state"], batch, loader
@@ -102,3 +102,72 @@ def test_local_elastic_runner_end_to_end(tmp_path):
     # (The *final* allocation size is a policy outcome of this box's
     # noisy timings — growing and later shrinking back to 1 replica is
     # legitimate; the rescale itself is the behavior under test.)
+
+
+LAUNCHERS_OFF_JAX = textwrap.dedent(
+    """
+    import subprocess, sys
+
+    launched = []
+    real_popen = subprocess.Popen
+
+    class RecordingPopen(real_popen):
+        def __init__(self, args, *a, **kw):
+            # What this parent looked like at the moment it started a
+            # child: a chip serves one process, so it must not have
+            # touched jax (not imported is the strongest form).
+            launched.append((list(args), "jax" in sys.modules))
+            super().__init__(args, *a, **kw)
+
+    subprocess.Popen = RecordingPopen
+
+    from adaptdl_tpu import cli
+    from adaptdl_tpu.sched import local_runner
+
+    script, ckpt = sys.argv[1], sys.argv[2]
+    # No --chips: the count comes from a short-lived child.
+    sys.argv = ["local_runner", script, "--checkpoint-dir", ckpt + "/a"]
+    assert local_runner.main() == 0
+    assert cli.main(
+        ["submit", script, "--checkpoint-dir", ckpt + "/b", "--chips", "1"]
+    ) == 0
+    workers = [cmd for cmd, _ in launched if cmd[-1] == script]
+    counters = [cmd for cmd, _ in launched if "jax.devices()" in cmd[-1]]
+    assert len(workers) == 2 and len(counters) == 1, launched
+    assert not any(had_jax for _, had_jax in launched), launched
+    assert "jax" not in sys.modules
+    print("LAUNCHERS_OFF_JAX_OK")
+    """
+)
+
+
+def test_launchers_reach_popen_without_touching_jax(tmp_path):
+    """``python -m adaptdl_tpu.sched.local_runner`` and ``adaptdl-tpu
+    submit`` start the worker (and, without ``--chips``, the chip
+    counter before it — once here, it imports jax for seconds) from a
+    parent that never imported jax — on a
+    directly attached chip a parent holding the backend would leave
+    its worker none. Runs in a subprocess: this pytest process has
+    long since initialised a backend itself."""
+    import subprocess
+    import sys
+
+    script = tmp_path / "worker.py"
+    script.write_text("print('WORKER_RAN', flush=True)\n")
+    driver = tmp_path / "driver.py"
+    driver.write_text(LAUNCHERS_OFF_JAX)
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [repo_root, env.get("PYTHONPATH")])
+    )
+    proc = subprocess.run(
+        [sys.executable, str(driver), str(script), str(tmp_path)],
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "LAUNCHERS_OFF_JAX_OK" in proc.stdout
+    assert proc.stdout.count("WORKER_RAN") == 2

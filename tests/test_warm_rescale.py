@@ -859,6 +859,13 @@ def _drive_rescale(runner, log, errors, alloc):
         errors.append(repr(exc))
 
 
+# Steps of the simulated job. The incumbent must still be training when
+# the speculative successor — a fresh python whose imports alone take
+# 2.5-3.5 s on a slow box — reports ready (or dies, or is discarded):
+# 160 steps x 0.04 s leaves twice that.
+SIM_TOTAL = 160
+
+
 def _run_elastic(
     tmp_path,
     monkeypatch,
@@ -866,7 +873,7 @@ def _run_elastic(
     warm_enabled=True,
     sim_env=None,
     fault_spec=None,
-    total=80,
+    total=SIM_TOTAL,
 ):
     monkeypatch.setenv(
         "ADAPTDL_WARMUP_ENABLED", "on" if warm_enabled else ""
@@ -942,8 +949,10 @@ def test_warm_rescale_cutover_loses_zero_steps(tmp_path, monkeypatch):
     assert "ckpt.restore" not in spans, (
         "warm cutover touched checkpoint storage"
     )
-    assert len(dones) == 1 and int(dones[0][2]) == 80
-    assert np.array_equal(_done_weights(dones[0]), _expected_w(80)), (
+    assert len(dones) == 1 and int(dones[0][2]) == SIM_TOTAL
+    assert np.array_equal(
+        _done_weights(dones[0]), _expected_w(SIM_TOTAL)
+    ), (
         "warm cutover is loss-equal to uninterrupted training"
     )
     assert runner.state.get_job("test/warm").status == "Succeeded"
@@ -963,7 +972,7 @@ def test_warm_spawn_fault_falls_back_cold_loss_equal(
         ["1", "cold"],
     ], f"spawn fault falls back to the cold planned path: {starts}"
     dones = [ln for ln in lines if ln[0] == "done"]
-    assert np.array_equal(_done_weights(dones[0]), _expected_w(80))
+    assert np.array_equal(_done_weights(dones[0]), _expected_w(SIM_TOTAL))
 
 
 def test_warm_successor_killed_midwarm_falls_back_cold(
@@ -980,7 +989,7 @@ def test_warm_successor_killed_midwarm_falls_back_cold(
         ["1", "cold"],
     ], f"dead speculation is discarded, rescale goes cold: {starts}"
     dones = [ln for ln in lines if ln[0] == "done"]
-    assert np.array_equal(_done_weights(dones[0]), _expected_w(80))
+    assert np.array_equal(_done_weights(dones[0]), _expected_w(SIM_TOTAL))
 
 
 def test_incumbent_crash_before_cutover_discards_warm(
@@ -1002,8 +1011,8 @@ def test_incumbent_crash_before_cutover_discards_warm(
         f"{starts}"
     )
     dones = [ln for ln in lines if ln[0] == "done"]
-    assert len(dones) == 1 and int(dones[0][2]) == 80
-    assert np.array_equal(_done_weights(dones[0]), _expected_w(80))
+    assert len(dones) == 1 and int(dones[0][2]) == SIM_TOTAL
+    assert np.array_equal(_done_weights(dones[0]), _expected_w(SIM_TOTAL))
 
 
 def test_mispredicted_candidate_discards_warm_successor(
