@@ -308,28 +308,6 @@ def _prune(directory: str) -> None:
         pass
 
 
-# Compiles that jax's persistent compilation cache served in this
-# process, counted through jax.monitoring (see load_or_compile).
-_PC_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_pc_hits = 0
-_pc_listener_registered = False
-
-
-def _persistent_cache_hits() -> int:
-    global _pc_listener_registered
-    if not _pc_listener_registered:
-        _pc_listener_registered = True
-        import jax.monitoring
-
-        def on_event(event: str, **_kwargs) -> None:
-            global _pc_hits
-            if event == _PC_HIT_EVENT:
-                _pc_hits += 1
-
-        jax.monitoring.register_event_listener(on_event)
-    return _pc_hits
-
-
 def load_or_compile(trainer: Any, key: tuple, jitted: Any, args: tuple):
     """The train step's first-call path: return a cached executable if
     the fingerprint hits, else AOT-compile through ``jitted`` and
@@ -347,10 +325,14 @@ def load_or_compile(trainer: Any, key: tuple, jitted: Any, args: tuple):
         trace.event("aot.hit")
         return compiled, fp
     trace.event("aot.miss")
-    pc_hits = _persistent_cache_hits()
+    # Whether jax's persistent compile cache served this compile: the
+    # bridge counts its hits (tests and tools come here without
+    # initialize_job having installed it).
+    trace.install_jax_bridge()
+    pc_hits = trace.jax_cache_hits()
     with trace.span("aot.compile", fingerprint=fp[:12]) as attrs:
         compiled = jitted.lower(*args).compile()
-        from_pc = _persistent_cache_hits() != pc_hits
+        from_pc = trace.jax_cache_hits() != pc_hits
         attrs["persistent_cache_hit"] = from_pc
     import jax
 

@@ -160,6 +160,9 @@ def initialize_job(distributed: bool | None = None) -> None:
     # restore/first-step spans of this incarnation must land in the
     # same trace as the allocator decision that restarted it.
     trace.init_from_env()
+    # jax's trace / lower / compile phases become jit.* spans from
+    # here on, under whatever span is open where they happen.
+    trace.install_jax_bridge()
     if not _restart_span_armed:
         _restart_span_armed = True
         # The restart->first-step window: opened here, closed by the

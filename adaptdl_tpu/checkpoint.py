@@ -1240,7 +1240,9 @@ def load_state(state: State, prefer_good: bool = False) -> bool:
         # truncated file fails its manifest digest here instead of
         # loading as silent garbage (pickle and np.load happily accept
         # many corruptions).
-        verdict = _verify_state_payload(ckpt, state.name)
+        with trace.span("ckpt.verify", state=state.name) as attrs:
+            verdict = _verify_state_payload(ckpt, state.name)
+            attrs["verdict"] = verdict
         if verdict == "corrupt":
             attempted = True
             LOG.warning(

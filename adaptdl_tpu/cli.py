@@ -813,13 +813,22 @@ def _cmd_trace(args) -> int:  # wire: consumes=trace_payload,trace_span
     the Chrome/Perfetto ``trace_event`` file."""
     from adaptdl_tpu import rpc, trace
 
-    payload = rpc.default_client().get(
-        f"{args.supervisor}/trace/{args.job}",
-        endpoint="cli/trace",
-        timeout=10,
-        attempts=3,
-        deadline=30.0,
-    ).json()
+    if args.journal:
+        # A worker's own journal (ADAPTDL_TRACE_DIR): no supervisor
+        # needed to read where one restart spent its time.
+        payload = {"spans": trace.read_journal(args.journal)}
+    elif args.supervisor:
+        payload = rpc.default_client().get(
+            f"{args.supervisor}/trace/{args.job}",
+            endpoint="cli/trace",
+            timeout=10,
+            attempts=3,
+            deadline=30.0,
+        ).json()
+    else:
+        print("trace: give --supervisor URL or --journal FILE",
+              file=sys.stderr)
+        return 2
     spans = payload.get("spans") or []
     if not spans:
         print(f"no spans recorded for {args.job}", file=sys.stderr)
@@ -861,9 +870,16 @@ def _cmd_trace(args) -> int:  # wire: consumes=trace_payload,trace_span
     print(trace.render_waterfall(selected))
     summary = trace.phase_summary(selected)
     if summary:
+        durs: dict[str, list] = {}
+        for rec in selected:
+            durs.setdefault(rec["name"], []).append(rec.get("dur", 0.0))
         print("\nper-phase medians:")
         for name in sorted(summary):
-            print(f"  {name:<28} {summary[name] * 1e3:>10.2f} ms")
+            print(
+                f"  {name:<28} {summary[name] * 1e3:>10.2f} ms"
+                f"  x{len(durs[name]):<4}"
+                f" total {sum(durs[name]) * 1e3:>10.2f} ms"
+            )
     if args.perfetto:
         with open(args.perfetto, "w", encoding="utf-8") as f:
             json.dump(trace.to_perfetto(selected), f)
@@ -1506,7 +1522,15 @@ def main(argv=None) -> int:
         "Chrome/Perfetto trace_event file)",
     )
     p.add_argument("job", help="namespace/name")
-    p.add_argument("--supervisor", required=True)
+    p.add_argument("--supervisor", default=None)
+    p.add_argument(
+        "--journal",
+        default=None,
+        metavar="FILE",
+        help="read the spans from a trace journal "
+        "(ADAPTDL_TRACE_DIR/trace-<job>.jsonl) instead of the "
+        "supervisor",
+    )
     p.add_argument(
         "--trace-id",
         default=None,

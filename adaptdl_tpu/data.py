@@ -51,6 +51,7 @@ from adaptdl_tpu import (
     env,
     metrics,
     sched_hints,
+    trace,
 )
 
 LOG = logging.getLogger(__name__)
@@ -231,12 +232,15 @@ class AdaptiveDataLoader:
 
     def _optimize_batch_size(self) -> None:
         """Re-optimize (atomic_bsz, accum_steps); adopt on >5% speedup."""
-        if env.replica_rank() == 0:
-            decision = self._rank0_decision()
-        else:
-            decision = None
-        decision = collective.broadcast(decision)
-        self.apply_retune(*decision)
+        with trace.span("policy.optimize") as attrs:
+            if env.replica_rank() == 0:
+                decision = self._rank0_decision()
+            else:
+                decision = None
+            decision = collective.broadcast(decision)
+            self.apply_retune(*decision)
+            attrs["atomic_bsz"] = self._atomic_bsz
+            attrs["accum_steps"] = self._accum_steps
 
     def apply_retune(self, atomic_bsz: int, accum_steps: int) -> None:
         """Adopt a new (atomic_bsz, accum_steps) IN-PROCESS — the live

@@ -598,19 +598,20 @@ def _fit() -> PerfParams | None:
         interleaves.append(chunks // ss if runnable else 1)
     if not nodes:
         return None
-    return fit_perf_params(
-        nodes,
-        replicas,
-        bszs,
-        accum_times,
-        optim_times,
-        seq_shards=sps,
-        model_shards=tps,
-        stage_shards=sss,
-        pipeline_micro=micros,
-        expert_shards=eps,
-        pipeline_interleave=interleaves,
-    )
+    with trace.span("goodput.fit", points=len(nodes)):
+        return fit_perf_params(
+            nodes,
+            replicas,
+            bszs,
+            accum_times,
+            optim_times,
+            seq_shards=sps,
+            model_shards=tps,
+            stage_shards=sss,
+            pipeline_micro=micros,
+            expert_shards=eps,
+            pipeline_interleave=interleaves,
+        )
 
 
 def _maybe_fit_and_report(

@@ -261,6 +261,19 @@ def absorb(records: list[dict]) -> None:
             _observe(rec)
 
 
+def _parent_context(traceparent: str | None) -> tuple[str, str]:
+    """(trace_id, parent span id) of a record made now: the explicit
+    foreign context when one is given, else this thread's innermost
+    open span, else the process root."""
+    parsed = parse_traceparent(traceparent) if traceparent else None
+    if parsed is not None:
+        return parsed
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        return stack[-1]
+    return _root_context()
+
+
 @contextmanager
 def span(  # wire: produces=trace_span
     name: str, traceparent: str | None = None, **attrs
@@ -276,15 +289,7 @@ def span(  # wire: produces=trace_span
     if not enabled():
         yield attrs
         return
-    parsed = parse_traceparent(traceparent) if traceparent else None
-    if parsed is not None:
-        trace_id, parent_id = parsed
-    else:
-        stack = getattr(_tls, "stack", None)
-        if stack:
-            trace_id, parent_id = stack[-1]
-        else:
-            trace_id, parent_id = _root_context()
+    trace_id, parent_id = _parent_context(traceparent)
     span_id = _rand_hex(8)
     if not hasattr(_tls, "stack"):
         _tls.stack = []
@@ -324,14 +329,12 @@ def record_span(  # wire: produces=trace_span
 ) -> None:
     """Record an already-measured span (the supervisor's epoch
     prepare→commit window is timed by the state layer, not a ``with``
-    block)."""
+    block; jax reports a compile phase when it has ended). Parents
+    like :func:`span`: a ``jit.lower`` recorded while ``aot.compile``
+    is open on the thread is its child."""
     if not enabled():
         return
-    parsed = parse_traceparent(traceparent) if traceparent else None
-    if parsed is not None:
-        trace_id, parent_id = parsed
-    else:
-        trace_id, parent_id = _root_context()
+    trace_id, parent_id = _parent_context(traceparent)
     _record(
         {
             "name": name,
@@ -356,15 +359,7 @@ def event(  # wire: produces=trace_span
     circuit opens, cache hits/misses, epoch prepares."""
     if not enabled():
         return
-    parsed = parse_traceparent(traceparent) if traceparent else None
-    if parsed is not None:
-        trace_id, parent_id = parsed
-    else:
-        stack = getattr(_tls, "stack", None)
-        if stack:
-            trace_id, parent_id = stack[-1]
-        else:
-            trace_id, parent_id = _root_context()
+    trace_id, parent_id = _parent_context(traceparent)
     _record(
         {
             "name": name,
@@ -419,10 +414,99 @@ def end_pending(name: str, **attrs) -> bool:
         return False
     wall, start, open_attrs = opened
     open_attrs.update(attrs)
+    # A pending span was opened at another callsite, so whatever span
+    # is open on the closing thread is not its parent: the root is.
     record_span(
-        name, time.monotonic() - start, ts=wall, **open_attrs
+        name,
+        time.monotonic() - start,
+        traceparent=format_traceparent(*_root_context()),
+        ts=wall,
+        **open_attrs,
     )
     return True
+
+
+# ---- jax.monitoring bridge: jit.trace / jit.lower / jit.compile ------
+
+# jax reports each phase of making a program runnable as a duration
+# event when the phase has ended; the bridge turns the outermost ones
+# into spans. ``jit.compile`` is the backend compile OR the load from
+# the persistent compile cache, whichever served the request.
+_JAX_PHASE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "jit.cache_hit",
+    "/jax/compilation_cache/cache_misses": "jit.cache_miss",
+}
+_jax_bridge_lock = threading.Lock()  # lock-order: 75
+_jax_bridge_installed = False  # guarded-by: _jax_bridge_lock
+_jax_cache_hits = 0  # guarded-by: _jax_bridge_lock
+
+
+def _on_jax_phase_start(jax_event: str, _value, **_kwargs) -> None:
+    # jax announces a phase's start as a scalar (its wall clock) and
+    # its end as a duration: the pair gives the nesting depth.
+    if jax_event in _JAX_PHASE_SPANS:
+        _tls.jax_depth = getattr(_tls, "jax_depth", 0) + 1
+
+
+def _on_jax_phase_end(
+    jax_event: str, duration_s: float, **kwargs
+) -> None:
+    name = _JAX_PHASE_SPANS.get(jax_event)
+    if name is None:
+        return
+    depth = max(getattr(_tls, "jax_depth", 0) - 1, 0)
+    _tls.jax_depth = depth
+    if depth:
+        # Tracing one program traces every jitted function it calls
+        # (each jnp operation is one): hundreds of events per program,
+        # all inside the outermost one's interval. Recording them
+        # would count their time twice and flood the ring buffer.
+        return
+    record_span(name, duration_s, fun=str(kwargs.get("fun_name", "")))
+
+
+def _on_jax_event(jax_event: str, **_kwargs) -> None:
+    global _jax_cache_hits
+    name = _JAX_CACHE_EVENTS.get(jax_event)
+    if name is None:
+        return
+    if name == "jit.cache_hit":
+        with _jax_bridge_lock:
+            _jax_cache_hits += 1
+    event(name)
+
+
+def install_jax_bridge() -> None:
+    """Register this module's ``jax.monitoring`` listeners, once per
+    process: the only ones the program registers. Imports jax here,
+    not at module level: the control plane imports this module and
+    must not pay for (or need) jax."""
+    global _jax_bridge_installed
+    with _jax_bridge_lock:
+        if _jax_bridge_installed:
+            return
+        _jax_bridge_installed = True
+    import jax.monitoring
+
+    jax.monitoring.register_scalar_listener(_on_jax_phase_start)
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_jax_phase_end
+    )
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def jax_cache_hits() -> int:
+    """Programs jax's persistent compile cache has served in this
+    process since the bridge was installed. A plain count, kept with
+    tracing on or off: ``aot_cache.load_or_compile`` decides from it
+    whether an executable may be serialized."""
+    with _jax_bridge_lock:
+        return _jax_cache_hits
 
 
 # ---- exporter 1: per-job JSONL structured event journal --------------
@@ -842,9 +926,36 @@ def phase_summary(records: list[dict]) -> dict[str, float]:
     return summary
 
 
+def self_times(records: list[dict]) -> dict[str, float]:
+    """span id -> self time (seconds): the span's duration less the
+    part of its interval that its child spans (by ``parent`` id)
+    cover. Summed over a span and all its descendants, self times
+    give the span's duration: the non-overlapping account of it."""
+    spans = [r for r in records if r.get("kind") != "event"]
+    children: dict[str, list[tuple[float, float]]] = {}
+    for rec in spans:
+        start = float(rec.get("ts", 0.0))
+        children.setdefault(rec.get("parent", ""), []).append(
+            (start, start + float(rec.get("dur", 0.0)))
+        )
+    out = {}
+    for rec in spans:
+        start = float(rec.get("ts", 0.0))
+        end = start + float(rec.get("dur", 0.0))
+        covered, reach = 0.0, start
+        for c_start, c_end in sorted(children.get(rec["span"], ())):
+            c_start, c_end = max(c_start, reach), min(c_end, end)
+            if c_end > c_start:
+                covered += c_end - c_start
+                reach = c_end
+        out[rec["span"]] = max(end - start - covered, 0.0)
+    return out
+
+
 def render_waterfall(records: list[dict], width: int = 32) -> str:
     """ASCII phase waterfall of one trace's spans, ordered by wall
-    start (``adaptdl-tpu trace`` prints this)."""
+    start, children indented under their parents, each with its self
+    time (``adaptdl-tpu trace`` prints this)."""
     spans = [r for r in records if r.get("kind") != "event"]
     if not spans:
         return "(no spans)"
@@ -853,9 +964,20 @@ def render_waterfall(records: list[dict], width: int = 32) -> str:
     horizon = max(
         float(r["ts"]) + float(r.get("dur", 0.0)) for r in spans
     ) - t0 or 1e-9
+    own = self_times(spans)
+    parent_of = {r["span"]: r.get("parent") for r in spans}
+
+    def depth(span_id: str) -> int:
+        n = 0  # bounded: a garbled journal's parent cycle must end
+        while (
+            span_id := parent_of.get(span_id)
+        ) in parent_of and n < len(parent_of):
+            n += 1
+        return n
+
     lines = [
         f"{'PHASE':<28} {'SIDE':<12} {'START(ms)':>10} "
-        f"{'DUR(ms)':>10}  TIMELINE"
+        f"{'DUR(ms)':>10} {'SELF(ms)':>10}  TIMELINE"
     ]
     for rec in spans:
         offset = float(rec["ts"]) - t0
@@ -863,9 +985,10 @@ def render_waterfall(records: list[dict], width: int = 32) -> str:
         lead = int(width * offset / horizon)
         bar = max(int(width * dur / horizon), 1)
         side = f"pid{rec.get('pid', '?')}/i{rec.get('inc', 0)}"
+        name = "  " * depth(rec["span"]) + rec["name"]
         lines.append(
-            f"{rec['name']:<28} {side:<12} {offset * 1e3:>10.2f} "
-            f"{dur * 1e3:>10.2f}  "
+            f"{name:<28} {side:<12} {offset * 1e3:>10.2f} "
+            f"{dur * 1e3:>10.2f} {own[rec['span']] * 1e3:>10.2f}  "
             f"{' ' * lead}{'#' * min(bar, width - lead or 1)}"
         )
     return "\n".join(lines)

@@ -40,7 +40,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from adaptdl_tpu import checkpoint, gns
+from adaptdl_tpu import checkpoint, gns, trace
 
 _LOG = logging.getLogger(__name__)
 from adaptdl_tpu.parallel.mesh import (
@@ -934,7 +934,16 @@ class ElasticTrainer:
         """Fresh TrainState on the mesh: data-parallel leaves
         replicated, tensor-parallel params laid out per
         ``param_sharding_fn``."""
+        # Host time: the placements are dispatched here and may still
+        # be in flight on the device when the span closes.
+        with trace.span("trainer.init_state") as attrs:
+            state = self._init_state()
+            leaves = jax.tree.leaves(state)
+            attrs["leaves"] = len(leaves)
+            attrs["bytes"] = sum(int(x.nbytes) for x in leaves)
+        return state
 
+    def _init_state(self) -> TrainState:
         def put(x, spec):
             return _materialize(x, NamedSharding(self.mesh, spec))
 
@@ -1923,25 +1932,33 @@ class ElasticTrainer:
 
         from adaptdl_tpu import env as env_mod
 
-        fn = self._build_compute_only(atomic_bsz)
-        # host_batch rows are process-local (the loader's multi-host
-        # contract); take this process's share of one microbatch.
-        local_rows = (
-            self.num_replicas * atomic_bsz // env_mod.num_processes()
-        )
-        micro = jax.tree.map(lambda x: x[:local_rows], host_batch)
-        micro = self.shard_batch(micro)
-        jax.block_until_ready(
-            fn(state.params, micro, state.rng, aux)
-        )  # compile
-        best = float("inf")
-        for _ in range(repeats):
+        with trace.span(
+            "step.calibrate", atomic_bsz=int(atomic_bsz)
+        ) as attrs:
+            fn = self._build_compute_only(atomic_bsz)
+            # host_batch rows are process-local (the loader's
+            # multi-host contract); take this process's share of one
+            # microbatch.
+            local_rows = (
+                self.num_replicas * atomic_bsz
+                // env_mod.num_processes()
+            )
+            micro = jax.tree.map(lambda x: x[:local_rows], host_batch)
+            micro = self.shard_batch(micro)
             start = _time.monotonic()
             jax.block_until_ready(
                 fn(state.params, micro, state.rng, aux)
-            )
-            best = min(best, _time.monotonic() - start)
-        metrics_mod.profile_accum_time(atomic_bsz, best)
+            )  # compile
+            attrs["first_call_s"] = _time.monotonic() - start
+            best = float("inf")
+            for _ in range(repeats):
+                start = _time.monotonic()
+                jax.block_until_ready(
+                    fn(state.params, micro, state.rng, aux)
+                )
+                best = min(best, _time.monotonic() - start)
+            attrs["best_s"] = best
+            metrics_mod.profile_accum_time(atomic_bsz, best)
         return best
 
     def run_step(  # graftcheck: hot-path

@@ -741,3 +741,410 @@ def test_render_waterfall_orders_and_scales():
     assert lines[2].split()[0] == "w.second"
     assert "#" in lines[1]
     assert trace.render_waterfall([]) == "(no spans)"
+
+
+def test_self_time_is_duration_less_what_children_cover():
+    """Two children that overlap each other, one sticking out past
+    the parent's end, one grandchild: every span's self time is its
+    duration less the union of its children's intervals inside it,
+    and the waterfall indents children and prints the column."""
+    tp = trace.new_traceparent()
+    trace_id, root = trace.parse_traceparent(tp)
+
+    def rec(span, parent, ts, dur):
+        return {
+            "name": f"self.{span}", "trace": trace_id, "span": span,
+            "parent": parent, "ts": ts, "dur": dur, "pid": 1, "inc": 0,
+        }
+
+    records = [
+        rec("a", root, 100.0, 10.0),
+        rec("b", "a", 101.0, 4.0),  # covers [101, 105]
+        rec("c", "a", 103.0, 4.0),  # adds (105, 107]
+        rec("d", "a", 109.0, 5.0),  # clipped to [109, 110]
+        rec("e", "b", 102.0, 1.0),
+        {**rec("x", "a", 100.5, 0.0), "kind": "event"},
+    ]
+    own = trace.self_times(records)
+    assert own == pytest.approx(
+        {"a": 3.0, "b": 3.0, "c": 4.0, "d": 5.0, "e": 1.0}
+    )
+    lines = trace.render_waterfall(records).splitlines()
+    assert "SELF(ms)" in lines[0]
+    assert lines[1].startswith("self.a ")
+    assert lines[2].startswith("  self.b ")
+    assert lines[3].startswith("    self.e ")
+    assert lines[1].split()[2:5] == ["0.00", "10000.00", "3000.00"]
+
+
+def test_cli_trace_reads_a_journal(tmp_path, monkeypatch):
+    """``adaptdl-tpu trace --journal FILE``: a worker's own journal
+    renders without a supervisor; with neither source it refuses."""
+    from adaptdl_tpu import cli
+
+    monkeypatch.setenv("ADAPTDL_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("ADAPTDL_JOB_ID", "ns/journaled")
+    trace._reset_state()
+    with trace.span("journal.outer"):
+        trace.record_span("journal.inner", 0.002)
+    path = trace.journal_path()
+    stdout = io.StringIO()
+    with redirect_stdout(stdout):
+        rc = cli.main(["trace", "ns/journaled", "--journal", path])
+    rendered = stdout.getvalue()
+    assert rc == 0
+    assert "journal.outer" in rendered and "  journal.inner" in rendered
+    assert "x1" in rendered and "total" in rendered
+    assert cli.main(["trace", "ns/journaled"]) == 2
+
+
+# ---- the restart's dark half: jit.* bridge + set-up spans ------------
+
+
+def _spans(name):
+    return [r for r in trace.snapshot_spans() if r["name"] == name]
+
+
+def test_record_span_nests_under_the_open_span():
+    """An already-measured span parents like ``span`` and ``event``:
+    under the thread's innermost open span, so that self time (a
+    span's duration less its children's) comes out right."""
+    with trace.span("nest.outer"):
+        trace.record_span("nest.measured", 0.01)
+    trace.record_span("nest.alone", 0.01)
+    (outer,) = _spans("nest.outer")
+    (measured,) = _spans("nest.measured")
+    (alone,) = _spans("nest.alone")
+    assert measured["parent"] == outer["span"]
+    assert measured["trace"] == outer["trace"]
+    assert alone["parent"] == outer["parent"]  # the process root
+
+
+def test_record_span_explicit_traceparent_wins_over_open_span():
+    header = trace.new_traceparent()
+    with trace.span("nest.outer"):
+        trace.record_span("nest.foreign", 0.01, traceparent=header)
+    (foreign,) = _spans("nest.foreign")
+    assert (foreign["trace"], foreign["parent"]) == (
+        trace.parse_traceparent(header)
+    )
+
+
+def test_pending_span_parents_to_root_not_to_the_closing_span():
+    """A pending span was opened at another callsite: the span that
+    happens to be open where it is closed (and is shorter than it) is
+    not its parent."""
+    trace.begin_pending("nest.pending")
+    with trace.span("nest.closer"):
+        assert trace.end_pending("nest.pending")
+    (pending,) = _spans("nest.pending")
+    (closer,) = _spans("nest.closer")
+    assert pending["parent"] == closer["parent"] != closer["span"]
+
+
+def test_jax_bridge_records_first_call_phases_once():
+    """A jitted function's first call leaves jit.trace, jit.lower and
+    jit.compile with ``fun`` set, nested under the open span; the
+    functions it calls while tracing (every jnp operation is a jitted
+    function) leave nothing; a second call leaves nothing at all."""
+    import jax
+    import jax.numpy as jnp
+
+    trace.install_jax_bridge()
+
+    @jax.jit
+    def bridged_fn(x):
+        return jax.nn.gelu(x) + jnp.where(x > 0, x, 0).sum()
+
+    x = jnp.ones((3, 5))  # its own programs: before the bracket
+    before = trace.buffer_seq()
+    with trace.span("bridge.outer"):
+        jax.block_until_ready(bridged_fn(x))
+    new = [r for r in trace.snapshot_spans() if r["seq"] > before]
+    (outer,) = [r for r in new if r["name"] == "bridge.outer"]
+    # (jit.cache_hit / jit.cache_miss events also appear when an
+    # earlier test left the persistent compile cache on.)
+    phases = [
+        r
+        for r in new
+        if r["name"].startswith("jit.") and r.get("kind") != "event"
+    ]
+    assert [r["name"] for r in phases] == [
+        "jit.trace", "jit.lower", "jit.compile"
+    ]
+    for rec in phases:
+        assert "bridged_fn" in rec["attrs"]["fun"]
+        assert rec["parent"] == outer["span"]
+        assert rec["dur"] > 0
+        assert outer["ts"] <= rec["ts"]
+        assert rec["ts"] + rec["dur"] <= outer["ts"] + outer["dur"] + 0.05
+    before = trace.buffer_seq()
+    jax.block_until_ready(bridged_fn(x))
+    assert trace.buffer_seq() == before
+
+
+def test_jax_bridge_is_idempotent():
+    """Installed twice, each phase is still recorded once."""
+    import jax
+    import jax.numpy as jnp
+
+    trace.install_jax_bridge()
+    trace.install_jax_bridge()
+    x = jnp.ones(7)  # its own programs: before the bracket
+    before = trace.buffer_seq()
+    jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)(x))
+    names = [
+        r["name"]
+        for r in trace.snapshot_spans()
+        if r["seq"] > before and r.get("kind") != "event"
+    ]
+    assert names == ["jit.trace", "jit.lower", "jit.compile"]
+
+
+def test_jax_bridge_off_records_nothing_but_still_counts_cache_hits(
+    monkeypatch,
+):
+    """ADAPTDL_TRACE=off: no span, no event — but the persistent-cache
+    hit count is a plain integer kept either way, because
+    aot_cache.load_or_compile decides from it whether an executable
+    may be serialized."""
+    import jax
+    import jax.monitoring
+    import jax.numpy as jnp
+
+    trace.install_jax_bridge()
+    monkeypatch.setenv("ADAPTDL_TRACE", "off")
+    trace._reset_state()
+    jax.block_until_ready(jax.jit(lambda x: x * 5 - 2)(jnp.ones(9)))
+    hits = trace.jax_cache_hits()
+    jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    assert trace.snapshot_spans() == []
+    assert trace.jax_cache_hits() == hits + 1
+
+
+def test_jax_bridge_cache_events_become_counters():
+    import jax.monitoring
+
+    trace.install_jax_bridge()
+    hits = trace.jax_cache_hits()
+    with trace.span("bridge.outer"):
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+    jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+    jax.monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    assert trace.jax_cache_hits() == hits + 1
+    events = [
+        r for r in trace.snapshot_spans() if r.get("kind") == "event"
+    ]
+    assert [r["name"] for r in events] == [
+        "jit.cache_hit", "jit.cache_miss"
+    ]
+    (outer,) = _spans("bridge.outer")
+    assert events[0]["parent"] == outer["span"]
+    text = trace.prometheus_lines()
+    assert 'adaptdl_trace_events_total{event="jit.cache_hit"} 1' in text
+
+
+def test_trace_module_imports_without_jax():
+    """The control plane imports adaptdl_tpu.trace; the bridge's jax
+    import lives inside install_jax_bridge."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; import adaptdl_tpu.trace; "
+        "assert 'jax' not in sys.modules"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def _tiny_trainer():
+    from tests.test_compile_cache import _linear_trainer
+
+    return _linear_trainer()[0]
+
+
+def _tiny_dataset(n=256):
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(n, 4)).astype(np.float32)
+    return {"x": x, "y": (x @ np.arange(4.0)).astype(np.float32)}
+
+
+def test_init_state_span_counts_what_it_placed(monkeypatch):
+    import jax
+
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    state = _tiny_trainer().init_state()
+    (rec,) = _spans("trainer.init_state")
+    leaves = jax.tree.leaves(state)
+    assert rec["attrs"]["leaves"] == len(leaves)
+    assert rec["attrs"]["bytes"] == sum(x.nbytes for x in leaves) > 0
+
+
+def test_calibrate_span_times_its_first_call_and_its_best(monkeypatch):
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    trace.install_jax_bridge()
+    trainer = _tiny_trainer()
+    state = trainer.init_state()
+    batch = {k: v[:8] for k, v in _tiny_dataset().items()}
+    best = trainer.calibrate_accum_time(state, batch, 8)
+    (rec,) = _spans("step.calibrate")
+    attrs = rec["attrs"]
+    assert attrs["atomic_bsz"] == 8
+    assert attrs["best_s"] == best
+    assert 0 < attrs["best_s"] <= attrs["first_call_s"] <= rec["dur"]
+    # Its program's trace / lower / compile are its children.
+    children = [
+        r
+        for r in trace.snapshot_spans()
+        if r["parent"] == rec["span"] and r["name"].startswith("jit.")
+    ]
+    assert {"jit.trace", "jit.lower", "jit.compile"} <= {
+        r["name"] for r in children
+    }
+
+
+def test_goodput_fit_span_counts_its_points(monkeypatch):
+    from adaptdl_tpu import metrics
+
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    metrics._reset_state()
+    assert metrics._fit() is None  # nothing profiled: no fit, no span
+    assert _spans("goodput.fit") == []
+    metrics.profile_accum_time(8, 0.01)
+    metrics.profile_step(8, 0, 0.02)
+    metrics.profile_step(16, 0, 0.03)
+    # profile_step's cadence may have started the adaptdl-fit thread,
+    # which leaves a span of its own, on its own thread.
+    if metrics._fit_thread is not None:
+        metrics._fit_thread.join(60)
+    before = trace.buffer_seq()
+    assert metrics._fit() is not None
+    (rec,) = [r for r in _spans("goodput.fit") if r["seq"] > before]
+    assert rec["attrs"]["points"] == 2
+    assert rec["tid"] == threading.current_thread().name
+    metrics._reset_state()
+
+
+def test_policy_optimize_span_carries_the_decision(monkeypatch):
+    from adaptdl_tpu.data import AdaptiveDataLoader
+
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    loader = AdaptiveDataLoader(
+        _tiny_dataset(), batch_size=8, name="trace-policy"
+    )
+    loader._optimize_batch_size()
+    (rec,) = _spans("policy.optimize")
+    assert rec["attrs"] == {
+        "atomic_bsz": loader.current_atomic_bsz,
+        "accum_steps": loader.current_accum_steps,
+    }
+    assert rec["attrs"]["atomic_bsz"] == 8
+
+
+def test_restore_is_preceded_by_its_verify_span(tmp_path, monkeypatch):
+    """load_state hashes the payload against the manifest before it
+    deserializes it: that time has its own span, ahead of
+    ckpt.restore, with the verdict."""
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    state = _BlobState("verified", b"v" * 8192)
+    checkpoint.save_all_states()
+    state.payload = b""
+    assert checkpoint.load_state(state)
+    assert state.payload == b"v" * 8192
+    (verify,) = _spans("ckpt.verify")
+    (restore,) = _spans("ckpt.restore")
+    assert verify["attrs"] == {"state": "verified", "verdict": "ok"}
+    assert verify["seq"] < restore["seq"]
+    assert verify["parent"] == restore["parent"]
+
+
+def test_aot_compile_reads_cache_hits_from_the_bridge(
+    tmp_path, monkeypatch
+):
+    """load_or_compile installs the bridge itself when nobody has, its
+    aot.compile span says whether jax's persistent cache served the
+    compile, and the program's jit.* phases are that span's children."""
+    import jax
+    import numpy as np
+
+    from adaptdl_tpu import aot_cache
+
+    monkeypatch.setenv("ADAPTDL_AOT_CACHE", str(tmp_path))
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    trainer = _tiny_trainer()
+    batch = trainer.shard_batch(
+        {k: v[:8] for k, v in _tiny_dataset().items()}
+    )
+    _, m = trainer.train_step(8, 0)(trainer.init_state(), batch)
+    jax.block_until_ready(m["loss"])
+    aot_cache.wait_for_writes()
+    (rec,) = _spans("aot.compile")
+    children = {
+        r["name"]
+        for r in trace.snapshot_spans()
+        if r["parent"] == rec["span"]
+    }
+    assert {"jit.trace", "jit.lower", "jit.compile"} <= children
+    # True only if the cache served it (an earlier test may have left
+    # the persistent cache on): the bridge's own event says which.
+    assert rec["attrs"]["persistent_cache_hit"] is (
+        "jit.cache_hit" in children
+    )
+    assert np.isfinite(float(m["loss"]))
+
+
+def test_worker_prologue_lies_inside_restart_first_step(
+    tmp_path, monkeypatch, compile_cache_config_restored
+):
+    """The worker's path on CPU, in process: initialize_job, a tiny
+    trainer, init_state, a loader, steps until restart.first_step
+    closes. The set-up spans start and end inside its interval and
+    carry its trace id: containment is by time, since a pending span
+    is nobody's parent."""
+    import jax
+
+    from adaptdl_tpu import bootstrap, epoch, metrics
+    from adaptdl_tpu.data import AdaptiveDataLoader
+
+    header = trace.new_traceparent()
+    monkeypatch.setenv("ADAPTDL_TRACEPARENT", header)
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    monkeypatch.setattr(bootstrap, "_restart_span_armed", False)
+    trace._reset_state()
+    metrics._reset_state()
+    bootstrap.initialize_job()
+    try:
+        trainer = _tiny_trainer()
+        state = trainer.init_state()
+        loader = AdaptiveDataLoader(
+            _tiny_dataset(), batch_size=8, name="trace-prologue"
+        )
+        for _ in epoch.remaining_epochs_until(1):
+            for batch in loader:
+                state, m = trainer.run_step(state, batch, loader)
+                if _spans("restart.first_step"):
+                    break
+        jax.block_until_ready(m["loss"])
+    finally:
+        bootstrap.stop_heartbeat()
+        metrics._reset_state()
+    (umbrella,) = _spans("restart.first_step")
+    start, end = umbrella["ts"], umbrella["ts"] + umbrella["dur"]
+    inside = {
+        name: _spans(name)
+        for name in (
+            "bootstrap.init", "trainer.init_state", "step.calibrate",
+            "policy.optimize", "jit.lower",
+        )
+    }
+    for name, recs in inside.items():
+        assert recs, name
+        for rec in recs:
+            assert rec["trace"] == umbrella["trace"], name
+            assert start - 0.05 <= rec["ts"], name
+            assert rec["ts"] + rec["dur"] <= end + 0.05, name
+    assert umbrella["trace"] == trace.parse_traceparent(header)[0]
