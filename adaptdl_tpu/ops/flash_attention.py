@@ -2,27 +2,66 @@
 
 ``adaptdl_tpu.models.transformer.causal_attention`` materializes the
 full [seq, seq] logits matrix — fine at tutorial sizes, HBM-bound at
-real sequence lengths. This kernel is the classic blockwise
-online-softmax formulation: Q blocks stream through VMEM, K/V blocks
-stream past them, and the running (max, sum, accumulator) triple is
-kept in VMEM scratch — O(block²) memory instead of O(seq²), with both
-matmuls per block landing on the MXU. (The reference framework has no
-kernel layer to compare against — it rides torch's prebuilt CUDA
-attention; this is the TPU-native equivalent of that native layer.)
+real sequence lengths. This kernel is the blockwise online-softmax
+formulation: one tile of logits at a time, the running (max, sum,
+accumulator) triple in float32, both matmuls of a tile on the MXU and
+no [seq, seq] intermediate. (The reference framework has no kernel
+layer to compare against — it rides torch's prebuilt CUDA attention;
+this is the TPU-native equivalent of that native layer.)
+
+The forward schedule, chosen per call by :func:`_schedule` from
+``seq_len``, ``head_dim``, the dtype's itemsize and ``causal`` (no
+flag, no environment variable; ``flash.schedule`` in the trace journal
+says what was chosen):
+
+- **One grid step per (batch, head) and query tile, the k-loop inside
+  the kernel.** K and V of the head are one VMEM block whose index
+  does not depend on the query tile, so they are fetched once per
+  head, not once per query tile. A traced ``lax.fori_loop`` walks the
+  key tiles below the causal diagonal unmasked and stops there: a
+  masked tile costs neither a grid step nor a DMA nor a trace.
+- **Tiles are runs of the caller's tiles.** ``block_q`` / ``block_k``
+  are the caller's granularity and the divisibility contract; the
+  kernel fuses adjacent ones (runs of what divides both, where they
+  differ) into one square tile of up to ``_TILE_ROWS`` (1024) rows,
+  because a 128 x 128 update is too small a loop body to hide the
+  MXU's latency (measured, PERF.md PR 25: 2.7 ms a call at
+  128 x 128, 0.55 ms as shipped). What the larger
+  tile would waste above the diagonal is won back inside it: the tile
+  the diagonal crosses is done in pieces of ``_DIAG_ROWS`` (512) keys,
+  each multiplied only with the queries at or after it, and only the
+  corner block of a piece is masked.
+- **Logits are held transposed**, ``[keys, queries]``: a query's
+  statistics run along lanes, so max and sum reduce across sublanes
+  (plain VPU work, not lane rotations), the rescale of the
+  accumulator ``[head_dim, queries]`` is a sublane broadcast, and the
+  log-sum-exp leaves as one float32 per row, ``[bh, 1, seq]``, with
+  no lane replication. The primal call has no such output at all.
+- **MXU operands in the input dtype, float32 accumulation** — the
+  plain path's arithmetic: bf16 in means QK^T on bf16 operands with
+  ``preferred_element_type=float32``, max / exp / sum / rescale and
+  the accumulator in float32, probabilities rounded to ``v.dtype`` for
+  PV. float32 in means float32 operands. Read from ``q.dtype``.
+- **K/V resident while they fit.** K and V of a head, double-buffered,
+  stay in VMEM whole while they fit ``_KV_VMEM_BUDGET`` (8 MiB: up to
+  16k keys at head 64 in bf16). Beyond it the key axis is blocked
+  into the largest chunks of whole tiles that fit: a third,
+  ``arbitrary`` grid axis, the triple carried in VMEM scratch, and
+  index maps clamped to the diagonal so that chunks above it are
+  neither fetched nor computed. One algorithm; the shape decides how
+  many chunks there are.
 
 Differentiation: ``pallas_call`` is not autodiff-transparent, so
 :func:`flash_attention` is a ``jax.custom_vjp``. The backward pass
 recomputes attention blockwise in plain JAX (a ``lax.scan`` over K
-blocks using the saved per-row log-sum-exp) — the standard
-recompute-instead-of-store trade, keeping backward memory O(seq·block)
-too. XLA fuses the backward scan well; the forward is where a custom
-kernel beats the default lowering (no [seq, seq] intermediate).
+blocks of ``block_k`` keys using the saved per-row log-sum-exp) — the
+standard recompute-instead-of-store trade, keeping backward memory
+O(seq·block) too. It is the next kernel to write (PERF.md §5).
 
 On CPU the kernel runs in interpret mode (bit-accurate semantics,
-Python speed) so the whole path is testable without hardware — and a
-program lowered that way carries no ``MOSAIC_CALL``, which is what the
-chip-path checks look for. The mesh-sharded long-context path still
-uses
+Python speed) so the whole path is testable without hardware — and a program lowered that way carries no ``MOSAIC_CALL``,
+which is what the chip-path checks look for. The mesh-sharded
+long-context path still uses
 ``adaptdl_tpu.parallel.ring_attention`` — this kernel is the
 *within-chip* block engine.
 """
@@ -31,6 +70,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +78,28 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from adaptdl_tpu import trace
+
 NEG_INF = -1e30
+
+# VMEM the K and V blocks of one grid step may take, double-buffered
+# as the pipeline holds them. While a head's whole K and V fit, they
+# stay resident across its query tiles; beyond it (bf16, head 64: past
+# 16k keys) the key axis is blocked into chunks that do.
+_KV_VMEM_BUDGET = 8 * 2**20
+# What the kernel asks of Mosaic for one call: the budget above, the
+# query / output blocks and the float32 logits of one tile (the chip's
+# default, 16 MiB of its 128, leaves those too little at the budget).
+_VMEM_LIMIT = 32 * 2**20
+# Adjacent caller tiles are fused into one tile of this many rows at
+# most, and the tile the causal diagonal crosses is done in pieces of
+# this many keys at most (whole vregs of lanes). Measured on a v5e at
+# (16, 12, 1024, 64) bf16, forward ms a call (PERF.md, PR 25): tile
+# 1024 in pieces of 512 0.55, of 256 0.57 (and a quarter more to trace
+# and lower), of 128 0.60, unsplit 0.85; tile 512 0.67; 128 x 128 2.7.
+_TILE_ROWS = 1024
+_DIAG_ROWS = 512
+_LANES = 128
 
 # How the Mosaic-compiled kernel appears in a lowered or compiled
 # program's text. Interpret mode leaves no such call, so code that
@@ -53,136 +114,268 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fwd_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    lse_ref,
-    m_scratch,
-    l_scratch,
-    acc_scratch,
-    *,
-    causal: bool,
-    scale: float,
+class _Schedule(NamedTuple):
+    """How one forward call is laid on the grid: chosen by
+    :func:`_schedule` from what the call can see, never by the
+    caller."""
+
+    tile: int  # query rows of a grid step = keys of one softmax update
+    diag: int  # keys of one update inside the tile the diagonal crosses
+    chunk_k: int  # keys held in VMEM at once; seq_len = K/V resident
+
+
+def _fuse(block: int, size: int, target: int) -> int:
+    """The longest run of ``block`` rows that divides ``size`` and
+    stays within ``target`` rows (``block`` itself where that is
+    larger already)."""
+    return max(
+        rows
+        for rows in range(block, max(target, block) + 1, block)
+        if size % rows == 0
+    )
+
+
+def _schedule(
+    seq_len: int,
+    head_dim: int,
+    itemsize: int,
     block_q: int,
     block_k: int,
-):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    # A fully-masked block (whole K block strictly above the causal
-    # diagonal) contributes nothing: skip its matmuls.
-    if causal:
-        diag_visible = ki * block_k <= qi * block_q + block_q - 1
-    else:
-        diag_visible = ki >= 0  # always, as a traced predicate
-
-    @pl.when(diag_visible)
-    def _block():
-        q = q_ref[0].astype(jnp.float32) * scale  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0].astype(jnp.float32)  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_scratch[:, 0:1]  # [bq, 1] (lanes replicated)
-        l_prev = l_scratch[:, 0:1]
-        m_curr = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_curr)
-        p = jnp.exp(s - m_next)
-        rescale = jnp.exp(m_prev - m_next)
-        l_next = l_prev * rescale + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scratch[...] = acc_scratch[...] * rescale + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scratch[...] = jnp.broadcast_to(m_next, m_scratch.shape)
-        l_scratch[...] = jnp.broadcast_to(l_next, l_scratch.shape)
-
-    @pl.when(ki == num_k - 1)
-    def _finalize():
-        l_final = l_scratch[:, 0:1]
-        safe_l = jnp.maximum(l_final, 1e-30)
-        o_ref[0] = (acc_scratch[...] / safe_l).astype(o_ref.dtype)
-        lse = m_scratch[:, 0:1] + jnp.log(safe_l)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:]).astype(
-            jnp.float32
-        )
-
-
-def _fwd_pallas(q, k, v, causal, scale, block_q, block_k):
-    """q/k/v: [bh, seq, d] -> (out [bh, seq, d], lse [bh, seq, 128])."""
-    bh, seq_len, head_dim = q.shape
+) -> _Schedule:
     block_q = min(block_q, seq_len)
     block_k = min(block_k, seq_len)
     assert seq_len % block_q == 0 and seq_len % block_k == 0, (
         f"seq_len {seq_len} must divide into blocks "
         f"({block_q}, {block_k})"
     )
-    grid = (bh, seq_len // block_q, seq_len // block_k)
+    # One tile for queries and keys, a run of what both caller tiles
+    # are made of, so the causal diagonal crosses exactly the tiles
+    # (i, i), corner to corner.
+    tile = _fuse(math.gcd(block_q, block_k), seq_len, _TILE_ROWS)
+    # Lane-aligned pieces of that tile, or the tile whole.
+    diag = tile
+    if tile % _LANES == 0:
+        diag = _fuse(_LANES, tile, _DIAG_ROWS)
+    # K and V of one head, each double-buffered by the pipeline.
+    bytes_per_key = 2 * 2 * head_dim * itemsize
+    chunk_k = _fuse(
+        tile, seq_len, min(seq_len, _KV_VMEM_BUDGET // bytes_per_key)
+    )
+    return _Schedule(tile, diag, chunk_k)
+
+
+def _tiles_visited(sched: _Schedule, seq_len: int, causal: bool) -> int:
+    """(``diag`` x ``diag``) blocks of logits one (batch, head)
+    computes: all ``(seq_len / diag) ** 2``, or those the causal loop
+    bounds leave."""
+    num_q, per_tile = seq_len // sched.tile, sched.tile // sched.diag
+    if not causal:
+        return (num_q * per_tile) ** 2
+    below = per_tile**2 * num_q * (num_q - 1) // 2
+    return below + num_q * per_tile * (per_tile + 1) // 2
+
+
+def _fwd_kernel(
+    q_ref,
+    k_ref,
+    v_ref,
+    o_ref,
+    *rest,
+    causal: bool,
+    scale: float,
+    diag: int,
+    num_chunks: int,
+    with_lse: bool,
+):
+    """One grid step: one (batch, head), one query tile, one chunk of
+    keys (all of them when K/V are resident). Logits are held
+    transposed, ``[keys, queries]``, so the softmax statistics of a
+    query run along lanes. A traced loop walks the chunk's key tiles
+    below the causal diagonal unmasked; the tile the diagonal crosses
+    comes last, in ``diag``-key pieces, each only for the queries at
+    or after it, and only its corner block is masked."""
+    _, tile, head_dim = q_ref.shape
+    chunk_tiles = k_ref.shape[1] // tile
+    rest = list(rest)
+    lse_ref = rest.pop(0) if with_lse else None
+    state = rest  # VMEM scratch (m, l, acc) across chunks, if chunked
+    qi, ci = pl.program_id(1), pl.program_id(2)
+    first_tile = ci * chunk_tiles  # of this chunk, among all key tiles
+    # The last chunk with keys this query tile sees: the diagonal's.
+    last = qi // chunk_tiles if causal else num_chunks - 1
+
+    def update(keys, queries, carry, mask=None):
+        """One online-softmax update of the statistics and accumulator
+        of ``queries`` (a static range of the tile's rows) with
+        ``keys`` (rows of the chunk). Operands in the input dtype."""
+        m_prev, l_prev, acc = carry  # [1, n], [1, n], [d, n]
+        q, k, v = q_ref[0, queries, :], k_ref[0, keys, :], v_ref[0, keys, :]
+        s = scale * lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [keys, queries]
+        if mask is not None:
+            s = mask(s)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_next)
+        rescale = jnp.exp(m_prev - m_next)
+        l_next = l_prev * rescale + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * rescale + lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return m_next, l_next, acc
+
+    def whole_tile(t, carry):
+        keys = pl.ds(pl.multiple_of(t * tile, tile), tile)
+        return update(keys, slice(None), carry)
+
+    def corner_mask(s):
+        # Query lane >= key row, within the corner block: both start
+        # at the same position.
+        corner = s[:, :diag]
+        visible = lax.broadcasted_iota(
+            jnp.int32, corner.shape, 1
+        ) >= lax.broadcasted_iota(jnp.int32, corner.shape, 0)
+        corner = jnp.where(visible, corner, NEG_INF)
+        if s.shape[1] == diag:
+            return corner
+        return jnp.concatenate([corner, s[:, diag:]], axis=1)
+
+    def diagonal_tile(carry):
+        start = pl.multiple_of((qi - first_tile) * tile, tile)
+        for lo in range(0, tile, diag):
+            done = tuple(x[:, :lo] for x in carry)
+            live = update(
+                pl.ds(start + lo, diag),
+                slice(lo, None),
+                tuple(x[:, lo:] for x in carry),
+                corner_mask,
+            )
+            carry = tuple(
+                jnp.concatenate(pair, axis=1) if lo else pair[1]
+                for pair in zip(done, live)
+            )
+        return carry
+
+    def finish(carry):
+        m, l, acc = diagonal_tile(carry) if causal else carry
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc / l).T.astype(o_ref.dtype)
+        if with_lse:
+            lse_ref[0] = m + jnp.log(l)
+
+    # (A ``when`` around the whole body even where it always holds,
+    # K/V resident: interpret mode under a shard_map cannot run block
+    # accesses in a kernel's own straight line, only inside regions.)
+    @pl.when(ci <= last)
+    def _chunk():
+        if num_chunks == 1:
+            carry = (
+                jnp.full((1, tile), NEG_INF, jnp.float32),
+                jnp.zeros((1, tile), jnp.float32),
+                jnp.zeros((head_dim, tile), jnp.float32),
+            )
+        else:
+
+            @pl.when(ci == 0)
+            def _init():
+                for ref, value in zip(state, (NEG_INF, 0.0, 0.0)):
+                    ref[...] = jnp.full_like(ref, value)
+
+            carry = tuple(ref[...] for ref in state)
+        # Key tiles of the chunk that every query of the tile sees
+        # whole: those before the diagonal's, or all.
+        whole = chunk_tiles
+        if causal:
+            whole = jnp.minimum(qi - first_tile, chunk_tiles)
+        carry = lax.fori_loop(0, whole, whole_tile, carry)
+        if num_chunks == 1:
+            finish(carry)
+        else:
+            for ref, value in zip(state, carry):
+                ref[...] = value
+            pl.when(ci == last)(functools.partial(finish, carry))
+
+
+def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
+    """q/k/v: [bh, seq, d] -> (out [bh, seq, d], lse [bh, seq] or
+    None)."""
+    bh, seq_len, head_dim = q.shape
+    sched = _schedule(
+        seq_len, head_dim, q.dtype.itemsize, block_q, block_k
+    )
+    tile, diag, chunk_k = sched
+    num_chunks = seq_len // chunk_k
+    grid = (bh, seq_len // tile, num_chunks)
+    trace.event(
+        "flash.schedule",
+        seq_len=seq_len,
+        head_dim=head_dim,
+        dtype=q.dtype.name,
+        causal=causal,
+        kv_resident=num_chunks == 1,
+        tile=tile,
+        diag_tile=diag,
+        grid_steps=math.prod(grid),
+        k_tiles_visited=_tiles_visited(sched, seq_len, causal),
+        k_tiles_total=(seq_len // diag) ** 2,
+    )
     kernel = functools.partial(
         _fwd_kernel,
         causal=causal,
         scale=scale,
-        block_q=block_q,
-        block_k=block_k,
+        diag=diag,
+        num_chunks=num_chunks,
+        with_lse=with_lse,
     )
+
+    def kv_index(b, qi, ci):
+        if causal:
+            # A chunk above the diagonal repeats the index of the one
+            # the diagonal is in: nothing is fetched for it, and the
+            # kernel does nothing in it.
+            ci = jnp.minimum(ci, qi * tile // chunk_k)
+        return (b, ci, 0)
+
+    q_spec = pl.BlockSpec(
+        (1, tile, head_dim), lambda b, qi, ci: (b, qi, 0)
+    )
+    kv_spec = pl.BlockSpec((1, chunk_k, head_dim), kv_index)
     # Inside a shard_map (the trainer's data/seq axes) pallas outputs
     # must declare how they vary: the same way q does.
     vma = jax.typeof(q).vma
-    out, lse = pl.pallas_call(
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)]
+    if with_lse:
+        # One float32 per query row, rows along lanes.
+        out_specs.append(
+            pl.BlockSpec((1, 1, tile), lambda b, qi, ci: (b, 0, qi))
+        )
+        out_shape.append(
+            jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32, vma=vma)
+        )
+    scratch_shapes = []
+    if num_chunks > 1:
+        scratch_shapes = [
+            pltpu.VMEM((1, tile), jnp.float32),  # running max
+            pltpu.VMEM((1, tile), jnp.float32),  # running sum
+            pltpu.VMEM((head_dim, tile), jnp.float32),  # accumulator
+        ]
+    out, *lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, block_q, head_dim), lambda b, qi, ki: (b, qi, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, head_dim), lambda b, qi, ki: (b, ki, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, head_dim), lambda b, qi, ki: (b, ki, 0)
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, block_q, head_dim), lambda b, qi, ki: (b, qi, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_q, 128), lambda b, qi, ki: (b, qi, 0)
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
-            jax.ShapeDtypeStruct(
-                (bh, seq_len, 128), jnp.float32, vma=vma
-            ),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, head_dim), jnp.float32),  # accumulator
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=_use_interpret(),
     )(q, k, v)
-    return out, lse[..., 0]
+    return out, (lse[0][:, 0] if with_lse else None)
 
 
 @functools.partial(
@@ -203,16 +396,22 @@ def flash_attention(
       q, k, v: ``[batch, heads, seq, head_dim]``.
       causal: apply the causal mask.
       scale: logit scale; default ``head_dim ** -0.5``.
-      block_q / block_k: VMEM tile sizes (must divide seq).
+      block_q / block_k: the caller's tile granularity (must divide
+        seq; ``min(block, seq)`` is used). The forward kernel fuses
+        adjacent tiles into one online-softmax update and decides
+        how much a grid step covers (module docstring); the backward
+        scans blocks of ``block_k`` keys.
 
     Returns:
       ``[batch, heads, seq, head_dim]``, dtype of ``q``.
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    out, _ = _flash_fwd(
+        q, k, v, causal, scale, block_q, block_k, with_lse=False
+    )
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
     batch, heads, seq_len, head_dim = q.shape
     resolved_scale = (
         head_dim**-0.5 if scale is None else float(scale)
@@ -220,15 +419,18 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
     flat = lambda x: x.reshape(batch * heads, seq_len, head_dim)  # noqa: E731
     out, lse = _fwd_pallas(
         flat(q), flat(k), flat(v), causal, resolved_scale,
-        block_q, block_k,
+        block_q, block_k, with_lse,
     )
     out = out.reshape(q.shape)
-    lse = lse.reshape(batch, heads, seq_len)
+    if with_lse:
+        lse = lse.reshape(batch, heads, seq_len)
     return out, lse
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    out, lse = _flash_fwd(
+        q, k, v, causal, scale, block_q, block_k, with_lse=True
+    )
     return out, (q, k, v, out, lse)
 
 

@@ -66,6 +66,9 @@ def chip_compile(monkeypatch):
 
 FLAGSHIP = (8, 12, 512, 64)  # examples/transformer_lm.py at batch 8
 LONG = (4, 8, 2048, 64)
+CELL = (16, 12, 1024, 64)  # the benchmark's gpt2-124m micro-batch
+HEAD_128 = (2, 8, 4096, 128)
+K_BLOCKED = (1, 2, 32768, 128)  # K and V of a head past the VMEM budget
 
 
 def _attend(q, k, v):
@@ -86,6 +89,14 @@ def _attend_loss(q, k, v):
         ("shard_map", FLAGSHIP, 1),
         ("shard_map", FLAGSHIP, 4),
         ("shard_map_grad", FLAGSHIP, 4),
+        ("fwd", CELL, 0),
+        ("grad", CELL, 0),
+        ("shard_map", CELL, 4),
+        ("shard_map_grad", CELL, 4),
+        ("fwd", HEAD_128, 0),
+        ("grad", HEAD_128, 0),
+        ("fwd", K_BLOCKED, 0),
+        ("grad", K_BLOCKED, 0),
     ],
 )
 def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
@@ -109,6 +120,8 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
     compiled = jax.jit(fn).lower(arg, arg, arg).compile()
     assert flash_mod.MOSAIC_CALL in compiled.as_text()
+    resident = flash_mod._schedule(*shape[2:], 2, 128, 128).chunk_k == shape[2]
+    assert resident == (shape != K_BLOCKED)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 

@@ -4,13 +4,21 @@ bidirectional, and use as the transformer's attention_fn. Runs in
 interpret mode on CPU — same semantics the compiled kernel executes
 on TPU."""
 
+import importlib
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from adaptdl_tpu import trace
+from adaptdl_tpu.models.transformer import causal_attention
 from adaptdl_tpu.ops import flash_attention, make_flash_attention
+
+# ``import adaptdl_tpu.ops.flash_attention as m`` yields the FUNCTION
+# (the package re-exports it under the module's name).
+flash_mod = importlib.import_module("adaptdl_tpu.ops.flash_attention")
 
 
 def _dense(q, k, v, causal):
@@ -28,13 +36,23 @@ def _dense(q, k, v, causal):
     ).astype(q.dtype)
 
 
-def _qkv(batch=2, heads=2, seq=64, d=16, seed=0):
+def _qkv(batch=2, heads=2, seq=64, d=16, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     shape = (batch, heads, seq, d)
     return tuple(
-        jnp.asarray(rng.normal(size=shape).astype(np.float32))
+        jnp.asarray(rng.normal(size=shape).astype(np.float32)).astype(
+            dtype
+        )
         for _ in range(3)
     )
+
+
+def _schedule_events():
+    return [
+        rec["attrs"]
+        for rec in trace.snapshot_spans()
+        if rec["name"] == "flash.schedule"
+    ]
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -80,10 +98,24 @@ def test_gradients_match_dense(causal):
         )
 
 
-def test_transformer_attention_fn_hook():
+@pytest.mark.parametrize(
+    "causal, remat, kv_budget",
+    [
+        (True, False, None),
+        (True, True, None),
+        (False, True, None),
+        (True, True, 2 * 2 * 16 * 16 * 4),  # K-blocked: 16 keys at once
+    ],
+)
+def test_transformer_attention_fn_hook(monkeypatch, causal, remat, kv_budget):
     """The kernel drops into TransformerConfig.attention_fn and the
-    model still trains (end-to-end through the elastic trainer)."""
+    model still trains (end-to-end through the elastic trainer: under
+    its shard_map, with and without remat, either schedule)."""
     import optax
+
+    if kv_budget is not None:
+        monkeypatch.setattr(flash_mod, "_KV_VMEM_BUDGET", kv_budget)
+        monkeypatch.setattr(flash_mod, "_TILE_ROWS", 16)
 
     from adaptdl_tpu.models import TransformerConfig, init_transformer
     from adaptdl_tpu.parallel import create_mesh
@@ -91,8 +123,10 @@ def test_transformer_attention_fn_hook():
 
     cfg = TransformerConfig(
         vocab_size=64, num_layers=1, num_heads=2, d_model=32, d_ff=64,
-        max_seq_len=32, dtype=jnp.float32, remat=False,
-        attention_fn=make_flash_attention(block_q=16, block_k=16),
+        max_seq_len=32, dtype=jnp.float32, remat=remat, causal=causal,
+        attention_fn=make_flash_attention(
+            causal=causal, block_q=16, block_k=16
+        ),
     )
     model, params = init_transformer(cfg, seq_len=32)
 
@@ -119,3 +153,295 @@ def test_transformer_attention_fn_hook():
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
     assert np.isfinite(losses).all()
+    assert _schedule_events()[-1]["kv_resident"] == (kv_budget is None)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("reference", ["causal_attention", "dense_f32"])
+def test_bf16_operands_match_plain_attention(causal, reference):
+    """bf16 in: the kernel multiplies bf16 operands with float32
+    accumulation, takes the softmax in float32 and rounds the
+    probabilities to bf16 before PV — the plain path's arithmetic, so
+    it agrees with ``causal_attention`` in bf16 to an ulp of the
+    output, and with the float32 reference to bf16's precision."""
+    q, k, v = _qkv(seq=64, d=64, seed=3, dtype=jnp.bfloat16)
+    out = flash_attention(q, k, v, causal, None, 16, 32)
+    assert out.dtype == jnp.bfloat16
+    if reference == "causal_attention":
+        ref, tol = causal_attention(q, k, v, causal=causal), 8e-3
+    else:
+        ref, tol = _dense(q, k, v, causal), 2e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(ref, np.float32),
+        atol=tol,
+        rtol=tol,
+    )
+
+
+# name -> (shape kwargs, block_q, block_k, module constants, expected
+# schedule as (tile, diag_tile, kv_resident)). Fusing and the pieces of
+# the diagonal tile are pinned off unless the case sets them (None:
+# the module's own constants). 32 float32 keys at head 16, K and V,
+# double-buffered:
+KEYS_32 = 2 * 2 * 32 * 16 * 4
+SCHEDULES = {
+    "resident": (dict(seq=64), 16, 16, {}, (16, 16, True)),
+    "fused_tiles": (
+        dict(seq=64), 16, 16, {"_TILE_ROWS": 32}, (32, 32, True)
+    ),
+    "diagonal_in_pieces": (
+        dict(seq=64), 32, 32,
+        {"_LANES": 8, "_DIAG_ROWS": 8}, (32, 8, True),
+    ),
+    "fused_and_in_pieces": (
+        dict(seq=96), 16, 16,
+        {"_TILE_ROWS": 48, "_LANES": 8, "_DIAG_ROWS": 16},
+        (48, 16, True),
+    ),
+    "k_blocked": (
+        dict(seq=64), 16, 16,
+        {"_KV_VMEM_BUDGET": KEYS_32}, (16, 16, False),
+    ),
+    "k_blocked_in_pieces": (
+        dict(seq=128), 32, 32,
+        {"_KV_VMEM_BUDGET": 2 * KEYS_32, "_LANES": 8, "_DIAG_ROWS": 16},
+        (32, 16, False),
+    ),
+    "k_blocked_unequal": (
+        dict(seq=96), 48, 16,
+        {"_KV_VMEM_BUDGET": 2 * KEYS_32}, (16, 16, False),
+    ),
+    "seq_is_block": (dict(seq=32), 128, 128, {}, (32, 32, True)),
+    "unequal_q_over_k": (dict(seq=64), 32, 16, {}, (16, 16, True)),
+    "unequal_k_over_q": (dict(seq=64), 16, 32, {}, (16, 16, True)),
+    "unequal_not_nested": (dict(seq=96), 48, 32, {}, (16, 16, True)),
+    "unequal_fused": (
+        dict(seq=96), 48, 32, {"_TILE_ROWS": 64}, (48, 48, True)
+    ),
+    "head_64": (dict(seq=32, d=64), 16, 16, {}, (16, 16, True)),
+    "head_128": (dict(seq=32, d=128), 16, 16, {}, (16, 16, True)),
+    "module_constants": (
+        dict(batch=1, seq=1024, d=64), 128, 128, None, (1024, 512, True)
+    ),
+}
+
+
+@pytest.fixture
+def schedule_case(request, monkeypatch):
+    shape, block_q, block_k, constants, expected = SCHEDULES[
+        request.param
+    ]
+    if constants is not None:
+        pinned = {"_TILE_ROWS": 1, "_DIAG_ROWS": 1}
+        for name, value in {**pinned, **constants}.items():
+            monkeypatch.setattr(flash_mod, name, value)
+    return shape, block_q, block_k, expected
+
+
+def _engaged():
+    attrs = _schedule_events()[-1]
+    return attrs["tile"], attrs["diag_tile"], attrs["kv_resident"]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("schedule_case", list(SCHEDULES), indirect=True)
+def test_forward_across_schedules(schedule_case, causal):
+    shape, block_q, block_k, expected = schedule_case
+    q, k, v = _qkv(seed=5, **shape)
+    out = flash_attention(q, k, v, causal, None, block_q, block_k)
+    assert _engaged() == expected
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_dense(q, k, v, causal)),
+        atol=2e-5,
+        rtol=2e-5,
+    )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("schedule_case", list(SCHEDULES), indirect=True)
+def test_gradients_across_schedules(schedule_case, causal):
+    shape, block_q, block_k, expected = schedule_case
+    q, k, v = _qkv(seed=6, **shape)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
+
+    got = jax.grad(
+        loss(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal, None, block_q, block_k
+            )
+        ),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    assert _engaged() == expected
+    want = jax.grad(
+        loss(lambda q, k, v: _dense(q, k, v, causal)), argnums=(0, 1, 2)
+    )(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(g),
+            np.asarray(w),
+            atol=5e-5,
+            rtol=5e-4,
+            err_msg=f"d{name}",
+        )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize(
+    "schedule_case",
+    ["resident", "diagonal_in_pieces", "k_blocked", "k_blocked_in_pieces"],
+    indirect=True,
+)
+def test_vjp_forward_lse_is_logsumexp(schedule_case, causal):
+    """The residual the backward pass reads: one float32 per query
+    row, the log-sum-exp of that row's (masked) scaled logits."""
+    shape, block_q, block_k, _ = schedule_case
+    q, k, v = _qkv(seed=7, **shape)
+    out, (_, _, _, _, lse) = flash_mod._flash_vjp_fwd(
+        q, k, v, causal, None, block_q, block_k
+    )
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        seq = q.shape[2]
+        logits = jnp.where(
+            jnp.tril(jnp.ones((seq, seq), bool)), logits, -jnp.inf
+        )
+    assert lse.shape == q.shape[:3] and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse),
+        np.asarray(jax.nn.logsumexp(logits, axis=-1)),
+        atol=2e-5,
+        rtol=2e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_dense(q, k, v, causal)),
+        atol=2e-5,
+        rtol=2e-5,
+    )
+
+
+def test_primal_call_has_no_lse_output():
+    """``flash_attention`` outside differentiation drops the
+    log-sum-exp; a custom call's output cannot be removed by XLA, so
+    the primal kernel has none."""
+    q, k, v = _qkv()
+    primal = jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16)
+    )(q, k, v)
+    vjp = jax.make_jaxpr(
+        lambda q, k, v: flash_mod._flash_vjp_fwd(
+            q, k, v, True, None, 16, 16
+        )
+    )(q, k, v)
+
+    def pallas_outputs(jaxpr):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append(len(eqn.outvars))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert pallas_outputs(primal) == [1]
+    assert pallas_outputs(vjp) == [2]
+
+
+CELL = (16, 12, 1024, 64)  # the benchmark's gpt2-124m micro-batch
+TILE_128 = {"_TILE_ROWS": 128, "_DIAG_ROWS": 128}
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, causal, constants, expected",
+    [
+        (
+            # As shipped: one grid step per (batch, head), the whole
+            # sequence one tile, its diagonal in two pieces.
+            CELL, jnp.bfloat16, True, {},
+            dict(kv_resident=True, tile=1024, diag_tile=512,
+                 k_tiles_visited=3, k_tiles_total=4, grid_steps=192),
+        ),
+        (
+            # At the caller's 128 x 128: the causal skip share.
+            CELL, jnp.bfloat16, True, TILE_128,
+            dict(kv_resident=True, tile=128, diag_tile=128,
+                 k_tiles_visited=36, k_tiles_total=64,
+                 grid_steps=192 * 8),
+        ),
+        (
+            CELL, jnp.bfloat16, False, TILE_128,
+            dict(kv_resident=True, k_tiles_visited=64, k_tiles_total=64,
+                 grid_steps=192 * 8),
+        ),
+        (
+            # K and V of one head past the budget: the key axis is
+            # blocked, a quarter of the keys in VMEM at once.
+            (1, 2, 1024, 64), jnp.float32, True,
+            {**TILE_128, "_KV_VMEM_BUDGET": 2 * 2 * 256 * 64 * 4},
+            dict(kv_resident=False, tile=128, k_tiles_visited=36,
+                 k_tiles_total=64, grid_steps=2 * 8 * 4),
+        ),
+        (
+            # Long context as shipped: 8k keys of 32k resident.
+            (1, 1, 32768, 128), jnp.bfloat16, True, {},
+            dict(kv_resident=False, tile=1024, diag_tile=512,
+                 k_tiles_visited=4 * 32 * 31 // 2 + 32 * 3,
+                 k_tiles_total=64 * 64, grid_steps=32 * 4),
+        ),
+    ],
+)
+def test_schedule_event(monkeypatch, shape, dtype, causal, constants, expected):
+    """Tracing a call records one ``flash.schedule`` event that says
+    what was chosen from the shape; nothing is recorded at run time."""
+    for name, value in constants.items():
+        monkeypatch.setattr(flash_mod, name, value)
+    arg = jax.ShapeDtypeStruct(shape, dtype)
+    jax.eval_shape(
+        lambda q, k, v: flash_attention(q, k, v, causal, None, 128, 128),
+        arg, arg, arg,
+    )
+    (attrs,) = _schedule_events()
+    assert attrs["seq_len"] == shape[2] and attrs["head_dim"] == shape[3]
+    assert attrs["dtype"] == jnp.dtype(dtype).name
+    assert attrs["causal"] is causal
+    for name, value in expected.items():
+        assert attrs[name] == value, name
+
+
+def test_schedule_adapts_to_the_shape():
+    """With the module's own constants: tiles are whole caller tiles
+    that divide the sequence, K and V stay resident while they fit the
+    budget and are blocked into chunks of whole tiles beyond it, and
+    the caller's divisibility contract still raises."""
+    budget = flash_mod._KV_VMEM_BUDGET
+    for seq, head, itemsize, blocks in [
+        (1024, 64, 2, (128, 128)),
+        (512, 64, 2, (128, 128)),
+        (384, 64, 2, (128, 128)),
+        (1536, 64, 2, (512, 768)),
+        (2048, 128, 4, (256, 512)),
+        (4096, 64, 2, (512, 4096)),
+        (4096, 64, 2, (2048, 2048)),
+        (8192, 128, 2, (128, 128)),
+        (65536, 128, 2, (128, 128)),
+        (64, 16, 4, (16, 32)),
+    ]:
+        tile, diag, chunk_k = flash_mod._schedule(seq, head, itemsize, *blocks)
+        assert seq % tile == 0 and tile <= max(1024, min(blocks))
+        assert seq % chunk_k == 0 and chunk_k % tile == 0
+        assert tile % diag == 0 and (diag == tile or diag % 128 == 0)
+        resident = 2 * 2 * seq * head * itemsize <= budget
+        assert (chunk_k == seq) == resident, (seq, head, itemsize)
+        if not resident:
+            assert 2 * 2 * chunk_k * head * itemsize <= budget
+    with pytest.raises(AssertionError, match="must divide"):
+        flash_mod._schedule(100, 64, 2, 64, 64)
