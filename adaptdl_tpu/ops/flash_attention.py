@@ -52,11 +52,34 @@ says what was chosen):
   many chunks there are.
 
 Differentiation: ``pallas_call`` is not autodiff-transparent, so
-:func:`flash_attention` is a ``jax.custom_vjp``. The backward pass
-recomputes attention blockwise in plain JAX (a ``lax.scan`` over K
-blocks of ``block_k`` keys using the saved per-row log-sum-exp) — the
-standard recompute-instead-of-store trade, keeping backward memory
-O(seq·block) too. It is the next kernel to write (PERF.md §5).
+:func:`flash_attention` is a ``jax.custom_vjp`` whose residuals are
+``(q, k, v, out, lse)``. The backward is ONE Pallas kernel on the same
+schedule with the loops swapped (:func:`_bwd_kernel`; ``flash.
+schedule_bwd`` in the trace journal): P is recomputed from the saved
+log-sum-exp, never stored, so backward memory stays O(seq).
+
+- **One grid step per (batch, head), key chunk and query tile.** The
+  chunk's K and V (all of them while they fit the budget above) stay
+  in VMEM while the query tiles pass; its dK and dV accumulate in
+  float32 scratch over the query tiles at or after it and the tile's
+  dQ over the chunk's keys at or before it, so P and dS are computed
+  once for all three gradients: five matmuls a block of logits, where
+  the usual pair of kernels (dK/dV, then dQ) pays seven.
+- **Only the blocks at or below the diagonal**, in updates of
+  ``_BWD_DIAG_ROWS`` (256) keys: a traced loop over those below the
+  diagonal tile, then the diagonal tile's pieces, each with the
+  queries at or after it and only its corner block masked.
+- **Transposed logits, operands in the input dtype**, as forward:
+  ``lse`` and ``delta = rowsum(dO * O)`` (taken in the kernel, in
+  float32) broadcast along sublanes; P^T and dS^T, rounded to the
+  input dtype, are the left operands of dV and dK as they stand, and
+  dQ is accumulated transposed (``K^T dS^T``: only the small K is
+  turned). ``exp``, ``delta``, ``dS`` and the three accumulators are
+  float32; float32 in means float32 operands.
+- **Beyond the budget** the key chunks are a grid axis: a query tile
+  before a chunk fetches and computes nothing, and dQ leaves as one
+  float32 partial per chunk, summed outside. Correct and 7 x the scan
+  it replaced at 16k x 128 (PERF.md, PR 26); tuned in no cell.
 
 On CPU the kernel runs in interpret mode (bit-accurate semantics,
 Python speed) so the whole path is testable without hardware — and a program lowered that way carries no ``MOSAIC_CALL``,
@@ -91,6 +114,9 @@ _KV_VMEM_BUDGET = 8 * 2**20
 # query / output blocks and the float32 logits of one tile (the chip's
 # default, 16 MiB of its 128, leaves those too little at the budget).
 _VMEM_LIMIT = 32 * 2**20
+# The backward holds, beside K and V of the chunk, its dK and dV blocks
+# and their float32 accumulators: three times the budget at most.
+_VMEM_LIMIT_BWD = 64 * 2**20
 # Adjacent caller tiles are fused into one tile of this many rows at
 # most, and the tile the causal diagonal crosses is done in pieces of
 # this many keys at most (whole vregs of lanes). Measured on a v5e at
@@ -99,6 +125,14 @@ _VMEM_LIMIT = 32 * 2**20
 # and lower), of 128 0.60, unsplit 0.85; tile 512 0.67; 128 x 128 2.7.
 _TILE_ROWS = 1024
 _DIAG_ROWS = 512
+# The backward's updates cover this many keys at most, in the diagonal
+# tile and below it: it holds four float32 [keys, queries] values live
+# per update where the forward holds two. Measured on a v5e, backward
+# kernel ms a call from the device trace (PERF.md, PR 26), pieces of
+# 512 / 256 / 128 keys: (16, 12, 1024, 64) bf16 1.086 / 0.918 / 0.878
+# (128 costs half as much again to trace and lower as 256); seq 2048
+# 1.71 / 1.59; 4096 x 128 2.16 / 2.10; bidirectional 1.39 / 1.44.
+_BWD_DIAG_ROWS = 256
 _LANES = 128
 
 # How the Mosaic-compiled kernel appears in a lowered or compiled
@@ -106,6 +140,10 @@ _LANES = 128
 # measures or proves the chip path (chip_smoke.py, bench.py, the chip
 # compile tests) asserts this string is present.
 MOSAIC_CALL = "tpu_custom_call"
+# The backward kernel's name in a lowered program and in a device
+# trace (``%flash_bwd.<n>``); the forward is unnamed inside the model's
+# ``attention`` scope, and the benchmark finds it as ``%attention.<n>``.
+BWD_KERNEL_NAME = "flash_bwd"
 
 
 def _use_interpret() -> bool:
@@ -141,7 +179,10 @@ def _schedule(
     itemsize: int,
     block_q: int,
     block_k: int,
+    diag_rows: int | None = None,
 ) -> _Schedule:
+    """``diag_rows``: the most keys of one update inside a tile; the
+    forward's ``_DIAG_ROWS`` unless the backward passes its own."""
     block_q = min(block_q, seq_len)
     block_k = min(block_k, seq_len)
     assert seq_len % block_q == 0 and seq_len % block_k == 0, (
@@ -155,7 +196,9 @@ def _schedule(
     # Lane-aligned pieces of that tile, or the tile whole.
     diag = tile
     if tile % _LANES == 0:
-        diag = _fuse(_LANES, tile, _DIAG_ROWS)
+        diag = _fuse(
+            _LANES, tile, _DIAG_ROWS if diag_rows is None else diag_rows
+        )
     # K and V of one head, each double-buffered by the pipeline.
     bytes_per_key = 2 * 2 * head_dim * itemsize
     chunk_k = _fuse(
@@ -173,6 +216,21 @@ def _tiles_visited(sched: _Schedule, seq_len: int, causal: bool) -> int:
         return (num_q * per_tile) ** 2
     below = per_tile**2 * num_q * (num_q - 1) // 2
     return below + num_q * per_tile * (per_tile + 1) // 2
+
+
+def _corner_mask(s, diag: int):
+    """Mask the corner block of transposed logits ``[keys, queries]``
+    whose first key and first query sit at the same position: query
+    lane >= key row within the first ``diag`` queries; every later
+    query sees all of these keys."""
+    corner = s[:, :diag]
+    visible = lax.broadcasted_iota(
+        jnp.int32, corner.shape, 1
+    ) >= lax.broadcasted_iota(jnp.int32, corner.shape, 0)
+    corner = jnp.where(visible, corner, NEG_INF)
+    if s.shape[1] == diag:
+        return corner
+    return jnp.concatenate([corner, s[:, diag:]], axis=1)
 
 
 def _fwd_kernel(
@@ -230,17 +288,7 @@ def _fwd_kernel(
         keys = pl.ds(pl.multiple_of(t * tile, tile), tile)
         return update(keys, slice(None), carry)
 
-    def corner_mask(s):
-        # Query lane >= key row, within the corner block: both start
-        # at the same position.
-        corner = s[:, :diag]
-        visible = lax.broadcasted_iota(
-            jnp.int32, corner.shape, 1
-        ) >= lax.broadcasted_iota(jnp.int32, corner.shape, 0)
-        corner = jnp.where(visible, corner, NEG_INF)
-        if s.shape[1] == diag:
-            return corner
-        return jnp.concatenate([corner, s[:, diag:]], axis=1)
+    corner_mask = functools.partial(_corner_mask, diag=diag)
 
     def diagonal_tile(carry):
         start = pl.multiple_of((qi - first_tile) * tile, tile)
@@ -397,10 +445,9 @@ def flash_attention(
       causal: apply the causal mask.
       scale: logit scale; default ``head_dim ** -0.5``.
       block_q / block_k: the caller's tile granularity (must divide
-        seq; ``min(block, seq)`` is used). The forward kernel fuses
-        adjacent tiles into one online-softmax update and decides
-        how much a grid step covers (module docstring); the backward
-        scans blocks of ``block_k`` keys.
+        seq; ``min(block, seq)`` is used). Both kernels fuse adjacent
+        tiles and decide themselves how much a grid step and an
+        update cover (module docstring).
 
     Returns:
       ``[batch, heads, seq, head_dim]``, dtype of ``q``.
@@ -434,67 +481,235 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
-    """Blockwise backward: scan over K blocks recomputing P from the
-    saved log-sum-exp (the flash-attention backward identities):
+def _bwd_kernel(
+    q_ref,
+    k_ref,
+    v_ref,
+    do_ref,
+    o_ref,
+    lse_ref,
+    dq_ref,
+    dk_ref,
+    dv_ref,
+    delta_ref,
+    dq_acc,
+    dk_acc,
+    dv_acc,
+    *,
+    causal: bool,
+    scale: float,
+    diag: int,
+    num_chunks: int,
+):
+    """One grid step of the backward: one (batch, head), one chunk of
+    keys (all of them when K/V are resident), one query tile. The
+    forward's schedule with the loops swapped: the chunk's K and V stay
+    while the query tiles pass, its dK and dV accumulate in float32
+    scratch over the query tiles at or after it, and the query tile's
+    dQ over the chunk's keys at or before it. Logits are transposed,
+    ``[keys, queries]``, so ``lse`` and ``delta`` broadcast along
+    sublanes and P^T and dS^T are the left operands of dV and dK as
+    they stand. Every update covers ``diag`` keys: a traced loop walks
+    those below the diagonal tile unmasked; the diagonal tile's pieces
+    take only the queries at or after them, corner block masked."""
+    _, tile, head_dim = q_ref.shape
+    chunk_k = k_ref.shape[1]
+    chunk_tiles, per_tile = chunk_k // tile, tile // diag
+    ci, qi = pl.program_id(1), pl.program_id(2)
+    first_tile = ci * chunk_tiles  # of this chunk, among all key tiles
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+    lead = (0,) * (len(dq_ref.shape) - 2)  # [chunk,] batch-head
 
+    def update(keys, queries, mask=None):
+        q, do = q_ref[0, queries, :], do_ref[0, queries, :]
+        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+        s = scale * lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32
+        )  # [keys, queries]
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse_ref[0, :, queries])
+        dp = lax.dot_general(
+            v, do, nt, preferred_element_type=jnp.float32
+        )
+        ds = (p * (dp - delta_ref[:, queries])).astype(q.dtype)
+        dv_acc[keys, :] += jnp.dot(
+            p.astype(do.dtype), do, preferred_element_type=jnp.float32
+        )
+        dk_acc[keys, :] += jnp.dot(
+            ds, q, preferred_element_type=jnp.float32
+        )
+        dq_acc[:, queries] += lax.dot_general(
+            k, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [head_dim, queries]: only the small K is transposed
+
+    def piece_below(t, carry):
+        update(pl.ds(pl.multiple_of(t * diag, diag), diag), slice(None))
+        return carry
+
+    def diagonal_tile():
+        start = pl.multiple_of((qi - first_tile) * tile, tile)
+        mask = functools.partial(_corner_mask, diag=diag)
+        for lo in range(0, tile, diag):
+            update(pl.ds(start + lo, diag), slice(lo, None), mask)
+
+    # (Every block access sits inside a ``when``, as in the forward:
+    # interpret mode under a shard_map needs it.)
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    # Query tiles before the chunk see none of its keys.
+    sees = qi >= first_tile if causal else qi >= 0
+
+    @pl.when(sees)
+    def _tile():
+        # delta = rowsum(dO * O) in float32, rows along lanes as lse
+        # has them (the transpose is what turns them).
+        d_o = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        delta_ref[...] = jnp.sum(d_o.T, axis=0, keepdims=True)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        below = chunk_tiles
+        if causal:
+            below = jnp.minimum(qi - first_tile, chunk_tiles)
+        lax.fori_loop(0, below * per_tile, piece_below, 0)
+        if causal and num_chunks == 1:
+            diagonal_tile()
+        elif causal:
+            pl.when(qi - first_tile < chunk_tiles)(diagonal_tile)
+        dq_ref[lead] = (scale * dq_acc[...]).T.astype(dq_ref.dtype)
+
+    if causal and num_chunks > 1:
+
+        @pl.when(jnp.logical_not(sees))
+        def _no_keys():
+            dq_ref[lead] = jnp.zeros((tile, head_dim), dq_ref.dtype)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = (scale * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
+    """q/k/v/do/out: [bh, seq, d]; lse: [bh, 1, seq] float32 ->
+    (dq, dk, dv), each [bh, seq, d] in its primal's dtype."""
+    bh, seq_len, head_dim = q.shape
+    sched = _schedule(
+        seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
+        diag_rows=_BWD_DIAG_ROWS,
+    )
+    tile, diag, chunk_k = sched
+    num_chunks = seq_len // chunk_k
+    grid = (bh, num_chunks, seq_len // tile)
+    visited = _tiles_visited(sched, seq_len, causal)
+    trace.event(
+        "flash.schedule_bwd",
+        seq_len=seq_len,
+        head_dim=head_dim,
+        dtype=q.dtype.name,
+        causal=causal,
+        kv_resident=num_chunks == 1,
+        tile=tile,
+        diag_tile=diag,
+        grid_steps=math.prod(grid),
+        kernels=1,  # dK, dV and dQ from one recomputation of P, dS
+        dkv_tiles_visited=visited,
+        dq_tiles_visited=visited,
+        k_tiles_total=(seq_len // diag) ** 2,
+    )
+
+    def q_index(b, ci, qi):
+        if causal:
+            # A query tile before the chunk repeats the index of the
+            # first that sees it: nothing is fetched for it.
+            qi = jnp.maximum(qi, ci * chunk_k // tile)
+        return qi
+
+    q_spec = pl.BlockSpec(
+        (1, tile, head_dim), lambda b, ci, qi: (b, q_index(b, ci, qi), 0)
+    )
+    lse_spec = pl.BlockSpec(
+        (1, 1, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
+    )
+    kv_spec = pl.BlockSpec(
+        (1, chunk_k, head_dim), lambda b, ci, qi: (b, ci, 0)
+    )
+    vma = jax.typeof(q).vma
+    # dQ of a query tile is summed over the key chunks: the gradient
+    # itself while K/V are resident; beyond, one float32 partial per
+    # chunk, added up outside.
+    if num_chunks == 1:
+        dq_spec = pl.BlockSpec(
+            (1, tile, head_dim), lambda b, ci, qi: (b, qi, 0)
+        )
+        dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)
+    else:
+        dq_spec = pl.BlockSpec(
+            (1, 1, tile, head_dim), lambda b, ci, qi: (ci, b, qi, 0)
+        )
+        dq_shape = jax.ShapeDtypeStruct(
+            (num_chunks, *q.shape), jnp.float32, vma=vma
+        )
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel,
+            causal=causal,
+            scale=scale,
+            diag=diag,
+            num_chunks=num_chunks,
+        ),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
+        out_specs=[dq_spec, kv_spec, kv_spec],
+        out_shape=[
+            dq_shape,
+            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, tile), jnp.float32),  # delta of the tile
+            pltpu.VMEM((head_dim, tile), jnp.float32),  # dQ^T of the tile
+            pltpu.VMEM((chunk_k, head_dim), jnp.float32),  # dK of the chunk
+            pltpu.VMEM((chunk_k, head_dim), jnp.float32),  # dV of the chunk
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BWD,
+        ),
+        interpret=_use_interpret(),
+        # Not the forward's ``%attention.<n>``: the trace tells the two
+        # kernels apart by name.
+        name=BWD_KERNEL_NAME,
+    )(q, k, v, do, out, lse)
+    if num_chunks > 1:
+        dq = jnp.sum(dq, axis=0).astype(q.dtype)
+    return dq, dk, dv
+
+
+def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
+    """The flash-attention backward identities, on the forward's
+    schedule in one Pallas kernel (:func:`_bwd_kernel`): P is
+    recomputed from the saved log-sum-exp, never stored.
+
+        P = exp(S - lse)
         dV = P^T dO
         dP = dO V^T
-        dS = P * (dP - rowsum(dO * O))
+        dS = P * (dP - delta),  delta = rowsum(dO * O)
         dQ = dS K * scale ;  dK = dS^T Q * scale
     """
     q, k, v, out, lse = residuals
     batch, heads, seq_len, head_dim = q.shape
     resolved_scale = head_dim**-0.5 if scale is None else float(scale)
-    block = min(block_k, seq_len)
-    num_blocks = seq_len // block
-
-    q32 = q.astype(jnp.float32) * resolved_scale
-    k32 = k.astype(jnp.float32)
-    v32 = v.astype(jnp.float32)
-    g32 = g.astype(jnp.float32)
-    # delta_i = sum_d dO_id * O_id  (the softmax-jacobian row term)
-    delta = jnp.sum(g32 * out.astype(jnp.float32), axis=-1)
-
-    q_pos = jnp.arange(seq_len)
-
-    def kv_block(carry, block_idx):
-        dq_acc = carry
-        start = block_idx * block
-        k_blk = lax.dynamic_slice_in_dim(k32, start, block, axis=2)
-        v_blk = lax.dynamic_slice_in_dim(v32, start, block, axis=2)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q32, k_blk)
-        if causal:
-            k_pos = start + jnp.arange(block)
-            visible = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(visible[None, None], s, NEG_INF)
-        p = jnp.exp(s - lse[..., None])
-        dp = jnp.einsum("bhqd,bhkd->bhqk", g32, v_blk)
-        ds = p * (dp - delta[..., None])
-        dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, g32)
-        dk_blk = jnp.einsum(
-            "bhqk,bhqd->bhkd", ds, q32
-        )  # scale folded into q32
-        dq_acc = dq_acc + jnp.einsum(
-            "bhqk,bhkd->bhqd", ds, k_blk
-        ) * resolved_scale
-        return dq_acc, (dk_blk, dv_blk)
-
-    dq, (dk_blocks, dv_blocks) = lax.scan(
-        kv_block,
-        # Derive the accumulator init from q so it inherits q's
-        # varying-axis type under shard_map (a literal zeros array is
-        # typed unvarying and fails the scan carry check).
-        q32 * 0.0,
-        jnp.arange(num_blocks),
+    flat = lambda x: x.reshape(batch * heads, seq_len, head_dim)  # noqa: E731
+    grads = _bwd_pallas(
+        flat(q), flat(k), flat(v), flat(g), flat(out),
+        lse.reshape(batch * heads, 1, seq_len),
+        causal, resolved_scale, block_q, block_k,
     )
-    # blocks: [num_blocks, batch, heads, block, d] -> [b, h, seq, d]
-    merge = lambda blocks: jnp.moveaxis(blocks, 0, 2).reshape(  # noqa: E731
-        batch, heads, seq_len, head_dim
-    )
-    dk = merge(dk_blocks)
-    dv = merge(dv_blocks)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return tuple(x.reshape(q.shape) for x in grads)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

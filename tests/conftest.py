@@ -16,11 +16,26 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import atexit  # noqa: E402
+import shutil  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
 import time  # noqa: E402
 
 import pytest  # noqa: E402
+
+# Under pytest-xdist each worker gets a temp dir of its own (children
+# inherit it through TMPDIR): the leak canary below looks for the
+# package's stray directories in the temp dir, and in a shared one it
+# takes another worker's live ``adaptdl-warmup-*`` for this test's
+# leak (23 teardown errors in the driver's run of PR 25, ROADMAP D0).
+if os.environ.get("PYTEST_XDIST_WORKER"):
+    _worker_tmp = tempfile.mkdtemp(
+        prefix=f"pytest-{os.environ['PYTEST_XDIST_WORKER']}-"
+    )
+    os.environ["TMPDIR"] = _worker_tmp
+    tempfile.tempdir = None  # re-read TMPDIR at the next use
+    atexit.register(shutil.rmtree, _worker_tmp, ignore_errors=True)
 
 from adaptdl_tpu import checkpoint, trace  # noqa: E402
 

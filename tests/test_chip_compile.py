@@ -13,6 +13,7 @@
 
 import importlib
 import os
+import re
 import sys
 
 import jax
@@ -119,7 +120,16 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
         sharding = SingleDeviceSharding(v5e.devices[0])
     arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
     compiled = jax.jit(fn).lower(arg, arg, arg).compile()
-    assert flash_mod.MOSAIC_CALL in compiled.as_text()
+    text = compiled.as_text()
+    assert flash_mod.MOSAIC_CALL in text
+    # The backward kernel is in a gradient's program under its own
+    # name, which is how a device trace tells it from the forward.
+    named = re.findall(
+        rf"%[\w\-]*{flash_mod.BWD_KERNEL_NAME}[\w\-]*[.\d]* = "
+        rf".*{flash_mod.MOSAIC_CALL}",
+        text,
+    )
+    assert len(named) == (1 if "grad" in what else 0)
     resident = flash_mod._schedule(*shape[2:], 2, 128, 128).chunk_k == shape[2]
     assert resident == (shape != K_BLOCKED)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30

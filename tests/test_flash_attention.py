@@ -1,6 +1,7 @@
-"""Flash-attention Pallas kernel vs the dense reference: forward
-values, gradients (custom VJP with blockwise recompute), causal and
-bidirectional, and use as the transformer's attention_fn. Runs in
+"""Flash-attention Pallas kernels vs the dense reference: forward
+values, gradients (custom VJP, the backward kernel recomputing P from
+the saved log-sum-exp), causal and bidirectional, float32 and bf16,
+and use as the transformer's attention_fn. Runs in
 interpret mode on CPU — same semantics the compiled kernel executes
 on TPU."""
 
@@ -47,11 +48,16 @@ def _qkv(batch=2, heads=2, seq=64, d=16, seed=0, dtype=jnp.float32):
     )
 
 
-def _schedule_events():
+def _schedule_events(name="flash.schedule", **own):
+    """Attributes of the ``name`` events in the trace buffer, oldest
+    first; with ``own``, only those of calls with these attributes (a
+    test asserts on the events of ITS call, whatever else was traced
+    into the process-wide buffer)."""
     return [
         rec["attrs"]
         for rec in trace.snapshot_spans()
-        if rec["name"] == "flash.schedule"
+        if rec["name"] == name
+        and all(rec["attrs"][key] == value for key, value in own.items())
     ]
 
 
@@ -153,7 +159,12 @@ def test_transformer_attention_fn_hook(monkeypatch, causal, remat, kv_budget):
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
     assert np.isfinite(losses).all()
-    assert _schedule_events()[-1]["kv_resident"] == (kv_budget is None)
+    for name in ("flash.schedule", "flash.schedule_bwd"):
+        (resident,) = {
+            attrs["kv_resident"]
+            for attrs in _schedule_events(name, seq_len=32, head_dim=16)
+        }
+        assert resident == (kv_budget is None), name
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -177,6 +188,120 @@ def test_bf16_operands_match_plain_attention(causal, reference):
         atol=tol,
         rtol=tol,
     )
+
+
+def _relative(got, want):
+    """|got - want| / |want| in the 2-norm, both taken in float32."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# name -> (shape kwargs, causal, module constants). 32 bf16 keys at
+# head 64, K and V, double-buffered: two chunks of the 64 keys, so dQ
+# is summed over chunks outside the kernel.
+BF16_GRADIENT_CASES = {
+    "causal": (dict(seq=64, d=64), True, {}),
+    "bidirectional": (dict(seq=64, d=64), False, {}),
+    "head_128": (dict(batch=1, seq=64, d=128), True, {}),
+    "k_blocked": (
+        dict(seq=64, d=64), True,
+        {"_KV_VMEM_BUDGET": 2 * 2 * 32 * 64 * 2, "_TILE_ROWS": 16},
+    ),
+}
+
+
+@pytest.mark.parametrize("reference", ["causal_attention", "dense_f32"])
+@pytest.mark.parametrize("case", list(BF16_GRADIENT_CASES))
+def test_bf16_gradients_match_plain_attention(monkeypatch, case, reference):
+    """bf16 in: the backward kernel multiplies bf16 operands with
+    float32 accumulation, takes ``exp``, ``delta`` and ``dS`` in
+    float32 and rounds P and dS to bf16 only as operands of dV, dK and
+    dQ — what ``jax.grad(causal_attention)`` does on the same inputs.
+
+    Distances are relative 2-norms per gradient. Measured over seeds
+    3-5 in interpret mode: the kernel sits 2.2e-3 to 3.0e-3 from the
+    float32 "highest" reference (the plain path 2.2e-3 to 2.6e-3: one
+    bf16 rounding, 2**-9, of each operand) and 3.2e-3 to 3.7e-3 from
+    the plain path (two such roundings apart). The bounds are 1.5
+    times that: P or dS rounded one format below bf16 (three mantissa
+    bits: ~3e-2) or the ``delta`` term dropped (order 1) fail them."""
+    shape, causal, constants = BF16_GRADIENT_CASES[case]
+    for name, value in constants.items():
+        monkeypatch.setattr(flash_mod, name, value)
+    q, k, v = _qkv(seed=3, dtype=jnp.bfloat16, **shape)
+    g = _qkv(seed=4, dtype=jnp.bfloat16, **shape)[0]  # the cotangent
+
+    def grads(attend, *args):
+        return jax.vjp(attend, *args[:3])[1](args[3])
+
+    got = grads(
+        lambda q, k, v: flash_attention(q, k, v, causal, None, 16, 32),
+        q, k, v, g,
+    )
+    resident = _schedule_events("flash.schedule_bwd")[-1]["kv_resident"]
+    assert resident == (case != "k_blocked")
+    if reference == "causal_attention":
+        want = grads(
+            lambda q, k, v: causal_attention(q, k, v, causal=causal),
+            q, k, v, g,
+        )
+        bound = 5.5e-3
+    else:
+        want = grads(
+            lambda q, k, v: _dense(q, k, v, causal),
+            *(x.astype(jnp.float32) for x in (q, k, v, g)),
+        )
+        bound = 4.5e-3
+    for got_x, want_x, name in zip(got, want, "qkv"):
+        assert got_x.dtype == jnp.bfloat16
+        assert _relative(got_x, want_x) < bound, f"d{name}"
+
+
+@pytest.mark.parametrize("transform", ["shard_map", "remat", "shard_map_remat"])
+def test_gradients_under_transforms(transform):
+    """The backward kernel where the trainer puts it: under
+    ``jax.shard_map`` over a ``data`` axis (its outputs must declare
+    how they vary, and interpret mode needs every block access inside
+    a region) and under ``nn.remat`` (the forward kernel re-run inside
+    the backward pass)."""
+    import flax.linen as nn
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    class Attend(nn.Module):
+        @nn.compact
+        def __call__(self, q, k, v):
+            return flash_attention(q, k, v, True, None, 16, 16)
+
+    module = (nn.remat(Attend) if "remat" in transform else Attend)()
+    q, k, v = _qkv(batch=4, seq=32, seed=8)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
+
+    grad = jax.grad(
+        loss(lambda q, k, v: module.apply({}, q, k, v)), argnums=(0, 1, 2)
+    )
+    if "shard_map" in transform:
+        # The loss is a sum over sequences, so a shard's gradient is
+        # the global gradient's rows of that shard.
+        mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+        grad = jax.jit(
+            jax.shard_map(
+                grad, mesh=mesh, in_specs=P("data"), out_specs=P("data")
+            )
+        )
+    got = grad(q, k, v)
+    assert len(_schedule_events("flash.schedule_bwd")) == 1
+    # Remat traces the forward a second time, inside the backward.
+    assert len(_schedule_events()) == (2 if "remat" in transform else 1)
+    want = jax.grad(
+        loss(lambda q, k, v: _dense(q, k, v, True)), argnums=(0, 1, 2)
+    )(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=5e-5, rtol=5e-4,
+            err_msg=f"d{name}",
+        )
 
 
 # name -> (shape kwargs, block_q, block_k, module constants, expected
@@ -233,14 +358,25 @@ def schedule_case(request, monkeypatch):
         request.param
     ]
     if constants is not None:
-        pinned = {"_TILE_ROWS": 1, "_DIAG_ROWS": 1}
-        for name, value in {**pinned, **constants}.items():
+        pinned = {"_TILE_ROWS": 1, "_DIAG_ROWS": 1, **constants}
+        # The backward's pieces as the forward's, so that one
+        # expectation holds for both passes.
+        pinned["_BWD_DIAG_ROWS"] = pinned["_DIAG_ROWS"]
+        for name, value in pinned.items():
             monkeypatch.setattr(flash_mod, name, value)
     return shape, block_q, block_k, expected
 
 
-def _engaged():
-    attrs = _schedule_events()[-1]
+def _engaged(name, q, causal):
+    """(tile, diag_tile, kv_resident) of the newest ``name`` event of
+    a call with ``q``'s shape and dtype."""
+    attrs = _schedule_events(
+        name,
+        seq_len=q.shape[2],
+        head_dim=q.shape[3],
+        dtype=q.dtype.name,
+        causal=causal,
+    )[-1]
     return attrs["tile"], attrs["diag_tile"], attrs["kv_resident"]
 
 
@@ -250,7 +386,7 @@ def test_forward_across_schedules(schedule_case, causal):
     shape, block_q, block_k, expected = schedule_case
     q, k, v = _qkv(seed=5, **shape)
     out = flash_attention(q, k, v, causal, None, block_q, block_k)
-    assert _engaged() == expected
+    assert _engaged("flash.schedule", q, causal) == expected
     np.testing.assert_allclose(
         np.asarray(out),
         np.asarray(_dense(q, k, v, causal)),
@@ -276,7 +412,13 @@ def test_gradients_across_schedules(schedule_case, causal):
         ),
         argnums=(0, 1, 2),
     )(q, k, v)
-    assert _engaged() == expected
+    # Both passes run the schedule the shape chose (as shipped the
+    # backward's updates cover 256 keys, the forward's 512).
+    assert _engaged("flash.schedule", q, causal) == expected
+    tile, diag, resident = expected
+    if expected == (1024, 512, True):
+        diag = 256
+    assert _engaged("flash.schedule_bwd", q, causal) == (tile, diag, resident)
     want = jax.grad(
         loss(lambda q, k, v: _dense(q, k, v, causal)), argnums=(0, 1, 2)
     )(q, k, v)
@@ -357,7 +499,7 @@ def test_primal_call_has_no_lse_output():
 
 
 CELL = (16, 12, 1024, 64)  # the benchmark's gpt2-124m micro-batch
-TILE_128 = {"_TILE_ROWS": 128, "_DIAG_ROWS": 128}
+TILE_128 = {"_TILE_ROWS": 128, "_DIAG_ROWS": 128, "_BWD_DIAG_ROWS": 128}
 
 
 @pytest.mark.parametrize(
@@ -413,6 +555,79 @@ def test_schedule_event(monkeypatch, shape, dtype, causal, constants, expected):
     assert attrs["seq_len"] == shape[2] and attrs["head_dim"] == shape[3]
     assert attrs["dtype"] == jnp.dtype(dtype).name
     assert attrs["causal"] is causal
+    for name, value in expected.items():
+        assert attrs[name] == value, name
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, causal, constants, expected",
+    [
+        (
+            # As shipped: one grid step per (batch, head); of the 16
+            # 256 x 256 blocks of logits the six above the diagonal
+            # are skipped, for dK / dV and for dQ alike (one kernel).
+            CELL, jnp.bfloat16, True, {},
+            dict(kv_resident=True, tile=1024, diag_tile=256, kernels=1,
+                 dkv_tiles_visited=10, dq_tiles_visited=10,
+                 k_tiles_total=16, grid_steps=192),
+        ),
+        (
+            # At the caller's 128 x 128: the causal skip share, 36 of
+            # 64, read from the schedule and not from a clock.
+            CELL, jnp.bfloat16, True, TILE_128,
+            dict(kv_resident=True, tile=128, diag_tile=128,
+                 dkv_tiles_visited=36, dq_tiles_visited=36,
+                 k_tiles_total=64, grid_steps=192 * 8),
+        ),
+        (
+            CELL, jnp.bfloat16, False, TILE_128,
+            dict(kv_resident=True, dkv_tiles_visited=64,
+                 dq_tiles_visited=64, k_tiles_total=64,
+                 grid_steps=192 * 8),
+        ),
+        (
+            # K and V of one head past the budget: four chunks of keys,
+            # each passed by all eight query tiles (those before it do
+            # nothing and fetch nothing).
+            (1, 2, 1024, 64), jnp.float32, True,
+            {**TILE_128, "_KV_VMEM_BUDGET": 2 * 2 * 256 * 64 * 4},
+            dict(kv_resident=False, tile=128, dkv_tiles_visited=36,
+                 k_tiles_total=64, grid_steps=2 * 4 * 8),
+        ),
+        (
+            # Long context as shipped: 8k keys of 32k resident.
+            (1, 1, 32768, 128), jnp.bfloat16, True, {},
+            dict(kv_resident=False, tile=1024, diag_tile=256,
+                 dkv_tiles_visited=16 * 32 * 31 // 2 + 32 * 10,
+                 k_tiles_total=128 * 128, grid_steps=4 * 32),
+        ),
+    ],
+)
+def test_backward_schedule_event(
+    monkeypatch, shape, dtype, causal, constants, expected
+):
+    """Tracing a gradient records one ``flash.schedule_bwd`` event
+    beside the forward's: the same schedule, and the blocks of logits
+    the backward recomputes of all — the causal skip, proved from the
+    loop bounds."""
+    for name, value in constants.items():
+        monkeypatch.setattr(flash_mod, name, value)
+    arg = jax.ShapeDtypeStruct(shape, dtype)
+    jax.eval_shape(
+        jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal, None, 128, 128
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        arg, arg, arg,
+    )
+    (attrs,) = _schedule_events("flash.schedule_bwd")
+    (forward,) = _schedule_events()
+    for name in ("seq_len", "head_dim", "dtype", "causal", "tile",
+                 "kv_resident"):
+        assert attrs[name] == forward[name], name
+    assert attrs["dq_tiles_visited"] == attrs["dkv_tiles_visited"]
     for name, value in expected.items():
         assert attrs[name] == value, name
 
