@@ -92,22 +92,34 @@ class TransformerConfig:
 
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
-    """Rotary position embedding over the last (head_dim) axis.
+    """Rotary position embedding over the last (head_dim) axis:
+    adjacent pairs ``(x[2i], x[2i + 1])`` turned by ``positions *
+    10000 ** (-2i / head_dim)``.
 
-    x: [batch, heads, seq, head_dim]; positions: [seq].
+    x: [batch, seq, heads, head_dim], the projection's own layout;
+    positions: [seq].
+
+    Written as ``x * cos + partner(x) * (-+sin)`` at full width, the
+    partner of a lane the other one of its pair, taken by a
+    ``[head_dim, head_dim]`` permutation as a matmul (exact: one 1 a
+    column): one pass that XLA fuses, in its own layout. Strided halves
+    (``x[..., 0::2]``) and their ``stack`` cost gathers and a relayout
+    through an array whose minor dimension is 2, and a lane roll is
+    slices and pads that XLA leaves unfused (PERF.md, PR 27); the
+    values are the same to the bit.
     """
     head_dim = x.shape[-1]
-    freqs = 1.0 / (
-        10000.0 ** (jnp.arange(0, head_dim, 2) / head_dim)
+    lane = jnp.arange(head_dim)
+    freqs = 1.0 / (10000.0 ** ((lane // 2 * 2) / head_dim))
+    angles = positions[:, None] * freqs[None, :]  # [seq, head_dim]
+    sin = jnp.sin(angles).astype(x.dtype)
+    sin = jnp.where(lane % 2 == 0, -sin, sin)[:, None, :]
+    cos = jnp.cos(angles).astype(x.dtype)[:, None, :]
+    swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
+    partner = jnp.einsum(
+        "...d,de->...e", x, swap, precision=jax.lax.Precision.HIGHEST
     )
-    angles = positions[:, None] * freqs[None, :]  # [seq, head_dim/2]
-    sin = jnp.sin(angles)[None, None, :, :].astype(x.dtype)
-    cos = jnp.cos(angles)[None, None, :, :].astype(x.dtype)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rotated = jnp.stack(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    )
-    return rotated.reshape(x.shape)
+    return x * cos + partner * sin
 
 
 def causal_attention(q, k, v, axis_name=None, causal=True):
@@ -142,11 +154,16 @@ class Attention(nn.Module):
             name="qkv",
         )(x)
         q, k, v = jnp.moveaxis(qkv, -3, 0)  # each [b, s, h, d]
-        q = jnp.swapaxes(q, 1, 2)  # [b, h, s, d]
-        k = jnp.swapaxes(k, 1, 2)
-        v = jnp.swapaxes(v, 1, 2)
         q = rope(q, positions)
         k = rope(k, positions)
+        # attention_fn's contract is [b, h, s, d]. The flash kernels
+        # index [b * h, d, s], which is how XLA lays these arrays out
+        # by itself, and swap into it themselves: their swap and this
+        # one (and the one of ``out``) cost no copy between the
+        # projections and the kernels (PERF.md, PR 27).
+        q = jnp.swapaxes(q, 1, 2)
+        k = jnp.swapaxes(k, 1, 2)
+        v = jnp.swapaxes(v, 1, 2)
         attn = cfg.attention_fn
         if attn is None:
             if cfg.seq_axis is not None:

@@ -14,6 +14,19 @@ The forward schedule, chosen per call by :func:`_schedule` from
 flag, no environment variable; ``flash.schedule`` in the trace journal
 says what was chosen):
 
+- **Positions along lanes.** Both kernels index ``[batch * heads,
+  head_dim, seq]``: a (batch, head) is ``head_dim`` rows of whole
+  sequences. That is how XLA itself lays out what the QKV projection
+  and rotary write and what the out projection and their gradients
+  read on the chip (``[batch, seq, heads, head_dim]`` with ``seq``
+  minor-most: a minor dimension of 64 would leave half of every lane
+  tile empty), so :func:`flash_attention`'s swap from its
+  ``[batch, heads, seq, head_dim]`` contract and the model's swap into
+  it are transposes of each other and no copy is made on either side
+  of either kernel; the residuals are saved as the kernels index
+  them. A caller that really holds row-major ``[batch, heads, seq,
+  head_dim]`` pays one transposing copy an operand (PERF.md, PR 27;
+  ``layout`` in the trace journal).
 - **One grid step per (batch, head) and query tile, the k-loop inside
   the kernel.** K and V of the head are one VMEM block whose index
   does not depend on the query tile, so they are fetched once per
@@ -31,12 +44,15 @@ says what was chosen):
   the diagonal crosses is done in pieces of ``_DIAG_ROWS`` (512) keys,
   each multiplied only with the queries at or after it, and only the
   corner block of a piece is masked.
-- **Logits are held transposed**, ``[keys, queries]``: a query's
-  statistics run along lanes, so max and sum reduce across sublanes
-  (plain VPU work, not lane rotations), the rescale of the
-  accumulator ``[head_dim, queries]`` is a sublane broadcast, and the
-  log-sum-exp leaves as one float32 per row, ``[bh, 1, seq]``, with
-  no lane replication. The primal call has no such output at all.
+- **Logits are held** ``[keys, queries]``: a query's statistics run
+  along lanes, so max and sum reduce across sublanes (plain VPU work,
+  not lane rotations), the rescale of the accumulator ``[head_dim,
+  queries]`` is a sublane broadcast, the output tile is the
+  accumulator as it stands, and the log-sum-exp leaves as one float32
+  per row, ``[bh, 1, seq]``, with no lane replication. The primal
+  call has no such output at all. ``K^T Q`` contracts over
+  ``head_dim``, which both hold along sublanes, so the small K piece
+  is turned; ``V P`` is a plain product.
 - **MXU operands in the input dtype, float32 accumulation** — the
   plain path's arithmetic: bf16 in means QK^T on bf16 operands with
   ``preferred_element_type=float32``, max / exp / sum / rescale and
@@ -69,13 +85,17 @@ log-sum-exp, never stored, so backward memory stays O(seq).
   ``_BWD_DIAG_ROWS`` (256) keys: a traced loop over those below the
   diagonal tile, then the diagonal tile's pieces, each with the
   queries at or after it and only its corner block masked.
-- **Transposed logits, operands in the input dtype**, as forward:
-  ``lse`` and ``delta = rowsum(dO * O)`` (taken in the kernel, in
-  float32) broadcast along sublanes; P^T and dS^T, rounded to the
-  input dtype, are the left operands of dV and dK as they stand, and
-  dQ is accumulated transposed (``K^T dS^T``: only the small K is
-  turned). ``exp``, ``delta``, ``dS`` and the three accumulators are
-  float32; float32 in means float32 operands.
+- **Logits** ``[keys, queries]``, **operands in the input dtype**, as
+  forward: ``lse`` and ``delta = rowsum(dO * O)`` (taken in the
+  kernel, in float32, a sublane reduction) broadcast along sublanes;
+  ``dV = dO P^T`` and ``dK = Q dS^T`` contract over lanes as they
+  stand, ``dQ = K dS`` is a plain product, and all three leave as
+  they were accumulated, ``[head_dim, positions]``. The logits and
+  ``dP`` contract over ``head_dim``: the K and V pieces are turned
+  update by update, or, where more than one query tile passes a
+  chunk, the chunk once into VMEM scratch. ``exp``, ``delta``,
+  ``dS`` and the three accumulators are float32; float32 in means
+  float32 operands.
 - **Beyond the budget** the key chunks are a grid axis: a query tile
   before a chunk fetches and computes nothing, and dQ leaves as one
   float32 partial per chunk, summed outside. Correct and 7 x the scan
@@ -144,6 +164,9 @@ MOSAIC_CALL = "tpu_custom_call"
 # trace (``%flash_bwd.<n>``); the forward is unnamed inside the model's
 # ``attention`` scope, and the benchmark finds it as ``%attention.<n>``.
 BWD_KERNEL_NAME = "flash_bwd"
+# What both kernels index, as the ``flash.schedule*`` events name it:
+# ``[batch * heads, head_dim, seq]``.
+LAYOUT = "bhds"
 
 
 def _use_interpret() -> bool:
@@ -246,14 +269,15 @@ def _fwd_kernel(
     with_lse: bool,
 ):
     """One grid step: one (batch, head), one query tile, one chunk of
-    keys (all of them when K/V are resident). Logits are held
-    transposed, ``[keys, queries]``, so the softmax statistics of a
-    query run along lanes. A traced loop walks the chunk's key tiles
-    below the causal diagonal unmasked; the tile the diagonal crosses
-    comes last, in ``diag``-key pieces, each only for the queries at
-    or after it, and only its corner block is masked."""
-    _, tile, head_dim = q_ref.shape
-    chunk_tiles = k_ref.shape[1] // tile
+    keys (all of them when K/V are resident). Every block is
+    ``[head_dim, positions]``, positions along lanes; logits are
+    ``[keys, queries]``, so the softmax statistics of a query run
+    along lanes too. A traced loop walks the chunk's key tiles below
+    the causal diagonal unmasked; the tile the diagonal crosses comes
+    last, in ``diag``-key pieces, each only for the queries at or
+    after it, and only its corner block is masked."""
+    _, head_dim, tile = q_ref.shape
+    chunk_tiles = k_ref.shape[2] // tile
     rest = list(rest)
     lse_ref = rest.pop(0) if with_lse else None
     state = rest  # VMEM scratch (m, l, acc) across chunks, if chunked
@@ -264,23 +288,23 @@ def _fwd_kernel(
 
     def update(keys, queries, carry, mask=None):
         """One online-softmax update of the statistics and accumulator
-        of ``queries`` (a static range of the tile's rows) with
-        ``keys`` (rows of the chunk). Operands in the input dtype."""
+        of ``queries`` (a static range of the tile's positions) with
+        ``keys`` (positions of the chunk). Operands in the input
+        dtype."""
         m_prev, l_prev, acc = carry  # [1, n], [1, n], [d, n]
-        q, k, v = q_ref[0, queries, :], k_ref[0, keys, :], v_ref[0, keys, :]
+        q, k, v = q_ref[0, :, queries], k_ref[0, :, keys], v_ref[0, :, keys]
         s = scale * lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
+            k, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [keys, queries]
+        )  # [keys, queries]: only the small K is transposed
         if mask is not None:
             s = mask(s)
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_next)
         rescale = jnp.exp(m_prev - m_next)
         l_next = l_prev * rescale + jnp.sum(p, axis=0, keepdims=True)
-        acc = acc * rescale + lax.dot_general(
-            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        acc = acc * rescale + jnp.dot(
+            v, p.astype(v.dtype), preferred_element_type=jnp.float32
         )
         return m_next, l_next, acc
 
@@ -309,7 +333,7 @@ def _fwd_kernel(
     def finish(carry):
         m, l, acc = diagonal_tile(carry) if causal else carry
         l = jnp.maximum(l, 1e-30)
-        o_ref[0] = (acc / l).T.astype(o_ref.dtype)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
         if with_lse:
             lse_ref[0] = m + jnp.log(l)
 
@@ -347,9 +371,9 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
-    """q/k/v: [bh, seq, d] -> (out [bh, seq, d], lse [bh, seq] or
+    """q/k/v: [bh, d, seq] -> (out [bh, d, seq], lse [bh, seq] or
     None)."""
-    bh, seq_len, head_dim = q.shape
+    bh, head_dim, seq_len = q.shape
     sched = _schedule(
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k
     )
@@ -362,6 +386,7 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         head_dim=head_dim,
         dtype=q.dtype.name,
         causal=causal,
+        layout=LAYOUT,
         kv_resident=num_chunks == 1,
         tile=tile,
         diag_tile=diag,
@@ -384,12 +409,12 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
             # the diagonal is in: nothing is fetched for it, and the
             # kernel does nothing in it.
             ci = jnp.minimum(ci, qi * tile // chunk_k)
-        return (b, ci, 0)
+        return (b, 0, ci)
 
     q_spec = pl.BlockSpec(
-        (1, tile, head_dim), lambda b, qi, ci: (b, qi, 0)
+        (1, head_dim, tile), lambda b, qi, ci: (b, 0, qi)
     )
-    kv_spec = pl.BlockSpec((1, chunk_k, head_dim), kv_index)
+    kv_spec = pl.BlockSpec((1, head_dim, chunk_k), kv_index)
     # Inside a shard_map (the trainer's data/seq axes) pallas outputs
     # must declare how they vary: the same way q does.
     vma = jax.typeof(q).vma
@@ -452,33 +477,51 @@ def flash_attention(
     Returns:
       ``[batch, heads, seq, head_dim]``, dtype of ``q``.
     """
-    out, _ = _flash_fwd(
+    *_, out, _ = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, with_lse=False
     )
-    return out
+    return _from_kernel(out, q.shape)
+
+
+def _to_kernel(x):
+    """``[batch, heads, seq, head_dim]`` as the kernels index it:
+    ``[batch * heads, head_dim, seq]``, positions along lanes. That is
+    how XLA itself lays out what the QKV projection and rotary write
+    and what the out projection and their gradients read (a minor
+    dimension of ``head_dim`` would waste lanes), so inside a model
+    this swap and the model's own to ``[batch, heads, seq, head_dim]``
+    are transposes of each other that cost no copy."""
+    batch, heads, seq_len, head_dim = x.shape
+    return jnp.swapaxes(x, 2, 3).reshape(batch * heads, head_dim, seq_len)
+
+
+def _from_kernel(x, shape):
+    batch, heads, seq_len, head_dim = shape
+    return jnp.swapaxes(x.reshape(batch, heads, head_dim, seq_len), 2, 3)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
-    batch, heads, seq_len, head_dim = q.shape
+    """-> (q, k, v, out, lse) as the kernels index them."""
+    head_dim = q.shape[-1]
     resolved_scale = (
         head_dim**-0.5 if scale is None else float(scale)
     )
-    flat = lambda x: x.reshape(batch * heads, seq_len, head_dim)  # noqa: E731
+    operands = tuple(_to_kernel(x) for x in (q, k, v))
     out, lse = _fwd_pallas(
-        flat(q), flat(k), flat(v), causal, resolved_scale,
-        block_q, block_k, with_lse,
+        *operands, causal, resolved_scale, block_q, block_k, with_lse
     )
-    out = out.reshape(q.shape)
-    if with_lse:
-        lse = lse.reshape(batch, heads, seq_len)
-    return out, lse
+    return (*operands, out, lse)
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_fwd(
+    """Residuals ``(q, k, v, out, lse)``: the first four in the
+    kernels' layout, as the backward reads them (a copy here would be
+    a copy a step), ``lse`` as ``[batch, heads, seq]``."""
+    *operands, out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, with_lse=True
     )
-    return out, (q, k, v, out, lse)
+    residuals = (*operands, out, lse.reshape(q.shape[:3]))
+    return _from_kernel(out, q.shape), residuals
 
 
 def _bwd_kernel(
@@ -495,7 +538,7 @@ def _bwd_kernel(
     dq_acc,
     dk_acc,
     dv_acc,
-    *,
+    *turned,
     causal: bool,
     scale: float,
     diag: int,
@@ -506,43 +549,55 @@ def _bwd_kernel(
     forward's schedule with the loops swapped: the chunk's K and V stay
     while the query tiles pass, its dK and dV accumulate in float32
     scratch over the query tiles at or after it, and the query tile's
-    dQ over the chunk's keys at or before it. Logits are transposed,
-    ``[keys, queries]``, so ``lse`` and ``delta`` broadcast along
-    sublanes and P^T and dS^T are the left operands of dV and dK as
-    they stand. Every update covers ``diag`` keys: a traced loop walks
+    dQ over the chunk's keys at or before it. Blocks are ``[head_dim,
+    positions]`` and logits ``[keys, queries]``, so ``lse`` and
+    ``delta`` broadcast along sublanes, dV = dO P^T and dK = Q dS^T
+    contract over lanes as they stand, and dQ = K dS is a plain
+    product. Every update covers ``diag`` keys: a traced loop walks
     those below the diagonal tile unmasked; the diagonal tile's pieces
     take only the queries at or after them, corner block masked."""
-    _, tile, head_dim = q_ref.shape
-    chunk_k = k_ref.shape[1]
+    _, head_dim, tile = q_ref.shape
+    chunk_k = k_ref.shape[2]
     chunk_tiles, per_tile = chunk_k // tile, tile // diag
     ci, qi = pl.program_id(1), pl.program_id(2)
     first_tile = ci * chunk_tiles  # of this chunk, among all key tiles
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
     lead = (0,) * (len(dq_ref.shape) - 2)  # [chunk,] batch-head
+    kt_ref, vt_ref = turned or (None, None)
+
+    def across_head_dim(ref, turned_ref, keys, x):
+        """``ref[0, :, keys].T @ x``: K or V of ``keys`` against q or
+        dO over ``head_dim``, which both hold along sublanes. One of
+        the two has to be turned: the piece here, update by update,
+        or, where more than one query tile passes the chunk, the whole
+        chunk once (``turned_ref``)."""
+        if turned_ref is not None:
+            return jnp.dot(
+                turned_ref[keys, :], x, preferred_element_type=jnp.float32
+            )
+        return lax.dot_general(
+            ref[0, :, keys], x, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     def update(keys, queries, mask=None):
-        q, do = q_ref[0, queries, :], do_ref[0, queries, :]
-        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-        s = scale * lax.dot_general(
-            k, q, nt, preferred_element_type=jnp.float32
-        )  # [keys, queries]
+        q, do = q_ref[0, :, queries], do_ref[0, :, queries]  # [d, n]
+        s = scale * across_head_dim(k_ref, kt_ref, keys, q)  # [keys, queries]
         if mask is not None:
             s = mask(s)
         p = jnp.exp(s - lse_ref[0, :, queries])
-        dp = lax.dot_general(
-            v, do, nt, preferred_element_type=jnp.float32
-        )
+        dp = across_head_dim(v_ref, vt_ref, keys, do)
         ds = (p * (dp - delta_ref[:, queries])).astype(q.dtype)
-        dv_acc[keys, :] += jnp.dot(
-            p.astype(do.dtype), do, preferred_element_type=jnp.float32
-        )
-        dk_acc[keys, :] += jnp.dot(
-            ds, q, preferred_element_type=jnp.float32
-        )
-        dq_acc[:, queries] += lax.dot_general(
-            k, ds, (((0,), (0,)), ((), ())),
+        dv_acc[:, keys] += lax.dot_general(
+            do, p.astype(do.dtype), nt,
             preferred_element_type=jnp.float32,
-        )  # [head_dim, queries]: only the small K is transposed
+        )
+        dk_acc[:, keys] += lax.dot_general(
+            q, ds, nt, preferred_element_type=jnp.float32
+        )
+        dq_acc[:, queries] += jnp.dot(
+            k_ref[0, :, keys], ds, preferred_element_type=jnp.float32
+        )
 
     def piece_below(t, carry):
         update(pl.ds(pl.multiple_of(t * diag, diag), diag), slice(None))
@@ -560,16 +615,18 @@ def _bwd_kernel(
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        for ref, turned_ref in zip((k_ref, v_ref), turned):
+            turned_ref[...] = ref[0].T
 
     # Query tiles before the chunk see none of its keys.
     sees = qi >= first_tile if causal else qi >= 0
 
     @pl.when(sees)
     def _tile():
-        # delta = rowsum(dO * O) in float32, rows along lanes as lse
-        # has them (the transpose is what turns them).
+        # delta = rowsum(dO * O) in float32, positions along lanes as
+        # lse has them.
         d_o = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        delta_ref[...] = jnp.sum(d_o.T, axis=0, keepdims=True)
+        delta_ref[...] = jnp.sum(d_o, axis=0, keepdims=True)
         dq_acc[...] = jnp.zeros_like(dq_acc)
         below = chunk_tiles
         if causal:
@@ -579,13 +636,13 @@ def _bwd_kernel(
             diagonal_tile()
         elif causal:
             pl.when(qi - first_tile < chunk_tiles)(diagonal_tile)
-        dq_ref[lead] = (scale * dq_acc[...]).T.astype(dq_ref.dtype)
+        dq_ref[lead] = (scale * dq_acc[...]).astype(dq_ref.dtype)
 
     if causal and num_chunks > 1:
 
         @pl.when(jnp.logical_not(sees))
         def _no_keys():
-            dq_ref[lead] = jnp.zeros((tile, head_dim), dq_ref.dtype)
+            dq_ref[lead] = jnp.zeros((head_dim, tile), dq_ref.dtype)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
@@ -594,9 +651,9 @@ def _bwd_kernel(
 
 
 def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
-    """q/k/v/do/out: [bh, seq, d]; lse: [bh, 1, seq] float32 ->
-    (dq, dk, dv), each [bh, seq, d] in its primal's dtype."""
-    bh, seq_len, head_dim = q.shape
+    """q/k/v/do/out: [bh, d, seq]; lse: [bh, 1, seq] float32 ->
+    (dq, dk, dv), each [bh, d, seq] in its primal's dtype."""
+    bh, head_dim, seq_len = q.shape
     sched = _schedule(
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
         diag_rows=_BWD_DIAG_ROWS,
@@ -611,6 +668,7 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
         head_dim=head_dim,
         dtype=q.dtype.name,
         causal=causal,
+        layout=LAYOUT,
         kv_resident=num_chunks == 1,
         tile=tile,
         diag_tile=diag,
@@ -629,13 +687,13 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
         return qi
 
     q_spec = pl.BlockSpec(
-        (1, tile, head_dim), lambda b, ci, qi: (b, q_index(b, ci, qi), 0)
+        (1, head_dim, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
     )
     lse_spec = pl.BlockSpec(
         (1, 1, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
     )
     kv_spec = pl.BlockSpec(
-        (1, chunk_k, head_dim), lambda b, ci, qi: (b, ci, 0)
+        (1, head_dim, chunk_k), lambda b, ci, qi: (b, 0, ci)
     )
     vma = jax.typeof(q).vma
     # dQ of a query tile is summed over the key chunks: the gradient
@@ -643,16 +701,26 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
     # chunk, added up outside.
     if num_chunks == 1:
         dq_spec = pl.BlockSpec(
-            (1, tile, head_dim), lambda b, ci, qi: (b, qi, 0)
+            (1, head_dim, tile), lambda b, ci, qi: (b, 0, qi)
         )
         dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)
     else:
         dq_spec = pl.BlockSpec(
-            (1, 1, tile, head_dim), lambda b, ci, qi: (ci, b, qi, 0)
+            (1, 1, head_dim, tile), lambda b, ci, qi: (ci, b, 0, qi)
         )
         dq_shape = jax.ShapeDtypeStruct(
             (num_chunks, *q.shape), jnp.float32, vma=vma
         )
+    # Where more than one query tile passes a chunk: its K and V once
+    # more with keys along sublanes (measured, PERF.md PR 27: 2.5-4% of
+    # the kernel at 4096 and 16k keys; with one tile a chunk, turning
+    # piece by piece costs 4% less).
+    turned = []
+    if seq_len > tile:
+        turned = [
+            pltpu.VMEM((chunk_k, head_dim), k.dtype),
+            pltpu.VMEM((chunk_k, head_dim), v.dtype),
+        ]
     dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_kernel,
@@ -671,10 +739,11 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
         ],
         scratch_shapes=[
             pltpu.VMEM((1, tile), jnp.float32),  # delta of the tile
-            pltpu.VMEM((head_dim, tile), jnp.float32),  # dQ^T of the tile
-            pltpu.VMEM((chunk_k, head_dim), jnp.float32),  # dK of the chunk
-            pltpu.VMEM((chunk_k, head_dim), jnp.float32),  # dV of the chunk
-        ],
+            pltpu.VMEM((head_dim, tile), jnp.float32),  # dQ of the tile
+            pltpu.VMEM((head_dim, chunk_k), jnp.float32),  # dK of the chunk
+            pltpu.VMEM((head_dim, chunk_k), jnp.float32),  # dV of the chunk
+        ]
+        + turned,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BWD,
@@ -701,15 +770,13 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
         dQ = dS K * scale ;  dK = dS^T Q * scale
     """
     q, k, v, out, lse = residuals
-    batch, heads, seq_len, head_dim = q.shape
+    head_dim, seq_len = g.shape[3], g.shape[2]
     resolved_scale = head_dim**-0.5 if scale is None else float(scale)
-    flat = lambda x: x.reshape(batch * heads, seq_len, head_dim)  # noqa: E731
     grads = _bwd_pallas(
-        flat(q), flat(k), flat(v), flat(g), flat(out),
-        lse.reshape(batch * heads, 1, seq_len),
+        q, k, v, _to_kernel(g), out, lse.reshape(-1, 1, seq_len),
         causal, resolved_scale, block_q, block_k,
     )
-    return tuple(x.reshape(q.shape) for x in grads)
+    return tuple(_from_kernel(x, g.shape) for x in grads)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

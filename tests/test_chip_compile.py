@@ -135,6 +135,98 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
+def test_block_keeps_the_projections_layout_on_v5e(v5e, chip_compile):
+    """One remat ``Block`` of the benchmark's model, forward and
+    gradient at the cell's micro-batch, compiled for the described
+    v5e: ``Attention``'s swaps and the kernels' own cancel against
+    the layout XLA gives the projections (sequence minor-most), so
+    every operand of both kernels is a bitcast of what a fusion wrote:
+    q, k, v and out are never copied into ``[b, h, s, d]``, into a
+    flat ``[b * h, s, d]`` or into the kernels' ``[b * h, d, s]``, and
+    rotary makes no gather and no pair-shaped array (PERF.md, PR 27).
+    The kernels keep the names the benchmark's readers find them by:
+    the forward ONE Mosaic call ``%attention.<n>``, the backward
+    ``flash_bwd``."""
+    import functools
+
+    import flax.linen as nn
+
+    from adaptdl_tpu.models.transformer import Block, TransformerConfig
+
+    batch, heads, seq, head_dim = CELL
+    cfg = TransformerConfig(
+        vocab_size=50257, num_layers=1, num_heads=heads,
+        d_model=heads * head_dim, d_ff=4 * heads * head_dim,
+        max_seq_len=seq, dtype=jnp.bfloat16, remat=True,
+        attention_fn=functools.partial(
+            flash_mod.flash_attention, block_q=128, block_k=128
+        ),
+    )
+    block = nn.remat(Block, static_argnums=())(cfg)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), jnp.bfloat16)
+    positions = jnp.arange(seq)
+    params = jax.eval_shape(
+        lambda: block.init(
+            jax.random.key(0), jnp.zeros(x.shape, x.dtype), positions, None
+        )
+    )
+
+    def loss(params, x):
+        out = block.apply(params, x, positions, None)
+        return out.astype(jnp.float32).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1)))
+        .lower(on_chip(params), on_chip(x))
+        .compile()
+        .as_text()
+    )
+    mosaic = flash_mod.MOSAIC_CALL
+    # The forward kernel, under the module's scope name alone (first
+    # pass and remat are one call here: XLA merges the two of a lone
+    # block).
+    assert re.findall(rf"^\s*%attention[.\d]* = .*{mosaic}", text, re.M)
+    assert len(re.findall(
+        rf"^\s*%{flash_mod.BWD_KERNEL_NAME}[.\d]* = .*{mosaic}", text, re.M
+    )) == 1
+    calls = re.findall(
+        rf"^\s*%(?:attention|{flash_mod.BWD_KERNEL_NAME})[.\d]* = "
+        rf".*? custom-call\(([^)]*)\), custom_call_target=\"{mosaic}",
+        text, re.M,
+    )
+    assert len(calls) >= 2
+    for operands in calls:
+        assert "%copy." not in operands and "%transpose" not in operands
+    moved = re.findall(
+        r"^\s*(?:ROOT )?%[\w\-.]+ = (\w+\[[\d,]*\])\S* "
+        r"(copy|transpose|gather|reshape)\(",
+        text, re.M,
+    )
+    assert moved, "the pattern found no data movement at all"
+    flat = batch * heads
+    banned = [
+        (op, shape) for shape, op in moved
+        if shape in (
+            f"bf16[{batch},{heads},{seq},{head_dim}]",
+            f"bf16[{flat},{seq},{head_dim}]",
+            f"bf16[{batch},{heads},{head_dim},{seq}]",
+            f"bf16[{flat},{head_dim},{seq}]",
+        )
+        or shape.endswith(",2]")
+    ]
+    assert not banned, banned
+
+
 def test_chip_smoke_incarnations_on_cpu(tmp_path, monkeypatch):
     """The smoke's two incarnations (children of this process, which
     holds no chip) with a tiny ``TransformerConfig`` and the expected
