@@ -156,9 +156,7 @@ def fingerprint(trainer: Any, key: tuple, args: tuple) -> str:
                 trainer.precondition,
                 trainer.smoothing,
                 trainer.has_aux,
-                trainer.zero1,
-                trainer.zero3,
-                trainer.zero3_blocks,
+                trainer.storage.name,
                 trainer.num_param_groups,
                 trainer.pipeline_micro,
                 trainer._group_ids,

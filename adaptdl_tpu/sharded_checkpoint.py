@@ -34,7 +34,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from adaptdl_tpu import checkpoint, env, faults
+from adaptdl_tpu import checkpoint, env, faults, storage
 
 
 def _sharded_root() -> str:
@@ -217,154 +217,21 @@ class ShardedTrainerCheckpoint(checkpoint.State):
 
     # -- State protocol ----------------------------------------------
 
-    def _zero1_canon_device(self, opt_state):
-        """zero1 run layout -> canonical on-device: [dp, shard] moment
-        rows reshape to one [n] vector (pad trimmed) — a device-side
-        collective, no host gather, so the path works multi-host where
-        TrainerCheckpoint's host-numpy canonical form cannot."""
-        tr = self._trainer
-        dp, shard, n = tr.num_replicas, tr._zero1_shard, tr._zero1_n
-        # Canonical vectors are REPLICATED: n is rarely divisible by
-        # dp, and in zero1 the params themselves are replicated, so a
-        # transient params-sized moment vector stays within the job's
-        # existing memory envelope.
-        sharding = NamedSharding(tr.mesh, P())
-        canon = jax.jit(
-            lambda v: v.reshape(dp * shard)[:n],
-            out_shardings=sharding,
-        )
-        return tr._zero1_map_opt(opt_state, False, canon)
-
-    def _zero1_expand_device(self, opt_state):
-        """Canonical [n] moment vectors -> this incarnation's
-        [dp, shard] rows, re-padded on device for the current replica
-        count."""
-        from adaptdl_tpu.parallel.mesh import DATA_AXIS
-
-        tr = self._trainer
-        dp, shard, pad = (
-            tr.num_replicas, tr._zero1_shard, tr._zero1_pad,
-        )
-        sharding = NamedSharding(tr.mesh, P(DATA_AXIS))
-        expand = jax.jit(
-            lambda v: jax.numpy.pad(v, (0, pad)).reshape(dp, shard),
-            out_shardings=sharding,
-        )
-        return tr._zero1_map_opt(opt_state, True, expand)
-
-    def _zero3_canon_params_device(self, rows):
-        """zero3 run layout -> canonical on-device: [dp, shard] param
-        rows unravel to the (replicated) parameter tree — the same
-        dp-independent disk format a dense trainer would write."""
-        tr = self._trainer
-        dp, shard, n = tr.num_replicas, tr._zero1_shard, tr._zero1_n
-
-        def to_tree(r):
-            return tr._zero1_unravel(r.reshape(dp * shard)[:n])
-
-        abstract = jax.eval_shape(to_tree, rows)
-        out_sh = jax.tree.map(
-            lambda _: NamedSharding(tr.mesh, P()), abstract
-        )
-        return jax.jit(to_tree, out_shardings=out_sh)(rows)
-
-    def _zero3_rows_device(self, tree):
-        """Canonical param tree -> this incarnation's [dp, shard]
-        rows, sharded over the data axis."""
-        from adaptdl_tpu.parallel.mesh import DATA_AXIS
-
-        tr = self._trainer
-        return jax.jit(
-            tr._tree_to_rows,
-            out_shardings=NamedSharding(tr.mesh, P(DATA_AXIS)),
-        )(tree)
-
-    # -- zero3_blocks device-side canonical conversions ----------------
-
-    def _z3b_canon_device(self, state):
-        """zero3_blocks run layout -> canonical on-device: params as
-        the replicated TREE, moments and the prev_grad carry as
-        replicated flat [n] vectors — the same dp-independent formats
-        the pickle path writes, produced by device collectives (no
-        host gather, multi-host safe)."""
-        tr = self._trainer
-
-        def tree_canon(rows):
-            abstract = jax.eval_shape(tr._z3b_tree_from_rows, rows)
-            out_sh = jax.tree.map(
-                lambda _: NamedSharding(tr.mesh, P()), abstract
-            )
-            return jax.jit(
-                tr._z3b_tree_from_rows, out_shardings=out_sh
-            )(rows)
-
-        def flat_canon(rows):
-            return jax.jit(
-                lambda r: tr._z3b.rows_to_flat_canonical(
-                    r["blocks"], r["other"],
-                    tr.zero3_blocks, tr._z3b_spec,
-                ),
-                out_shardings=NamedSharding(tr.mesh, P()),
-            )(rows)
-
-        return state._replace(
-            params=tree_canon(state.params),
-            opt_state=tr._z3b_map_opt(state.opt_state, False, flat_canon),
-            gns=state.gns._replace(
-                prev_grad=flat_canon(state.gns.prev_grad)
-            ),
-        )
-
-    def _z3b_rows_sharding(self):
-        from adaptdl_tpu.parallel.mesh import DATA_AXIS
-
-        tr = self._trainer
-        return {
-            "blocks": NamedSharding(tr.mesh, P(None, DATA_AXIS)),
-            "other": NamedSharding(tr.mesh, P(DATA_AXIS)),
-        }
-
-    def _z3b_expand_device(self, flat):
-        """Canonical flat [n] -> this incarnation's rows dict, born
-        sharded over the data axis."""
-        tr = self._trainer
-
-        def expand(v):
-            blocks_rows, other_rows = tr._z3b.flat_canonical_to_rows(
-                v, tr.zero3_blocks, tr._z3b_spec,
-                tr.num_replicas, tr._z3b_unravel_full,
-            )
-            return {"blocks": blocks_rows, "other": other_rows}
-
-        return jax.jit(
-            expand, out_shardings=self._z3b_rows_sharding()
-        )(flat)
-
-    def _z3b_rows_device(self, tree):
-        """Canonical param tree -> rows dict, born sharded."""
-        tr = self._trainer
-        return jax.jit(
-            tr._z3b_rows_from_tree,
-            out_shardings=self._z3b_rows_sharding(),
-        )(tree)
-
-    def _saved_prev_grad_is_placeholder(self, checkpointer, path):
-        """Whether the payload's gns.prev_grad was written in the
-        placeholder ((1,)-leaf) layout, from orbax metadata: True /
-        False, or None when the metadata cannot be read (the restore
-        then tries the current layout first and falls back to the
-        pre-placeholder one)."""
+    def _saved_carry_shapes(self, checkpointer, path):
+        """Leaf shapes of the payload's gns.prev_grad, from orbax
+        metadata, or None when the metadata cannot be read (the
+        restore then tries the current canonical layout first and
+        falls back to the older ones the storage layout still
+        reads)."""
         try:
             tree = checkpointer.metadata(path).item_metadata.tree
-            prev = tree["gns"]["prev_grad"]
-            leaves = jax.tree.leaves(
-                prev, is_leaf=lambda x: hasattr(x, "shape")
-            )
-            params = jax.tree.leaves(self._trainer._init_params)
-            return any(
-                tuple(leaf.shape) == (1,) and np.shape(p) != (1,)
-                for leaf, p in zip(leaves, params)
-            )
+            return [
+                tuple(leaf.shape)
+                for leaf in jax.tree.leaves(
+                    tree["gns"]["prev_grad"],
+                    is_leaf=lambda x: hasattr(x, "shape"),
+                )
+            ]
         except Exception:  # noqa: BLE001 - metadata is best-effort
             return None
 
@@ -392,29 +259,12 @@ class ShardedTrainerCheckpoint(checkpoint.State):
         state = self._get_state()
         # RNG keys are opaque; store raw key data alongside.
         state = state._replace(rng=jax.random.key_data(state.rng))
-        if self._trainer.zero3_blocks is not None:
-            state = self._z3b_canon_device(state)
-        if self._trainer.zero1:
-            state = state._replace(
-                opt_state=self._zero1_canon_device(state.opt_state)
-            )
-        if self._trainer.zero3:
-            state = state._replace(
-                params=self._zero3_canon_params_device(state.params)
-            )
-        if self._trainer.zero1 and self._trainer.num_replicas == 1:
-            # Canonical prev_grad is the placeholder layout; at dp>1
-            # the run state already IS that layout (replicated on the
-            # mesh), so only the dp==1 full tree needs converting —
-            # built under jit with out_shardings (host-local arrays
-            # would be unserializable in a multi-process job).
-            state = state._replace(
-                gns=state.gns._replace(
-                    prev_grad=(
-                        self._trainer._empty_prev_grad_replicated()
-                    )
-                )
-            )
+        # Canonical (dp-independent) layout, produced by device
+        # collectives and REPLICATED: n is rarely divisible by dp, and
+        # a transient params-sized vector stays within the job's
+        # existing memory envelope.
+        layout = self._trainer.storage
+        state = layout.to_canonical(state, storage.on_mesh(layout.mesh))
         path = _next_payload_dir(self.name)
         # Measured payload volume for the metrics layer: logical
         # device bytes summed over leaves (cheap — shape metadata, no
@@ -522,159 +372,68 @@ class ShardedTrainerCheckpoint(checkpoint.State):
         template = template._replace(
             rng=jax.random.key_data(template.rng)
         )
-        mesh = self._trainer.mesh
+        layout = self._trainer.storage
+        mesh = layout.mesh
+        repl = NamedSharding(mesh, P())
 
-        def abstract(leaf, spec: P):
+        def abstract(path_, leaf):
+            spec = P() if self._sharding_fn is None else (
+                self._sharding_fn(path_)
+            )
             return jax.ShapeDtypeStruct(
-                np.shape(leaf),
-                leaf.dtype,
+                np.shape(leaf), leaf.dtype,
                 sharding=NamedSharding(mesh, spec),
             )
 
-        if self._sharding_fn is None:
-            target = jax.tree.map(lambda x: abstract(x, P()), template)
-        else:
-            target = jax.tree_util.tree_map_with_path(
-                lambda path_, x: abstract(
-                    x, self._sharding_fn(path_)
-                ),
-                template,
-            )
-        if self._trainer.zero1:
-            # The payload stores moments in the canonical [n] layout
-            # (sync() wrote them that way, replicated); restore them
-            # [n] and expand to this incarnation's [dp, shard] rows.
-            tr = self._trainer
-            dp, shard, n = (
-                tr.num_replicas, tr._zero1_shard, tr._zero1_n,
-            )
-            target = target._replace(
-                opt_state=jax.tree.map(
-                    lambda t: (
-                        jax.ShapeDtypeStruct(
-                            (n,),
-                            t.dtype,
-                            sharding=NamedSharding(mesh, P()),
-                        )
-                        if getattr(t, "shape", None) == (dp, shard)
-                        else t
-                    ),
-                    target.opt_state,
-                )
-            )
-        if self._trainer.zero3:
-            # Params are stored as the canonical tree; build its
-            # abstract target from the trainer's init tree (shapes
-            # and dtypes are dp-independent).
-            tr = self._trainer
-            target = target._replace(
-                params=jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(
-                        np.shape(p),
-                        p.dtype,
-                        sharding=NamedSharding(mesh, P()),
-                    ),
-                    tr._init_params,
-                )
-            )
-        if self._trainer.zero3_blocks is not None:
-            # Canonical targets: params as the init TREE, moments and
-            # prev_grad as flat [n] vectors — all replicated.
-            tr = self._trainer
-            n = tr._z3b_n_total
-            repl = NamedSharding(mesh, P())
-            target = target._replace(
-                params=jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(
-                        np.shape(p), p.dtype, sharding=repl
-                    ),
-                    tr._init_params,
-                ),
-                opt_state=tr._z3b_map_opt(
-                    target.opt_state,
-                    False,
-                    lambda rows: jax.ShapeDtypeStruct(
-                        (n,), rows["blocks"].dtype, sharding=repl
-                    ),
-                ),
-                gns=target.gns._replace(
-                    prev_grad=jax.ShapeDtypeStruct(
-                        (n,), np.float32, sharding=repl
-                    )
-                ),
-            )
-        tr = self._trainer
+        # The payload holds the canonical layout sync() wrote: its
+        # abstract form is the transform's own, traced.
+        target = jax.tree_util.tree_map_with_path(
+            abstract,
+            jax.eval_shape(
+                lambda s: layout.to_canonical(s, storage.inline), template
+            ),
+        )
         checkpointer = ocp.StandardCheckpointer()
-        if tr.zero1:
-            # Align the prev_grad target with the SAVED layout, read
-            # from the payload's metadata (canonical placeholders
-            # since the placeholder change; full param-shaped trees
-            # in payloads written before it).
-            saved_placeholder = self._saved_prev_grad_is_placeholder(
-                checkpointer, path
+        # Align the prev_grad target with the SAVED layout, read from
+        # the payload's metadata: the canonical one, or one from before
+        # it that the layout still reads.
+        carries = [target.gns.prev_grad] + [
+            jax.tree.map(
+                lambda leaf: jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype, sharding=repl
+                ),
+                carry,
             )
-
-            def prev_grad_target(placeholder: bool):
-                return jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(
-                        (1,) if placeholder else np.shape(p),
-                        np.float32,
-                        sharding=NamedSharding(mesh, P()),
-                    ),
-                    tr._init_params,
-                )
-
-            target = target._replace(
-                gns=target.gns._replace(
-                    prev_grad=prev_grad_target(
-                        saved_placeholder is not False
-                    )
-                )
-            )
-        if tr.zero1 and saved_placeholder is None:
-            # Metadata unreadable (likely an older payload): try the
-            # current layout, fall back to the pre-placeholder one —
-            # re-raising the ORIGINAL error if neither fits.
+            for carry in layout.legacy_carries()
+        ]
+        saved = (
+            self._saved_carry_shapes(checkpointer, path)
+            if len(carries) > 1
+            else None
+        )
+        if saved is not None:
+            carries = [
+                c for c in carries
+                if [leaf.shape for leaf in jax.tree.leaves(c)] == saved
+            ] or carries[:1]
+        # Metadata unreadable (likely an older payload): try each
+        # layout, current first — re-raising the ORIGINAL error if
+        # none fits.
+        first_err = None
+        for carry in carries:
             try:
-                restored = checkpointer.restore(path, target)
-            except Exception as first_err:
-                fallback = target._replace(
-                    gns=target.gns._replace(
-                        prev_grad=prev_grad_target(False)
-                    )
+                restored = checkpointer.restore(
+                    path,
+                    target._replace(
+                        gns=target.gns._replace(prev_grad=carry)
+                    ),
                 )
-                try:
-                    restored = checkpointer.restore(path, fallback)
-                except Exception:
-                    raise first_err
+                break
+            except Exception as err:  # noqa: BLE001 - re-raised below
+                first_err = first_err or err
         else:
-            restored = checkpointer.restore(path, target)
-        if tr.zero1:
-            restored = restored._replace(
-                opt_state=self._zero1_expand_device(
-                    restored.opt_state
-                ),
-                # One shared rule (trainer._normalize_gns_layout):
-                # dp>1 -> placeholder layout; dp==1 -> re-materialize
-                # full zeros and let the estimator re-prime.
-                gns=tr._normalize_gns_layout_on_mesh(restored.gns),
-            )
-        if self._trainer.zero3:
-            restored = restored._replace(
-                params=self._zero3_rows_device(restored.params)
-            )
-        if self._trainer.zero3_blocks is not None:
-            restored = restored._replace(
-                params=self._z3b_rows_device(restored.params),
-                opt_state=tr._z3b_map_opt(
-                    restored.opt_state, True, self._z3b_expand_device
-                ),
-                gns=restored.gns._replace(
-                    prev_grad=self._z3b_expand_device(
-                        restored.gns.prev_grad
-                    )
-                ),
-            )
+            raise first_err
+        restored = layout.from_canonical(restored, storage.on_mesh(mesh))
         restored = restored._replace(
             rng=jax.random.wrap_key_data(restored.rng)
         )

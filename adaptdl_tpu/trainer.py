@@ -40,7 +40,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from adaptdl_tpu import checkpoint, gns, trace
+from adaptdl_tpu import checkpoint, gns, storage, trace
 
 _LOG = logging.getLogger(__name__)
 from adaptdl_tpu.parallel.mesh import (
@@ -61,46 +61,6 @@ class TrainState(NamedTuple):
     progress: jnp.ndarray  # scale-invariant steps (advanced by gain)
     step: jnp.ndarray  # raw optimizer steps taken
     rng: jax.Array
-
-
-def _materialize(x, sharding) -> jax.Array:
-    """Place a host/device value onto a (possibly multi-process) mesh.
-
-    ``jax.device_put`` only accepts shardings whose devices are all
-    addressable from this process; on a multi-host mesh each process
-    must instead supply its local shards via
-    ``jax.make_array_from_callback``. PRNG key arrays round-trip
-    through their raw key data (callbacks produce plain arrays).
-    """
-    if isinstance(x, jax.Array) and jax.dtypes.issubdtype(
-        x.dtype, jax.dtypes.prng_key
-    ):
-        data = jax.random.key_data(x)
-        placed = _materialize(np.asarray(jax.device_get(data)), sharding)
-        return jax.random.wrap_key_data(placed)
-    if sharding.is_fully_addressable:
-        if isinstance(x, jax.Array):
-            # Copy: device_put aliases buffers whose sharding already
-            # matches, and the donated train step would then delete
-            # the caller's array out from under them.
-            x = jnp.array(x, copy=True)
-        return jax.device_put(x, sharding)
-    host = np.asarray(jax.device_get(x))
-    return jax.make_array_from_callback(
-        host.shape, sharding, lambda idx: host[idx]
-    )
-
-
-def _find_adam_nu(opt_state) -> Any | None:
-    """Locate Adam's second-moment tree inside an optax state."""
-    if isinstance(opt_state, optax.ScaleByAdamState):
-        return opt_state.nu
-    if isinstance(opt_state, tuple):
-        for child in opt_state:
-            found = _find_adam_nu(child)
-            if found is not None:
-                return found
-    return None
 
 
 class ElasticTrainer:
@@ -221,122 +181,22 @@ class ElasticTrainer:
             self.mesh.shape.get(EXPERT_AXIS, 1),
             self.pipeline_micro,
         )
-        # ZeRO-1 optimizer-state sharding: the flattened parameter
-        # vector is partitioned across the data axis; each replica
-        # holds and updates 1/dp of the optimizer moments (8 bytes/
-        # param under Adam drop to 8/dp) and the updated shards are
-        # reassembled with one scatter+psum. The memory/comm trade:
-        # one extra parameter-sized all-reduce per step buys a
-        # dp-factor cut in optimizer-state HBM — worthwhile exactly
-        # when moments are a real fraction of HBM (large models),
-        # where steps are compute-dominated and the collective rides
-        # ICI under the compute. (ZeRO stage 1, Rajbhandari et al.;
-        # implementation original, built on the flat-vector psum
-        # pattern rather than torch's per-bucket broadcast.)
-        # ZeRO-3-lite: additionally store the PARAMETERS as flat
-        # [dp, shard] rows over the data axis. The step assembles the
-        # full tree on the fly (scatter+psum, the FSDP all-gather) and
-        # the optimizer updates only this replica's row — which also
-        # makes the update path CHEAPER than zero1's (no parameter
-        # reassembly collective after the update; assembly happens
-        # once at step start). Storage per device: params n/dp +
-        # moments 2n/dp, vs n + 2n replicated — the transient full
-        # tree lives only inside the step. Params checkpoint in
-        # canonical TREE form (dp-independent; same layout a dense
-        # trainer writes) while the moments stay flat-canonical, so
-        # like zero1 the flag is part of the job's stable config:
-        # rescales change dp freely, not the zero family.
-        # zero3_blocks: TRUE per-layer ZeRO-3/FSDP. Parameters persist
-        # as per-block flat rows over the data axis and the loss_fn
-        # (written against parallel.zero3.Zero3View) gathers ONE block
-        # at a time inside its layer scan — per-device peak HBM is
-        # params/dp + one gathered block + activations, where the lite
-        # ``zero3=True`` mode still materialises the whole tree at
-        # step start. Gradients arrive reduce-scattered through the
-        # gather's AD transpose, so the GNS runs on per-microbatch
-        # GLOBAL gradients (count = num_microbatches; the differenced
-        # estimator covers accum_steps == 0).
-        self.zero3_blocks = zero3_blocks
-        if zero3_blocks is not None:
-            if zero1 or zero3:
-                raise ValueError(
-                    "zero3_blocks is a storage mode of its own; do not "
-                    "combine with zero1/zero3"
-                )
-            if (
-                param_sharding_fn is not None
-                or MODEL_AXIS in self.mesh.shape
-                or self.sharded_param_axes
-            ):
-                raise ValueError(
-                    "zero3_blocks shards parameter storage over the "
-                    "data axis and composes with data and sequence "
-                    "parallelism only (model/stage/expert axes "
-                    "manage their own layouts)"
-                )
-            if self.num_param_groups > 1:
-                raise ValueError(
-                    "zero3_blocks supports a single param group (the "
-                    "row layout has no per-position group table yet)"
-                )
-            if zero3_blocks not in params:
-                raise ValueError(
-                    f"params has no {zero3_blocks!r} entry to treat as "
-                    "the layer-stacked block family"
-                )
-            from adaptdl_tpu.parallel import zero3 as z3
-
-            self._z3b = z3
-            self._z3b_spec = z3.block_spec(params, zero3_blocks)
-            self._z3b_shard_b, self._z3b_shard_o = z3.shard_sizes(
-                self._z3b_spec, self.num_replicas
-            )
-            from jax.flatten_util import ravel_pytree
-
-            flat_all, unravel_all = ravel_pytree(params)
-            self._z3b_n_total = int(flat_all.size)
-            self._z3b_unravel_full = unravel_all
-        self.zero3 = bool(zero3)
-        self.zero1 = bool(zero1) or self.zero3
-        if self.zero1:
-            if (
-                self.sharded_param_axes
-                or MODEL_AXIS in self.mesh.shape
-                or param_sharding_fn is not None
-            ):
-                raise ValueError(
-                    "zero1 shards optimizer state over the data axis "
-                    "and composes with data/seq parallelism only; "
-                    "stage/expert/model axes manage their own "
-                    "parameter and optimizer layouts"
-                )
-            from jax.flatten_util import ravel_pytree
-
-            flat, unravel = ravel_pytree(params)
-            n = int(flat.size)
-            dp = self.num_replicas
-            pad = (-n) % dp
-            self._zero1_n = n
-            self._zero1_pad = pad
-            self._zero1_shard = (n + pad) // dp
-            self._zero1_unravel = unravel
-            # Flat group-id table for per-position LR factors — only
-            # when groups actually differ: it costs 4 bytes/param of
-            # replicated HBM (the slice start is rank-dynamic, so XLA
-            # can't fold it), which would claw back half the moment
-            # saving in the common single-group case.
-            if self.num_param_groups > 1:
-                gid_runs = [
-                    np.full(int(np.size(leaf)), gid, np.int32)
-                    for leaf, gid in zip(
-                        jax.tree.leaves(params), self._group_ids
-                    )
-                ]
-                self._zero1_flat_gids = np.concatenate(
-                    gid_runs + [np.zeros(pad, np.int32)]
-                )
-            else:
-                self._zero1_flat_gids = None
+        # How params, moments and the GNS carry are stored, on the
+        # mesh and on disk: the three arguments name one of the four
+        # layouts of adaptdl_tpu.storage, which owns everything that
+        # differs between them.
+        self.storage = storage.resolve(
+            zero1=bool(zero1),
+            zero3=bool(zero3),
+            zero3_blocks=zero3_blocks,
+            mesh=self.mesh,
+            params=params,
+            optimizer=optimizer,
+            param_sharding_fn=param_sharding_fn,
+            group_ids=self._group_ids,
+            num_groups=self.num_param_groups,
+            precondition=precondition,
+        )
         self._init_params = params
         self._step_cache: dict[tuple, Callable] = {}
         self._calibrated: set[int] = set()
@@ -394,495 +254,16 @@ class ElasticTrainer:
             return P(DATA_AXIS, SEQ_AXIS)
         return P(DATA_AXIS)
 
-    def _param_spec_tree(self, params):
-        if self.param_sharding_fn is None:
-            return jax.tree.map(lambda _: P(), params)
-        return jax.tree_util.tree_map_with_path(
-            lambda path, leaf: self.param_sharding_fn(path, leaf), params
-        )
-
     def state_spec_tree(self, state: "TrainState"):
-        """PartitionSpec tree for a full TrainState.
-
-        Params take ``param_sharding_fn`` specs; derived trees that
-        mirror the params — optimizer moments, the GNS prev-grad — take
-        the *same* specs, identified by path suffix + shape (an optax
-        ``mu`` leaf's path ends with the corresponding param's path).
-        Everything else (counts, EMA scalars, rng, progress) is
-        replicated.
-        """
-        if self.zero3_blocks is not None:
-            # Rows dicts (params, moments, prev_grad) shard over the
-            # data axis; everything else replicates. Matching is by
-            # shape, like zero1's moment matcher.
-            dp = self.num_replicas
-            L = self._z3b_spec.num_blocks
-            blocks_shape = (L, dp, self._z3b_shard_b)
-            other_shape = (dp, self._z3b_shard_o)
-
-            def spec_for(leaf):
-                shp = np.shape(leaf)
-                if shp == blocks_shape:
-                    return P(None, DATA_AXIS)
-                if shp == other_shape:
-                    return P(DATA_AXIS)
-                return P()
-
-            return jax.tree.map(spec_for, state)
-        if self.zero1:
-            # zero1 excludes param_sharding_fn (checked in __init__):
-            # every leaf replicates except the sharded moment rows —
-            # and, under zero3, the params rows themselves.
-            base = jax.tree.map(lambda _: P(), state)._replace(
-                opt_state=self._zero1_opt_specs(state.opt_state)
-            )
-            rows_shape = (self.num_replicas, self._zero1_shard)
-            if (
-                self.zero3
-                and getattr(state.params, "shape", None) == rows_shape
-            ):
-                base = base._replace(params=P(DATA_AXIS))
-            return base
-        if self.param_sharding_fn is None:
-            return jax.tree.map(lambda _: P(), state)
-        param_leaves = jax.tree_util.tree_flatten_with_path(state.params)[0]
-        spec_leaves = jax.tree.leaves(
-            self._param_spec_tree(state.params),
-            is_leaf=lambda x: isinstance(x, P),
-        )
-        matchers = [
-            (tuple(path), np.shape(leaf), spec)
-            for (path, leaf), spec in zip(param_leaves, spec_leaves)
-        ]
-
-        def assign(path, leaf):
-            path = tuple(path)
-            for ppath, shape, spec in matchers:
-                if (
-                    len(path) >= len(ppath)
-                    and path[-len(ppath):] == ppath
-                    and np.shape(leaf) == shape
-                ):
-                    return spec
-            return P()
-
-        return jax.tree_util.tree_map_with_path(assign, state)
-
-    def _tree_to_rows(self, params):
-        """Param tree -> padded flat ``[dp, shard]`` rows (the zero1/
-        zero3 run layout). Traceable; works on host or under jit."""
-        from jax.flatten_util import ravel_pytree
-
-        flat, _ = ravel_pytree(params)
-        if self._zero1_pad:
-            flat = jnp.concatenate(
-                [flat, jnp.zeros((self._zero1_pad,), flat.dtype)]
-            )
-        return flat.reshape(self.num_replicas, self._zero1_shard)
-
-    def _init_opt_state(self, params):
-        """Optimizer state in the run layout: the param tree normally;
-        under zero1, the optimizer is initialized over the padded flat
-        parameter vector reshaped ``[dp, shard]`` so its moment leaves
-        shard ``P("data")`` (dim 0) and each replica owns one row.
-        Works for elementwise transforms (the Adam/SGD families);
-        norm-based transforms (clip_by_global_norm) would see
-        shard-local norms and are unsupported under zero1. Accepts
-        params already in rows layout (zero3 states)."""
-        if not self.zero1:
-            return self.optimizer.init(params)
-        rows_shape = (self.num_replicas, self._zero1_shard)
-        if getattr(params, "shape", None) == rows_shape:
-            rows = params
-        else:
-            rows = self._tree_to_rows(params)
-        return self.optimizer.init(rows)
-
-    def _rows_to_flat(self, rows_local):
-        """Inside the manual step: this replica's ``[1, shard]`` row
-        -> the full ``[n]`` flat vector. Scatter + psum over the data
-        axis (psum output is typed invariant under the vma system,
-        which a tiled all_gather is not)."""
-        full = jnp.zeros(
-            (self.num_replicas * self._zero1_shard,),
-            rows_local.dtype,
-        )
-        full = jax.lax.pcast(full, DATA_AXIS, to="varying")
-        rank = jax.lax.axis_index(DATA_AXIS)
-        full = jax.lax.dynamic_update_slice(
-            full, rows_local[0], (rank * self._zero1_shard,)
-        )
-        return jax.lax.psum(full, DATA_AXIS)[: self._zero1_n]
-
-    def _zero1_opt_specs(self, opt_state):
-        dp = self.num_replicas
-        shard = self._zero1_shard
-        return jax.tree.map(
-            lambda leaf: (
-                P(DATA_AXIS)
-                if np.shape(leaf) == (dp, shard)
-                else P()
-            ),
-            opt_state,
-        )
-
-    def _zero1_map_opt(self, opt_state, from_canonical: bool, convert):
-        """THE single definition of which optimizer leaves carry the
-        zero1 moment layout: canonical ``[n]`` vectors when
-        ``from_canonical``, run-layout ``[dp, shard]`` rows otherwise.
-        Every canonical<->run conversion (host pickle path here,
-        device orbax path in sharded_checkpoint) goes through this
-        matcher with its own ``convert``, so the on-disk layout and
-        the leaf-identification rule cannot drift between paths."""
-        match_shape = (
-            (self._zero1_n,)
-            if from_canonical
-            else (self.num_replicas, self._zero1_shard)
-        )
-        return jax.tree.map(
-            lambda leaf: (
-                convert(leaf)
-                if np.shape(leaf) == match_shape
-                else leaf
-            ),
-            opt_state,
-        )
-
-    def _zero1_canonical_opt(self, opt_state):
-        """Host opt state, run layout -> canonical disk layout: the
-        [dp, shard] moment rows flatten to one [n] vector (pad
-        trimmed) so a different-dp incarnation can restore them —
-        the zero1 analog of the pipeline family's layer-major
-        canonical checkpoints."""
-        dp, shard, n = (
-            self.num_replicas, self._zero1_shard, self._zero1_n,
-        )
-        return self._zero1_map_opt(
-            opt_state,
-            False,
-            lambda leaf: np.asarray(leaf).reshape(dp * shard)[:n],
-        )
-
-    def _zero1_expand_opt(self, opt_state):
-        """Canonical [n] moment vectors -> this trainer's [dp, shard]
-        rows (re-padded for the current replica count)."""
-        dp, shard, pad = (
-            self.num_replicas, self._zero1_shard, self._zero1_pad,
-        )
-
-        def expand(leaf):
-            flat = np.asarray(leaf)
-            if pad:
-                flat = np.concatenate(
-                    [flat, np.zeros(pad, flat.dtype)]
-                )
-            return flat.reshape(dp, shard)
-
-        return self._zero1_map_opt(opt_state, True, expand)
-
-    def _zero3_canonical_params(self, rows):
-        """Host params, run layout -> canonical disk layout: the
-        [dp, shard] rows unravel back to the parameter TREE, so the
-        on-disk format is dp-independent (and identical to a dense
-        trainer's param layout)."""
-        dp, shard, n = (
-            self.num_replicas, self._zero1_shard, self._zero1_n,
-        )
-        flat = np.asarray(rows).reshape(dp * shard)[:n]
-        tree = self._zero1_unravel(jnp.asarray(flat))
-        return jax.tree.map(np.asarray, tree)
-
-    def _zero3_rows_from_tree(self, tree):
-        """Canonical param tree -> this trainer's [dp, shard] rows
-        (host wrapper over the single layout definition)."""
-        return np.asarray(
-            self._tree_to_rows(jax.tree.map(jnp.asarray, tree))
-        )
-
-    # ---- zero3_blocks (per-layer FSDP) layout plumbing ---------------
-    #
-    # Storage: params (and every params-shaped mirror: optimizer
-    # moments, the GNS prev_grad carry) live as the rows dict
-    #     {"blocks": [L, dp, shard_b], "other": [dp, shard_o]}
-    # sharded P(None, "data") / P("data") — each device persistently
-    # holds 1/dp of every tensor. Canonical disk layouts match the
-    # zero1/zero3-lite family: params as the plain TREE, derived
-    # mirrors as the flat [n] vector in ravel_pytree(tree) order, so
-    # rescales change dp freely and may even cross storage modes.
-
-    def _z3b_rows_from_tree(self, tree):
-        """Canonical param tree -> rows dict (traceable)."""
-        blocks_rows, other_rows = self._z3b.tree_to_rows(
-            tree, self.zero3_blocks, self._z3b_spec, self.num_replicas
-        )
-        return {"blocks": blocks_rows, "other": other_rows}
-
-    def _z3b_tree_from_rows(self, rows):
-        """Rows dict -> canonical param tree (traceable)."""
-        return self._z3b.rows_to_tree(
-            rows["blocks"], rows["other"], self.zero3_blocks,
-            self._z3b_spec,
-        )
-
-    def _z3b_build_state(self) -> "TrainState":
-        """THE single zero3_blocks TrainState constructor (traceable):
-        rows-layout params, moments, and GNS carry. Both
-        ``_abstract_state`` (spec derivation) and ``init_state`` (the
-        born-sharded jit) call this, so the abstract specs can never
-        diverge from the real state."""
-        rows = self._z3b_rows_from_tree(
-            jax.tree.map(jnp.asarray, self._init_params)
-        )
-        return TrainState(
-            params=rows,
-            opt_state=self.optimizer.init(rows),
-            gns=gns.init(rows, self.num_param_groups),
-            progress=jnp.zeros(()),
-            step=jnp.zeros((), jnp.int32),
-            rng=jax.random.key(self._seed),
-        )
-
-    def _z3b_is_rows(self, node) -> bool:
-        """Recognize a rows-dict mirror inside an arbitrary state tree
-        (the optax moments that track the params' structure)."""
-        return (
-            isinstance(node, dict)
-            and set(node) == {"blocks", "other"}
-            and np.shape(node.get("blocks"))
-            == (
-                self._z3b_spec.num_blocks,
-                self.num_replicas,
-                self._z3b_shard_b,
-            )
-            and np.shape(node.get("other"))
-            == (self.num_replicas, self._z3b_shard_o)
-        )
-
-    def _z3b_canonical_params(self, rows):
-        """Host rows dict -> canonical param TREE (dp-independent, the
-        same layout a dense trainer checkpoints)."""
-        return jax.tree.map(
-            np.asarray,
-            self._z3b_tree_from_rows(
-                jax.tree.map(jnp.asarray, dict(rows))
-            ),
-        )
-
-    def _z3b_map_opt(self, opt_state, from_canonical: bool, convert):
-        """THE single matcher for zero3_blocks optimizer-state layout
-        conversions — rows dicts on the run side, flat [n] canonical
-        vectors on disk (identical to zero1's moment layout, so lite
-        and blocks checkpoints interchange)."""
-        if from_canonical:
-            n = (self._z3b_n_total,)
-            return jax.tree.map(
-                lambda leaf: (
-                    convert(leaf) if np.shape(leaf) == n else leaf
-                ),
-                opt_state,
-            )
-        return jax.tree.map(
-            lambda node: (
-                convert(node) if self._z3b_is_rows(node) else node
-            ),
-            opt_state,
-            is_leaf=self._z3b_is_rows,
-        )
-
-    def _z3b_flat_canonical(self, rows):
-        """Rows dict -> flat [n] canonical vector (host)."""
-        return np.asarray(
-            self._z3b.rows_to_flat_canonical(
-                jnp.asarray(rows["blocks"]),
-                jnp.asarray(rows["other"]),
-                self.zero3_blocks,
-                self._z3b_spec,
-            )
-        )
-
-    def _z3b_rows_from_flat(self, flat):
-        """Flat [n] canonical vector -> rows dict for THIS dp (host)."""
-        blocks_rows, other_rows = self._z3b.flat_canonical_to_rows(
-            flat, self.zero3_blocks, self._z3b_spec,
-            self.num_replicas, self._z3b_unravel_full,
-        )
-        return {
-            "blocks": np.asarray(blocks_rows),
-            "other": np.asarray(other_rows),
-        }
-
-    def _z3b_rows_from_tree_host(self, tree):
-        """Canonical param tree -> rows dict, host numpy (checkpoint
-        restore for THIS trainer's dp)."""
-        return jax.tree.map(
-            np.asarray,
-            self._z3b_rows_from_tree(
-                jax.tree.map(jnp.asarray, tree)
-            ),
-        )
-
-    def _z3b_canonical_opt(self, opt_state):
-        return self._z3b_map_opt(
-            opt_state, False, self._z3b_flat_canonical
-        )
-
-    def _z3b_is_param_tree(self, node) -> bool:
-        """Recognize a params-TREE-shaped mirror (what a dense
-        trainer's checkpoint stores for Adam's mu/nu) so cross-mode
-        restores convert it to rows instead of leaving a structure
-        mismatch for the first step to trip over."""
-        try:
-            if jax.tree_util.tree_structure(
-                node
-            ) != jax.tree_util.tree_structure(self._init_params):
-                return False
-        except Exception:  # noqa: BLE001 - unregistered node types
-            return False
-        return all(
-            np.shape(a) == np.shape(b)
-            for a, b in zip(
-                jax.tree.leaves(node),
-                jax.tree.leaves(self._init_params),
-            )
-        )
-
-    def _z3b_expand_opt(self, opt_state):
-        """Canonical moments -> rows dicts. Accepts BOTH canonical
-        layouts: flat [n] vectors (zero family checkpoints) and plain
-        param trees (a dense trainer's checkpoint crossing into
-        blocks mode)."""
-        n = (self._z3b_n_total,)
-
-        def is_match(node):
-            # getattr, not np.shape: is_leaf probes container nodes
-            # too, and np.asarray on ragged containers can throw.
-            return getattr(
-                node, "shape", None
-            ) == n or self._z3b_is_param_tree(node)
-
-        def convert(node):
-            if self._z3b_is_param_tree(node):
-                return self._z3b_rows_from_tree_host(node)
-            return self._z3b_rows_from_flat(node)
-
-        return jax.tree.map(
-            lambda node: convert(node) if is_match(node) else node,
-            opt_state,
-            is_leaf=is_match,
-        )
-
-    def _empty_prev_grad(self):
-        """zero1/zero3 at dp > 1: the GNS differenced-estimator carry
-        (prev_grad, a full f32 param-sized tree) backs ONLY the dp==1
-        single-sample estimator — at dp > 1 gns.update's count>1
-        branch never reads it, so persisting it replicated would
-        silently claw back the memory the zero family sheds. Store
-        one-element placeholder leaves instead ((1,), not (0,):
-        orbax refuses zero-size arrays)."""
-        return jax.tree.map(
-            lambda _: jnp.zeros((1,), jnp.float32), self._init_params
-        )
-
-    def _empty_prev_grad_host(self):
-        """Host-numpy form of the placeholder layout (checkpoint
-        canonicalization paths)."""
-        return jax.tree.map(
-            lambda _: np.zeros((1,), np.float32), self._init_params
-        )
-
-    def _empty_prev_grad_replicated(self):
-        """The placeholder layout placed replicated on THIS mesh
-        (multi-process safe: built under jit with out_shardings, never
-        as host-local arrays orbax would refuse to serialize)."""
-        out_sh = jax.tree.map(
-            lambda _: NamedSharding(self.mesh, P()),
-            jax.eval_shape(self._empty_prev_grad),
-        )
-        return jax.jit(
-            self._empty_prev_grad, out_shardings=out_sh
-        )()
-
-    def _normalize_gns_layout(self, gns_state):
-        """Restore-time prev_grad layout fix-up: canonical checkpoints
-        store it EMPTY under the zero family; a dp==1 trainer (the only
-        reader) re-materializes zeros and invalidates the carry so the
-        differenced estimator re-primes on its next step."""
-        if not self.zero1:
-            return gns_state
-
-        def is_marker(leaf, param):
-            # A (1,) leaf standing in for a differently-shaped param.
-            return (
-                np.shape(leaf) == (1,) and np.shape(param) != (1,)
-            )
-
-        if self.num_replicas > 1:
-            # The carry is never read at dp>1: placeholder layout,
-            # whatever came in.
-            return gns_state._replace(
-                prev_grad=self._empty_prev_grad_host()
-            )
-        markers = [
-            is_marker(leaf, param)
-            for leaf, param in zip(
-                jax.tree.leaves(gns_state.prev_grad),
-                jax.tree.leaves(self._init_params),
-            )
-        ]
-        if not any(markers):
-            return gns_state
-        return gns_state._replace(
-            prev_grad=jax.tree.map(
-                lambda p: np.zeros(np.shape(p), np.float32),
-                self._init_params,
-            ),
-            prev_grad_valid=np.zeros((), bool),
-        )
-
-    def _normalize_gns_layout_on_mesh(self, gns_state):
-        """:meth:`_normalize_gns_layout` with any rebuilt leaves placed
-        replicated on this trainer's mesh (multi-process safe) — the
-        single re-prime/placeholder rule shared by the pickle and
-        orbax restore paths."""
-        normalized = self._normalize_gns_layout(gns_state)
-        if normalized is gns_state:
-            return gns_state
-        sharding = NamedSharding(self.mesh, P())
-
-        def place(x):
-            if isinstance(x, jax.Array):
-                return x
-            return _materialize(np.asarray(x), sharding)
-
-        return normalized._replace(
-            prev_grad=jax.tree.map(place, normalized.prev_grad),
-            prev_grad_valid=place(normalized.prev_grad_valid),
-        )
+        """PartitionSpec tree for a full TrainState in run layout."""
+        return self.storage.state_specs(state)
 
     def _abstract_state(self) -> "TrainState":
         """Shape/structure skeleton of the TrainState (no devices):
         what spec-tree construction needs before any state exists."""
 
         def build():
-            params = self._init_params
-            if self.zero3_blocks is not None:
-                # Rows-layout state throughout: params, moments, and
-                # the GNS prev_grad (the differenced-estimator carry is
-                # LIVE at any dp under zero3_blocks — count is the
-                # microbatch count, not dp*microbatches — and in rows
-                # layout it costs n/dp per device, not n).
-                return self._z3b_build_state()
-            opt_state = self._init_opt_state(params)
-            gns_state = gns.init(params, self.num_param_groups)
-            if self.zero1 and self.num_replicas > 1:
-                # prev_grad backs only the dp==1 differenced
-                # estimator; at dp>1 keep it empty (see
-                # _empty_prev_grad).
-                gns_state = gns_state._replace(
-                    prev_grad=self._empty_prev_grad()
-                )
-            if self.zero3:
-                params = self._tree_to_rows(params)
+            params, opt_state, gns_state = self.storage.build()
             return TrainState(
                 params=params,
                 opt_state=opt_state,
@@ -894,39 +275,8 @@ class ElasticTrainer:
 
         return jax.eval_shape(build)
 
-    @staticmethod
-    def _restrict_specs(specs, manual_axes: set):
-        """Keep only the shard_map's MANUAL axes in a spec tree:
-        pipeline-stage components stay (they are sharded inside the
-        step), model-axis components drop (GSPMD auto handles them)."""
-
-        def restrict(spec):
-            kept = []
-            for part in spec or ():
-                if part is None:
-                    kept.append(None)
-                    continue
-                # A dim may be sharded over SEVERAL axes at once
-                # (tuple entry, e.g. ("stage", "model")): filter
-                # inside it rather than dropping the whole entry.
-                axes = (part,) if isinstance(part, str) else tuple(part)
-                axes = tuple(a for a in axes if a in manual_axes)
-                if not axes:
-                    kept.append(None)
-                elif len(axes) == 1:
-                    kept.append(axes[0])
-                else:
-                    kept.append(axes)
-            while kept and kept[-1] is None:
-                kept.pop()
-            return P(*kept)
-
-        return jax.tree.map(
-            restrict, specs, is_leaf=lambda x: isinstance(x, P)
-        )
-
     def _manual_state_specs(self, manual_axes: set):
-        return self._restrict_specs(
+        return storage.restrict_specs(
             self.state_spec_tree(self._abstract_state()), manual_axes
         )
 
@@ -944,341 +294,18 @@ class ElasticTrainer:
         return state
 
     def _init_state(self) -> TrainState:
-        def put(x, spec):
-            return _materialize(x, NamedSharding(self.mesh, spec))
+        def put(x):
+            return storage.materialize(x, NamedSharding(self.mesh, P()))
 
-        if self.zero3_blocks is not None:
-            # Born sharded: one jit with rows out_shardings so params,
-            # moments, and prev_grad land as [.., dp, shard] rows over
-            # the data axis and never exist replicated on device. (The
-            # init TREE itself is a replicated host constant — the
-            # transient any fresh init or checkpoint load pays; the
-            # per-STEP bound is what zero3_blocks guarantees.)
-            abstract = self._abstract_state()
-            out_sh = jax.tree.map(
-                lambda s: NamedSharding(self.mesh, s),
-                self.state_spec_tree(abstract),
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            return jax.jit(
-                self._z3b_build_state, out_shardings=out_sh
-            )()
-
-        specs = self._param_spec_tree(self._init_params)
-        params = jax.tree.map(put, self._init_params, specs)
-        # Optimizer moments follow the params' layout: eager
-        # zeros_like on a sharded array preserves its sharding. Under
-        # zero1 the moments are flat [dp, shard] rows placed P("data").
-        if self.zero1:
-            # Born sharded: jit with out_shardings so the moment rows
-            # never exist replicated — an eager init would transiently
-            # hold params + flat copy + both replicated moments per
-            # device, an OOM risk at exactly the scale zero1 targets.
-            abstract = jax.eval_shape(self._init_opt_state, params)
-            out_sh = jax.tree.map(
-                lambda s: NamedSharding(self.mesh, s),
-                self._zero1_opt_specs(abstract),
-            )
-            opt_state = jax.jit(
-                self._init_opt_state, out_shardings=out_sh
-            )(params)
-        else:
-            # Leaves the optimizer creates itself (Adam's step count)
-            # land on the default device only: replicate them over the
-            # mesh like every other leaf, so that a fresh state has
-            # exactly the placement a restored one gets — the AOT
-            # executable cache keys on it, and incarnation 0's entry
-            # must serve incarnation 1.
-            opt_state = jax.tree.map(
-                lambda x: x
-                if isinstance(x.sharding, NamedSharding)
-                else put(x, P()),
-                self._init_opt_state(params),
-            )
-        gns_state = gns.init(params, self.num_param_groups)
-        if self.zero1 and self.num_replicas > 1:
-            gns_state = gns_state._replace(
-                prev_grad=self._empty_prev_grad()
-            )
-            prev_specs = jax.tree.map(
-                lambda _: P(), gns_state.prev_grad
-            )
-        else:
-            prev_specs = specs
-        gns_state = gns_state._replace(
-            prev_grad=jax.tree.map(
-                put, gns_state.prev_grad, prev_specs
-            ),
-            sqr_biased=put(gns_state.sqr_biased, P()),
-            sqr_unbias=put(gns_state.sqr_unbias, P()),
-            var_biased=put(gns_state.var_biased, P()),
-            var_unbias=put(gns_state.var_unbias, P()),
-            ema_is_biased=put(gns_state.ema_is_biased, P()),
-            prev_grad_valid=put(gns_state.prev_grad_valid, P()),
-        )
-        if self.zero3:
-            # Params born sharded too: each device ends with only its
-            # [1, shard] row (the replicated tree above was needed to
-            # seed the optimizer/GNS mirrors and is dropped here).
-            params = jax.jit(
-                self._tree_to_rows,
-                out_shardings=NamedSharding(self.mesh, P(DATA_AXIS)),
-            )(params)
+        params, opt_state, gns_state = self.storage.init()
         return TrainState(
             params=params,
             opt_state=opt_state,
             gns=gns_state,
-            progress=put(jnp.zeros((), jnp.float32), P()),
-            step=put(jnp.zeros((), jnp.int32), P()),
-            rng=put(jax.random.key(self._seed), P()),
+            progress=put(jnp.zeros((), jnp.float32)),
+            step=put(jnp.zeros((), jnp.int32)),
+            rng=put(jax.random.key(self._seed)),
         )
-
-    def _precond(self, opt_state):
-        if self.precondition != "adam":
-            return None
-        nu = _find_adam_nu(opt_state)
-        if nu is None:
-            raise ValueError(
-                "precondition='adam' but optimizer state has no "
-                "ScaleByAdamState"
-            )
-        return jax.tree.map(
-            lambda v: jnp.sqrt(jnp.maximum(v, 0.0)) + 1e-8, nu
-        )
-
-    def _zero1_precond(self, opt_state_local):
-        """Preconditioner under zero1, inside the manual step: each
-        replica holds one [1, shard] row of Adam's nu; reassemble the
-        param-shaped tree with the same scatter+psum the parameter
-        update uses, then take sqrt."""
-        if self.precondition != "adam":
-            return None
-        nu_local = _find_adam_nu(opt_state_local)
-        if nu_local is None:
-            raise ValueError(
-                "precondition='adam' but optimizer state has no "
-                "ScaleByAdamState"
-            )
-        nu_tree = self._zero1_unravel(self._rows_to_flat(nu_local))
-        return jax.tree.map(
-            lambda v: jnp.sqrt(
-                jnp.maximum(v.astype(jnp.float32), 0.0)
-            )
-            + 1e-8,
-            nu_tree,
-        )
-
-    def _z3b_varying_axes(self) -> tuple:
-        """The zero3_blocks model's full varying set: gathered values
-        (and activations) vary over data plus, under sequence
-        parallelism, seq — THE single definition every z3b builder
-        (train step, eval, compute-only calibration) shares."""
-        if self.seq_shards > 1:
-            return (DATA_AXIS, SEQ_AXIS)
-        return (DATA_AXIS,)
-
-    def _z3b_precond(self, opt_state_local):
-        """Preconditioner under zero3_blocks: Adam's nu is a rows-dict
-        mirror; this device's local rows precondition this device's
-        row-space gradients directly — no reassembly (globally
-        consistent: the rows ARE the true nu shards)."""
-        if self.precondition != "adam":
-            return None
-        nu_local = _find_adam_nu(opt_state_local)
-        if nu_local is None:
-            raise ValueError(
-                "precondition='adam' but optimizer state has no "
-                "ScaleByAdamState"
-            )
-        return jax.tree.map(
-            lambda v: jnp.sqrt(
-                jnp.maximum(v.astype(jnp.float32), 0.0)
-            )
-            + 1e-8,
-            nu_local,
-        )
-
-    def _build_step_z3b(self, atomic_bsz: int, accum_steps: int):
-        """The zero3_blocks train step (per-layer FSDP).
-
-        Differs from the dense/zero1 step in one structural way: the
-        loss is differentiated directly with respect to this device's
-        ROW storage. The forward gathers parameters (the non-block
-        subtree once, each block inside the model's layer scan), so
-        the AD transpose hands back cotangents that are already
-        globally SUMMED over the data axis and scattered to each
-        device's own rows — FSDP's reduce-scatter, for free. Two
-        consequences:
-
-        - No gradient pmean: dividing the row cotangent by dp IS the
-          fully averaged gradient. The optimizer runs on local rows.
-        - The GNS sees only per-microbatch GLOBAL gradients (the
-          per-replica signal is consumed by the reduce-scatter), so
-          ``count = num_microbatches`` — the estimator pairs batch
-          sizes (dp*atomic, full) instead of (atomic, full) — and at
-          accum_steps == 0 the differenced estimator takes over, its
-          prev_grad carry held in rows layout (n/dp per device).
-        """
-        z3 = self._z3b
-        spec = self._z3b_spec
-        num_replicas = self.num_replicas
-        seq_shards = self.seq_shards
-        # The model's full varying set: a seq-sharded group is one
-        # logical replica whose members hold pieces of the same batch
-        # rows; gathered values vary over both axes, but the rows and
-        # their cotangents stay seq-invariant (the +seq pcast's
-        # transpose psums the seq shards before the reduce-scatter).
-        varying_axes = self._z3b_varying_axes()
-        grad_divisor = num_replicas * seq_shards
-        num_micro = accum_steps + 1
-        count = num_micro
-        accum_scale = num_replicas * atomic_bsz / self.init_batch_size
-        scale = accum_scale * num_micro
-        batch_size = num_replicas * num_micro * atomic_bsz
-
-        def rows_normsqr(tree, pre=None):
-            """Squared norm of a row-space tree, psum'd over the data
-            axis: each device's rows are a disjoint shard of the flat
-            gradient, so the sum of local squared norms is the global
-            squared norm (pad positions carry zero cotangent)."""
-            ids = tuple(0 for _ in jax.tree.leaves(tree))
-            out = gns.group_normsqr(tree, ids, 1, pre)
-            return jax.lax.psum(out, DATA_AXIS)
-
-        def per_replica_step(state: TrainState, local_batch, aux):
-            rows = state.params  # {"blocks":[L,1,sb], "other":[1,so]}
-            precond = self._z3b_precond(state.opt_state)
-            rng = jax.random.fold_in(state.rng, state.step)
-            rng = jax.random.fold_in(
-                rng, jax.lax.axis_index(DATA_AXIS)
-            )
-            if seq_shards > 1:
-                rng = jax.random.fold_in(
-                    rng, jax.lax.axis_index(SEQ_AXIS)
-                )
-            micro_batches = jax.tree.map(
-                lambda x: x.reshape(
-                    (num_micro, atomic_bsz) + x.shape[1:]
-                ),
-                local_batch,
-            )
-            micro_rngs = jax.random.split(rng, num_micro)
-
-            def loss_of_rows(r, mb, mb_rng):
-                view = z3.build_view(
-                    r["blocks"], r["other"], spec,
-                    varying_axes=varying_axes,
-                )
-                if self.has_aux:
-                    return self.loss_fn(view, mb, mb_rng, aux)
-                return self.loss_fn(view, mb, mb_rng)
-
-            def micro_step(carry, inputs):
-                grad_sum, lsqr_sum, loss_sum = carry
-                mb, mb_rng = inputs
-                loss, grad = jax.value_and_grad(loss_of_rows)(
-                    rows, mb, mb_rng
-                )
-                # The row cotangent is the SUM over every device (seq
-                # shards psum'd by the pcast transpose, data replicas
-                # by the reduce-scatter) of the per-device mean-loss
-                # gradient; /(dp*sp) makes it this microbatch's global
-                # mean gradient.
-                grad = jax.tree.map(
-                    lambda g: g / grad_divisor, grad
-                )
-                grad_sum = jax.tree.map(jnp.add, grad_sum, grad)
-                # Per-microbatch GLOBAL squared norm (invariant after
-                # the psum inside rows_normsqr).
-                lsqr_sum = lsqr_sum + rows_normsqr(grad, precond)
-                return (grad_sum, lsqr_sum, loss_sum + loss), None
-
-            grad_init = jax.tree.map(
-                lambda p: (p * 0.0).astype(jnp.float32), rows
-            )
-            lsqr_init = jnp.zeros((1,))
-            loss_init = jax.lax.pcast(
-                jnp.zeros(()), varying_axes, to="varying"
-            )
-            init = (grad_init, lsqr_init, loss_init)
-            (grad_sum, lsqr_sum, loss_sum), _ = jax.lax.scan(
-                micro_step, init, (micro_batches, micro_rngs)
-            )
-            # Already globally averaged over replicas; average the
-            # microbatches. No pmean — the collective already happened
-            # inside AD.
-            grads = jax.tree.map(lambda g: g / num_micro, grad_sum)
-            local_sqr_mean = lsqr_sum / num_micro
-            loss = jax.lax.pmean(loss_sum / num_micro, varying_axes)
-
-            new_gns = gns.update(
-                state.gns,
-                grads,
-                local_sqr_mean,
-                count=count,
-                accum_scale=accum_scale,
-                num_microbatches=num_micro,
-                smoothing=self.smoothing,
-                precond=precond,
-                group_ids=tuple(
-                    0 for _ in jax.tree.leaves(grads)
-                ),
-                num_groups=1,
-                normsqr_fn=rows_normsqr,
-            )
-            step_gain = gns.gain(new_gns, scale)
-            ctx = RuleContext(
-                scale=scale,
-                batch_size=batch_size,
-                init_batch_size=self.init_batch_size,
-                gns_state=new_gns,
-                progress=state.progress,
-            )
-            lr_factor = self.scaling_rule.lr_factor(ctx)
-            group_factors = self.scaling_rule.lr_factor_groups(ctx)
-            updates, new_opt_state = self.optimizer.update(
-                grads, state.opt_state, rows
-            )
-            updates = jax.tree.map(
-                lambda u: (
-                    u.astype(jnp.float32) * group_factors[0]
-                ).astype(u.dtype),
-                updates,
-            )
-            new_rows = optax.apply_updates(rows, updates)
-            new_state = TrainState(
-                params=new_rows,
-                opt_state=new_opt_state,
-                gns=new_gns,
-                progress=state.progress + step_gain,
-                step=state.step + 1,
-                rng=state.rng,
-            )
-            metrics = {
-                "loss": loss,
-                "gain": step_gain,
-                "lr_factor": lr_factor,
-                "grad_sqr": gns.sqr_avg(new_gns),
-                "grad_var": gns.var_avg(new_gns),
-                "progress": new_state.progress,
-                "scale": jnp.asarray(scale, jnp.float32),
-            }
-            return new_state, metrics
-
-        batch_spec = (
-            P(DATA_AXIS, SEQ_AXIS) if seq_shards > 1 else P(DATA_AXIS)
-        )
-        manual = {DATA_AXIS}
-        if seq_shards > 1:
-            manual.add(SEQ_AXIS)
-        state_specs = self._manual_state_specs(manual)
-        sharded = jax.shard_map(
-            per_replica_step,
-            mesh=self.mesh,
-            in_specs=(state_specs, batch_spec, P()),
-            out_specs=(state_specs, P()),
-        )
-        return self._finalize_step(sharded, (atomic_bsz, accum_steps))
 
     def _aot_wrap(self, stepped_pair, key) -> Callable:
         """First-call AOT fast path over a 3-arg jitted step: consult
@@ -1378,136 +405,31 @@ class ElasticTrainer:
         return self._step_cache[key]
 
     def _build_step(self, atomic_bsz: int, accum_steps: int):
-        if self.zero3_blocks is not None:
-            return self._build_step_z3b(atomic_bsz, accum_steps)
+        """THE step skeleton. What differs between storage layouts —
+        what the loss is differentiated against, how the gradient is
+        reduced, the GNS squared norm and count, the preconditioner,
+        the optimizer update — is asked of ``self.storage``."""
+        layout = self.storage
         num_replicas = self.num_replicas
         seq_shards = self.seq_shards
         sharded_axes = self.sharded_param_axes
         num_micro = accum_steps + 1
-        count = num_replicas * num_micro
         accum_scale = num_replicas * atomic_bsz / self.init_batch_size
         scale = accum_scale * num_micro
         batch_size = num_replicas * num_micro * atomic_bsz
 
-        # Per-leaf psum axes for gradient-norm statistics: a leaf
-        # sharded over stage/expert contributes a psum'd term; a
-        # replicated leaf's gradient is already complete on every
-        # device (vma auto-psums its cotangents over those axes) and
-        # must not be double-counted.
-        param_manual_specs = self._restrict_specs(
-            self._param_spec_tree(self._init_params), set(sharded_axes)
-        )
-        leaf_psum_axes = tuple(
-            tuple(
-                axis
-                for part in (spec or ())
-                if part is not None
-                for axis in (
-                    (part,) if isinstance(part, str) else tuple(part)
-                )
-                if axis in sharded_axes
-            )
-            for spec in jax.tree.leaves(
-                param_manual_specs, is_leaf=lambda x: isinstance(x, P)
-            )
-        )
-
-        def stat_normsqr(tree, pre=None):
-            return gns.sharded_group_normsqr(
-                tree,
-                self._group_ids,
-                self.num_param_groups,
-                leaf_psum_axes,
-                pre,
-            )
-
-        def zero1_update(grads, opt_local, params, p_rows, group_factors):
-            """ZeRO-1/3 sharded optimizer step: slice this replica's
-            row of the flat gradient vector, update it against the
-            local [1, shard] moment row, and apply the per-position
-            group LR factor. Under zero1 the full parameter vector is
-            then reassembled with scatter + psum (typed invariant over
-            the data axis, which a tiled all_gather is not under the
-            vma system); under zero3 the updated row IS the new
-            parameter state — no reassembly collective at all (the
-            next step's assembly does that work once)."""
-            from jax.flatten_util import ravel_pytree
-
-            shard = self._zero1_shard
-            pad = self._zero1_pad
-            flat_g, _ = ravel_pytree(grads)
-            if pad:
-                flat_g = jnp.concatenate(
-                    [flat_g, jnp.zeros((pad,), flat_g.dtype)]
-                )
-            rank = jax.lax.axis_index(DATA_AXIS)
-            start = rank * shard
-            g_sh = jax.lax.dynamic_slice(flat_g, (start,), (shard,))[
-                None
-            ]
-            if self.zero3:
-                p_sh = p_rows  # the local [1, shard] row, as stored
-                unravel_p = None
-            else:
-                flat_p, unravel_p = ravel_pytree(params)
-                if pad:
-                    flat_p = jnp.concatenate(
-                        [flat_p, jnp.zeros((pad,), flat_p.dtype)]
-                    )
-                p_sh = jax.lax.dynamic_slice(
-                    flat_p, (start,), (shard,)
-                )[None]
-            updates_sh, new_opt = self.optimizer.update(
-                g_sh, opt_local, p_sh
-            )
-            if self._zero1_flat_gids is None:
-                factor_sh = group_factors[0]
-            else:
-                gid_sh = jax.lax.dynamic_slice(
-                    jnp.asarray(self._zero1_flat_gids),
-                    (start,),
-                    (shard,),
-                )
-                factor_sh = group_factors[gid_sh][None]
-            updates_sh = (
-                updates_sh.astype(jnp.float32) * factor_sh
-            ).astype(updates_sh.dtype)
-            new_p_sh = optax.apply_updates(p_sh, updates_sh)
-            if self.zero3:
-                return new_p_sh, new_opt
-            return unravel_p(self._rows_to_flat(new_p_sh)), new_opt
-
         def per_replica_step(state: TrainState, local_batch, aux):
-            # Differentiate wrt a per-replica *varying* view of the
-            # params: under shard_map's vma system, grads of replicated
-            # params are auto-psum'ed across the mesh, which would hand
-            # every replica the summed gradient and erase the per-replica
-            # noise signal the GNS needs. Varying params keep gradients
-            # local; the cross-replica mean is taken explicitly below.
-            params = state.params
-            if self.zero3:
-                # FSDP-style assembly: this device's [1, shard] row ->
-                # the full parameter tree, once per step (the
-                # all-gather of ZeRO-3, as a vma-typed scatter+psum).
-                params = self._zero1_unravel(
-                    self._rows_to_flat(params)
-                )
-            varying_axes = (
-                (DATA_AXIS, SEQ_AXIS) if seq_shards > 1 else DATA_AXIS
-            )
-            params_v = jax.lax.pcast(params, varying_axes, to="varying")
-            precond = (
-                self._zero1_precond(state.opt_state)
-                if self.zero1
-                else self._precond(state.opt_state)
-            )
-            # The preconditioner multiplies gradients *after* their
-            # seq-axis pmean, so it is data-varying only.
-            precond_v = (
-                None
-                if precond is None
-                else jax.lax.pcast(precond, DATA_AXIS, to="varying")
-            )
+            # (The intermediates below are named one by one on purpose,
+            # beyond what reading needs: on CPython 3.12 the time to
+            # TRACE this function moves by 3.5 s at gpt2-124m with the
+            # size of this frame, and this size is on the fast side;
+            # PERF.md section 6, PR 28.)
+            stored, moments = state.params, state.opt_state
+            gns_state = state.gns
+            params = layout.assemble(stored)
+            wrt = layout.differentiable(params)
+            precond = layout.precond(moments)
+            precond_micro = layout.micro_precond(precond)
             # Per-replica, per-step rng; microbatch rngs split below.
             rng = jax.random.fold_in(state.rng, state.step)
             rng = jax.random.fold_in(
@@ -1525,68 +447,33 @@ class ElasticTrainer:
                 local_batch,
             )
             micro_rngs = jax.random.split(rng, num_micro)
+            extra = (aux,) if self.has_aux else ()
+            loss_fn = layout.differentiated(self.loss_fn)
 
             def micro_step(carry, inputs):
                 grad_sum, lsqr_sum, loss_sum = carry
                 mb, mb_rng = inputs
-                if self.has_aux:
-                    loss, grad = jax.value_and_grad(self.loss_fn)(
-                        params_v, mb, mb_rng, aux
-                    )
-                else:
-                    loss, grad = jax.value_and_grad(self.loss_fn)(
-                        params_v, mb, mb_rng
-                    )
-                if seq_shards > 1:
-                    # A sequence-sharded group is one logical replica:
-                    # average its shard-gradients *before* the GNS
-                    # squared norm so the noise statistics see whole-
-                    # sample gradients.
-                    grad = jax.lax.pmean(grad, SEQ_AXIS)
-                    loss = jax.lax.pmean(loss, SEQ_AXIS)
+                loss, grad = jax.value_and_grad(loss_fn)(
+                    wrt, mb, mb_rng, *extra
+                )
+                loss, grad = layout.whole_sample(loss, grad)
                 grad_sum = jax.tree.map(jnp.add, grad_sum, grad)
-                lsqr_sum = lsqr_sum + stat_normsqr(grad, precond_v)
+                lsqr_sum = lsqr_sum + layout.normsqr(grad, precond_micro)
                 return (grad_sum, lsqr_sum, loss_sum + loss), None
 
-            # Derive the grad accumulator from the params so it
-            # inherits their varying-axis types (stage-sharded leaves
-            # are stage-varying; a literal zeros array would be typed
-            # unvarying and fail the scan carry check), then add the
-            # data axis. The loss carry stays stage-UNvarying (a
-            # pipelined loss_fn psums over the stage axis); the lsqr
-            # carry follows the gradients.
-            zeros = jax.tree.map(
-                lambda p: (p * 0.0).astype(jnp.float32), params
-            )
-            grad_init = jax.lax.pcast(zeros, DATA_AXIS, to="varying")
-            # lsqr is already psum'd over the sharded axes inside
-            # stat_normsqr, so the carry varies over data only.
-            lsqr_init = jax.lax.pcast(
-                jnp.zeros((self.num_param_groups,)),
-                DATA_AXIS,
-                to="varying",
-            )
-            loss_init = jax.lax.pcast(
-                jnp.zeros(()), DATA_AXIS, to="varying"
-            )
+            grad_init, lsqr_init, loss_init = layout.accumulators(params)
             init = (grad_init, lsqr_init, loss_init)
+            xs = (micro_batches, micro_rngs)
             (grad_sum, lsqr_sum, loss_sum), _ = jax.lax.scan(
-                micro_step, init, (micro_batches, micro_rngs)
+                micro_step, init, xs
             )
-            grads_local = jax.tree.map(lambda g: g / num_micro, grad_sum)
-            # The gradient all-reduce: one fused pmean over ICI/DCN,
-            # with the two GNS scalars riding alongside. Pipeline
-            # stages do NOT average gradients — each stage owns its
-            # parameter shard — but the gradient-norm statistics sum
-            # across the shards.
-            grads = jax.lax.pmean(grads_local, DATA_AXIS)
-            local_sqr_mean = jax.lax.pmean(
-                lsqr_sum / num_micro, DATA_AXIS
+            grads, local_sqr_mean, loss = layout.reduce(
+                grad_sum, lsqr_sum, loss_sum, num_micro
             )
-            loss = jax.lax.pmean(loss_sum / num_micro, DATA_AXIS)
 
+            count = layout.gns_count(num_micro)
             new_gns = gns.update(
-                state.gns,
+                gns_state,
                 grads,
                 local_sqr_mean,
                 count=count,
@@ -1594,9 +481,9 @@ class ElasticTrainer:
                 num_microbatches=num_micro,
                 smoothing=self.smoothing,
                 precond=precond,
-                group_ids=self._group_ids,
-                num_groups=self.num_param_groups,
-                normsqr_fn=stat_normsqr,
+                group_ids=layout.group_ids,
+                num_groups=layout.num_groups,
+                normsqr_fn=layout.normsqr,
             )
             step_gain = gns.gain(new_gns, scale)
             ctx = RuleContext(
@@ -1608,37 +495,15 @@ class ElasticTrainer:
             )
             lr_factor = self.scaling_rule.lr_factor(ctx)
             group_factors = self.scaling_rule.lr_factor_groups(ctx)
-            if self.zero1:
-                new_params, new_opt_state = zero1_update(
-                    grads, state.opt_state, params,
-                    state.params if self.zero3 else None,
-                    group_factors,
-                )
-            else:
-                updates, new_opt_state = self.optimizer.update(
-                    grads, state.opt_state, params
-                )
-                # Each leaf's update scales by ITS group's factor (the
-                # reference multiplies scale_lr's vector into each
-                # optimizer param group's lr, scaling_rules.py:78-83).
-                flat_updates, treedef = jax.tree_util.tree_flatten(
-                    updates
-                )
-                flat_updates = [
-                    (u.astype(jnp.float32) * group_factors[gid]).astype(
-                        u.dtype
-                    )
-                    for u, gid in zip(flat_updates, self._group_ids)
-                ]
-                updates = jax.tree_util.tree_unflatten(
-                    treedef, flat_updates
-                )
-                new_params = optax.apply_updates(params, updates)
+            new_params, new_opt_state = layout.apply(
+                grads, moments, params, stored, group_factors
+            )
+            progress = state.progress + step_gain
             new_state = TrainState(
                 params=new_params,
                 opt_state=new_opt_state,
                 gns=new_gns,
-                progress=state.progress + step_gain,
+                progress=progress,
                 step=state.step + 1,
                 rng=state.rng,
             )
@@ -1648,7 +513,7 @@ class ElasticTrainer:
                 "lr_factor": lr_factor,
                 "grad_sqr": gns.sqr_avg(new_gns),
                 "grad_var": gns.var_avg(new_gns),
-                "progress": new_state.progress,
+                "progress": progress,
                 "scale": jnp.asarray(scale, jnp.float32),
             }
             return new_state, metrics
@@ -1669,7 +534,8 @@ class ElasticTrainer:
             extra["axis_names"] = manual
         # State specs over the manual axes: replicated (P()) leaves in
         # pure data parallelism; stage-sharded params (and their
-        # optimizer/GNS mirrors) under pipeline parallelism.
+        # optimizer/GNS mirrors) under pipeline parallelism; rows over
+        # the data axis in the sharded storage layouts.
         state_specs = self._manual_state_specs(manual)
         sharded = jax.shard_map(
             per_replica_step,
@@ -1683,37 +549,9 @@ class ElasticTrainer:
     def params_tree(self, state: TrainState) -> Any:
         """The parameter TREE of a TrainState, whatever the storage
         layout — the accessor user code (evaluation, export, analysis)
-        should reach for instead of ``state.params``, which under
-        zero3 holds flat [dp, shard] rows."""
-        if self.zero3_blocks is not None:
-            key = ("params_tree",)
-            assemble = self._step_cache.get(key)
-            if assemble is None:
-                assemble = jax.jit(
-                    self._z3b_tree_from_rows,
-                    out_shardings=NamedSharding(self.mesh, P()),
-                )
-                self._step_cache[key] = assemble
-            return assemble(state.params)
-        if not self.zero3:
-            return state.params
-        # Assemble ON DEVICE: the [dp, shard] rows are sharded over the
-        # data axis and not fully addressable on multi-host jobs, so a
-        # host-side np.asarray would crash exactly where zero3 matters.
-        # A jit with replicated out_shardings makes XLA all-gather the
-        # rows and unravel them into the canonical tree.
-        key = ("params_tree",)
-        assemble = self._step_cache.get(key)
-        if assemble is None:
-            n = self._zero1_n
-            assemble = jax.jit(
-                lambda rows: self._zero1_unravel(
-                    rows.reshape(-1)[:n]
-                ),
-                out_shardings=NamedSharding(self.mesh, P()),
-            )
-            self._step_cache[key] = assemble
-        return assemble(state.params)
+        should reach for instead of ``state.params``, which a sharded
+        layout holds as flat rows."""
+        return self.storage.full_params(state.params)
 
     def eval_step(self, metric_fn: Callable) -> Callable:
         """Compiled sharded evaluation: ``(state, batch) -> metrics``.
@@ -1721,9 +559,10 @@ class ElasticTrainer:
         ``metric_fn(params_tree, local_batch)`` runs on each data (and
         seq) shard and returns a pytree of PARTIAL SUMS (e.g. correct
         counts, loss sums, row counts); the step psums them over the
-        mesh's manual axes and returns replicated totals. Under zero3
-        the parameter tree is assembled on the fly, so the same
-        metric_fn works for every storage layout. Cached per
+        mesh's manual axes and returns replicated totals. A layout
+        that stores rows assembles the tree on the fly, so the same
+        metric_fn works for every storage layout (zero3-blocks hands
+        it the ``Zero3View`` its loss_fn gets). Cached per
         metric_fn.
         """
         # id() is a safe key here (and keeps unhashable callables
@@ -1737,19 +576,9 @@ class ElasticTrainer:
         sharded_axes = self.sharded_param_axes
 
         def per_replica(params, local_batch):
-            if self.zero3_blocks is not None:
-                # metric_fn receives the same Zero3View the loss_fn
-                # does: the model's scan_blocks forward works unchanged
-                # and eval keeps the per-block memory bound.
-                params = self._z3b.build_view(
-                    params["blocks"], params["other"], self._z3b_spec,
-                    varying_axes=self._z3b_varying_axes(),
-                )
-            elif self.zero3:
-                params = self._zero1_unravel(
-                    self._rows_to_flat(params)
-                )
-            out = metric_fn(params, local_batch)
+            out = metric_fn(
+                self.storage.model_params(params), local_batch
+            )
             # Partial sums must be varying before the psum (computed
             # from the sharded batch, they already are; pcast is for
             # metric_fns that return constants).
@@ -1774,17 +603,9 @@ class ElasticTrainer:
         extra = {}
         if MODEL_AXIS in self.mesh.shape:
             extra["axis_names"] = manual
-        if self.zero3_blocks is not None:
-            param_specs = {
-                "blocks": P(None, DATA_AXIS),
-                "other": P(DATA_AXIS),
-            }
-        elif self.zero3:
-            param_specs = P(DATA_AXIS)
-        else:
-            param_specs = self._restrict_specs(
-                self._param_spec_tree(self._init_params), manual
-            )
+        param_specs = storage.restrict_specs(
+            self.storage.param_specs(), manual
+        )
         sharded = jax.shard_map(
             per_replica,
             mesh=self.mesh,
@@ -1843,48 +664,24 @@ class ElasticTrainer:
     # ---- profiling integration --------------------------------------
 
     def _build_compute_only(self, atomic_bsz: int):
-        """One microbatch forward+backward with no collective: the
-        calibration measurement that splits compute from gradient-sync
-        time in the perf model (hook timing being impossible under XLA
-        fusion; see adaptdl_tpu.metrics)."""
-
+        """One microbatch forward+backward with no collective but the
+        storage layout's own gathers: the calibration measurement that
+        splits compute from gradient-sync time in the perf model (hook
+        timing being impossible under XLA fusion; see
+        adaptdl_tpu.metrics). Differentiates exactly what the train
+        step does — the calibration must time the same schedule it
+        models."""
+        layout = self.storage
         seq_shards = self.seq_shards
         sharded_axes = self.sharded_param_axes
-        varying_axes = (
-            (DATA_AXIS, SEQ_AXIS) if seq_shards > 1 else DATA_AXIS
-        )
 
         def per_replica(params, local_batch, rng, aux):
             extra = (aux,) if self.has_aux else ()
-            if self.zero3_blocks is not None:
-                # Differentiate wrt the rows through the view, exactly
-                # as the train step does — the calibration must time
-                # the same gather/reduce-scatter schedule it models.
-                rng = jax.random.fold_in(
-                    rng, jax.lax.axis_index(DATA_AXIS)
-                )
-
-                def loss_of_rows(r):
-                    view = self._z3b.build_view(
-                        r["blocks"], r["other"], self._z3b_spec,
-                        varying_axes=self._z3b_varying_axes(),
-                    )
-                    return self.loss_fn(view, local_batch, rng, *extra)
-
-                loss, grads = jax.value_and_grad(loss_of_rows)(params)
-                if seq_shards > 1:
-                    loss = jax.lax.pmean(loss, SEQ_AXIS)
-                total = gns.normsqr(grads) + loss
-                return total[None]
-            if self.zero3:
-                params = self._zero1_unravel(
-                    self._rows_to_flat(params)
-                )
-            params_v = jax.lax.pcast(params, varying_axes, to="varying")
+            wrt = layout.differentiable(layout.assemble(params))
             rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
-            loss, grads = jax.value_and_grad(self.loss_fn)(
-                params_v, local_batch, rng, *extra
-            )
+            loss, grads = jax.value_and_grad(
+                layout.differentiated(self.loss_fn)
+            )(wrt, local_batch, rng, *extra)
             total = gns.normsqr(grads) + loss
             if seq_shards > 1:
                 total = jax.lax.pmean(total, SEQ_AXIS)
@@ -1901,17 +698,9 @@ class ElasticTrainer:
         extra = {}
         if MODEL_AXIS in self.mesh.shape:
             extra["axis_names"] = manual
-        if self.zero3_blocks is not None:
-            param_specs = {
-                "blocks": P(None, DATA_AXIS),
-                "other": P(DATA_AXIS),
-            }
-        elif self.zero3:
-            param_specs = P(DATA_AXIS)  # the flat rows
-        else:
-            param_specs = self._restrict_specs(
-                self._param_spec_tree(self._init_params), manual
-            )
+        param_specs = storage.restrict_specs(
+            layout.param_specs(), manual
+        )
         sharded = jax.shard_map(
             per_replica,
             mesh=self.mesh,
@@ -1920,7 +709,6 @@ class ElasticTrainer:
             **extra,
         )
         return jax.jit(sharded)
-
     def calibrate_accum_time(
         self, state: TrainState, host_batch: Any, atomic_bsz: int,
         repeats: int = 3, aux: Any = (),
@@ -2168,48 +956,10 @@ class TrainerCheckpoint(checkpoint.State):
                 except (AttributeError, RuntimeError):
                     pass  # backend without async transfers
         state = jax.tree.map(np.asarray, state)
-        if self._trainer.zero3_blocks is not None:
-            # Canonical disk layouts: params as the plain TREE (what a
-            # dense trainer writes), moments and the prev_grad carry
-            # as flat [n] vectors in tree-ravel order (what zero1/lite
-            # write) — dp-independent, and the carry itself holds the
-            # GLOBAL mean gradient, so it survives a dp change intact.
-            state = state._replace(
-                params=self._trainer._z3b_canonical_params(
-                    state.params
-                ),
-                opt_state=self._trainer._z3b_canonical_opt(
-                    state.opt_state
-                ),
-                gns=state.gns._replace(
-                    prev_grad=self._trainer._z3b_flat_canonical(
-                        state.gns.prev_grad
-                    )
-                ),
-            )
-        if self._trainer.zero1:
-            # Canonical (dp-independent) moment layout on disk; zero1
-            # is part of the job's flag-stable config, so the restoring
-            # incarnation re-expands for ITS replica count.
-            state = state._replace(
-                opt_state=self._trainer._zero1_canonical_opt(
-                    state.opt_state
-                )
-            )
-        if self._trainer.zero3:
-            state = state._replace(
-                params=self._trainer._zero3_canonical_params(
-                    state.params
-                )
-            )
-        if self._trainer.zero1:
-            # Canonical prev_grad is always empty under the zero
-            # family (dp-independent; the dp==1 reader re-primes).
-            state = state._replace(
-                gns=state.gns._replace(
-                    prev_grad=self._trainer._empty_prev_grad_host()
-                )
-            )
+        # Canonical (dp-independent) layout on disk; the storage
+        # layout is part of the job's flag-stable config, so the
+        # restoring incarnation re-expands for ITS replica count.
+        state = self._trainer.storage.to_canonical(state, storage.on_host)
         if self._transform_save is not None:
             state = self._transform_save(state)
         return state
@@ -2263,19 +1013,18 @@ class TrainerCheckpoint(checkpoint.State):
         multi-process tensor-parallel restore range-pulls only its
         own rows with zero launcher configuration. ``devices``
         overrides the device subset (tests simulate a peer process's
-        view). Covers the dense path only: the zero family and
-        transform hooks store a canonical layout whose leaves don't
-        map positionally onto the run spec tree, and there the
-        conservative full pull stays. Single-process meshes derive
+        view). Covers only layouts whose checkpoint IS the run layout:
+        transform hooks and the sharded storage layouts store a
+        canonical layout whose leaves don't map positionally onto the
+        run spec tree, and there the conservative full pull stays.
+        Single-process meshes derive
         full spans, which ``handoff._normalize_plan`` drops — the
         behavior is unchanged exactly where the plan couldn't help."""
         trainer = self._trainer
         if (
             self._transform_save is not None
             or self._transform_load is not None
-            or trainer.zero1
-            or trainer.zero3
-            or trainer.zero3_blocks is not None
+            or not trainer.storage.canonical_is_stored
         ):
             return None
         try:
@@ -2353,68 +1102,9 @@ class TrainerCheckpoint(checkpoint.State):
         the chunk-reassembled ``load_chunks``/handoff paths."""
         if self._transform_load is not None:
             host_state = self._transform_load(host_state)
-        if self._trainer.zero3_blocks is not None:
-            tr = self._trainer
-            prev = host_state.gns.prev_grad
-            if (
-                isinstance(prev, np.ndarray)
-                and prev.shape == (tr._z3b_n_total,)
-            ):
-                # Our canonical carry: the global mean gradient,
-                # dp-independent — expand to this dp's rows.
-                new_prev = tr._z3b_rows_from_flat(prev)
-                new_valid = host_state.gns.prev_grad_valid
-            else:
-                # Foreign layout (a dense/lite checkpoint crossing
-                # into blocks mode): re-prime the differenced
-                # estimator.
-                new_prev = jax.tree.map(
-                    lambda x: np.zeros(np.shape(x), np.float32),
-                    tr._z3b_rows_from_tree_host(tr._init_params),
-                )
-                new_valid = np.zeros((), bool)
-            host_state = host_state._replace(
-                params=tr._z3b_rows_from_tree_host(host_state.params),
-                opt_state=tr._z3b_expand_opt(host_state.opt_state),
-                gns=host_state.gns._replace(
-                    prev_grad=new_prev, prev_grad_valid=new_valid
-                ),
-            )
-        if self._trainer.zero1 and (
-            isinstance(host_state.gns.prev_grad, np.ndarray)
-            and host_state.gns.prev_grad.shape
-            == (self._trainer._zero1_n,)
-            and np.shape(self._trainer._init_params) != (
-                self._trainer._zero1_n,
-            )
-        ):
-            # A zero3_blocks checkpoint crossing into the zero1/lite
-            # family: its flat canonical carry has no zero1 reader —
-            # drop to the placeholder layout and re-prime.
-            host_state = host_state._replace(
-                gns=host_state.gns._replace(
-                    prev_grad=self._trainer._empty_prev_grad_host(),
-                    prev_grad_valid=np.zeros((), bool),
-                )
-            )
-        if self._trainer.zero1:
-            host_state = host_state._replace(
-                opt_state=self._trainer._zero1_expand_opt(
-                    host_state.opt_state
-                )
-            )
-        if self._trainer.zero3:
-            host_state = host_state._replace(
-                params=self._trainer._zero3_rows_from_tree(
-                    host_state.params
-                )
-            )
-        if self._trainer.zero1:
-            host_state = host_state._replace(
-                gns=self._trainer._normalize_gns_layout(
-                    host_state.gns
-                )
-            )
+        host_state = self._trainer.storage.from_canonical(
+            host_state, storage.on_host
+        )
         host_state = host_state._replace(
             rng=jax.random.wrap_key_data(jnp.asarray(host_state.rng)),
         )
@@ -2429,7 +1119,7 @@ class TrainerCheckpoint(checkpoint.State):
         specs = trainer.state_spec_tree(host_state)
         self._set_state(
             jax.tree.map(
-                lambda x, s: _materialize(
+                lambda x, s: storage.materialize(
                     x, NamedSharding(trainer.mesh, s)
                 ),
                 host_state,

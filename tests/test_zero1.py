@@ -237,10 +237,10 @@ def test_zero1_sharded_checkpoint_rescale(tmp_path, monkeypatch):
     assert int(holder2["state"].step) == 3
     # Moments landed as this trainer's [2, shard2] rows and match the
     # canonical content of the dp=4 run.
-    canon4 = tr4._zero1_canonical_opt(
+    canon4 = tr4.storage.moments_to_canonical(
         jax.tree.map(np.asarray, holder["state"].opt_state)
     )
-    canon2 = tr2._zero1_canonical_opt(
+    canon2 = tr2.storage.moments_to_canonical(
         jax.tree.map(np.asarray, holder2["state"].opt_state)
     )
     for a, b in zip(jax.tree.leaves(canon4), jax.tree.leaves(canon2)):

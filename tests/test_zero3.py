@@ -28,9 +28,9 @@ def _lm_setup(seed=0):
 
 def _params_tree(trainer, state):
     """Materialize a zero3 state's params back to the tree layout."""
-    if not trainer.zero3:
-        return state.params
-    return trainer._zero3_canonical_params(np.asarray(state.params))
+    return trainer.storage.params_to_canonical(
+        jax.tree.map(np.asarray, state.params)
+    )
 
 
 @pytest.mark.parametrize(

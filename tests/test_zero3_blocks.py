@@ -435,21 +435,16 @@ def test_z3b_storage_is_sharded_rows():
     step = tr.train_step(4, 0)
     state, _ = step(state, tr.shard_batch(batch_np))
 
-    def rows_dicts(tree):
-        return [
-            node
-            for node in jax.tree.leaves(
-                tree, is_leaf=tr._z3b_is_rows
-            )
-            if tr._z3b_is_rows(node)
-        ]
-
     found = (
-        rows_dicts(state.params)
-        + rows_dicts(state.opt_state)
-        + rows_dicts(state.gns.prev_grad)
+        [state.params]
+        + tr.storage.mirrors(state.opt_state)
+        + [state.gns.prev_grad]
     )
     assert len(found) >= 4  # params + mu + nu + prev_grad
+    rows_shapes = jax.tree.map(np.shape, state.params)
+    assert all(
+        jax.tree.map(np.shape, rows) == rows_shapes for rows in found
+    )
     for rows in found:
         for key, sharded_dim in (("blocks", 1), ("other", 0)):
             leaf = rows[key]
@@ -658,7 +653,7 @@ def test_z3b_cross_mode_checkpoint_into_lite(tmp_path, monkeypatch):
     ckpt_mod.load_state(ck2)
     ck2.unregister()
     assert int(holder2["state"].step) == 3
-    p_after = tr_l._zero3_canonical_params(
+    p_after = tr_l.storage.params_to_canonical(
         np.asarray(holder2["state"].params)
     )
     for a, b in zip(
@@ -713,15 +708,10 @@ def test_dense_checkpoint_into_z3b(tmp_path, monkeypatch):
     ck2.unregister()
     assert int(holder2["state"].step) == 3
     # Moments really converted to rows (not left as trees).
-    assert tr_z._z3b_is_rows(
-        jax.tree.leaves(
-            holder2["state"].opt_state, is_leaf=tr_z._z3b_is_rows
-        )[0]
-    ) or any(
-        tr_z._z3b_is_rows(n)
-        for n in jax.tree.leaves(
-            holder2["state"].opt_state, is_leaf=tr_z._z3b_is_rows
-        )
+    rows_shapes = jax.tree.map(np.shape, holder2["state"].params)
+    moments = tr_z.storage.mirrors(holder2["state"].opt_state)
+    assert moments and all(
+        jax.tree.map(np.shape, m) == rows_shapes for m in moments
     )
     step_z = tr_z.train_step(4, 0)
     for _ in range(2):
