@@ -39,7 +39,10 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from adaptdl_tpu.models.transformer import Block, TransformerConfig
+from adaptdl_tpu.models.transformer import (
+    TransformerConfig,
+    block_remat,
+)
 from adaptdl_tpu.parallel.mesh import STAGE_AXIS
 from adaptdl_tpu.parallel.pipeline import (
     gpipe,
@@ -288,9 +291,7 @@ def init_pipeline_lm(
     block_config = dataclasses.replace(
         config, seq_axis=None, attention_fn=None, moe_axis=None
     )
-    block = Block(block_config)
-    if config.remat:
-        block = nn.remat(Block, static_argnums=())(block_config)
+    block = block_remat(block_config)(block_config)
     embed = nn.Embed(
         config.vocab_size, config.d_model, dtype=config.dtype
     )

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from adaptdl_tpu import trace
 
 
 @dataclass(frozen=True)
@@ -40,10 +41,14 @@ class TransformerConfig:
     remat: bool = True
     # Rematerialisation policy (a jax.checkpoint_policies name, e.g.
     # "dots_with_no_batch_dims_saveable" to keep matmul outputs and
-    # recompute only the cheap elementwise ops, or "nothing_saveable"
-    # for maximum HBM savings). None = save nothing beyond the
-    # defaults. The policy trades recompute FLOPs for HBM — the knob
-    # to turn when activations, not weights, bound the batch size.
+    # recompute only the cheap elementwise ops). A remat'd block
+    # ALWAYS keeps the flash kernel's output and log-sum-exp
+    # (block_remat: attention is never computed twice; per layer one
+    # [batch, seq, d_model] activation plus 4 bytes a head and
+    # position); a named policy ADDS to that, so None and
+    # "nothing_saveable" keep just those two. The policy trades
+    # recompute FLOPs for HBM — the knob to turn when activations,
+    # not weights, bound the batch size.
     remat_policy: str | None = None
     # attention_fn(q, k, v) -> out; q/k/v are [batch, heads, seq,
     # head_dim]; None selects plain causal attention (or ring
@@ -302,6 +307,70 @@ class Block(nn.Module):
         return x + y
 
 
+# Only the zero-config policies are valid by NAME — the other
+# jax.checkpoint_policies attributes are factories (they build a
+# policy from arguments) and passing one where a policy is expected
+# silently disables remat or crashes mid-trace.
+_REMAT_POLICIES = (
+    "everything_saveable",
+    "nothing_saveable",
+    "dots_saveable",
+    "checkpoint_dots",
+    "dots_with_no_batch_dims_saveable",
+    "checkpoint_dots_with_no_batch_dims",
+)
+
+
+def _check_remat_policy(config: TransformerConfig) -> None:
+    if (
+        config.remat_policy is not None
+        and config.remat_policy not in _REMAT_POLICIES
+    ):
+        raise ValueError(
+            f"unknown remat_policy {config.remat_policy!r}; valid "
+            f"names: {sorted(_REMAT_POLICIES)} (policy FACTORIES like "
+            "save_only_these_names need arguments — build them "
+            "yourself and wrap the Block with nn.remat directly)"
+        )
+
+
+def block_remat(config: TransformerConfig):
+    """The ``Block`` class a model of this config stacks: ``Block``
+    itself, or with ``config.remat`` its ``nn.remat`` — the one place
+    that knows what a remat'd block keeps from forward to backward.
+
+    Always the flash kernel's ``out`` and ``lse``, by the names its
+    forward rule gives them: the kernel's FLOPs per byte of output
+    grow with the sequence, so its output is the dearest byte of the
+    block at every shape, and no policy a user can name would keep it
+    (a ``pallas_call`` is not a dot). A named ``remat_policy`` adds
+    what it saves to that. Blocks without the kernel name nothing and
+    are remat'd as the policy alone would.
+
+    Records one ``remat.policy`` event a call: a model calls this once
+    each time it is traced.
+    """
+    if not config.remat:
+        return Block
+    _check_remat_policy(config)
+    from adaptdl_tpu.ops.flash_attention import SAVED_LSE, SAVED_OUT
+
+    saved_names = (SAVED_OUT, SAVED_LSE)
+    policy = jax.checkpoint_policies.save_only_these_names(*saved_names)
+    if config.remat_policy is not None:
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            getattr(jax.checkpoint_policies, config.remat_policy),
+            policy,
+        )
+    trace.event(
+        "remat.policy",
+        saved_names=",".join(saved_names),
+        policy=config.remat_policy or "none",
+        blocks=config.num_layers,
+    )
+    return nn.remat(Block, static_argnums=(), policy=policy)
+
+
 class TransformerLM(nn.Module):
     config: TransformerConfig
 
@@ -331,16 +400,7 @@ class TransformerLM(nn.Module):
             ) * tokens.shape[1] + jnp.arange(tokens.shape[1])
         else:
             positions = jnp.arange(tokens.shape[1])
-        block_cls = Block
-        if cfg.remat:
-            remat_kwargs = {}
-            if cfg.remat_policy is not None:
-                remat_kwargs["policy"] = getattr(
-                    jax.checkpoint_policies, cfg.remat_policy
-                )
-            block_cls = nn.remat(
-                Block, static_argnums=(), **remat_kwargs
-            )
+        block_cls = block_remat(cfg)
         for layer in range(cfg.num_layers):
             dropout_rng = (
                 jax.random.fold_in(rng, layer)
@@ -384,30 +444,9 @@ def init_transformer(config: TransformerConfig, rng=None, seq_len=None):
             "causal=True (expert-choice gating sees future tokens); "
             "use causal=False (encoder/MLM) or moe_router='tokens'"
         )
-    # Only the zero-config policies are valid by NAME — the other
-    # jax.checkpoint_policies attributes are factories (they build a
-    # policy from arguments) and passing one where a policy is
-    # expected silently disables remat or crashes mid-trace. Fail at
-    # configuration time, not deep inside the first step's jit trace
-    # (which on TPU wastes the whole startup).
-    _REMAT_POLICIES = (
-        "everything_saveable",
-        "nothing_saveable",
-        "dots_saveable",
-        "checkpoint_dots",
-        "dots_with_no_batch_dims_saveable",
-        "checkpoint_dots_with_no_batch_dims",
-    )
-    if (
-        config.remat_policy is not None
-        and config.remat_policy not in _REMAT_POLICIES
-    ):
-        raise ValueError(
-            f"unknown remat_policy {config.remat_policy!r}; valid "
-            f"names: {sorted(_REMAT_POLICIES)} (policy FACTORIES like "
-            "save_only_these_names need arguments — build them "
-            "yourself and wrap the Block with nn.remat directly)"
-        )
+    # Fail at configuration time, not deep inside the first step's
+    # jit trace (which on TPU wastes the whole startup).
+    _check_remat_policy(config)
     model = TransformerLM(config)
     # Parameter shapes don't depend on the parallelism config, and the
     # mapped seq/expert axes don't exist outside shard_map — init

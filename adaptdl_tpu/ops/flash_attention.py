@@ -72,7 +72,10 @@ Differentiation: ``pallas_call`` is not autodiff-transparent, so
 ``(q, k, v, out, lse)``. The backward is ONE Pallas kernel on the same
 schedule with the loops swapped (:func:`_bwd_kernel`; ``flash.
 schedule_bwd`` in the trace journal): P is recomputed from the saved
-log-sum-exp, never stored, so backward memory stays O(seq).
+log-sum-exp, never stored, so backward memory stays O(seq). The forward
+rule names ``out`` and ``lse`` (``SAVED_OUT``, ``SAVED_LSE``) and a
+remat'd block keeps them by name, so under remat the kernel's output
+is not computed a second time (``models.transformer.block_remat``).
 
 - **One grid step per (batch, head), key chunk and query tile.** The
   chunk's K and V (all of them while they fit the budget above) stay
@@ -118,6 +121,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -164,6 +168,14 @@ MOSAIC_CALL = "tpu_custom_call"
 # trace (``%flash_bwd.<n>``); the forward is unnamed inside the model's
 # ``attention`` scope, and the benchmark finds it as ``%attention.<n>``.
 BWD_KERNEL_NAME = "flash_bwd"
+# What the forward rule names (``jax.ad_checkpoint.checkpoint_name``)
+# of what it produces: the kernel's output and log-sum-exp, as the
+# backward reads them. A remat'd block saves them by these names
+# (``models.transformer.block_remat``), so the kernel runs once a step
+# and not once more inside every backward; outside a remat a name is
+# an identity.
+SAVED_OUT = "flash_out"
+SAVED_LSE = "flash_lse"
 # What both kernels index, as the ``flash.schedule*`` events name it:
 # ``[batch * heads, head_dim, seq]``.
 LAYOUT = "bhds"
@@ -371,7 +383,7 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
-    """q/k/v: [bh, d, seq] -> (out [bh, d, seq], lse [bh, seq] or
+    """q/k/v: [bh, d, seq] -> (out [bh, d, seq], lse [bh, 1, seq] or
     None)."""
     bh, head_dim, seq_len = q.shape
     sched = _schedule(
@@ -448,7 +460,7 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         ),
         interpret=_use_interpret(),
     )(q, k, v)
-    return out, (lse[0][:, 0] if with_lse else None)
+    return out, (lse[0] if with_lse else None)
 
 
 @functools.partial(
@@ -514,13 +526,19 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
-    """Residuals ``(q, k, v, out, lse)``: the first four in the
-    kernels' layout, as the backward reads them (a copy here would be
-    a copy a step), ``lse`` as ``[batch, heads, seq]``."""
+    """Residuals ``(q, k, v, out, lse)`` as the backward kernel reads
+    them (a copy here would be a copy a step): the first four in the
+    kernels' layout, ``lse`` as ``[batch * heads, 1, seq]``. ``out``
+    and ``lse`` are NAMED before anything reads them, so that a
+    ``jax.checkpoint`` policy around the call can keep them: a name
+    inside a ``custom_vjp``'s forward rule is seen by the enclosing
+    remat."""
     *operands, out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, with_lse=True
     )
-    residuals = (*operands, out, lse.reshape(q.shape[:3]))
+    out = checkpoint_name(out, SAVED_OUT)
+    lse = checkpoint_name(lse, SAVED_LSE)
+    residuals = (*operands, out, lse)
     return _from_kernel(out, q.shape), residuals
 
 
@@ -770,10 +788,10 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
         dQ = dS K * scale ;  dK = dS^T Q * scale
     """
     q, k, v, out, lse = residuals
-    head_dim, seq_len = g.shape[3], g.shape[2]
+    head_dim = g.shape[3]
     resolved_scale = head_dim**-0.5 if scale is None else float(scale)
     grads = _bwd_pallas(
-        q, k, v, _to_kernel(g), out, lse.reshape(-1, 1, seq_len),
+        q, k, v, _to_kernel(g), out, lse,
         causal, resolved_scale, block_q, block_k,
     )
     return tuple(_from_kernel(x, g.shape) for x in grads)

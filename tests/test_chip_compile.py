@@ -227,6 +227,57 @@ def test_block_keeps_the_projections_layout_on_v5e(v5e, chip_compile):
     assert not banned, banned
 
 
+def test_remat_keeps_the_kernels_output_on_v5e(v5e, chip_compile):
+    """The gradient of a remat'd two-layer ``TransformerLM`` at the
+    flagship widths and the cell's micro-batch, compiled for the
+    described v5e, holds ONE forward and ONE backward Mosaic call a
+    layer: a block keeps the kernel's ``out`` and ``lse`` by name
+    (``block_remat``), where the bare ``nn.remat`` held two forwards a
+    layer — the kernel re-run in every backward to rebuild an output
+    it had (PERF.md, PR 29)."""
+    import functools
+
+    from adaptdl_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    batch, heads, seq, head_dim = CELL
+    layers = 2
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=layers, num_heads=heads,
+        d_model=heads * head_dim, d_ff=4 * heads * head_dim,
+        max_seq_len=seq, dtype=jnp.bfloat16, remat=True,
+        attention_fn=functools.partial(
+            flash_mod.flash_attention, block_q=128, block_k=128
+        ),
+    )
+    model = TransformerLM(cfg)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.key(0), jnp.zeros(tokens.shape, tokens.dtype)
+            )
+        ),
+    )
+
+    def loss(params, tokens):
+        return model.apply(params, tokens, train=False).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+
+    def calls(name):
+        return len(re.findall(
+            rf"^\s*%{name}[.\d]* = .*{flash_mod.MOSAIC_CALL}", text, re.M
+        ))
+
+    assert calls("attention") == layers
+    assert calls(flash_mod.BWD_KERNEL_NAME) == layers
+
+
 def test_chip_smoke_incarnations_on_cpu(tmp_path, monkeypatch):
     """The smoke's two incarnations (children of this process, which
     holds no chip) with a tiny ``TransformerConfig`` and the expected

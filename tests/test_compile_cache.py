@@ -253,15 +253,17 @@ def test_executable_from_persistent_cache_is_not_reserialized(
 
 
 @functools.lru_cache(maxsize=None)
-def _remat_loss_and_grads(policy):
+def _remat_loss_and_grads(policy, attention="plain", remat=True):
     """One eager value_and_grad of the tiny LM under a remat policy;
-    cached so the no-policy reference is computed once for all cases."""
+    cached so the references are computed once for all cases."""
     from adaptdl_tpu.models import (
         TransformerConfig,
         init_transformer,
         lm_loss_fn,
     )
+    from adaptdl_tpu.ops import make_flash_attention
 
+    causal = "bidirectional" not in attention
     rng = np.random.default_rng(0)
     batch = {
         "tokens": jnp.asarray(
@@ -270,8 +272,12 @@ def _remat_loss_and_grads(policy):
     }
     cfg = TransformerConfig(
         vocab_size=64, num_layers=2, num_heads=2, d_model=32,
-        d_ff=64, max_seq_len=16, dtype=jnp.float32, remat=True,
-        remat_policy=policy,
+        d_ff=64, max_seq_len=16, dtype=jnp.float32, remat=remat,
+        remat_policy=policy, causal=causal,
+        attention_fn=(
+            make_flash_attention(causal=causal, block_q=8, block_k=8)
+            if "flash" in attention else None
+        ),
     )
     model, params = init_transformer(cfg, seq_len=16)
     loss, grads = jax.value_and_grad(lm_loss_fn(model))(
@@ -281,14 +287,22 @@ def _remat_loss_and_grads(policy):
 
 
 @pytest.mark.parametrize(
+    "attention", ["plain", "flash", "flash_bidirectional"]
+)
+@pytest.mark.parametrize(
     "policy",
     [None, "dots_with_no_batch_dims_saveable", "nothing_saveable"],
 )
-def test_remat_policy_preserves_numerics(policy):
+def test_remat_policy_preserves_numerics(policy, attention):
     """Remat policies change the memory/recompute schedule, never the
-    values: loss and gradients match the no-policy build."""
-    base_loss, base_grads = _remat_loss_and_grads(None)
-    loss, grads = _remat_loss_and_grads(policy)
+    values: loss and gradients match the build WITHOUT remat, with
+    plain attention and with the flash kernel (causal and not), whose
+    output a remat'd block keeps under every policy, the composed
+    ``nothing_saveable`` too (``block_remat``)."""
+    base_loss, base_grads = _remat_loss_and_grads(
+        None, attention, remat=False
+    )
+    loss, grads = _remat_loss_and_grads(policy, attention)
     assert loss == pytest.approx(base_loss, rel=1e-6)
     for a, b in zip(jax.tree.leaves(base_grads), jax.tree.leaves(grads)):
         np.testing.assert_allclose(

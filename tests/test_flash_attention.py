@@ -257,13 +257,36 @@ def test_bf16_gradients_match_plain_attention(monkeypatch, case, reference):
         assert _relative(got_x, want_x) < bound, f"d{name}"
 
 
-@pytest.mark.parametrize("transform", ["shard_map", "remat", "shard_map_remat"])
+def _kernel_calls(jaxpr):
+    """(forward, backward) ``pallas_call``s anywhere in ``jaxpr``: the
+    backward kernel is the named one."""
+    forward = backward = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            named = eqn.params["name"] == flash_mod.BWD_KERNEL_NAME
+            backward += named
+            forward += not named
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            inner = _kernel_calls(sub)
+            forward, backward = forward + inner[0], backward + inner[1]
+    return forward, backward
+
+
+@pytest.mark.parametrize(
+    "transform",
+    [
+        "shard_map", "remat", "shard_map_remat",
+        "remat_saved", "shard_map_remat_saved",
+    ],
+)
 def test_gradients_under_transforms(transform):
     """The backward kernel where the trainer puts it: under
     ``jax.shard_map`` over a ``data`` axis (its outputs must declare
     how they vary, and interpret mode needs every block access inside
-    a region) and under ``nn.remat`` (the forward kernel re-run inside
-    the backward pass)."""
+    a region), under a bare ``nn.remat`` (the forward kernel re-run
+    inside the backward pass) and under a remat whose policy saves the
+    forward rule's names (``_saved``: as the model's blocks do, the
+    kernel runs once)."""
     import flax.linen as nn
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -272,7 +295,14 @@ def test_gradients_under_transforms(transform):
         def __call__(self, q, k, v):
             return flash_attention(q, k, v, True, None, 16, 16)
 
-    module = (nn.remat(Attend) if "remat" in transform else Attend)()
+    module = Attend()
+    if "remat" in transform:
+        policy = None
+        if "saved" in transform:
+            policy = jax.checkpoint_policies.save_only_these_names(
+                flash_mod.SAVED_OUT, flash_mod.SAVED_LSE
+            )
+        module = nn.remat(Attend, policy=policy)()
     q, k, v = _qkv(batch=4, seq=32, seed=8)
 
     def loss(attend):
@@ -292,8 +322,13 @@ def test_gradients_under_transforms(transform):
         )
     got = grad(q, k, v)
     assert len(_schedule_events("flash.schedule_bwd")) == 1
-    # Remat traces the forward a second time, inside the backward.
+    # Remat traces the forward a second time: the block's body, then
+    # the forward rule when it is differentiated.
     assert len(_schedule_events()) == (2 if "remat" in transform else 1)
+    # A bare remat RUNS it a second time too, inside the backward.
+    assert _kernel_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr) == (
+        2 if transform.endswith("remat") else 1, 1
+    )
     want = jax.grad(
         loss(lambda q, k, v: _dense(q, k, v, True)), argnums=(0, 1, 2)
     )(q, k, v)
@@ -440,7 +475,9 @@ def test_gradients_across_schedules(schedule_case, causal):
 )
 def test_vjp_forward_lse_is_logsumexp(schedule_case, causal):
     """The residual the backward pass reads: one float32 per query
-    row, the log-sum-exp of that row's (masked) scaled logits."""
+    row, the log-sum-exp of that row's (masked) scaled logits, in the
+    shape the backward kernel takes it (``[batch * heads, 1, seq]``:
+    saving it under remat costs no copy)."""
     shape, block_q, block_k, _ = schedule_case
     q, k, v = _qkv(seed=7, **shape)
     out, (_, _, _, _, lse) = flash_mod._flash_vjp_fwd(
@@ -452,9 +489,11 @@ def test_vjp_forward_lse_is_logsumexp(schedule_case, causal):
         logits = jnp.where(
             jnp.tril(jnp.ones((seq, seq), bool)), logits, -jnp.inf
         )
-    assert lse.shape == q.shape[:3] and lse.dtype == jnp.float32
+    batch, heads, seq_len, _ = q.shape
+    assert lse.shape == (batch * heads, 1, seq_len)
+    assert lse.dtype == jnp.float32
     np.testing.assert_allclose(
-        np.asarray(lse),
+        np.asarray(lse).reshape(q.shape[:3]),
         np.asarray(jax.nn.logsumexp(logits, axis=-1)),
         atol=2e-5,
         rtol=2e-5,
@@ -723,14 +762,14 @@ def test_native_layout_forward_and_gradients(case):
 
 def test_residuals_stay_in_the_kernels_layout():
     """What the backward reads is what the forward wrote: q, k, v and
-    out are saved as ``[b * h, d, s]`` (no copy between the passes),
-    the log-sum-exp as ``[b, h, s]``."""
+    out are saved as ``[b * h, d, s]`` and the log-sum-exp as
+    ``[b * h, 1, s]`` (no copy between the passes)."""
     q, k, v = _qkv(batch=2, heads=4, seq=32, d=64, seed=14)
     out, residuals = flash_mod._flash_vjp_fwd(q, k, v, True, None, 16, 16)
     assert out.shape == q.shape
     *operands, lse = residuals
     assert [x.shape for x in operands] == 4 * [(2 * 4, 64, 32)]
-    assert lse.shape == (2, 4, 32)
+    assert lse.shape == (2 * 4, 1, 32)
     for saved, given in ((operands[0], q), (operands[3], out)):
         np.testing.assert_array_equal(
             np.asarray(saved),
