@@ -33,12 +33,14 @@ gradient-norm statistics count each expert shard exactly once.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from adaptdl_tpu import trace
+from adaptdl_tpu.ops import grouped_matmul as gmm
 from adaptdl_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -301,3 +303,255 @@ def dense_switch_moe(
     if return_aux:
         return out, jnp.mean(jnp.stack(auxes))
     return out
+
+
+# ---- the dropless expert layer: one chip's share of the experts -----
+#
+# Separate from the capacity-dropping Switch path above and calling
+# none of it. The layer is TOLD which experts it holds
+# (``first_expert``, ``experts_held`` of ``experts_total``): it routes
+# over all of them as published, drops nothing under any imbalance,
+# and returns the part of the result its own experts give. With
+# ``experts_held == experts_total`` that is the whole layer; with fewer
+# it is one chip's share of an expert-parallel deployment — what the
+# other chips' experts would add is left out, and nothing here stands
+# in for them or for their exchange.
+
+
+class RowPlan(NamedTuple):
+    """Where every (token, choice) assignment to a held expert lies in
+    the row buffer the grouped products run over: groups in expert
+    order, each starting at a multiple of the row tile."""
+
+    dest: Any  # int32 [tokens, top_k]: row of the assignment, or
+    # ``rows`` (one past the buffer) for an expert not held here
+    row_token: Any  # int32 [rows]: the token a row holds (0: padding)
+    row_assignment: Any  # int32 [rows]: token * top_k + choice, or -1
+    tile_expert: Any  # int32 [rows / tile]: the held expert of a tile
+    active_tiles: Any  # int32 [1]: leading tiles that hold rows
+    group_sizes: Any  # int32 [experts_held]: rows placed per expert
+
+
+def sigmoid_top_k(x, router, bias, top_k: int, eps: float, scale: float):
+    """The published router: float32 sigmoid scores over ALL experts;
+    the chosen set is the top ``top_k`` of ``score + bias`` (the bias a
+    buffer no gradient reaches); the weights are the chosen scores
+    WITHOUT the bias, divided by their sum, times ``scale``.
+
+    x: [tokens, d]; router: [d, experts]; bias: [experts] ->
+    (experts int32 [tokens, top_k], weights float32 [tokens, top_k]).
+    """
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )
+    )
+    _, experts = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + eps) * scale
+    return experts, weights
+
+
+def rows_capacity(
+    tokens: int, top_k: int, experts_held: int, tile: int
+) -> int:
+    """Rows of the buffer: every assignment a token can make to held
+    experts (its choices are distinct experts) plus each group's
+    padding to whole tiles, in whole tiles."""
+    worst = tokens * min(top_k, experts_held) + experts_held * (tile - 1)
+    return -(-worst // tile) * tile
+
+
+def plan_rows(
+    experts, first_expert: int, experts_held: int, tile: int
+) -> RowPlan:
+    """Order the assignments by held expert. Integer work only: two
+    stable sorts of ``tokens * top_k`` keys (the order, and its
+    inverse) and a few gathers; no scatter, no one-hot over rows."""
+    tokens, top_k = experts.shape
+    count = tokens * top_k
+    rows = rows_capacity(tokens, top_k, experts_held, tile)
+    local = experts.reshape(count) - first_expert
+    held = (local >= 0) & (local < experts_held)
+    key = jnp.where(held, local, experts_held).astype(jnp.int32)
+    ids = jnp.arange(count, dtype=jnp.int32)
+    # order[p]: the assignment at sorted position p; rank[a]: the
+    # sorted position of assignment a.
+    _, order = lax.sort((key, ids), num_keys=1, is_stable=True)
+    _, rank = lax.sort((order, ids), num_keys=1, is_stable=True)
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(experts_held)[None, :], axis=0,
+        dtype=jnp.int32,
+    )
+    padded = -(-group_sizes // tile) * tile
+    ends = jnp.cumsum(group_sizes)
+    padded_ends = jnp.cumsum(padded)
+    # A group's rows are shifted right by the padding before it.
+    shift = jnp.concatenate(
+        [(padded_ends - padded) - (ends - group_sizes),
+         jnp.zeros((1,), jnp.int32)]
+    )
+    dest = jnp.where(held, rank + shift[key], rows).reshape(
+        tokens, top_k
+    )
+    active = padded_ends[-1] // tile
+    tile_start = jnp.arange(rows // tile, dtype=jnp.int32) * tile
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(padded_ends, tile_start, side="right"),
+        experts_held - 1,
+    ).astype(jnp.int32)
+    # Past the active tiles: the last active tile's expert, so that
+    # the kernels' index maps stay where they were.
+    last = tile_expert[jnp.maximum(active - 1, 0)]
+    tile_expert = jnp.where(
+        jnp.arange(rows // tile) < active, tile_expert, last
+    )
+    row = jnp.arange(rows, dtype=jnp.int32)
+    row_expert = jnp.repeat(tile_expert, tile)
+    position = row - shift[row_expert]
+    placed = (row < padded_ends[-1]) & (position < ends[row_expert])
+    row_assignment = jnp.where(
+        placed, order[jnp.clip(position, 0, count - 1)], -1
+    )
+    return RowPlan(
+        dest=dest,
+        row_token=jnp.maximum(row_assignment, 0) // top_k,
+        row_assignment=row_assignment,
+        tile_expert=tile_expert,
+        active_tiles=active.reshape(1).astype(jnp.int32),
+        group_sizes=group_sizes,
+    )
+
+
+def _gather_rows(buffer, dest):
+    """``buffer[dest]`` with zeros where ``dest`` is past the buffer:
+    [tokens, top_k, d]. A select, not a product: rows nobody placed
+    may hold anything."""
+    rows = buffer.shape[0]
+    taken = buffer[jnp.minimum(dest, rows - 1)]
+    return jnp.where((dest < rows)[..., None], taken, 0)
+
+
+@jax.custom_vjp
+def dispatch_rows(x, row_token, dest):
+    """Tokens into the row buffer: ``out[r] = x[row_token[r]]``. Both
+    directions are gathers: the transpose sums, per token, the rows
+    its assignments went to."""
+    return x[row_token]
+
+
+def _dispatch_fwd(x, row_token, dest):
+    return x[row_token], dest
+
+
+def _dispatch_bwd(dest, d_rows):
+    return _gather_rows(d_rows, dest).sum(axis=1), None, None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(y_rows, weights, dest, row_assignment):
+    """Rows back to tokens: ``out[t] = sum_j weights[t, j] *
+    y_rows[dest[t, j]]`` over the choices whose expert is held here.
+    The transpose is a gather by row."""
+    taken = _gather_rows(y_rows, dest)
+    return jnp.einsum(
+        "tjd,tj->td", taken, weights.astype(y_rows.dtype)
+    )
+
+
+def _combine_fwd(y_rows, weights, dest, row_assignment):
+    out = combine_rows(y_rows, weights, dest, row_assignment)
+    return out, (y_rows, weights, dest, row_assignment)
+
+
+def _combine_bwd(residuals, d_out):
+    y_rows, weights, dest, row_assignment = residuals
+    top_k = weights.shape[1]
+    placed = row_assignment >= 0
+    at = jnp.maximum(row_assignment, 0)
+    row_weight = jnp.where(placed, weights.reshape(-1)[at], 0.0)
+    d_rows = d_out[at // top_k] * row_weight.astype(d_out.dtype)[:, None]
+    d_weights = jnp.einsum(
+        "tjd,td->tj", _gather_rows(y_rows, dest), d_out,
+        preferred_element_type=jnp.float32,
+    )
+    return d_rows, d_weights.astype(weights.dtype), None, None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_experts(
+    x,
+    router,
+    bias,
+    w_gate,
+    w_up,
+    w_down,
+    *,
+    experts_total: int,
+    first_expert: int,
+    top_k: int,
+    norm_eps: float = 1e-20,
+    scale: float = 1.0,
+):
+    """One chip's share of a dropless top-k expert layer.
+
+    x: [tokens, d] in the compute dtype; router: [d, experts_total];
+    bias: [experts_total]; w_gate / w_up: [experts_held, d, f];
+    w_down: [experts_held, f, d] — the held experts are
+    ``first_expert .. first_expert + experts_held``. Returns
+    ``(y [tokens, d], load)``: ``y[t] = sum over chosen AND held e of
+    weight_e * w_down[e] (silu(w_gate[e] x) * (w_up[e] x))``, and
+    ``load`` the int32 counters ``held_rows [experts_held]``,
+    ``left_out`` (assignments to experts not held here) and
+    ``dropped`` (assignments to held experts that found no row:
+    0 by construction, counted from the plan, not assumed), beside
+    the router's own result, ``experts`` and ``weights`` ``[tokens,
+    top_k]``, for whoever checks the routing itself.
+    """
+    tokens, _ = x.shape
+    experts_held = w_gate.shape[0]
+    assert router.shape[1] == experts_total == bias.shape[0]
+    assert 0 <= first_expert <= experts_total - experts_held
+    tile = gmm.tile_rows(tokens * min(top_k, experts_held))
+    trace.event(
+        "moe.schedule",
+        experts_total=experts_total,
+        experts_held=experts_held,
+        first_expert=first_expert,
+        top_k=top_k,
+        tokens=tokens,
+        rows_capacity=rows_capacity(tokens, top_k, experts_held, tile),
+        tile_rows=tile,
+        d_model=x.shape[1],
+        d_expert=w_gate.shape[2],
+        dtype=x.dtype.name,
+        product="pallas:" + gmm.GMM_KERNEL_NAME + "," + gmm.TGMM_KERNEL_NAME,
+    )
+    experts, weights = sigmoid_top_k(
+        x, router, bias, top_k, norm_eps, scale
+    )
+    plan = lax.stop_gradient(
+        plan_rows(experts, first_expert, experts_held, tile)
+    )
+    groups = (plan.tile_expert, plan.active_tiles, plan.group_sizes)
+    rows = dispatch_rows(x, plan.row_token, plan.dest)
+    hidden = jax.nn.silu(
+        gmm.grouped_matmul(rows, w_gate, *groups)
+    ) * gmm.grouped_matmul(rows, w_up, *groups)
+    y_rows = gmm.grouped_matmul(hidden, w_down, *groups)
+    y = combine_rows(y_rows, weights, plan.dest, plan.row_assignment)
+    held = jnp.sum(plan.dest < rows.shape[0], dtype=jnp.int32)
+    load = {
+        "held_rows": plan.group_sizes,
+        "left_out": jnp.int32(tokens * top_k) - held,
+        "dropped": held - jnp.sum(plan.row_assignment >= 0, dtype=jnp.int32),
+        "experts": experts,
+        "weights": lax.stop_gradient(weights),
+    }
+    return y, load

@@ -94,12 +94,68 @@ class TransformerConfig:
     # token slices as if the batch were split across this many
     # devices, matching an expert-parallel run's per-device capacity.
     moe_dense_slices: int = 1
+    # ---- block options beyond the GPT-2 shape -----------------------
+    # "layernorm" (scale only) or "rmsnorm"; ``norm_eps`` is either's.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    # "gelu" (up, gelu, down) or "swiglu" (down(silu(gate) * up)).
+    ffn: str = "gelu"
+    # Grouped-query attention: ``num_heads`` query heads on this many
+    # key/value heads (query head i on kv head i // group); None =
+    # one kv head a query head. ``qk_norm``: RMSNorm over each head of
+    # q and k (one learned scale of head_dim), before rotary. These
+    # two, and no other option, change the attention's parameter tree
+    # (``q`` + ``kv`` for the fused ``qkv``) and have no
+    # sequence-parallel path.
+    num_kv_heads: int | None = None
+    qk_norm: bool = False
+    rope_theta: float = 10000.0
+    # One mixer kind a layer: "full_attention" or "conv" (the gated
+    # short convolution, ``conv_kernel`` causal taps a channel); None
+    # = attention everywhere.
+    layer_types: tuple[str, ...] | None = None
+    conv_kernel: int = 3
+    # Dropless routed experts (models/moe.py: routed_experts): layers
+    # from ``num_dense_layers`` on replace the FFN with a sigmoid
+    # top-``experts_top_k`` router over ``experts_total`` experts of
+    # width ``d_expert``, of which THIS model holds ``experts_held``
+    # starting at ``first_expert`` (all of them when None) — one
+    # chip's share of an expert-parallel deployment; what the absent
+    # experts would add is left out. 0 experts = no routed layer.
+    experts_total: int = 0
+    experts_held: int | None = None
+    first_expert: int = 0
+    experts_top_k: int = 4
+    d_expert: int = 0
+    num_dense_layers: int = 0
+    expert_weight_eps: float = 1e-20
+    routed_scaling_factor: float = 1.0
+
+    def switch_moe(self, layer: int) -> bool:
+        """Whether block ``layer`` has the Switch FFN (moe_every_n)."""
+        return (
+            self.moe_every_n > 0
+            and self.moe_num_experts > 0
+            and (layer + 1) % self.moe_every_n == 0
+        )
+
+    def mixer(self, layer: int) -> str:
+        return (
+            "full_attention"
+            if self.layer_types is None
+            else self.layer_types[layer]
+        )
+
+    def routed(self, layer: int) -> bool:
+        return self.experts_total > 0 and layer >= self.num_dense_layers
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
+def rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
+) -> jnp.ndarray:
     """Rotary position embedding over the last (head_dim) axis:
     adjacent pairs ``(x[2i], x[2i + 1])`` turned by ``positions *
-    10000 ** (-2i / head_dim)``.
+    theta ** (-2i / head_dim)``.
 
     x: [batch, seq, heads, head_dim], the projection's own layout;
     positions: [seq].
@@ -113,9 +169,8 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
     slices and pads that XLA leaves unfused (PERF.md, PR 27); the
     values are the same to the bit.
     """
-    head_dim = x.shape[-1]
-    lane = jnp.arange(head_dim)
-    freqs = 1.0 / (10000.0 ** ((lane // 2 * 2) / head_dim))
+    lane = jnp.arange(x.shape[-1])
+    freqs = 1.0 / (theta ** ((lane // 2 * 2) / x.shape[-1]))
     angles = positions[:, None] * freqs[None, :]  # [seq, head_dim]
     sin = jnp.sin(angles).astype(x.dtype)
     sin = jnp.where(lane % 2 == 0, -sin, sin)[:, None, :]
@@ -159,8 +214,8 @@ class Attention(nn.Module):
             name="qkv",
         )(x)
         q, k, v = jnp.moveaxis(qkv, -3, 0)  # each [b, s, h, d]
-        q = rope(q, positions)
-        k = rope(k, positions)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
         # attention_fn's contract is [b, h, s, d]. The flash kernels
         # index [b * h, d, s], which is how XLA lays these arrays out
         # by itself, and swap into it themselves: their swap and this
@@ -204,6 +259,206 @@ class Attention(nn.Module):
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
         )(out)
+
+
+def make_norm(cfg: TransformerConfig):
+    """The config's normalisation, scale only: LayerNorm or RMSNorm
+    with ``norm_eps`` (statistics in float32, result in ``dtype``)."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+    if cfg.norm != "layernorm":
+        raise ValueError(
+            f"norm must be 'layernorm' or 'rmsnorm', got {cfg.norm!r}"
+        )
+    return nn.LayerNorm(
+        epsilon=cfg.norm_eps, dtype=cfg.dtype, use_bias=False
+    )
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention with fewer key/value heads than query heads,
+    optional per-head RMSNorm on q and k, and the config's rotary
+    base. ``attention_fn`` keeps its ``[b, h, s, d]`` contract with
+    equal head counts: each kv head is repeated ``group`` times on the
+    way in, and autodiff sums dK / dV over the group on the way out
+    (a kv index inside the flash kernels is later work)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        if cfg.seq_axis is not None:
+            raise ValueError(
+                "grouped-query attention has no sequence-parallel path"
+            )
+        head_dim = cfg.d_model // cfg.num_heads
+        kv_heads = cfg.num_kv_heads or cfg.num_heads
+        group = cfg.num_heads // kv_heads
+        assert group * kv_heads == cfg.num_heads, (
+            f"{cfg.num_heads} query heads on {kv_heads} kv heads"
+        )
+        q = nn.DenseGeneral(
+            (cfg.num_heads, head_dim), axis=-1, dtype=cfg.dtype,
+            use_bias=False, name="q",
+        )(x)
+        kv = nn.DenseGeneral(
+            (2, kv_heads, head_dim), axis=-1, dtype=cfg.dtype,
+            use_bias=False, name="kv",
+        )(x)
+        k, v = jnp.moveaxis(kv, -3, 0)  # each [b, s, kv_heads, d]
+        if cfg.qk_norm:
+            q = nn.RMSNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype, name="q_norm"
+            )(q)
+            k = nn.RMSNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype, name="k_norm"
+            )(k)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
+        attn = cfg.attention_fn
+        if attn is None:
+            from functools import partial
+
+            attn = partial(causal_attention, causal=cfg.causal)
+        out = attn(
+            jnp.swapaxes(q, 1, 2),
+            jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2),
+        )  # [b, h, s, d]
+        out = jnp.swapaxes(out, 1, 2).reshape(
+            x.shape[:-1] + (cfg.d_model,)
+        )
+        return nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
+        )(out)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution: ``[B, C, X] = split3(W_in u)``,
+    ``z = B * X``, a depthwise causal convolution of ``conv_kernel``
+    taps over ``z`` (zero history, no bias), ``y = W_out (C * c)``."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        del positions
+        cfg = self.config
+        taps = cfg.conv_kernel
+        trace.event(
+            "conv.schedule",
+            channels=cfg.d_model,
+            taps=taps,
+            seq_len=x.shape[1],
+            dtype=jnp.dtype(cfg.dtype).name,
+        )
+        bcx = nn.DenseGeneral(
+            (3, cfg.d_model), axis=-1, dtype=cfg.dtype, use_bias=False,
+            name="in_proj",
+        )(x)
+        gate_b, gate_c, inner = jnp.moveaxis(bcx, -2, 0)
+        # weight[j] multiplies z[t - (taps - 1 - j)]: the last tap is
+        # the current position.
+        weight = self.param(
+            "conv",
+            nn.initializers.variance_scaling(1.0, "fan_in", "normal"),
+            (taps, cfg.d_model),
+            jnp.float32,
+        ).astype(cfg.dtype)
+        z = gate_b * inner
+        seq_len = z.shape[1]
+        padded = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+        mixed = sum(
+            weight[j] * padded[:, j:j + seq_len] for j in range(taps)
+        )
+        return nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out_proj"
+        )(gate_c * mixed)
+
+
+class GatedFFN(nn.Module):
+    """SwiGLU: ``down(silu(gate x) * up x)``, no biases."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        gate = nn.Dense(
+            cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_gate"
+        )(x)
+        up = nn.Dense(
+            cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_up"
+        )(x)
+        return nn.Dense(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="ff_down"
+        )(nn.silu(gate) * up)
+
+
+class RoutedFFN(nn.Module):
+    """This model's share of a dropless top-k expert layer
+    (``models.moe.routed_experts``): the router over all
+    ``experts_total`` experts in float32, gated experts of width
+    ``d_expert`` for the ``experts_held`` held here. The expert bias
+    shifts the selection only and no gradient reaches it. The layer's
+    load counters are sown into the "moe_load" collection, the
+    router's choice (``experts``, ``weights``) into "moe_routing"."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from adaptdl_tpu.models.moe import routed_experts
+
+        cfg = self.config
+        held = cfg.experts_held or cfg.experts_total
+        router = self.param(
+            "router", nn.initializers.normal(0.02),
+            (cfg.d_model, cfg.experts_total), jnp.float32,
+        )
+        bias = self.param(
+            "expert_bias", nn.initializers.zeros,
+            (cfg.experts_total,), jnp.float32,
+        )
+        fan_in = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,),
+        )
+        w_gate = self.param(
+            "w_gate", fan_in, (held, cfg.d_model, cfg.d_expert),
+            jnp.float32,
+        )
+        w_up = self.param(
+            "w_up", fan_in, (held, cfg.d_model, cfg.d_expert),
+            jnp.float32,
+        )
+        w_down = self.param(
+            "w_down", fan_in, (held, cfg.d_expert, cfg.d_model),
+            jnp.float32,
+        )
+        y, load = routed_experts(
+            x.reshape(-1, cfg.d_model),
+            router,
+            bias,
+            w_gate,
+            w_up,
+            w_down,
+            experts_total=cfg.experts_total,
+            first_expert=cfg.first_expert,
+            top_k=cfg.experts_top_k,
+            norm_eps=cfg.expert_weight_eps,
+            scale=cfg.routed_scaling_factor,
+        )
+        for name, value in load.items():
+            self.sow(
+                "moe_routing" if name in ("experts", "weights")
+                else "moe_load",
+                name, value,
+            )
+        return y.reshape(x.shape).astype(cfg.dtype)
 
 
 class MoEFFN(nn.Module):
@@ -278,23 +533,48 @@ class MoEFFN(nn.Module):
         return out.reshape(x.shape).astype(cfg.dtype)
 
 
+def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
+    """The layer's sequence mixer. ``num_kv_heads`` and ``qk_norm``
+    are the two options that change the attention's parameter tree
+    (``qkv`` becomes ``q`` and ``kv``, plus the head norms' scales)
+    and give up the sequence-parallel paths: they select
+    ``GroupedQueryAttention``. Everything else, ``rope_theta``
+    included, is the plain ``Attention``'s."""
+    kind = cfg.mixer(layer)
+    if kind == "conv":
+        return ShortConv(cfg, name="short_conv")
+    if kind != "full_attention":
+        raise ValueError(
+            f"layer_types[{layer}] must be 'full_attention' or 'conv', "
+            f"got {kind!r}"
+        )
+    if cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm:
+        return GroupedQueryAttention(cfg, name="attention")
+    return Attention(cfg, name="attention")
+
+
 class Block(nn.Module):
     config: TransformerConfig
     use_moe: bool = False
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions, dropout_rng=None):
         cfg = self.config
-        y = nn.LayerNorm(dtype=cfg.dtype, use_bias=False)(x)
-        y = Attention(cfg, name="attention")(y, positions)
+        y = make_norm(cfg)(x)
+        y = _mixer(cfg, self.layer)(y, positions)
         if cfg.dropout_rate > 0 and dropout_rng is not None:
             y = nn.Dropout(cfg.dropout_rate, deterministic=False)(
                 y, rng=dropout_rng
             )
         x = x + y
-        y = nn.LayerNorm(dtype=cfg.dtype, use_bias=False)(x)
+        y = make_norm(cfg)(x)
         if self.use_moe:
             y = MoEFFN(cfg, name="moe")(y)
+        elif cfg.routed(self.layer):
+            y = RoutedFFN(cfg, name="moe")(y)
+        elif cfg.ffn == "swiglu":
+            y = GatedFFN(cfg, name="ffn")(y)
         else:
             y = nn.Dense(
                 cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_up"
@@ -407,15 +687,10 @@ class TransformerLM(nn.Module):
                 if (train and rng is not None and cfg.dropout_rate > 0)
                 else None
             )
-            use_moe = (
-                cfg.moe_every_n > 0
-                and cfg.moe_num_experts > 0
-                and (layer + 1) % cfg.moe_every_n == 0
-            )
-            x = block_cls(cfg, use_moe=use_moe, name=f"layer_{layer}")(
-                x, positions, dropout_rng
-            )
-        x = nn.LayerNorm(dtype=cfg.dtype, use_bias=False)(x)
+            x = block_cls(
+                cfg, cfg.switch_moe(layer), layer, name=f"layer_{layer}"
+            )(x, positions, dropout_rng)
+        x = make_norm(cfg)(x)
         if return_hidden:
             # For losses that stream the output head themselves (the
             # chunked cross-entropy, ops/chunked_xent.py): no
@@ -501,6 +776,46 @@ def apply_with_moe_aux(
     return out, jnp.zeros(())
 
 
+def moe_load_counters(config: TransformerConfig, mutated) -> dict:
+    """The routed layers' sown load (the "moe_load" collection of an
+    ``apply(..., mutable=["moe_load"])``) stacked over the routed
+    layers in order: ``{"held_rows": int32 [layers, held], "left_out":
+    [layers], "dropped": [layers]}``."""
+    sown = mutated["moe_load"]
+    return {
+        name: jnp.stack(
+            [
+                sown[f"layer_{i}"]["moe"][name][0]
+                for i in range(config.num_layers)
+                if config.routed(i)
+            ]
+        )
+        for name in ("held_rows", "left_out", "dropped")
+    }
+
+
+def routed_lm_loss_fn(model: TransformerLM):
+    """Next-token cross-entropy of a model with routed experts;
+    batch = {"inputs", "targets"}, each [b, s] int32. Returns ``(loss,
+    {"moe.load": counters})``: a loss_fn that returns such a pair has
+    the counters summed over the step by the trainer and journalled as
+    ``moe.load`` events where it pulls its statistics
+    (``ElasticTrainer.run_step``)."""
+
+    def loss_fn(params, batch, rng):
+        logits, mutated = model.apply(
+            {"params": params}, batch["inputs"], train=True, rng=rng,
+            mutable=["moe_load"],
+        )
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, batch["targets"]
+        ).mean()
+        return loss, {"moe.load": moe_load_counters(model.config, mutated)}
+
+    loss_fn.has_counters = True
+    return loss_fn
+
+
 def mlm_loss_fn(
     model: TransformerLM, mask_token: int, mask_rate: float = 0.15
 ):
@@ -561,6 +876,6 @@ def moe_param_sharding_fn(path, leaf):
     keys = tuple(
         str(p.key) if hasattr(p, "key") else str(p) for p in path
     )
-    if "moe" in keys and keys[-1] in ("w_up", "w_down"):
+    if "moe" in keys and keys[-1] in ("w_gate", "w_up", "w_down"):
         return P(EXPERT_AXIS)
     return P()

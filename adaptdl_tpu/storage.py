@@ -79,6 +79,25 @@ def materialize(x, sharding) -> jax.Array:
     )
 
 
+def abstract(tree):
+    """The tree's shapes and dtypes."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x)),
+        tree,
+    )
+
+
+def device_bytes(tree) -> int:
+    """Bytes ONE device holds of a tree of placed arrays (its first
+    addressable shard of every leaf)."""
+    return sum(
+        int(leaf.addressable_shards[0].data.nbytes)
+        for leaf in jax.tree.leaves(tree)
+        if isinstance(leaf, jax.Array)
+        and not jax.dtypes.issubdtype(leaf.dtype, jax.dtypes.prng_key)
+    )
+
+
 def restrict_specs(specs, manual_axes: set):
     """Keep only the shard_map's MANUAL axes in a spec tree:
     pipeline-stage components stay (they are sharded inside the
@@ -207,7 +226,10 @@ class Layout:
         precondition: str | None,
     ):
         self.mesh = mesh
-        self.template = params
+        # The parameter TREE's shapes and dtypes, never its values: a
+        # layout keeps no copy of the parameters beside the state
+        # (init() is handed them).
+        self.template = abstract(params)
         self.optimizer = optimizer
         self.param_sharding_fn = param_sharding_fn
         self.group_ids = group_ids
@@ -223,6 +245,13 @@ class Layout:
 
     def _put(self, x, spec):
         return materialize(x, NamedSharding(self.mesh, spec))
+
+    def _zeros(self):
+        """Zeros of the template's shapes: what :meth:`build` under
+        ``jax.eval_shape`` and a re-primed carry are made from."""
+        return jax.tree.map(
+            lambda t: jnp.zeros(t.shape, t.dtype), self.template
+        )
 
     # ---- the fresh state and its specs --------------------------------
 
@@ -273,25 +302,27 @@ class Layout:
 
         return jax.tree_util.tree_map_with_path(assign, state)
 
-    def build(self):
+    def build(self, params=None):
         """The fresh ``(params, opt_state, gns)`` in run layout, as a
-        traceable function of the template: ``jax.eval_shape`` of it is
-        the state's skeleton before any state exists."""
+        traceable function of the parameters (zeros of the template
+        when not given): ``jax.eval_shape`` of it is the state's
+        skeleton before any state exists."""
+        values = self._zeros() if params is None else params
         return (
-            self.template,
-            self.optimizer.init(self.template),
-            self._fresh_gns(self.template),
+            values,
+            self.optimizer.init(values),
+            self._fresh_gns(values),
         )
 
     def _fresh_gns(self, params):
         return gns.init(params, self.num_groups)
 
-    def init(self):
-        """The fresh ``(params, opt_state, gns)`` on the mesh:
-        data-parallel leaves replicated, tensor-parallel params laid
-        out per ``param_sharding_fn``."""
+    def init(self, params):
+        """The fresh ``(params, opt_state, gns)`` on the mesh from the
+        initial ``params``: data-parallel leaves replicated,
+        tensor-parallel params laid out per ``param_sharding_fn``."""
         specs = self.template_specs()
-        params = jax.tree.map(self._put, self.template, specs)
+        params = jax.tree.map(self._put, params, specs)
         return (
             params,
             self._init_moments(params),
@@ -541,7 +572,7 @@ class _DataSharded(Layout):
             or self.param_sharding_fn is not None
         ):
             raise ValueError(self._composes)
-        flat, self.unravel = ravel_pytree(self.template)
+        flat, self.unravel = ravel_pytree(self._zeros())
         self.n = int(flat.size)
 
     @functools.cached_property
@@ -639,7 +670,7 @@ class Zero1(_DataSharded):
         if self.num_groups > 1:
             self._flat_gids = np.concatenate(
                 [
-                    np.full(int(np.size(leaf)), gid, np.int32)
+                    np.full(int(np.prod(np.shape(leaf))), gid, np.int32)
                     for leaf, gid in zip(
                         jax.tree.leaves(self.template), self.group_ids
                     )
@@ -688,19 +719,20 @@ class Zero1(_DataSharded):
             gns_state = gns_state._replace(prev_grad=self._empty_carry())
         return gns_state
 
-    def build(self):
+    def build(self, params=None):
+        values = self._zeros() if params is None else params
         return (
-            self._store(self.template),
-            self._init_rows_moments(self.template),
-            self._fresh_gns(self.template),
+            self._store(values),
+            self._init_rows_moments(values),
+            self._fresh_gns(values),
         )
 
     def _store(self, params):
         return params
 
-    def init(self):
+    def init(self, params):
         specs = self.template_specs()
-        params = jax.tree.map(self._put, self.template, specs)
+        params = jax.tree.map(self._put, params, specs)
         # Born sharded: jit with out_shardings so the moment rows
         # never exist replicated — an eager init would transiently
         # hold params + flat copy + both replicated moments per
@@ -955,7 +987,7 @@ class Zero3Blocks(_DataSharded):
             )
         self.name = f"{self.name}:{blocks_key}"
         self.blocks_key = blocks_key
-        self.spec = z3.block_spec(self.template, blocks_key)
+        self.spec = z3.block_spec(self._zeros(), blocks_key)
         self.shard_b, self.shard_o = z3.shard_sizes(self.spec, self.dp)
         self.group_ids = (0, 0)  # the rows dict's two leaves
 
@@ -974,22 +1006,24 @@ class Zero3Blocks(_DataSharded):
 
     carry_specs = param_specs
 
-    def build(self):
-        rows = self.tree_to_rows(self.template)
+    def build(self, params=None):
+        rows = self.tree_to_rows(
+            self._zeros() if params is None else params
+        )
         return (
             rows,
             self.optimizer.init(rows),
             self._fresh_gns(rows),
         )
 
-    def init(self):
+    def init(self, params):
         # Born sharded: one jit with rows out_shardings so params,
         # moments, and prev_grad land as [.., dp, shard] rows over
         # the data axis and never exist replicated on device. (The
         # init TREE itself is a replicated host constant — the
         # transient any fresh init or checkpoint load pays; the
         # per-STEP bound is what zero3-blocks guarantees.)
-        params, opt_state, gns_state = jax.eval_shape(self.build)
+        _, opt_state, gns_state = jax.eval_shape(self.build)
         specs = (
             self.param_specs(),
             self._map_mirrors(
@@ -999,7 +1033,9 @@ class Zero3Blocks(_DataSharded):
                 prev_grad=self.carry_specs()
             ),
         )
-        return on_mesh(self.mesh)(self.build, specs)()
+        return on_mesh(self.mesh)(
+            functools.partial(self.build, params), specs
+        )()
 
     # ---- inside the step's shard_map ----------------------------------
 
@@ -1027,7 +1063,10 @@ class Zero3Blocks(_DataSharded):
         return params
 
     def differentiated(self, loss_fn):
-        return lambda rows, *args: loss_fn(self.model_params(rows), *args)
+        # (wraps: a counting loss_fn keeps its ``has_counters``.)
+        return functools.wraps(loss_fn)(
+            lambda rows, *args: loss_fn(self.model_params(rows), *args)
+        )
 
     def whole_sample(self, loss, grad):
         # The row cotangent is the SUM over every device (seq shards
@@ -1137,7 +1176,7 @@ class Zero3Blocks(_DataSharded):
         fresh, invalid = run(
             lambda: (
                 jax.tree.map(
-                    jnp.zeros_like, self.tree_to_rows(self.template)
+                    jnp.zeros_like, self.tree_to_rows(self._zeros())
                 ),
                 jnp.zeros((), bool),
             ),

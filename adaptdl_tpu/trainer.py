@@ -63,6 +63,43 @@ class TrainState(NamedTuple):
     rng: jax.Array
 
 
+def _counts(loss_fn: Callable) -> bool:
+    """A ``loss_fn`` with the attribute ``has_counters = True`` returns
+    ``(loss, counters)``, ``counters`` a ``{event name: {attribute:
+    array}}`` of things it counted (a routed model's expert load); the
+    step sums them over its micro-batches and replicas and hands them
+    out as ``metrics["counters"]``. Any other returns the loss alone
+    and is differentiated exactly as before."""
+    return bool(getattr(loss_fn, "has_counters", False))
+
+
+def _value_and_grad(loss_fn: Callable) -> Callable:
+    return jax.value_and_grad(loss_fn, has_aux=_counts(loss_fn))
+
+
+def _loss_and_counters(loss_fn: Callable, out):
+    """What ``_value_and_grad(loss_fn)`` returned first, as ``(loss,
+    counters or None)``."""
+    return out if _counts(loss_fn) else (out, None)
+
+
+def _journal_counters(counters) -> None:
+    """One trace event a name from the last step's counters, pulled
+    where ``run_step`` has just drained the queue for its statistics
+    (the values are ready: a transfer, no new wait). An attribute with
+    a trailing axis (per expert, say) also gets ``_max`` and ``_mean``
+    over it."""
+    for name, attrs in jax.device_get(counters).items():
+        fields = {}
+        for attr, value in attrs.items():
+            value = np.asarray(value)
+            fields[attr] = value.tolist()
+            if value.ndim >= 2:
+                fields[attr + "_max"] = value.max(axis=-1).tolist()
+                fields[attr + "_mean"] = value.mean(axis=-1).tolist()
+        trace.event(name, **fields)
+
+
 class ElasticTrainer:
     """Builds and caches jitted elastic train steps over a device mesh.
 
@@ -197,6 +234,9 @@ class ElasticTrainer:
             num_groups=self.num_param_groups,
             precondition=precondition,
         )
+        # The initial parameters, until the first fresh state is made
+        # of them: a trainer keeps no copy of the parameters beside
+        # its state (``storage.template`` has their shapes).
         self._init_params = params
         self._step_cache: dict[tuple, Callable] = {}
         self._calibrated: set[int] = set()
@@ -280,24 +320,39 @@ class ElasticTrainer:
             self.state_spec_tree(self._abstract_state()), manual_axes
         )
 
-    def init_state(self) -> TrainState:
+    def init_state(self, params=None) -> TrainState:
         """Fresh TrainState on the mesh: data-parallel leaves
         replicated, tensor-parallel params laid out per
-        ``param_sharding_fn``."""
+        ``param_sharding_fn``. The first is made of the parameters the
+        trainer was built from, which it then lets go of; any further
+        one takes ``params`` (the same tree) from the caller."""
         # Host time: the placements are dispatched here and may still
         # be in flight on the device when the span closes.
         with trace.span("trainer.init_state") as attrs:
-            state = self._init_state()
+            state = self._init_state(params)
             leaves = jax.tree.leaves(state)
             attrs["leaves"] = len(leaves)
             attrs["bytes"] = sum(int(x.nbytes) for x in leaves)
         return state
 
-    def _init_state(self) -> TrainState:
+    def _init_state(self, params=None) -> TrainState:
         def put(x):
             return storage.materialize(x, NamedSharding(self.mesh, P()))
 
-        params, opt_state, gns_state = self.storage.init()
+        if params is None:
+            params, self._init_params = self._init_params, None
+        if params is None:
+            raise ValueError(
+                "this trainer's initial parameters went into its first "
+                "fresh state and it kept no copy: another fresh state "
+                "takes them as init_state(params)"
+            )
+        if storage.abstract(params) != self.storage.template:
+            raise ValueError(
+                "init_state(params): not the parameter tree (shapes "
+                "and dtypes) this trainer was built for"
+            )
+        params, opt_state, gns_state = self.storage.init(params)
         return TrainState(
             params=params,
             opt_state=opt_state,
@@ -336,10 +391,26 @@ class ElasticTrainer:
             if not cell["tried"]:
                 cell["tried"] = True
                 try:
-                    cell["compiled"], hit_fp = aot_cache.load_or_compile(
-                        self, key, cacheable, (state, batch, aux)
+                    cell["fit"] = self._second_state_fits(state)
+                    if cell["fit"]["fits"]:
+                        cell["compiled"], hit_fp = (
+                            aot_cache.load_or_compile(
+                                self, key, cacheable, (state, batch, aux)
+                            )
+                        )
+                        cell["unverified"] = hit_fp
+                        if hit_fp is None:
+                            cell["fit"] = self._second_state_fits(
+                                state, cell["compiled"]
+                            )
+                    if not cell["fit"].pop("fits"):
+                        cell["compiled"] = None
+                    trace.event(
+                        "step.donation",
+                        step=f"{key[0]},{key[1]}",
+                        donated=cell["compiled"] is None,
+                        **cell.pop("fit"),
                     )
-                    cell["unverified"] = hit_fp
                 except Exception:  # noqa: BLE001 - cache best-effort
                     _LOG.warning(
                         "AOT executable cache failed for step %s; "
@@ -368,6 +439,42 @@ class ElasticTrainer:
             return jitted(state, batch, aux)
 
         return stepped
+
+    def _device_bytes_limit(self) -> int | None:
+        """What one device's allocator may hand out, where the
+        backend says (a TPU does; the CPU does not)."""
+        stats = self.mesh.devices.flat[0].memory_stats() or {}
+        return stats.get("bytes_limit")
+
+    def _second_state_fits(self, state, compiled=None) -> dict:
+        """Whether the NON-donating twin the AOT cache runs, whose
+        input and output states are alive together, fits this device;
+        a step whose twin does not fit runs with its state DONATED
+        (the jitted path). Decided from what can be observed; the
+        numbers compared go into the ``step.donation`` event. Before
+        any compile: twice the state one device holds plus the
+        accumulated gradients (a float32 copy of its parameters)
+        against the device's ``bytes_limit``. With the twin compiled
+        (where the backend compiles a program that cannot fit at all):
+        its ``memory_analysis()`` total."""
+        limit = self._device_bytes_limit()
+        state_bytes = storage.device_bytes(state)
+        grad_bytes = storage.device_bytes(state.params)
+        needed, decided_by = 2 * state_bytes + grad_bytes, "state"
+        mem = compiled.memory_analysis() if compiled is not None else None
+        if mem is not None:
+            needed, decided_by = (
+                mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+            ), "program"
+        return {
+            "fits": limit is None or needed <= limit,
+            "decided_by": decided_by,
+            "state_bytes": state_bytes,
+            "grad_bytes": grad_bytes,
+            "needed_bytes": int(needed),
+            "bytes_limit": -1 if limit is None else int(limit),
+        }
 
     def _finalize_step(self, sharded, key) -> Callable:
         """Shared tail of every step builder: AOT-cache wrapping plus
@@ -452,19 +559,19 @@ class ElasticTrainer:
 
             def micro_step(carry, inputs):
                 grad_sum, lsqr_sum, loss_sum = carry
-                mb, mb_rng = inputs
-                loss, grad = jax.value_and_grad(loss_fn)(
-                    wrt, mb, mb_rng, *extra
-                )
+                # (inputs: the micro-batch and its rng.)
+                value_and_grad = _value_and_grad(loss_fn)
+                loss, grad = value_and_grad(wrt, *inputs, *extra)
+                loss, counted = _loss_and_counters(loss_fn, loss)
                 loss, grad = layout.whole_sample(loss, grad)
                 grad_sum = jax.tree.map(jnp.add, grad_sum, grad)
                 lsqr_sum = lsqr_sum + layout.normsqr(grad, precond_micro)
-                return (grad_sum, lsqr_sum, loss_sum + loss), None
+                return (grad_sum, lsqr_sum, loss_sum + loss), counted
 
             grad_init, lsqr_init, loss_init = layout.accumulators(params)
             init = (grad_init, lsqr_init, loss_init)
             xs = (micro_batches, micro_rngs)
-            (grad_sum, lsqr_sum, loss_sum), _ = jax.lax.scan(
+            (grad_sum, lsqr_sum, loss_sum), counted = jax.lax.scan(
                 micro_step, init, xs
             )
             grads, local_sqr_mean, loss = layout.reduce(
@@ -516,6 +623,18 @@ class ElasticTrainer:
                 "progress": progress,
                 "scale": jnp.asarray(scale, jnp.float32),
             }
+            if counted:
+                # What the loss_fn counted (it returned ``(loss,
+                # counters)``), summed over the step's micro-batches
+                # and replicas.
+                metrics["counters"] = jax.tree.map(
+                    lambda c: jax.lax.psum(
+                        c.sum(axis=0),
+                        (DATA_AXIS, SEQ_AXIS) if seq_shards > 1
+                        else DATA_AXIS,
+                    ),
+                    counted,
+                )
             return new_state, metrics
 
         batch_spec = (
@@ -679,9 +798,10 @@ class ElasticTrainer:
             extra = (aux,) if self.has_aux else ()
             wrt = layout.differentiable(layout.assemble(params))
             rng = jax.random.fold_in(rng, jax.lax.axis_index(DATA_AXIS))
-            loss, grads = jax.value_and_grad(
+            loss, grads = _value_and_grad(
                 layout.differentiated(self.loss_fn)
             )(wrt, local_batch, rng, *extra)
+            loss = _loss_and_counters(self.loss_fn, loss)[0]
             total = gns.normsqr(grads) + loss
             if seq_shards > 1:
                 total = jax.lax.pmean(total, SEQ_AXIS)
@@ -795,9 +915,13 @@ class ElasticTrainer:
         self._steps_since_pull += 1
         if self._steps_since_pull >= self.metrics_every:
             self._steps_since_pull = 0
-            # graftcheck: disable=GC202 (deliberate gated pull: drains
-            # once every metrics_every steps, not per step)
-            jax.block_until_ready(metrics_out["loss"])
+            # The span is the wait for the device to finish what was
+            # queued; from its end to the next one's start is the
+            # host's own time for ``metrics_every`` steps.
+            with trace.span("step.pull", steps=self.metrics_every):
+                # graftcheck: disable=GC202 (deliberate gated pull:
+                # drains once every metrics_every steps, not per step)
+                jax.block_until_ready(metrics_out["loss"])
             loss_val = float(metrics_out["loss"])  # graftcheck: disable=GC202 (gated above)
             grad_sqr = float(metrics_out["grad_sqr"])  # graftcheck: disable=GC202 (gated above)
             grad_var = float(metrics_out["grad_var"])  # graftcheck: disable=GC202 (gated above)
@@ -818,6 +942,8 @@ class ElasticTrainer:
                 grad_var=grad_var,
                 dataloader=dataloader,
             )
+            if "counters" in metrics_out:
+                _journal_counters(metrics_out["counters"])
         return state, metrics_out
 
     # ---- checkpoint integration -------------------------------------

@@ -204,7 +204,19 @@ def main():
     # Mixture-of-experts: every 2nd block's FFN becomes a Switch/
     # GShard MoE with this many experts; the expert axis shards over
     # the scheduler's chosen expertShards (ADAPTDL_EXPERT_SHARDS).
-    parser.add_argument("--moe-experts", type=int, default=0)
+    parser.add_argument(
+        "--moe-experts", type=int, default=0,
+        help="experts of the capacity-dropping Switch FFN. The other "
+        "block options are TransformerConfig fields with no flag "
+        "here: norm / norm_eps (RMSNorm), ffn='swiglu', num_kv_heads "
+        "and qk_norm (grouped-query attention; these two change the "
+        "parameter tree), rope_theta, layer_types + conv_kernel "
+        "(gated short convolutions beside attention) and the "
+        "dropless routed experts (experts_total, experts_held, "
+        "first_expert, experts_top_k, d_expert, num_dense_layers; "
+        "loss: models.transformer.routed_lm_loss_fn): "
+        "benchmark/configs/lfm2-8b-a1b.py sets them all",
+    )
     parser.add_argument("--moe-top-k", type=int, default=1)
     # Pipeline parallelism: the block stack runs the GPipe (or
     # interleaved, when the chunk count admits v = chunks/ss > 1)

@@ -39,6 +39,16 @@ def _run_replica(fn, rank, num_replicas, num_restarts, ckpt_dir, port, write_fd)
             "ADAPTDL_NUM_RESTARTS": str(num_restarts),
         }
     )
+    # A reducer an earlier test of this pytest process left behind
+    # (``collective._require`` makes a single-replica one on demand,
+    # ``run_step`` and ``initialize_job`` among its callers) came
+    # through the fork: with it ``collective.initialize()`` is a no-op
+    # and every replica reduces alone. Whether such a test ran before
+    # on this xdist worker is scheduling. Drop it, do not close it:
+    # its sockets and threads are the parent's.
+    from adaptdl_tpu import collective
+
+    collective._reducer = None
     status = 0
     try:
         result = fn()

@@ -780,12 +780,12 @@ def test_residuals_stay_in_the_kernels_layout():
 # ---- rotary without strided lanes ----
 
 
-def _rope_reference(x, positions):
+def _rope_reference(x, positions, theta=10000.0):
     """The formula ``rope`` had before PR 27 (strided halves, a stack
     and a reshape), on the layout it has now, [b, s, h, d]: the judge
-    of the new one."""
+    of the new one (with the base ``rope`` has taken since PR 30)."""
     head_dim = x.shape[-1]
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, head_dim, 2) / head_dim))
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2) / head_dim))
     angles = positions[:, None] * freqs[None, :]  # [seq, head_dim/2]
     sin = jnp.sin(angles)[None, :, None, :].astype(x.dtype)
     cos = jnp.cos(angles)[None, :, None, :].astype(x.dtype)

@@ -108,7 +108,9 @@ def _trained(trainer, steps=2):
         "x": rng.normal(size=(ROWS, D)).astype(np.float32),
         "y": rng.normal(size=(ROWS, 7)).astype(np.float32),
     })
-    state = trainer.init_state()
+    # (A trainer lets go of its initial parameters with its first
+    # fresh state: every further one is handed them.)
+    state = trainer.init_state(_params())
     step = trainer.train_step(ROWS // trainer.num_replicas, 0)
     for _ in range(steps):
         state, _ = step(state, batch)
@@ -141,7 +143,7 @@ def test_layout_contract(layout, dp):
 
     # The spec tree is the placement of the state the layout builds,
     # fresh and after a step, and of its abstract skeleton.
-    fresh = trainer.init_state()
+    fresh = trainer.init_state(_params())
     state = _trained(trainer)
     abstract = trainer._abstract_state()
     for built in (fresh, state):
