@@ -36,6 +36,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import shutil
 import subprocess
@@ -880,6 +881,16 @@ def _cmd_trace(args) -> int:  # wire: consumes=trace_payload,trace_span
                 f"  x{len(durs[name]):<4}"
                 f" total {sum(durs[name]) * 1e3:>10.2f} ms"
             )
+    events = collections.Counter(
+        rec["name"] for rec in selected if rec.get("kind") == "event"
+    )
+    if events:
+        # Point events have no bar to draw; their counts say e.g. how
+        # a restart got its calibration (step.calibrate_reused here,
+        # a step.calibrate span above) and its step (aot.hit / .miss).
+        print("\nevents:")
+        for name in sorted(events):
+            print(f"  {name:<28} x{events[name]}")
     if args.perfetto:
         with open(args.perfetto, "w", encoding="utf-8") as f:
             json.dump(trace.to_perfetto(selected), f)

@@ -297,6 +297,20 @@ def profile_accum_time(atomic_bsz: int, accum_time: float) -> None:
         entry.accum_count += 1
 
 
+def accum_time_on_record(atomic_bsz: int) -> tuple[float, int] | None:
+    """What calibration has already measured for ``atomic_bsz`` under
+    the RUNNING layout: (mean seconds, observations) of the entry
+    ``profile_accum_time`` would add to now, else None. A restored
+    profile answers for a predecessor that ran this very layout; a
+    new batch size or another replica count is another key."""
+    key = _profile_key(atomic_bsz)
+    with _profile_lock:
+        entry = _state.profile.get(key)
+        if entry is None or entry.accum_count <= 0:
+            return None
+        return entry.accum_time_sum / entry.accum_count, entry.accum_count
+
+
 def profile_step(
     atomic_bsz: int, accum_steps: int, step_time: float
 ) -> None:
@@ -857,7 +871,12 @@ class _MetricsCheckpoint(checkpoint.State):
 
 
 def ensure_checkpoint_registered() -> None:
+    """Register the metrics state so that it is saved, and restore it
+    from the job's newest checkpoint — once: a second call finds it
+    registered and leaves it alone. Every process calls this (all load
+    the same payload); without a checkpoint it only registers."""
     try:
-        _MetricsCheckpoint()
+        state = _MetricsCheckpoint()
     except ValueError:
-        pass  # already registered
+        return  # already registered, and restored then
+    checkpoint.load_state(state)

@@ -100,6 +100,32 @@ def _journal_counters(counters) -> None:
         trace.event(name, **fields)
 
 
+def _calibration_on_record(atomic_bsz: int) -> bool:
+    """Whether the (restored) metrics profile already holds the
+    compute-only time ``calibrate_accum_time`` would measure now:
+    same batch size, same layout (``metrics.accum_time_on_record``).
+    The calibration program is SPMD over the whole mesh and a process
+    that skipped it while another ran it would hang the job, so with
+    several processes every one takes rank 0's answer; with one, no
+    collective runs. A reuse journals ``step.calibrate_reused`` where
+    a measurement journals its ``step.calibrate`` span."""
+    from adaptdl_tpu import collective, env, metrics
+
+    found = metrics.accum_time_on_record(atomic_bsz)
+    # The world size is the same on every rank: all broadcast or none.
+    if env.num_processes() > 1:
+        found = collective.broadcast(found)
+    if found is None:
+        return False
+    trace.event(
+        "step.calibrate_reused",
+        atomic_bsz=int(atomic_bsz),
+        accum_time_s=float(found[0]),
+        observations=int(found[1]),
+    )
+    return True
+
+
 class ElasticTrainer:
     """Builds and caches jitted elastic train steps over a device mesh.
 
@@ -895,10 +921,14 @@ class ElasticTrainer:
         atomic_bsz = dataloader.current_atomic_bsz
         accum_steps = dataloader.current_accum_steps
         if atomic_bsz not in self._calibrated:
-            self.calibrate_accum_time(
-                state, host_batch, atomic_bsz,
-                aux=aux if self.has_aux else (),
-            )
+            # A predecessor that ran this layout at this batch size
+            # left its measurement in the restored profile: the
+            # successor does not build and time the program again.
+            if not _calibration_on_record(atomic_bsz):
+                self.calibrate_accum_time(
+                    state, host_batch, atomic_bsz,
+                    aux=aux if self.has_aux else (),
+                )
             self._calibrated.add(atomic_bsz)
         step_fn = self.train_step(atomic_bsz, accum_steps)
         batch = self.shard_batch(host_batch)

@@ -60,7 +60,7 @@ def _loss_fn(params, batch, rng):
     return jnp.mean((pred - batch["y"]) ** 2)
 
 
-def _incarnation(num_replicas, preempt_after_steps=None):
+def _incarnation(num_replicas, preempt_after_steps=None, autoscale=True):
     """One process incarnation of the user program.
 
     Returns (final_state, epochs_visited, losses) or raises SystemExit
@@ -89,9 +89,10 @@ def _incarnation(num_replicas, preempt_after_steps=None):
 
     dataset = _dataset()
     loader = AdaptiveDataLoader(dataset, batch_size=32, name="e2e-loader")
-    loader.autoscale_batch_size(
-        256, local_bsz_bounds=(8, 64), gradient_accumulation=True
-    )
+    if autoscale:
+        loader.autoscale_batch_size(
+            256, local_bsz_bounds=(8, 64), gradient_accumulation=True
+        )
     accum = Accumulator(name="e2e-accum")
 
     epochs_visited = []
@@ -144,6 +145,59 @@ def test_elastic_preempt_rescale_resume(tmp_path, monkeypatch):
     assert losses[-1] < 0.1
     # Profiling survived and accumulated across both incarnations.
     assert metrics.current_state().max_profiled_replicas == 8
+
+
+def test_same_layout_restart_reuses_the_calibration(
+    tmp_path, monkeypatch
+):
+    """Preempted and restarted under the SAME layout and batch size,
+    the successor does not time the compute-only program again: its
+    journal holds ``step.calibrate_reused`` where the predecessor's
+    holds ``step.calibrate``, and its first fit of the performance
+    model is fed the predecessor's measurement."""
+    from adaptdl_tpu import trace
+
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    monkeypatch.setenv("ADAPTDL_NUM_NODES", "1")
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "2")
+    fits = []
+    fit_perf_params = metrics.fit_perf_params
+
+    def recording_fit(nodes, replicas, bszs, accum_times, *args, **kw):
+        fits.append((list(bszs), list(accum_times)))
+        return fit_perf_params(
+            nodes, replicas, bszs, accum_times, *args, **kw
+        )
+
+    monkeypatch.setattr(metrics, "fit_perf_params", recording_fit)
+
+    def named(name):
+        return [r for r in trace.snapshot_spans() if r["name"] == name]
+
+    monkeypatch.setenv("ADAPTDL_NUM_RESTARTS", "0")
+    with pytest.raises(SystemExit):
+        _incarnation(2, preempt_after_steps=5, autoscale=False)
+    (measured,) = named("step.calibrate")
+    assert not named("step.calibrate_reused")
+    accum_time, count = metrics.accum_time_on_record(16)
+    assert (accum_time, count) == (measured["attrs"]["best_s"], 1)
+
+    monkeypatch.setenv("ADAPTDL_NUM_RESTARTS", "1")
+    _signal.set_exit_flag(False)
+    trace._reset_state()
+    del fits[:]
+    with pytest.raises(SystemExit):
+        _incarnation(2, preempt_after_steps=3, autoscale=False)
+    metrics._fit_thread.join(timeout=120)
+    (reused,) = named("step.calibrate_reused")
+    assert reused["attrs"] == {
+        "atomic_bsz": 16, "accum_time_s": accum_time, "observations": 1,
+    }
+    assert not named("step.calibrate")
+    assert named("goodput.fit")
+    assert fits[0] == ([16], [accum_time])
+    # No sample was added for a measurement that was not made.
+    assert metrics.accum_time_on_record(16) == (accum_time, 1)
 
 
 def test_elastic_preempt_rescale_resume_zero3_blocks(

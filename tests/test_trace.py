@@ -798,6 +798,31 @@ def test_cli_trace_reads_a_journal(tmp_path, monkeypatch):
     assert cli.main(["trace", "ns/journaled"]) == 2
 
 
+def test_cli_trace_counts_the_journals_events(tmp_path, monkeypatch):
+    """Point events have no bar in the waterfall; the CLI counts them
+    by name under it, so a journal shows how a restart got its
+    calibration: ``step.calibrate_reused`` beside ``step.calibrate``."""
+    from adaptdl_tpu import cli
+
+    monkeypatch.setenv("ADAPTDL_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("ADAPTDL_JOB_ID", "ns/events")
+    trace._reset_state()
+    with trace.span("step.calibrate", atomic_bsz=8):
+        pass
+    trace.event("step.calibrate_reused", atomic_bsz=16)
+    trace.event("step.calibrate_reused", atomic_bsz=32)
+    stdout = io.StringIO()
+    with redirect_stdout(stdout):
+        rc = cli.main(
+            ["trace", "ns/events", "--journal", trace.journal_path()]
+        )
+    assert rc == 0
+    spans, _, events = stdout.getvalue().partition("\nevents:\n")
+    assert "step.calibrate " in spans
+    assert "step.calibrate_reused" not in spans
+    assert events.split() == ["step.calibrate_reused", "x2"]
+
+
 # ---- the restart's dark half: jit.* bridge + set-up spans ------------
 
 
@@ -1148,3 +1173,70 @@ def test_worker_prologue_lies_inside_restart_first_step(
             assert start - 0.05 <= rec["ts"], name
             assert rec["ts"] + rec["dur"] <= end + 0.05, name
     assert umbrella["trace"] == trace.parse_traceparent(header)[0]
+
+
+def test_restored_worker_reuses_calibration_inside_restart_first_step(
+    tmp_path, monkeypatch, compile_cache_config_restored
+):
+    """The twin of the prologue test for a RESTORED job: a second
+    incarnation under the same layout registers the metrics state,
+    which restores the predecessor's profile, and its first step finds
+    the calibration on record. ``step.calibrate_reused`` lies inside
+    ``restart.first_step`` and there is no ``step.calibrate`` span."""
+    import jax
+
+    from adaptdl_tpu import bootstrap, epoch, metrics
+    from adaptdl_tpu.data import AdaptiveDataLoader
+
+    header = trace.new_traceparent()
+    monkeypatch.setenv("ADAPTDL_TRACEPARENT", header)
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+
+    def incarnation(restarts):
+        monkeypatch.setenv("ADAPTDL_NUM_RESTARTS", str(restarts))
+        monkeypatch.setattr(bootstrap, "_restart_span_armed", False)
+        checkpoint._reset_registry()
+        trace._reset_state()
+        metrics._reset_state()
+        epoch._reset_state()
+        bootstrap.initialize_job()
+        try:
+            trainer = _tiny_trainer()
+            state = trainer.init_state()
+            metrics.ensure_checkpoint_registered()
+            loader = AdaptiveDataLoader(
+                _tiny_dataset(), batch_size=8, name="trace-restored"
+            )
+            for _ in epoch.remaining_epochs_until(1):
+                for batch in loader:
+                    state, m = trainer.run_step(state, batch, loader)
+                    if _spans("restart.first_step"):
+                        checkpoint.save_all_states()
+                        break
+            jax.block_until_ready(m["loss"])
+        finally:
+            bootstrap.stop_heartbeat()
+
+    try:
+        incarnation(0)
+        (measured,) = _spans("step.calibrate")
+        assert not _spans("step.calibrate_reused")
+        incarnation(1)
+    finally:
+        metrics._reset_state()
+        epoch._reset_state()
+    (umbrella,) = _spans("restart.first_step")
+    assert umbrella["inc"] == 1
+    start, end = umbrella["ts"], umbrella["ts"] + umbrella["dur"]
+    assert not _spans("step.calibrate")
+    (reused,) = _spans("step.calibrate_reused")
+    assert reused["attrs"]["atomic_bsz"] == 8
+    assert reused["attrs"]["accum_time_s"] == measured["attrs"]["best_s"]
+    assert reused["attrs"]["observations"] == 1
+    restored = [r["attrs"]["state"] for r in _spans("ckpt.restore")]
+    assert "adaptdl_metrics" in restored
+    for rec in [reused, *_spans("ckpt.restore")]:
+        assert rec["trace"] == umbrella["trace"], rec["name"]
+        assert start - 0.05 <= rec["ts"], rec["name"]
+        assert rec["ts"] + rec.get("dur", 0.0) <= end + 0.05, rec["name"]
