@@ -353,6 +353,26 @@ def sigmoid_top_k(x, router, bias, top_k: int, eps: float, scale: float):
     return experts, weights
 
 
+def softmax_top_k(x, router, top_k: int, eps: float, scale: float):
+    """The other published router: float32 logits over ALL experts,
+    softmax over all of them, the ``top_k`` largest probabilities,
+    divided by their sum, times ``scale``; no bias buffer.
+
+    x: [tokens, d]; router: [d, experts] -> (experts int32 [tokens,
+    top_k], weights float32 [tokens, top_k]).
+    """
+    probs = jax.nn.softmax(
+        jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        ),
+        axis=-1,
+    )
+    chosen, experts = lax.top_k(probs, top_k)
+    weights = chosen / (chosen.sum(-1, keepdims=True) + eps) * scale
+    return experts, weights
+
+
 def rows_capacity(
     tokens: int, top_k: int, experts_held: int, tile: int
 ) -> int:
@@ -498,11 +518,13 @@ def routed_experts(
     top_k: int,
     norm_eps: float = 1e-20,
     scale: float = 1.0,
+    router_kind: str = "sigmoid",
 ):
     """One chip's share of a dropless top-k expert layer.
 
     x: [tokens, d] in the compute dtype; router: [d, experts_total];
-    bias: [experts_total]; w_gate / w_up: [experts_held, d, f];
+    bias: [experts_total] (``router_kind`` "sigmoid": ``sigmoid_top_k``)
+    or None ("softmax": ``softmax_top_k``); w_gate / w_up: [experts_held, d, f];
     w_down: [experts_held, f, d] — the held experts are
     ``first_expert .. first_expert + experts_held``. Returns
     ``(y [tokens, d], load)``: ``y[t] = sum over chosen AND held e of
@@ -516,7 +538,13 @@ def routed_experts(
     """
     tokens, _ = x.shape
     experts_held = w_gate.shape[0]
-    assert router.shape[1] == experts_total == bias.shape[0]
+    assert router.shape[1] == experts_total
+    if router_kind not in ("sigmoid", "softmax"):
+        raise ValueError(
+            f"router_kind must be 'sigmoid' or 'softmax', got "
+            f"{router_kind!r}"
+        )
+    assert (bias is None) == (router_kind == "softmax")
     assert 0 <= first_expert <= experts_total - experts_held
     tile = gmm.tile_rows(tokens * min(top_k, experts_held))
     trace.event(
@@ -532,10 +560,14 @@ def routed_experts(
         d_expert=w_gate.shape[2],
         dtype=x.dtype.name,
         product="pallas:" + gmm.GMM_KERNEL_NAME + "," + gmm.TGMM_KERNEL_NAME,
+        router=router_kind,
     )
-    experts, weights = sigmoid_top_k(
-        x, router, bias, top_k, norm_eps, scale
-    )
+    if router_kind == "softmax":
+        experts, weights = softmax_top_k(x, router, top_k, norm_eps, scale)
+    else:
+        experts, weights = sigmoid_top_k(
+            x, router, bias, top_k, norm_eps, scale
+        )
     plan = lax.stop_gradient(
         plan_rows(experts, first_expert, experts_held, tile)
     )

@@ -110,9 +110,9 @@ class TransformerConfig:
     num_kv_heads: int | None = None
     qk_norm: bool = False
     rope_theta: float = 10000.0
-    # One mixer kind a layer: "full_attention" or "conv" (the gated
-    # short convolution, ``conv_kernel`` causal taps a channel); None
-    # = attention everywhere.
+    # One mixer kind a layer: "full_attention", "conv" (the gated
+    # short convolution, ``conv_kernel`` causal taps a channel) or
+    # "sparse_attention" (below); None = attention everywhere.
     layer_types: tuple[str, ...] | None = None
     conv_kernel: int = 3
     # Dropless routed experts (models/moe.py: routed_experts): layers
@@ -130,6 +130,29 @@ class TransformerConfig:
     num_dense_layers: int = 0
     expert_weight_eps: float = 1e-20
     routed_scaling_factor: float = 1.0
+    # "sigmoid" (scores + a selection-only bias buffer) or "softmax"
+    # (probabilities over all experts, no bias buffer); either way the
+    # chosen weights are divided by their sum.
+    experts_router: str = "sigmoid"
+    # Width of one attention head where it is not ``d_model //
+    # num_heads`` (the q / kv projections then map ``d_model`` to
+    # ``heads * head_dim`` and ``out`` maps back).
+    head_dim: int | None = None
+    # The "sparse_attention" mixer kind of ``layer_types``: grouped-
+    # query attention over the ``index_topk`` keys a learned indexer
+    # (``index_heads`` heads of ``index_dim`` on ONE key head) selects
+    # for each query (ops/sparse_attention.py); the indexer trains on
+    # its own loss, sown into "indexer_loss".
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # False: an output table of its own (``lm_head``) instead of the
+    # embedding's.
+    tie_embeddings: bool = True
+
+    @property
+    def attention_head_dim(self) -> int:
+        return self.head_dim or self.d_model // self.num_heads
 
     def switch_moe(self, layer: int) -> bool:
         """Whether block ``layer`` has the Switch FFN (moe_every_n)."""
@@ -205,7 +228,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
-        head_dim = cfg.d_model // cfg.num_heads
+        head_dim = cfg.attention_head_dim
         qkv = nn.DenseGeneral(
             (3, cfg.num_heads, head_dim),
             axis=-1,
@@ -254,7 +277,7 @@ class Attention(nn.Module):
                 attn = partial(causal_attention, causal=cfg.causal)
         out = attn(q, k, v)  # [b, h, s, d]
         out = jnp.swapaxes(out, 1, 2).reshape(
-            x.shape[:-1] + (cfg.d_model,)
+            x.shape[:-1] + (cfg.num_heads * head_dim,)
         )
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
@@ -292,7 +315,7 @@ class GroupedQueryAttention(nn.Module):
             raise ValueError(
                 "grouped-query attention has no sequence-parallel path"
             )
-        head_dim = cfg.d_model // cfg.num_heads
+        head_dim = cfg.attention_head_dim
         kv_heads = cfg.num_kv_heads or cfg.num_heads
         group = cfg.num_heads // kv_heads
         assert group * kv_heads == cfg.num_heads, (
@@ -329,11 +352,130 @@ class GroupedQueryAttention(nn.Module):
             jnp.swapaxes(v, 1, 2),
         )  # [b, h, s, d]
         out = jnp.swapaxes(out, 1, 2).reshape(
-            x.shape[:-1] + (cfg.d_model,)
+            x.shape[:-1] + (cfg.num_heads * head_dim,)
         )
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
         )(out)
+
+
+class Indexer(nn.Module):
+    """The lightning indexer's three projections of a block's (stopped)
+    input, as the kernels take them: ``index_q`` [b, heads, s,
+    index_dim] and ``index_k`` [b, s, index_dim] (ONE key head) in the
+    compute dtype, rotary as on q / k; ``index_w`` [b, s, heads]
+    float32 with both scales folded in (``index_dim ** -0.5`` of the
+    dot and ``heads ** -0.5`` of the weights: a positive factor
+    commutes with the ReLU)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        index_q = rope(
+            nn.DenseGeneral(
+                (cfg.index_heads, cfg.index_dim), axis=-1,
+                dtype=cfg.dtype, use_bias=False, name="index_q",
+            )(x),
+            positions, cfg.rope_theta,
+        )
+        index_k = rope(
+            nn.Dense(
+                cfg.index_dim, dtype=cfg.dtype, use_bias=False,
+                name="index_k",
+            )(x)[:, :, None, :],
+            positions, cfg.rope_theta,
+        )[:, :, 0, :]
+        index_w = nn.Dense(
+            cfg.index_heads, dtype=jnp.float32, use_bias=False,
+            precision=jax.lax.Precision.HIGHEST, name="index_w",
+        )(x) * (cfg.index_heads**-0.5 * cfg.index_dim**-0.5)
+        return jnp.swapaxes(index_q, 1, 2), index_k, index_w
+
+
+class SparseAttention(nn.Module):
+    """Grouped-query attention over the keys a learned indexer selects
+    (``ops.sparse_attention``): q / kv / head norms / rotary / out as
+    ``GroupedQueryAttention``, beside them the ``Indexer`` on the
+    STOPPED input. Every query keeps the
+    ``index_topk`` keys of largest index score (all earlier keys while
+    it has fewer); no gradient passes through the choice. The indexer
+    learns from its own loss alone, the Kullback-Leibler divergence of
+    its scores' softmax over the selected keys from the attention's
+    head-mean probabilities, sown token by token into "indexer_loss"; the loss of the model never reaches it. The
+    selection's counters are sown into "sparse_select". Told nothing
+    about chips; no sequence-parallel path."""
+
+    config: TransformerConfig
+
+    def setup(self):
+        cfg = self.config
+        head_dim = cfg.attention_head_dim
+        self.kv_heads = cfg.num_kv_heads or cfg.num_heads
+        assert cfg.num_heads % self.kv_heads == 0, (
+            f"{cfg.num_heads} query heads on {self.kv_heads} kv heads"
+        )
+        self.q = nn.DenseGeneral(
+            (cfg.num_heads, head_dim), axis=-1, dtype=cfg.dtype,
+            use_bias=False,
+        )
+        self.kv = nn.DenseGeneral(
+            (2, self.kv_heads, head_dim), axis=-1, dtype=cfg.dtype,
+            use_bias=False,
+        )
+        if cfg.qk_norm:
+            self.q_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+            self.k_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+        self.indexer = Indexer(cfg)
+        self.out = nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False
+        )
+
+    def project(self, x, positions):
+        """What the kernels take of a block's input: ``(q [b, heads, s,
+        d], k, v [b, kv_heads, s, d], index_q, index_k, index_w)``."""
+        cfg = self.config
+        if cfg.seq_axis is not None:
+            raise ValueError(
+                "sparse attention has no sequence-parallel path: the "
+                "selection ranks ALL earlier keys of a query"
+            )
+        q = self.q(x)
+        k, v = jnp.moveaxis(self.kv(x), -3, 0)  # each [b, s, kv_heads, d]
+        if cfg.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        return (
+            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+            jnp.swapaxes(v, 1, 2),
+            *self.indexer(jax.lax.stop_gradient(x), positions),
+        )
+
+    def __call__(self, x, positions):
+        from adaptdl_tpu.ops import sparse_attention as sparse
+
+        cfg = self.config
+        out, index_loss, count, tied = sparse.sparse_attention(
+            *self.project(x, positions), cfg.index_topk
+        )
+        self.sow("indexer_loss", "loss", index_loss)  # [b, s]
+        seq_len = x.shape[1]
+        for name, value in (
+            ("queries", jnp.int32(x.shape[0] * seq_len)),
+            ("keys_selected", jnp.sum(count)),
+            (
+                "keys_visited",
+                jnp.int32(x.shape[0] * sparse.keys_visited(seq_len)),
+            ),
+            ("tied_queries", jnp.sum(tied)),
+        ):
+            self.sow("sparse_select", name, value)
+        out = jnp.swapaxes(out, 1, 2).reshape(
+            x.shape[:-1] + (cfg.num_heads * cfg.attention_head_dim,)
+        )
+        return self.out(out)
 
 
 class ShortConv(nn.Module):
@@ -419,9 +561,13 @@ class RoutedFFN(nn.Module):
             "router", nn.initializers.normal(0.02),
             (cfg.d_model, cfg.experts_total), jnp.float32,
         )
-        bias = self.param(
-            "expert_bias", nn.initializers.zeros,
-            (cfg.experts_total,), jnp.float32,
+        bias = (
+            self.param(
+                "expert_bias", nn.initializers.zeros,
+                (cfg.experts_total,), jnp.float32,
+            )
+            if cfg.experts_router == "sigmoid"
+            else None  # the softmax router has no bias buffer
         )
         fan_in = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
@@ -451,6 +597,7 @@ class RoutedFFN(nn.Module):
             top_k=cfg.experts_top_k,
             norm_eps=cfg.expert_weight_eps,
             scale=cfg.routed_scaling_factor,
+            router_kind=cfg.experts_router,
         )
         for name, value in load.items():
             self.sow(
@@ -543,10 +690,12 @@ def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
     kind = cfg.mixer(layer)
     if kind == "conv":
         return ShortConv(cfg, name="short_conv")
+    if kind == "sparse_attention":
+        return SparseAttention(cfg, name="attention")
     if kind != "full_attention":
         raise ValueError(
-            f"layer_types[{layer}] must be 'full_attention' or 'conv', "
-            f"got {kind!r}"
+            f"layer_types[{layer}] must be 'full_attention', 'conv' or "
+            f"'sparse_attention', got {kind!r}"
         )
     if cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm:
         return GroupedQueryAttention(cfg, name="attention")
@@ -636,6 +785,13 @@ def block_remat(config: TransformerConfig):
     from adaptdl_tpu.ops.flash_attention import SAVED_LSE, SAVED_OUT
 
     saved_names = (SAVED_OUT, SAVED_LSE)
+    if "sparse_attention" in (config.layer_types or ()):
+        # The selection (its threshold), the indexer's statistics and
+        # loss, and the sparse kernel's out and lse: neither the index
+        # scores, the selection nor the attention forward run twice.
+        from adaptdl_tpu.ops.sparse_attention import SAVED_NAMES
+
+        saved_names += SAVED_NAMES
     policy = jax.checkpoint_policies.save_only_these_names(*saved_names)
     if config.remat_policy is not None:
         policy = jax.checkpoint_policies.save_from_both_policies(
@@ -696,9 +852,31 @@ class TransformerLM(nn.Module):
             # chunked cross-entropy, ops/chunked_xent.py): no
             # [tokens, vocab] logits tensor is ever built.
             return x
+        if not cfg.tie_embeddings:
+            # An output table of its own: operands in the compute
+            # dtype, accumulation and logits in float32.
+            table = self.param(
+                "lm_head",
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=-1, out_axis=-2
+                ),
+                (cfg.vocab_size, cfg.d_model),
+                jnp.float32,
+            )
+            return untied_logits(x, table)
         # Tied output head through the embedding table keeps the only
         # O(vocab x d_model) matmul single-sourced.
         return embed.attend(x).astype(jnp.float32)
+
+
+def untied_logits(hidden, table):
+    """The untied head: ``hidden`` [..., d] in the compute dtype
+    against the output table [vocab, d] rounded to it, accumulated in
+    float32; float32 logits."""
+    return jnp.einsum(
+        "...d,vd->...v", hidden, table.astype(hidden.dtype),
+        preferred_element_type=jnp.float32,
+    )
 
 
 def init_transformer(config: TransformerConfig, rng=None, seq_len=None):
@@ -794,23 +972,70 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
     }
 
 
+def sparse_layers(config: TransformerConfig) -> list[int]:
+    return [
+        i for i in range(config.num_layers)
+        if config.mixer(i) == "sparse_attention"
+    ]
+
+
+def sparse_select_counters(config: TransformerConfig, mutated) -> dict:
+    """What the sparse mixers sowed (an ``apply(..., mutable=
+    ["indexer_loss", "sparse_select"])``) stacked over those layers in
+    order, under the names the trainer journals them by: ``{
+    "sparse.select": {"queries", "keys_selected", "keys_visited",
+    "tied_queries": int32 [layers]}, "indexer.loss": {"loss": float32
+    [layers] (the layer's mean over tokens), "micro_batches": 1}}``.
+    The model's loss adds ``loss.mean()``."""
+    layers = sparse_layers(config)
+
+    def sown(collection, name, layer):
+        return mutated[collection][f"layer_{layer}"]["attention"][name][0]
+
+    return {
+        "sparse.select": {
+            name: jnp.stack([sown("sparse_select", name, i) for i in layers])
+            for name in (
+                "queries", "keys_selected", "keys_visited",
+                "tied_queries",
+            )
+        },
+        "indexer.loss": {
+            "loss": jnp.stack(
+                [jnp.mean(sown("indexer_loss", "loss", i)) for i in layers]
+            ),
+            "micro_batches": jnp.int32(1),
+        },
+    }
+
+
 def routed_lm_loss_fn(model: TransformerLM):
-    """Next-token cross-entropy of a model with routed experts;
+    """Next-token cross-entropy of a model with routed experts, plus
+    the indexer's loss (mean over sparse layers and tokens) where the
+    model has sparse attention layers;
     batch = {"inputs", "targets"}, each [b, s] int32. Returns ``(loss,
     {"moe.load": counters})``: a loss_fn that returns such a pair has
     the counters summed over the step by the trainer and journalled as
     ``moe.load`` events where it pulls its statistics
     (``ElasticTrainer.run_step``)."""
 
+    sparse = sparse_layers(model.config)
+
     def loss_fn(params, batch, rng):
         logits, mutated = model.apply(
             {"params": params}, batch["inputs"], train=True, rng=rng,
-            mutable=["moe_load"],
+            mutable=["moe_load", "indexer_loss", "sparse_select"]
+            if sparse else ["moe_load"],
         )
         loss = optax.softmax_cross_entropy_with_integer_labels(
             logits, batch["targets"]
         ).mean()
-        return loss, {"moe.load": moe_load_counters(model.config, mutated)}
+        counters = {"moe.load": moe_load_counters(model.config, mutated)}
+        if sparse:
+            selected = sparse_select_counters(model.config, mutated)
+            loss = loss + selected["indexer.loss"]["loss"].mean()
+            counters.update(selected)
+        return loss, counters
 
     loss_fn.has_counters = True
     return loss_fn

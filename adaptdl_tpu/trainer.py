@@ -479,14 +479,16 @@ class ElasticTrainer:
         (the jitted path). Decided from what can be observed; the
         numbers compared go into the ``step.donation`` event. Before
         any compile: twice the state one device holds plus the
-        accumulated gradients (a float32 copy of its parameters)
+        accumulated gradients and one micro-batch's own gradient
+        before it is added to them (two float32 copies of its
+        parameters: every step holds both, whatever its activations)
         against the device's ``bytes_limit``. With the twin compiled
         (where the backend compiles a program that cannot fit at all):
         its ``memory_analysis()`` total."""
         limit = self._device_bytes_limit()
         state_bytes = storage.device_bytes(state)
         grad_bytes = storage.device_bytes(state.params)
-        needed, decided_by = 2 * state_bytes + grad_bytes, "state"
+        needed, decided_by = 2 * (state_bytes + grad_bytes), "state"
         mem = compiled.memory_analysis() if compiled is not None else None
         if mem is not None:
             needed, decided_by = (

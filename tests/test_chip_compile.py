@@ -135,6 +135,59 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
+@pytest.mark.parametrize("what", ["fwd", "grad", "fwd_f32_out"])
+def test_sparse_attention_kernels_compile_for_v5e(v5e, chip_compile, what):
+    """The indexer's selection and sparse attention at the published
+    widths (32 / 4 heads of 128, an indexer of 16 x 64, topk 2048) on a
+    row of 4096 in bf16: every kernel is in the program under the name
+    a device trace shows, the forward's four and, in a gradient's, the
+    backward's two instead of the loss's (whose value a gradient does
+    not need)."""
+    sparse = importlib.import_module("adaptdl_tpu.ops.sparse_attention")
+    one = SingleDeviceSharding(v5e.devices[0])
+    seq = 4096
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (
+        arg((1, 32, seq, 128)), arg((1, 4, seq, 128)),
+        arg((1, 4, seq, 128)), arg((1, 16, seq, 64)), arg((1, seq, 64)),
+        arg((1, seq, 16), jnp.float32),
+    )
+
+    def forward(*a):
+        return sparse.sparse_attention(
+            *a, 2048,
+            out_dtype=jnp.float32 if what == "fwd_f32_out" else None,
+        )
+
+    def loss(*a):
+        out, index_loss, _, _ = forward(*a)
+        return out.astype(jnp.float32).sum() + index_loss.sum()
+
+    fn = (
+        jax.grad(loss, argnums=tuple(range(6))) if what == "grad" else forward
+    )
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    found = set(
+        re.findall(
+            r"%[\w\-]*?(sparse_(?:attn|index)_[a-z_]*[a-z])_*[.\d]* = "
+            rf".*{flash_mod.MOSAIC_CALL}",
+            text,
+        )
+    )
+    want = {sparse.SELECT_KERNEL_NAME, sparse.FWD_KERNEL_NAME}
+    want |= (
+        {sparse.KL_KERNEL_NAME} if what != "grad"
+        else {sparse.BWD_Q_KERNEL_NAME, sparse.BWD_KV_KERNEL_NAME}
+    )
+    assert found == want, found
+    assert text.count(flash_mod.MOSAIC_CALL) >= len(want)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
 def test_block_keeps_the_projections_layout_on_v5e(v5e, chip_compile):
     """One remat ``Block`` of the benchmark's model, forward and
     gradient at the cell's micro-batch, compiled for the described

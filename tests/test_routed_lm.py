@@ -627,6 +627,59 @@ def test_donation_rule(limit, donated, tmp_path, monkeypatch):
     assert leaf.is_deleted() is donated
 
 
+@pytest.mark.parametrize(
+    "limit_of, fits",
+    [
+        (lambda state, grad: 2 * (state + grad), True),
+        (lambda state, grad: 2 * (state + grad) - 1, False),
+        # What the rule compared before it counted a micro-batch's own
+        # gradient: a device this size now donates without a twin.
+        (lambda state, grad: 2 * state + grad, False),
+        # gpt2-124m on a v5e: 16 B of state and 4 B of gradient a
+        # parameter against 15.75 GiB; both readings say "fits".
+        (lambda state, grad: (state + grad) * 15.75 * 2**30
+         / (124.4e6 * 20), True),
+    ],
+    ids=["at", "one_under", "old_threshold", "gpt2_on_v5e"],
+)
+def test_donation_rule_first_reading(limit_of, fits, tmp_path, monkeypatch):
+    """The reading before any compile: two states, the accumulated
+    gradients and one micro-batch's gradient against the device's
+    limit, on both sides of the threshold. A state that cannot fit
+    twice never has its non-donating twin compiled."""
+    from adaptdl_tpu import aot_cache
+
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    sizes = _sizes()
+    built = _built(monkeypatch, sizes)
+    trainer = built["trainer"]
+    state = trainer.init_state()
+    monkeypatch.setattr(trainer, "_device_bytes_limit", lambda: None)
+    sized = trainer._second_state_fits(state)
+    limit = int(limit_of(sized["state_bytes"], sized["grad_bytes"]))
+    monkeypatch.setattr(trainer, "_device_bytes_limit", lambda: limit)
+    reading = trainer._second_state_fits(state)
+    assert reading["fits"] is fits
+    assert reading["decided_by"] == "state"
+    assert reading["needed_bytes"] == 2 * (
+        sized["state_bytes"] + sized["grad_bytes"]
+    )
+    if fits:
+        return
+    monkeypatch.setattr(
+        aot_cache, "load_or_compile",
+        lambda *a, **k: pytest.fail("the twin was compiled"),
+    )
+    data = _config_module().make_dataset(sizes, 5, 8)
+    batch = trainer.shard_batch({k: v[:4] for k, v in data.items()})
+    seen = len(_donation_events())
+    new_state, _ = trainer.train_step(2, 1)(state, batch)
+    jax.block_until_ready(new_state)
+    (event,) = _donation_events()[seen:]
+    assert event["donated"] is True and event["decided_by"] == "state"
+    assert jax.tree.leaves(state.params)[0].is_deleted()
+
+
 def test_a_second_fresh_state_takes_the_parameters():
     """A trainer lets go of its initial parameters with its first
     fresh state, whoever else holds them; another takes them as an

@@ -1,0 +1,438 @@
+"""What the keye-vl-2.0-30b-a3b configuration forced (PR 34), at small
+sizes in float32 against the configuration's own plain reference
+(``benchmark/configs/keye-vl-2.0-30b-a3b.py``, which imports nothing
+from ``adaptdl_tpu``): the indexer's selection, sparse attention and
+its backward, the indexer's own loss, the softmax top-k router, heads
+wider than ``d_model / heads`` and the untied output table."""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from adaptdl_tpu import trace
+from adaptdl_tpu.models import moe
+from adaptdl_tpu.models.transformer import (
+    GroupedQueryAttention,
+    SparseAttention,
+    TransformerConfig,
+    TransformerLM,
+    routed_lm_loss_fn,
+    sparse_select_counters,
+)
+from adaptdl_tpu.ops import sparse_attention as sparse
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = {
+    "hidden_size": 64, "intermediate_size": 96,
+    "moe_intermediate_size": 24, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 32, "router_width": 8,
+    "experts_held": 8, "num_experts": 8, "num_local_experts": 8,
+    "num_experts_per_tok": 2, "vocab_size": 97, "sequence_length": 32,
+    "num_hidden_layers": 1, "compute_dtype": "float32",
+    "sa_config": {
+        "indexer_head_dim": 16, "indexer_num_heads": 3,
+        "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+        "q_chunk_size": 512, "topk": 8,
+    },
+}
+
+
+@functools.cache
+def _config_module():
+    from benchmark import manifest
+
+    return manifest.load_module(
+        os.path.join(ROOT, "benchmark", "configs", "keye-vl-2.0-30b-a3b.py")
+    )
+
+
+def _sizes(**changes):
+    with open(
+        os.path.join(
+            ROOT, "benchmark", "configs", "keye-vl-2.0-30b-a3b.json"
+        )
+    ) as f:
+        sizes = json.load(f)
+    sizes.update(TINY)
+    sizes.update(changes)
+    return sizes
+
+
+def _model(sizes, seed=3):
+    cfg = _config_module().model_config(sizes)
+    model = TransformerLM(cfg)
+    params = model.init(
+        jax.random.key(seed),
+        jnp.zeros((1, sizes["sequence_length"]), jnp.int32), train=False,
+    )["params"]
+    return model, params
+
+
+def _row(sizes, seed=5):
+    data = _config_module().make_dataset(sizes, seed, 4)
+    return {k: jnp.asarray(v[:1]) for k, v in data.items()}
+
+
+# ---- the whole model against the plain reference --------------------
+
+
+def test_losses_and_every_gradient_equal_the_reference():
+    """L_LM, L_I and the gradient of their sum to every parameter, the
+    system (kernels interpreted, remat on) against ``jax.grad`` of the
+    plain reference on the same weights and row."""
+    config = _config_module()
+    sizes = _sizes()
+    model, params = _model(sizes)
+    batch = _row(sizes)
+    loss_fn = routed_lm_loss_fn(model)
+    (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, batch, jax.random.key(0)
+    )
+
+    def reference(weights):
+        lm, index, _ = config.reference_loss(
+            weights, batch["inputs"][0], batch["targets"][0], sizes
+        )
+        return lm + index, (lm, index)
+
+    weights = config.reference_weights(params, sizes)
+    (total, (lm, index)), want = jax.value_and_grad(
+        reference, has_aux=True
+    )(weights)
+    np.testing.assert_allclose(loss, total, rtol=2e-6)
+    np.testing.assert_allclose(
+        counters["indexer.loss"]["loss"].mean(), index, rtol=2e-5
+    )
+    assert float(index) > 0 and float(lm) > float(index)
+    got = config.reference_weights(grads, sizes)
+    flat_got, _ = jax.tree_util.tree_flatten_with_path(got)
+    flat_want = jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want) == 3 + 15
+    for (path, a), b in zip(flat_got, flat_want):
+        assert float(jnp.abs(b).max()) > 0, path
+        np.testing.assert_allclose(
+            a, b, atol=3e-5 * float(jnp.abs(b).max()), err_msg=str(path)
+        )
+    select = counters["sparse.select"]
+    row = config.selected_pairs_per_row(32, 8)
+    np.testing.assert_array_equal(select["keys_selected"], [row])
+    np.testing.assert_array_equal(select["queries"], [32])
+    np.testing.assert_array_equal(
+        select["keys_visited"], [sparse.keys_visited(32)]
+    )
+
+
+def test_no_gradient_crosses_between_the_two_losses():
+    """L_LM reaches everything but the indexer's parameters, L_I
+    reaches them and nothing else: exact zeros on the other side."""
+    sizes = _sizes()
+    model, params = _model(sizes)
+    batch = _row(sizes)
+    cfg = model.config
+
+    def losses(params):
+        logits, sown = model.apply(
+            {"params": params}, batch["inputs"], train=True,
+            mutable=["moe_load", "indexer_loss", "sparse_select"],
+        )
+        picked = jnp.take_along_axis(
+            jax.nn.log_softmax(logits), batch["targets"][..., None], -1
+        )
+        return -picked.mean(), sparse_select_counters(cfg, sown)[
+            "indexer.loss"
+        ]["loss"].mean()
+
+    of_lm = jax.grad(lambda p: losses(p)[0])(params)
+    of_index = jax.grad(lambda p: losses(p)[1])(params)
+    for path, leaf in jax.tree_util.tree_flatten_with_path(of_lm)[0]:
+        inside = any(getattr(k, "key", "") == "indexer" for k in path)
+        other = of_index
+        for k in path:
+            other = other[k.key]
+        if inside:
+            assert not np.any(np.asarray(leaf)), path
+            assert np.any(np.asarray(other)), path
+        else:
+            assert not np.any(np.asarray(other)), path
+            assert np.any(np.asarray(leaf)), path
+
+
+# ---- the selection ----------------------------------------------------
+
+
+def _index_inputs(seq, heads=3, dim=16, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 3)
+    return (
+        jax.random.normal(keys[0], (1, heads, seq, dim)),
+        jax.random.normal(keys[1], (1, seq, dim)),
+        jax.random.normal(keys[2], (1, seq, heads)) * 0.3,
+    )
+
+
+def _plain_sets(qi, ki, w, topk):
+    config = _config_module()
+    scores = config.reference_scores(
+        jnp.swapaxes(qi[0], 0, 1) * qi.shape[-1] ** 0.5, ki[0],
+        w[0] * qi.shape[1] ** 0.5,
+    )
+    return scores, config.reference_select(scores, 0, topk)[0]
+
+
+@pytest.mark.parametrize(
+    "case", ["fewer_than_topk", "exactly_topk_after", "ties_to_lower_key",
+             "several_tiles"],
+)
+def test_selection_edges(case):
+    seq, topk, blocks = 64, 8, (16, 32)
+    qi, ki, w = _index_inputs(seq)
+    if case == "ties_to_lower_key":
+        # Every score is 0: a query keeps its LOWEST topk keys.
+        ki = jnp.zeros_like(ki)
+    if case == "several_tiles":
+        blocks = (16, 16)
+    pairs, scores, count, tied = sparse.selected_pairs(
+        qi, ki, w, topk, *blocks
+    )
+    member = np.asarray(pairs[0]).astype(bool)
+    want_scores, want = _plain_sets(qi, ki, w, topk)
+    np.testing.assert_array_equal(member, np.asarray(want))
+    causal = np.tril(np.ones((seq, seq), bool))
+    np.testing.assert_allclose(
+        np.where(causal, scores[0], 0), np.where(causal, want_scores, 0),
+        atol=1e-5,
+    )
+    np.testing.assert_array_equal(
+        count[0], np.minimum(np.arange(seq) + 1, topk)
+    )
+    if case == "fewer_than_topk":
+        np.testing.assert_array_equal(member[:topk], causal[:topk])
+        assert not np.any(np.asarray(tied[0, :topk]))
+    elif case == "exactly_topk_after":
+        assert np.all(member[topk:].sum(-1) == topk)
+        assert not np.any(member & ~causal)
+    elif case == "ties_to_lower_key":
+        lowest = np.arange(seq)[None, :] < topk
+        np.testing.assert_array_equal(member[topk:], (lowest & causal)[topk:])
+        assert np.all(np.asarray(tied[0, topk:]) == 1)
+
+
+def test_a_row_shorter_than_topk_is_grouped_query_attention():
+    """With at most ``topk`` keys a query every earlier key is kept:
+    the sparse mixer equals ``GroupedQueryAttention`` on the same
+    weights to rounding, and its gradient to the input too."""
+    cfg = _config_module().model_config(
+        _sizes(sa_config={**TINY["sa_config"], "topk": 64})
+    )
+    x = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    positions = jnp.arange(32)
+    mixer = SparseAttention(cfg)
+    params = mixer.init(jax.random.key(2), x, positions)["params"]
+    dense = {k: v for k, v in params.items() if k != "indexer"}
+
+    def sparse_out(x):
+        return mixer.apply(
+            {"params": params}, x, positions,
+            mutable=["indexer_loss", "sparse_select"],
+        )[0]
+
+    def dense_out(x):
+        return GroupedQueryAttention(cfg).apply(
+            {"params": dense}, x, positions
+        )
+
+    np.testing.assert_allclose(sparse_out(x), dense_out(x), atol=2e-5)
+    np.testing.assert_allclose(
+        jax.grad(lambda x: jnp.sum(sparse_out(x) ** 2))(x),
+        jax.grad(lambda x: jnp.sum(dense_out(x) ** 2))(x),
+        atol=2e-4,
+    )
+
+
+def test_the_sparse_mixer_raises_under_a_sequence_axis():
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        _config_module().model_config(_sizes()), seq_axis="seq"
+    )
+    with pytest.raises(ValueError, match="sequence-parallel"):
+        SparseAttention(cfg).init(
+            jax.random.key(0), jnp.zeros((1, 32, 64)), jnp.arange(32)
+        )
+
+
+def test_schedule_event_and_kernel_names():
+    """One ``sparse.schedule`` event a traced call site, and the names
+    the benchmark's readers find the kernels by."""
+    qi, ki, w = _index_inputs(32)
+    q = jax.random.normal(jax.random.key(3), (1, 4, 32, 32))
+    kv = jax.random.normal(jax.random.key(4), (1, 2, 32, 32))
+    trace.reset_for_tests() if hasattr(trace, "reset_for_tests") else None
+    before = len(
+        [r for r in trace.snapshot_spans() if r["name"] == "sparse.schedule"]
+    )
+    sparse.sparse_attention(q, kv, kv, qi, ki, w, 8)
+    events = [
+        r for r in trace.snapshot_spans() if r["name"] == "sparse.schedule"
+    ]
+    assert len(events) == before + 1
+    attrs = events[-1]["attrs"]
+    assert attrs["path"] == "causal_tiles_masked"
+    assert (attrs["topk"], attrs["heads"], attrs["head_dim"]) == (8, 4, 32)
+    assert attrs["keys_visited"] == 32 * 32
+    assert sparse.SELECT_KERNEL_NAME.startswith("sparse_index")
+    for name in (
+        sparse.FWD_KERNEL_NAME, sparse.KL_KERNEL_NAME,
+        sparse.BWD_Q_KERNEL_NAME, sparse.BWD_KV_KERNEL_NAME,
+    ):
+        assert name.startswith("sparse_attn")
+
+
+# ---- the softmax router and the share ---------------------------------
+
+
+def _layer(seed=7, tokens=64, d=32, f=24, experts=8):
+    keys = jax.random.split(jax.random.key(seed), 5)
+    return {
+        "x": jax.random.normal(keys[0], (tokens, d)),
+        "router": jax.random.normal(keys[1], (d, experts)) * 0.5,
+        "w1": jax.random.normal(keys[2], (experts, d, f)) * d**-0.5,
+        "w3": jax.random.normal(keys[3], (experts, d, f)) * d**-0.5,
+        "w2": jax.random.normal(keys[4], (experts, f, d)) * f**-0.5,
+    }
+
+
+def test_softmax_router_equals_a_plain_one():
+    layer = _layer()
+    sizes = _sizes()
+    experts, weights = moe.softmax_top_k(
+        layer["x"], layer["router"], 2, 1e-20, 1.0
+    )
+    config = _config_module()
+    want = config.reference_router(layer, layer["x"], sizes)
+    got = config.in_expert_order(experts, weights)
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_allclose(got[1], want[1], atol=1e-6)
+    np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-6)
+    # Softmax is not the sigmoid router: the weights differ.
+    _, sigmoid = moe.sigmoid_top_k(
+        layer["x"], layer["router"], jnp.zeros(8), 2, 1e-20, 1.0
+    )
+    assert float(jnp.abs(jnp.sort(sigmoid) - jnp.sort(weights)).max()) > 1e-3
+    with pytest.raises(ValueError, match="router_kind"):
+        moe.routed_experts(
+            layer["x"], layer["router"], None, layer["w1"], layer["w3"],
+            layer["w2"], experts_total=8, first_expert=0, top_k=2,
+            router_kind="argmax",
+        )
+
+
+@pytest.mark.parametrize("shares", [1, 4])
+def test_the_shares_of_a_softmax_layer_add_up_to_the_uncut_layer(shares):
+    """``shares`` chips, each told which ``8 / shares`` experts it
+    holds: their partial results, summed, are the whole layer's as the
+    uncut reference gives it."""
+    layer = _layer()
+    sizes = _sizes()
+    held = 8 // shares
+    with jax.default_matmul_precision("highest"):
+        whole, counts = _config_module().reference_routed_ffn(
+            layer, layer["x"], sizes, first_expert=0
+        )
+    total, rows = jnp.zeros_like(whole), []
+    for chip in range(shares):
+        at = slice(chip * held, (chip + 1) * held)
+        y, load = moe.routed_experts(
+            layer["x"], layer["router"], None, layer["w1"][at],
+            layer["w3"][at], layer["w2"][at], experts_total=8,
+            first_expert=chip * held, top_k=2, router_kind="softmax",
+        )
+        assert int(load["dropped"]) == 0
+        assert int(load["held_rows"].sum() + load["left_out"]) == 64 * 2
+        rows.append(np.asarray(load["held_rows"]))
+        total = total + y
+    np.testing.assert_allclose(total, whole, atol=5e-5)
+    np.testing.assert_array_equal(np.concatenate(rows), np.asarray(counts))
+
+
+# ---- heads of their own width, the untied table -----------------------
+
+
+def _tree(params):
+    return {
+        "/".join(str(k.key) for k in path): leaf.shape
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]
+    }
+
+
+@pytest.mark.parametrize("family", ["gpt2", "lfm2"])
+def test_the_new_options_keep_the_older_parameter_trees(family):
+    """``head_dim`` and ``tie_embeddings`` left alone build the trees
+    the two older configurations' checkpoints hold (a checkpoint of
+    either restores): every leaf by path and shape."""
+    if family == "gpt2":
+        cfg = TransformerConfig(
+            vocab_size=64, num_layers=1, num_heads=2, d_model=16, d_ff=32,
+            max_seq_len=16, dtype=jnp.float32,
+        )
+        want = {
+            "embed/embedding": (64, 16),
+            "LayerNorm_0/scale": (16,),
+            "layer_0/LayerNorm_0/scale": (16,),
+            "layer_0/LayerNorm_1/scale": (16,),
+            "layer_0/attention/qkv/kernel": (16, 3, 2, 8),
+            "layer_0/attention/out/kernel": (16, 16),
+            "layer_0/ff_up/kernel": (16, 32),
+            "layer_0/ff_down/kernel": (32, 16),
+        }
+    else:
+        cfg = TransformerConfig(
+            vocab_size=64, num_layers=1, num_heads=4, num_kv_heads=2,
+            d_model=16, d_ff=32, max_seq_len=16, dtype=jnp.float32,
+            norm="rmsnorm", ffn="swiglu", qk_norm=True,
+            layer_types=("full_attention",), experts_total=4,
+            experts_held=2, experts_top_k=2, d_expert=8,
+        )
+        want = {
+            "embed/embedding": (64, 16),
+            "RMSNorm_0/scale": (16,),
+            "layer_0/RMSNorm_0/scale": (16,),
+            "layer_0/RMSNorm_1/scale": (16,),
+            "layer_0/attention/q/kernel": (16, 4, 4),
+            "layer_0/attention/kv/kernel": (16, 2, 2, 4),
+            "layer_0/attention/q_norm/scale": (4,),
+            "layer_0/attention/k_norm/scale": (4,),
+            "layer_0/attention/out/kernel": (16, 16),
+            "layer_0/moe/router": (16, 4),
+            "layer_0/moe/expert_bias": (4,),
+            "layer_0/moe/w_gate": (2, 16, 8),
+            "layer_0/moe/w_up": (2, 16, 8),
+            "layer_0/moe/w_down": (2, 8, 16),
+        }
+    assert cfg.head_dim is None and cfg.tie_embeddings
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 16), jnp.int32), train=False
+    )["params"]
+    assert _tree(params) == want
+
+
+def test_wider_heads_and_the_untied_table():
+    """Heads of 32 on a hidden size of 64 (4 x 32 = 128 columns), an
+    output table of its own, and no bias buffer under the softmax
+    router."""
+    sizes = _sizes()
+    _, params = _model(sizes)
+    tree = _tree(params)
+    assert tree["layer_0/attention/q/kernel"] == (64, 4, 32)
+    assert tree["layer_0/attention/kv/kernel"] == (64, 2, 2, 32)
+    assert tree["layer_0/attention/out/kernel"] == (128, 64)
+    assert tree["layer_0/attention/indexer/index_q/kernel"] == (64, 3, 16)
+    assert tree["layer_0/attention/indexer/index_k/kernel"] == (64, 16)
+    assert tree["layer_0/attention/indexer/index_w/kernel"] == (64, 3)
+    assert tree["lm_head"] == (97, 64) == tree["embed/embedding"]
+    assert "layer_0/moe/expert_bias" not in tree
+    assert not np.array_equal(params["lm_head"], params["embed"]["embedding"])
