@@ -19,7 +19,8 @@ cotangent of ``o`` reaches ``q, k, v`` over the selected pairs only and
 nothing else; the cotangent of ``L_I`` reaches ``qi, ki, w`` and nothing
 else; no gradient passes through the choice of ``S``.
 
-**The schedule: every causal tile, masked.** The five kernels walk the
+**The schedule: every causal tile, masked.** The kernels (the
+selection, the forward, the loss's second pass, the backward) walk the
 key tiles at or below the diagonal and multiply every pair of a tile,
 then mask what the selection left out: ``topk`` of up to ``seq`` keys
 a query lie scattered over all key tiles (2048 of 16 384: on average 64
@@ -31,6 +32,22 @@ tile for 128 queries and 8 query heads. ``sparse.schedule`` in the
 trace journal says ``path="causal_tiles_masked"``, and the
 ``keys_visited`` counter of ``sparse.select`` counts what the kernels
 multiply, not what was selected.
+
+**The backward visits a tile once where the row's accumulators fit
+VMEM.** A tile adds to its QUERY tile's dq / dqI / dw and to its KEY
+tile's dk / dv / dkI. ``sparse_attn_bwd`` walks (row, query tile, key
+tile) with the query tile's accumulators in scratch as the forward
+does, and holds dk, dv and dkI of the WHOLE ROW as float32 scratch
+(``backward_schedule``: 72 MiB at 4 kv heads of 128 and a row of
+16 384), added to at the key tile's offset and written out, tile by
+tile, while the row's last query tile passes: mask, index products,
+``S``, ``P``, ``dP`` and ``dS`` are computed once a tile and feed all
+six gradients, five matrix products a pair and head. A row whose
+accumulators are past ``_ROW_BUDGET`` (32 768 keys, or 8 kv heads at
+16 384) takes ``sparse_attn_bwd_q`` and ``sparse_attn_bwd_kv``, which
+hold one tile's accumulators each and compute the shared part twice
+(seven products); ``sparse.schedule`` says which (``backward``,
+``backward_vmem_bytes``). The sums run in the same order either way.
 
 **The selection is two integers a query.** ``index_select`` holds the
 scores of one query tile against all earlier keys in VMEM as
@@ -76,6 +93,10 @@ from adaptdl_tpu import trace
 NEG_INF = -1e30
 INT_MIN = -(2**31)
 _VMEM_LIMIT = 100 * 2**20
+# What the one-kernel backward may hold for a whole row (dK, dV, dkI in
+# float32: ``backward_schedule``) beside its tiles' working set; a
+# row past it takes the two kernels that hold one tile's each.
+_ROW_BUDGET = 76 * 2**20
 
 # The kernels' names in a lowered program and a device trace
 # (``%sparse_attn_fwd.<n>`` ...): the benchmark's readers find the
@@ -84,6 +105,7 @@ _VMEM_LIMIT = 100 * 2**20
 SELECT_KERNEL_NAME = "sparse_index_select"
 FWD_KERNEL_NAME = "sparse_attn_fwd"
 KL_KERNEL_NAME = "sparse_attn_kl"
+BWD_KERNEL_NAME = "sparse_attn_bwd"
 BWD_Q_KERNEL_NAME = "sparse_attn_bwd_q"
 BWD_KV_KERNEL_NAME = "sparse_attn_bwd_kv"
 # What the forward rule names of what it produces; a remat'd block
@@ -154,16 +176,20 @@ def _nn(a, b):
     )
 
 
-def _index_scores(ki, qi_ref, wt_ref):
+def _index_scores(ki, qi_ref, wt_ref, relu_ref=None):
     """``I^T`` of one tile, ``[keys, queries]`` float32: ``ki`` [keys,
     di]; ``qi_ref`` block (1, heads, queries, di); ``wt_ref`` block (1,
     heads, queries). One code path for every kernel: the attention
-    kernels must reproduce the selection kernel's scores to the bit."""
+    kernels must reproduce the selection kernel's scores to the bit.
+    ``relu_ref`` (heads, keys, queries), where given, keeps each head's
+    ``relu(qi_j . ki)`` for the backward (``> 0`` where the head is
+    live), so that no kernel multiplies them a second time."""
     acc = None
     for j in range(qi_ref.shape[1]):
-        part = wt_ref[0, j:j + 1, :] * jnp.maximum(
-            _nt(ki, qi_ref[0, j]), 0.0
-        )
+        relu = jnp.maximum(_nt(ki, qi_ref[0, j]), 0.0)
+        if relu_ref is not None:
+            relu_ref[j] = relu
+        part = wt_ref[0, j:j + 1, :] * relu
         acc = part if acc is None else acc + part
     # -0.0 (every head at or below zero under a negative weight) and
     # +0.0 are one score.
@@ -350,10 +376,12 @@ def index_select(qi, ki, wt, topk: int, block_q: int = 128, block_k: int = 512):
 # ---- attention forward ----------------------------------------------
 
 
-def _tile_mask(ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, k0, q0):
+def _tile_mask(
+    ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, k0, q0, relu_ref=None
+):
     """(selected [keys, queries], the tile's index scores)."""
     tk, tq = ki_ref.shape[1], qi_ref.shape[2]
-    scores = _index_scores(ki_ref[0], qi_ref, wt_ref)
+    scores = _index_scores(ki_ref[0], qi_ref, wt_ref, relu_ref)
     s_pos, t_pos = _positions(k0, q0, tk, tq)
     return _selected(scores, thr_ref[0], cut_ref[0], s_pos, t_pos), scores
 
@@ -572,78 +600,155 @@ def _index_loss(q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk):
 # ---- backward -------------------------------------------------------
 
 
-def _head_grads(h, q_ref, k_ref, v_ref, dot_ref, lse_ref, delta_ref, sel,
-                scale: float):
-    """(p, ds) of one query head on one tile, ``[keys, queries]``."""
-    g = h // (q_ref.shape[1] // k_ref.shape[1])
-    s = _nt(k_ref[0, g], q_ref[0, h]) * scale
-    p = jnp.where(sel, jnp.exp(s - lse_ref[0, h]), 0.0)
-    dp = _nn(v_ref[0, g], dot_ref[0, h])
-    return p, p * (dp - delta_ref[0, h]), g
+def backward_schedule(
+    kv_heads: int, seq_len: int, head_dim: int, index_dim: int
+) -> tuple[str, int]:
+    """(``"one_kernel"`` / ``"two_kernels"``, bytes): what the
+    one-kernel backward holds in VMEM for a whole row — dK and dV
+    ``[kv_heads, seq, head_dim]`` and dkI ``[seq, index_dim]`` as
+    float32, minor dimensions padded to the 128 lanes — and one kernel
+    wherever that fits the budget."""
+    lanes = lambda n: -(-n // 128) * 128  # noqa: E731
+    held = 4 * seq_len * (2 * kv_heads * lanes(head_dim) + lanes(index_dim))
+    return ("one_kernel" if held <= _ROW_BUDGET else "two_kernels"), held
 
 
-def _index_grad_terms(ki, qi_ref, wt_ref, d_scores, j):
-    """Of indexer head ``j`` on one tile: relu(s_j) and ``dI * w_j``
-    where ``s_j > 0``, both ``[keys, queries]`` float32."""
-    s = _nt(ki, qi_ref[0, j])
-    live = s > 0.0
-    return jnp.where(live, s, 0.0), jnp.where(
-        live, d_scores * wt_ref[0, j:j + 1, :], 0.0
+def _zero(*refs):
+    for ref in refs:
+        ref[...] = jnp.zeros_like(ref)
+
+
+def _backward_tile(
+    q_ref, k_ref, v_ref, dot_ref, qi_ref, ki_ref, wt_ref, thr_ref, cut_ref,
+    lse_ref, ilse_ref, delta_ref, dli_ref, relu_ref, k0, q0, scale: float,
+    on_head, on_index_head,
+):
+    """What every backward kernel does on one tile, once: the mask and
+    the indexer heads' relu'd products (kept in ``relu_ref``); per kv
+    head ``g`` (a loop) its query heads ``h`` in turn (unrolled: the
+    scheduler overlaps one head's products with the next one's
+    softmax), ``on_head(h, g, p, ds)`` with ``p`` and ``ds`` ``[keys,
+    queries]`` float32; then ``dL_I / dI`` and per indexer head
+    ``on_index_head(j, dI * relu_j summed over keys [1, queries], dI *
+    w_j where the head is live [keys, queries])``."""
+    heads, kv_heads = q_ref.shape[1], k_ref.shape[1]
+    group = heads // kv_heads
+    sel, scores = _tile_mask(
+        ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, k0, q0, relu_ref
     )
 
+    def heads_of(g, total):
+        for r in range(group):
+            h = g * group + r
+            s = _nt(k_ref[0, g], q_ref[0, h]) * scale
+            p = jnp.where(sel, jnp.exp(s - lse_ref[0, h]), 0.0)
+            dp = _nn(v_ref[0, g], dot_ref[0, h])
+            on_head(h, g, p, p * (dp - delta_ref[0, h]))
+            total = total + p
+        return total
 
-def _d_scores(scores, sel, p_mean, ilse_ref, dli_ref):
-    """``dL_I / dI`` on one tile: ``dli * (softmax_S(I) - p)``."""
-    return jnp.where(
+    p_mean = lax.fori_loop(
+        0, kv_heads, heads_of, jnp.zeros(sel.shape, jnp.float32)
+    ) / heads
+    # dL_I / dI = dli * (softmax_S(I) - p) on the selected pairs.
+    d_scores = jnp.where(
         sel,
         dli_ref[0] * (jnp.exp(scores - ilse_ref[0]) - p_mean),
         0.0,
     )
+    for j in range(qi_ref.shape[1]):
+        relu = relu_ref[j]
+        on_index_head(
+            j,
+            jnp.sum(d_scores * relu, axis=0, keepdims=True),
+            jnp.where(relu > 0.0, d_scores * wt_ref[0, j:j + 1, :], 0.0),
+        )
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, kt_ref, do_ref, dot_ref, qi_ref, ki_ref, kit_ref,
+    wt_ref, thr_ref, cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref,
+    dqt_ref, dqit_ref, dwt_ref, dk_ref, dv_ref, dki_ref,
+    dq_acc, dqi_acc, dw_acc, dk_acc, dv_acc, dki_acc, relu_ref,
+    *, scale: float,
+):
+    """Every gradient from one visit of a tile. Grid (batch, query
+    tile, key tile): dq / dqI / dw accumulate over a query tile's keys
+    as in ``_bwd_q_kernel``; dk / dv / dkI accumulate for the WHOLE ROW
+    in VMEM and leave, tile by tile, while the row's last query tile
+    (which reaches every key tile) passes."""
+    tq, tk = q_ref.shape[2], k_ref.shape[2]
+    qb, kb = pl.program_id(1), pl.program_id(2)
+    keys = pl.ds(pl.multiple_of(kb * tk, tk), tk)
+
+    @pl.when((qb == 0) & (kb == 0))
+    def _init_row():
+        _zero(dk_acc, dv_acc, dki_acc)
+
+    @pl.when(kb == 0)
+    def _init():
+        _zero(dq_acc, dqi_acc, dw_acc)
+
+    @pl.when(kb <= _last_key_tile(qb, tq, tk))
+    def _tile():
+        def on_head(h, g, p, ds):
+            ds = ds.astype(q_ref.dtype)
+            dv_acc[g, keys, :] += _nn(p.astype(do_ref.dtype), do_ref[0, h])
+            dk_acc[g, keys, :] += _nn(ds, q_ref[0, h]) * scale
+            dq_acc[h] += _nn(kt_ref[0, g], ds) * scale
+
+        def on_index_head(j, dw, through):
+            through = through.astype(qi_ref.dtype)
+            dw_acc[j:j + 1, :] += dw
+            dqi_acc[j] += _nn(kit_ref[0], through)
+            dki_acc[keys, :] += _nn(through, qi_ref[0, j])
+
+        _backward_tile(
+            q_ref, k_ref, v_ref, dot_ref, qi_ref, ki_ref, wt_ref, thr_ref,
+            cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref, relu_ref,
+            kb * tk, qb * tq, scale, on_head, on_index_head,
+        )
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _done():
+        dqt_ref[0] = dq_acc[...].astype(dqt_ref.dtype)
+        dqit_ref[0] = dqi_acc[...]
+        dwt_ref[0] = dw_acc[...]
+
+    @pl.when(qb == pl.num_programs(1) - 1)
+    def _done_keys():
+        dk_ref[0] = dk_acc[:, keys, :].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:, keys, :].astype(dv_ref.dtype)
+        dki_ref[0] = dki_acc[keys, :]
 
 
 def _bwd_q_kernel(
     q_ref, k_ref, v_ref, kt_ref, dot_ref, qi_ref, ki_ref, kit_ref, wt_ref,
     thr_ref, cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref,
     dqt_ref, dqit_ref, dwt_ref,
-    dq_acc, dqi_acc, dw_acc, *, scale: float,
+    dq_acc, dqi_acc, dw_acc, relu_ref, *, scale: float,
 ):
-    heads, tq = q_ref.shape[1], q_ref.shape[2]
-    tk = k_ref.shape[2]
-    hi = qi_ref.shape[1]
+    tq, tk = q_ref.shape[2], k_ref.shape[2]
     qb, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        dqi_acc[...] = jnp.zeros_like(dqi_acc)
-        dw_acc[...] = jnp.zeros_like(dw_acc)
+        _zero(dq_acc, dqi_acc, dw_acc)
 
     @pl.when(kb <= _last_key_tile(qb, tq, tk))
     def _tile():
-        sel, scores = _tile_mask(
-            ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, kb * tk, qb * tq
-        )
-
-        def head(h, total):
-            p, ds, g = _head_grads(
-                h, q_ref, k_ref, v_ref, dot_ref, lse_ref, delta_ref, sel,
-                scale,
-            )
+        def on_head(h, g, p, ds):
             dq_acc[h] += _nn(kt_ref[0, g], ds.astype(kt_ref.dtype)) * scale
-            return total + p
 
-        p_mean = lax.fori_loop(
-            0, heads, head, jnp.zeros(sel.shape, jnp.float32)
-        ) / heads
-        d_scores = _d_scores(scores, sel, p_mean, ilse_ref, dli_ref)
-        for j in range(hi):
-            relu, through = _index_grad_terms(
-                ki_ref[0], qi_ref, wt_ref, d_scores, j
-            )
-            dw_acc[j:j + 1, :] += jnp.sum(
-                d_scores * relu, axis=0, keepdims=True
-            )
+        def on_index_head(j, dw, through):
+            dw_acc[j:j + 1, :] += dw
             dqi_acc[j] += _nn(kit_ref[0], through.astype(kit_ref.dtype))
+
+        _backward_tile(
+            q_ref, k_ref, v_ref, dot_ref, qi_ref, ki_ref, wt_ref, thr_ref,
+            cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref, relu_ref,
+            kb * tk, qb * tq, scale, on_head, on_index_head,
+        )
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _done():
@@ -656,43 +761,30 @@ def _bwd_kv_kernel(
     q_ref, k_ref, v_ref, do_ref, dot_ref, qi_ref, ki_ref, wt_ref,
     thr_ref, cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref,
     dk_ref, dv_ref, dki_ref,
-    dk_acc, dv_acc, dki_acc, *, scale: float,
+    dk_acc, dv_acc, dki_acc, relu_ref, *, scale: float,
 ):
-    heads, tq = q_ref.shape[1], q_ref.shape[2]
-    tk = k_ref.shape[2]
-    hi = qi_ref.shape[1]
+    tq, tk = q_ref.shape[2], k_ref.shape[2]
     kb, qb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qb == 0)
     def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-        dki_acc[...] = jnp.zeros_like(dki_acc)
+        _zero(dk_acc, dv_acc, dki_acc)
 
     @pl.when(qb >= _first_query_tile(kb, tq, tk))
     def _tile():
-        sel, scores = _tile_mask(
-            ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, kb * tk, qb * tq
-        )
-
-        def head(h, total):
-            p, ds, g = _head_grads(
-                h, q_ref, k_ref, v_ref, dot_ref, lse_ref, delta_ref, sel,
-                scale,
-            )
+        def on_head(h, g, p, ds):
             dv_acc[g] += _nn(p.astype(do_ref.dtype), do_ref[0, h])
             dk_acc[g] += _nn(ds.astype(q_ref.dtype), q_ref[0, h]) * scale
-            return total + p
 
-        p_mean = lax.fori_loop(
-            0, heads, head, jnp.zeros(sel.shape, jnp.float32)
-        ) / heads
-        d_scores = _d_scores(scores, sel, p_mean, ilse_ref, dli_ref)
-        for j in range(hi):
-            _, through = _index_grad_terms(
-                ki_ref[0], qi_ref, wt_ref, d_scores, j
-            )
+        def on_index_head(j, dw, through):
+            del dw
             dki_acc[...] += _nn(through.astype(qi_ref.dtype), qi_ref[0, j])
+
+        _backward_tile(
+            q_ref, k_ref, v_ref, dot_ref, qi_ref, ki_ref, wt_ref, thr_ref,
+            cut_ref, lse_ref, ilse_ref, delta_ref, dli_ref, relu_ref,
+            kb * tk, qb * tq, scale, on_head, on_index_head,
+        )
 
     @pl.when(qb == pl.num_programs(2) - 1)
     def _done():
@@ -717,6 +809,58 @@ def _attention_backward(
     grid_q = (batch, seq_len // tq, seq_len // tk)
     qs = _query_specs(heads, hi, tq, d, di, _by_query(tq, tk)[0])
     ks = _key_specs(kv_heads, tk, d, di, _by_query(tq, tk)[1])
+    query_out = [
+        jax.ShapeDtypeStruct((batch, heads, d, seq_len), q.dtype, vma=vma),
+        jax.ShapeDtypeStruct((batch, hi, di, seq_len), jnp.float32, vma=vma),
+        jax.ShapeDtypeStruct((batch, hi, seq_len), jnp.float32, vma=vma),
+    ]
+    key_out = [
+        jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+        jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
+        jax.ShapeDtypeStruct(ki.shape, jnp.float32, vma=vma),
+    ]
+    query_scratch = [
+        pltpu.VMEM((heads, d, tq), jnp.float32),
+        pltpu.VMEM((hi, di, tq), jnp.float32),
+        pltpu.VMEM((hi, tq), jnp.float32),
+    ]
+    relu = pltpu.VMEM((hi, tk, tq), jnp.float32)
+    if backward_schedule(kv_heads, seq_len, d, di)[0] == "one_kernel":
+        # The key-side outputs leave under the row's LAST query tile,
+        # the one that reaches every key tile; until then their block
+        # stays put and nothing is written back.
+        last = seq_len // tq - 1
+        leaving = _key_specs(
+            kv_heads, tk, d, di,
+            lambda b, i, j: (b, jnp.where(i == last, j, 0)),
+        )
+        dqt, dqit, dwt, dk, dv, dki = pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale),
+            grid=grid_q,
+            in_specs=[
+                qs["q"], ks["k"], ks["k"], ks["kt"], qs["q"], qs["ot"],
+                qs["qi"], ks["ki"], ks["kit"], qs["wt"], qs["row"],
+                qs["row"], qs["stat"], qs["row"], qs["stat"], qs["row"],
+            ],
+            out_specs=[
+                qs["ot"], qs["qit"], qs["wt"], leaving["k"], leaving["k"],
+                leaving["ki"],
+            ],
+            out_shape=query_out + key_out,
+            scratch_shapes=query_scratch + [
+                pltpu.VMEM((kv_heads, seq_len, d), jnp.float32),
+                pltpu.VMEM((kv_heads, seq_len, d), jnp.float32),
+                pltpu.VMEM((seq_len, di), jnp.float32),
+                relu,
+            ],
+            compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+            interpret=_use_interpret(),
+            name=BWD_KERNEL_NAME,
+        )(
+            q, k, v, kt, do, dot, qi, ki, kit, wt, thr, cut, lse, ilse,
+            delta, dli,
+        )
+        return dqt, dk, dv, dqit, dki, dwt
     dqt, dqit, dwt = pl.pallas_call(
         functools.partial(_bwd_q_kernel, scale=scale),
         grid=grid_q,
@@ -726,18 +870,8 @@ def _attention_backward(
             qs["stat"], qs["row"], qs["stat"], qs["row"],
         ],
         out_specs=[qs["ot"], qs["qit"], qs["wt"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, heads, d, seq_len), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct(
-                (batch, hi, di, seq_len), jnp.float32, vma=vma
-            ),
-            jax.ShapeDtypeStruct((batch, hi, seq_len), jnp.float32, vma=vma),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((heads, d, tq), jnp.float32),
-            pltpu.VMEM((hi, di, tq), jnp.float32),
-            pltpu.VMEM((hi, tq), jnp.float32),
-        ],
+        out_shape=query_out,
+        scratch_shapes=query_scratch + [relu],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=_use_interpret(),
         name=BWD_Q_KERNEL_NAME,
@@ -753,15 +887,12 @@ def _attention_backward(
             qs["row"], qs["stat"], qs["row"],
         ],
         out_specs=[ks["k"], ks["k"], ks["ki"]],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
-            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
-            jax.ShapeDtypeStruct(ki.shape, jnp.float32, vma=vma),
-        ],
+        out_shape=key_out,
         scratch_shapes=[
             pltpu.VMEM((kv_heads, tk, d), jnp.float32),
             pltpu.VMEM((kv_heads, tk, d), jnp.float32),
             pltpu.VMEM((tk, di), jnp.float32),
+            relu,
         ],
         compiler_params=_params("parallel", "parallel", "arbitrary"),
         interpret=_use_interpret(),
@@ -918,6 +1049,9 @@ def sparse_attention(
     batch, heads, seq_len, head_dim = q.shape
     scale = head_dim**-0.5 if scale is None else float(scale)
     tq, tk = _tiles(seq_len, block_q, block_k)
+    backward, held = backward_schedule(
+        k.shape[1], seq_len, head_dim, qi.shape[3]
+    )
     trace.event(
         "sparse.schedule",
         rows=batch,
@@ -933,6 +1067,8 @@ def sparse_attention(
         keys_visited=keys_visited(seq_len, tq, tk),
         dtype=q.dtype.name,
         path=PATH,
+        backward=backward,
+        backward_vmem_bytes=held,
     )
     return _sparse_attention(
         q, k, v, qi, ki, w, topk, scale, tq, tk, out_dtype

@@ -135,25 +135,41 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
-@pytest.mark.parametrize("what", ["fwd", "grad", "fwd_f32_out"])
-def test_sparse_attention_kernels_compile_for_v5e(v5e, chip_compile, what):
+@pytest.mark.parametrize(
+    "what, seq, kv_heads",
+    [
+        ("fwd", 4096, 4),
+        ("grad", 4096, 4),
+        ("fwd_f32_out", 4096, 4),
+        # The benchmark cell's row: dK, dV and dkI of the WHOLE row are
+        # float32 VMEM scratch of the one backward kernel (72 MiB).
+        ("grad", 16384, 4),
+        # Past the budget, by the row and by the kv heads: two kernels.
+        ("grad", 32768, 4),
+        ("grad", 16384, 8),
+    ],
+)
+def test_sparse_attention_kernels_compile_for_v5e(
+    v5e, chip_compile, what, seq, kv_heads
+):
     """The indexer's selection and sparse attention at the published
-    widths (32 / 4 heads of 128, an indexer of 16 x 64, topk 2048) on a
-    row of 4096 in bf16: every kernel is in the program under the name
-    a device trace shows, the forward's four and, in a gradient's, the
-    backward's two instead of the loss's (whose value a gradient does
-    not need)."""
+    widths (32 / 4 heads of 128, an indexer of 16 x 64, topk 2048) in
+    bf16: every kernel is in the program under the name a device trace
+    shows, the forward's four and, in a gradient's, the backward in
+    place of the loss's (whose value a gradient does not need): ONE
+    kernel where the row's accumulators fit the VMEM budget — the
+    cell's row of 16 384 does, under the module's own limit —, the two
+    kernels that hold a tile's each where they do not."""
     sparse = importlib.import_module("adaptdl_tpu.ops.sparse_attention")
     one = SingleDeviceSharding(v5e.devices[0])
-    seq = 4096
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     args = (
-        arg((1, 32, seq, 128)), arg((1, 4, seq, 128)),
-        arg((1, 4, seq, 128)), arg((1, 16, seq, 64)), arg((1, seq, 64)),
-        arg((1, seq, 16), jnp.float32),
+        arg((1, 32, seq, 128)), arg((1, kv_heads, seq, 128)),
+        arg((1, kv_heads, seq, 128)), arg((1, 16, seq, 64)),
+        arg((1, seq, 64)), arg((1, seq, 16), jnp.float32),
     )
 
     def forward(*a):
@@ -178,11 +194,17 @@ def test_sparse_attention_kernels_compile_for_v5e(v5e, chip_compile, what):
             text,
         )
     )
+    schedule, held = sparse.backward_schedule(kv_heads, seq, 128, 64)
+    assert (schedule == "one_kernel") == (
+        seq <= 16384 and kv_heads == 4
+    ) == (held <= sparse._ROW_BUDGET < sparse._VMEM_LIMIT)
     want = {sparse.SELECT_KERNEL_NAME, sparse.FWD_KERNEL_NAME}
-    want |= (
-        {sparse.KL_KERNEL_NAME} if what != "grad"
-        else {sparse.BWD_Q_KERNEL_NAME, sparse.BWD_KV_KERNEL_NAME}
-    )
+    if what != "grad":
+        want |= {sparse.KL_KERNEL_NAME}
+    elif schedule == "one_kernel":
+        want |= {sparse.BWD_KERNEL_NAME}
+    else:
+        want |= {sparse.BWD_Q_KERNEL_NAME, sparse.BWD_KV_KERNEL_NAME}
     assert found == want, found
     assert text.count(flash_mod.MOSAIC_CALL) >= len(want)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

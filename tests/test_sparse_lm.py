@@ -78,10 +78,20 @@ def _row(sizes, seed=5):
     return {k: jnp.asarray(v[:1]) for k, v in data.items()}
 
 
+@pytest.fixture(params=["one_kernel", "two_kernels"])
+def backward(request, monkeypatch):
+    """Both schedules of the backward on the same small shapes: the
+    row-wide accumulators of a test's row always fit, so the two
+    kernels are reached by taking the budget away."""
+    if request.param == "two_kernels":
+        monkeypatch.setattr(sparse, "_ROW_BUDGET", 0)
+    assert sparse.backward_schedule(2, 32, 32, 16)[0] == request.param
+
+
 # ---- the whole model against the plain reference --------------------
 
 
-def test_losses_and_every_gradient_equal_the_reference():
+def test_losses_and_every_gradient_equal_the_reference(backward):
     """L_LM, L_I and the gradient of their sum to every parameter, the
     system (kernels interpreted, remat on) against ``jax.grad`` of the
     plain reference on the same weights and row."""
@@ -127,7 +137,7 @@ def test_losses_and_every_gradient_equal_the_reference():
     )
 
 
-def test_no_gradient_crosses_between_the_two_losses():
+def test_no_gradient_crosses_between_the_two_losses(backward):
     """L_LM reaches everything but the indexer's parameters, L_I
     reaches them and nothing else: exact zeros on the other side."""
     sizes = _sizes()
@@ -265,6 +275,90 @@ def test_the_sparse_mixer_raises_under_a_sequence_axis():
         )
 
 
+def _operands(case, seq=64, dim=32, index_heads=3, index_dim=16):
+    """float32 operands of ``sparse_attention`` on several tiles
+    (blocks of 16 queries and 32 keys) and the ``topk`` to run them
+    with."""
+    rows = 2 if case == "two_rows" else 1
+    heads, kv_heads = (8, 2) if case == "grouped" else (4, 2)
+    keys = jax.random.split(jax.random.key(11), 6)
+    q = jax.random.normal(keys[0], (rows, heads, seq, dim))
+    k = jax.random.normal(keys[1], (rows, kv_heads, seq, dim))
+    v = jax.random.normal(keys[2], (rows, kv_heads, seq, dim))
+    qi = jax.random.normal(keys[3], (rows, index_heads, seq, index_dim))
+    ki = jax.random.normal(keys[4], (rows, seq, index_dim))
+    w = jax.random.normal(keys[5], (rows, seq, index_heads)) * 0.3
+    if case == "ties_at_the_threshold":
+        # Eight distinct index keys, each at eight positions: scores
+        # tie, and the position decides which of the tied keys stay.
+        ki = jnp.tile(ki[:, :8], (1, seq // 8, 1))
+    return (q, k, v, qi, ki, w), 128 if case == "shorter_than_topk" else 8
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["grouped", "ties_at_the_threshold", "shorter_than_topk", "two_rows"],
+)
+def test_the_two_backward_schedules_agree(case, monkeypatch):
+    """On several query and key tiles the one-kernel backward (dK, dV,
+    dkI accumulated for the whole row, zeroed and written once a ROW)
+    and the two kernels give the same six gradients of both outputs,
+    and both the plain reference's, row by row."""
+    config = _config_module()
+    operands, topk = _operands(case)
+    rows, heads, seq, dim = operands[0].shape
+    assert heads > operands[1].shape[1]  # grouped: kv heads are shared
+    weights = jax.random.split(jax.random.key(12), 2)
+    d_out = jax.random.normal(weights[0], (rows, heads, seq, dim))
+    d_loss = jax.random.normal(weights[1], (rows, seq))
+
+    def objective(*operands):
+        out, index_loss, _, tied = sparse.sparse_attention(
+            *operands, topk, block_q=16, block_k=32
+        )
+        return jnp.sum(out * d_out) + jnp.sum(index_loss * d_loss), tied
+
+    def grads(schedule):
+        assert sparse.backward_schedule(
+            operands[1].shape[1], seq, dim, operands[3].shape[3]
+        )[0] == schedule
+        return jax.grad(objective, argnums=tuple(range(6)), has_aux=True)(
+            *operands
+        )
+
+    one, tied = grads("one_kernel")
+    monkeypatch.setattr(sparse, "_ROW_BUDGET", 0)
+    two, _ = grads("two_kernels")
+    if case == "ties_at_the_threshold":
+        assert np.asarray(tied)[:, 16:].mean() > 0.5
+    for a, b in zip(one, two):
+        assert float(jnp.abs(b).max()) > 0
+        np.testing.assert_allclose(a, b, atol=1e-6 * float(jnp.abs(b).max()))
+
+    sizes = {"sa_config": {"topk": topk}}
+    for row in range(rows):
+        alone = tuple(x[row:row + 1] for x in operands)
+
+        def reference(operands):
+            out, index_loss = config.reference_attend(operands, sizes)
+            out = jnp.swapaxes(out.reshape(seq, heads, dim), 0, 1)
+            return jnp.sum(out * d_out[row]) + jnp.sum(
+                index_loss * d_loss[row]
+            )
+
+        want = jax.grad(reference)(config.as_reference_operands(alone, sizes))
+        folded = alone[3].shape[1] ** -0.5 * alone[3].shape[3] ** -0.5
+        in_system_layout = (
+            jnp.swapaxes(want["q"].reshape(seq, heads, dim), 0, 1),
+            jnp.swapaxes(want["k"], 0, 1), jnp.swapaxes(want["v"], 0, 1),
+            jnp.swapaxes(want["qi"], 0, 1), want["ki"], want["w"] / folded,
+        )
+        for got, b in zip(one, in_system_layout):
+            np.testing.assert_allclose(
+                got[row], b, atol=3e-5 * float(jnp.abs(b).max())
+            )
+
+
 def test_schedule_event_and_kernel_names():
     """One ``sparse.schedule`` event a traced call site, and the names
     the benchmark's readers find the kernels by."""
@@ -284,10 +378,21 @@ def test_schedule_event_and_kernel_names():
     assert attrs["path"] == "causal_tiles_masked"
     assert (attrs["topk"], attrs["heads"], attrs["head_dim"]) == (8, 4, 32)
     assert attrs["keys_visited"] == 32 * 32
+    # dK and dV [2, 32, 32 -> 128 lanes] and dkI [32, 16 -> 128] in
+    # float32 fit; the published widths at a row of 16 384 do too, a
+    # row of 32 768 or 8 kv heads do not.
+    assert attrs["backward"] == "one_kernel"
+    assert attrs["backward_vmem_bytes"] == 4 * 32 * (2 * 2 * 128 + 128)
+    assert sparse.backward_schedule(4, 16384, 128, 64) == (
+        "one_kernel", 72 * 2**20
+    )
+    assert sparse.backward_schedule(4, 32768, 128, 64)[0] == "two_kernels"
+    assert sparse.backward_schedule(8, 16384, 128, 64)[0] == "two_kernels"
     assert sparse.SELECT_KERNEL_NAME.startswith("sparse_index")
     for name in (
         sparse.FWD_KERNEL_NAME, sparse.KL_KERNEL_NAME,
-        sparse.BWD_Q_KERNEL_NAME, sparse.BWD_KV_KERNEL_NAME,
+        sparse.BWD_KERNEL_NAME, sparse.BWD_Q_KERNEL_NAME,
+        sparse.BWD_KV_KERNEL_NAME,
     ):
         assert name.startswith("sparse_attn")
 
