@@ -31,7 +31,24 @@ SURVEY.md section 2):
 - :mod:`adaptdl_tpu.sched` — Pollux policy + cluster components.
 """
 
+import sys as _sys
+import time as _time
+
+# What importing this package costs a (re)starting worker, for the
+# ``boot.import`` span that ``initialize_job`` records: the clocks and
+# the size of ``sys.modules`` here and on the last line, and whether
+# the script had imported jax before us.
+_boot = {
+    "start": _time.time(),
+    "mono": _time.monotonic(),
+    "modules": len(_sys.modules),
+    "jax_preloaded": "jax" in _sys.modules,
+}
+
 __version__ = "0.1.0"
 
-from adaptdl_tpu import env  # noqa: F401
-from adaptdl_tpu.bootstrap import initialize_job  # noqa: F401
+from adaptdl_tpu import env  # noqa: E402,F401
+from adaptdl_tpu.bootstrap import initialize_job  # noqa: E402,F401
+
+_boot["seconds"] = _time.monotonic() - _boot["mono"]
+_boot["modules"] = len(_sys.modules) - _boot["modules"]

@@ -17,7 +17,10 @@ init). The TPU-native sequence:
 from __future__ import annotations
 
 import logging
+import os
+import sys
 import threading
+import time
 
 from adaptdl_tpu import _signal, collective, env, rpc, sched_hints, trace
 
@@ -151,6 +154,69 @@ def stop_heartbeat(timeout: float | None = 5.0) -> None:
         _prefetch_thread.join(timeout)
 
 
+def _process_age() -> float | None:
+    """Seconds since the kernel started this process: field 22 of
+    ``/proc/self/stat`` (clock ticks since boot) against
+    ``CLOCK_BOOTTIME``, to the tick (10 ms). None where there is no
+    ``/proc``."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # Field 2, the command, may hold spaces and parentheses:
+            # count from its closing one, after which field 3 follows.
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if age >= 0 else None
+
+
+def _record_boot_spans() -> None:
+    """``boot.process``: the kernel starting this process -> now, the
+    entry of ``initialize_job`` (interpreter start, the script's own
+    imports, reaching the chip where the script does that first, and
+    importing this package). Its child ``boot.import`` is the package
+    import alone, from the clocks ``adaptdl_tpu/__init__.py`` took."""
+    import adaptdl_tpu
+
+    boot = adaptdl_tpu._boot
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    age = _process_age()
+    process = None
+    if age is not None:
+        process = trace.record_span(
+            "boot.process",
+            age,
+            restarts=env.num_restarts(),
+            jax_preloaded=boot["jax_preloaded"],
+            backend_ready=bool(
+                bridge is not None and bridge.backends_are_initialized()
+            ),
+        )
+    trace.record_span(
+        "boot.import",
+        boot["seconds"],
+        traceparent=process,
+        ts=boot["start"],
+        modules=boot["modules"],
+    )
+
+
+def _close_exit_trace() -> None:
+    """atexit, registered by the first ``initialize_job``: before the
+    program's lazily registered joins (checkpoint writer, AOT writer,
+    fit thread), so LIFO order runs it after them. Closes
+    ``exit.atexit`` (opened where ``_check_exit`` calls ``sys.exit``:
+    what those joins cost) and hands the spans since the signal to the
+    successor. What follows (jax's own hooks, interpreter finalisation,
+    the runtime letting go of the chip) is visible only from outside."""
+    trace.end_pending("exit.atexit")
+    since = _signal.signal_time()
+    if since is not None and env.process_rank() == 0:
+        trace.write_handover(since)
+
+
 def initialize_job(distributed: bool | None = None) -> None:
     """Initialize this process for (possibly multi-host) elastic
     training. Idempotent; safe to call in single-process jobs."""
@@ -165,6 +231,15 @@ def initialize_job(distributed: bool | None = None) -> None:
     trace.install_jax_bridge()
     if not _restart_span_armed:
         _restart_span_armed = True
+        if trace.enabled():
+            import atexit
+
+            atexit.register(_close_exit_trace)
+            # The predecessor's signal -> save -> exit first, then this
+            # process's own start -> here: the rescale in time order.
+            if env.num_restarts() > 0 and env.process_rank() == 0:
+                trace.adopt_handover()
+            _record_boot_spans()
         # The restart->first-step window: opened here, closed by the
         # first profiled train step (metrics.profile_step) — the
         # end-to-end restart cost a rescale trace must account for.

@@ -411,7 +411,26 @@ class AdaptiveDataLoader:
             if should_exit:
                 from adaptdl_tpu.sched import preemption
 
-                if preemption.notice_active():
+                notice = preemption.notice_active()
+                # signal -> every replica agreed: the head of the
+                # rescale's trace. The handler's one clock is the wall
+                # clock that the span starts on (a replica that agreed
+                # on a peer's signal has none: a point).
+                since = _signal.signal_time()
+                # graftcheck: disable=GC701 (the signal handler may
+                # only store one clock, and the span's start must be
+                # the machine's wall clock to align two processes)
+                waited = time.time() - since if since else 0.0
+                step_s = metrics.step_time_ewma()
+                trace.record_span(
+                    "exit.agree",
+                    waited,
+                    ts=since,
+                    replicas=env.num_replicas(),
+                    steps=round(waited / step_s, 2) if step_s else None,
+                    notice=bool(notice),
+                )
+                if notice:
                     LOG.info(
                         "graceful exit (preemption notice): urgent "
                         "drain then exit 143"
@@ -438,6 +457,9 @@ class AdaptiveDataLoader:
                         handoff.spawn_server(
                             snapshots=handle.snapshots
                         )
+                # Closed by bootstrap's atexit hook, the last of the
+                # program's: what its joins cost on the way out.
+                trace.begin_pending("exit.atexit")
                 sys.exit(_signal.GRACEFUL_EXIT_CODE)
         self._exit_future = collective.allreduce_async(
             bool(_signal.get_exit_flag()), lambda vs: any(vs)

@@ -39,7 +39,12 @@ CheckFreq's (FAST'21) snapshot/write/stall breakdowns are built on:
 Workers flush their buffered spans to the supervisor (piggybacked on
 the sched-hints cadence) via ``PUT /trace/{job}``; the supervisor
 serves the stitched per-job view on ``GET /trace/{job}`` and the
-``adaptdl-tpu trace`` CLI renders the phase waterfall.
+``adaptdl-tpu trace`` CLI renders the phase waterfall. The final save
+and the exit come after a doomed worker's last flush, so its records
+from the termination signal on cross the restart through one file of
+the checkpoint directory (:func:`write_handover` /
+:func:`adopt_handover`): the successor's own buffer then holds the
+rescale from the signal to its first step, journal or no journal.
 """
 
 from __future__ import annotations
@@ -326,20 +331,23 @@ def record_span(  # wire: produces=trace_span
     traceparent: str | None = None,
     ts: float | None = None,
     **attrs,
-) -> None:
+) -> str | None:
     """Record an already-measured span (the supervisor's epoch
     prepare→commit window is timed by the state layer, not a ``with``
     block; jax reports a compile phase when it has ended). Parents
     like :func:`span`: a ``jit.lower`` recorded while ``aot.compile``
-    is open on the thread is its child."""
+    is open on the thread is its child. Returns the span's own
+    traceparent (None when tracing is off), so that a caller can record
+    a child of it (``boot.import`` under ``boot.process``)."""
     if not enabled():
-        return
+        return None
     trace_id, parent_id = _parent_context(traceparent)
+    span_id = _rand_hex(8)
     _record(
         {
             "name": name,
             "trace": trace_id,
-            "span": _rand_hex(8),
+            "span": span_id,
             "parent": parent_id,
             "ts": time.time() - duration_s if ts is None else ts,
             "dur": max(float(duration_s), 0.0),
@@ -349,6 +357,7 @@ def record_span(  # wire: produces=trace_span
             "inc": _inc(),
         }
     )
+    return format_traceparent(trace_id, span_id)
 
 
 def event(  # wire: produces=trace_span
@@ -600,6 +609,86 @@ def read_journal(path: str) -> list[dict]:
     except OSError:
         return []
     return records
+
+
+# ---- the hand-over: a dying worker's last spans -> its successor ------
+
+# Workers flush to the supervisor on the heartbeat cadence; the final
+# save and the exit come after the last beat, and the ring buffer dies
+# with the process. So the exiting rank 0 leaves its records from the
+# signal on in ONE fixed file of the checkpoint directory (the volume
+# both incarnations share), and the successor puts them into its own
+# ring buffer: its snapshot, its first flush and ``adaptdl-tpu trace``
+# then show the rescale from the signal to the first step. The name
+# matches neither ``checkpoint-*`` nor ``_tmp-checkpoint-*``, which the
+# checkpoint layer scans and prunes.
+HANDOVER_FILE = "trace-handover.jsonl"
+HANDOVER_MAX_RECORDS = 256
+
+
+def handover_path() -> str | None:
+    root = env.checkpoint_path()
+    return os.path.join(root, HANDOVER_FILE) if root else None
+
+
+def write_handover(since: float) -> bool:
+    """Write the ring buffer's records that started at ``since`` (wall
+    clock) or later, the newest ``HANDOVER_MAX_RECORDS`` of them, to the
+    hand-over file: whole to a temporary name, then renamed.
+    Best-effort, like the journal; nothing with tracing off or without
+    a checkpoint directory."""
+    path = handover_path() if enabled() else None
+    if path is None:
+        return False
+    records = [
+        rec for rec in snapshot_spans() if rec.get("ts", 0.0) >= since
+    ][-HANDOVER_MAX_RECORDS:]
+    tmp = f"{path}.tmp-{os.getpid()}"
+    try:
+        with open(tmp, "w", encoding="utf-8") as f:
+            for rec in records:
+                f.write(json.dumps(rec, sort_keys=True) + "\n")
+        os.replace(tmp, path)
+    except OSError:  # noqa: BLE001 - observability is best-effort
+        LOG.debug("trace hand-over write failed", exc_info=True)
+        return False
+    return True
+
+
+def adopt_handover() -> int:
+    """Put the predecessor's handed-over records (those whose ``inc`` is
+    one below this incarnation's: an older incarnation's file is stale)
+    into the ring buffer under their own ``pid`` / ``inc`` / ``ts``,
+    ahead of anything this process records next. Not re-journalled and
+    not observed into this process's histograms: where a journal is
+    configured the predecessor wrote them itself. Returns how many were
+    adopted. The writer renames a whole file into place, so a file with
+    a torn or unparsable line is not its work: like a missing one it
+    adopts nothing, without error."""
+    global _seq
+    path = handover_path() if enabled() else None
+    if path is None:
+        return 0
+    try:
+        with open(path, "rb") as f:
+            lines = sum(1 for raw in f if raw.strip())
+    except OSError:
+        return 0
+    records = read_journal(path)
+    if len(records) != lines:
+        return 0
+    want = _inc() - 1
+    adopted = [
+        rec
+        for rec in records[-HANDOVER_MAX_RECORDS:]
+        if rec.get("inc") == want and "name" in rec and "ts" in rec
+    ]
+    with _buffer_lock:
+        for rec in adopted:
+            _seq += 1
+            rec["seq"] = _seq
+            _buffer_locked().append(rec)
+    return len(adopted)
 
 
 # ---- exporter 2: Chrome/Perfetto trace_event JSON --------------------
