@@ -67,10 +67,19 @@ the forward a second time.
 log-sum-exp) are ``[1, queries]`` rows, reductions over keys are plain
 VPU adds, and nothing with a minor dimension of 1 is stored. The output
 leaves as ``[batch, heads, head_dim, seq]`` and is turned outside.
-All query heads of a query tile are one grid step (a loop over heads
-inside), so the mask of a tile is computed once for the 32 heads and
-dK / dV sum over a kv head's group inside the kernel: no ``repeat`` of
-K and V.
+All query heads of a query tile are one grid step, so the mask of a
+tile is computed once for the 32 heads and dK / dV sum over a kv head's
+group inside the kernel: no ``repeat`` of K and V. Inside the step
+every attention kernel walks the kv heads in a loop and a kv head's
+group of query heads as straight-line code in the loop's body, heads 0
+.. ``heads - 1`` in order, so every sum keeps its order (the body is
+``group`` copies, 8 at the published widths): only inside one body
+does the scheduler run one head's matrix products under another's
+softmax. The forward and the loss's second pass take the group's
+logits as one product ahead of the heads (``_over_heads``;
+``sparse.schedule`` says ``head_loop="kv_groups_unrolled"`` and
+``group``); the backward multiplies them head by head
+(``_backward_tile``).
 
 MXU operands in the input dtype with float32 accumulation; index
 scores, thresholds, softmax statistics and accumulators in float32.
@@ -115,6 +124,9 @@ SAVED_NAMES = (
     "sparse_index_lse", "sparse_index_loss",
 )
 PATH = "causal_tiles_masked"
+# How the forward and the loss's second pass walk a tile's query heads
+# (``_over_heads``), as ``sparse.schedule`` reports it.
+HEAD_LOOP = "kv_groups_unrolled"
 
 
 def _use_interpret() -> bool:
@@ -218,6 +230,42 @@ def _last_key_tile(qb, tq: int, tk: int):
 def _first_query_tile(kb, tq: int, tk: int):
     """The first query tile that reaches key tile ``kb``."""
     return (kb * tk) // tq
+
+
+def _over_heads(q_ref, k_ref, scale: float, head, carry):
+    """``carry = head(h, g, s, carry)`` for the query heads ``h = 0 ..
+    heads - 1`` in that order: ``g = h // group`` is the head's kv head
+    and ``s`` its logits ``k[g] . q[h] * scale``, ``[keys, queries]``
+    float32. How the forward and the loss's second pass walk a tile's
+    heads (``sparse.schedule``: ``head_loop``, ``group``).
+
+    A loop over the kv heads whose body is the group's ``heads //
+    kv_heads`` query heads as straight-line code (``group`` copies of
+    ``head``'s body, so the compiled kernel grows with the group;
+    ``heads == kv_heads`` is one head a step), with the group's logits
+    as ONE product at the top of the body: the kv head's keys against
+    the group's queries viewed ``[group * queries, head_dim]`` (a merge
+    of leading dimensions), a head's share a slice of lanes. Across the
+    iterations of a loop the scheduler starts nothing of one head under
+    the previous one's softmax; inside one body it does, but a product
+    issued after a store to scratch at a dynamic index (a head's
+    statistics and accumulator) waits for that store, so the products
+    go ahead of the first head's stores (PERF.md, PR 37)."""
+    heads, tq, d = q_ref.shape[1:]
+    kv_heads = k_ref.shape[1]
+    group = heads // kv_heads
+
+    def group_of(g, carry):
+        queries = q_ref[0, pl.ds(g * group, group)].reshape(group * tq, d)
+        logits = _nt(k_ref[0, g], queries)
+        for r in range(group):
+            carry = head(
+                g * group + r, g,
+                logits[:, r * tq:(r + 1) * tq] * scale, carry,
+            )
+        return carry
+
+    return lax.fori_loop(0, kv_heads, group_of, carry)
 
 
 # ---- index scores + selection ---------------------------------------
@@ -390,9 +438,7 @@ def _fwd_kernel(
     q_ref, k_ref, vt_ref, qi_ref, ki_ref, wt_ref, thr_ref, cut_ref,
     ot_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale: float,
 ):
-    heads, tq = q_ref.shape[1], q_ref.shape[2]
-    group = heads // k_ref.shape[1]
-    tk = k_ref.shape[2]
+    tq, tk = q_ref.shape[2], k_ref.shape[2]
     qb, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -407,15 +453,16 @@ def _fwd_kernel(
             ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, kb * tk, qb * tq
         )
 
-        def head(h, carry):
-            g = h // group
-            s = _nt(k_ref[0, g], q_ref[0, h]) * scale
+        def head(h, g, s, carry):
+            # One masked copy of the logits serves the maximum and the
+            # probabilities: exp(NEG_INF - m) is 0.0, unless the query
+            # has selected nothing yet and m is NEG_INF itself (every
+            # masked pair would count 1 until the first selected key's
+            # alpha = 0 wiped it).
+            s = jnp.where(sel, s, NEG_INF)
             m_prev = m_ref[h]
-            m_new = jnp.maximum(
-                m_prev,
-                jnp.max(jnp.where(sel, s, NEG_INF), axis=0, keepdims=True),
-            )
-            p = jnp.where(sel, jnp.exp(s - m_new), 0.0)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - jnp.where(m_new == NEG_INF, 0.0, m_new))
             alpha = jnp.exp(m_prev - m_new)
             l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=0, keepdims=True)
             acc_ref[h] = alpha * acc_ref[h] + _nn(
@@ -424,7 +471,7 @@ def _fwd_kernel(
             m_ref[h] = m_new
             return carry
 
-        lax.fori_loop(0, heads, head, 0)
+        _over_heads(q_ref, k_ref, scale, head, 0)
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _done():
@@ -531,17 +578,13 @@ def _attention_forward(
 def _mean_probs(q_ref, k_ref, lse_ref, sel, scale: float):
     """``p^T [keys, queries]``: the mean over the query heads of the
     selected pairs' probabilities."""
-    heads = q_ref.shape[1]
-    group = heads // k_ref.shape[1]
-
-    def head(h, total):
-        s = _nt(k_ref[0, h // group], q_ref[0, h]) * scale
+    def head(h, g, s, total):
         return total + jnp.where(sel, jnp.exp(s - lse_ref[0, h]), 0.0)
 
-    total = lax.fori_loop(
-        0, heads, head, jnp.zeros(sel.shape, jnp.float32)
+    total = _over_heads(
+        q_ref, k_ref, scale, head, jnp.zeros(sel.shape, jnp.float32)
     )
-    return total / heads
+    return total / q_ref.shape[1]
 
 
 def _kl_kernel(
@@ -627,7 +670,10 @@ def _backward_tile(
     the indexer heads' relu'd products (kept in ``relu_ref``); per kv
     head ``g`` (a loop) its query heads ``h`` in turn (unrolled: the
     scheduler overlaps one head's products with the next one's
-    softmax), ``on_head(h, g, p, ds)`` with ``p`` and ``ds`` ``[keys,
+    softmax; a head's logits are its own product here, because ONE
+    product a group as in ``_over_heads`` reads 47.5 ms a call for
+    45.2 where five products a head already fill the MXU: PERF.md, PR
+    37), ``on_head(h, g, p, ds)`` with ``p`` and ``ds`` ``[keys,
     queries]`` float32; then ``dL_I / dI`` and per indexer head
     ``on_index_head(j, dI * relu_j summed over keys [1, queries], dI *
     w_j where the head is live [keys, queries])``."""
@@ -1069,6 +1115,8 @@ def sparse_attention(
         path=PATH,
         backward=backward,
         backward_vmem_bytes=held,
+        head_loop=HEAD_LOOP,
+        group=heads // k.shape[1],
     )
     return _sparse_attention(
         q, k, v, qi, ki, w, topk, scale, tq, tk, out_dtype

@@ -147,6 +147,10 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
         # Past the budget, by the row and by the kv heads: two kernels.
         ("grad", 32768, 4),
         ("grad", 16384, 8),
+        # The cell's row through the loss's second pass, which is in no
+        # gradient's program: a group's 8 heads unrolled over one
+        # product of logits [512, 8 x 128] (PR 37).
+        ("fwd", 16384, 4),
     ],
 )
 def test_sparse_attention_kernels_compile_for_v5e(

@@ -275,12 +275,14 @@ def test_the_sparse_mixer_raises_under_a_sequence_axis():
         )
 
 
-def _operands(case, seq=64, dim=32, index_heads=3, index_dim=16):
+def _operands(
+    case, seq=64, dim=32, index_heads=3, index_dim=16, kv_heads=2
+):
     """float32 operands of ``sparse_attention`` on several tiles
     (blocks of 16 queries and 32 keys) and the ``topk`` to run them
     with."""
     rows = 2 if case == "two_rows" else 1
-    heads, kv_heads = (8, 2) if case == "grouped" else (4, 2)
+    heads = 8 if case == "grouped" else 4
     keys = jax.random.split(jax.random.key(11), 6)
     q = jax.random.normal(keys[0], (rows, heads, seq, dim))
     k = jax.random.normal(keys[1], (rows, kv_heads, seq, dim))
@@ -292,6 +294,11 @@ def _operands(case, seq=64, dim=32, index_heads=3, index_dim=16):
         # Eight distinct index keys, each at eight positions: scores
         # tie, and the position decides which of the tied keys stay.
         ki = jnp.tile(ki[:, :8], (1, seq // 8, 1))
+    if case == "only_late_keys":
+        # Index scores that grow with the key's position: a query
+        # selects the keys just before it and none further back.
+        qi, w = jnp.abs(qi), jnp.abs(w) + 0.1
+        ki = jnp.ones_like(ki) * (1.0 + jnp.arange(seq))[None, :, None] / 16
     return (q, k, v, qi, ki, w), 128 if case == "shorter_than_topk" else 8
 
 
@@ -359,6 +366,56 @@ def test_the_two_backward_schedules_agree(case, monkeypatch):
             )
 
 
+@pytest.mark.parametrize("picks", ["scattered", "only_late_keys"])
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_the_forward_equals_the_reference_for_every_group(group, picks):
+    """``out``, ``lse`` and ``L_I`` of the forward kernels alone, four
+    query heads on 4, 2 and 1 kv heads (``heads == kv_heads`` is one
+    head a step of ``_over_heads``), on several tiles against the plain
+    reference. ``only_late_keys``: index scores that grow with the
+    key's position, so a query past the first key tile selects nothing
+    in it and meets its first selected key with its running maximum
+    still at NEG_INF, where the forward's one masked copy of the
+    logits reads ``NEG_INF - NEG_INF``."""
+    config = _config_module()
+    tq, tk = 16, 32
+    operands, topk = _operands(picks, kv_heads=4 // group)
+    q, k, v, qi, ki, w = operands
+    _, heads, seq, dim = q.shape
+    _, member = _plain_sets(qi, ki, w, topk)
+    late = np.asarray(member)[tk + topk:, :tk]
+    assert late.any() == (picks == "scattered")
+
+    scale = dim**-0.5
+    wt = jnp.swapaxes(w, 1, 2)
+    thr, cut, ilse, _, _ = sparse.index_select(qi, ki, wt, topk, tq, tk)
+    out_t, lse = sparse._attention_forward(
+        q, k, jnp.swapaxes(v, 2, 3), qi, ki, wt, thr, cut, scale, tq, tk
+    )
+    index_loss = sparse._index_loss(
+        q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk
+    )
+
+    sizes = {"sa_config": {"topk": topk}}
+    want_out, want_loss = config.reference_attend(
+        config.as_reference_operands(operands, sizes), sizes
+    )
+    want_out = jnp.swapaxes(want_out.reshape(seq, heads, dim), 0, 1)
+    logits = jnp.einsum(
+        "htd,hsd->hts", q[0], jnp.repeat(k[0], group, axis=0),
+        precision="highest",
+    ) * scale
+    want_lse = jax.nn.logsumexp(
+        jnp.where(member[None], logits, -jnp.inf), axis=-1
+    )
+    np.testing.assert_allclose(
+        jnp.swapaxes(out_t[0], 1, 2), want_out,
+        atol=2e-5 * float(jnp.abs(want_out).max()),
+    )
+    np.testing.assert_allclose(lse[0, :, 0], want_lse, atol=2e-5)
+    np.testing.assert_allclose(index_loss[0, 0], want_loss, atol=2e-5)
+
+
 def test_schedule_event_and_kernel_names():
     """One ``sparse.schedule`` event a traced call site, and the names
     the benchmark's readers find the kernels by."""
@@ -378,6 +435,8 @@ def test_schedule_event_and_kernel_names():
     assert attrs["path"] == "causal_tiles_masked"
     assert (attrs["topk"], attrs["heads"], attrs["head_dim"]) == (8, 4, 32)
     assert attrs["keys_visited"] == 32 * 32
+    # How the forward-side kernels walk the 4 query heads on 2 kv heads.
+    assert (attrs["head_loop"], attrs["group"]) == ("kv_groups_unrolled", 2)
     # dK and dV [2, 32, 32 -> 128 lanes] and dkI [32, 16 -> 128] in
     # float32 fit; the published widths at a row of 16 384 do too, a
     # row of 32 768 or 8 kv heads do not.
