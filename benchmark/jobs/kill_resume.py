@@ -12,7 +12,20 @@ predecessor says it is ready), ``successor_chips`` (layout of the
 successor; equal to the cell's chips for now), ``loss_band`` (how far
 the first loss after the resume may lie from the last before it),
 ``warm_steps``, ``trace_after_steps``, ``trace_slice_s`` as in
-``steady``.
+``steady``, and ``reference_check``: WHERE the run's weights are held
+to the configuration's plain reference. ``"successor"`` (the default
+when the key is absent) holds the RESTORED weights after the
+successor's first step. ``"predecessor_fresh"`` holds the predecessor's
+FRESH weights after ``enter_job()`` and before it trains, where
+``steady`` holds them and where a configuration's limits are read (a
+head's error grows as training sharpens the logits: PERF.md section
+7); the result travels with the ``ready`` event and the parent carries
+it into the successor's record and ``reference_agrees``. The restored
+weights are then held by what a restart has to hold: step, loader
+position, batch configuration and progress restored, the first loss
+continuing the predecessor's, ``ckpt.verify``'s hashes between the
+saved and the restored bytes. Either way the check is off the path
+``rescale_s`` times.
 """
 
 from __future__ import annotations
@@ -24,6 +37,8 @@ import time
 
 from benchmark import harness, launch
 
+REFERENCE_CHECKS = ("successor", "predecessor_fresh")
+
 
 def run(ctx) -> dict:
     job = ctx.cell.workload["job"]
@@ -34,7 +49,7 @@ def run(ctx) -> dict:
         launch.job_env(ctx.root, ctx.ckpt_dir, 0, ctx.cell.chips),
     )
     try:
-        first.wait_for("ready", ctx.deadline)
+        ready = first.wait_for("ready", ctx.deadline)
         sigterm_at = first.sigterm()
         code = first.wait_exit(ctx.deadline)
         exited_at = time.monotonic()
@@ -58,6 +73,7 @@ def run(ctx) -> dict:
             role="successor",
             prev=prev,
             parent={"save_exit_s": exited_at - sigterm_at},
+            reference=ready.get("reference"),
         ),
         launch.job_env(
             ctx.root, ctx.ckpt_dir, 1, int(job["successor_chips"])
@@ -128,11 +144,22 @@ def _predecessor(spec: dict, events: harness.Events) -> None:
     SystemExit(143)."""
     from adaptdl_tpu import _signal
 
+    where = spec["job"].get("reference_check", "successor")
+    harness.check(
+        where in REFERENCE_CHECKS,
+        f"job.reference_check is {where!r}, not one of {REFERENCE_CHECKS}",
+    )
     run_ = harness.Run(spec, events)
     harness.check(not run_.enter_job(), "a fresh job found a checkpoint")
+    found = {}
+    if where == "predecessor_fresh":
+        found["reference"] = run_.reference_check()
     run_.settle(int(spec["job"]["steps_before_kill"]))
-    events.send("ready", steps=run_.steps)
-    harness.say(f"ready for SIGTERM after {run_.steps} steps")
+    harness.say(
+        f"ready for SIGTERM after {run_.steps} steps; compiles so far "
+        f"{json.dumps(run_.compiles.summary())}"
+    )
+    events.send("ready", steps=run_.steps, **found)
     sent = run_.steps
     last = {}
 
@@ -185,7 +212,8 @@ def _successor(spec: dict, events: harness.Events) -> None:
     def first_step(m):
         jax.block_until_ready(m)
         events.send("first_step", loss=float(m["loss"]))
-        first["compile_s"] = run_.compiles.summary()["compile_s"]
+        first["compiles"] = run_.compiles.summary()
+        first["compile_s"] = first["compiles"]["compile_s"]
         first["loss"] = float(m["loss"])
 
     run_.settle(int(job["warm_steps"]), on_first_step=first_step)
@@ -198,16 +226,32 @@ def _successor(spec: dict, events: harness.Events) -> None:
     harness.say(
         f"first loss {first['loss']:.4f} after {prev['last_loss']:.4f} "
         f"(predecessor's first {prev['first_loss']:.4f}); compile/load "
-        f"before it {first['compile_s']:.2f}s"
+        f"before it {first['compile_s']:.2f}s: "
+        f"{json.dumps(first['compiles'])}"
     )
-    # After the first step, so that the benchmark's own check is not
-    # on the path rescale_s times.
-    reference = run_.reference_check()
+    if job.get("reference_check", "successor") == "successor":
+        # After the first step, so that the benchmark's own check is
+        # not on the path rescale_s times.
+        reference = run_.reference_check()
+    else:
+        reference = spec["reference"]
+        harness.check(
+            reference is not None,
+            "the predecessor's ready event carried no reference result",
+        )
     checks["reference_agrees"] = reference["ok"]
     harness.quiesce()
     result = run_.window(spec["seconds"])
     record = {
         "reference": reference,
+        "resume": {
+            "first_loss": first["loss"],
+            "predecessor_last_loss": prev["last_loss"],
+            "predecessor_first_loss": prev["first_loss"],
+            "loss_band": job["loss_band"],
+            "restored": now,
+            "saved": {k: prev[k] for k in now},
+        },
         "parent": spec["parent"],
         "successor_compile_s": first["compile_s"],
     }

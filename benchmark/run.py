@@ -7,9 +7,10 @@ Runs one cell of ``BENCHMARK.json`` on the machine it is started on and
 prints, as the last line of stdout, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics
 with ``--trace 0``, its per-layer metrics with ``--trace 1``),
-``device`` and, traced, ``breakdown``. It exits non-zero and prints no
-result when there is no TPU, fewer chips than the cell asks for, or no
-program to measure.
+``device``, traced ``breakdown``, and last ``compared`` (every check
+and every number compared beside its limit, also the last line of
+stderr). It exits non-zero and prints no result when there is no TPU,
+fewer chips than the cell asks for, or no program to measure.
 
 This process never imports jax: the workers it starts own the chip.
 Everything a cell needs is found by name (``manifest.py``).
@@ -128,6 +129,14 @@ def assemble(cell, args, out: dict) -> dict:
     }
     if args.trace and "breakdown" in done:
         line["breakdown"] = done["breakdown"]
+    # Last on the line and last on stderr: every check, and every
+    # number the job compared beside its limit (the configuration's
+    # reference check; a restart's losses and positions).
+    record = done.get("record", {})
+    line["compared"] = {
+        "checks": checks,
+        **{k: record[k] for k in ("reference", "resume") if k in record},
+    }
     if not line["correct"]:
         print(
             f"[bench] NOT correct: {json.dumps(checks)}", file=sys.stderr
@@ -154,6 +163,10 @@ def main(argv=None) -> int:
     except (manifest.ManifestError, launch.WorkerFailure) as exc:
         print(f"[bench] FAILED: {exc}", file=sys.stderr)
         return 1
+    print(
+        f"[bench] compared: {json.dumps(line['compared'])}",
+        file=sys.stderr, flush=True,
+    )
     print(json.dumps(line), flush=True)
     return 0
 

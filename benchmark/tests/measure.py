@@ -30,8 +30,11 @@ OUT = os.path.join(ROOT, "chiprun_out")
 def spread(values: list[float]) -> float:
     if len(values) < 2:
         return 0.0
-    q = statistics.quantiles(values, n=4, method="inclusive")
-    return (q[2] - q[0]) / statistics.median(values)
+    q = statistics.quantiles(values, n=4)  # as the driver takes them
+    median = statistics.median(values)
+    if median == 0:  # compiles_in_window in every run of a traced set
+        return 0.0 if q[2] == q[0] else float("inf")
+    return (q[2] - q[0]) / abs(median)
 
 
 def main() -> int:

@@ -2,20 +2,15 @@
 ``flash_bwd_ms`` and ``flash_bwd_roofline`` on hand-made traces, their
 FLOP and byte functions, and both through the harness on the CPU.
 
-No cell names them yet, for the reason ``test_setup_readers.py``
-gives: a cell reports only what its ``workloads/<cell>.json`` names.
-The rehearsal runs on a scratch copy of the manifest with the names
-appended and the entries declared — the whole edit a benchmark PR has
-to make. On the CPU the kernel is interpreted, so there is no Mosaic
-call to find: the readers must return nothing and the line must leave
-the two out, which is also what they do on a parent without the
-kernel.
+Since PR 39 the three ``gpt2-124m`` cells list both. On the CPU the
+kernel is interpreted, so there is no Mosaic call to find: the readers
+must return nothing and the line must leave the two out, which is
+also what they do on a parent without the kernel.
 """
 
 import argparse
 import json
 import os
-import shutil
 
 import pytest
 
@@ -118,37 +113,31 @@ def test_backward_operations_and_bytes(causal):
     ) == 8 * 192 * 1024 * 64 * 4 + 192 * 1024 * 4
 
 
-def _declared(tmp_path, cell_name):
-    """A scratch manifest in which ``cell_name`` reports the two."""
-    shutil.copytree(
-        os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
-        ignore=shutil.ignore_patterns("__pycache__", "tests"),
-    )
+GPT2_CELLS = ["gpt2-124m-steady", "gpt2-124m-rescale", "gpt2-124m-dp4"]
+
+
+def test_the_cells_that_list_them():
+    """The three ``gpt2-124m`` cells report both; ``lfm2-8b-a1b-steady``
+    runs the kernel too (through GQA, at 8192 keys) and lists the
+    time, not the roofline share, whose reader takes ``n_head`` and
+    ``n_positions`` from sizes that configuration does not have."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cells = [w["name"] for w in bench["workloads"]]
+        listed = {
+            m["name"]: m["workloads"]
+            for m in json.load(f)["per_layer"] if m["name"] in NAMES
+        }
+    assert listed["flash_bwd_roofline"] == GPT2_CELLS
+    assert listed["flash_bwd_ms"] == GPT2_CELLS + ["lfm2-8b-a1b-steady"]
     for name in NAMES:
-        reader = _reader(name)
-        bench["per_layer"].append({
-            "name": name, "unit": reader.UNIT,
-            "better": "lower" if reader.UNIT == "ms" else "higher",
-            "source": reader.SOURCE, "layer": reader.LAYER,
-            "moves": reader.MOVES, "workloads": cells,
-        })
-    with open(tmp_path / "BENCHMARK.json", "w") as f:
-        json.dump(bench, f)
-    path = tmp_path / "benchmark" / "workloads" / f"{cell_name}.json"
-    workload = json.loads(path.read_text())
-    workload["metrics"] += NAMES
-    path.write_text(json.dumps(workload))
-    return manifest.load_cell(cell_name, str(tmp_path))
+        for cell_name in listed[name]:
+            cell = manifest.load_cell(cell_name)
+            assert name in {m["name"] for m in cell.per_layer}
 
 
 def test_traced_rehearsal_leaves_the_backward_metrics_out_on_the_cpu(
     tmp_path, monkeypatch
 ):
-    """The steady job at a tiny size on the CPU with the two names
-    declared: the traced line is ``correct`` and carries neither (no
+    """The steady job at a tiny size on the CPU: the traced line is ``correct`` and carries neither (no
     device trace, no Mosaic call), as on a parent without the
     kernel. A number from a CPU run is never a device metric."""
     import rehearse
@@ -162,7 +151,7 @@ def test_traced_rehearsal_leaves_the_backward_metrics_out_on_the_cpu(
     monkeypatch.setenv(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
     )
-    cell = _declared(tmp_path, "gpt2-124m-steady")
+    cell = manifest.load_cell("gpt2-124m-steady")
     assert set(NAMES) <= {m["name"] for m in cell.per_layer}
     rehearse.shrink(cell)
     args = argparse.Namespace(

@@ -103,6 +103,29 @@ def test_readers_declare_what_benchmark_json_says(bench):
         ), metric["name"]
 
 
+def test_every_reader_is_reported_by_some_cell(bench):
+    """A reader file no cell lists reaches no ledger (thirteen did
+    not until PR 39): every file under ``layer_metrics/`` is declared
+    in ``per_layer`` and named by the ``metrics`` list of at least one
+    cell it is declared for; a metric with a ``workloads`` key is
+    reported by exactly those cells."""
+    readers = {
+        os.path.splitext(f)[0]
+        for f in os.listdir(manifest.bench_path(ROOT, "layer_metrics"))
+        if f.endswith(".py")
+    }
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    assert readers == set(declared)
+    reported: dict = {}
+    for entry in bench["workloads"]:
+        for metric in manifest.load_cell(entry["name"]).per_layer:
+            reported.setdefault(metric["name"], []).append(entry["name"])
+    assert set(reported) == readers
+    for name, metric in declared.items():
+        if "workloads" in metric:
+            assert reported[name] == metric["workloads"], name
+
+
 def test_unknown_workload_is_rejected():
     with pytest.raises(manifest.ManifestError, match="unknown workload"):
         manifest.load_cell("no-such-cell")

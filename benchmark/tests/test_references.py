@@ -38,7 +38,17 @@ def test_reference_agrees_with_system(cell_name, rtol, monkeypatch):
 
 def test_gpt2_comparison_has_teeth(monkeypatch):
     """Untying the head, dropping the rotation or the causal mask moves
-    the loss beyond REFERENCE_RTOL."""
+    the loss beyond REFERENCE_RTOL; bf16 blocks stay inside it.
+
+    The bf16 blocks' rounding averages out of a MEAN loss with the
+    number of tokens: on the first 2 rows (64 tokens, what this test
+    held to the limit until PR 39) seeds 3-7 read 4.1e-4, 1.9e-4,
+    3.5e-6, 2.0e-4, 1.8e-4 of the float32 loss, the noise itself
+    around the limit of 3e-4; on all 64 rows (2048 tokens) 2.3e-5,
+    3.6e-5, 3.3e-5, 1.6e-5, 5.6e-5 (this CPU, tiny widths; the chip's
+    check reads 0.05e-5-3.5e-5 on 2048 tokens at real widths). So the
+    bf16 case takes every row of the dataset; the limit is the
+    configuration's, untouched."""
     config, built, params, dataset, sizes = _built(
         "gpt2-124m-steady", monkeypatch
     )
@@ -69,13 +79,14 @@ def test_gpt2_comparison_has_teeth(monkeypatch):
     built16 = config.build(
         sizes16, {"atomic_bsz": 2, "accum_steps": 0, "global_batch": 2}, 3
     )
-    bf16 = float(built16["loss_fn"](params, sample, jax.random.key(0)))
+    bf16 = float(built16["loss_fn"](params, dataset, jax.random.key(0)))
     ref = float(
         config.reference_loss(
-            weights, sample["inputs"], sample["targets"], eps
+            weights, dataset["inputs"], dataset["targets"], eps
         )
     )
-    assert 0 < abs(bf16 - ref) / ref <= config.REFERENCE_RTOL
+    assert len(dataset["inputs"]) == 64
+    assert 0 < abs(bf16 - ref) / ref <= config.REFERENCE_RTOL / 3
 
 
 def test_gpt2_head_comparison_has_teeth(monkeypatch):

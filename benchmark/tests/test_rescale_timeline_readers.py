@@ -1,14 +1,17 @@
 """The readers of one rescale's timeline (PR 36): each on hand-made
 records, and all of them through the harness on the CPU, on a shrunk
-copy of the cell that would report them, ``lfm2-8b-a1b-rescale``.
+copy of the cell proposed for them, ``lfm2-8b-a1b-rescale``.
 
-No cell names them yet. The cell this PR was to add cannot be
-``correct`` with the files the benchmark has (``jobs/kill_resume.py``
-holds the RESTORED weights to the configuration's reference, whose
-limits were read on fresh ones: PERF.md section 7), so its workload
-file waits in ``tests/data`` and the rehearsal runs on a scratch copy
-of the manifest in which ``timeline_run.declare`` has made the whole
-edit a benchmark PR has to make."""
+``gpt2-124m-rescale`` lists the ten since PR 39. The proposed cell
+holds the reference where its limits were read since then
+(``job.reference_check`` ``predecessor_fresh``) and was still not
+added: its ``rescale_s`` reads in two populations 40 s apart by
+whether the successor finds the donating step in the compile cache,
+which the PROGRAM decides, and its data is learned before the kill, so
+``loss_went_down`` in its window has little room (PERF.md section 7).
+Its workload file waits in ``tests/data`` and the rehearsal runs on a
+scratch copy of the manifest in which ``timeline_run.declare`` has made
+the whole edit that adds it, data files and entries only."""
 
 import argparse
 import json
@@ -122,18 +125,22 @@ def test_the_declared_cell_reports_the_timeline(declared):
     steady = manifest.load_cell("lfm2-8b-a1b-steady")
     assert cell.workload["geometry"] == steady.workload["geometry"]
     assert cell.workload["dataset_samples"] == 1024
-    assert cell.workload["job"]["kind"] == "kill_resume"
+    job = cell.workload["job"]
+    assert job["kind"] == "kill_resume"
+    assert job["reference_check"] == "predecessor_fresh"
     assert {m["name"] for m in cell.end_to_end} == {
         "tokens_per_s", "rescale_s", "setup_s"
     }
     assert set(NEW + ATTACHED) <= {m["name"] for m in cell.per_layer}
-    # The edit changes nothing that is there: every old cell loads as
-    # before, and the old entries are a prefix of the new lists.
+    # Adding the cell is data only, and changes nothing that is there:
+    # no entry is added or lost, and every cell loads as before.
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         old = json.load(f)
     with open(os.path.join(declared, "BENCHMARK.json")) as f:
         new = json.load(f)
-    assert len(new["per_layer"]) == len(old["per_layer"]) + 10
+    assert [m["name"] for m in new["per_layer"]] == [
+        m["name"] for m in old["per_layer"]
+    ]
     assert len(json.dumps(new)) < 64 * 1024
     for entry in old["workloads"]:
         before = manifest.load_cell(entry["name"])
@@ -141,6 +148,19 @@ def test_the_declared_cell_reports_the_timeline(declared):
         assert [m["name"] for m in after.per_layer] == [
             m["name"] for m in before.per_layer
         ]
+
+
+def test_the_rescale_cell_lists_the_ten():
+    cell = manifest.load_cell("gpt2-124m-rescale")
+    listed = {m["name"]: m for m in cell.per_layer}
+    assert set(NEW + ATTACHED) <= set(listed)
+    for name in NEW:
+        assert listed[name]["workloads"] == ["gpt2-124m-rescale"]
+    # What the timeline divides is reported beside it.
+    assert {"save_exit_s", "ckpt_restore_s", "successor_compile_s"} <= set(
+        listed
+    )
+    assert "reference_check" not in cell.workload["job"]
 
 
 def test_traced_rehearsal_reports_the_timeline(
@@ -191,3 +211,6 @@ def test_traced_rehearsal_reports_the_timeline(
     assert abs(timeline_run.identities(
         values, out["end_to_end"]["rescale_s"]
     )["still_dark_s"]) < 2.0
+    # The reference was held in the predecessor, on its fresh weights.
+    assert out["done"]["record"]["reference"]["ok"] is True
+    assert out["done"]["checks"]["reference_agrees"] is True

@@ -3,6 +3,7 @@ job drivers end to end at a tiny size on the CPU (the rehearsals)."""
 
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -80,8 +81,17 @@ def test_rehearsal_on_cpu(cell, trace, tmp_path, monkeypatch):
     monkeypatch.setenv("TMPDIR", str(tmp_path))
     line = rehearse.rehearse(cell, seconds=2.0, trace=trace)
     assert set(line) <= {
-        "correct", "attempted", "failed", "metrics", "device", "breakdown"
+        "correct", "attempted", "failed", "metrics", "device", "breakdown",
+        "compared",
     }
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["checks"]["reference_agrees"] is True
+    assert line["compared"]["reference"]["rel_diff"] <= (
+        line["compared"]["reference"]["rtol"]
+    )
+    if "rescale" in cell:
+        resume = line["compared"]["resume"]
+        assert resume["restored"] == resume["saved"]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"
@@ -92,3 +102,106 @@ def test_rehearsal_on_cpu(cell, trace, tmp_path, monkeypatch):
         assert set(line["metrics"]) == {m["name"] for m in group}
     json.dumps(line)
     assert not list(tmp_path.glob("adaptdl-bench-*")), "work dir left"
+
+
+def _said_by(err: str, what: str) -> list[str]:
+    """Pids of the workers whose stderr carries ``what``."""
+    return re.findall(rf"\[bench-worker (\d+)\] {what}", err)
+
+
+@pytest.mark.parametrize(
+    "where, dtype, correct",
+    [
+        (None, "float32", True),
+        ("successor", "float32", True),
+        ("predecessor_fresh", "float32", True),
+        # On the CPU a bfloat16 model rounds its logits
+        # (test_references.py): the head comparison fails.
+        ("predecessor_fresh", "bfloat16", False),
+    ],
+)
+def test_kill_resume_holds_the_reference_where_the_job_says(
+    where, dtype, correct, tmp_path, monkeypatch, capfd
+):
+    """``job.reference_check``: absent behaves as ``"successor"`` (the
+    successor checks its RESTORED weights after its first step);
+    ``"predecessor_fresh"`` checks in the predecessor before it
+    trains, the result arrives in the successor's record, and a
+    failing predecessor check makes the run not ``correct``."""
+    import argparse
+
+    import rehearse
+    import timeline_run
+
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    cell = manifest.load_cell("gpt2-124m-rescale")
+    assert "reference_check" not in cell.workload["job"]
+    rehearse.shrink(cell)
+    cell.sizes["compute_dtype"] = dtype
+    if where is not None:
+        cell.workload["job"]["reference_check"] = where
+    args = argparse.Namespace(
+        workload=cell.name, seed=3, seconds=2.0, trace=0
+    )
+    out, line = timeline_run.run_job(cell, args, str(tmp_path))
+    err = capfd.readouterr().err
+    checked = _said_by(err, "reference check")
+    predecessor = _said_by(err, "ready for SIGTERM")
+    successor = _said_by(err, "first loss")
+    assert len(checked) == len(predecessor) == len(successor) == 1
+    assert predecessor != successor
+    assert checked == (
+        predecessor if where == "predecessor_fresh" else successor
+    )
+    reference = out["done"]["record"]["reference"]
+    assert reference["ok"] is correct
+    assert out["done"]["checks"]["reference_agrees"] is correct
+    assert line["correct"] is correct, (out["done"]["checks"], out["checks"])
+    # What a restart has to hold is held either way.
+    for check in ("step_restored", "loader_position_restored",
+                  "batch_config_restored", "progress_restored",
+                  "loss_continues"):
+        assert out["done"]["checks"][check] is True, check
+    assert f'"head_token_loss_err": {reference["head_token_loss_err"]}' in err
+
+
+@pytest.mark.parametrize(
+    "where, lose_result, said",
+    [
+        ("somewhere", False, "reference_check"),
+        # The successor branches on the job's parameter, not on whether
+        # a result happened to arrive: it never falls back to checking
+        # trained weights at limits read on fresh ones.
+        ("predecessor_fresh", True, "no reference result"),
+    ],
+)
+def test_kill_resume_refuses_what_its_reference_check_cannot_hold(
+    where, lose_result, said, tmp_path, monkeypatch
+):
+    import argparse
+
+    import rehearse
+
+    from benchmark import launch, run
+
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    cell = manifest.load_cell("gpt2-124m-rescale")
+    rehearse.shrink(cell)
+    cell.workload["job"]["reference_check"] = where
+    if lose_result:
+        wait_for = launch.Worker.wait_for
+
+        def lossy(self, event, deadline):
+            found = wait_for(self, event, deadline)
+            if event == "ready":
+                found.pop("reference")
+            return found
+
+        monkeypatch.setattr(launch.Worker, "wait_for", lossy)
+    args = argparse.Namespace(
+        workload=cell.name, seed=3, seconds=2.0, trace=0
+    )
+    with pytest.raises(launch.WorkerFailure, match=said):
+        run.run_cell(cell, args)

@@ -6,14 +6,18 @@ ISSUE 36 asks of the timeline, and the run's waterfall.
     chiprun -- python benchmark/tests/timeline_run.py \
         --cell gpt2-124m-rescale --seed 3600100002 [--trace 0]
 
-The seven timeline readers are attached to no cell of ``BENCHMARK.json``
-yet, so their values are computed here from the run's trace journals
-(``ADAPTDL_TRACE_DIR`` under ``chiprun_out/``), which hold the records
-the successor's ring buffer holds. ``--cell lfm2-8b-a1b-rescale`` runs
-the PROPOSED cell (``tests/data/lfm2-8b-a1b-rescale.json``) on a scratch
-copy of the manifest in which ``declare`` has made the whole edit a
-benchmark PR has to make; there the cell's own readers report, from the
-successor's buffer. No jax here: the job's workers own the chip.
+A rescale cell reports the ten timeline metrics since PR 39, read by
+the cell's own readers from the successor's ring buffer (a traced
+run's ``metrics``); ``from_journal`` computes the same values by the
+readers' own code from the run's trace journals (``ADAPTDL_TRACE_DIR``
+under ``chiprun_out/``), so an untraced run (``--trace 0``) has them
+too, and ``identities_reported`` holds the three identities from the
+reported values alone. ``--cell lfm2-8b-a1b-rescale`` runs the PROPOSED
+cell (``tests/data/lfm2-8b-a1b-rescale.json``; why it is not a cell:
+PERF.md section 7) on a scratch copy of the manifest in which
+``declare`` has made the whole edit that adds it: data only, its file,
+its entry and its name in the lists of the metrics it reports. No jax
+here: the job's workers own the chip.
 """
 
 from __future__ import annotations
@@ -40,9 +44,6 @@ NEW = (
     "exit_teardown_s", "boot_process_s", "boot_import_s",
 )
 ATTACHED = ("restart_span_s", "state_init_s", "trace_lower_s")
-LISTS_THE_CELL = (
-    "rescale_s", "save_exit_s", "ckpt_restore_s", "successor_compile_s"
-)
 TIMELINE = (
     "exit.agree", "ckpt.snapshot", "ckpt.write", "exit.atexit",
     "boot.process", "boot.import", "restart.first_step",
@@ -56,9 +57,8 @@ SUCCESSOR_DETAIL = (
 
 def declare(scratch: str) -> None:
     """A copy of the manifest under ``scratch`` in which the proposed
-    cell exists: its workload file, its entry, its name in the four
-    lists, and the ten per-layer entries with the constants the reader
-    files declare."""
+    cell exists: its workload file, its entry, and its name in the
+    ``workloads`` list of every metric it reports that has one."""
     from benchmark import manifest
 
     shutil.copytree(
@@ -66,29 +66,24 @@ def declare(scratch: str) -> None:
         os.path.join(scratch, "benchmark"),
         ignore=shutil.ignore_patterns("__pycache__", "tests"),
     )
+    source = manifest.bench_path(ROOT, "tests", "data", f"{PROPOSED}.json")
     shutil.copy(
-        manifest.bench_path(ROOT, "tests", "data", f"{PROPOSED}.json"),
-        manifest.bench_path(scratch, "workloads", f"{PROPOSED}.json"),
+        source, manifest.bench_path(scratch, "workloads", f"{PROPOSED}.json")
     )
+    reported = manifest.load_json(source)["metrics"]
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     bench["workloads"].append({
         "name": PROPOSED, "config": "lfm2-8b-a1b", "traffic": "rescale",
         "chips": 1,
-        "why": "4 x 8192 tokens a step; SIGTERM, save 8.1 GB of donated "
+        "why": "4 x 8192 tokens a step; SIGTERM, save 8.1 GB of DONATED "
         "state, exit 143, a new process restores and re-traces the "
-        "donating step; the window runs on the successor",
+        "donating step; window on the successor; bypasses collectives "
+        "and a change of layout",
     })
     for metric in bench["end_to_end"] + bench["per_layer"]:
-        if metric["name"] in LISTS_THE_CELL:
+        if metric["name"] in reported and "workloads" in metric:
             metric["workloads"].append(PROPOSED)
-    for name in NEW + ATTACHED:
-        reader = manifest.load_module(manifest.reader_path(ROOT, name))
-        bench["per_layer"].append({
-            "name": name, "unit": reader.UNIT, "better": "lower",
-            "source": reader.SOURCE, "layer": reader.LAYER,
-            "moves": reader.MOVES, "workloads": [PROPOSED],
-        })
     with open(os.path.join(scratch, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f, indent=1)
 
@@ -195,6 +190,8 @@ def main() -> int:
             {"save_exit_s": save_exit_s, **from_journal}, rescale_s
         ),
     }
+    if "save_exit_s" in values:  # a traced line: the cell's own readers
+        report["identities_reported"] = identities(values, rescale_s)
     with open(os.path.join(OUT, "timelines.jsonl"), "a") as f:
         f.write(json.dumps(report) + "\n")
     print(json.dumps(report, indent=1))
