@@ -33,6 +33,8 @@ gradient-norm statistics count each expert shard exactly once.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -320,11 +322,15 @@ def dense_switch_moe(
 
 class RowPlan(NamedTuple):
     """Where every (token, choice) assignment to a held expert lies in
-    the row buffer the grouped products run over: groups in expert
-    order, each starting at a multiple of the row tile."""
+    the rows the grouped products run over: groups in expert order,
+    each starting at a multiple of the row tile, the first at row 0.
+    The row arrays are ``rows_capacity`` long, so that a plan exists
+    under any imbalance; where a row lies does not depend on that
+    length, so the first ``rows_bound`` rows ARE the plan of a buffer
+    of that length whenever ``active_tiles`` lie inside it."""
 
     dest: Any  # int32 [tokens, top_k]: row of the assignment, or
-    # ``rows`` (one past the buffer) for an expert not held here
+    # ``rows_capacity`` (past every buffer) for an expert not held here
     row_token: Any  # int32 [rows]: the token a row holds (0: padding)
     row_assignment: Any  # int32 [rows]: token * top_k + choice, or -1
     tile_expert: Any  # int32 [rows / tile]: the held expert of a tile
@@ -376,11 +382,44 @@ def softmax_top_k(x, router, top_k: int, eps: float, scale: float):
 def rows_capacity(
     tokens: int, top_k: int, experts_held: int, tile: int
 ) -> int:
-    """Rows of the buffer: every assignment a token can make to held
-    experts (its choices are distinct experts) plus each group's
-    padding to whole tiles, in whole tiles."""
+    """Rows of the worst case: every assignment a token can make to
+    held experts (its choices are distinct experts) plus each group's
+    padding to whole tiles, in whole tiles. The plan is this long; the
+    usual step walks ``rows_bound`` of its rows."""
     worst = tokens * min(top_k, experts_held) + experts_held * (tile - 1)
     return -(-worst // tile) * tile
+
+
+# Rows the glue usually walks, as a multiple of the held assignments
+# EXPECTED under an even router. On fresh weights both routed benchmark
+# cells place 1.0 of them; within its window ``lfm2-8b-a1b-steady``
+# drifts to 1.6 (PERF.md section 6, PR 40): 2.5 leaves half as much
+# again. A step that places more walks the rest of the plan too and
+# drops nothing.
+ROWS_BOUND_FACTOR = 2.5
+
+
+def rows_bound(
+    tokens: int, top_k: int, experts_held: int, experts_total: int,
+    tile: int,
+) -> int:
+    """Rows of the buffer a step usually walks: ``ROWS_BOUND_FACTOR``
+    times the assignments an even router sends to the held experts,
+    plus each group's padding to whole tiles, in whole tiles — where
+    the rest of ``rows_capacity`` is no longer than that, else
+    ``rows_capacity`` itself (as where every expert is held). A step
+    whose active tiles pass the bound walks the rest of the plan in a
+    second pass (``expert_rows``), which costs more than one pass over
+    all of it would have; where the worst case is several times the
+    bound, a router that leaves even routing would pay that on every
+    step (one chip's share of 16 of 128 experts does, within twenty
+    steps: PERF.md section 6, PR 40), so there the layer keeps the one
+    pass."""
+    capacity = rows_capacity(tokens, top_k, experts_held, tile)
+    expected = tokens * top_k * experts_held / experts_total
+    usual = math.ceil(ROWS_BOUND_FACTOR * expected) + experts_held * (tile - 1)
+    bound = -(-usual // tile) * tile
+    return bound if capacity - bound <= bound < capacity else capacity
 
 
 def plan_rows(
@@ -445,64 +484,169 @@ def plan_rows(
 
 
 def _gather_rows(buffer, dest):
-    """``buffer[dest]`` with zeros where ``dest`` is past the buffer:
-    [tokens, top_k, d]. A select, not a product: rows nobody placed
-    may hold anything."""
+    """``buffer[dest]`` with zeros where ``dest`` lies outside the
+    buffer: ``dest.shape + buffer.shape[1:]``. A select, not a product:
+    rows nobody placed may hold anything."""
     rows = buffer.shape[0]
-    taken = buffer[jnp.minimum(dest, rows - 1)]
-    return jnp.where((dest < rows)[..., None], taken, 0)
-
-
-@jax.custom_vjp
-def dispatch_rows(x, row_token, dest):
-    """Tokens into the row buffer: ``out[r] = x[row_token[r]]``. Both
-    directions are gathers: the transpose sums, per token, the rows
-    its assignments went to."""
-    return x[row_token]
-
-
-def _dispatch_fwd(x, row_token, dest):
-    return x[row_token], dest
-
-
-def _dispatch_bwd(dest, d_rows):
-    return _gather_rows(d_rows, dest).sum(axis=1), None, None
-
-
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def combine_rows(y_rows, weights, dest, row_assignment):
-    """Rows back to tokens: ``out[t] = sum_j weights[t, j] *
-    y_rows[dest[t, j]]`` over the choices whose expert is held here.
-    The transpose is a gather by row."""
-    taken = _gather_rows(y_rows, dest)
-    return jnp.einsum(
-        "tjd,tj->td", taken, weights.astype(y_rows.dtype)
+    taken = buffer[jnp.clip(dest, 0, rows - 1)]
+    inside = ((dest >= 0) & (dest < rows)).reshape(
+        dest.shape + (1,) * (buffer.ndim - 1)
     )
+    return jnp.where(inside, taken, 0)
 
 
-def _combine_fwd(y_rows, weights, dest, row_assignment):
-    out = combine_rows(y_rows, weights, dest, row_assignment)
-    return out, (y_rows, weights, dest, row_assignment)
+def _tile(plan: RowPlan) -> int:
+    return plan.row_token.shape[0] // plan.tile_expert.shape[0]
 
 
-def _combine_bwd(residuals, d_out):
-    y_rows, weights, dest, row_assignment = residuals
-    top_k = weights.shape[1]
-    placed = row_assignment >= 0
-    at = jnp.maximum(row_assignment, 0)
-    row_weight = jnp.where(placed, weights.reshape(-1)[at], 0.0)
-    d_rows = d_out[at // top_k] * row_weight.astype(d_out.dtype)[:, None]
-    d_weights = jnp.einsum(
-        "tjd,td->tj", _gather_rows(y_rows, dest), d_out,
-        preferred_element_type=jnp.float32,
+def _groups(start, rows: int, plan: RowPlan):
+    """The grouped products' view of rows ``start .. start + rows`` of
+    the plan (whole tiles): the tiles' experts, how many of them are
+    active, and the active tiles each group has there (which groups
+    the pass's weight gradient visits)."""
+    first, tiles = start // _tile(plan), rows // _tile(plan)
+    tile_expert = lax.dynamic_slice_in_dim(plan.tile_expert, first, tiles)
+    active = jnp.clip(plan.active_tiles - first, 0, tiles)
+    visited = jnp.sum(
+        (tile_expert[:, None] == jnp.arange(plan.group_sizes.shape[0]))
+        & (jnp.arange(tiles) < active[0])[:, None],
+        axis=0, dtype=jnp.int32,
     )
-    return d_rows, d_weights.astype(weights.dtype), None, None
+    return tile_expert, active, visited
 
 
-combine_rows.defvjp(_combine_fwd, _combine_bwd)
+@functools.partial(jax.jit, static_argnums=(1,))
+def _forward_rows(start, rows: int, x, weights, w_gate, w_up, w_down, plan):
+    """The held experts over rows ``start .. start + rows`` of the
+    plan: tokens into the rows (``x[row_token]``, a gather), the three
+    grouped products, and rows back to tokens, ``y[t] = sum_j
+    weights[t, j] * y_rows[dest[t, j]]`` over the choices whose row
+    lies there. Returns ``(y, residuals)``, the residuals all ``[rows,
+    .]``."""
+    groups = _groups(start, rows, plan)
+    taken = x[lax.dynamic_slice_in_dim(plan.row_token, start, rows)]
+    gate = gmm.grouped_matmul(taken, w_gate, *groups)
+    up = gmm.grouped_matmul(taken, w_up, *groups)
+    hidden = jax.nn.silu(gate) * up
+    y_rows = gmm.grouped_matmul(hidden, w_down, *groups)
+    y = jnp.einsum(
+        "tjd,tj->td", _gather_rows(y_rows, plan.dest - start),
+        weights.astype(y_rows.dtype),
+    )
+    return y, (taken, gate, up, hidden, y_rows)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _backward_rows(
+    start, rows: int, residuals, d_y, x, weights, w_gate, w_up, w_down, plan
+):
+    """The transpose of ``_forward_rows`` over the same rows; every
+    direction is a gather. The combine's weight gradient is taken on
+    the row side: ``d_y[row_token]`` is gathered once, for the rows'
+    cotangent, and ``<y_rows[r], d_y[row_token[r]]>`` in float32 beside
+    it; a token reads its ``top_k`` scalars back by ``dest``."""
+    taken, gate, up, hidden, y_rows = residuals
+    groups = _groups(start, rows, plan)
+    dest = plan.dest - start
+    assignment = lax.dynamic_slice_in_dim(plan.row_assignment, start, rows)
+    row_weight = jnp.where(
+        assignment >= 0, weights.reshape(-1)[jnp.maximum(assignment, 0)], 0.0
+    )
+    d_y_taken = d_y[lax.dynamic_slice_in_dim(plan.row_token, start, rows)]
+    d_y_rows = d_y_taken * row_weight.astype(d_y.dtype)[:, None]
+    d_weights = _gather_rows(
+        jnp.einsum(
+            "rd,rd->r", y_rows, d_y_taken,
+            preferred_element_type=jnp.float32,
+        ),
+        dest,
+    )
+    d_hidden, d_w_down = gmm.grouped_matmul_transposes(
+        hidden, w_down, *groups, d_y_rows
+    )
+    _, gated = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up)
+    d_gate, d_up = gated(d_hidden)
+    d_taken_gate, d_w_gate = gmm.grouped_matmul_transposes(
+        taken, w_gate, *groups, d_gate
+    )
+    d_taken_up, d_w_up = gmm.grouped_matmul_transposes(
+        taken, w_up, *groups, d_up
+    )
+    d_x = _gather_rows(d_taken_gate + d_taken_up, dest).sum(axis=1)
+    return d_x, d_weights.astype(weights.dtype), d_w_gate, d_w_up, d_w_down
+
+
+def _past_the_bound(bound: int, plan: RowPlan, first, rest):
+    """``first`` (what the plan's first ``bound`` rows gave) plus
+    ``rest(bound, rows)`` (what its other ``rows`` rows give) where an
+    active tile lies among those: in the usual step it does not, and
+    ``first`` is handed through untouched.
+
+    A ``while_loop`` that runs once or not at all, in place of a
+    ``cond``: the TPU compiler gives the two branches of a ``cond``
+    memory of their own each and copies what one of them only passes
+    on, where a loop's state is updated in place."""
+    rows = plan.row_token.shape[0]
+    if bound == rows:
+        return first
+    rows_active = plan.active_tiles[0] * _tile(plan)
+
+    def add_the_rest(state):
+        start, total = state
+        return start + (rows - bound), jax.tree.map(
+            jnp.add, total, rest(start, rows - bound)
+        )
+
+    return lax.while_loop(
+        lambda state: state[0] < rows_active, add_the_rest,
+        (jnp.int32(bound), first),
+    )[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def expert_rows(bound: int, x, weights, w_gate, w_up, w_down, plan):
+    """The held experts' part of the layer for a router's ``weights``
+    and their ``plan``: ``_forward_rows`` over the first ``bound`` rows
+    and, only where an active tile lies past them, over the plan's
+    other rows as well — nothing is dropped, and no more is alive at
+    once than one buffer of ``rows_capacity`` rows held. Everything
+    XLA generates around the kernels has static shapes and walks every
+    row it is given (the kernels skip inactive tiles by themselves), so
+    the usual step is given ``bound`` rows; they are the same rows in
+    the same tiles as in a buffer of any length, so the same bits.
+
+    One ``custom_vjp`` for the whole section: differentiated, either
+    kind of control flow would write zeros in the shapes of the path
+    not taken. The forward rule hands over the first rows' residuals;
+    the forward of the rest, where it ran, runs again in the
+    backward."""
+    return _expert_rows_fwd(bound, x, weights, w_gate, w_up, w_down, plan)[0]
+
+
+def _expert_rows_fwd(bound: int, *operands):
+    y, residuals = _forward_rows(0, bound, *operands)
+    y = _past_the_bound(
+        bound, operands[-1], y,
+        lambda start, rows: _forward_rows(start, rows, *operands)[0],
+    )
+    return y, (residuals, operands)
+
+
+def _expert_rows_bwd(bound: int, saved, d_y):
+    residuals, operands = saved
+
+    def rest(start, rows):
+        _, again = _forward_rows(start, rows, *operands)
+        return _backward_rows(start, rows, again, d_y, *operands)
+
+    grads = _past_the_bound(
+        bound, operands[-1],
+        _backward_rows(0, bound, residuals, d_y, *operands), rest,
+    )
+    return (*grads, None)
+
+
+expert_rows.defvjp(_expert_rows_fwd, _expert_rows_bwd)
 
 
 def routed_experts(
@@ -531,9 +675,13 @@ def routed_experts(
     weight_e * w_down[e] (silu(w_gate[e] x) * (w_up[e] x))``, and
     ``load`` the int32 counters ``held_rows [experts_held]``,
     ``left_out`` (assignments to experts not held here) and
-    ``dropped`` (assignments to held experts that found no row:
-    0 by construction, counted from the plan, not assumed), beside
-    the router's own result, ``experts`` and ``weights`` ``[tokens,
+    ``dropped`` (assignments to held experts that found no row among
+    those walked: 0 by construction, counted, not assumed),
+    ``rows_active`` (the plan's active tiles in rows: what has to fit
+    ``rows_bound``), ``rows_walked`` (rows the glue passed over:
+    ``rows_bound``, or ``rows_capacity`` where the plan did not fit)
+    and ``fell_back`` (1 where it did not), beside the router's own
+    result, ``experts`` and ``weights`` ``[tokens,
     top_k]``, for whoever checks the routing itself.
     """
     tokens, _ = x.shape
@@ -547,6 +695,8 @@ def routed_experts(
     assert (bias is None) == (router_kind == "softmax")
     assert 0 <= first_expert <= experts_total - experts_held
     tile = gmm.tile_rows(tokens * min(top_k, experts_held))
+    capacity = rows_capacity(tokens, top_k, experts_held, tile)
+    bound = rows_bound(tokens, top_k, experts_held, experts_total, tile)
     trace.event(
         "moe.schedule",
         experts_total=experts_total,
@@ -554,7 +704,8 @@ def routed_experts(
         first_expert=first_expert,
         top_k=top_k,
         tokens=tokens,
-        rows_capacity=rows_capacity(tokens, top_k, experts_held, tile),
+        rows_capacity=capacity,
+        rows_bound=bound,
         tile_rows=tile,
         d_model=x.shape[1],
         d_expert=w_gate.shape[2],
@@ -571,18 +722,22 @@ def routed_experts(
     plan = lax.stop_gradient(
         plan_rows(experts, first_expert, experts_held, tile)
     )
-    groups = (plan.tile_expert, plan.active_tiles, plan.group_sizes)
-    rows = dispatch_rows(x, plan.row_token, plan.dest)
-    hidden = jax.nn.silu(
-        gmm.grouped_matmul(rows, w_gate, *groups)
-    ) * gmm.grouped_matmul(rows, w_up, *groups)
-    y_rows = gmm.grouped_matmul(hidden, w_down, *groups)
-    y = combine_rows(y_rows, weights, plan.dest, plan.row_assignment)
-    held = jnp.sum(plan.dest < rows.shape[0], dtype=jnp.int32)
+    y = expert_rows(bound, x, weights, w_gate, w_up, w_down, plan)
+    # Counted from the plan and the rows the step walked, not assumed.
+    rows_active = (plan.active_tiles[0] * tile).astype(jnp.int32)
+    walked = jnp.where(rows_active > bound, capacity, bound)
+    held = jnp.sum(plan.dest < capacity, dtype=jnp.int32)
+    placed = jnp.sum(
+        (plan.row_assignment >= 0) & (jnp.arange(capacity) < walked),
+        dtype=jnp.int32,
+    )
     load = {
         "held_rows": plan.group_sizes,
         "left_out": jnp.int32(tokens * top_k) - held,
-        "dropped": held - jnp.sum(plan.row_assignment >= 0, dtype=jnp.int32),
+        "dropped": held - placed,
+        "rows_active": rows_active,
+        "rows_walked": walked,
+        "fell_back": (walked > bound).astype(jnp.int32),
         "experts": experts,
         "weights": lax.stop_gradient(weights),
     }

@@ -958,7 +958,8 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
     """The routed layers' sown load (the "moe_load" collection of an
     ``apply(..., mutable=["moe_load"])``) stacked over the routed
     layers in order: ``{"held_rows": int32 [layers, held], "left_out":
-    [layers], "dropped": [layers]}``."""
+    [layers], "dropped": [layers], "rows_active": [layers],
+    "rows_walked": [layers], "fell_back": [layers]}``."""
     sown = mutated["moe_load"]
     return {
         name: jnp.stack(
@@ -968,7 +969,10 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
                 if config.routed(i)
             ]
         )
-        for name in ("held_rows", "left_out", "dropped")
+        for name in (
+            "held_rows", "left_out", "dropped", "rows_active",
+            "rows_walked", "fell_back",
+        )
     }
 
 

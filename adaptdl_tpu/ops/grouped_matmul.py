@@ -3,9 +3,10 @@ kernels for a dropless mixture-of-experts layer.
 
 The rows of one buffer ``[rows, k]`` belong to groups (one per held
 expert), each group multiplied with its own ``[k, n]`` weight. How
-many rows a group has is known only at run time; the buffer is sized
-for the worst case and most of it is usually unused. Three products,
-one op family:
+many rows a group has is known only at run time; the caller sizes the
+buffer (``models.moe``: near the rows it expects, with the worst
+case's length as its fall-back) and part of it is usually unused.
+Three products, one op family:
 
 - ``x W``     (``moe_gmm``):  ``out[r] = x[r] @ w[group(r)]``
 - ``dy W^T``  (``moe_gmm``):  the input gradient, same kernel with the
@@ -276,14 +277,23 @@ def _grouped_matmul_fwd(x, w, tile_expert, active_tiles, group_sizes):
     return out, (x, w, tile_expert, active_tiles, group_sizes)
 
 
-def _grouped_matmul_bwd(residuals, dy):
-    x, w, tile_expert, active_tiles, group_sizes = residuals
+def grouped_matmul_transposes(
+    x, w, tile_expert, active_tiles, group_sizes, dy
+):
+    """The two other products of ``grouped_matmul(x, w, ...)`` for its
+    result's cotangent ``dy``: ``(dy W^T [rows, k], x^T dy [experts, k,
+    n] in ``w.dtype``)``; what the ``custom_vjp`` runs, for a caller
+    that writes its own backward."""
     dx = _gmm(
         dy, w.astype(dy.dtype), tile_expert, active_tiles,
         transpose_rhs=True,
     )
     dw = _tgmm(x, dy, tile_expert, active_tiles, group_sizes)
-    return dx, dw.astype(w.dtype), None, None, None
+    return dx, dw.astype(w.dtype)
+
+
+def _grouped_matmul_bwd(residuals, dy):
+    return (*grouped_matmul_transposes(*residuals, dy), None, None, None)
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

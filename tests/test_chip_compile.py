@@ -214,6 +214,171 @@ def test_sparse_attention_kernels_compile_for_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
+@pytest.mark.parametrize(
+    "held, total, top_k, d_expert, router, bound, capacity",
+    [
+        (8, 32, 4, 1792, "sigmoid", 45056, 69632),  # lfm2-8b-a1b
+        # keye-vl-2.0-30b-a3b: the rest would be longer than the bound,
+        # so one pass over the worst case and no loop.
+        (16, 128, 8, 768, "softmax", 139264, 139264),
+    ],
+)
+def test_routed_layer_walks_the_bounded_buffer_on_v5e(
+    v5e, chip_compile, monkeypatch, held, total, top_k, d_expert, router,
+    bound, capacity,
+):
+    """A routed layer of either cell and its gradients, 16 384 tokens
+    of 2048 in bf16. Where the layer bounds its buffer, the glue XLA
+    generates has ``rows_bound`` rows in the usual pass and the plan's
+    other rows in the loop that runs where the plan passes the bound,
+    no array of the worst case's length is left in the program (the
+    int32 row plan apart), and the program's temporaries are under the
+    1.37 GiB the worst-case buffer took; the grouped products are in
+    every pass under their names."""
+    gmm = importlib.import_module("adaptdl_tpu.ops.grouped_matmul")
+    moe = importlib.import_module("adaptdl_tpu.models.moe")
+    monkeypatch.setattr(gmm, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e.devices[0])
+    tokens, d = 16384, 2048
+    assert moe.rows_bound(tokens, top_k, held, total, 512) == bound
+    assert moe.rows_capacity(tokens, top_k, held, 512) == capacity
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, router_w, w_gate, w_up, w_down):
+        y, load = moe.routed_experts(
+            x, router_w,
+            jnp.zeros((total,)) if router == "sigmoid" else None,
+            w_gate, w_up, w_down, experts_total=total, first_expert=0,
+            top_k=top_k, router_kind=router,
+        )
+        return y.astype(jnp.float32).sum(), load["fell_back"]
+
+    compiled = jax.jit(
+        jax.grad(loss, argnums=tuple(range(5)), has_aux=True)
+    ).lower(
+        arg((tokens, d), jnp.bfloat16), arg((d, total)),
+        arg((held, d, d_expert)), arg((held, d, d_expert)),
+        arg((held, d_expert, d)),
+    ).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    assert bound == capacity or " while(" in text
+    for rows in {bound, capacity - bound} - {0}:
+        assert re.search(rf"(bf16|f32)\[{rows},", text), rows
+    if bound < capacity:
+        assert not re.search(rf"(bf16|f32)\[{capacity},", text)
+    # Forward and both transposes, of each pass.
+    passes = 2 if bound < capacity else 1
+    assert text.count(f"%{gmm.GMM_KERNEL_NAME}") >= 6 * passes
+    assert text.count(f"%{gmm.TGMM_KERNEL_NAME}") >= 3 * passes
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.25 if bound < capacity else 2.5
+    ) * 2**30
+
+
+def _router_products(text, tokens, experts):
+    """How the compiler tiles each float32 "highest" product with a
+    ``[tokens, experts]`` result in an optimized program: the
+    ``window_config`` of the fusion that holds it (its bounds say how
+    the contraction is split, so which sums are taken in which
+    order)."""
+    found = []
+    bodies = dict(
+        re.findall(r"^(%[\w.\-]+) \(.*?\) -> .*? \{\n(.*?)^\}", text,
+                   re.S | re.M)
+    )
+    for line in text.splitlines():
+        called = re.search(r" fusion\(.*calls=(%[\w.\-]+)", line)
+        if called and re.search(
+            rf"f32\[{tokens},{experts}\]\S* convolution\(.*"
+            r"operand_precision=\{highest,highest\}",
+            bodies.get(called.group(1), ""),
+        ):
+            found.append(
+                re.search(r'"window_config":\{(.*?)"estimated_cycles"',
+                          line).group(1)
+            )
+    return found
+
+
+def test_lfm2_check_and_system_tile_their_routers_alike_on_v5e(
+    v5e, chip_compile, monkeypatch
+):
+    """``lfm2-8b-a1b-steady``'s own check holds every routed layer's
+    output to a reference that routes for itself, token by token, with
+    no allowance for a token whose 4th and 5th scores tie to the last
+    bit (``benchmark/configs/lfm2-8b-a1b.py:routed_check``): such a
+    token passes only while the reference's router product is summed
+    in the system's order, and the compiler decides that per program
+    from what else the program holds. With the row buffer at twice the
+    rows expected the check's program split the contraction in four
+    and one seed in eleven failed on the chip (PERF.md section 6, PR
+    40). Until a benchmark PR lets the comparison skip disputed
+    tokens, a change to the routed layer's shapes has to keep the two
+    products tiled alike: compiled here for a described v5e, as the
+    chip's compiler does it."""
+    from benchmark import manifest
+    from adaptdl_tpu.parallel import mesh as mesh_mod
+
+    gmm = importlib.import_module("adaptdl_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gmm, "_use_interpret", lambda: False)
+    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    one = SingleDeviceSharding(v5e.devices[0])
+    mesh = Mesh(np.array(v5e.devices[:1]), ("data",))
+    monkeypatch.setattr(
+        mesh_mod, "create_mesh_from_topology", lambda **kw: mesh
+    )
+    cell = manifest.load_cell("lfm2-8b-a1b-steady")
+    config = manifest.load_module(cell.config_py)
+    sizes = cell.sizes
+    # Abstract weights: nothing can be placed on a described device.
+    real_jit = jax.jit
+    monkeypatch.setattr(
+        jax, "jit",
+        lambda f, **kw: (lambda *a: jax.eval_shape(f, *a))
+        if getattr(f, "__name__", "") == "<lambda>" else real_jit(f, **kw),
+    )
+    built = config.build(sizes, dict(cell.workload["geometry"]), 0)
+    monkeypatch.setattr(jax, "jit", real_jit)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+            tree,
+        )
+
+    params = on_chip(built["trainer"]._abstract_state().params)
+    rows, seq = config.REFERENCE_SEQUENCES, sizes["sequence_length"]
+    batch = {
+        k: jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one)
+        for k in ("inputs", "targets")
+    }
+    system = jax.jit(built["head_io"]).lower(
+        params, batch, on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    ).compile().as_text()
+    at = sizes["num_dense_layers"]
+    layer = on_chip(
+        jax.eval_shape(
+            lambda p: config.reference_weights(p, sizes)["layers"][at],
+            params,
+        )
+    )
+    x = jax.ShapeDtypeStruct(
+        (rows * seq, sizes["hidden_size"]), jnp.bfloat16, sharding=one
+    )
+    check = jax.jit(config.routed_check(built, sizes)).lower(
+        layer, params[f"layer_{at}"]["moe"], x, x
+    ).compile().as_text()
+    experts = sizes["num_experts"]
+    of_system = _router_products(system, rows * seq, experts)
+    of_reference = _router_products(check, rows * seq, experts)
+    assert len(of_system) == sizes["num_hidden_layers"] - at
+    assert len(of_reference) == 1
+    assert set(of_system) == set(of_reference), (of_system, of_reference)
+
+
 def test_block_keeps_the_projections_layout_on_v5e(v5e, chip_compile):
     """One remat ``Block`` of the benchmark's model, forward and
     gradient at the cell's micro-batch, compiled for the described

@@ -24,6 +24,7 @@ from adaptdl_tpu.models.transformer import (
     routed_lm_loss_fn,
     sparse_select_counters,
 )
+from adaptdl_tpu.ops import grouped_matmul as gmm
 from adaptdl_tpu.ops import sparse_attention as sparse
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -521,6 +522,99 @@ def test_the_shares_of_a_softmax_layer_add_up_to_the_uncut_layer(shares):
         total = total + y
     np.testing.assert_allclose(total, whole, atol=5e-5)
     np.testing.assert_array_equal(np.concatenate(rows), np.asarray(counts))
+
+
+def _softmax_value_and_gradients(layer, first, held, top_k):
+    """The softmax layer's output, load and the gradients of a fixed
+    functional of it by input, router and the three expert leaves."""
+    cot = jax.random.normal(jax.random.key(23), layer["x"].shape)
+    at = slice(first, first + held)
+
+    def of(x, router, w1, w3, w2):
+        y, load = moe.routed_experts(
+            x, router, None, w1, w3, w2,
+            experts_total=layer["router"].shape[1], first_expert=first,
+            top_k=top_k, router_kind="softmax",
+        )
+        return (y * cot).sum(), (y, load)
+
+    (_, (y, load)), grads = jax.value_and_grad(of, range(5), has_aux=True)(
+        layer["x"], layer["router"], layer["w1"][at], layer["w3"][at],
+        layer["w2"][at],
+    )
+    return y, grads, load
+
+
+@pytest.mark.parametrize(
+    "tokens, experts, first, held, top_k",
+    [(64, 16, 4, 2, 2), (128, 32, 0, 4, 8)],
+)
+def test_the_bounded_softmax_layer_equals_the_worst_case_bit_for_bit(
+    tokens, experts, first, held, top_k, monkeypatch
+):
+    """A softmax router's share small enough to bound (the cell's own,
+    16 of 128, is not: ``rows_bound``): the glue walks ``rows_bound``
+    rows, and output and all five gradients are those of the layer
+    that walks ``rows_capacity``."""
+    layer = _layer(seed=tokens, tokens=tokens, experts=experts)
+    tile = gmm.tile_rows(tokens * min(top_k, held))
+    bound = moe.rows_bound(tokens, top_k, held, experts, tile)
+    capacity = moe.rows_capacity(tokens, top_k, held, tile)
+    assert bound < capacity
+    bounded = _softmax_value_and_gradients(layer, first, held, top_k)
+    assert int(bounded[2]["fell_back"]) == 0
+    assert int(bounded[2]["rows_walked"]) == bound
+    assert int(bounded[2]["dropped"]) == 0
+    monkeypatch.setattr(moe, "ROWS_BOUND_FACTOR", 1e9)
+    worst = _softmax_value_and_gradients(layer, first, held, top_k)
+    assert int(worst[2]["rows_walked"]) == capacity
+    np.testing.assert_array_equal(bounded[0], worst[0])
+    for name, a, b in zip(
+        ("x", "router", "w1", "w3", "w2"), bounded[1], worst[1]
+    ):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize(
+    "held_pair, active", [(32, 4), (48, 6), (64, 8)]
+)
+def test_a_softmax_plan_past_the_bound_falls_back_and_drops_nothing(
+    held_pair, active
+):
+    """64 tokens, top 2 of 16, experts 0 and 1 held, tiles of 16 rows:
+    the glue walks 80 rows where the worst case has 160.
+    ``held_pair`` tokens choose the held pair and
+    the rest two experts held elsewhere: inside the bound, past it,
+    and every token on the pair."""
+    layer = _layer(tokens=64, experts=16)
+    d = layer["x"].shape[1]
+    on_pair = jnp.arange(64) < held_pair
+    x = jnp.where(on_pair[:, None], jnp.eye(d)[0], jnp.eye(d)[1])
+    router = (
+        jnp.full((d, 16), -6.0)
+        .at[0, 0].set(4.0).at[0, 1].set(3.0)
+        .at[1, 5].set(4.0).at[1, 6].set(3.0)
+    )
+    steered = dict(layer, x=x, router=router)
+    at = slice(0, 2)
+    y, load = moe.routed_experts(
+        x, router, None, layer["w1"][at], layer["w3"][at], layer["w2"][at],
+        experts_total=16, first_expert=0, top_k=2, router_kind="softmax",
+    )
+    with jax.default_matmul_precision("highest"):
+        want, counts = _config_module().reference_routed_ffn(
+            {**steered, "w1": layer["w1"][at], "w3": layer["w3"][at],
+             "w2": layer["w2"][at]},
+            x, _sizes(num_experts=16), first_expert=0,
+        )
+    assert moe.rows_bound(64, 2, 2, 16, 16) == 80
+    assert int(load["dropped"]) == 0
+    assert int(load["fell_back"]) == int(active * 16 > 80)
+    assert int(load["rows_active"]) == active * 16
+    assert int(load["rows_walked"]) == (160 if active * 16 > 80 else 80)
+    np.testing.assert_array_equal(load["held_rows"], [held_pair] * 2)
+    np.testing.assert_array_equal(load["held_rows"], counts[:2])
+    np.testing.assert_allclose(y, want, atol=5e-5)
 
 
 # ---- heads of their own width, the untied table -----------------------
