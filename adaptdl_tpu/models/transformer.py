@@ -17,6 +17,7 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -24,8 +25,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
+from jax.ad_checkpoint import checkpoint_name
 
-from adaptdl_tpu import trace
+from adaptdl_tpu import device_budget, trace
 
 
 @dataclass(frozen=True)
@@ -45,10 +47,14 @@ class TransformerConfig:
     # ALWAYS keeps the flash kernel's output and log-sum-exp
     # (block_remat: attention is never computed twice; per layer one
     # [batch, seq, d_model] activation plus 4 bytes a head and
-    # position); a named policy ADDS to that, so None and
-    # "nothing_saveable" keep just those two. The policy trades
-    # recompute FLOPs for HBM — the knob to turn when activations,
-    # not weights, bound the batch size.
+    # position), and beyond them, where a trainer traces the model on
+    # a device that has the bytes, as many rungs as fit of q / k / v,
+    # the residual after the mixer and ff_up's result (3 + 1 + d_ff /
+    # d_model activations a layer; _remat_ladder: no knob, the
+    # trainer's budget decides). A named policy ADDS to that, so None
+    # and "nothing_saveable" add nothing. The policy trades recompute
+    # FLOPs for HBM — the knob to turn when a job should keep MORE
+    # than the ladder takes by itself.
     remat_policy: str | None = None
     # attention_fn(q, k, v) -> out; q/k/v are [batch, heads, seq,
     # head_dim]; None selects plain causal attention (or ring
@@ -275,6 +281,10 @@ class Attention(nn.Module):
                 from functools import partial
 
                 attn = partial(causal_attention, causal=cfg.causal)
+                # The plain path's rung (a) of ``block_remat``'s
+                # ladder; the flash kernel's forward rule names its
+                # own operands, in its own layout.
+                q, k, v = (checkpoint_name(t, SAVED_QKV) for t in (q, k, v))
         out = attn(q, k, v)  # [b, h, s, d]
         out = jnp.swapaxes(out, 1, 2).reshape(
             x.shape[:-1] + (cfg.num_heads * head_dim,)
@@ -716,7 +726,7 @@ class Block(nn.Module):
             y = nn.Dropout(cfg.dropout_rate, deterministic=False)(
                 y, rng=dropout_rng
             )
-        x = x + y
+        x = checkpoint_name(x + y, SAVED_MIXED)
         y = make_norm(cfg)(x)
         if self.use_moe:
             y = MoEFFN(cfg, name="moe")(y)
@@ -728,7 +738,7 @@ class Block(nn.Module):
             y = nn.Dense(
                 cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_up"
             )(y)
-            y = nn.gelu(y)
+            y = nn.gelu(checkpoint_name(y, SAVED_FF_UP))
             y = nn.Dense(
                 cfg.d_model, dtype=cfg.dtype, use_bias=False,
                 name="ff_down",
@@ -763,7 +773,66 @@ def _check_remat_policy(config: TransformerConfig) -> None:
         )
 
 
-def block_remat(config: TransformerConfig):
+# What a block names (``jax.ad_checkpoint.checkpoint_name``) beyond
+# the kernels' own names, for the rungs of ``block_remat``'s ladder:
+# q and k after rotary and v on the plain attention path (the flash
+# kernel names its operands itself: ``flash_attention.SAVED_QKV``),
+# the residual stream after the mixer, ``ff_up``'s result before the
+# gelu. Outside a remat, and in a remat that does not save it, a name
+# is an identity.
+SAVED_QKV = "attention_qkv"
+SAVED_MIXED = "block_mixed"
+SAVED_FF_UP = "block_ff_up"
+
+
+def _remat_ladder(config: TransformerConfig, tokens_shape):
+    """What a remat'd block keeps beyond its kernels' outputs: ``(names,
+    attributes of the remat.policy event)``.
+
+    The rungs, dearest recomputation a byte first (PERF.md, PR 41: 15.4
+    / 9.6 / 8.8 ms a GiB at gpt2-124m): q, k, v as attention reads them
+    (no QKV projection and no rotary in the backward), the residual
+    after the mixer (neither the mixer's out projection nor the second
+    norm's input), ``ff_up``'s result (the gelu FFN's first matmul).
+    A rung costs layers x this device's tokens of the micro-batch x its
+    width x the compute dtype's bytes — priced for every layer, so too
+    high where a layer's mixer or FFN has no such value — and is taken
+    while the sum fits what the trainer says the device has free
+    (``device_budget``) less the model's own large temporaries: the
+    float32 logits and their gradient. No budget (no trainer, or a
+    device that does not say its ``bytes_limit``), no rung. A pure
+    function of the config, the shape and the budget.
+    """
+    budget = device_budget.activations()
+    if budget is None or tokens_shape is None:
+        return (), {"rungs": "", "rung_bytes": 0, "budget_bytes": -1,
+                    "bytes_limit": -1}
+    from adaptdl_tpu.ops.flash_attention import SAVED_QKV as FLASH_QKV
+
+    tokens = math.prod(tokens_shape)
+    left = budget.free_bytes - 2 * tokens * config.vocab_size * 4
+    ladder = [
+        ("qkv", (FLASH_QKV, SAVED_QKV),
+         3 * config.num_heads * config.attention_head_dim),
+        ("mixed", (SAVED_MIXED,), config.d_model),
+    ]
+    if config.ffn == "gelu":
+        ladder.append(("ff_up", (SAVED_FF_UP,), config.d_ff))
+    per_width = config.num_layers * tokens * jnp.dtype(config.dtype).itemsize
+    names, rungs, spent = (), [], 0
+    for rung, rung_names, width in ladder:
+        if spent + per_width * width > left:
+            break
+        names += rung_names
+        rungs.append(rung)
+        spent += per_width * width
+    return names, {
+        "rungs": ",".join(rungs), "rung_bytes": spent,
+        "budget_bytes": left, "bytes_limit": budget.bytes_limit,
+    }
+
+
+def block_remat(config: TransformerConfig, tokens_shape=None):
     """The ``Block`` class a model of this config stacks: ``Block``
     itself, or with ``config.remat`` its ``nn.remat`` — the one place
     that knows what a remat'd block keeps from forward to backward.
@@ -772,9 +841,11 @@ def block_remat(config: TransformerConfig):
     forward rule gives them: the kernel's FLOPs per byte of output
     grow with the sequence, so its output is the dearest byte of the
     block at every shape, and no policy a user can name would keep it
-    (a ``pallas_call`` is not a dot). A named ``remat_policy`` adds
-    what it saves to that. Blocks without the kernel name nothing and
-    are remat'd as the policy alone would.
+    (a ``pallas_call`` is not a dot). Then, as far as the device's
+    bytes allow for a micro-batch of ``tokens_shape`` on this device,
+    the rungs of ``_remat_ladder``. A named ``remat_policy`` adds what
+    it saves to that. Blocks without the kernel and with no budget
+    name nothing and are remat'd as the policy alone would.
 
     Records one ``remat.policy`` event a call: a model calls this once
     each time it is traced.
@@ -792,6 +863,8 @@ def block_remat(config: TransformerConfig):
         from adaptdl_tpu.ops.sparse_attention import SAVED_NAMES
 
         saved_names += SAVED_NAMES
+    rung_names, ladder_attrs = _remat_ladder(config, tokens_shape)
+    saved_names += rung_names
     policy = jax.checkpoint_policies.save_only_these_names(*saved_names)
     if config.remat_policy is not None:
         policy = jax.checkpoint_policies.save_from_both_policies(
@@ -803,6 +876,7 @@ def block_remat(config: TransformerConfig):
         saved_names=",".join(saved_names),
         policy=config.remat_policy or "none",
         blocks=config.num_layers,
+        **ladder_attrs,
     )
     return nn.remat(Block, static_argnums=(), policy=policy)
 
@@ -836,7 +910,7 @@ class TransformerLM(nn.Module):
             ) * tokens.shape[1] + jnp.arange(tokens.shape[1])
         else:
             positions = jnp.arange(tokens.shape[1])
-        block_cls = block_remat(cfg)
+        block_cls = block_remat(cfg, tokens.shape)
         for layer in range(cfg.num_layers):
             dropout_rng = (
                 jax.random.fold_in(rng, layer)

@@ -176,6 +176,12 @@ BWD_KERNEL_NAME = "flash_bwd"
 # an identity.
 SAVED_OUT = "flash_out"
 SAVED_LSE = "flash_lse"
+# ... and of what it consumes: q, k and v in the kernels' layout, the
+# residuals the backward kernel reads. A remat'd block keeps them where
+# the device has the bytes (``block_remat``'s ladder), and then neither
+# the QKV projection nor rotary runs in the backward; named HERE so
+# that what is kept is the array the backward kernel reads.
+SAVED_QKV = "flash_qkv"
 # What both kernels index, as the ``flash.schedule*`` events name it:
 # ``[batch * heads, head_dim, seq]``.
 LAYOUT = "bhds"
@@ -519,6 +525,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
         head_dim**-0.5 if scale is None else float(scale)
     )
     operands = tuple(_to_kernel(x) for x in (q, k, v))
+    if with_lse:  # the forward rule: what a remat around it may keep
+        operands = tuple(checkpoint_name(x, SAVED_QKV) for x in operands)
     out, lse = _fwd_pallas(
         *operands, causal, resolved_scale, block_q, block_k, with_lse
     )
@@ -528,11 +536,11 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
     """Residuals ``(q, k, v, out, lse)`` as the backward kernel reads
     them (a copy here would be a copy a step): the first four in the
-    kernels' layout, ``lse`` as ``[batch * heads, 1, seq]``. ``out``
-    and ``lse`` are NAMED before anything reads them, so that a
-    ``jax.checkpoint`` policy around the call can keep them: a name
-    inside a ``custom_vjp``'s forward rule is seen by the enclosing
-    remat."""
+    kernels' layout, ``lse`` as ``[batch * heads, 1, seq]``. All five
+    are NAMED before anything reads them (the operands in
+    ``_flash_fwd``), so that a ``jax.checkpoint`` policy around the
+    call can keep them: a name inside a ``custom_vjp``'s forward rule
+    is seen by the enclosing remat."""
     *operands, out, lse = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, with_lse=True
     )

@@ -30,7 +30,9 @@ adaptdl_tpu.data) so recompilation stays rare.
 
 from __future__ import annotations
 
+import functools
 import logging
+import math
 import pickle
 from typing import Any, Callable, NamedTuple
 
@@ -40,7 +42,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from adaptdl_tpu import checkpoint, gns, storage, trace
+from adaptdl_tpu import checkpoint, device_budget, gns, storage, trace
 
 _LOG = logging.getLogger(__name__)
 from adaptdl_tpu.parallel.mesh import (
@@ -52,6 +54,10 @@ from adaptdl_tpu.parallel.mesh import (
     STAGE_AXIS,
 )
 from adaptdl_tpu.scaling_rules import RuleContext, ScalingRule
+
+
+def _memory_stats(device) -> dict:
+    return device.memory_stats() or {}
 
 
 class TrainState(NamedTuple):
@@ -401,8 +407,6 @@ class ElasticTrainer:
         from adaptdl_tpu import aot_cache
 
         jitted, cacheable = stepped_pair
-        if not aot_cache.enabled():
-            return jitted
         # "unverified": a DESERIALIZED executable has not run yet. Its
         # first execution is awaited inside the try below — dispatch
         # is asynchronous, so a runtime failure of a bad entry would
@@ -411,19 +415,42 @@ class ElasticTrainer:
         # that finds the same entry).
         cell: dict[str, Any] = {
             "compiled": None, "tried": False, "unverified": None,
+            "aot": aot_cache.enabled(),
         }
 
         def stepped(state, batch, aux):
+            # The program traced here, at the step's first call, is
+            # traced under what the device has free for activations
+            # (``_activations``): the twin, or without a cache the
+            # step that donates by default. The donating step that
+            # runs because the twin was REFUSED gets nothing: a job
+            # that cannot afford a second copy of its state has no
+            # bytes to spend.
+            if not cell["tried"] and not cell["aot"]:
+                cell["tried"] = True
+                with device_budget.tracing_with(self._activations()):
+                    return jitted(state, batch, aux)
             if not cell["tried"]:
                 cell["tried"] = True
                 try:
                     cell["fit"] = self._second_state_fits(state)
                     if cell["fit"]["fits"]:
-                        cell["compiled"], hit_fp = (
-                            aot_cache.load_or_compile(
-                                self, key, cacheable, (state, batch, aux)
-                            )
+                        # (Not a ``with``: its exit on the stack
+                        # would make this frame a slot larger, and
+                        # the sizes of the frames above a trace are
+                        # held: PERF.md section 6, PR 28.)
+                        cell["budget"] = device_budget.enter(
+                            self._activations()
                         )
+                        try:
+                            cell["compiled"], hit_fp = (
+                                aot_cache.load_or_compile(
+                                    self, key, cacheable,
+                                    (state, batch, aux),
+                                )
+                            )
+                        finally:
+                            device_budget.leave(cell.pop("budget"))
                         cell["unverified"] = hit_fp
                         if hit_fp is None:
                             cell["fit"] = self._second_state_fits(
@@ -469,8 +496,48 @@ class ElasticTrainer:
     def _device_bytes_limit(self) -> int | None:
         """What one device's allocator may hand out, where the
         backend says (a TPU does; the CPU does not)."""
-        stats = self.mesh.devices.flat[0].memory_stats() or {}
-        return stats.get("bytes_limit")
+        return _memory_stats(self.mesh.devices.flat[0]).get("bytes_limit")
+
+    @functools.cached_property
+    def _held_bytes(self) -> int:
+        """Bytes one device holds of the train state plus one gradient
+        — what ``_second_state_fits`` reads off a placed state, here
+        from shapes and the storage layout's specs alone, so that it
+        is there when a program is traced and is the same in every
+        incarnation of the job."""
+        state = self._abstract_state()
+
+        def nbytes(leaf, spec):
+            if jax.dtypes.issubdtype(leaf.dtype, jax.dtypes.prng_key):
+                return 0
+            shape = NamedSharding(self.mesh, spec).shard_shape(leaf.shape)
+            return math.prod(shape) * leaf.dtype.itemsize
+
+        held = jax.tree.map(nbytes, state, self.state_spec_tree(state))
+        return sum(jax.tree.leaves(held)) + sum(jax.tree.leaves(held.params))
+
+    def _activations(self) -> device_budget.Activations | None:
+        """What this device has free for activations under a step
+        program of this job: ``bytes_limit`` less TWO copies of the
+        train state and gradient, as ``_second_state_fits`` reckons
+        the non-donating twin, and a sixteenth of the limit in reserve
+        (what the runtime and a program's scheduling take beyond any
+        count made here). A job with no AOT cache runs no twin and
+        holds its state once; it is priced alike, because there no
+        compiler-checked fall-back stands behind a budget that was
+        too generous. Set around TRACING a program
+        (``device_budget.tracing_with``, inline at the program's
+        first call: a frame more between ``run_step`` and the model
+        moves the trace time by seconds, PERF.md PR 41), for a model
+        that can trade memory for recomputation (``block_remat``).
+        None where the device does not say its limit: the program is
+        then the one traced without this."""
+        limit = self._device_bytes_limit()
+        if limit is None:
+            return None
+        return device_budget.Activations(
+            limit - 2 * self._held_bytes - limit // 16, limit
+        )
 
     def _second_state_fits(self, state, compiled=None) -> dict:
         """Whether the NON-donating twin the AOT cache runs, whose
@@ -882,9 +949,11 @@ class ElasticTrainer:
             micro = jax.tree.map(lambda x: x[:local_rows], host_batch)
             micro = self.shard_batch(micro)
             start = _time.monotonic()
-            jax.block_until_ready(
-                fn(state.params, micro, state.rng, aux)
-            )  # compile
+            # Traced as the step it models is (``_aot_wrap``).
+            with device_budget.tracing_with(self._activations()):
+                jax.block_until_ready(
+                    fn(state.params, micro, state.rng, aux)
+                )  # compile
             attrs["first_call_s"] = _time.monotonic() - start
             best = float("inf")
             for _ in range(repeats):

@@ -522,6 +522,143 @@ def test_remat_keeps_the_kernels_output_on_v5e(v5e, chip_compile):
     assert calls(flash_mod.BWD_KERNEL_NAME) == layers
 
 
+def test_ladder_keeps_the_projections_results_on_v5e(v5e, chip_compile):
+    """Two layers at the flagship widths and the cell's micro-batch,
+    the gradient compiled for the described v5e: with the budget a
+    16 GB chip leaves a ``gpt2-124m`` job (``block_remat``'s ladder
+    takes its three rungs) the text holds ONE fused QKV projection a
+    layer, where without a budget — the program before the ladder — it
+    holds two, the forward's and the backward's re-run; the kernels
+    are one forward and one backward a layer either way (PERF.md, PR
+    41)."""
+    import functools
+
+    from adaptdl_tpu import device_budget, trace
+    from adaptdl_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    batch, heads, seq, head_dim = CELL
+    layers = 2
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=layers, num_heads=heads,
+        d_model=heads * head_dim, d_ff=4 * heads * head_dim,
+        max_seq_len=seq, dtype=jnp.bfloat16, remat=True,
+        attention_fn=functools.partial(
+            flash_mod.flash_attention, block_q=128, block_k=128
+        ),
+    )
+    model = TransformerLM(cfg)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: model.init(
+                jax.random.key(0), jnp.zeros(tokens.shape, tokens.dtype)
+            )
+        ),
+    )
+
+    def loss(params, tokens):
+        return model.apply(params, tokens, train=False).sum()
+
+    def qkv_and_kernels(budget):
+        with device_budget.tracing_with(budget):
+            text = (
+                jax.jit(jax.grad(loss)).lower(params, tokens).compile()
+                .as_text()
+            )
+        qkv = len(re.findall(
+            rf"^\s*%[\w.\-]+ = bf16\[3,{batch},{seq},{heads},{head_dim}\]"
+            r"\S* fusion\(", text, re.M,
+        ))
+        kernels = tuple(
+            len(re.findall(
+                rf"^\s*%{name}[.\d]* = .*{flash_mod.MOSAIC_CALL}", text, re.M
+            ))
+            for name in ("attention", flash_mod.BWD_KERNEL_NAME)
+        )
+        return qkv, kernels
+
+    assert qkv_and_kernels(None) == (2 * layers, (layers, layers))
+    gib = 2**30
+    # gpt2-124m on a v5e: 15.75 GiB less two copies of 2.30 GiB of
+    # state and gradient and a sixteenth in reserve.
+    budget = device_budget.Activations(int(10.17 * gib), int(15.75 * gib))
+    assert qkv_and_kernels(budget) == (layers, (layers, layers))
+    (attrs,) = [
+        r["attrs"] for r in trace.snapshot_spans()
+        if r["name"] == "remat.policy"
+    ][-1:]
+    assert attrs["rungs"] == "qkv,mixed,ff_up"
+    assert attrs["rung_bytes"] == layers * batch * seq * 2 * 8 * cfg.d_model
+
+
+@pytest.mark.parametrize(
+    "cell", ["lfm2-8b-a1b-steady", "keye-vl-2.0-30b-a3b-steady"]
+)
+def test_routed_cells_steps_are_the_programs_before_the_ladder(
+    v5e, chip_compile, monkeypatch, tmp_path, cell
+):
+    """Both routed cells at real size, on a described v5e that says
+    what a 16 GB chip says (``bytes_limit`` 15.75 GiB) under a job
+    with a checkpoint path: two copies of their state and gradient
+    pass the limit, so the step DONATES, and a job that donates for
+    want of memory has no bytes for the ladder — the program the
+    trainer lowers is, text for text, the one lowered with no budget
+    mechanism and no rung named at all (their traffic bypasses the
+    ladder by the rule's own decision, not by their names)."""
+    from adaptdl_tpu import trace
+    from adaptdl_tpu import trainer as trainer_mod
+    from adaptdl_tpu.models import transformer
+    from tools import compile_step_v5e as rehearsal
+
+    gmm = importlib.import_module("adaptdl_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gmm, "_use_interpret", lambda: False)
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+
+    def lowered(limit):
+        rehearsal.inject_limit(limit, monkeypatch.setattr)
+        lower, facts = rehearsal.step_program(
+            cell, bytes_limit=rehearsal.BYTES_LIMIT, topo=v5e
+        )
+        assert facts["donated"]
+        # (Without the serial numbers that lowering appends to the
+        # names of private functions.)
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", lower().as_text())
+
+    def no_mechanism():
+        for mod in (flash_mod, transformer):
+            monkeypatch.setattr(
+                mod, "checkpoint_name",
+                lambda x, name, keep=mod.checkpoint_name: x if name in (
+                    flash_mod.SAVED_QKV, transformer.SAVED_QKV,
+                    transformer.SAVED_MIXED, transformer.SAVED_FF_UP,
+                ) else keep(x, name),
+            )
+        monkeypatch.setattr(
+            trainer_mod.ElasticTrainer, "_activations", lambda self: None,
+        )
+
+    texts = []
+    # (One call site for both: a Pallas kernel's serialized body holds
+    # the Python stack it was traced under, line and column.)
+    for limit, prepare in (
+        (rehearsal.BYTES_LIMIT, lambda: None), (None, no_mechanism)
+    ):
+        prepare()
+        before = len(trace.snapshot_spans())
+        texts.append(lowered(limit))
+        policies = [
+            r["attrs"] for r in trace.snapshot_spans()[before:]
+            if r["name"] == "remat.policy"
+        ]
+        assert policies and all(p["rungs"] == "" for p in policies)
+    assert texts[0] == texts[1]
+
+
 def test_chip_smoke_incarnations_on_cpu(tmp_path, monkeypatch):
     """The smoke's two incarnations (children of this process, which
     holds no chip) with a tiny ``TransformerConfig`` and the expected
