@@ -67,13 +67,18 @@ def transformer_train_flops(
     Matmul-only accounting; layernorms, softmax, RoPE, and residual
     adds are ignored (sub-percent at real widths). MoE blocks cost
     ``top_k`` expert FFNs plus the router per token — the capacity
-    padding all_to_all moves is communication, not model FLOPs.
+    padding all_to_all moves is communication, not model FLOPs. A
+    looped model (``loop_passes`` > 1) runs every block and, in
+    training over all its exits, the head once a pass.
     """
     d = config.d_model
     d_ff = config.d_ff
     tokens = batch_size * seq_len
+    passes = getattr(config, "loop_passes", 1)
 
-    dense_ffn = 2 * (2 * d * d_ff)  # up + down projections, per token
+    # up + down projections (and the gate's of a SwiGLU), per token
+    ffn_matmuls = 3 if getattr(config, "ffn", "gelu") == "swiglu" else 2
+    dense_ffn = 2 * (ffn_matmuls * d * d_ff)
     moe_every = getattr(config, "moe_every_n", 0) or 0
     num_moe = (
         sum(
@@ -86,13 +91,14 @@ def transformer_train_flops(
     )
     num_dense = config.num_layers - num_moe
     top_k = max(getattr(config, "moe_top_k", 1), 1)
-    moe_ffn = top_k * dense_ffn + 2 * d * max(
+    # (A Switch expert is up + down whatever ``ffn`` says.)
+    moe_ffn = top_k * 2 * (2 * d * d_ff) + 2 * d * max(
         getattr(config, "moe_num_experts", 0), 0
     )
 
     proj = 2 * (4 * d * d)  # fused QKV (3 d^2) + output (d^2), per token
     head = 2 * d * config.vocab_size  # LM head, per token
-    fwd_matmul = tokens * (
+    fwd_matmul = tokens * passes * (
         config.num_layers * proj
         + num_dense * dense_ffn
         + num_moe * moe_ffn
@@ -105,7 +111,7 @@ def transformer_train_flops(
     attn_per_token = 2 * (2 * seq_len * d)
     if getattr(config, "causal", True):
         attn_per_token /= 2
-    fwd_attn = tokens * config.num_layers * attn_per_token
+    fwd_attn = tokens * passes * config.num_layers * attn_per_token
 
     return FlopsBreakdown(
         matmul=3.0 * fwd_matmul, attention=3.0 * fwd_attn

@@ -155,6 +155,21 @@ class TransformerConfig:
     # False: an output table of its own (``lm_head``) instead of the
     # embedding's.
     tie_embeddings: bool = True
+    # Sandwich normalisation: a second norm on the mixer's and on the
+    # FFN's RESULT, before each is added to the residual stream (four
+    # norms a block, ``a = x + N2(Mixer(N1 x))``, ``y = a + N4(FFN(N3
+    # a))``).
+    sandwich_norm: bool = False
+    # A looped stack: ``layer_0 .. layer_{num_layers - 1}`` applied
+    # ``loop_passes`` times a forward pass with ONE set of parameters
+    # (a ``lax.scan`` over the passes, the parameters broadcast), the
+    # final norm after every pass — the next pass starts from the
+    # NORMED state — and after every pass an exit: the normed state
+    # through the one output table, and a learned gate on it
+    # (``exit_gate``, one linear ``d_model -> 1`` with bias, float32).
+    # 1 = every block once, no gate: the tree and program of before.
+    # Dense blocks without dropout only (``looped_lm_loss_fn``).
+    loop_passes: int = 1
 
     @property
     def attention_head_dim(self) -> int:
@@ -722,6 +737,8 @@ class Block(nn.Module):
         cfg = self.config
         y = make_norm(cfg)(x)
         y = _mixer(cfg, self.layer)(y, positions)
+        if cfg.sandwich_norm:
+            y = make_norm(cfg)(y)
         if cfg.dropout_rate > 0 and dropout_rng is not None:
             y = nn.Dropout(cfg.dropout_rate, deterministic=False)(
                 y, rng=dropout_rng
@@ -743,6 +760,8 @@ class Block(nn.Module):
                 cfg.d_model, dtype=cfg.dtype, use_bias=False,
                 name="ff_down",
             )(y)
+        if cfg.sandwich_norm:
+            y = make_norm(cfg)(y)
         return x + y
 
 
@@ -794,12 +813,14 @@ def _remat_ladder(config: TransformerConfig, tokens_shape):
     (no QKV projection and no rotary in the backward), the residual
     after the mixer (neither the mixer's out projection nor the second
     norm's input), ``ff_up``'s result (the gelu FFN's first matmul).
-    A rung costs layers x this device's tokens of the micro-batch x its
-    width x the compute dtype's bytes — priced for every layer, so too
-    high where a layer's mixer or FFN has no such value — and is taken
+    A rung costs block applications (layers x ``loop_passes``) x this
+    device's tokens of the micro-batch x its width x the compute
+    dtype's bytes — priced for every layer, so too high where a
+    layer's mixer or FFN has no such value — and is taken
     while the sum fits what the trainer says the device has free
     (``device_budget``) less the model's own large temporaries: the
-    float32 logits and their gradient. No budget (no trainer, or a
+    float32 logits and their gradient (of ONE exit: a looped model's
+    loss streams its exits' heads). No budget (no trainer, or a
     device that does not say its ``bytes_limit``), no rung. A pure
     function of the config, the shape and the budget.
     """
@@ -818,7 +839,10 @@ def _remat_ladder(config: TransformerConfig, tokens_shape):
     ]
     if config.ffn == "gelu":
         ladder.append(("ff_up", (SAVED_FF_UP,), config.d_ff))
-    per_width = config.num_layers * tokens * jnp.dtype(config.dtype).itemsize
+    per_width = (
+        config.num_layers * config.loop_passes * tokens
+        * jnp.dtype(config.dtype).itemsize
+    )
     names, rungs, spent = (), [], 0
     for rung, rung_names, width in ladder:
         if spent + per_width * width > left:
@@ -875,7 +899,7 @@ def block_remat(config: TransformerConfig, tokens_shape=None):
         "remat.policy",
         saved_names=",".join(saved_names),
         policy=config.remat_policy or "none",
-        blocks=config.num_layers,
+        blocks=config.num_layers * config.loop_passes,
         **ladder_attrs,
     )
     return nn.remat(Block, static_argnums=(), policy=policy)
@@ -892,6 +916,7 @@ class TransformerLM(nn.Module):
         train: bool = True,
         rng=None,
         return_hidden: bool = False,
+        return_exits: bool = False,
     ):
         cfg = self.config
         embed = nn.Embed(
@@ -911,16 +936,27 @@ class TransformerLM(nn.Module):
         else:
             positions = jnp.arange(tokens.shape[1])
         block_cls = block_remat(cfg, tokens.shape)
-        for layer in range(cfg.num_layers):
-            dropout_rng = (
-                jax.random.fold_in(rng, layer)
-                if (train and rng is not None and cfg.dropout_rate > 0)
-                else None
-            )
-            x = block_cls(
-                cfg, cfg.switch_moe(layer), layer, name=f"layer_{layer}"
-            )(x, positions, dropout_rng)
-        x = make_norm(cfg)(x)
+        if cfg.loop_passes > 1:
+            exits = self._looped(block_cls, x, positions)
+            if return_exits:
+                # For ``looped_lm_loss_fn``: every exit's normed state
+                # [passes, b, s, d] and gate logit [passes, b, s].
+                return exits
+            x = exits[0][-1]  # the last exit's: no exit is taken early
+        else:
+            if return_exits:
+                raise ValueError("return_exits needs loop_passes > 1")
+            for layer in range(cfg.num_layers):
+                dropout_rng = (
+                    jax.random.fold_in(rng, layer)
+                    if (train and rng is not None and cfg.dropout_rate > 0)
+                    else None
+                )
+                x = block_cls(
+                    cfg, cfg.switch_moe(layer), layer,
+                    name=f"layer_{layer}",
+                )(x, positions, dropout_rng)
+            x = make_norm(cfg)(x)
         if return_hidden:
             # For losses that stream the output head themselves (the
             # chunked cross-entropy, ops/chunked_xent.py): no
@@ -941,6 +977,52 @@ class TransformerLM(nn.Module):
         # Tied output head through the embedding table keeps the only
         # O(vocab x d_model) matmul single-sourced.
         return embed.attend(x).astype(jnp.float32)
+
+    def _looped(self, block_cls, x, positions):
+        """The stack applied ``loop_passes`` times with one set of
+        parameters, as one ``lax.scan`` over the passes (``nn.scan``,
+        the parameters broadcast: a program of ``num_layers`` blocks
+        whatever the passes; a shared leaf's cotangent is summed in
+        the backward scan's carry). Returns every pass's normed state
+        ``[passes, b, s, d]`` and gate logit ``[passes, b, s]``."""
+        cfg = self.config
+        if (
+            cfg.dropout_rate > 0
+            or cfg.experts_total > 0
+            or cfg.moe_every_n > 0
+            or "sparse_attention" in (cfg.layer_types or ())
+        ):
+            raise ValueError(
+                "loop_passes > 1 takes dense blocks without dropout: "
+                "what a routed, Switch or sparse layer sows has no "
+                "pass axis"
+            )
+
+        def exit_of(mdl, x):
+            # (Under a remat of its own: a pass keeps its un-normed
+            # state for the backward, not the norm's float32 insides.)
+            z = make_norm(cfg)(x)
+            gate = nn.Dense(
+                1, dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                name="exit_gate",
+            )(z)
+            return z, gate[..., 0]
+
+        def one_pass(mdl, x, _):
+            for layer in range(cfg.num_layers):
+                x = block_cls(cfg, False, layer, name=f"layer_{layer}")(
+                    x, positions, None
+                )
+            z, gate = nn.remat(exit_of)(mdl, x)
+            return z, (z, gate)
+
+        _, exits = nn.scan(
+            one_pass,
+            variable_broadcast="params",
+            split_rngs={"params": False},
+            length=cfg.loop_passes,
+        )(self, x, None)
+        return exits
 
 
 def untied_logits(hidden, table):
@@ -1114,6 +1196,93 @@ def routed_lm_loss_fn(model: TransformerLM):
             loss = loss + selected["indexer.loss"]["loss"].mean()
             counters.update(selected)
         return loss, counters
+
+    loss_fn.has_counters = True
+    return loss_fn
+
+
+def exit_log_probs(gate):
+    """The exit distribution of gate logits ``[passes, ...]``, as
+    logarithms: ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for ``t <
+    passes`` and the rest of the mass on the last exit, ``lambda =
+    sigmoid(gate)``."""
+    stay = jax.nn.log_sigmoid(-gate)  # log (1 - lambda_t)
+    stayed = jnp.cumsum(stay, axis=0) - stay  # log prod_{j<t} (1 - lambda_j)
+    return jnp.concatenate(
+        [stayed[:-1] + jax.nn.log_sigmoid(gate[:-1]), stayed[-1:]]
+    )
+
+
+def looped_lm_loss_fn(
+    model: TransformerLM, beta: float = 0.1, chunk_size: int = 2048
+):
+    """The expected next-token cross-entropy of a looped model
+    (``loop_passes > 1``) over its exits, less ``beta`` times the
+    exit distribution's entropy: ``mean_tokens(sum_t p_t CE(l_t) -
+    beta H(p))``, ``l_t`` the exit's normed state through the ONE
+    output table, ``p`` from the gate (``exit_log_probs``), everything
+    from the cross-entropies on in float32. batch = {"inputs",
+    "targets"}, each [b, s] int32.
+
+    The exits' heads, softmax and per-token losses stream the table
+    ONCE, in chunks of ``chunk_size`` columns against the rows of all
+    exits together (``ops.chunked_xent``: compute dtype operands,
+    float32 accumulation, a chunk's logits recomputed in the
+    backward): between forward and backward an exit keeps its state
+    and two ``[tokens]`` vectors, nothing ``[tokens, vocab]``-sized
+    ever exists, and the table's gradient is one contraction over
+    every exit's rows, not a sum of one a exit.
+
+    Returns ``(loss, {"loop.exit": ...})`` (``has_counters``, as
+    ``routed_lm_loss_fn``): per exit the mean cross-entropy ``xent``
+    and mean probability ``p``, the mean ``entropy`` and the mean
+    ``expected_exit = sum_t t p_t`` (1 .. passes), each summed over
+    the step's ``micro_batches``. Journals one ``loop.schedule`` event
+    each time it is traced."""
+    from adaptdl_tpu.ops.chunked_xent import chunked_softmax_xent
+
+    cfg = model.config
+    passes = cfg.loop_passes
+    assert passes > 1, "looped_lm_loss_fn needs loop_passes > 1"
+    chunk = min(chunk_size, cfg.vocab_size)
+
+    def loss_fn(params, batch, rng):
+        trace.event(
+            "loop.schedule",
+            passes=passes,
+            blocks=cfg.num_layers,
+            applications=passes * cfg.num_layers,
+            how="scan",
+            exits=passes,
+            head=f"chunked_xent, {chunk} of {cfg.vocab_size} columns "
+            "a chunk, the rows of all exits at once",
+        )
+        states, gate = model.apply(
+            {"params": params}, batch["inputs"], train=True, rng=rng,
+            return_exits=True,
+        )
+        table = (
+            params["embed"]["embedding"] if cfg.tie_embeddings
+            else params["lm_head"]
+        )
+        xent = chunked_softmax_xent(
+            states.reshape(-1, cfg.d_model), table,
+            jnp.tile(batch["targets"].reshape(-1), passes), chunk,
+        ).reshape(passes, -1)
+        log_p = exit_log_probs(gate.reshape(passes, -1))
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * xent, axis=0) - beta * entropy)
+        exit_at = jnp.arange(1, passes + 1, dtype=jnp.float32)
+        return loss, {
+            "loop.exit": {
+                "xent": xent.mean(axis=1),
+                "p": p.mean(axis=1),
+                "entropy": entropy.mean(),
+                "expected_exit": (exit_at[:, None] * p).sum(axis=0).mean(),
+                "micro_batches": jnp.int32(1),
+            }
+        }
 
     loss_fn.has_counters = True
     return loss_fn

@@ -57,6 +57,15 @@ def _chunk_mask(chunk_idx, chunk_size, vocab, rows):
     return jnp.broadcast_to(cols[None, :] < vocab, (rows, chunk_size))
 
 
+def _is_target(chunk_idx, chunk_size, targets):
+    """[rows, chunk] True at each row's target column, where this chunk
+    holds it: an elementwise compare that fuses into the chunk's
+    softmax pass, where a gather of ``embedding[targets]`` and a
+    scatter into the table's gradient are a row at a time on a TPU."""
+    cols = chunk_idx * chunk_size + jnp.arange(chunk_size)
+    return cols[None, :] == targets[:, None]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def chunked_softmax_xent(
     x: jnp.ndarray,
@@ -84,20 +93,25 @@ def chunked_softmax_xent(
 def _xent_fwd_impl(x, embedding, targets, chunk_size):
     tokens, d = x.shape
     vocab = embedding.shape[0]
-    # Operands stay in their input dtype (bf16 on TPU keeps the MXU at
-    # full rate and avoids an O(vocab x d) f32 table copy); every dot
-    # ACCUMULATES in f32 via preferred_element_type, and the softmax
-    # arithmetic runs on the f32 products.
+    # Operands in x's dtype — a table chunk is rounded to it as it is
+    # read (bf16 on TPU keeps the MXU at full rate, and no O(vocab x
+    # d) copy of a float32 table is ever made); every dot ACCUMULATES
+    # in f32 via preferred_element_type, and the softmax arithmetic
+    # runs on the f32 products.
     chunks, _ = _pad_chunks(embedding, chunk_size)
     chunk_size = chunks.shape[1]
 
     def fold(carry, inp):
-        m, s = carry
+        m, s, target_logit = carry
         idx, e_chunk = inp
         logits = jnp.einsum(
-            "td,kd->tk", x, e_chunk,
+            "td,kd->tk", x, e_chunk.astype(x.dtype),
             preferred_element_type=jnp.float32,
         )  # [tokens, chunk] — the live buffer
+        target_logit = target_logit + jnp.sum(
+            jnp.where(_is_target(idx, chunk_size, targets), logits, 0.0),
+            axis=-1,
+        )
         logits = jnp.where(
             _chunk_mask(idx, chunk_size, vocab, tokens), logits, NEG_INF
         )
@@ -105,22 +119,18 @@ def _xent_fwd_impl(x, embedding, targets, chunk_size):
         s = s * jnp.exp(m - m_new) + jnp.sum(
             jnp.exp(logits - m_new[:, None]), axis=-1
         )
-        return (m_new, s), None
+        return (m_new, s, target_logit), None
 
     # Derive the accumulator init arithmetically from x so it inherits
     # x's varying-axis type under shard_map (the trainer's data/seq
     # axes) — a literal zeros array would be typed unvarying and fail
     # the scan's carry check (same pattern as ring_attention.py).
     zero_rows = jnp.sum(x * 0.0, axis=-1).astype(jnp.float32)
-    init = (zero_rows + NEG_INF, zero_rows)
-    (m, s), _ = lax.scan(
+    init = (zero_rows + NEG_INF, zero_rows, zero_rows)
+    (m, s, target_logit), _ = lax.scan(
         fold, init, (jnp.arange(chunks.shape[0]), chunks)
     )
     lse = m + jnp.log(jnp.maximum(s, 1e-30))
-    target_logit = jnp.einsum(
-        "td,td->t", x, embedding[targets],
-        preferred_element_type=jnp.float32,
-    )
     return lse - target_logit, lse
 
 
@@ -130,21 +140,24 @@ def _xent_vjp_fwd(x, embedding, targets, chunk_size):
 
 
 def _xent_vjp_bwd(chunk_size, residuals, g):
-    """dL/dx = diag(g) (P @ E - E[targets]);  dL/dE = P^T diag(g) x
-    minus the scatter of g x onto target rows — all accumulated
-    chunkwise from recomputed probabilities P_c = exp(x E_c^T - lse).
+    """dL/dx = diag(g) (P - onehot) @ E;  dL/dE = (P - onehot)^T
+    diag(g) x — all accumulated chunkwise from recomputed
+    probabilities P_c = exp(x E_c^T - lse), the one-hot of the targets
+    taken off inside the chunk that holds each.
     """
     x, embedding, targets, lse = residuals
     tokens, d = x.shape
     vocab = embedding.shape[0]
     g32 = g.astype(jnp.float32)
-    # Same mixed-precision policy as forward: operands keep their
-    # input dtype, dots accumulate in f32.
+    # Same mixed-precision policy as forward: operands in x's dtype,
+    # dots accumulate in f32; dE leaves in the TABLE's dtype (float32
+    # for a float32 table: the gradient is not rounded on the way).
     chunks, pad = _pad_chunks(embedding, chunk_size)
     chunk_size = chunks.shape[1]
 
     def chunk_grads(dx_acc, inp):
         idx, e_chunk = inp
+        e_chunk = e_chunk.astype(x.dtype)
         logits = jnp.einsum(
             "td,kd->tk", x, e_chunk,
             preferred_element_type=jnp.float32,
@@ -153,7 +166,9 @@ def _xent_vjp_bwd(chunk_size, residuals, g):
             _chunk_mask(idx, chunk_size, vocab, tokens), logits, NEG_INF
         )
         p = jnp.exp(logits - lse[:, None])  # [tokens, chunk] f32
-        gp = g32[:, None] * p
+        gp = g32[:, None] * (
+            p - _is_target(idx, chunk_size, targets).astype(jnp.float32)
+        )
         dx_acc = dx_acc + jnp.einsum(
             "tk,kd->td", gp, e_chunk,
             preferred_element_type=jnp.float32,
@@ -173,9 +188,6 @@ def _xent_vjp_bwd(chunk_size, residuals, g):
     de = de_chunks.reshape(-1, d)
     if pad:
         de = de[:vocab]
-    # The -1 of (p - onehot) on the target columns.
-    dx = dx - g32[:, None] * embedding[targets].astype(jnp.float32)
-    de = de.at[targets].add(-g32[:, None] * x.astype(jnp.float32))
     return dx.astype(x.dtype), de.astype(embedding.dtype), None
 
 

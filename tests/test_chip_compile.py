@@ -69,6 +69,7 @@ FLAGSHIP = (8, 12, 512, 64)  # examples/transformer_lm.py at batch 8
 LONG = (4, 8, 2048, 64)
 CELL = (16, 12, 1024, 64)  # the benchmark's gpt2-124m micro-batch
 HEAD_128 = (2, 8, 4096, 128)
+LOOPED_CELL = (1, 16, 8192, 128)  # ouro-2.6b-steady's micro-batch
 K_BLOCKED = (1, 2, 32768, 128)  # K and V of a head past the VMEM budget
 
 
@@ -96,6 +97,8 @@ def _attend_loss(q, k, v):
         ("shard_map_grad", CELL, 4),
         ("fwd", HEAD_128, 0),
         ("grad", HEAD_128, 0),
+        ("fwd", LOOPED_CELL, 0),
+        ("grad", LOOPED_CELL, 0),
         ("fwd", K_BLOCKED, 0),
         ("grad", K_BLOCKED, 0),
     ],

@@ -61,6 +61,25 @@ def test_moe_blocks_cost_topk_experts():
     assert moe.total - dense.total == pytest.approx(expected_extra)
 
 
+@pytest.mark.parametrize("passes", [1, 4])
+def test_a_looped_model_counts_blocks_and_head_once_a_pass(passes):
+    """``loop_passes`` applications of every block and one head an
+    exit; a SwiGLU FFN is three matmuls."""
+    base = dict(
+        vocab_size=1000, num_layers=3, num_heads=4, d_model=64,
+        d_ff=256, max_seq_len=128, ffn="swiglu",
+    )
+    once = transformer_train_flops(TransformerConfig(**base), 2, 128)
+    tokens = 2 * 128
+    per_token = 3 * (2 * 4 * 64 * 64 + 2 * 3 * 64 * 256) + 2 * 64 * 1000
+    assert once.matmul == pytest.approx(3 * tokens * per_token)
+    looped = transformer_train_flops(
+        TransformerConfig(**base, loop_passes=passes), 2, 128
+    )
+    assert looped.matmul == pytest.approx(passes * once.matmul)
+    assert looped.attention == pytest.approx(passes * once.attention)
+
+
 def test_mfu_uses_peak_and_devices():
     value = mfu(
         flops_per_step=100e12, step_time_s=1.0,
