@@ -213,6 +213,10 @@ class Layout:
     # position (the handoff's default shard plan reads row spans off
     # the run spec tree).
     canonical_is_stored = True
+    # Whether :meth:`reduce` all-reduces the gradient over the data
+    # axis: what the backward of a step's last micro-batch can run
+    # beside (``ElasticTrainer._reduce_has_tail``).
+    reduces_gradient = True
 
     def __init__(
         self,
@@ -478,8 +482,11 @@ class Layout:
         )
 
     def reduce(self, grad_sum, lsqr_sum, loss_sum, num_micro: int):
-        """The gradient all-reduce: one fused pmean over ICI/DCN, with
-        the GNS scalars riding alongside. Pipeline stages do NOT
+        """The gradient all-reduce: a ``pmean`` over the data axis (ICI
+        or DCN), one all-reduce a leaf as lowered — the compiler's
+        combiner merges them into a few, except under
+        ``trainer.REDUCE_OVERLAP_OPTIONS`` — with the GNS scalars
+        riding alongside. Pipeline stages do NOT
         average gradients — each stage owns its parameter shard — but
         the gradient-norm statistics sum across the shards."""
         grads_local = jax.tree.map(lambda g: g / num_micro, grad_sum)
@@ -965,6 +972,7 @@ class Zero3Blocks(_DataSharded):
     flat [n] vector."""
 
     name = "zero3-blocks"
+    reduces_gradient = False  # (the reduce-scatter inside AD, above)
     mirror_specs = {"blocks": P(None, DATA_AXIS), "other": P(DATA_AXIS)}
     _composes = (
         "zero3_blocks shards parameter storage over the "

@@ -662,6 +662,141 @@ def test_routed_cells_steps_are_the_programs_before_the_ladder(
     assert texts[0] == texts[1]
 
 
+def test_the_tpu_compiler_has_the_reduce_overlap_options(v5e, chip_compile):
+    """The three (internal) names a step of several replicas is
+    compiled under: this jaxlib's TPU compiler takes each of them, as
+    it refuses a name it does not have. Were one dropped or renamed,
+    every such step would fail to compile, jitted and AOT alike."""
+    from adaptdl_tpu.trainer import REDUCE_OVERLAP_OPTIONS
+
+    mesh = Mesh(np.array(v5e.devices), ("data",))
+    mean = jax.shard_map(
+        lambda x: jax.lax.pmean(x, "data"),
+        mesh=mesh, in_specs=P("data"), out_specs=P(),
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 128), jnp.float32, sharding=NamedSharding(mesh, P("data"))
+    )
+    assert len(REDUCE_OVERLAP_OPTIONS) == 3
+    for name, value in REDUCE_OVERLAP_OPTIONS.items():
+        jax.jit(mean, compiler_options={name: value}).lower(x).compile()
+        with pytest.raises(Exception, match="No such compile option"):
+            jax.jit(
+                mean, compiler_options={name + "_": value}
+            ).lower(x).compile()
+
+
+def test_dp4_step_reduces_under_the_last_backward_on_v5e(
+    v5e, chip_compile, monkeypatch, tmp_path
+):
+    """``gpt2-124m-dp4``'s step at real size on the described v5e:2x2
+    with the chip's memory limit: the last micro-batch stands behind
+    the accumulation loop and the model is traced ONCE for both (scan
+    keeps its body's trace by the function); the compiled program
+    holds both micro-batches' backwards in the entry computation (a loop of one
+    trip is inlined), one after the other (no more memory than the
+    all-in-scan step's), and the leaves' all-reduces run under the
+    weight-gradient products the scheduler left for the end — the
+    compiler writes such a pair as an ``async-collective-start`` /
+    ``-done`` fusion around the op it runs under — the first of them
+    before the last micro-batch's backward has ended."""
+    from adaptdl_tpu import trace
+    from adaptdl_tpu import trainer as trainer_mod
+    from tools import compile_step_v5e as rehearsal
+
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    rehearsal.inject_limit(rehearsal.BYTES_LIMIT, monkeypatch.setattr)
+    calls = []
+
+    def counting(loss_fn, differentiate=trainer_mod._value_and_grad):
+        def counted(*args):
+            calls.append(1)
+            return loss_fn(*args)
+
+        return differentiate(counted)
+
+    monkeypatch.setattr(trainer_mod, "_value_and_grad", counting)
+    lower, facts = rehearsal.step_program(
+        "gpt2-124m-dp4", bytes_limit=rehearsal.BYTES_LIMIT, topo=v5e
+    )
+    assert facts["chips"] == 4 and not facts["donated"]
+    lowered = lower()
+    assert len(calls) == 1
+    (event,) = [
+        r["attrs"] for r in trace.snapshot_spans()
+        if r["name"] == "step.reduce_overlap"
+    ]
+    assert event == {
+        "replicas": 4, "num_micro": 2, "scanned": 1, "tail": True,
+        "groups": 74,
+    }
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # (The all-in-scan step: 7.48 GiB of temporaries; both
+    # micro-batches' activations alive at once: 10.24.)
+    assert mem.temp_size_in_bytes < 8 * 2**30
+    lines = rehearsal.entry_computation(compiled.as_text()).split("\n")
+    backward = [
+        i for i, line in enumerate(lines)
+        if re.match(r"\s*%flash_bwd[.\d]* = ", line)
+    ]
+    started = [
+        i for i, line in enumerate(lines)
+        if re.match(rehearsal.ASYNC_START, line)
+    ]
+    assert len(backward) == 24
+    # (48 matrices a step; the 25 LayerNorm vectors, 3 KB each, and
+    # the tied table, whose gradient ends with the backward, stay
+    # synchronous.)
+    assert len(started) >= 40
+    assert started[0] < backward[-1]
+    for start in started:
+        under = [
+            line for line in lines[start:start + 120]
+            if "calls=%async_collective_fusion" in line
+        ]
+        assert under and "dot_general" in under[0]
+
+
+def test_one_chip_step_is_the_program_with_no_tail(
+    v5e, chip_compile, monkeypatch, tmp_path
+):
+    """``gpt2-124m-steady``'s step on one described chip: one replica
+    has no reduce to overlap, and the program the trainer lowers is,
+    text for text, the one lowered with the tail (and with it its
+    compiler options) switched off in the trainer."""
+    from adaptdl_tpu import trace
+    from adaptdl_tpu import trainer as trainer_mod
+    from tools import compile_step_v5e as rehearsal
+
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    rehearsal.inject_limit(rehearsal.BYTES_LIMIT, monkeypatch.setattr)
+
+    def lowered():
+        lower, facts = rehearsal.step_program(
+            "gpt2-124m-steady", bytes_limit=rehearsal.BYTES_LIMIT, topo=v5e
+        )
+        assert facts["chips"] == 1
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", lower().as_text())
+
+    def no_tail():
+        monkeypatch.setattr(
+            trainer_mod.ElasticTrainer, "_reduce_has_tail",
+            lambda self: False,
+        )
+
+    texts = []
+    for prepare in (lambda: None, no_tail):
+        prepare()
+        texts.append(lowered())
+    assert texts[0] == texts[1]
+    events = [
+        r["attrs"] for r in trace.snapshot_spans()
+        if r["name"] == "step.reduce_overlap"
+    ]
+    assert [e["tail"] for e in events[:1]] == [False]
+
+
 def test_chip_smoke_incarnations_on_cpu(tmp_path, monkeypatch):
     """The smoke's two incarnations (children of this process, which
     holds no chip) with a tiny ``TransformerConfig`` and the expected

@@ -87,7 +87,7 @@ def _rows_loss(spec):
     return loss
 
 
-def _trainer(layout, dp):
+def _trainer(layout, dp, wrap=lambda loss: loss):
     params = _params()
     loss = (
         _rows_loss(z3.block_spec(params, "blocks"))
@@ -95,7 +95,7 @@ def _trainer(layout, dp):
         else _dense_loss
     )
     return ElasticTrainer(
-        loss, params, optax.adamw(1e-2), ROWS,
+        wrap(loss), params, optax.adamw(1e-2), ROWS,
         scaling_rule=AdamScale(), precondition="adam",
         mesh=create_mesh({"data": dp}, devices=jax.devices()[:dp]),
         **LAYOUTS[layout],

@@ -280,3 +280,169 @@ def test_single_group_checkpoint_restores_into_grouped_trainer(
     np.testing.assert_allclose(fixed.sqr_biased, [0.5] * 3)
     with pytest.raises(ValueError):
         gns_mod.normalize_groups(fixed, 2)
+
+
+# ---- the last micro-batch outside the accumulation scan (PR 43) -------
+
+
+TRACED = []  # one entry a trace of a ``_counting`` loss
+
+
+def _counting(loss):
+    """``loss`` scaled by a draw from the micro-batch's rng, with the
+    draw and the rows seen among its counters."""
+
+    def counted(params, batch, rng):
+        TRACED.append(1)
+        draw = jax.random.uniform(rng)
+        seen = {"rows": jnp.float32(len(batch["x"])), "draw": draw}
+        return loss(params, batch, rng) * (1 + draw), {"test.seen": seen}
+
+    counted.has_counters = True
+    return counted
+
+
+def _two_steps(layout, dp, accum_steps, lower_only=False):
+    from tests import test_storage as cases
+
+    trainer = cases._trainer(layout, dp, wrap=_counting)
+    rng = np.random.default_rng(1)
+    batch = trainer.shard_batch({
+        "x": rng.normal(size=(cases.ROWS, cases.D)).astype(np.float32),
+        "y": rng.normal(size=(cases.ROWS, 7)).astype(np.float32),
+    })
+    state = trainer.init_state(cases._params())
+    step = trainer.train_step(
+        cases.ROWS // dp // (accum_steps + 1), accum_steps
+    )
+    if lower_only:
+        return step._jitted.lower(state, batch, ()).as_text()
+    for _ in range(2):
+        state, metrics = step(state, batch)
+    return cases._stored(state), metrics
+
+
+def _assert_same_bits(tailed, in_scan):
+    assert jax.tree.structure(tailed) == jax.tree.structure(in_scan)
+    for x, y in zip(jax.tree.leaves(tailed), jax.tree.leaves(in_scan)):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+
+
+@pytest.mark.parametrize("accum_steps", [0, 1, 3])
+@pytest.mark.parametrize(
+    "layout", ["replicated", "zero1", "zero3-lite", "zero3-blocks"]
+)
+def test_last_micro_batch_outside_the_scan_is_the_scans_step(
+    layout, accum_steps, monkeypatch
+):
+    """With more than one data replica the step's last micro-batch
+    runs behind the accumulation scan, not in it (so that the reduce
+    of a leaf waits for that leaf alone), the model traced once for
+    both (``TRACED``). Against the all-in-scan step: the state after
+    two steps, the metrics and the counters (sums of draws from the
+    micro-batches' rngs) to the bit. zero3-blocks reduces its rows
+    inside AD and keeps the all-in-scan step."""
+    from adaptdl_tpu import trainer as trainer_mod
+
+    events = []
+    monkeypatch.setattr(
+        trainer_mod.trace, "event",
+        lambda name, **attrs: events.append((name, attrs)),
+    )
+    del TRACED[:]
+    tailed = _two_steps(layout, 4, accum_steps)
+    assert len(TRACED) == 1
+    has_tail = layout != "zero3-blocks"
+    assert [a for n, a in events if n == "step.reduce_overlap"] == [{
+        "replicas": 4, "num_micro": accum_steps + 1,
+        "scanned": accum_steps + (not has_tail), "tail": has_tail,
+        "groups": 7 if has_tail else 0,  # one all-reduce a leaf
+    }]
+    monkeypatch.setattr(
+        ElasticTrainer, "_reduce_has_tail", lambda self: False
+    )
+    in_scan = _two_steps(layout, 4, accum_steps)
+    assert events[-1][1]["tail"] is False
+    _assert_same_bits(tailed, in_scan)
+    seen = tailed[1]["counters"]["test.seen"]
+    assert float(seen["rows"]) == 16.0
+    assert float(seen["draw"]) not in (0.0, float(seen["rows"]))
+
+
+def test_another_order_of_the_sums_is_not_the_scans_step(monkeypatch):
+    """What the comparison above can see: the three micro-batches in
+    the loop summed last first — every sum within 2e-5 of the all-in-
+    scan step's — are not the same bits."""
+    scan = jax.lax.scan
+
+    def last_first(body, init, xs, **kwargs):
+        return scan(
+            body, init, xs, reverse=body.__name__ == "micro_step", **kwargs
+        )
+
+    with monkeypatch.context() as patched:
+        patched.setattr(jax.lax, "scan", last_first)
+        faulty = _two_steps("replicated", 4, 3)
+    monkeypatch.setattr(
+        ElasticTrainer, "_reduce_has_tail", lambda self: False
+    )
+    in_scan = _two_steps("replicated", 4, 3)
+    for x, y in zip(jax.tree.leaves(faulty), jax.tree.leaves(in_scan)):
+        np.testing.assert_allclose(x, y, rtol=2e-5, atol=1e-7)
+    with pytest.raises(AssertionError):
+        _assert_same_bits(faulty, in_scan)
+
+
+def test_only_plain_data_parallel_jobs_get_the_tail():
+    """The tail (and the compiler options that go with it) where it
+    was measured: the data axis the mesh's only one, more than one
+    replica, a layout that all-reduces the gradient."""
+    from tests import test_storage as cases
+
+    def has_tail(axes, layout="replicated"):
+        size = int(np.prod(list(axes.values())))
+        return _make_trainer(
+            size, mesh=create_mesh(axes, devices=jax.devices()[:size]),
+            **cases.LAYOUTS[layout],
+        )._reduce_has_tail()
+
+    assert has_tail({"data": 4})
+    assert has_tail({"data": 2}, "zero1")
+    assert not has_tail({"data": 1})
+    assert not has_tail({"data": 2, "seq": 2})
+    assert not has_tail({"data": 2, "model": 2})
+    assert not has_tail({"data": 2, "stage": 2})
+    assert _make_trainer(4)._reduce_overlap_options() is None  # no TPU
+
+
+def test_one_replica_keeps_the_all_in_scan_step(monkeypatch):
+    """One replica has nothing to overlap: its step program is the
+    all-in-scan one, text for text — the program four replicas lower
+    with the tail switched off is the form compared with."""
+    from adaptdl_tpu import trainer as trainer_mod
+
+    events = []
+    monkeypatch.setattr(
+        trainer_mod.trace, "event",
+        lambda name, **attrs: events.append((name, attrs)),
+    )
+    shipped = _two_steps("replicated", 1, 1, lower_only=True)
+    assert events[-1] == ("step.reduce_overlap", {
+        "replicas": 1, "num_micro": 2, "scanned": 2, "tail": False,
+        "groups": 7,
+    })
+    tailed = _two_steps("replicated", 4, 1, lower_only=True)
+    monkeypatch.setattr(
+        ElasticTrainer, "_reduce_has_tail", lambda self: False
+    )
+    assert _two_steps("replicated", 1, 1, lower_only=True) == shipped
+    in_scan = _two_steps("replicated", 4, 1, lower_only=True)
+    # (The accumulation is one ``while`` more where the last
+    # micro-batch has a scan of its own; the model's are the rest.)
+    whiles = [
+        text.count("stablehlo.while") for text in (shipped, in_scan, tailed)
+    ]
+    assert whiles[0] == whiles[1] == whiles[2] - 1
+    assert "optimization_barrier" in tailed
+    assert "optimization_barrier" not in shipped + in_scan

@@ -34,6 +34,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 BYTES_LIMIT = int(15.75 * 2**30)
+# How the TPU compiler writes an all-reduce that runs beside the op
+# scheduled next to it: three fusions, ``%async-collective-start`` (it
+# holds the all-reduce), the op it runs under (``calls=
+# %async_collective_fusion``) and ``%async-collective-done``.
+ASYNC_START = r"^\s*%async-collective-start[.\d]* = "
+
+
+def entry_computation(text: str) -> str:
+    """The instructions of a compiled module's entry computation, in
+    the order they are scheduled."""
+    return re.search(
+        r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.M | re.S
+    ).group(1)
 
 
 def inject_limit(bytes_limit, set_attribute=setattr):
@@ -115,7 +128,13 @@ def step_program(cell_name, chips=None, atomic=None, accum=None,
     donated = (
         bytes_limit is not None and 2 * trainer._held_bytes > bytes_limit
     )
-    jitted = jax.jit(sharded, donate_argnums=0) if donated else jax.jit(sharded)
+    # (With the compiler options ``_finalize_step`` gives both of the
+    # pair: what a job of several replicas compiles its reduce under.)
+    options = trainer._reduce_overlap_options()
+    jitted = jax.jit(
+        sharded, donate_argnums=0 if donated else (),
+        compiler_options=options,
+    )
 
     def lower():
         with device_budget.tracing_with(
@@ -161,10 +180,12 @@ def main() -> None:
         t0 = time.monotonic()
         lowered = lower()
         lower_s = time.monotonic() - t0
-    policy = [
-        r["attrs"] for r in trace.snapshot_spans()
-        if r["name"] == "remat.policy"
-    ][-1]
+    policy, overlap = (
+        [
+            r["attrs"] for r in trace.snapshot_spans() if r["name"] == name
+        ][-1]
+        for name in ("remat.policy", "step.reduce_overlap")
+    )
     print(
         f"{args.cell} chips={facts['chips']} step "
         f"({facts['geometry']['atomic_bsz']}, "
@@ -174,7 +195,8 @@ def main() -> None:
         f"trace + lower {lower_s:.1f}s; remat.policy rungs="
         f"{policy['rungs']!r} rung_bytes={policy['rung_bytes'] / gib:.3f} "
         f"GiB budget_bytes={policy['budget_bytes'] / gib:.3f} GiB "
-        f"bytes_limit={policy['bytes_limit']}",
+        f"bytes_limit={policy['bytes_limit']}; step.reduce_overlap "
+        + " ".join(f"{k}={v}" for k, v in overlap.items()),
         flush=True,
     )
     if args.lower_only:
@@ -187,6 +209,7 @@ def main() -> None:
             mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes
         )
+        entry = entry_computation(text)
         qkv = re.findall(
             r"^\s*%[\w.\-]+ = bf16\[3,[\d,]+\]\S* fusion\(", text, re.M
         )
@@ -201,7 +224,9 @@ def main() -> None:
             f"%attention x{text.count(' %attention')} "
             f"%flash_bwd x{text.count(' %flash_bwd')} "
             f"%moe_gmm x{text.count(' %moe_gmm')} "
-            f"all-reduce={'all-reduce' in text}",
+            f"all-reduce: synchronous x{entry.count(' all-reduce(')}, "
+            f"under another op (async collective fusions) x"
+            f"{len(re.findall(ASYNC_START, entry, re.M))}",
             flush=True,
         )
     if args.text:
