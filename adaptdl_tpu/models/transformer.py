@@ -1224,14 +1224,16 @@ def looped_lm_loss_fn(
     from the cross-entropies on in float32. batch = {"inputs",
     "targets"}, each [b, s] int32.
 
-    The exits' heads, softmax and per-token losses stream the table
-    ONCE, in chunks of ``chunk_size`` columns against the rows of all
-    exits together (``ops.chunked_xent``: compute dtype operands,
-    float32 accumulation, a chunk's logits recomputed in the
-    backward): between forward and backward an exit keeps its state
-    and two ``[tokens]`` vectors, nothing ``[tokens, vocab]``-sized
-    ever exists, and the table's gradient is one contraction over
-    every exit's rows, not a sum of one a exit.
+    The exits' heads, softmax and per-token losses stream the rows of
+    all exits together, ``chunk_size`` rows at a time against the whole
+    table (``ops.chunked_xent.weighted_xent_sum``: compute dtype
+    operands, float32 accumulation). A row's weight in the loss,
+    ``p_t / tokens``, comes from the gate and not from the head, so
+    the head forms ``dx`` and the table's gradient in the forward
+    pass, three products a chunk and none repeated; the gate's
+    gradient flows through the weights. Nothing ``[tokens,
+    vocab]``-sized ever exists, and the table's gradient is one
+    accumulator over every exit's rows, not a sum of one an exit.
 
     Returns ``(loss, {"loop.exit": ...})`` (``has_counters``, as
     ``routed_lm_loss_fn``): per exit the mean cross-entropy ``xent``
@@ -1239,14 +1241,18 @@ def looped_lm_loss_fn(
     ``expected_exit = sum_t t p_t`` (1 .. passes), each summed over
     the step's ``micro_batches``. Journals one ``loop.schedule`` event
     each time it is traced."""
-    from adaptdl_tpu.ops.chunked_xent import chunked_softmax_xent
+    from adaptdl_tpu.ops.chunked_xent import (
+        rows_per_chunk,
+        weighted_xent_sum,
+    )
 
     cfg = model.config
     passes = cfg.loop_passes
     assert passes > 1, "looped_lm_loss_fn needs loop_passes > 1"
-    chunk = min(chunk_size, cfg.vocab_size)
 
     def loss_fn(params, batch, rng):
+        tokens = batch["targets"].size
+        rows = passes * tokens
         trace.event(
             "loop.schedule",
             passes=passes,
@@ -1254,8 +1260,8 @@ def looped_lm_loss_fn(
             applications=passes * cfg.num_layers,
             how="scan",
             exits=passes,
-            head=f"chunked_xent, {chunk} of {cfg.vocab_size} columns "
-            "a chunk, the rows of all exits at once",
+            head=f"xent_sum, {rows_per_chunk(rows, chunk_size)} of {rows} "
+            "rows a chunk, gradients in the forward",
         )
         states, gate = model.apply(
             {"params": params}, batch["inputs"], train=True, rng=rng,
@@ -1265,14 +1271,16 @@ def looped_lm_loss_fn(
             params["embed"]["embedding"] if cfg.tie_embeddings
             else params["lm_head"]
         )
-        xent = chunked_softmax_xent(
-            states.reshape(-1, cfg.d_model), table,
-            jnp.tile(batch["targets"].reshape(-1), passes), chunk,
-        ).reshape(passes, -1)
         log_p = exit_log_probs(gate.reshape(passes, -1))
         p = jnp.exp(log_p)
         entropy = -jnp.sum(p * log_p, axis=0)
-        loss = jnp.mean(jnp.sum(p * xent, axis=0) - beta * entropy)
+        expected_xent, xent = weighted_xent_sum(
+            states.reshape(-1, cfg.d_model), table,
+            jnp.tile(batch["targets"].reshape(-1), passes),
+            p.reshape(-1) / tokens, chunk_size,
+        )
+        xent = xent.reshape(passes, -1)
+        loss = expected_xent - beta * jnp.mean(entropy)
         exit_at = jnp.arange(1, passes + 1, dtype=jnp.float32)
         return loss, {
             "loop.exit": {

@@ -307,7 +307,7 @@ def _bench_transformer_tokens(on_tpu: bool, full: bool) -> dict | None:
 
     bsz = 8
     out = {}
-    # Chunked-head arm FIRST (TPU full mode only): the vocab-streaming
+    # Chunked-head arm FIRST (TPU full mode only): the row-streaming
     # loss (ops/chunked_xent.py) removes the [tokens, vocab] logits
     # buffer. peak_bytes_in_use is a cumulative process-wide
     # high-water mark, so the smaller arm must run before the dense
@@ -327,7 +327,7 @@ def _bench_transformer_tokens(on_tpu: bool, full: bool) -> dict | None:
                 flat,
                 p["embed"]["embedding"],
                 batch["targets"].reshape(-1),
-                4096,
+                1024,  # rows a chunk: the live logits are 1/8 of dense
             ).mean()
 
         try:

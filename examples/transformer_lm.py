@@ -75,14 +75,16 @@ def flash_attention_fn(seq_len: int):
 
 def dense_lm_loss(model, chunked_xent: int = 0):
     """Next-token loss over ``{"inputs", "targets"}`` batches, plus the
-    MoE aux loss; ``chunked_xent`` > 0 streams the output head in
-    vocab chunks of that size (ops/chunked_xent.py)."""
+    MoE aux loss; ``chunked_xent`` > 0 streams the output head that
+    many rows at a time (ops/chunked_xent.py)."""
     import optax
 
     from adaptdl_tpu.models.transformer import apply_with_moe_aux
 
     if chunked_xent > 0:
-        from adaptdl_tpu.ops.chunked_xent import chunked_softmax_xent
+        import jax.numpy as jnp
+
+        from adaptdl_tpu.ops.chunked_xent import weighted_xent_sum
 
         def loss_fn(params, batch, rng):
             hidden, aux = apply_with_moe_aux(
@@ -90,13 +92,14 @@ def dense_lm_loss(model, chunked_xent: int = 0):
                 return_hidden=True,
             )
             flat = hidden.reshape(-1, hidden.shape[-1])
-            losses = chunked_softmax_xent(
+            mean, _ = weighted_xent_sum(
                 flat,
                 params["embed"]["embedding"],
                 batch["targets"].reshape(-1),
+                jnp.full(flat.shape[:1], 1.0 / flat.shape[0], jnp.float32),
                 chunked_xent,
             )
-            return losses.mean() + aux
+            return mean + aux
 
     else:
 
@@ -178,7 +181,7 @@ def main():
     # blocked softmax.
     parser.add_argument("--flash", action="store_true")
     parser.add_argument("--seq-len", type=int, default=None)
-    # Stream the output head in vocab chunks of this size instead of
+    # Stream the output head this many rows at a time instead of
     # materializing [tokens, vocab] logits (ops/chunked_xent.py) —
     # the HBM saving buys batch size at large vocab. 0 = dense head.
     parser.add_argument("--chunked-xent", type=int, default=0)

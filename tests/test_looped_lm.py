@@ -510,8 +510,8 @@ def test_run_step_journals_the_loop_and_restores_exactly(
     assert schedule and schedule[-1] == {
         "passes": 4, "blocks": 2, "applications": 8, "how": "scan",
         "exits": 4,
-        "head": "chunked_xent, 32 of 97 columns a chunk, the rows of all "
-        "exits at once",
+        "head": "xent_sum, 32 of 128 rows a chunk, gradients in the "
+        "forward",
     }
     policy = [r["attrs"] for r in new if r["name"] == "remat.policy"]
     assert policy and policy[-1]["blocks"] == 8
