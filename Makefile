@@ -3,7 +3,7 @@
 PY ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: test test-fast bench bench-quick dryrun examples lint graftcheck chaos chaos-sched chaos-preempt guardgate trace-gate rescale-fast meshgate simgate watchgate warmgate shardgate bench-sched
+.PHONY: test test-fast dryrun examples lint graftcheck chaos chaos-sched chaos-preempt guardgate trace-gate rescale-fast meshgate simgate watchgate warmgate shardgate bench-sched
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -11,12 +11,6 @@ test:
 test-fast:
 	$(PY) -m pytest tests/ -x -q --deselect tests/test_local_runner.py \
 	    --deselect tests/test_multi_runner.py
-
-bench:
-	$(PY) bench.py
-
-bench-quick:
-	$(CPU_ENV) $(PY) -c "import jax; jax.config.update('jax_platforms','cpu'); import bench; bench.main(quick=True)"
 
 dryrun:
 	$(CPU_ENV) $(PY) -c "import jax; jax.config.update('jax_platforms','cpu'); import __graft_entry__ as g; g.dryrun_multichip(8)"
@@ -33,7 +27,7 @@ examples:
 # available as `adaptdl-tpu check`. The baseline must stay EMPTY:
 # findings get fixed, not deferred.
 lint:
-	$(PY) -m compileall -q adaptdl_tpu examples tutorial tests bench.py __graft_entry__.py tools
+	$(PY) -m compileall -q adaptdl_tpu examples tutorial tests __graft_entry__.py tools
 	$(PY) -m tools.graftcheck --fast adaptdl_tpu
 	$(PY) -c "import json,sys; b=json.load(open('graftcheck_baseline.json')); sys.exit('graftcheck_baseline.json must stay empty: fix findings instead of baselining them' if b.get('findings') else 0)"
 
@@ -96,7 +90,6 @@ trace-gate:
 # — and every delta-chain / fallback correctness property must hold.
 rescale-fast:
 	$(CPU_ENV) $(PY) -m pytest tests/test_delta_handoff.py \
-	    tests/test_bench.py::test_rescale_breakdown_sums_consistently \
 	    -q --durations=5
 
 # Mesh-shape elasticity gate (docs/checkpointing.md "Reshard-aware
@@ -158,8 +151,7 @@ shardgate:
 	$(CPU_ENV) ADAPTDL_FAULT_SEED=1234 $(PY) -m pytest \
 	    tests/test_chaos_shard.py -q --durations=10
 
-# Thousand-job control-plane bench standalone (bench.py also merges
-# these keys into the BENCH json): allocator decide p50/p99 at 1k
+# Thousand-job control-plane bench: allocator decide p50/p99 at 1k
 # jobs / 10k slots + supervisor per-endpoint p99s under load.
 bench-sched:
 	$(CPU_ENV) $(PY) bench_sched.py

@@ -3,8 +3,8 @@
 The XLA persistent compilation cache (bootstrap._enable_compilation_
 cache) only caches the *backend compile*; a restarted incarnation
 still pays Python tracing + jaxpr lowering for every train-step
-configuration before its first step — which bench.py measures as the
-dominant term of the rescale critical path. This module caches the
+configuration before its first step — the dominant term of a set-up
+(``trace_lower_s``, PERF.md section 3). This module caches the
 step at the level above: the fully compiled executable, serialized
 with ``jax.experimental.serialize_executable``, keyed by a
 fingerprint of everything that determines the program. A restarted

@@ -1512,7 +1512,7 @@ def main(argv=None) -> int:
         default=None,
         dest="fence_s",
         help="per-tenant write-fence budget in seconds (apply; "
-        "default ADAPTDL_RESHARD_FENCE_S)",
+        "default 5)",
     )
     p.set_defaults(fn=_cmd_reshard)
 

@@ -224,41 +224,8 @@ def handoff_enabled() -> bool:
 def handoff_url() -> str | None:
     """Explicit base URL of a predecessor's handoff shard server (the
     successor's discovery normally goes descriptor-file → supervisor;
-    this override short-circuits both — tests, bench, single-box)."""
+    this override short-circuits both — tests, single-box)."""
     return _get_str("ADAPTDL_HANDOFF_URL")
-
-
-def handoff_ttl_s() -> float:
-    """Seconds the spawned handoff shard server lingers waiting for
-    the successor before giving up and exiting (the durable checkpoint
-    then serves the restore, exactly as if no handoff existed)."""
-    return max(_get_float("ADAPTDL_HANDOFF_TTL_S", 60.0), 1.0)
-
-
-def handoff_timeout_s() -> float:
-    """Overall deadline for the successor's handoff fetch (manifest +
-    chunks); past it the restore falls back to the durable checkpoint
-    rather than stall the restart on a dead or slow peer."""
-    return max(_get_float("ADAPTDL_HANDOFF_TIMEOUT_S", 10.0), 0.1)
-
-
-def handoff_parts() -> int:
-    """Row parts each large leaf chunk is range-addressable in on the
-    handoff shard server (``GET /chunk/{state}/{leaf}@p{i}``): a
-    resharding successor pulls only the parts covering ITS shard-map
-    slice of each leaf instead of bulk-fetching full leaves. 1
-    disables range addressing (every pull is whole-leaf, the pre-mesh
-    behavior); higher values tighten the pulled-bytes bound toward
-    the successor's exact shard fraction at a per-part request cost."""
-    return max(_get_int("ADAPTDL_HANDOFF_PARTS", 8), 1)
-
-
-def handoff_part_min_bytes() -> int:
-    """Leaf chunks smaller than this are never split into range
-    parts — per-part HTTP round-trips would cost more than the bytes
-    they save. Tests lower it to exercise the range path on tiny
-    states."""
-    return max(_get_int("ADAPTDL_HANDOFF_PART_MIN_BYTES", 65536), 0)
 
 
 def handoff_diff_enabled() -> bool:
@@ -318,14 +285,6 @@ def warmup_cutover_file() -> str | None:
     return _get_str("ADAPTDL_WARMUP_CUTOVER_FILE")
 
 
-def warmup_deadline_s() -> float:
-    """Longest the runner waits for a warm successor to mark itself
-    ready before discarding it and rescaling cold — warm-up must never
-    delay a rescale by more than it saves. Also bounds how long a held
-    successor waits for the cutover file before exiting."""
-    return max(_get_float("ADAPTDL_WARMUP_DEADLINE_S", 20.0), 0.1)
-
-
 def supervisor_url() -> str | None:
     """Base URL of the cluster supervisor (rendezvous + sched hints)."""
     return _get_str("ADAPTDL_SUPERVISOR_URL")
@@ -334,11 +293,6 @@ def supervisor_url() -> str | None:
 def coordinator_addr() -> str | None:
     """``host:port`` for ``jax.distributed.initialize`` on multi-host."""
     return _get_str("ADAPTDL_COORDINATOR_ADDR")
-
-
-def sched_version() -> str | None:
-    """Scheduler semver, for trainer/scheduler compatibility checks."""
-    return _get_str("ADAPTDL_SCHED_VERSION")
 
 
 def num_replicas_is_set() -> bool:
@@ -381,9 +335,8 @@ def compile_cache_knob() -> str:
 def checkout_root() -> str:
     """Directory holding the ``adaptdl_tpu`` package: the last-resort
     home of the compile cache (``<root>/.jax_compile_cache``), and what
-    ``chip_smoke.py`` / ``bench.py`` name through
-    ``ADAPTDL_COMPILE_CACHE`` so their throw-away checkpoint
-    directories never become the cache path."""
+    ``chip_smoke.py`` names through ``ADAPTDL_COMPILE_CACHE`` so its
+    throw-away checkpoint directories never become the cache path."""
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -404,12 +357,6 @@ def trace_dir() -> str | None:
     return _get_str("ADAPTDL_TRACE_DIR")
 
 
-def trace_buffer_size() -> int:
-    """Bounded capacity of the in-memory span ring buffer (oldest
-    spans are evicted first; the buffer can never grow past this)."""
-    return max(_get_int("ADAPTDL_TRACE_BUFFER", 4096), 1)
-
-
 def traceparent() -> str | None:
     """W3C ``traceparent`` inherited across the checkpoint-restart
     boundary: the launcher exports the rescale decision's trace
@@ -417,44 +364,6 @@ def traceparent() -> str | None:
     spans land in the SAME trace as the allocator's decision and the
     doomed incarnation's final save."""
     return _get_str("ADAPTDL_TRACEPARENT")
-
-
-def watch_buffer_size() -> int:
-    """Samples retained per graftwatch time series (per-job, per-
-    tenant, and cluster ring buffers alike): oldest samples are
-    evicted first, so a long-lived cluster holds a bounded window of
-    goodput/fairness history, never an unbounded log."""
-    return max(_get_int("ADAPTDL_WATCH_BUFFER", 512), 8)
-
-
-def watch_drift_window() -> int:
-    """Samples in the rolling predicted-vs-measured goodput window
-    behind ``adaptdl_goodput_drift``: the drift ratio is the mean of
-    the last N per-cycle measured/predicted ratios."""
-    return max(_get_int("ADAPTDL_WATCH_DRIFT_WINDOW", 16), 3)
-
-
-def watch_drift_threshold() -> float:
-    """Relative deviation of the rolling drift ratio from 1.0 past
-    which a job is flagged for re-profiling (ratio outside
-    ``[1/(1+t), 1+t]``). Observability-only: the flag is a metric and
-    a /watch field, never a policy input."""
-    return max(_get_float("ADAPTDL_WATCH_DRIFT_THRESHOLD", 0.25), 0.01)
-
-
-def watch_explain_topk() -> int:
-    """Losing candidates kept per allocator-cycle explain record (the
-    top-k Pareto-front solutions that scored below the winner, each
-    with the objective term that killed it)."""
-    return max(_get_int("ADAPTDL_WATCH_EXPLAIN_TOPK", 3), 0)
-
-
-def watch_straggler_factor() -> float:
-    """A rank's heartbeat-reported step-time EWMA above this multiple
-    of its job's median rank EWMA marks the rank's slot suspect
-    (``adaptdl_slot_suspect``). Needs >= 3 reporting ranks — a
-    2-rank job has no majority to define "normal"."""
-    return max(_get_float("ADAPTDL_WATCH_STRAGGLER_FACTOR", 1.5), 1.0)
 
 
 def watch_slo_rho() -> float:
@@ -501,36 +410,6 @@ def sched_state_dir() -> str | None:
     return _get_str("ADAPTDL_SCHED_STATE_DIR")
 
 
-def alloc_commit_timeout() -> float:
-    """Seconds a newly published allocation has to prove itself — all
-    expected worker processes of the new group registering/heartbeating
-    — before the supervisor rolls the job back to its last-committed
-    allocation and strikes the failing slots (0 disables transactional
-    rescale: allocations commit immediately, the pre-PR-5 behavior)."""
-    return _get_float("ADAPTDL_ALLOC_COMMIT_TIMEOUT", 300.0)
-
-
-def slot_strike_limit() -> int:
-    """Consecutive failed-allocation strikes against a slot before it
-    is quarantined (the allocator stops placing jobs on it until a
-    timed un-quarantine probe)."""
-    return _get_int("ADAPTDL_SLOT_STRIKE_LIMIT", 3)
-
-
-def slot_quarantine_s() -> float:
-    """Seconds a struck-out slot stays quarantined before one probe
-    allocation is allowed again (a single new strike re-quarantines)."""
-    return _get_float("ADAPTDL_SLOT_QUARANTINE_S", 300.0)
-
-
-def sched_reconcile_window() -> float:
-    """Seconds after a supervisor recovery during which recovered
-    worker leases are granted a grace deadline and the sweeper may not
-    expire anyone — workers get this long to re-register/heartbeat
-    against the recovered records before liveness enforcement resumes."""
-    return _get_float("ADAPTDL_SCHED_RECONCILE_WINDOW", 30.0)
-
-
 def journal_group_commit_s() -> float:
     """Group-commit window (seconds) for the supervisor's write-ahead
     journal: appends landing within the window share ONE fsync instead
@@ -540,23 +419,6 @@ def journal_group_commit_s() -> float:
     records are flushed to the OS per append). 0 — the default — keeps
     the strict fsync-per-record behavior."""
     return max(_get_float("ADAPTDL_JOURNAL_GROUP_COMMIT_S", 0.0), 0.0)
-
-
-def alloc_dirty_threshold() -> float:
-    """Fraction of jobs that must be dirty (changed hints, arrivals,
-    departures, preemptions) before the allocator abandons the
-    incremental re-optimization path and runs a full Pollux cycle —
-    re-searching only dirty jobs is cheap but cannot globally
-    rebalance, so heavy churn falls back to the full search."""
-    return min(max(_get_float("ADAPTDL_ALLOC_DIRTY_THRESHOLD", 0.25), 0.0), 1.0)
-
-
-def alloc_full_every() -> int:
-    """Force a full Pollux optimization every Nth allocator cycle
-    regardless of dirtiness, so background jobs pinned by incremental
-    cycles are periodically re-balanced (freed capacity redistributed,
-    fairness restored). 1 disables incremental allocation entirely."""
-    return max(_get_int("ADAPTDL_ALLOC_FULL_EVERY", 10), 1)
 
 
 def preempt_notice_s() -> float:
@@ -581,29 +443,6 @@ def preempt_poll_s() -> float:
     return _get_float("ADAPTDL_PREEMPT_POLL_S", 0.0)
 
 
-def preempt_slow_poll_s() -> float:
-    """Backed-off poll cadence after the metadata endpoint has been
-    unreachable ``preempt_backoff_after()`` times in a row — off GCE
-    the listener idles at this rate instead of hammering a dead
-    endpoint every few seconds."""
-    return _get_float("ADAPTDL_PREEMPT_SLOW_POLL_S", 60.0)
-
-
-def preempt_backoff_after() -> int:
-    """Consecutive unreachable metadata polls before the listener
-    backs off to the slow cadence (one reachable poll restores the
-    base cadence)."""
-    return max(_get_int("ADAPTDL_PREEMPT_BACKOFF_AFTER", 12), 1)
-
-
-def hazard_tau_s() -> float:
-    """Time constant (seconds) of the per-slot-kind reclaim-hazard
-    EWMA the scheduler maintains from observed preemption notices: the
-    estimated rate converges to events-per-second over roughly this
-    horizon and decays back toward zero at the same pace."""
-    return max(_get_float("ADAPTDL_HAZARD_TAU_S", 3600.0), 1.0)
-
-
 def spot_price_ratio() -> float | None:
     """Configured spot-vs-on-demand price ratio for the expander's
     capacity-mix policy (raw; the expander applies its default)."""
@@ -621,27 +460,6 @@ def guard_policy() -> str:
     if policy not in ("off", "warn", "skip", "rollback"):
         return "rollback"
     return policy
-
-
-def guard_window() -> int:
-    """Healthy-step window over which the guard keeps loss samples for
-    the rolling median+MAD spike detector. Spike detection arms only
-    once the window holds at least ``guard_min_samples()`` entries."""
-    return max(_get_int("ADAPTDL_GUARD_WINDOW", 32), 4)
-
-
-def guard_min_samples() -> int:
-    """Healthy loss samples required before the median+MAD spike
-    detector arms — NaN/Inf detection is always on, but spike
-    thresholds need a baseline first."""
-    return max(_get_int("ADAPTDL_GUARD_MIN_SAMPLES", 8), 2)
-
-
-def guard_mad_k() -> float:
-    """Spike threshold in robust sigmas: a loss farther than this many
-    scaled MADs (1.4826 * MAD) above the rolling median is flagged as
-    ``loss_spike``."""
-    return max(_get_float("ADAPTDL_GUARD_MAD_K", 8.0), 1.0)
 
 
 def guard_confirm_steps() -> int:
@@ -776,21 +594,3 @@ def shard_map_path() -> str | None:
     return _get_str("ADAPTDL_SHARD_MAP_PATH")
 
 
-def router_port() -> int | None:
-    """Port the shard router's HTTP server binds (raw)."""
-    return _get_opt_int("ADAPTDL_ROUTER_PORT")
-
-
-def reshard_fence_s() -> float:
-    """Per-tenant write-fence budget for a live tenant migration: the
-    source shard 503s the tenant's mutations for at most this many
-    seconds while the destination drains the final journal tail; an
-    overrun rolls the migration back (workers ride the fence out on
-    the retrying rpc client)."""
-    return _get_float("ADAPTDL_RESHARD_FENCE_S", 5.0)
-
-
-def reshard_batch_records() -> int:
-    """Max journal records (or job snapshots) per reshard stream
-    batch — bounds each `GET /shard/stream/{tenant}` response."""
-    return max(_get_int("ADAPTDL_RESHARD_BATCH", 256), 1)

@@ -9,7 +9,7 @@ FLOPs. This module implements the standard matmul-only accounting
 backward pass costed at 2x forward, attention scored causally (half
 the full [seq, seq] rectangle when ``causal``).
 
-Used by ``bench.py`` for the flagship-transformer MFU line and
+Used by ``chip_smoke.py`` for the flagship transformer's MFU line and
 available to user code for their own reporting.
 """
 

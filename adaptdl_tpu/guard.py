@@ -16,10 +16,10 @@ pays nothing beyond a handful of float comparisons:
 
 - **NaN/Inf**: loss or gradient statistics non-finite -> ``nan_loss``
   / ``nan_grad``. Always armed.
-- **Spike**: a finite loss farther than ``ADAPTDL_GUARD_MAD_K``
+- **Spike**: a finite loss farther than ``MAD_K``
   robust sigmas (1.4826 x MAD) above the rolling median of the last
-  ``ADAPTDL_GUARD_WINDOW`` *healthy* losses -> ``loss_spike``. Arms
-  once ``ADAPTDL_GUARD_MIN_SAMPLES`` healthy samples exist; only the
+  ``WINDOW`` *healthy* losses -> ``loss_spike``. Arms
+  once ``MIN_SAMPLES`` healthy samples exist; only the
   upper side fires (a sudden improvement is not a failure). Unhealthy
   samples never enter the window, so a NaN burst cannot drag the
   baseline with it.
@@ -72,6 +72,13 @@ KIND_LOSS_SPIKE = "loss_spike"
 # standard deviation of a normal distribution.
 _MAD_SIGMA = 1.4826
 
+# The spike detector: healthy losses kept for the rolling median + MAD,
+# how many of them arm it (NaN/Inf detection is always on), and the
+# threshold in robust sigmas above the median.
+WINDOW = 32
+MIN_SAMPLES = 8
+MAD_K = 8.0
+
 
 def _finite(value: Any) -> bool:
     try:
@@ -86,9 +93,6 @@ class NumericGuard:
 
     def __init__(self) -> None:
         self.policy = env.guard_policy()
-        self.window_size = env.guard_window()
-        self.min_samples = env.guard_min_samples()
-        self.mad_k = env.guard_mad_k()
         self.confirm_steps = env.guard_confirm_steps()
         self._window: list[float] = []  # healthy losses, newest last
         self._observations = 0
@@ -104,7 +108,7 @@ class NumericGuard:
     def _spike_bound(self) -> float | None:
         """Upper loss bound before a sample counts as a spike, or None
         while the detector is still collecting its baseline."""
-        if len(self._window) < self.min_samples:
+        if len(self._window) < MIN_SAMPLES:
             return None
         ordered = sorted(self._window)
         n = len(ordered)
@@ -122,7 +126,7 @@ class NumericGuard:
         # A flat-lined window (MAD 0) still needs a usable bound:
         # fall back to a small fraction of the median's magnitude.
         scale = _MAD_SIGMA * mad or 0.01 * abs(median) or 1e-8
-        return median + self.mad_k * scale
+        return median + MAD_K * scale
 
     def _classify(
         self, loss: Any, grad_sqr: Any, grad_var: Any
@@ -183,8 +187,8 @@ class NumericGuard:
             self.healthy_streak += 1
             if loss is not None:
                 self._window.append(float(loss))
-                if len(self._window) > self.window_size:
-                    del self._window[: -self.window_size]
+                if len(self._window) > WINDOW:
+                    del self._window[: -WINDOW]
             from adaptdl_tpu import checkpoint
 
             checkpoint.note_healthy_step()

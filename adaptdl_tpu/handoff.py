@@ -37,12 +37,12 @@ Client side (successor): discovery goes explicit URL
 (``ADAPTDL_HANDOFF_URL`` / :func:`set_source`) → supervisor
 (``GET /handoff/{job}``) → descriptor file; all fetches ride the
 resilient rpc client with an overall deadline
-(``ADAPTDL_HANDOFF_TIMEOUT_S``). Measured transfer time and bytes
+(``FETCH_TIMEOUT_S``). Measured transfer time and bytes
 feed ``metrics.record_handoff`` and ride ``restartStats`` so Pollux
 prices planned rescales at their new, storage-free cost.
 
 Reshard-aware range pulls: large leaf chunks are additionally
-advertised in ``ADAPTDL_HANDOFF_PARTS`` row parts (per-part sha256 in
+advertised in ``RANGE_PARTS`` row parts (per-part sha256 in
 the manifest, served as ``GET /chunk/{state}/{leaf}@p{i}`` by
 re-slicing the whole-leaf bytes on demand). A successor state that
 declares a shard map (``State.handoff_shard_plan``; see
@@ -87,6 +87,21 @@ RAW_CHUNK = "__payload__"
 HANDOFF_SOURCE = "<handoff>"
 
 DESCRIPTOR_NAME = ".handoff.json"
+
+# Seconds the spawned shard server lingers for the successor before
+# giving up (the durable checkpoint then serves the restore).
+SERVER_TTL_S = 60.0
+
+# Overall deadline of the successor's fetch (manifest + chunks); past
+# it the restore falls back to the durable checkpoint rather than
+# stall the restart on a dead or slow peer.
+FETCH_TIMEOUT_S = 10.0
+
+# Row parts a large leaf chunk is range-addressable in, and the size
+# under which a leaf is never split: a part's round-trip would cost
+# more than the bytes it saves.
+RANGE_PARTS = 8
+RANGE_PART_MIN_BYTES = 65536
 
 
 def _descriptor_path(root: str | None = None) -> str | None:
@@ -193,15 +208,13 @@ def attach_parts(  # wire: produces=handoff_manifest # wire: consumes=handoff_ma
     preemption notice. Only metadata is retained — part bytes are
     re-sliced from the whole-leaf payload at serve time, so server
     memory stays one copy of the state."""
-    max_parts = env.handoff_parts()
-    min_bytes = env.handoff_part_min_bytes()
     for entry in payload.values():
         if "parts" in entry:
             continue
         parts: dict[str, dict] = {}
         for cid in entry["order"]:
             meta = _partition_chunk(
-                entry["chunks"][cid], max_parts, min_bytes
+                entry["chunks"][cid], RANGE_PARTS, RANGE_PART_MIN_BYTES
             )
             if meta is not None:
                 parts[cid] = meta
@@ -415,7 +428,7 @@ def spawn_server(  # wire: produces=handoff_payload
     """Fork the shard server into a detached child so it outlives
     this (doomed) process's exit-143: the child inherits only the
     pickled chunk payload over stdin — no devices, no jax — serves
-    until the successor's ``/done`` or ``ADAPTDL_HANDOFF_TTL_S``,
+    until the successor's ``/done`` or ``SERVER_TTL_S``,
     then withdraws its descriptor and exits. Rank 0 only (mirroring
     the save pipeline's writer — one peer per job, and the served
     bytes must be the same rank's view the durable checkpoint
@@ -493,7 +506,7 @@ def _serve_main() -> int:  # wire: consumes=handoff_payload
         advertise_url = f"http://{address}:{server._port}"
     _advertise(advertise_url, server.group)
     try:
-        server.done.wait(env.handoff_ttl_s())
+        server.done.wait(SERVER_TTL_S)
         if server.done.is_set():
             # Grace for trailing chunk fetches racing the /done post.
             time.sleep(0.2)
@@ -910,7 +923,7 @@ def _ensure_manifest() -> tuple[dict, str] | None:
         with _manifest_lock:
             _unavailable = True
         return None
-    deadline_s = env.handoff_timeout_s()
+    deadline_s = FETCH_TIMEOUT_S
     t0 = time.monotonic()
     try:
         fetched = _fetch_manifest(url, deadline_s)
@@ -983,11 +996,11 @@ def warm_prefetch(  # wire: consumes=handoff_manifest
     try:
         faults.maybe_fail("warmup.prefetch")
         with trace.span("warmup.prefetch") as attrs:
-            fetched = _fetch_manifest(url, env.handoff_timeout_s())
+            fetched = _fetch_manifest(url, FETCH_TIMEOUT_S)
             if fetched is None:
                 return 0
             manifest, _ = fetched
-            deadline = time.monotonic() + env.handoff_timeout_s()
+            deadline = time.monotonic() + FETCH_TIMEOUT_S
             for name, entry in manifest.items():
                 chunks, nbytes, reused = _fetch_state_chunks(
                     url, name, entry, deadline
@@ -1060,7 +1073,7 @@ def try_restore(  # wire: consumes=handoff_manifest,handoff_fetch_stats
             raw_plan = None
         if raw_plan:
             plan = _normalize_plan(raw_plan, parts_meta)
-    deadline = time.monotonic() + env.handoff_timeout_s()
+    deadline = time.monotonic() + FETCH_TIMEOUT_S
     t0 = time.monotonic()
     nbytes = 0
     reused = 0

@@ -161,8 +161,8 @@ _LANES = 128
 
 # How the Mosaic-compiled kernel appears in a lowered or compiled
 # program's text. Interpret mode leaves no such call, so code that
-# measures or proves the chip path (chip_smoke.py, bench.py, the chip
-# compile tests) asserts this string is present.
+# measures or proves the chip path (chip_smoke.py, the chip compile
+# tests) asserts this string is present.
 MOSAIC_CALL = "tpu_custom_call"
 # The backward kernel's name in a lowered program and in a device
 # trace (``%flash_bwd.<n>``); the forward is unnamed inside the model's

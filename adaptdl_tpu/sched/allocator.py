@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from adaptdl_tpu import env, trace
+from adaptdl_tpu import trace
 from adaptdl_tpu.goodput import GoodputFunction, GradParams, PerfParams
 from adaptdl_tpu.sched.policy import (
     JobInfo,
@@ -168,8 +168,8 @@ class Allocator:
         policy: PolluxPolicy | None = None,
         interval: float = 60.0,
         expander=None,
-        dirty_threshold: float | None = None,
-        full_every: int | None = None,
+        dirty_threshold: float = 0.25,
+        full_every: int = 10,
     ):
         """``nodes`` is the slice inventory: either a static dict or a
         zero-arg callable returning one — a callable makes provisioned
@@ -181,9 +181,11 @@ class Allocator:
         cluster state marked dirty (hints, arrivals, departures,
         preemptions) against a pinned background, falling back to a
         FULL Pollux cycle when the dirty fraction crosses
-        ``dirty_threshold`` (ADAPTDL_ALLOC_DIRTY_THRESHOLD), every
-        ``full_every``-th cycle (ADAPTDL_ALLOC_FULL_EVERY), or
-        whenever the slice inventory / exclusion set changed."""
+        ``dirty_threshold`` (re-searching only dirty jobs is cheap but
+        cannot rebalance the cluster), every ``full_every``-th cycle
+        (so that pinned background jobs are re-balanced; 1 disables
+        incremental allocation), or whenever the slice inventory /
+        exclusion set changed."""
         self._state = state
         self._nodes = nodes
         if node_template is None:
@@ -199,16 +201,8 @@ class Allocator:
         self._policy = policy or PolluxPolicy()
         self._interval = interval
         self._expander = expander
-        self._dirty_threshold = (
-            env.alloc_dirty_threshold()
-            if dirty_threshold is None
-            else min(max(float(dirty_threshold), 0.0), 1.0)
-        )
-        self._full_every = (
-            env.alloc_full_every()
-            if full_every is None
-            else max(int(full_every), 1)
-        )
+        self._dirty_threshold = min(max(float(dirty_threshold), 0.0), 1.0)
+        self._full_every = max(int(full_every), 1)
         self._cycle = 0
         self._last_slots: frozenset | None = None
         self._last_excluded: frozenset = frozenset()

@@ -308,7 +308,7 @@ class LocalElasticRunner:
             )
             warm.discard()
             return
-        if warm.wait_ready(env_mod.warmup_deadline_s()):
+        if warm.wait_ready(warmup.READY_DEADLINE_S):
             self._warm = warm
         else:
             warm.discard("never became ready")

@@ -345,7 +345,7 @@ class MultiJobRunner:
             )
             warm.discard()
             return
-        if warm.wait_ready(env_mod.warmup_deadline_s()):
+        if warm.wait_ready(warmup.READY_DEADLINE_S):
             self._warms[job.name] = warm
         else:
             warm.discard("never became ready")

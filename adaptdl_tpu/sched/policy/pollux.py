@@ -35,7 +35,6 @@ from collections import OrderedDict
 
 import numpy as np
 
-from adaptdl_tpu import env
 from adaptdl_tpu.sched.policy import nsga2
 from adaptdl_tpu.sched.policy.utils import JobInfo, NodeInfo
 
@@ -51,6 +50,9 @@ DEFAULT_RESTART_COST_S = 30.0
 # placement keeps a sliver of scored goodput, so the search can still
 # rank terrible options instead of flattening them all to zero.
 MAX_HAZARD_LOSS = 0.9
+# Losing candidates kept per cycle's explain record, each labelled
+# with the objective term that killed it.
+EXPLAIN_TOPK = 3
 
 
 class PolluxPolicy:
@@ -640,9 +642,8 @@ class PolluxPolicy:
             key=lambda i: (-float(comps["full"][i]), int(comps["sizes"][i]), i),
         )
         losers = []
-        topk = env.watch_explain_topk()
         for i in order:
-            if i == pick or len(losers) >= topk:
+            if i == pick or len(losers) >= EXPLAIN_TOPK:
                 continue
             if int(comps["sizes"][i]) > budget:
                 killed = "utilBand"
@@ -967,7 +968,7 @@ def _merge_explains(
             "nodes": win_nodes,
         }
     losers.sort(key=lambda lo: (-lo["objective"], lo["nodes"]))
-    merged["losers"] = losers[: env.watch_explain_topk()]
+    merged["losers"] = losers[:EXPLAIN_TOPK]
     for key, alloc in allocations.items():
         merged["jobs"].setdefault(
             key,

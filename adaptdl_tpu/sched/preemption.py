@@ -23,9 +23,9 @@ drain** path —
    window" is known, not hoped — then exits 143 as usual.
 
 The listener itself is hardened for off-GCE runs: the poll interval
-is jittered, and after ``ADAPTDL_PREEMPT_BACKOFF_AFTER`` consecutive
+is jittered, and after ``BACKOFF_AFTER`` consecutive
 *unreachable* polls (no metadata server at all — a dev box, a CI
-runner) it backs off to ``ADAPTDL_PREEMPT_SLOW_POLL_S`` instead of
+runner) it backs off to ``SLOW_POLL_S`` instead of
 hammering a dead endpoint every few seconds; one reachable poll
 restores the base cadence.
 """
@@ -45,6 +45,11 @@ GCE_PREEMPTED_URL = (
     "http://metadata.google.internal/computeMetadata/v1/instance/preempted"
 )
 _HEADERS = {"Metadata-Flavor": "Google"}
+
+# Off GCE: consecutive unreachable polls before the listener backs
+# off, and the cadence (seconds) it idles at from then on.
+BACKOFF_AFTER = 12
+SLOW_POLL_S = 60.0
 
 # Poll outcomes (tri-state: "reachable but not preempted" must reset
 # the off-GCE backoff streak, while "unreachable" must grow it).
@@ -361,18 +366,14 @@ def _next_interval(
 def start_listener(
     url: str = GCE_PREEMPTED_URL,
     interval: float | None = None,
-    slow_interval: float | None = None,
-    backoff_after: int | None = None,
+    slow_interval: float = SLOW_POLL_S,
+    backoff_after: int = BACKOFF_AFTER,
 ) -> threading.Event:
     """Poll for preemption in the background; on notice, run
     :func:`deliver_notice` (graceful-exit flag + supervisor report)
     and stop. Returns a stop event for tests/teardown."""
     if interval is None:
         interval = env.preempt_poll_s() or 5.0
-    if slow_interval is None:
-        slow_interval = env.preempt_slow_poll_s()
-    if backoff_after is None:
-        backoff_after = env.preempt_backoff_after()
     stop = threading.Event()
     rng = random.Random()
 

@@ -47,7 +47,7 @@ import time
 
 from adaptdl_tpu import env, faults, rpc
 from adaptdl_tpu._compat import pick_unused_port
-from adaptdl_tpu.sched.state import ClusterState
+from adaptdl_tpu.sched.state import RESHARD_FENCE_S, ClusterState
 from adaptdl_tpu.sched.supervisor import Supervisor
 
 
@@ -784,7 +784,7 @@ def migrate_tenant(  # wire: produces=reshard # wire: consumes=reshard
        seq-ordered) into the destination until a delta batch comes
        back empty. The source keeps serving throughout.
     3. **fence** — raise a bounded per-tenant write fence on the
-       source (``ADAPTDL_RESHARD_FENCE_S``; workers ride out the brief
+       source (``RESHARD_FENCE_S``; workers ride out the brief
        503s on the retrying rpc client) and drain the final delta.
        Overrunning the fence budget aborts.
     4. **verify** — both sides' full tenant exports must hash equal.
@@ -803,7 +803,7 @@ def migrate_tenant(  # wire: produces=reshard # wire: consumes=reshard
     Returns the flipped map (version + 1); raises
     :class:`ReshardError` after rollback."""
     client = client if client is not None else rpc.default_client()
-    fence_s = float(fence_s) if fence_s is not None else env.reshard_fence_s()
+    fence_s = RESHARD_FENCE_S if fence_s is None else float(fence_s)
     from_sid, to_sid = int(from_sid), int(to_sid)
     src = shard_map.shards[from_sid]
     dst = shard_map.shards[to_sid]

@@ -22,14 +22,14 @@ explicitly:
   re-register/heartbeat against the recovered records and ride out
   the restart with zero job restarts. Mutators carry a ``# journaled``
   annotation; graftcheck rule GC603/GC604 keeps the set honest.
-- **Transactional rescale** (``ADAPTDL_ALLOC_COMMIT_TIMEOUT``): an
+- **Transactional rescale** (``alloc_commit_timeout``): an
   allocation change opens a prepare→commit *epoch*. The new
   allocation only commits once the new worker group proves liveness
   (all expected processes register/heartbeat); if the commit deadline
   lapses the job **rolls back** to its last-committed allocation, the
-  failing slots earn a strike, and ``ADAPTDL_SLOT_STRIKE_LIMIT``
+  failing slots earn a strike, and ``slot_strike_limit``
   consecutive strikes quarantine a slot away from the allocator until
-  a timed un-quarantine probe (``ADAPTDL_SLOT_QUARANTINE_S``).
+  a timed un-quarantine probe (``slot_quarantine_s``).
 """
 
 from __future__ import annotations
@@ -63,6 +63,13 @@ _ALLOC_DECIDE_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
+
+# Live tenant migration: the write-fence budget (seconds the source
+# 503s a tenant's mutations while the destination drains the journal
+# tail; an overrun rolls the migration back) and the most journal
+# records one ``GET /shard/stream/{tenant}`` batch carries.
+RESHARD_FENCE_S = 5.0
+RESHARD_BATCH_RECORDS = 256
 
 
 def normalize_topology(  # wire: produces=topology # wire: consumes=topology
@@ -321,14 +328,23 @@ class ClusterState:
     def __init__(
         self,
         state_dir: str | None = None,
-        alloc_commit_timeout: float | None = None,
-        slot_strike_limit: int | None = None,
-        slot_quarantine_s: float | None = None,
-        reconcile_window: float | None = None,
+        alloc_commit_timeout: float = 300.0,
+        slot_strike_limit: int = 3,
+        slot_quarantine_s: float = 300.0,
+        reconcile_window: float = 30.0,
         snapshot_every: int = 256,
-        hazard_tau_s: float | None = None,
+        hazard_tau_s: float = 3600.0,
         clock=None,
     ):
+        """``alloc_commit_timeout`` seconds a published allocation
+        has to prove itself (every expected worker registering)
+        before the job rolls back and the failing slots are struck;
+        0 commits at once. ``slot_strike_limit`` consecutive strikes
+        quarantine a slot for ``slot_quarantine_s``, after which one
+        probe allocation is allowed. For ``reconcile_window`` seconds
+        after a recovery the sweeper expires no lease.
+        ``hazard_tau_s`` is the time constant of the per-slot-kind
+        reclaim-hazard EWMA."""
         self._cond = threading.Condition()  # lock-order: 10
         # Injectable clock (``monotonic()`` + ``time()``): defaults to
         # the real ``time`` module; the discrete-event simulator
@@ -352,27 +368,10 @@ class ClusterState:
         self._completions: dict[str, tuple[int, float]] = {}  # guarded-by: _cond
         # Transactional-rescale knobs (0 commit timeout disables the
         # epoch machinery entirely — allocations commit immediately).
-        self._commit_timeout = (
-            env.alloc_commit_timeout()
-            if alloc_commit_timeout is None
-            else float(alloc_commit_timeout)
-        )
-        self._strike_limit = max(
-            env.slot_strike_limit()
-            if slot_strike_limit is None
-            else int(slot_strike_limit),
-            1,
-        )
-        self._quarantine_s = (
-            env.slot_quarantine_s()
-            if slot_quarantine_s is None
-            else float(slot_quarantine_s)
-        )
-        self._reconcile_window = (
-            env.sched_reconcile_window()
-            if reconcile_window is None
-            else float(reconcile_window)
-        )
+        self._commit_timeout = float(alloc_commit_timeout)
+        self._strike_limit = max(int(slot_strike_limit), 1)
+        self._quarantine_s = float(slot_quarantine_s)
+        self._reconcile_window = float(reconcile_window)
         # Slot health: consecutive failed-allocation strikes and the
         # quarantine table (slot -> monotonic un-quarantine time).
         self._slot_strikes: dict[str, int] = {}  # guarded-by: _cond
@@ -385,11 +384,7 @@ class ClusterState:
         # clock so the estimate survives restarts via the journal),
         # notice counters, and the allocator-registered slot->kind map
         # (in-memory: derivable from the inventory every cycle).
-        self._hazard_tau = (
-            env.hazard_tau_s()
-            if hazard_tau_s is None
-            else max(float(hazard_tau_s), 1.0)
-        )
+        self._hazard_tau = max(float(hazard_tau_s), 1.0)
         self._draining_slots: dict[str, float] = {}  # guarded-by: _cond
         self._hazard: dict[str, tuple[float, float]] = {}  # guarded-by: _cond
         self._preempt_notices: dict[str, int] = {}  # guarded-by: _cond
@@ -1849,7 +1844,7 @@ class ClusterState:
     def hazard_rates(self, now: float | None = None) -> dict[str, float]:
         """Per-slot reclaim hazard by slot kind (expected notices per
         slot-second: the kind's aggregate EWMA over
-        ``ADAPTDL_HAZARD_TAU_S``, normalized by the kind's registered
+        ``hazard_tau_s``, normalized by the kind's registered
         fleet size), decayed to ``now`` (wall clock — the estimate is
         journal-anchored so it survives supervisor restarts)."""
         if now is None:
@@ -2284,7 +2279,7 @@ class ClusterState:
         ``from_seq`` None bootstraps with a snapshot-mode export;
         otherwise a delta batch of the tenant's journal records with
         seq > from_seq, in seq order, at most ``limit`` records
-        (``ADAPTDL_RESHARD_BATCH`` by default). The batch's ``seq`` is
+        (``RESHARD_BATCH_RECORDS`` by default). The batch's ``seq`` is
         the highest source seq the scan COVERED — other tenants'
         interleaved records advance it too, so the destination's
         watermark tracks the source head and an empty delta batch
@@ -2294,9 +2289,7 @@ class ClusterState:
         back to a fresh snapshot export rather than serving a gap."""
         faults.maybe_fail("reshard.stream.batch")
         limit = (
-            env.reshard_batch_records()
-            if limit is None
-            else max(int(limit), 1)
+            RESHARD_BATCH_RECORDS if limit is None else max(int(limit), 1)
         )
         with self._cond:
             if from_seq is None:
@@ -2608,16 +2601,14 @@ class ClusterState:
     ) -> float:
         """Raise the tenant's write fence: the supervisor 503s the
         tenant's mutations (reads keep flowing) for at most
-        ``timeout_s`` seconds (``ADAPTDL_RESHARD_FENCE_S`` default)
+        ``timeout_s`` seconds (``RESHARD_FENCE_S`` default)
         while the destination drains the final journal tail.
         In-memory by design — a source crash drops the fence with the
         process, which is safe: the map never flipped, so the
         recovered shard resumes serving the tenant. Returns the
         monotonic fence deadline."""
         timeout_s = (
-            env.reshard_fence_s()
-            if timeout_s is None
-            else float(timeout_s)
+            RESHARD_FENCE_S if timeout_s is None else float(timeout_s)
         )
         with self._cond:
             deadline = self._clock.monotonic() + max(timeout_s, 0.0)

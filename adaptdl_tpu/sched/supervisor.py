@@ -913,7 +913,7 @@ class Supervisor(ThreadedHttpServer):
             with self._trace_lock:
                 store = self._trace_store.get(key)
                 if store is None:
-                    store = deque(maxlen=env.trace_buffer_size())
+                    store = deque(maxlen=trace.BUFFER_SIZE)
                     self._trace_store[key] = store
                 seen = {rec.get("span") for rec in store}
                 fresh = []
@@ -1150,7 +1150,7 @@ class Supervisor(ThreadedHttpServer):
             "adaptdl_goodput_reprofile_flag",
             "gauge",
             "1 while a job's goodput drift sits outside the "
-            "ADAPTDL_WATCH_DRIFT_THRESHOLD band — the model needs "
+            "[1/1.25, 1.25] band — the model needs "
             "re-profiling (observability-only signal).",
         )
         b.family(
@@ -1592,7 +1592,7 @@ class Supervisor(ThreadedHttpServer):
             parts = request.path.split("/", 2)
             segment = parts[1] if len(parts) > 1 and parts[1] else "root"
             # record_span journals the span (file IO under the trace
-            # journal lock) when ADAPTDL_TRACE_JOURNAL is set — off
+            # journal lock) when ADAPTDL_TRACE_DIR is set — off
             # the loop, like every other blocking call here.
             await self._offload(
                 trace.record_span,

@@ -56,6 +56,15 @@ from adaptdl_tpu.sched.state import normalize_topology
 
 LOG = logging.getLogger(__name__)
 
+# Longest the runner waits for a warm successor to mark itself ready
+# before discarding it and rescaling cold: warm-up must never delay a
+# rescale by more than it saves. The held successor waits six times
+# as long for the cutover file — its hold spans the incumbent's whole
+# drain (the final save), not just the warm-up window — and takes an
+# expired wait for an abort.
+READY_DEADLINE_S = 20.0
+HOLD_DEADLINE_S = 6.0 * READY_DEADLINE_S
+
 # Cutover-file verdicts (the whole wire format of the runner ->
 # successor channel).
 GO = "go"
@@ -274,11 +283,7 @@ def _await_cutover(path: str | None) -> str:
     proceeding could fight an incumbent that still owns the chips."""
     if not path:
         return GO
-    # Generous: the hold spans the incumbent's whole drain (its final
-    # save), not just the warm-up window.
-    deadline = time.monotonic() + max(
-        env.warmup_deadline_s() * 6.0, 60.0
-    )
+    deadline = time.monotonic() + HOLD_DEADLINE_S
     while time.monotonic() < deadline:
         try:
             with open(path, encoding="utf-8") as f:

@@ -282,8 +282,8 @@ class ShardedTrainerCheckpoint(checkpoint.State):
         # and the chaos suite proves it.
         if env.sharded_hash_enabled():
             # Differential encoding: hash this process's addressable
-            # shards (one host transfer per save — ADAPTDL_SHARDED_
-            # HASHES=off for jobs where that dominates) and diff
+            # shards (one host transfer per save;
+            # ADAPTDL_SHARDED_HASHES=off where that dominates) and diff
             # against the previous save, so the pointer records which
             # shards actually changed.
             table = shard_hash_table(state)

@@ -171,8 +171,6 @@ class ClusterSim:
         reclaim_outage_s: float = 600.0,
         max_sim_s: float = 400_000.0,
         policy: PolluxPolicy | None = None,
-        dirty_threshold: float | None = None,
-        full_every: int | None = None,
         dp_only: bool = False,
     ):
         self.clock = VirtualClock()
@@ -226,10 +224,7 @@ class ClusterSim:
             # ramping jobs routinely dirty >25% of the ACTIVE set, and
             # a full partitioned re-solve every cycle both churns
             # settled jobs (restarts) and dominates the wall clock.
-            dirty_threshold=(
-                0.5 if dirty_threshold is None else dirty_threshold
-            ),
-            full_every=full_every,
+            dirty_threshold=0.5,
         )
         self.jobs: dict[str, _SimJob] = {}
         self._arrivals_pending = 0

@@ -22,7 +22,7 @@ CheckFreq's (FAST'21) snapshot/write/stall breakdowns are built on:
   incarnation's final save, the supervisor's epoch lifecycle, and the
   successor's restore/first-step into one timeline.
 - **Bounded ring buffer** — finished spans land in a lock-guarded
-  deque of ``ADAPTDL_TRACE_BUFFER`` capacity; a runaway producer can
+  deque of ``BUFFER_SIZE`` capacity; a runaway producer can
   evict history but never grow memory.
 - **Three exporters**:
 
@@ -207,6 +207,9 @@ def _inc() -> int:
 # Per-thread stack of (trace_id, span_id) for parent/child nesting.
 _tls = threading.local()
 
+# Capacity of the in-memory span ring (oldest spans evicted first).
+BUFFER_SIZE = 4096
+
 _buffer_lock = threading.Lock()  # lock-order: 72
 _buffer: deque | None = None  # guarded-by: _buffer_lock
 _seq = 0  # guarded-by: _buffer_lock
@@ -216,13 +219,13 @@ _flushed_seq = 0  # guarded-by: _buffer_lock
 def _buffer_locked() -> deque:  # holds-lock: _buffer_lock
     global _buffer
     if _buffer is None:
-        _buffer = deque(maxlen=env.trace_buffer_size())
+        _buffer = deque(maxlen=BUFFER_SIZE)
     return _buffer
 
 
 def buffer_seq() -> int:
     """Monotonic sequence of the newest recorded span (0 when none) —
-    lets a caller bracket a window of interest (bench does)."""
+    lets a caller bracket a window of interest."""
     with _buffer_lock:
         return _seq
 
@@ -995,8 +998,7 @@ def flush_to_supervisor(  # wire: produces=trace_payload
 
 def phase_summary(records: list[dict]) -> dict[str, float]:
     """name -> median duration (seconds) over span records — the
-    per-phase breakdown bench.py emits next to its stopwatch
-    numbers."""
+    per-phase breakdown ``adaptdl-tpu trace`` prints."""
     by_name: dict[str, list[float]] = {}
     for rec in records:
         if rec.get("kind") == "event":

@@ -9,7 +9,7 @@ starved?". This module is that accounting layer, in the Check-N-Run
 predicted-vs-realized, never assume.
 
 Four record streams, all held in bounded, lock-disciplined stdlib
-ring buffers (``ADAPTDL_WATCH_*`` knobs; a runaway cluster evicts
+ring buffers (``WatchStore``'s ``buffer``; a runaway cluster evicts
 history, never grows memory):
 
 - **Goodput samples** — once per allocator cycle, per active job:
@@ -29,13 +29,13 @@ history, never grows memory):
   journal-light (in-memory only), served via ``GET /explain/{job}``
   and rendered by ``adaptdl-tpu explain``.
 - **Straggler detection** — per-rank step-time EWMAs piggybacked on
-  worker heartbeats; a rank above ``ADAPTDL_WATCH_STRAGGLER_FACTOR``
+  worker heartbeats; a rank above ``straggler_factor``
   x its job's median marks its slot suspect
   (``adaptdl_slot_suspect``).
 
 The model-drift monitor folds the goodput samples into a rolling
 measured/predicted ratio per job (``adaptdl_goodput_drift``); a ratio
-outside ``[1/(1+t), 1+t]`` for ``ADAPTDL_WATCH_DRIFT_THRESHOLD`` t
+outside ``[1/(1+t), 1+t]`` for ``drift_threshold`` t
 flags the job for re-profiling — an observability-only signal, never
 a policy input.
 
@@ -131,33 +131,28 @@ class WatchStore:
     def __init__(
         self,
         clock=None,
-        buffer: int | None = None,
-        drift_window: int | None = None,
-        drift_threshold: float | None = None,
-        straggler_factor: float | None = None,
+        buffer: int = 512,
+        drift_window: int = 16,
+        drift_threshold: float = 0.25,
+        straggler_factor: float = 1.5,
         slo_rho: float | None = None,
     ):
+        """``buffer`` samples a time series keeps (oldest evicted
+        first); ``drift_window`` paired samples behind the rolling
+        measured/predicted ratio, a job flagged for re-profiling when
+        the ratio leaves ``[1/(1+t), 1+t]`` for ``t = drift_threshold``
+        (a metric, never a policy input); a rank whose step-time EWMA
+        is above ``straggler_factor`` x its job's median marks its
+        slot suspect (needs three reporting ranks)."""
         # Injectable clock like ClusterState's: the simulator passes
         # its VirtualClock so every sample timestamp derives from
         # event time (fixed seed => bit-identical series). Assigned
         # once before any other thread holds a reference.
         self._clock = time if clock is None else clock
-        self._buffer = (
-            env.watch_buffer_size() if buffer is None
-            else max(int(buffer), 8)
-        )
-        self._drift_window = (
-            env.watch_drift_window() if drift_window is None
-            else max(int(drift_window), 3)
-        )
-        self._drift_threshold = (
-            env.watch_drift_threshold() if drift_threshold is None
-            else max(float(drift_threshold), 0.01)
-        )
-        self._straggler_factor = (
-            env.watch_straggler_factor() if straggler_factor is None
-            else max(float(straggler_factor), 1.0)
-        )
+        self._buffer = max(int(buffer), 8)
+        self._drift_window = max(int(drift_window), 3)
+        self._drift_threshold = max(float(drift_threshold), 0.01)
+        self._straggler_factor = max(float(straggler_factor), 1.0)
         self._slo_rho = (
             env.watch_slo_rho() if slo_rho is None
             else max(float(slo_rho), 0.1)

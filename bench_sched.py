@@ -1,7 +1,6 @@
 """bench_sched: the thousand-job control-plane benchmark.
 
-Two phases, one JSON line (merged into the BENCH json by bench.py, or
-printed standalone via ``python bench_sched.py``):
+Two phases, one JSON line (``python bench_sched.py``):
 
 1. **Allocator decision latency at 1k-job steady state.** Builds an
    in-memory ClusterState with 1000 hint-posting jobs over 1250
@@ -486,7 +485,7 @@ def bench_reshard(
 
 
 def collect(quick: bool = False) -> dict:
-    """Everything on one dict (bench.py merges this into BENCH)."""
+    """Everything on one dict."""
     out = {}
     out.update(
         bench_allocator(jobs=200, slices=250, iterations=6)

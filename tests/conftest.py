@@ -54,13 +54,17 @@ def _clean_state_registry():
     trace._reset_state()
 
 
-@pytest.fixture
-def compile_cache_config_restored():
-    """For tests that switch the process-wide persistent compile cache
-    on (``bootstrap._enable_compilation_cache``): put jax's config back
-    so later tests in this session compile as they would alone."""
+@pytest.fixture(autouse=True)
+def _compile_cache_config_restored():
+    """A test that switches the process-wide persistent compile cache
+    on (``initialize_job`` -> ``bootstrap._enable_compilation_cache``)
+    does so for every test the worker runs after it, and which files
+    those are is up to xdist's scheduling: their programs then come
+    deserialized from ``.jax_compile_cache`` with ``jit.cache_*``
+    events beside them (eight cases of tests/test_trainer.py in the
+    driver's run of PR 43's tree, ROADMAP D0). Put jax's config back
+    after every test, so each compiles as it would alone."""
     import jax
-    from jax.experimental.compilation_cache import compilation_cache
 
     names = (
         "jax_compilation_cache_dir",
@@ -69,9 +73,12 @@ def compile_cache_config_restored():
     )
     prev = {name: getattr(jax.config, name) for name in names}
     yield
-    for name, value in prev.items():
-        jax.config.update(name, value)
-    compilation_cache.reset_cache()
+    if prev != {name: getattr(jax.config, name) for name in names}:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        for name, value in prev.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
 
 
 # ---- per-test resource-leak canary ----------------------------------

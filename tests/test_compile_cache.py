@@ -224,7 +224,7 @@ def test_fresh_and_restored_state_share_an_aot_fingerprint(
 
 
 def test_executable_from_persistent_cache_is_not_reserialized(
-    tmp_path, monkeypatch, compile_cache_config_restored
+    tmp_path, monkeypatch
 ):
     """On the CPU only an executable this process compiled goes into
     the AOT cache: one that jax's persistent cache served does not

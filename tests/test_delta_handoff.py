@@ -508,7 +508,6 @@ def test_spawned_child_server_serves_and_expires(
     successor, and exits after /done."""
     monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
     monkeypatch.setenv("ADAPTDL_HANDOFF", "on")
-    monkeypatch.setenv("ADAPTDL_HANDOFF_TTL_S", "30")
     state = Chunky("hand-c", {"w": 42})
     proc = handoff.spawn_server()
     assert proc is not None

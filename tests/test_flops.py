@@ -1,8 +1,8 @@
 """MFU accounting sanity (adaptdl_tpu/flops.py).
 
 The reference has no utilization reporting to mirror; these tests pin
-the arithmetic of the matmul-only convention so bench.py's MFU line is
-trustworthy.
+the arithmetic of the matmul-only convention so chip_smoke.py's MFU
+line is trustworthy.
 """
 
 import pytest

@@ -59,8 +59,7 @@ def test_nan_classification_precedence(monkeypatch):
 
 def test_spike_detector_arms_after_min_samples(monkeypatch):
     monkeypatch.setenv("ADAPTDL_GUARD_POLICY", "warn")
-    monkeypatch.setenv("ADAPTDL_GUARD_MIN_SAMPLES", "4")
-    monkeypatch.setenv("ADAPTDL_GUARD_MAD_K", "8")
+    monkeypatch.setattr(guard, "MIN_SAMPLES", 4)
     g = guard.NumericGuard()
     # Below min_samples even an absurd loss passes (no baseline yet).
     assert g.observe(1.0)["healthy"]
@@ -78,8 +77,7 @@ def test_spike_detector_arms_after_min_samples(monkeypatch):
 
 def test_flat_window_uses_relative_fallback_bound(monkeypatch):
     monkeypatch.setenv("ADAPTDL_GUARD_POLICY", "warn")
-    monkeypatch.setenv("ADAPTDL_GUARD_MIN_SAMPLES", "4")
-    monkeypatch.setenv("ADAPTDL_GUARD_MAD_K", "8")
+    monkeypatch.setattr(guard, "MIN_SAMPLES", 4)
     g = guard.NumericGuard()
     for _ in range(4):
         assert g.observe(2.0)["healthy"]

@@ -98,8 +98,8 @@ def _arrays(seed=0):
 def small_parts(monkeypatch):
     # The test leaves are a few KB; drop the production floor so they
     # partition into range-addressable parts.
-    monkeypatch.setenv("ADAPTDL_HANDOFF_PART_MIN_BYTES", "64")
-    monkeypatch.setenv("ADAPTDL_HANDOFF_PARTS", "4")
+    monkeypatch.setattr(handoff, "RANGE_PART_MIN_BYTES", 64)
+    monkeypatch.setattr(handoff, "RANGE_PARTS", 4)
     monkeypatch.setenv("ADAPTDL_NUM_RESTARTS", "0")
 
 

@@ -208,13 +208,6 @@ def test_generator_step_mesh_variant_matches_single_device():
         )
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_mlm_bidirectional_learns_masked_tokens_with_accumulation():
     """BERT-class objective (VERDICT r1 item 9): a bidirectional
     encoder + masked-LM loss, trained WITH gradient accumulation,

@@ -71,13 +71,6 @@ def test_expert_parallel_matches_dense():
     assert not np.allclose(np.asarray(piped), np.asarray(x))
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_trainer_dp_x_expert_trains_and_matches_dense_grads():
     """dp=2 x expert=2: the elastic step trains the MoE, and the first
     step's gradients (router AND experts) match a pure-DP run of the
@@ -257,13 +250,6 @@ def test_aux_loss_balances_uniform_and_collapsed_routers():
     assert float(aux_uniform) == pytest.approx(1.0, rel=1e-3)
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_moe_transformer_expert_parallel_matches_dense():
     """A MoE *transformer* (every 2nd block Switch-MoE) trains under
     dp x expert with the same loss as the dense-equivalent model —
@@ -448,13 +434,6 @@ def test_expert_choice_transformer_trains():
     assert np.isfinite(float(m["loss"]))
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_expert_choice_trainer_matches_dense_trajectory():
     """dp x expert with expert-choice routing: losses, GNS statistics,
     and the router AND expert parameter trajectories match the

@@ -210,24 +210,17 @@ def _run_phases(tmp_path, extra_env=None):
     assert any(abs(float(v)) > 1e-8 for v in w_saved.split(","))
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
+# Two real processes under ``jax.distributed`` on the CPU backend: in
+# this sandbox the cross-process (Gloo) context is never formed
+# (DEADLINE_EXCEEDED after 30 s, 37 s a case, alone or under xdist:
+# re-run on jax 0.9.0 in PR 45), so the case stays with the nightly
+# tier (-m slow) for machines where the two processes can rendezvous.
 @pytest.mark.slow
 def test_two_process_train_then_single_process_restore(tmp_path):
     _run_phases(tmp_path)
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
+@pytest.mark.slow  # two real processes, as above
 def test_two_process_zero1_then_single_process_restore(tmp_path):
     """The same cross-process-count rescale with ZeRO-1 moments: the
     2-process save writes canonical flat moments collectively (each
@@ -237,13 +230,7 @@ def test_two_process_zero1_then_single_process_restore(tmp_path):
     _run_phases(tmp_path, extra_env={"ZERO1": "1"})
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
+@pytest.mark.slow  # two real processes, as above
 def test_dp_spanning_two_slices_records_num_nodes_2_fit_rows(tmp_path):
     """A job SPANNING two slices over DCN (r3 verdict ask #5): dp runs
     across two ``jax.distributed`` processes, the metrics engine

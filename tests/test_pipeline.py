@@ -87,13 +87,6 @@ def test_gpipe_matches_sequential(num_stages, num_micro):
     )
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_trainer_dp_x_stage_matches_pure_dp():
     """The whole elastic step over a dp x stage mesh — stage-sharded
     params, GPipe forward, stage-summed GNS statistics — reproduces
@@ -272,13 +265,6 @@ def test_interleaved_matches_sequential(num_stages, v, num_micro):
     )
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_interleaved_trainer_matches_pure_dp():
     """dp x stage with the interleaved schedule (v=2) reproduces the
     pure-DP evolution of the same 4-chunk network."""
@@ -347,13 +333,6 @@ def test_interleaved_trainer_matches_pure_dp():
 
 
 @pytest.mark.parametrize("interleave", [1, 2])
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_pipeline_lm_matches_sequential_dp(interleave):
     """The staged transformer (GPipe and interleaved) reproduces the
     sequential run of the same params under pure DP: losses and the
@@ -719,13 +698,6 @@ def test_dense_and_pipelined_share_canonical_checkpoints(
     ck3.unregister()
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_pipeline_lm_composes_with_tensor_parallel():
     """dp x stage x model: block leaves manual on stage, GSPMD-auto on
     model — the composed run reproduces the stage-only run exactly."""

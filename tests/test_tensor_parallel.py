@@ -44,13 +44,6 @@ def test_tp_specs_cover_transformer():
     assert any("out" in n for n in names)
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_tp_training_matches_replicated():
     cfg = TransformerConfig(
         vocab_size=64, num_layers=2, num_heads=4, d_model=32, d_ff=64,
@@ -103,13 +96,6 @@ def test_tp_training_matches_replicated():
     assert "model" in str(spec)
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_trainer_checkpoint_restores_tp_sharded(tmp_path, monkeypatch):
     """TrainerCheckpoint.load honors param_sharding_fn: params, their
     optimizer moments, and the GNS prev-grad all come back laid out

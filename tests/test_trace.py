@@ -135,7 +135,7 @@ def test_pending_span_bridges_callsites():
 
 
 def test_ring_buffer_stays_bounded_under_hammer(monkeypatch):
-    monkeypatch.setenv("ADAPTDL_TRACE_BUFFER", "512")
+    monkeypatch.setattr(trace, "BUFFER_SIZE", 512)
     trace._reset_state()
     threads = [
         threading.Thread(
@@ -517,6 +517,7 @@ def test_initialize_job_rearm_is_once_per_incarnation(monkeypatch):
 
     monkeypatch.setattr(bootstrap, "_restart_span_armed", False)
     monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
+    monkeypatch.setenv("ADAPTDL_COMPILE_CACHE", "off")
     bootstrap.initialize_job()
     assert trace.end_pending("restart.first_step")
     bootstrap.initialize_job()  # documented-idempotent second call
@@ -1122,7 +1123,7 @@ def test_aot_compile_reads_cache_hits_from_the_bridge(
 
 
 def test_worker_prologue_lies_inside_restart_first_step(
-    tmp_path, monkeypatch, compile_cache_config_restored
+    tmp_path, monkeypatch
 ):
     """The worker's path on CPU, in process: initialize_job, a tiny
     trainer, init_state, a loader, steps until restart.first_step
@@ -1176,7 +1177,7 @@ def test_worker_prologue_lies_inside_restart_first_step(
 
 
 def test_restored_worker_reuses_calibration_inside_restart_first_step(
-    tmp_path, monkeypatch, compile_cache_config_restored
+    tmp_path, monkeypatch
 ):
     """The twin of the prologue test for a RESTORED job: a second
     incarnation under the same layout registers the metrics state,

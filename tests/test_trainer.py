@@ -350,11 +350,17 @@ def test_last_micro_batch_outside_the_scan_is_the_scans_step(
         trainer_mod.trace, "event",
         lambda name, **attrs: events.append((name, attrs)),
     )
+
+    def overlaps():
+        # (By name: with jax's persistent compile cache on, its
+        # ``jit.cache_*`` events follow the step's own.)
+        return [a for n, a in events if n == "step.reduce_overlap"]
+
     del TRACED[:]
     tailed = _two_steps(layout, 4, accum_steps)
     assert len(TRACED) == 1
     has_tail = layout != "zero3-blocks"
-    assert [a for n, a in events if n == "step.reduce_overlap"] == [{
+    assert overlaps() == [{
         "replicas": 4, "num_micro": accum_steps + 1,
         "scanned": accum_steps + (not has_tail), "tail": has_tail,
         "groups": 7 if has_tail else 0,  # one all-reduce a leaf
@@ -363,7 +369,7 @@ def test_last_micro_batch_outside_the_scan_is_the_scans_step(
         ElasticTrainer, "_reduce_has_tail", lambda self: False
     )
     in_scan = _two_steps(layout, 4, accum_steps)
-    assert events[-1][1]["tail"] is False
+    assert len(overlaps()) == 2 and overlaps()[-1]["tail"] is False
     _assert_same_bits(tailed, in_scan)
     seen = tailed[1]["counters"]["test.seen"]
     assert float(seen["rows"]) == 16.0

@@ -61,6 +61,9 @@ from adaptdl_tpu.trainer import ElasticTrainer, TrainerCheckpoint
 
 SEED = 1234
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# One deadline for every wait on another process: long enough for a
+# loaded machine, and never what a passing run waits for.
+WAIT_S = 180.0
 
 
 @pytest.fixture(autouse=True)
@@ -258,7 +261,7 @@ def test_warm_successor_lifecycle_ready_then_cutover(tmp_path):
     )
     warm.spawn()
     try:
-        assert warm.wait_ready(30.0), "successor never marked ready"
+        assert warm.wait_ready(WAIT_S), "successor never marked ready"
         assert warm.alive(), "successor must hold after ready"
         assert warm.matches(["local", "local"], None)
         assert warm.matches(
@@ -267,7 +270,7 @@ def test_warm_successor_lifecycle_ready_then_cutover(tmp_path):
         assert not warm.matches(["local"], None)
         assert warm.restarts == 1
         proc = warm.cutover()
-        assert proc.wait(30) == 0, "released successor runs to completion"
+        assert proc.wait(WAIT_S) == 0, "released successor runs to completion"
     finally:
         warm.discard()
 
@@ -283,7 +286,7 @@ def test_warm_successor_discard_kills_and_cleans(tmp_path):
         restarts=2,
     )
     warm.spawn()
-    assert warm.wait_ready(30.0)
+    assert warm.wait_ready(WAIT_S)
     proc = warm.proc
     warm.discard("test discard")
     assert proc.poll() is not None, "discard reaps the successor"
@@ -306,13 +309,13 @@ def test_maybe_hold_abort_exits_with_graceful_code(tmp_path):
         stdout=subprocess.PIPE,
     )
     try:
-        deadline = time.monotonic() + 30
+        deadline = time.monotonic() + WAIT_S
         while time.monotonic() < deadline and not os.path.exists(ready):
             assert proc.poll() is None, "died before marking ready"
             time.sleep(0.05)
         assert os.path.exists(ready)
         warmup._write_atomic(cut, warmup.ABORT)
-        out, _ = proc.communicate(timeout=30)
+        out, _ = proc.communicate(timeout=WAIT_S)
         assert proc.returncode == 143, (
             "an aborted speculation exits with the graceful rescale "
             "code so nothing counts it as a failure"
@@ -742,7 +745,7 @@ SIM_SCRIPT = textwrap.dedent(
         desc = os.path.join(
             os.environ["ADAPTDL_CHECKPOINT_PATH"], ".handoff.json"
         )
-        deadline = time.monotonic() + 15.0
+        deadline = time.monotonic() + 120.0
         while time.monotonic() < deadline and not os.path.exists(desc):
             time.sleep(0.02)
 
@@ -787,6 +790,11 @@ SIM_SCRIPT = textwrap.dedent(
     TRUE_W = np.array([1.0, -2.0, 3.0, 0.5])
     total = int(os.environ.get("SIM_TOTAL_STEPS", "80"))
     pause = float(os.environ.get("SIM_STEP_SLEEP", "0.04"))
+    # The first incarnation goes no further than this step until it
+    # is signalled: it is mid-training when the rescale comes, however
+    # long the successor's warm-up takes on this machine.
+    hold_at = 0 if restarts else int(os.environ.get("SIM_HOLD_AT", "0"))
+    holding = False
     while state.step < total:
         if _signal.get_exit_flag():
             if os.environ.get("SIM_CRASH_ON_TERM"):
@@ -800,6 +808,12 @@ SIM_SCRIPT = textwrap.dedent(
                 handoff.spawn_server(snapshots=handle.snapshots)
             emit("drain %d %d" % (restarts, state.step))
             sys.exit(143)
+        if hold_at and state.step == hold_at:
+            if not holding:
+                holding = True
+                emit("hold %d %d" % (restarts, state.step))
+            time.sleep(pause)
+            continue
         state.w = state.w + 0.1 * (TRUE_W - state.w)
         state.step += 1
         if state.step % 25 == 0:
@@ -835,35 +849,35 @@ def _done_weights(line):
 
 
 def _drive_rescale(runner, log, errors, alloc):
-    """Test-side allocator: once the incumbent is up and stepping,
-    publish the candidate (as the real allocator does, just ahead of
-    the decision) and then the decision itself."""
+    """Test-side allocator: once the incumbent has taken its stretch
+    of steps (and holds for the signal), publish the candidate (as the
+    real allocator does, just ahead of the decision) and then the
+    decision itself."""
     try:
-        deadline = time.monotonic() + 60
+        deadline = time.monotonic() + WAIT_S
         while time.monotonic() < deadline:
-            if os.path.exists(log):
-                with open(log, encoding="utf-8") as f:
-                    if any(
-                        ln.startswith("start 0 ")
-                        for ln in f.read().splitlines()
-                    ):
-                        break
+            if os.path.exists(log) and any(
+                ln[0] == "hold" for ln in _log_lines(log)
+            ):
+                break
             time.sleep(0.05)
         else:
-            errors.append("incumbent never started")
+            errors.append("incumbent never reached its hold")
             return
-        time.sleep(0.8)  # let it take a stretch of steps first
         runner.state.publish_candidate(runner.job_name, alloc, None)
         runner.state.update(runner.job_name, allocation=alloc)
     except Exception as exc:  # noqa: BLE001 - surfaced via errors
         errors.append(repr(exc))
 
 
-# Steps of the simulated job. The incumbent must still be training when
-# the speculative successor — a fresh python whose imports alone take
-# 2.5-3.5 s on a slow box — reports ready (or dies, or is discarded):
-# 160 steps x 0.04 s leaves twice that.
-SIM_TOTAL = 160
+# Steps of the simulated job, and the step at which its first
+# incarnation waits to be signalled: the incumbent is still training
+# when the speculative successor — a fresh python whose imports alone
+# take seconds, many of them beside five other workers — reports ready
+# (or dies, or is discarded). Past a periodic save (every 25 steps),
+# so that a crash has steps to lose.
+SIM_TOTAL = 80
+SIM_HOLD_AT = 40
 
 
 def _run_elastic(
@@ -878,6 +892,9 @@ def _run_elastic(
     monkeypatch.setenv(
         "ADAPTDL_WARMUP_ENABLED", "on" if warm_enabled else ""
     )
+    # The runner gives a successor this long to report ready before it
+    # rescales cold; the successor's death ends the wait at once.
+    monkeypatch.setattr(warmup, "READY_DEADLINE_S", WAIT_S)
     if fault_spec:
         faults.configure(fault_spec, seed=SEED)
     script = tmp_path / "sim.py"
@@ -891,6 +908,7 @@ def _run_elastic(
         + os.environ.get("PYTHONPATH", ""),
         "SIM_LOG": log,
         "SIM_TOTAL_STEPS": str(total),
+        "SIM_HOLD_AT": str(SIM_HOLD_AT),
         "SIM_STEP_SLEEP": "0.04",
     }
     extra.update(sim_env or {})
@@ -914,8 +932,8 @@ def _run_elastic(
     )
     driver.start()
     code = runner.run()
-    driver.join(10)
-    assert not errors, errors
+    driver.join(WAIT_S)
+    assert not driver.is_alive() and not errors, errors
     return code, log, runner
 
 
@@ -1058,7 +1076,7 @@ def test_mispredicted_candidate_discards_warm_successor(
             "mispredicted speculation must never be adopted"
         )
         assert runner._warm is None
-        warm_proc.wait(30)
+        warm_proc.wait(WAIT_S)
         assert warm_proc.returncode != 0
         assert not os.path.exists(workdir)
     finally:
@@ -1101,7 +1119,7 @@ def test_stale_restart_counter_discards_warm_successor(
         # restart index this successor was spawned with.
         runner.restarts += 2
         assert runner._adopt_warm(alloc, None) is None
-        warm_proc.wait(30)
+        warm_proc.wait(WAIT_S)
         assert warm_proc.returncode != 0
     finally:
         runner.supervisor.stop()

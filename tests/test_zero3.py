@@ -210,13 +210,6 @@ def test_zero3_sharded_checkpoint_rescale(tmp_path, monkeypatch):
     assert np.isfinite(float(m2["loss"]))
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_zero3_with_sequence_parallelism():
     """zero3 composes with the seq axis (data=2 x seq=2) and matches
     the replicated run."""

@@ -256,13 +256,6 @@ def test_z3b_matches_replicated(optimizer, accum):
         assert np.isfinite(float(m_z[key])), key
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_z3b_composes_with_sequence_parallelism():
     """Long-context + per-layer FSDP: zero3_blocks on a data=2 x seq=2
     mesh matches the dense trainer on the same mesh — rows stay
@@ -337,13 +330,6 @@ def test_z3b_composes_with_sequence_parallelism():
         )
 
 
-# Old-jax vma semantic gap (ROADMAP: pre-existing tier-1 failures):
-# the pinned jax 0.4.x lacks the varying-manual-axes type system this
-# scenario depends on, so it runs its full (multi-second) computation
-# and then mismatches. Exercised by the nightly soak tier (-m slow)
-# instead of every push; unshimmed gaps only — the cheap axis_size /
-# pcast-vjp shims in _compat.py already flipped 26 sibling tests.
-@pytest.mark.slow
 def test_zero3_lm_with_ring_attention_seq_parallelism():
     """The FLAGSHIP long-context configuration: zero3_lm with
     ``seq_axis`` set runs ring attention over the seq axis while the
