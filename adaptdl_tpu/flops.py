@@ -69,7 +69,11 @@ def transformer_train_flops(
     ``top_k`` expert FFNs plus the router per token — the capacity
     padding all_to_all moves is communication, not model FLOPs. A
     looped model (``loop_passes`` > 1) runs every block and, in
-    training over all its exits, the head once a pass.
+    training over all its exits, the head once a pass. A "kda" or
+    "mla" layer (``layer_types``) is counted at its own projections
+    and mixing (``_kda_layer`` / ``_mla_layer``); a layer with routed
+    experts at its router, the HELD share of the routed experts under
+    even routing, and the shared expert.
     """
     d = config.d_model
     d_ff = config.d_ff
@@ -98,11 +102,29 @@ def transformer_train_flops(
 
     proj = 2 * (4 * d * d)  # fused QKV (3 d^2) + output (d^2), per token
     head = 2 * d * config.vocab_size  # LM head, per token
+    kinds = getattr(config, "layer_types", None) or ()
+    new_kinds = [k for k in kinds if k in ("kda", "mla")]
+    extra_matmul = extra_attn = 0.0
+    if new_kinds:
+        # These layers' mixers are not ``proj`` + softmax attention at
+        # d_model, and their FFN may be routed: counted apart.
+        for layer, kind in enumerate(kinds):
+            if kind not in ("kda", "mla"):
+                continue
+            mix, attn = (
+                _kda_layer(config) if kind == "kda"
+                else _mla_layer(config, seq_len)
+            )
+            extra_matmul += mix - proj
+            extra_attn += attn
+            if config.routed(layer):
+                extra_matmul += _routed_ffn(config) - dense_ffn
     fwd_matmul = tokens * passes * (
         config.num_layers * proj
         + num_dense * dense_ffn
         + num_moe * moe_ffn
         + head
+        + extra_matmul
     )
 
     # Attention contractions: QK^T and PV are each 2*S*d_model FLOPs
@@ -111,10 +133,63 @@ def transformer_train_flops(
     attn_per_token = 2 * (2 * seq_len * d)
     if getattr(config, "causal", True):
         attn_per_token /= 2
-    fwd_attn = tokens * passes * config.num_layers * attn_per_token
+    fwd_attn = tokens * passes * (
+        (config.num_layers - len(new_kinds)) * attn_per_token + extra_attn
+    )
 
     return FlopsBreakdown(
         matmul=3.0 * fwd_matmul, attention=3.0 * fwd_attn
+    )
+
+
+def _kda_layer(config) -> tuple[float, float]:
+    """(projection FLOPs, mixing FLOPs) a token of one "kda" layer:
+    q, k, v and out at ``heads * head_dim``, the two low-rank gate
+    pairs and beta; the chunked delta rule's products a chunk (its own
+    four and the four that carry the state), as
+    ``benchmark/kda.py:forward_flops_per_token`` counts them."""
+    from adaptdl_tpu.ops.kda import CHUNK as chunk  # where it is used
+
+    d, heads = config.d_model, config.num_heads
+    hd, rank = config.attention_head_dim, config.kda_gate_rank
+    width = heads * hd
+    proj = 2 * (
+        3 * d * width + 2 * (d * rank + rank * width) + d * heads + width * d
+    )
+    mixing = 2 * heads * (4 * chunk * hd + 3 * hd * hd + chunk * hd)
+    return float(proj), float(mixing)
+
+
+def _mla_layer(config, seq_len: int) -> tuple[float, float]:
+    """(projection FLOPs, attention FLOPs) a token of one "mla"
+    layer: q, kv_a, kv_b and out; QK^T at the q/k width and PV at the
+    v width, the causal half."""
+    d, heads = config.d_model, config.num_heads
+    qk = config.qk_nope_head_dim + config.qk_rope_head_dim
+    proj = 2 * (
+        d * heads * qk
+        + d * (config.kv_lora_rank + config.qk_rope_head_dim)
+        + config.kv_lora_rank * heads
+        * (config.qk_nope_head_dim + config.v_head_dim)
+        + heads * config.v_head_dim * d
+    )
+    attn = 2 * seq_len * heads * (qk + config.v_head_dim)
+    if getattr(config, "causal", True):
+        attn /= 2
+    return float(proj), float(attn)
+
+
+def _routed_ffn(config) -> float:
+    """FLOPs a token of a routed layer's FFN: the router over all
+    experts, ``top_k * held / total`` gated experts (even routing),
+    the shared expert."""
+    d = config.d_model
+    held = config.experts_held or config.experts_total
+    experts = config.experts_top_k * held / config.experts_total
+    return float(
+        2 * d * config.experts_total
+        + experts * 2 * 3 * d * config.d_expert
+        + 2 * 3 * d * config.d_shared_expert
     )
 
 

@@ -397,6 +397,20 @@ def rows_capacity(
 # again. A step that places more walks the rest of the plan too and
 # drops nothing.
 ROWS_BOUND_FACTOR = 2.5
+# Where the worst case is at least this many times the usual rows (one
+# chip's share of 8 of 256 experts: 9.4 times), a buffer of the worst
+# case is mostly rows nobody is ever sent, alive and walked on every
+# step (three of 594 MiB a layer, which the compiler refuses beside
+# 602 M parameters): there the plan is walked in pieces of
+# ``rows_bound`` rows, as many as hold active tiles (the usual step:
+# one). Why the one pass stays below it: a share whose router SETTLES
+# on its held experts (16 of 128: 91-98% of the assignments within
+# twenty steps, 2.8 bounds) would fill three pieces on every step — a
+# loop around three times the kernel calls for the rows of one pass,
+# each call over a third of the rows, which the accepted reader of
+# the grouped products' roofline prices at all of them (PERF.md
+# section 7, PR 46). Between 2.8 and 9.4 nothing has been measured.
+ROWS_PIECES_FROM = 4
 
 
 def rows_bound(
@@ -414,23 +428,46 @@ def rows_bound(
     bound, a router that leaves even routing would pay that on every
     step (one chip's share of 16 of 128 experts does, within twenty
     steps: PERF.md section 6, PR 40), so there the layer keeps the one
-    pass."""
+    pass — unless the worst case is ``ROWS_PIECES_FROM`` times the
+    bound or more: then the plan (``rows_planned`` long) is walked in
+    pieces of the bound, and a router that leaves even routing pays
+    for the pieces it fills."""
     capacity = rows_capacity(tokens, top_k, experts_held, tile)
     expected = tokens * top_k * experts_held / experts_total
     usual = math.ceil(ROWS_BOUND_FACTOR * expected) + experts_held * (tile - 1)
     bound = -(-usual // tile) * tile
-    return bound if capacity - bound <= bound < capacity else capacity
+    if capacity - bound <= bound < capacity:
+        return bound
+    return bound if capacity >= ROWS_PIECES_FROM * bound else capacity
+
+
+def rows_planned(
+    tokens: int, top_k: int, experts_held: int, experts_total: int,
+    tile: int,
+) -> int:
+    """How long the plan's row arrays are: ``rows_capacity``, or,
+    where the plan is walked in pieces of ``rows_bound`` rows, the
+    next whole number of pieces."""
+    capacity = rows_capacity(tokens, top_k, experts_held, tile)
+    bound = rows_bound(tokens, top_k, experts_held, experts_total, tile)
+    if capacity - bound <= bound:
+        return capacity
+    return -(-capacity // bound) * bound
 
 
 def plan_rows(
-    experts, first_expert: int, experts_held: int, tile: int
+    experts, first_expert: int, experts_held: int, tile: int,
+    rows: int | None = None,
 ) -> RowPlan:
     """Order the assignments by held expert. Integer work only: two
     stable sorts of ``tokens * top_k`` keys (the order, and its
-    inverse) and a few gathers; no scatter, no one-hot over rows."""
+    inverse) and a few gathers; no scatter, no one-hot over rows.
+    ``rows``: the plan's length where it is more than
+    ``rows_capacity`` (``rows_planned``)."""
     tokens, top_k = experts.shape
     count = tokens * top_k
-    rows = rows_capacity(tokens, top_k, experts_held, tile)
+    if rows is None:
+        rows = rows_capacity(tokens, top_k, experts_held, tile)
     local = experts.reshape(count) - first_expert
     held = (local >= 0) & (local < experts_held)
     key = jnp.where(held, local, experts_held).astype(jnp.int32)
@@ -585,16 +622,21 @@ def _past_the_bound(bound: int, plan: RowPlan, first, rest):
     A ``while_loop`` that runs once or not at all, in place of a
     ``cond``: the TPU compiler gives the two branches of a ``cond``
     memory of their own each and copies what one of them only passes
-    on, where a loop's state is updated in place."""
+    on, where a loop's state is updated in place. Where the other rows
+    are more than ``bound`` (``rows_planned``: then a whole number of
+    pieces), the loop takes them ``bound`` at a time while active
+    tiles lie ahead."""
     rows = plan.row_token.shape[0]
     if bound == rows:
         return first
     rows_active = plan.active_tiles[0] * _tile(plan)
+    piece = rows - bound if rows - bound <= bound else bound
+    assert (rows - bound) % piece == 0
 
     def add_the_rest(state):
         start, total = state
-        return start + (rows - bound), jax.tree.map(
-            jnp.add, total, rest(start, rows - bound)
+        return start + piece, jax.tree.map(
+            jnp.add, total, rest(start, piece)
         )
 
     return lax.while_loop(
@@ -679,7 +721,8 @@ def routed_experts(
     those walked: 0 by construction, counted, not assumed),
     ``rows_active`` (the plan's active tiles in rows: what has to fit
     ``rows_bound``), ``rows_walked`` (rows the glue passed over:
-    ``rows_bound``, or ``rows_capacity`` where the plan did not fit)
+    ``rows_bound``, or ``rows_capacity`` where the plan did not fit,
+    or the pieces of ``rows_bound`` rows it took)
     and ``fell_back`` (1 where it did not), beside the router's own
     result, ``experts`` and ``weights`` ``[tokens,
     top_k]``, for whoever checks the routing itself.
@@ -695,7 +738,9 @@ def routed_experts(
     assert (bias is None) == (router_kind == "softmax")
     assert 0 <= first_expert <= experts_total - experts_held
     tile = gmm.tile_rows(tokens * min(top_k, experts_held))
-    capacity = rows_capacity(tokens, top_k, experts_held, tile)
+    capacity = rows_planned(
+        tokens, top_k, experts_held, experts_total, tile
+    )
     bound = rows_bound(tokens, top_k, experts_held, experts_total, tile)
     trace.event(
         "moe.schedule",
@@ -720,12 +765,15 @@ def routed_experts(
             x, router, bias, top_k, norm_eps, scale
         )
     plan = lax.stop_gradient(
-        plan_rows(experts, first_expert, experts_held, tile)
+        plan_rows(experts, first_expert, experts_held, tile, capacity)
     )
     y = expert_rows(bound, x, weights, w_gate, w_up, w_down, plan)
     # Counted from the plan and the rows the step walked, not assumed.
     rows_active = (plan.active_tiles[0] * tile).astype(jnp.int32)
-    walked = jnp.where(rows_active > bound, capacity, bound)
+    if capacity - bound > bound:  # walked in pieces of ``bound`` rows
+        walked = jnp.maximum(-(-rows_active // bound), 1) * bound
+    else:
+        walked = jnp.where(rows_active > bound, capacity, bound)
     held = jnp.sum(plan.dest < capacity, dtype=jnp.int32)
     placed = jnp.sum(
         (plan.row_assignment >= 0) & (jnp.arange(capacity) < walked),

@@ -170,6 +170,59 @@ class TransformerConfig:
     # 1 = every block once, no gate: the tree and program of before.
     # Dense blocks without dropout only (``looped_lm_loss_fn``).
     loop_passes: int = 1
+    # False: no rotary turn on any layer's q and k (a model whose
+    # recurrent layers order the tokens has no positions).
+    rope: bool = True
+    # The "kda" mixer kind of ``layer_types``: gated delta-rule linear
+    # attention with a per-channel decay (ops/kda.py), ``num_heads``
+    # heads of ``attention_head_dim`` for q, k and v, depthwise causal
+    # convolutions of ``conv_kernel`` taps on each, the decay and the
+    # output gate through low-rank pairs of ``kda_gate_rank``, the
+    # recurrence carried between chunks of ``ops.kda.CHUNK`` tokens. No
+    # sequence-parallel path: the state crosses the whole row.
+    kda_gate_rank: int = 0
+    # The "mla" mixer kind: latent attention without a query
+    # bottleneck. ``q`` is ``num_heads`` heads of ``qk_nope_head_dim +
+    # qk_rope_head_dim``; ``kv_a`` maps to ``kv_lora_rank`` (normed)
+    # and ONE shared key part of ``qk_rope_head_dim``; ``kv_b`` expands
+    # the latent to each head's ``qk_nope_head_dim`` of key and
+    # ``v_head_dim`` of value. Takes ``rope=False`` only.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A shared expert beside the routed ones: one gated FFN of this
+    # width on EVERY token of a routed layer, unweighted, added to the
+    # routed experts' result. 0 = none.
+    d_shared_expert: int = 0
+
+    def __post_init__(self):
+        kinds = self.layer_types or ()
+        if "kda" in kinds and self.seq_axis is not None:
+            raise ValueError(
+                "seq_axis: a 'kda' layer has no sequence-parallel path "
+                "(its state crosses the whole row)"
+            )
+        if "kda" in kinds and self.kda_gate_rank <= 0:
+            raise ValueError("kda_gate_rank must be set for 'kda' layers")
+        if "sparse_attention" in kinds and not self.rope:
+            raise ValueError(
+                "rope: the 'sparse_attention' mixer always turns q and k"
+            )
+        if "mla" in kinds:
+            if self.rope:
+                raise ValueError(
+                    "rope: the 'mla' mixer runs without positions only "
+                    "(set rope=False)"
+                )
+            for name in ("kv_lora_rank", "qk_nope_head_dim", "v_head_dim"):
+                if getattr(self, name) <= 0:
+                    raise ValueError(f"{name} must be set for 'mla' layers")
+        if self.d_shared_expert > 0 and self.experts_total <= 0:
+            raise ValueError(
+                "d_shared_expert: a shared expert stands beside routed "
+                "experts (experts_total is 0)"
+            )
 
     @property
     def attention_head_dim(self) -> int:
@@ -258,8 +311,9 @@ class Attention(nn.Module):
             name="qkv",
         )(x)
         q, k, v = jnp.moveaxis(qkv, -3, 0)  # each [b, s, h, d]
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         # attention_fn's contract is [b, h, s, d]. The flash kernels
         # index [b * h, d, s], which is how XLA lays these arrays out
         # by itself, and swap into it themselves: their swap and this
@@ -362,8 +416,9 @@ class GroupedQueryAttention(nn.Module):
             k = nn.RMSNorm(
                 epsilon=cfg.norm_eps, dtype=cfg.dtype, name="k_norm"
             )(k)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
         attn = cfg.attention_fn
@@ -546,19 +601,261 @@ class ShortConv(nn.Module):
         )(gate_c * mixed)
 
 
-class GatedFFN(nn.Module):
-    """SwiGLU: ``down(silu(gate x) * up x)``, no biases."""
+def _dense_f32(x, kernel):
+    """``x @ kernel`` on operands in ``x``'s dtype, accumulated and
+    returned in float32."""
+    return jnp.einsum(
+        "...d,de->...e", x, kernel.astype(x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
+KDA_L2_EPS = 1e-6
+
+
+class KDA(nn.Module):
+    """Gated delta-rule linear attention with a per-channel decay
+    (``ops.kda``). ``q, k, v = silu(conv(x W))`` (depthwise causal
+    convolutions, no bias), q and k L2-normalised a head; the
+    log-decay ``g = -exp(A_log[h]) softplus((x Wf_a) Wf_b + dt_bias)``
+    a channel and the step ``beta = sigmoid(x Wb)`` a head, both
+    float32; the state is zero at a row's start and crosses the whole
+    row (packed documents included). ``y = (rmsnorm_head(o) * scale *
+    sigmoid((x Wg_a) Wg_b)) Wo``. Journals ``kda.schedule`` where it
+    is traced."""
 
     config: TransformerConfig
 
     @nn.compact
+    def __call__(self, x, positions):
+        from adaptdl_tpu.ops import kda as kda_op
+
+        del positions  # the recurrence orders the tokens
+        cfg = self.config
+        heads, head_dim = cfg.num_heads, cfg.attention_head_dim
+        width, rank = heads * head_dim, cfg.kda_gate_rank
+        by_head = x.shape[:2] + (heads, head_dim)
+        fan_in = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        qkv = nn.DenseGeneral(
+            (3, width), axis=-1, dtype=cfg.dtype, use_bias=False,
+            name="qkv",
+        )(x)
+        taps = self.param(
+            "conv",
+            nn.initializers.variance_scaling(
+                1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+                batch_axis=(0,),
+            ),
+            (3, cfg.conv_kernel, width), jnp.float32,
+        )
+
+        def low_rank(name):
+            inner = nn.Dense(
+                rank, dtype=cfg.dtype, use_bias=False, name=f"{name}_a"
+            )(x)
+            return inner, self.param(
+                f"{name}_b", fan_in, (rank, width), jnp.float32
+            )
+
+        a_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+            ),
+            (heads,),
+        )
+
+        def dt_bias_init(key, shape):
+            # softplus(dt_bias) log-uniform in [1e-3, 1e-1].
+            dt = jnp.exp(
+                jax.random.uniform(
+                    key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)
+                )
+            )
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        dt_bias = self.param("dt_bias", dt_bias_init, (width,))
+        decay_inner, decay_out = low_rank("f")
+        beta = jax.nn.sigmoid(
+            _dense_f32(
+                x, self.param("beta", fan_in, (cfg.d_model, heads),
+                              jnp.float32)
+            )
+        )
+
+        def unit(t):  # L2-normalised a head, statistics in float32
+            t32 = t.astype(jnp.float32)
+            return (
+                t32 * jax.lax.rsqrt(
+                    jnp.sum(t32 * t32, -1, keepdims=True) + KDA_L2_EPS
+                )
+            ).astype(cfg.dtype)
+
+        def prepare(q, k, v, _, beta, taps, decay_out, dt_bias, a_log):
+            """A group of heads: the projections' [b, s, h, d] into the
+            rule's operands; ``taps`` [h, 3, taps, d], ``decay_out``
+            [h, rank, d], ``dt_bias`` [h, d], ``a_log`` [h]."""
+            taps = taps.astype(cfg.dtype)
+
+            def conv(z, which):
+                n, seq_len = taps.shape[2], z.shape[1]
+                padded = jnp.pad(z, ((0, 0), (n - 1, 0), (0, 0), (0, 0)))
+                return nn.silu(sum(
+                    taps[:, which, j] * padded[:, j:j + seq_len]
+                    for j in range(n)
+                ))
+
+            decay = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                jnp.einsum(
+                    "bsr,hrd->bshd", decay_inner,
+                    decay_out.astype(decay_inner.dtype),
+                    preferred_element_type=jnp.float32,
+                ) + dt_bias
+            )
+            return unit(conv(q, 0)), unit(conv(k, 1)), conv(v, 2), decay, beta
+
+        def per_head(t, axis):  # the heads' axis first, heads apart
+            shape = t.shape[:axis] + (heads, head_dim) + t.shape[axis + 1:]
+            return jnp.moveaxis(t.reshape(shape), axis, 0)
+
+        q, k, v = (qkv[:, :, i].reshape(by_head) for i in range(3))
+        out = kda_op.kda(
+            q, k, v, None, beta, prepare=prepare,
+            per_head=(
+                per_head(taps, 2), per_head(decay_out, 1),
+                dt_bias.reshape(heads, head_dim), a_log,
+            ),
+        )  # [b, s, heads, head_dim]
+        out = nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=cfg.dtype, name="o_norm"
+        )(out)
+        gate = jax.nn.sigmoid(_dense_f32(*low_rank("g"))).reshape(out.shape)
+        out = (out * gate.astype(cfg.dtype)).reshape(
+            x.shape[:2] + (width,)
+        )
+        return nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
+        )(out)
+
+
+class LatentAttention(nn.Module):
+    """Latent attention without a query bottleneck and without
+    positions: ``q = x Wq`` as heads of ``qk_nope_head_dim +
+    qk_rope_head_dim``; ``[c, k_pe] = x Wkv_a``; ``[k_nope, v] =
+    rmsnorm(c) Wkv_b`` a head; ``k = [k_nope, k_pe]``, the one
+    ``k_pe`` on every head; causal softmax attention at ``q``'s width,
+    values and output at ``v_head_dim``. ``attention_fn`` must take a
+    v narrower than q and k (``ops.flash_attention`` does). Journals
+    ``mla.schedule`` where it is traced."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        del positions
+        cfg = self.config
+        if cfg.seq_axis is not None:
+            raise ValueError(
+                "seq_axis: the 'mla' mixer has no sequence-parallel path"
+            )
+        heads, nope, pe = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, v_dim = cfg.kv_lora_rank, cfg.v_head_dim
+        q = nn.DenseGeneral(
+            (heads, nope + pe), axis=-1, dtype=cfg.dtype, use_bias=False,
+            name="q",
+        )(x)
+        kv_a = nn.Dense(
+            rank + pe, dtype=cfg.dtype, use_bias=False, name="kv_a"
+        )(x)
+        latent = nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=cfg.dtype, name="kv_norm"
+        )(kv_a[..., :rank])
+        kv = nn.DenseGeneral(
+            (heads, nope + v_dim), axis=-1, dtype=cfg.dtype,
+            use_bias=False, name="kv_b",
+        )(latent)
+        k = kv[..., :nope]
+        if pe:
+            k = jnp.concatenate(
+                [k, jnp.broadcast_to(
+                    kv_a[:, :, None, rank:], k.shape[:3] + (pe,)
+                )],
+                axis=-1,
+            )
+        v = kv[..., nope:]
+        attn = cfg.attention_fn
+        # Heads in runs, one call a run, as many as the flash kernels
+        # want at once (``ops.flash_attention.heads_a_call``, at the
+        # attention's own blocks where it says them: past 16k keys the
+        # backward writes a float32 dQ a key chunk for the heads it is
+        # given; at 16 384 keys of 192: four), and a run's is let go
+        # before the next run's. Any ``attention_fn`` is asked so: a
+        # ``functools.partial`` of the kernel says nothing of itself
+        # but its blocks.
+        run = heads
+        if attn is not None:
+            from adaptdl_tpu.ops.flash_attention import heads_a_call
+
+            blocks = {
+                k: v for k, v in getattr(attn, "keywords", {}).items()
+                if k in ("block_q", "block_k")
+            }
+            run = getattr(attn, "heads_a_call", heads_a_call)(
+                heads, x.shape[1], nope + pe, v_dim,
+                jnp.dtype(cfg.dtype).itemsize, **blocks,
+            )
+        trace.event(
+            "mla.schedule",
+            heads=heads,
+            heads_a_call=run,
+            qk_width=nope + pe,
+            v_width=v_dim,
+            latent_rank=rank,
+            seq_len=x.shape[1],
+            positions="none",
+            dtype=jnp.dtype(cfg.dtype).name,
+            attention="attention_fn" if attn is not None
+            else "plain causal attention",
+        )
+        if attn is None:
+            from functools import partial
+
+            attn = partial(causal_attention, causal=cfg.causal)
+        # The scope the flash kernels' readers know the forward by.
+        with jax.named_scope("attention"):
+            out = jnp.concatenate(
+                [
+                    attn(*(
+                        jnp.swapaxes(t[:, :, at:at + run], 1, 2)
+                        for t in (q, k, v)
+                    ))
+                    for at in range(0, heads, run)
+                ],
+                axis=1,
+            )  # [b, h, s, v_dim]
+        out = jnp.swapaxes(out, 1, 2).reshape(
+            x.shape[:-1] + (heads * v_dim,)
+        )
+        return nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
+        )(out)
+
+
+class GatedFFN(nn.Module):
+    """SwiGLU: ``down(silu(gate x) * up x)``, no biases."""
+
+    config: TransformerConfig
+    width: int | None = None  # ``d_ff`` unless given
+
+    @nn.compact
     def __call__(self, x):
         cfg = self.config
+        width = self.width or cfg.d_ff
         gate = nn.Dense(
-            cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_gate"
+            width, dtype=cfg.dtype, use_bias=False, name="ff_gate"
         )(x)
         up = nn.Dense(
-            cfg.d_ff, dtype=cfg.dtype, use_bias=False, name="ff_up"
+            width, dtype=cfg.dtype, use_bias=False, name="ff_up"
         )(x)
         return nn.Dense(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="ff_down"
@@ -570,7 +867,10 @@ class RoutedFFN(nn.Module):
     (``models.moe.routed_experts``): the router over all
     ``experts_total`` experts in float32, gated experts of width
     ``d_expert`` for the ``experts_held`` held here. The expert bias
-    shifts the selection only and no gradient reaches it. The layer's
+    shifts the selection only and no gradient reaches it. With
+    ``d_shared_expert`` a shared expert (``GatedFFN`` of that width,
+    ``shared``) runs on every token and is added unweighted; the
+    tokens it multiplied are sown as ``shared_rows``. The layer's
     load counters are sown into the "moe_load" collection, the
     router's choice (``experts``, ``weights``) into "moe_routing"."""
 
@@ -630,7 +930,14 @@ class RoutedFFN(nn.Module):
                 else "moe_load",
                 name, value,
             )
-        return y.reshape(x.shape).astype(cfg.dtype)
+        y = y.reshape(x.shape).astype(cfg.dtype)
+        if cfg.d_shared_expert > 0:
+            self.sow(
+                "moe_load", "shared_rows",
+                jnp.int32(math.prod(x.shape[:-1])),
+            )
+            y = y + GatedFFN(cfg, cfg.d_shared_expert, name="shared")(x)
+        return y
 
 
 class MoEFFN(nn.Module):
@@ -717,10 +1024,14 @@ def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
         return ShortConv(cfg, name="short_conv")
     if kind == "sparse_attention":
         return SparseAttention(cfg, name="attention")
+    if kind == "kda":
+        return KDA(cfg, name="kda")
+    if kind == "mla":
+        return LatentAttention(cfg, name="mla")
     if kind != "full_attention":
         raise ValueError(
-            f"layer_types[{layer}] must be 'full_attention', 'conv' or "
-            f"'sparse_attention', got {kind!r}"
+            f"layer_types[{layer}] must be 'full_attention', 'conv', "
+            f"'sparse_attention', 'kda' or 'mla', got {kind!r}"
         )
     if cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm:
         return GroupedQueryAttention(cfg, name="attention")
@@ -843,13 +1154,17 @@ def _remat_ladder(config: TransformerConfig, tokens_shape):
         config.num_layers * config.loop_passes * tokens
         * jnp.dtype(config.dtype).itemsize
     )
+    priced = [
+        (rung, rung_names, per_width * width)
+        for rung, rung_names, width in ladder
+    ]
     names, rungs, spent = (), [], 0
-    for rung, rung_names, width in ladder:
-        if spent + per_width * width > left:
+    for rung, rung_names, cost in priced:
+        if spent + cost > left:
             break
         names += rung_names
         rungs.append(rung)
-        spent += per_width * width
+        spent += cost
     return names, {
         "rungs": ",".join(rungs), "rung_bytes": spent,
         "budget_bytes": left, "bytes_limit": budget.bytes_limit,
@@ -887,6 +1202,13 @@ def block_remat(config: TransformerConfig, tokens_shape=None):
         from adaptdl_tpu.ops.sparse_attention import SAVED_NAMES
 
         saved_names += SAVED_NAMES
+    if "kda" in (config.layer_types or ()):
+        # The delta rule's output (one activation of heads x head_dim a
+        # layer): the block's recomputation then does not run the rule
+        # again before the rule's own backward does.
+        from adaptdl_tpu.ops.kda import SAVED_OUT as KDA_OUT
+
+        saved_names += (KDA_OUT,)
     rung_names, ladder_attrs = _remat_ladder(config, tokens_shape)
     saved_names += rung_names
     policy = jax.checkpoint_policies.save_only_these_names(*saved_names)
@@ -1115,7 +1437,9 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
     ``apply(..., mutable=["moe_load"])``) stacked over the routed
     layers in order: ``{"held_rows": int32 [layers, held], "left_out":
     [layers], "dropped": [layers], "rows_active": [layers],
-    "rows_walked": [layers], "fell_back": [layers]}``."""
+    "rows_walked": [layers], "fell_back": [layers]}``, and
+    ``"shared_rows": [layers]`` where the layers have a shared
+    expert."""
     sown = mutated["moe_load"]
     return {
         name: jnp.stack(
@@ -1128,7 +1452,7 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
         for name in (
             "held_rows", "left_out", "dropped", "rows_active",
             "rows_walked", "fell_back",
-        )
+        ) + (("shared_rows",) if config.d_shared_expert > 0 else ())
     }
 
 
@@ -1169,7 +1493,9 @@ def sparse_select_counters(config: TransformerConfig, mutated) -> dict:
     }
 
 
-def routed_lm_loss_fn(model: TransformerLM):
+def routed_lm_loss_fn(
+    model: TransformerLM, head_chunk_rows: int | None = None
+):
     """Next-token cross-entropy of a model with routed experts, plus
     the indexer's loss (mean over sparse layers and tokens) where the
     model has sparse attention layers;
@@ -1177,19 +1503,38 @@ def routed_lm_loss_fn(model: TransformerLM):
     {"moe.load": counters})``: a loss_fn that returns such a pair has
     the counters summed over the step by the trainer and journalled as
     ``moe.load`` events where it pulls its statistics
-    (``ElasticTrainer.run_step``)."""
+    (``ElasticTrainer.run_step``). With ``head_chunk_rows`` the head
+    is streamed that many rows at a time (``ops.chunked_xent.
+    weighted_xent_sum``: the same operands and float32 accumulation,
+    its gradients formed in the forward pass) and no ``[tokens,
+    vocab]`` array exists in the step."""
 
     sparse = sparse_layers(model.config)
+    cfg = model.config
 
     def loss_fn(params, batch, rng):
-        logits, mutated = model.apply(
+        out, mutated = model.apply(
             {"params": params}, batch["inputs"], train=True, rng=rng,
+            return_hidden=head_chunk_rows is not None,
             mutable=["moe_load", "indexer_loss", "sparse_select"]
             if sparse else ["moe_load"],
         )
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, batch["targets"]
-        ).mean()
+        if head_chunk_rows is None:
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                out, batch["targets"]
+            ).mean()
+        else:
+            from adaptdl_tpu.ops.chunked_xent import weighted_xent_sum
+
+            rows = out.reshape(-1, cfg.d_model)
+            loss, _ = weighted_xent_sum(
+                rows,
+                params["embed"]["embedding"] if cfg.tie_embeddings
+                else params["lm_head"],
+                batch["targets"].reshape(-1),
+                jnp.full(rows.shape[:1], 1.0 / rows.shape[0], jnp.float32),
+                head_chunk_rows,
+            )
         counters = {"moe.load": moe_load_counters(model.config, mutated)}
         if sparse:
             selected = sparse_select_counters(model.config, mutated)

@@ -221,9 +221,12 @@ def _schedule(
     block_q: int,
     block_k: int,
     diag_rows: int | None = None,
+    v_dim: int | None = None,
 ) -> _Schedule:
     """``diag_rows``: the most keys of one update inside a tile; the
-    forward's ``_DIAG_ROWS`` unless the backward passes its own."""
+    forward's ``_DIAG_ROWS`` unless the backward passes its own.
+    ``v_dim``: the width of a head of v (and of the output) where it
+    is not q's and k's ``head_dim``."""
     block_q = min(block_q, seq_len)
     block_k = min(block_k, seq_len)
     assert seq_len % block_q == 0 and seq_len % block_k == 0, (
@@ -241,7 +244,7 @@ def _schedule(
             _LANES, tile, _DIAG_ROWS if diag_rows is None else diag_rows
         )
     # K and V of one head, each double-buffered by the pipeline.
-    bytes_per_key = 2 * 2 * head_dim * itemsize
+    bytes_per_key = 2 * (head_dim + (v_dim or head_dim)) * itemsize
     chunk_k = _fuse(
         tile, seq_len, min(seq_len, _KV_VMEM_BUDGET // bytes_per_key)
     )
@@ -294,7 +297,8 @@ def _fwd_kernel(
     the causal diagonal unmasked; the tile the diagonal crosses comes
     last, in ``diag``-key pieces, each only for the queries at or
     after it, and only its corner block is masked."""
-    _, head_dim, tile = q_ref.shape
+    tile = q_ref.shape[2]
+    v_dim = v_ref.shape[1]  # the accumulator's and the output's rows
     chunk_tiles = k_ref.shape[2] // tile
     rest = list(rest)
     lse_ref = rest.pop(0) if with_lse else None
@@ -364,7 +368,7 @@ def _fwd_kernel(
             carry = (
                 jnp.full((1, tile), NEG_INF, jnp.float32),
                 jnp.zeros((1, tile), jnp.float32),
-                jnp.zeros((head_dim, tile), jnp.float32),
+                jnp.zeros((v_dim, tile), jnp.float32),
             )
         else:
 
@@ -389,11 +393,13 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
-    """q/k/v: [bh, d, seq] -> (out [bh, d, seq], lse [bh, 1, seq] or
-    None)."""
+    """q/k: [bh, d, seq], v: [bh, dv, seq] -> (out [bh, dv, seq], lse
+    [bh, 1, seq] or None)."""
     bh, head_dim, seq_len = q.shape
+    v_dim = v.shape[1]
     sched = _schedule(
-        seq_len, head_dim, q.dtype.itemsize, block_q, block_k
+        seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
+        v_dim=v_dim,
     )
     tile, diag, chunk_k = sched
     num_chunks = seq_len // chunk_k
@@ -433,11 +439,16 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         (1, head_dim, tile), lambda b, qi, ci: (b, 0, qi)
     )
     kv_spec = pl.BlockSpec((1, head_dim, chunk_k), kv_index)
+    v_spec = pl.BlockSpec((1, v_dim, chunk_k), kv_index)
     # Inside a shard_map (the trainer's data/seq axes) pallas outputs
     # must declare how they vary: the same way q does.
     vma = jax.typeof(q).vma
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)]
+    out_specs = [
+        pl.BlockSpec((1, v_dim, tile), lambda b, qi, ci: (b, 0, qi))
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((bh, v_dim, seq_len), q.dtype, vma=vma)
+    ]
     if with_lse:
         # One float32 per query row, rows along lanes.
         out_specs.append(
@@ -451,12 +462,12 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         scratch_shapes = [
             pltpu.VMEM((1, tile), jnp.float32),  # running max
             pltpu.VMEM((1, tile), jnp.float32),  # running sum
-            pltpu.VMEM((head_dim, tile), jnp.float32),  # accumulator
+            pltpu.VMEM((v_dim, tile), jnp.float32),  # accumulator
         ]
     out, *lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
@@ -484,7 +495,8 @@ def flash_attention(
     """Blockwise exact attention.
 
     Args:
-      q, k, v: ``[batch, heads, seq, head_dim]``.
+      q, k, v: ``[batch, heads, seq, head_dim]``; v's heads may have
+        another width than q's and k's, and the result has v's.
       causal: apply the causal mask.
       scale: logit scale; default ``head_dim ** -0.5``.
       block_q / block_k: the caller's tile granularity (must divide
@@ -493,12 +505,12 @@ def flash_attention(
         update cover (module docstring).
 
     Returns:
-      ``[batch, heads, seq, head_dim]``, dtype of ``q``.
+      ``[batch, heads, seq, v's head_dim]``, dtype of ``q``.
     """
     *_, out, _ = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, with_lse=False
     )
-    return _from_kernel(out, q.shape)
+    return _from_kernel(out, v.shape)
 
 
 def _to_kernel(x):
@@ -547,7 +559,7 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
     out = checkpoint_name(out, SAVED_OUT)
     lse = checkpoint_name(lse, SAVED_LSE)
     residuals = (*operands, out, lse)
-    return _from_kernel(out, q.shape), residuals
+    return _from_kernel(out, v.shape), residuals
 
 
 def _bwd_kernel(
@@ -582,7 +594,7 @@ def _bwd_kernel(
     product. Every update covers ``diag`` keys: a traced loop walks
     those below the diagonal tile unmasked; the diagonal tile's pieces
     take only the queries at or after them, corner block masked."""
-    _, head_dim, tile = q_ref.shape
+    _, head_dim, tile = q_ref.shape  # v and dO may be wider or narrower
     chunk_k = k_ref.shape[2]
     chunk_tiles, per_tile = chunk_k // tile, tile // diag
     ci, qi = pl.program_id(1), pl.program_id(2)
@@ -677,12 +689,13 @@ def _bwd_kernel(
 
 
 def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
-    """q/k/v/do/out: [bh, d, seq]; lse: [bh, 1, seq] float32 ->
-    (dq, dk, dv), each [bh, d, seq] in its primal's dtype."""
+    """q/k: [bh, d, seq]; v/do/out: [bh, dv, seq]; lse: [bh, 1, seq]
+    float32 -> (dq, dk, dv), each as its primal."""
     bh, head_dim, seq_len = q.shape
+    v_dim = v.shape[1]
     sched = _schedule(
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
-        diag_rows=_BWD_DIAG_ROWS,
+        diag_rows=_BWD_DIAG_ROWS, v_dim=v_dim,
     )
     tile, diag, chunk_k = sched
     num_chunks = seq_len // chunk_k
@@ -721,6 +734,12 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
     kv_spec = pl.BlockSpec(
         (1, head_dim, chunk_k), lambda b, ci, qi: (b, 0, ci)
     )
+    v_spec = pl.BlockSpec(
+        (1, v_dim, chunk_k), lambda b, ci, qi: (b, 0, ci)
+    )
+    do_spec = pl.BlockSpec(
+        (1, v_dim, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
+    )
     vma = jax.typeof(q).vma
     # dQ of a query tile is summed over the key chunks: the gradient
     # itself while K/V are resident; beyond, one float32 partial per
@@ -745,7 +764,7 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
     if seq_len > tile:
         turned = [
             pltpu.VMEM((chunk_k, head_dim), k.dtype),
-            pltpu.VMEM((chunk_k, head_dim), v.dtype),
+            pltpu.VMEM((chunk_k, v_dim), v.dtype),
         ]
     dq, dk, dv = pl.pallas_call(
         functools.partial(
@@ -756,8 +775,8 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
             num_chunks=num_chunks,
         ),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
-        out_specs=[dq_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, v_spec, do_spec, do_spec, lse_spec],
+        out_specs=[dq_spec, kv_spec, v_spec],
         out_shape=[
             dq_shape,
             jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
@@ -767,7 +786,7 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
             pltpu.VMEM((1, tile), jnp.float32),  # delta of the tile
             pltpu.VMEM((head_dim, tile), jnp.float32),  # dQ of the tile
             pltpu.VMEM((head_dim, chunk_k), jnp.float32),  # dK of the chunk
-            pltpu.VMEM((head_dim, chunk_k), jnp.float32),  # dV of the chunk
+            pltpu.VMEM((v_dim, chunk_k), jnp.float32),  # dV of the chunk
         ]
         + turned,
         compiler_params=pltpu.CompilerParams(
@@ -796,27 +815,55 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
         dQ = dS K * scale ;  dK = dS^T Q * scale
     """
     q, k, v, out, lse = residuals
-    head_dim = g.shape[3]
+    head_dim = q.shape[1]
     resolved_scale = head_dim**-0.5 if scale is None else float(scale)
     grads = _bwd_pallas(
         q, k, v, _to_kernel(g), out, lse,
         causal, resolved_scale, block_q, block_k,
     )
-    return tuple(_from_kernel(x, g.shape) for x in grads)
+    return tuple(
+        _from_kernel(x, g.shape[:3] + (x.shape[1],)) for x in grads
+    )
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def heads_a_call(
+    heads: int, seq_len: int, head_dim: int, v_dim: int, itemsize: int,
+    block_q: int = 128, block_k: int = 128,
+) -> int:
+    """How many heads (a divisor of ``heads``) a caller that can
+    split them should give one call. All of them while K and V of a
+    head stay in VMEM; beyond, the backward writes one float32 dQ a
+    key chunk (``_bwd_pallas``), ``chunks * 4 / itemsize`` times q for
+    the heads it is given: a call's partials are held to the bytes of
+    q itself, all heads. (Blocks that do not divide the row are cut
+    to ones that do: this asks about memory and refuses no shape.)"""
+    sched = _schedule(
+        seq_len, head_dim, itemsize, math.gcd(block_q, seq_len),
+        math.gcd(block_k, seq_len), diag_rows=_BWD_DIAG_ROWS, v_dim=v_dim,
+    )
+    chunks = seq_len // sched.chunk_k
+    if chunks == 1:
+        return heads
+    at_once = max(1, heads * itemsize // (4 * chunks))
+    return next(n for n in range(at_once, 0, -1) if heads % n == 0)
 
 
 def make_flash_attention(
     causal: bool = True, block_q: int = 128, block_k: int = 128
 ):
     """Partial suitable for ``TransformerConfig.attention_fn``
-    (signature ``attn(q, k, v) -> out``)."""
+    (signature ``attn(q, k, v) -> out``); its ``heads_a_call`` is
+    :func:`heads_a_call` at these blocks."""
 
     def attn(q, k, v):
         return flash_attention(
             q, k, v, causal, None, block_q, block_k
         )
 
+    attn.heads_a_call = functools.partial(
+        heads_a_call, block_q=block_q, block_k=block_k
+    )
     return attn

@@ -138,6 +138,62 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_flash_kernels_at_unequal_widths_compile_for_v5e(
+    v5e, chip_compile, what
+):
+    """Latent attention's call at the published widths (four heads a
+    call, q and k 192 wide, v 128, 16 384 keys, bf16): past the VMEM
+    budget, so the K-blocked schedule, forward and the one backward
+    kernel."""
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(width):
+        return jax.ShapeDtypeStruct(
+            (1, 4, 16384, width), jnp.bfloat16, sharding=one
+        )
+
+    fn = jax.grad(_attend_loss, argnums=(0, 1, 2)) if what == "grad" else _attend
+    compiled = jax.jit(fn).lower(arg(192), arg(192), arg(128)).compile()
+    text = compiled.as_text()
+    assert text.count(flash_mod.MOSAIC_CALL) == (2 if what == "grad" else 1)
+    if what == "grad":
+        assert flash_mod.BWD_KERNEL_NAME in text
+        grads = jax.eval_shape(fn, arg(192), arg(192), arg(128))
+        assert [g.shape[-1] for g in grads] == [192, 192, 128]
+
+
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_kda_kernels_compile_for_v5e(v5e, chip_compile, monkeypatch, what):
+    """The gated delta rule at the published widths (a group of four
+    heads of 128, chunks of 64, a row of 16 384, bf16): the state
+    kernels are in the program under the names a device trace shows,
+    ``kda_fwd`` and, in a gradient's, ``kda_bwd``."""
+    kda = importlib.import_module("adaptdl_tpu.ops.kda")
+    monkeypatch.setattr(kda, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (
+        arg((1, 16384, 4, 128)), arg((1, 16384, 4, 128)),
+        arg((1, 16384, 4, 128)), arg((1, 16384, 4, 128), jnp.float32),
+        arg((1, 16384, 4), jnp.float32),
+    )
+
+    def forward(*a):
+        return kda.kda(*a, chunk=64)
+
+    def loss(*a):
+        return forward(*a).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=tuple(range(5))) if what == "grad" else forward
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = set(re.findall(r"%[\w\-]*?(kda_(?:fwd|bwd))[\w\-]*[.\d]* = ", text))
+    assert found == ({"kda_fwd", "kda_bwd"} if what == "grad" else {"kda_fwd"})
+
+
 @pytest.mark.parametrize(
     "what, seq, kv_heads",
     [

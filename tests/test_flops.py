@@ -102,3 +102,34 @@ class FakeV5e:
 def test_device_peak_table():
     assert device_peak_flops(FakeV5e()) == pytest.approx(197e12)
     assert device_peak_flops(FakeCpu()) is None
+
+
+def test_kda_mla_and_shared_expert_are_counted_as_the_benchmark_counts():
+    """``transformer_train_flops`` of the kimi-linear-48b-a3b
+    configuration is the sum of ``benchmark/configs/
+    kimi-linear-48b-a3b.py:forward_flops_per_token`` a token, three
+    times (forward + backward)."""
+    import json
+    import os
+
+    from benchmark import manifest
+
+    base = os.path.join(
+        manifest.ROOT, "benchmark", "configs", "kimi-linear-48b-a3b"
+    )
+    with open(base + ".json") as f:
+        sizes = json.load(f)
+    config = manifest.load_module(base + ".py")
+    parts = config.forward_flops_per_token(sizes)
+    seq = sizes["sequence_length"]
+    got = transformer_train_flops(config.model_config(sizes), 2, seq)
+    assert got.total == pytest.approx(
+        3.0 * 2 * seq * sum(parts.values()), rel=1e-9
+    )
+    assert got.attention == pytest.approx(
+        3.0 * 2 * seq * (parts["mla_attention"] + parts["kda_mixing"]),
+        rel=1e-9,
+    )
+    assert got.total / (2 * seq) == pytest.approx(
+        config.train_flops_per_unit(sizes), rel=1e-9
+    )
