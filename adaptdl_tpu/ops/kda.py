@@ -23,17 +23,36 @@ start,
 so ``U = T (V - K+ S)`` with ``T = (I + Diag(beta) A)^{-1} Diag(beta)``
 a unit lower triangular solve a chunk. Two stages:
 
-- **The chunk's own work** (``_prepare``), for all chunks at once, in
-  XLA and differentiated by autodiff: the decay sums, ``A`` and ``B``,
-  the triangular inverse, ``W_k = T K+`` and ``W_v = T V``. No
-  exponent is ever positive: inside a sub-block of ``_SUB`` (16)
-  tokens ``e^{G_t - G_i}`` is taken exactly, pair by pair and channel
-  by channel; between sub-blocks the product is split at the later
+- **The chunk's own work**: the decay sums, ``A`` and ``B``, the
+  triangular inverse, ``W_k = T K+`` and ``W_v = T V``. No exponent
+  is ever positive: inside a sub-block of ``_SUB`` (16) tokens
+  ``e^{G_t - G_i}`` is taken exactly, pair by pair and channel by
+  channel; between sub-blocks the product is split at the later
   sub-block's first token, ``e^{G_t - R} e^{R - G_i}``, both factors
   at most 1. The inverse is block forward substitution from single
-  rows up (``_unit_lower_inverse``), which does not cancel as a
-  Neumann series does. Products take operands in the input dtype and accumulate in
-  float32; sums of ``g``, the exponents and the inverse are float32.
+  rows up, which does not cancel as a Neumann series does. Products
+  take operands in the input dtype and accumulate in float32; sums of
+  ``g``, the exponents and the inverse (its products at
+  ``Precision.HIGHEST``) are float32. Two programs of that one
+  arithmetic, the same rounding points:
+
+  - a Pallas kernel pair (``delta_chunk_fwd``, ``delta_chunk_bwd``;
+    ``_own_work``, a ``jax.custom_vjp``), a few chunks a grid step,
+    every float32 array of a chunk in VMEM and none of them in HBM:
+    the forward reads q, k, v, g, beta and writes what the recurrence
+    takes (``q e^G``, ``k e^{G_C - G}``, ``W_k``, ``W_v``, ``B`` in the
+    input dtype, ``e^{G_C}``); the backward forms the chunk's forward
+    again (``_own_shared``, the one function both bodies trace) and
+    then every step's transpose by hand, float32 where autodiff would
+    round a cotangent to the operand's dtype. Tokens lie on sublanes
+    and channels on lanes; a sub-block's pairs are walked one earlier
+    token at a time against the later rows, and each column of ``A``
+    so formed is at once one step of the forward substitution inside
+    the sub-block; above a sub-block the block substitution is two
+    whole-chunk products a level;
+  - ``_prepare``, for all chunks at once in XLA and differentiated by
+    autodiff (``_unit_lower_inverse``): where ``kernel_fits`` says no,
+    and what the kernels are tested against.
 - **The recurrence over chunks**, two Pallas kernels (``kda_fwd``,
   ``kda_bwd``; interpret mode off the TPU): one grid step a (batch,
   head) and chunk, the float32 state (its gradient, walking the chunks
@@ -41,12 +60,14 @@ a unit lower triangular solve a chunk. Two stages:
   The forward writes each chunk's starting state for the backward,
   which recomputes ``U`` from it. Where a head's widths are not whole
   lane tiles on the chip the same arithmetic runs as a ``lax.scan``
-  (``path`` = fallback in the ``kda.schedule`` event).
+  over ``_prepare``'s results (``path`` = fallback, ``own_work`` = xla
+  in the ``kda.schedule`` event).
 
 Both stages run a group of heads at a time (``head_groups``), one
-group after another, each group's work (``kda_fwd`` included) done
-again in its backward: the float32 arrays of the chunks' own work are
-then a group's, not the layer's.
+group after another, each group's work (both forward kernels) done
+again in its backward: what is alive in HBM at once (a group's
+operands, its chunk states and their cotangents) is then a group's,
+not the layer's.
 
 ``kda`` names its output (``SAVED_OUT``): a remat'd block keeps it
 (``models.transformer.block_remat``), so the block's recomputation
@@ -56,6 +77,7 @@ does not run the rule a second time before its backward does.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +90,10 @@ from adaptdl_tpu import trace
 
 FWD_KERNEL_NAME = "kda_fwd"
 BWD_KERNEL_NAME = "kda_bwd"
+# The chunks' own work (no ``kda_``: a device trace's ``kda_`` events
+# are the state kernels alone).
+OWN_FWD_KERNEL_NAME = "delta_chunk_fwd"
+OWN_BWD_KERNEL_NAME = "delta_chunk_bwd"
 # What ``kda`` names (``jax.ad_checkpoint.checkpoint_name``) of what it
 # produces: its output. A remat'd block keeps it by this name
 # (``models.transformer.block_remat``), so the block's recomputation
@@ -196,12 +222,477 @@ def _prepare(q, k, v, g, beta, chunk: int, scale: float):
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
+_NN = (((1,), (0,)), ((), ()))
 
 
-def _dot(a, b, dims=None):
-    if dims is None:
-        return jnp.dot(a, b, preferred_element_type=jnp.float32)
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+def _dot(a, b, dims=None, precision=None):
+    return lax.dot_general(
+        a, b, dims or _NN, precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+
+
+# ---- the chunk's own work as a kernel pair ----------------------------
+#
+# ``_prepare``'s arithmetic on ONE chunk's [C, w] blocks in VMEM, the
+# same rounding points, written for what Mosaic lowers: rows (tokens) on
+# sublanes and channels on lanes; a sub-block's pair decays one earlier
+# token ``i`` at a time against the sub-block's later rows; the inverse
+# by forward substitution, a column at a time inside a sub-block (as the
+# pair decays form the columns) and by ``_unit_lower_inverse``'s block
+# products above it, each two whole-chunk products whose other blocks
+# are zero.
+
+
+def _running_sum(x, reverse: bool = False):
+    """Inclusive running sum down the rows of ``x`` [C, w] (up them
+    with ``reverse``), float32 adds in log2(C) doubling steps."""
+    rows = x.shape[0]
+    at = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    shift = 1
+    while shift < rows:
+        if reverse:
+            moved = pltpu.roll(x, rows - shift, 0)
+            x = x + jnp.where(at < rows - shift, moved, 0.0)
+        else:
+            x = x + jnp.where(at >= shift, pltpu.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
+
+
+def _level_masks(size: int, sub: int):
+    """-> (row and column numbers of a [C, C] matrix, for each level
+    of the block forward substitution above a sub-block (blocks of
+    ``half`` = sub, 2 sub, .. rows) the mask of block (1, 0) of every
+    diagonal pair of blocks)."""
+    row = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    col = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+    masks, bits = [], sub.bit_length() - 1
+    while (1 << bits) < size:
+        masks.append(
+            ((row >> (bits + 1)) == (col >> (bits + 1)))
+            & (((row >> bits) & 1) == 1) & (((col >> bits) & 1) == 0)
+        )
+        bits += 1
+    return row, col, masks
+
+
+def _unit_lower_inverse_chunk(inv, lower, masks):
+    """``_unit_lower_inverse``'s levels from a sub-block up on one
+    [C, C] matrix: with ``X`` (``inv`` to begin with) the inverse of
+    the diagonal blocks of ``half`` rows and ``B`` the blocks (1, 0) of
+    every pair, ``X - X (B X)`` is the inverse of the blocks of ``2
+    half``: the same ``-D' (B A')`` a corner, the other blocks of both
+    products exactly zero. (Inside a sub-block the same forward
+    substitution runs a column at a time where the columns are
+    formed: ``_own_shared``.)"""
+    for below in masks:
+        inv = inv - _dot(
+            inv, _dot(jnp.where(below, lower, 0.0), inv, precision=_HIGHEST),
+            precision=_HIGHEST,
+        )
+    return inv
+
+
+def _unrolled() -> bool:
+    """Whether a sub-block's tokens are walked by unrolled code: where
+    the kernel is compiled (static rows and lanes, a straight line the
+    scheduler interleaves). Interpreted, the same body runs as a loop
+    and the CPU's compiler is spared the copies."""
+    return not _use_interpret()
+
+
+def _each(lo: int, hi: int, body, carry, unrolled: bool):
+    """``carry = body(i, carry)`` for i in [lo, hi)."""
+    if not unrolled:
+        return lax.fori_loop(lo, hi, body, carry)
+    for i in range(lo, hi):
+        carry = body(i, carry)
+    return carry
+
+
+def _one(x, at, axis: int):
+    """Row (axis 0) or column (axis 1) ``at`` of a value, kept 2-D."""
+    if isinstance(at, int):
+        return x[at:at + 1] if axis == 0 else x[:, at:at + 1]
+    return lax.dynamic_slice_in_dim(x, at, 1, axis)
+
+
+def _octet(sub: int, unrolled: bool) -> int:
+    """In runs of how many tokens a sub-block is walked, each run
+    against the rows from its first on: a float32 sublane tile where
+    the walk is unrolled (the whole tiles of rows before token ``i``
+    are skipped); as a loop, the whole sub-block (the rows before
+    token i carry zeros: both triangles are masked)."""
+    return 8 if sub % 8 == 0 and unrolled else sub
+
+
+def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows):
+    """One chunk's own work up to ``T``, on values: q, k [C, dk] in
+    the input dtype, g [C, dk] float32, beta [1, C] float32.
+    ``g_rows``, ``k_rows``: float32 [C, dk] scratch that holds ``G``
+    and k, from which single rows are read. Returns what the forward
+    forms its results from and the backward its gradients."""
+    low, f32 = q.dtype, jnp.float32
+    chunk, dk = k.shape
+    sub = _sub_block(chunk)
+    octet = _octet(sub, unrolled)
+    big_g = _running_sum(g)
+    k32, q32 = k.astype(f32), q.astype(f32) * scale
+    g_rows[...] = big_g
+    k_rows[...] = k32
+    lane = lax.broadcasted_iota(jnp.int32, (octet, chunk), 1)
+    down = lax.broadcasted_iota(jnp.int32, (octet, chunk), 0)
+    row, col, masks = _level_masks(chunk, sub)
+    beta_col = jnp.sum(jnp.where(row == col, beta, 0.0), axis=1, keepdims=True)
+    a_parts, b_parts, x_parts, between = [], [], [], {}
+    for at in range(0, chunk, sub):
+        g_sub, k_sub, q_sub = (x[at:at + sub] for x in (big_g, k32, q32))
+        if at:
+            # Between sub-blocks: split at this one's first token.
+            first = g_sub[:1]
+            rel = jnp.exp(g_sub - first)  # e^{G_t - R}
+            shrunk = jnp.exp(jnp.minimum(first - big_g[:at], 0.0))
+            back = jnp.concatenate(
+                [(shrunk * k32[:at]).astype(low),
+                 jnp.zeros((chunk - at, dk), low)], axis=0,
+            )  # k_i e^{R - G_i}, i before the sub-block
+            both = jnp.concatenate(
+                [(k_sub * rel).astype(low), (q_sub * rel).astype(low)], axis=0
+            )
+            off = _dot(both, back, _NT)  # [2 sub, C]
+            a_rows, b_rows = off[:sub], off[sub:]
+            between[at] = (rel, shrunk, back, both)
+        else:
+            a_rows = b_rows = jnp.zeros((sub, chunk), f32)
+        octets = range(0, sub, octet)
+        a_sub = [a_rows[o:o + octet] for o in octets]
+        b_sub = [b_rows[o:o + octet] for o in octets]
+        # The sub-block's rows of the inverse of its own diagonal
+        # block, the identity to begin with.
+        x_sub = [jnp.where(lane == down + (at + o), 1.0, 0.0) for o in octets]
+        beta_sub = beta_col[at:at + sub]
+        below = lax.broadcasted_iota(jnp.int32, (sub, 1), 0)
+        # Inside the sub-block: every pair's exponent by itself, one
+        # earlier token i against the rows from its tile on; and with
+        # column i of A formed, forward substitution's step i.
+        for lo in octets:
+
+            def pair(i, carry, at=at, lo=lo, g_sub=g_sub, k_sub=k_sub,
+                     q_sub=q_sub, beta_sub=beta_sub):
+                a_sub, b_sub, x_sub = (list(x) for x in carry)
+                decayed = jnp.exp(
+                    jnp.minimum(g_sub[lo:] - g_rows[pl.ds(at + i, 1), :], 0.0)
+                ) * k_rows[pl.ds(at + i, 1), :]
+                a_col = jnp.sum(k_sub[lo:] * decayed, axis=1, keepdims=True)
+                b_col = jnp.sum(q_sub[lo:] * decayed, axis=1, keepdims=True)
+                step = jnp.where(below[lo:] > i, beta_sub[lo:] * a_col, 0.0)
+                solved = _one(x_sub[lo // octet], i - lo, 0)  # row i, final
+                for o in range(lo // octet, sub // octet):
+                    rows = slice(o * octet - lo, (o + 1) * octet - lo)
+                    here = lane == at + i
+                    a_sub[o] = jnp.where(here, a_col[rows], a_sub[o])
+                    b_sub[o] = jnp.where(here, b_col[rows], b_sub[o])
+                    x_sub[o] = x_sub[o] - step[rows] * solved
+                return tuple(a_sub), tuple(b_sub), tuple(x_sub)
+
+            a_sub, b_sub, x_sub = _each(
+                lo, lo + octet, pair,
+                (tuple(a_sub), tuple(b_sub), tuple(x_sub)), unrolled,
+            )
+        a_parts += a_sub
+        b_parts += b_sub
+        x_parts += x_sub
+    a_full = jnp.where(row > col, jnp.concatenate(a_parts, axis=0), 0.0)
+    b_full = jnp.where(row >= col, jnp.concatenate(b_parts, axis=0), 0.0)
+    inv = _unit_lower_inverse_chunk(
+        jnp.concatenate(x_parts, axis=0), beta_col * a_full, masks
+    )
+    grown = jnp.exp(big_g)
+    last = g_rows[pl.ds(chunk - 1, 1), :]
+    return dict(
+        big_g=big_g, k32=k32, q32=q32, between=between, a_full=a_full,
+        b_full=b_full, beta_col=beta_col, inv=inv, row=row, col=col,
+        solve=(inv * beta).astype(low),  # T
+        grown=grown, k_plus=(k32 * grown).astype(low),
+        last=last, left=jnp.exp(last - big_g),  # e^{G_C - G}
+    )
+
+
+def _own_fwd_kernel(q, k, v, g, beta, qp, kd, wk, wv, b, dl, *rows, scale,
+                    unrolled):
+    # (Every block access inside a ``when``, as in the state kernels.)
+    @pl.when(pl.program_id(1) >= 0)
+    def _chunks():
+        def one(j, carry):
+            low = q.dtype
+            at = _own_shared(
+                q[0, j], k[0, j], g[0, j], beta[0, j], scale, unrolled, *rows
+            )
+            qp[0, j] = (at["q32"] * at["grown"]).astype(low)
+            kd[0, j] = (at["k32"] * at["left"]).astype(low)
+            wk[0, j] = _dot(at["solve"], at["k_plus"]).astype(low)
+            wv[0, j] = _dot(at["solve"], v[0, j]).astype(low)
+            b[0, j] = at["b_full"].astype(low)
+            dl[0, j] = jnp.exp(at["last"])
+            return carry
+
+        lax.fori_loop(0, q.shape[1], one, 0)
+
+
+def _own_backward(q, k, v, g, beta, cts, scale, unrolled, rows):
+    """One chunk's gradients on values, from the six cotangents of
+    what the forward wrote (the first five in the input dtype, the
+    decay's [1, dk] float32): the forward's values are formed again,
+    then every step's transpose by hand, float32 throughout except
+    where the forward's products take the input dtype. ``rows``: four
+    float32 [C, dk] scratch (G, k, and the sums of dG and dk that
+    single rows are added to). Returns (dq, dk, dv, dg, dbeta)."""
+    g_rows, k_rows, dg_rows, dk_rows = rows
+    low, f32 = q.dtype, jnp.float32
+    chunk, dk = k.shape
+    sub = _sub_block(chunk)
+    octet = _octet(sub, unrolled)
+    at = _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows)
+    big_g, k32, q32 = at["big_g"], at["k32"], at["q32"]
+    grown, left, inv, solve = at["grown"], at["left"], at["inv"], at["solve"]
+    row, col = at["row"], at["col"]
+    d_qp, d_kd, d_wk, d_wv, d_b, d_dl = cts
+    d_qp, d_kd = d_qp.astype(f32), d_kd.astype(f32)
+    # W_k = T K+, W_v = T V, T = X Diag(beta), X = (I + Diag(beta) A)^-1.
+    d_solve = _dot(d_wk, at["k_plus"], _NT) + _dot(d_wv, v, _NT)
+    d_kplus = _dot(solve, d_wk, _TN)
+    d_v = _dot(solve, d_wv, _TN)
+    d_beta = jnp.sum(d_solve * inv, axis=0, keepdims=True)  # [1, C]
+    d_lower = -_dot(
+        inv, _dot(d_solve * beta, inv, _NT, _HIGHEST), _TN, _HIGHEST
+    )  # -X^T dX X^T
+    d_lower = jnp.where(row > col, d_lower, 0.0)
+    d_beta += jnp.sum(
+        jnp.where(
+            row == col,
+            jnp.sum(d_lower * at["a_full"], axis=1, keepdims=True), 0.0,
+        ),
+        axis=0, keepdims=True,
+    )
+    d_a = at["beta_col"] * d_lower
+    d_b = jnp.where(row >= col, d_b.astype(f32), 0.0)
+    # q e^G, k e^G (inside W_k), k e^{G_C - G}, e^{G_C}.
+    to_last = d_kd * k32 * left
+    dk_rows[...] = d_kplus * grown + d_kd * left
+    dg_rows[...] = (d_qp * q32 + d_kplus * k32) * grown - to_last
+    dg_rows[pl.ds(chunk - 1, 1), :] += (
+        jnp.sum(to_last, axis=0, keepdims=True) + d_dl * jnp.exp(at["last"])
+    )
+    dq_parts = []
+    for here in range(0, chunk, sub):
+        g_sub, k_sub, q_sub = (x[here:here + sub] for x in (big_g, k32, q32))
+        da_rows, db_rows = d_a[here:here + sub], d_b[here:here + sub]
+        if here:
+            rel, shrunk, back, both = at["between"][here]
+            d_off = jnp.concatenate([da_rows, db_rows], axis=0).astype(low)
+            d_both = _dot(d_off, back)  # [2 sub, dk]
+            d_back = _dot(d_off, both, _TN)[:here]  # [tokens before, dk]
+            d_kr, d_qr = d_both[:sub], d_both[sub:]
+            dk_sub, dq_sub = d_kr * rel, d_qr * rel
+            dg_sub = (d_kr * k_sub + d_qr * q_sub) * rel  # d(G_t - R)
+            dk_rows[pl.ds(0, here), :] += d_back * shrunk
+            from_first = d_back * k32[:here] * shrunk  # d(R - G_i)
+            dg_rows[pl.ds(0, here), :] -= from_first
+            dg_rows[pl.ds(here, 1), :] += (
+                jnp.sum(from_first, axis=0, keepdims=True)
+                - jnp.sum(dg_sub, axis=0, keepdims=True)
+            )
+        else:
+            dk_sub = dq_sub = dg_sub = jnp.zeros((sub, dk), f32)
+        octets = range(0, sub, octet)
+        dk_sub = [dk_sub[o:o + octet] for o in octets]
+        dq_sub = [dq_sub[o:o + octet] for o in octets]
+        dg_sub = [dg_sub[o:o + octet] for o in octets]
+        for lo in octets:
+
+            def pair(i, carry, here=here, lo=lo, g_sub=g_sub, k_sub=k_sub,
+                     q_sub=q_sub, da_rows=da_rows, db_rows=db_rows):
+                dg_sub, dk_sub, dq_sub = (list(x) for x in carry)
+                k_i = k_rows[pl.ds(here + i, 1), :]
+                gone = jnp.exp(jnp.minimum(
+                    g_sub[lo:] - g_rows[pl.ds(here + i, 1), :], 0.0
+                ))  # e^{G_t - G_i}
+                da_col = _one(da_rows[lo:], here + i, 1)
+                db_col = _one(db_rows[lo:], here + i, 1)
+                to_i = (da_col * k_sub[lo:] + db_col * q_sub[lo:]) * gone
+                dk_rows[pl.ds(here + i, 1), :] += jnp.sum(
+                    to_i, axis=0, keepdims=True
+                )
+                to_gap = to_i * k_i
+                dg_rows[pl.ds(here + i, 1), :] -= jnp.sum(
+                    to_gap, axis=0, keepdims=True
+                )
+                decayed = gone * k_i
+                for o in range(lo // octet, sub // octet):
+                    rows_o = slice(o * octet - lo, (o + 1) * octet - lo)
+                    dg_sub[o] = dg_sub[o] + to_gap[rows_o]
+                    dk_sub[o] = dk_sub[o] + da_col[rows_o] * decayed[rows_o]
+                    dq_sub[o] = dq_sub[o] + db_col[rows_o] * decayed[rows_o]
+                return tuple(dg_sub), tuple(dk_sub), tuple(dq_sub)
+
+            dg_sub, dk_sub, dq_sub = _each(
+                lo, lo + octet, pair,
+                (tuple(dg_sub), tuple(dk_sub), tuple(dq_sub)), unrolled,
+            )
+        dk_rows[pl.ds(here, sub), :] += jnp.concatenate(dk_sub, axis=0)
+        dg_rows[pl.ds(here, sub), :] += jnp.concatenate(dg_sub, axis=0)
+        dq_parts += dq_sub
+    d_q = jnp.concatenate(dq_parts, axis=0) + d_qp * grown
+    return (
+        (d_q * scale).astype(low),
+        dk_rows[...].astype(low),
+        d_v.astype(low),
+        _running_sum(dg_rows[...], reverse=True),
+        d_beta,
+    )
+
+
+def _own_bwd_kernel(q, k, v, g, beta, d_qp, d_kd, d_wk, d_wv, d_b, d_dl,
+                    d_q, d_k, d_v, d_g, d_beta, *rows, scale, unrolled):
+    @pl.when(pl.program_id(1) >= 0)
+    def _chunks():
+        def one(j, carry):
+            grads = _own_backward(
+                q[0, j], k[0, j], v[0, j], g[0, j], beta[0, j],
+                tuple(x[0, j] for x in (d_qp, d_kd, d_wk, d_wv, d_b, d_dl)),
+                scale, unrolled, rows,
+            )
+            for ref, value in zip((d_q, d_k, d_v, d_g, d_beta), grads):
+                ref[0, j] = value
+            return carry
+
+        lax.fori_loop(0, q.shape[1], one, 0)
+
+
+def _own_specs(held: int, chunk: int, dk: int, dv: int):
+    """Block specs of the chunks' own work, ``held`` chunks a grid
+    step: (a [bh, chunks, C, dk] operand, a [.., C, dv] one, a
+    [.., C, C] one, a [.., 1, dk] one, beta's [.., 1, C])."""
+
+    def rows(height, width):
+        return pl.BlockSpec(
+            (1, held, height, width), lambda bh, ci: (bh, ci, 0, 0)
+        )
+
+    return (rows(chunk, dk), rows(chunk, dv), rows(chunk, chunk),
+            rows(1, dk), rows(1, chunk))
+
+
+# Chunks a grid step of the chunks' own work: a step's fixed cost
+# (~0.35 us) is then a small part of a chunk's ~1 us. (Four of them
+# abreast in one basic block ran 1.24 / 2.23 ms a call of 4 heads
+# forward / backward for 1.48 / 2.58 on a v5e, and cost every program
+# that holds the kernels four times the lowering: 137 s of
+# ``trace_lower_s`` in a run of the kimi cell for the parent's 96.)
+_OWN_HELD = 8
+
+
+class _How(NamedTuple):
+    """How the kernel pair is built: what is decided outside the
+    traced functions, so that they can be traced once a shape."""
+
+    interpret: bool  # off the TPU
+    unrolled: bool  # a sub-block's tokens by unrolled code (``_unrolled``)
+    held: int  # chunks a grid step
+
+
+def _own_how(chunks: int) -> _How:
+    held = next(n for n in range(min(_OWN_HELD, chunks), 0, -1)
+                if chunks % n == 0)
+    return _How(_use_interpret(), _unrolled(), held)
+
+
+def _own_rows(count: int, chunk: int, dk: int):
+    return [pltpu.VMEM((chunk, dk), jnp.float32)] * count
+
+
+_OWN_PARAMS = dict(dimension_semantics=("parallel", "parallel"))
+
+
+# (Both calls are functions jitted by themselves: a kernel's body is
+# some thousands of operations, unrolled, and a model traces it at
+# every call site of every program. As jitted functions the bodies are
+# traced once a process and shape, and lowered once a program.)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _own_fwd_pallas(scale: float, how: _How, q, k, v, g, beta):
+    bh, chunks, chunk, dk = q.shape
+    dv = v.shape[3]
+    wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
+    vma = jax.typeof(q).vma
+
+    def out(shape, dtype=q.dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+    return pl.pallas_call(
+        functools.partial(
+            _own_fwd_kernel, scale=scale, unrolled=how.unrolled
+        ),
+        grid=(bh, chunks // how.held),
+        in_specs=[wide, wide, tall, wide, steps],
+        out_specs=[wide, wide, wide, tall, square, decay],
+        out_shape=[
+            out(q.shape), out(q.shape), out(q.shape), out(v.shape),
+            out((bh, chunks, chunk, chunk)),
+            out((bh, chunks, 1, dk), jnp.float32),
+        ],
+        scratch_shapes=_own_rows(2, chunk, dk),
+        compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
+        interpret=how.interpret,
+        name=OWN_FWD_KERNEL_NAME,
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _own_bwd_pallas(scale: float, how: _How, q, k, v, g, beta, *cts):
+    bh, chunks, chunk, dk = q.shape
+    dv = v.shape[3]
+    wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
+    vma = jax.typeof(q).vma
+    return pl.pallas_call(
+        functools.partial(
+            _own_bwd_kernel, scale=scale, unrolled=how.unrolled
+        ),
+        grid=(bh, chunks // how.held),
+        in_specs=[wide, wide, tall, wide, steps,
+                  wide, wide, wide, tall, square, decay],
+        out_specs=[wide, wide, tall, wide, steps],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+            for x in (q, k, v, g, beta)
+        ],
+        scratch_shapes=_own_rows(4, chunk, dk),
+        compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
+        interpret=how.interpret,
+        name=OWN_BWD_KERNEL_NAME,
+    )(q, k, v, g, beta, *cts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _own_work(scale: float, q, k, v, g, beta):
+    """``_prepare`` as a kernel pair: q, k, g [bh, chunks, C, dk], v
+    [bh, chunks, C, dv], beta [bh, chunks, 1, C]; the six results as
+    the state kernels' blocks take them."""
+    return tuple(
+        _own_fwd_pallas(scale, _own_how(q.shape[1]), q, k, v, g, beta)
+    )
+
+
+def _own_work_fwd(scale, *operands):
+    return _own_work(scale, *operands), operands
+
+
+def _own_work_bwd(scale, operands, cts):
+    how = _own_how(operands[0].shape[1])
+    return tuple(_own_bwd_pallas(scale, how, *operands, *cts))
+
+
+_own_work.defvjp(_own_work_fwd, _own_work_bwd)
 
 
 def _chunk_forward(state, qp, kd, wk, wv, b, dl):
@@ -412,8 +903,21 @@ def _recurrence_bwd(kernel, saved, d_o):
 _recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
 
 
-# A group of heads whose k is at most this many elements is prepared
-# at once (32 MiB in float32: at 16 384 tokens and heads of 128, four).
+# A group of heads whose k is at most this many elements runs at once
+# (at 16 384 tokens and heads of 128: four). What a group keeps alive
+# in HBM since the chunks' own work is a kernel pair, a head of 128 at
+# 16 384 tokens: the caller's own work on the head (``prepare``:
+# convolutions, norms and the decay, half a dozen float32 arrays as
+# large as k) ~48 MiB; the five operands 20 (three in bf16, g in
+# float32); the recurrence's five 18; the forward's chunk states
+# [256, 128, 128] float32 16; and in the backward a cotangent beside
+# each: ~0.2 GiB a head, where the XLA ``_prepare`` held a dozen
+# float32 arrays as large as k and as many gradients. Groups of eight
+# (2**24) also pass `tools/compile_step_v5e.py
+# kimi-linear-48b-a3b-steady` (sixteen: refused by 396 MiB of 15.75
+# GiB) and are no faster on the chip (a layer and micro-batch forward
+# and backward 43.8 ms for 42.7: the kernels' grids do not care, and
+# a group's copies in and out grow with it), so four stay.
 _GROUP_ELEMENTS = 2**23
 
 
@@ -481,9 +985,16 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         path="kernel" if kernel else "fallback",
         product="pallas:" + FWD_KERNEL_NAME + "," + BWD_KERNEL_NAME
         if kernel else "scan",
-        backward="kernel over the forward's chunk states; the chunks' own "
-        "work by autodiff" + (", a group of heads at a time, done again "
-                              "in its backward" if groups > 1 else ""),
+        own_work="pallas:" + OWN_FWD_KERNEL_NAME + "," + OWN_BWD_KERNEL_NAME
+        if kernel else "xla",
+        backward=(
+            BWD_KERNEL_NAME + " over the forward's chunk states, then "
+            + OWN_BWD_KERNEL_NAME + " (a chunk's own work formed again in "
+            "VMEM, its transpose by hand)" if kernel else
+            "a reversed scan over the forward's chunk states; the chunks' "
+            "own work by autodiff"
+        ) + (", a group of heads at a time, done again in its backward"
+             if groups > 1 else ""),
         saved_names=SAVED_OUT,
     )
     pad = chunks * chunk - seq_len
@@ -494,23 +1005,32 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         q, k, v, g, beta = per_token  # [b, s, heads of the group, w]
         held = q.shape[2]
 
-        def rows(x):  # [b, s, h, w] -> [b * h * chunks, C, w]
+        bh = batch * held
+
+        def rows(x):  # [b, s, h, w] -> [b * h, chunks, C, w]
             x = jnp.swapaxes(x, 1, 2)
             if pad:
                 x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            return x.reshape(batch * held * chunks, chunk, x.shape[-1])
+            return x.reshape(bh, chunks, chunk, x.shape[-1])
 
-        qp, kd, wk, wv, b, dl = _prepare(
+        operands = (
             rows(q), rows(k), rows(v), rows(g.astype(jnp.float32)),
-            rows(beta.astype(jnp.float32)[..., None])[..., 0], chunk, scale,
+            rows(beta.astype(jnp.float32)[..., None]),
         )
-        bh = batch * held
-        out = _recurrence(
-            kernel,
-            *(x.reshape(bh, chunks, chunk, x.shape[-1])
-              for x in (qp, kd, wk, wv, b)),
-            dl.reshape(bh, chunks, 1, dk),
-        )
+        if kernel:
+            prepared = _own_work(
+                scale, *operands[:4], operands[4].reshape(bh, chunks, 1, chunk)
+            )
+        else:
+            prepared = _prepare(
+                *(x.reshape((bh * chunks,) + x.shape[2:])
+                  for x in operands[:4]),
+                operands[4].reshape(bh * chunks, chunk), chunk, scale,
+            )
+            prepared = tuple(
+                x.reshape((bh, chunks) + x.shape[1:]) for x in prepared[:5]
+            ) + (prepared[5].reshape(bh, chunks, 1, dk),)
+        out = _recurrence(kernel, *prepared)
         out = out.reshape(batch, held, chunks * chunk, dv)[:, :, :seq_len]
         return jnp.swapaxes(out, 1, 2).astype(v.dtype)
 
@@ -518,10 +1038,10 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     if groups == 1:
         return checkpoint_name(some_heads(per_token, per_head), SAVED_OUT)
 
-    # The chunks' own work holds a dozen float32 arrays as large as k
-    # (and its gradient as many again): a group of heads at a time, in
-    # turn, each group's work done again in its backward, so that what
-    # is alive at once is a group's and not the layer's.
+    # A group of heads at a time, in turn, each group's work done
+    # again in its backward, so that what is alive at once (operands,
+    # chunk states, cotangents: ``_GROUP_ELEMENTS``) is a group's and
+    # not the layer's.
     def token_groups(x):  # [b, s, h, ...] -> [groups, b, s, h / groups, ...]
         shape = x.shape[:2] + (groups, heads // groups) + x.shape[3:]
         return jnp.moveaxis(x.reshape(shape), 2, 0)

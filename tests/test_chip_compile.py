@@ -194,6 +194,51 @@ def test_kda_kernels_compile_for_v5e(v5e, chip_compile, monkeypatch, what):
     assert found == ({"kda_fwd", "kda_bwd"} if what == "grad" else {"kda_fwd"})
 
 
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_delta_chunk_kernels_compile_for_v5e(
+    v5e, chip_compile, monkeypatch, what
+):
+    """The chunks' own work of the gated delta rule at the published
+    widths (four heads of 128, 256 chunks of 64 — a row of 16 384 —,
+    bf16): the kernel pair lowers through Mosaic under the names a
+    device trace shows, ``delta_chunk_fwd`` and, in a gradient's,
+    ``delta_chunk_bwd``; neither name holds ``kda_`` (the state
+    kernels' readers match that)."""
+    kda = importlib.import_module("adaptdl_tpu.ops.kda")
+    monkeypatch.setattr(kda, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(width, dtype=jnp.bfloat16, rows=64):
+        return jax.ShapeDtypeStruct(
+            (4, 256, rows, width), dtype, sharding=one
+        )
+
+    args = (
+        arg(128), arg(128), arg(128), arg(128, jnp.float32),
+        arg(64, jnp.float32, rows=1),
+    )
+
+    def forward(*a):
+        return kda._own_work(128**-0.5, *a)
+
+    def loss(*a):
+        return sum(x.astype(jnp.float32).sum() for x in forward(*a))
+
+    assert kda.kernel_fits(128, 128, 64)
+    fn = jax.grad(loss, argnums=tuple(range(5))) if what == "grad" else forward
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = set(
+        re.findall(r"%[\w\-]*?(delta_chunk_(?:fwd|bwd))[\w\-]*[.\d]* = ", text)
+    )
+    assert found == {"delta_chunk_bwd" if what == "grad" else "delta_chunk_fwd"}
+    assert "kda_" not in kda.OWN_FWD_KERNEL_NAME + kda.OWN_BWD_KERNEL_NAME
+    if what == "grad":
+        grads = jax.eval_shape(fn, *args)
+        assert [(g.shape, g.dtype) for g in grads] == [
+            (a.shape, a.dtype) for a in args
+        ]
+
+
 @pytest.mark.parametrize(
     "what, seq, kv_heads",
     [

@@ -95,6 +95,13 @@ def _weighted(fn, shape):
     return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cotangent)
 
 
+def _grads(fn, shape, args):
+    """Every operand's gradient of ``fn`` under ``_weighted``'s
+    cotangent, as one compiled program (op by op, a chunk's hundreds
+    of small operations are each dispatched by themselves)."""
+    return jax.jit(jax.grad(_weighted(fn, shape), (0, 1, 2, 3, 4)))(*args)
+
+
 def _rel(got, want):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
@@ -112,10 +119,9 @@ def test_chunked_kda_is_the_recurrence(chunk, seq, use_kernel):
     args = _kda_inputs(0, seq=seq)
     want = kda_op.kda_recurrent(*args)
     run = functools.partial(kda_op.kda, chunk=chunk, use_kernel=use_kernel)
-    assert _rel(run(*args), want) < 1e-5
-    argnums = tuple(range(5))
-    got = jax.grad(_weighted(run, want.shape), argnums)(*args)
-    ref = jax.grad(_weighted(kda_op.kda_recurrent, want.shape), argnums)(*args)
+    assert _rel(jax.jit(run)(*args), want) < 1e-5
+    got = _grads(run, want.shape, args)
+    ref = _grads(kda_op.kda_recurrent, want.shape, args)
     for a, b in zip(got, ref):
         assert _rel(a, b) < 2e-5
 
@@ -129,18 +135,14 @@ def test_kda_kernels_equal_the_scan_on_bf16_operands():
     outs, grads = {}, {}
     for use_kernel in (True, False):
         run = functools.partial(kda_op.kda, chunk=16, use_kernel=use_kernel)
-        outs[use_kernel] = run(*args)
-        grads[use_kernel] = jax.grad(
-            _weighted(run, want.shape), (0, 1, 2, 3, 4)
-        )(*args)
+        outs[use_kernel] = jax.jit(run)(*args)
+        grads[use_kernel] = _grads(run, want.shape, args)
     assert outs[True].dtype == jnp.bfloat16
     assert _rel(outs[True], outs[False]) < 1e-2
     assert _rel(outs[True], want) < 3e-2
     for a, b in zip(grads[True], grads[False]):
         assert _rel(a, b) < 2e-2
-    ref = jax.grad(
-        _weighted(kda_op.kda_recurrent, want.shape), (0, 1, 2, 3, 4)
-    )(*args)
+    ref = _grads(kda_op.kda_recurrent, want.shape, args)
     for a, b in zip(grads[True], ref):
         assert _rel(a, b) < 6e-2
 
@@ -149,12 +151,11 @@ def test_kda_survives_a_decay_no_float32_inverse_holds():
     """A decay of e^-40 a token: ``e^{-G}`` of a chunk would overflow
     float32; no exponent here is positive."""
     args = _kda_inputs(2, seq=64, decay=40.0)
-    got = kda_op.kda(*args, chunk=64)
+    run = functools.partial(kda_op.kda, chunk=64)
+    got = jax.jit(run)(*args)
     assert bool(jnp.isfinite(got).all())
     assert _rel(got, kda_op.kda_recurrent(*args)) < 1e-5
-    grads = jax.grad(_weighted(
-        functools.partial(kda_op.kda, chunk=64), got.shape
-    ), (0, 1, 2, 3, 4))(*args)
+    grads = _grads(run, got.shape, args)
     assert all(bool(jnp.isfinite(x).all()) for x in grads)
 
 
@@ -163,12 +164,14 @@ def test_kda_in_head_groups_is_kda(monkeypatch):
     heads at once give, forward and backward."""
     args = _kda_inputs(3, seq=40, heads=4)
     run = functools.partial(kda_op.kda, chunk=16)
-    whole = run(*args)
-    whole_grads = jax.grad(_weighted(run, whole.shape), (0, 1, 2, 3, 4))(*args)
+    whole = jax.jit(run)(*args)
+    whole_grads = _grads(run, whole.shape, args)
     monkeypatch.setattr(kda_op, "_GROUP_ELEMENTS", 2 * 40 * 8)
     assert kda_op.head_groups(80, 4, 8) == 4
-    np.testing.assert_allclose(run(*args), whole, rtol=1e-6, atol=1e-7)
-    grads = jax.grad(_weighted(run, whole.shape), (0, 1, 2, 3, 4))(*args)
+    np.testing.assert_allclose(
+        jax.jit(lambda *a: run(*a))(*args), whole, rtol=1e-6, atol=1e-7
+    )
+    grads = _grads(lambda *a: run(*a), whole.shape, args)
     for a, b in zip(grads, whole_grads):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
@@ -215,6 +218,175 @@ def test_kda_schedule_is_journalled():
     assert attrs["path"] == "kernel" and "kda_fwd" in attrs["product"]
     assert attrs["saved_names"] == "kda_out"
     assert attrs["head_groups"] == 1
+
+
+# ---- the chunks' own work as a kernel pair ----------------------------
+
+
+def _chunked(args, chunk):
+    """``kda``'s operands as its two stages take them: [b * h, chunks,
+    C, w] blocks (beta [b * h, chunks, C]), the row padded with tokens
+    that leave the state as it is."""
+    q = args[0]
+    batch, seq, heads, _ = q.shape
+    chunks = -(-seq // chunk)
+
+    def rows(x):
+        x = jnp.swapaxes(x, 1, 2)
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, chunks * chunk - seq), (0, 0)))
+        return x.reshape(batch * heads, chunks, chunk, x.shape[-1])
+
+    q, k, v, g, beta = args
+    return rows(q), rows(k), rows(v), rows(g), rows(beta[..., None])[..., 0]
+
+
+def _own_work_both_ways(args, chunk, scale=0.3, xla=True):
+    """-> ((results, gradients) of the kernel pair, of ``_prepare``
+    unless ``xla`` is false), the gradients under random cotangents of
+    all six results."""
+    q, k, v, g, beta = _chunked(args, chunk)
+    bh, chunks = q.shape[:2]
+
+    def kernels(q, k, v, g, beta):
+        return kda_op._own_work(scale, q, k, v, g, beta[:, :, None, :])
+
+    def prepare(*operands):
+        outs = kda_op._prepare(
+            *(x.reshape((bh * chunks,) + x.shape[2:]) for x in operands),
+            chunk, scale,
+        )
+        return tuple(
+            x.reshape((bh, chunks) + x.shape[1:]) for x in outs[:5]
+        ) + (outs[5].reshape(bh, chunks, 1, -1),)
+
+    cotangents = [
+        jax.random.normal(jax.random.key(30 + i), x.shape)
+        for i, x in enumerate(jax.eval_shape(kernels, q, k, v, g, beta))
+    ]
+
+    def both(fn):
+        def loss(*operands):
+            return sum(
+                jnp.sum(x.astype(jnp.float32) * c)
+                for x, c in zip(fn(*operands), cotangents)
+            )
+
+        return jax.jit(
+            lambda *operands: (
+                fn(*operands), jax.grad(loss, tuple(range(5)))(*operands)
+            )
+        )(q, k, v, g, beta)
+
+    return both(kernels), both(prepare) if xla else None
+
+
+@pytest.mark.parametrize(
+    "dtype,chunk,seq,limit",
+    [
+        ("float32", 32, 64, 2e-5),  # two sub-blocks, the chunk divides
+        ("float32", 64, 100, 2e-5),  # four, the last chunk padded
+        ("bfloat16", 32, 64, 2e-2),
+        ("bfloat16", 64, 100, 2e-2),
+    ],
+)
+def test_chunk_kernels_equal_the_xla_own_work(dtype, chunk, seq, limit):
+    """``delta_chunk_fwd`` / ``delta_chunk_bwd`` (interpret mode)
+    against ``_prepare`` and its autodiff: every result, and every
+    operand's gradient under random cotangents of all six. In bfloat16
+    the forward rounds where ``_prepare`` rounds (nearly the same
+    bits); the hand-written backward keeps float32 where autodiff
+    rounds a cotangent to the operand's bfloat16."""
+    args = _kda_inputs(5, batch=1, seq=seq, dtype=jnp.dtype(dtype))
+    (got, got_grads), (want, want_grads) = _own_work_both_ways(args, chunk)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert _rel(a, b) < (1e-5 if dtype == "float32" else 4e-3)
+    for a, b in zip(got_grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert _rel(a, b) < limit
+
+
+@pytest.mark.parametrize(
+    "case", ["decay_e-40_a_token", "beta_near_0", "beta_near_1"]
+)
+def test_chunk_kernels_at_the_edges(case):
+    """A decay of e^-40 a token (``e^{-G}`` of a chunk overflows
+    float32; no exponent in the kernels is positive), a step ``beta``
+    of 1e-6 (``T`` ~ 0) and of 1 - 1e-6 (the inverse at its
+    largest): finite, and what ``_prepare`` gives."""
+    args = _kda_inputs(6, batch=1, seq=64,
+                       decay=40.0 if case.startswith("decay") else 0.5)
+    if case.startswith("beta"):
+        near = 1e-6 if case == "beta_near_0" else 1.0 - 1e-6
+        args = args[:4] + (jnp.full_like(args[4], near),)
+    (got, got_grads), (want, want_grads) = _own_work_both_ways(args, 32)
+    assert all(bool(jnp.isfinite(x).all()) for x in got + got_grads)
+    # (At e^-40 a token what is left of a product is differences of
+    # terms many times its size, in either program.)
+    loose = 50 if case.startswith("decay") else 1
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-5 * loose
+    for a, b in zip(got_grads, want_grads):
+        assert _rel(a, b) < 2e-5 * loose
+
+
+def test_the_unrolled_walk_of_a_sub_block_is_the_loop(monkeypatch):
+    """Compiled, the kernels walk a sub-block's tokens by unrolled
+    code, a tile of rows at a time and past the tiles before the
+    token (``_unrolled``: static rows and lanes, what
+    ``tests/test_chip_compile.py`` lowers for the chip); interpreted,
+    by a loop over all rows. One body, the same numbers."""
+    args = _kda_inputs(9, batch=1, heads=1, seq=32)
+    rolled, _ = _own_work_both_ways(args, 32, xla=False)
+    monkeypatch.setattr(kda_op, "_unrolled", lambda: True)
+    unrolled, _ = _own_work_both_ways(args, 32, xla=False)
+    for a, b in zip(jax.tree.leaves(unrolled), jax.tree.leaves(rolled)):
+        assert _rel(a, b) < 1e-6
+
+
+def test_kda_runs_its_own_work_in_the_kernels(monkeypatch):
+    """On the kernel path ``kda`` never calls the XLA ``_prepare``,
+    and is still the recurrence token by token, forward and
+    gradient; the ``kda.schedule`` event says what ran."""
+    def refuse(*_):
+        raise AssertionError("the XLA _prepare on the kernel path")
+
+    monkeypatch.setattr(kda_op, "_prepare", refuse)
+    args = _kda_inputs(7, seq=40)
+    run = functools.partial(kda_op.kda, chunk=32)
+    want = kda_op.kda_recurrent(*args)
+    assert _rel(jax.jit(run)(*args), want) < 1e-5
+    got = _grads(run, want.shape, args)
+    ref = _grads(kda_op.kda_recurrent, want.shape, args)
+    for a, b in zip(got, ref):
+        assert _rel(a, b) < 2e-5
+    attrs = [
+        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
+    ][-1]["attrs"]
+    assert attrs["own_work"] == "pallas:delta_chunk_fwd,delta_chunk_bwd"
+    assert "delta_chunk_bwd" in attrs["backward"]
+
+
+def test_kda_falls_back_where_the_kernels_do_not_fit(monkeypatch):
+    """Widths that are not whole lane tiles on the chip: neither
+    kernel pair is built; the scan and the XLA ``_prepare`` run."""
+    def refuse(*_, **__):
+        raise AssertionError("a kernel where the widths do not fit")
+
+    monkeypatch.setattr(kda_op, "_use_interpret", lambda: False)
+    assert not kda_op.kernel_fits(8, 8, 16)
+    assert kda_op.kernel_fits(128, 128, 64)
+    for name in ("_own_work", "_fwd_pallas", "_bwd_pallas"):
+        monkeypatch.setattr(kda_op, name, refuse)
+    args = _kda_inputs(8, seq=40)
+    run = functools.partial(kda_op.kda, chunk=16)
+    want = kda_op.kda_recurrent(*args)
+    assert _rel(jax.jit(run)(*args), want) < 1e-5
+    _grads(run, want.shape, args)
+    attrs = [
+        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
+    ][-1]["attrs"]
+    assert (attrs["path"], attrs["own_work"]) == ("fallback", "xla")
 
 
 # ---- latent attention -------------------------------------------------
