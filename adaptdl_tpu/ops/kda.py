@@ -54,9 +54,12 @@ a unit lower triangular solve a chunk. Two stages:
     autodiff (``_unit_lower_inverse``): where ``kernel_fits`` says no,
     and what the kernels are tested against.
 - **The recurrence over chunks**, two Pallas kernels (``kda_fwd``,
-  ``kda_bwd``; interpret mode off the TPU): one grid step a (batch,
-  head) and chunk, the float32 state (its gradient, walking the chunks
-  backwards) in VMEM scratch, four (nine) products a chunk on the MXU.
+  ``kda_bwd``; interpret mode off the TPU): a grid step takes a
+  group's heads abreast and several chunks of each in turn
+  (``_state_how``: what fits the kernels' VMEM, from the shapes
+  alone), a head's float32 state (its gradient, walking the chunks
+  backwards) in VMEM scratch, four (nine) products a chunk and head on
+  the MXU.
   The forward writes each chunk's starting state for the backward,
   which recomputes ``U`` from it. Where a head's widths are not whole
   lane tiles on the chip the same arithmetic runs as a ``lax.scan``
@@ -729,79 +732,162 @@ def _chunk_backward(d_state, state, qp, kd, wk, wv, b, dl, d_o):
     return grads, d_start
 
 
-def _fwd_kernel(qp, kd, wk, wv, b, dl, o_ref, st_ref, state):
+def _fwd_kernel(qp, kd, wk, wv, b, dl, o_ref, st_ref, states):
     ci = pl.program_id(1)
+    heads, held = qp.shape[:2]
 
     # (Every block access inside a ``when``: interpret mode under a
     # shard_map needs it, as the flash kernels note.)
     @pl.when(ci == 0)
     def _init():
-        state[...] = jnp.zeros_like(state)
+        states[...] = jnp.zeros_like(states)
 
     @pl.when(ci >= 0)
-    def _chunk():
-        start = state[...]
-        st_ref[0, 0] = start
-        o, state[...] = _chunk_forward(
-            start, qp[0, 0], kd[0, 0], wk[0, 0], wv[0, 0], b[0, 0],
-            dl[0, 0],
-        )
-        o_ref[0, 0] = o.astype(o_ref.dtype)
+    def _chunks():
+        # The block's chunks in turn, its heads abreast: independent
+        # recurrences in one straight line, which the scheduler
+        # interleaves.
+        def one(j, carry):
+            for h in range(heads):
+                start = states[h]
+                st_ref[h, j] = start
+                o, states[h] = _chunk_forward(
+                    start, qp[h, j], kd[h, j], wk[h, j], wv[h, j], b[h, j],
+                    dl[h, j],
+                )
+                o_ref[h, j] = o.astype(o_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, held, one, 0)
 
 
 def _bwd_kernel(
     qp, kd, wk, wv, b, dl, st, d_o,
-    d_qp, d_kd, d_wk, d_wv, d_b, d_dl, d_state,
+    d_qp, d_kd, d_wk, d_wv, d_b, d_dl, d_states,
 ):
-    ci = pl.program_id(1)  # the index maps walk the chunks backwards
+    # The index maps walk the blocks backwards, the loop a block's
+    # chunks.
+    ci = pl.program_id(1)
+    heads, held = qp.shape[:2]
 
     @pl.when(ci == 0)
     def _init():
-        d_state[...] = jnp.zeros_like(d_state)
+        d_states[...] = jnp.zeros_like(d_states)
 
     @pl.when(ci >= 0)
-    def _chunk():
-        grads, d_state[...] = _chunk_backward(
-            d_state[...], st[0, 0], qp[0, 0], kd[0, 0], wk[0, 0],
-            wv[0, 0], b[0, 0], dl[0, 0], d_o[0, 0],
+    def _chunks():
+        def one(i, carry):
+            j = held - 1 - i
+            for h in range(heads):
+                grads, d_states[h] = _chunk_backward(
+                    d_states[h], st[h, j], qp[h, j], kd[h, j], wk[h, j],
+                    wv[h, j], b[h, j], dl[h, j], d_o[h, j],
+                )
+                for ref, value in zip((d_qp, d_kd, d_wk, d_wv, d_b), grads):
+                    ref[h, j] = value.astype(ref.dtype)
+                d_dl[h, j] = grads[5]
+            return carry
+
+        lax.fori_loop(0, held, one, 0)
+
+
+# What the state kernels ask of Mosaic for one call (the chip's
+# default is 16 MiB of its 128), and the share of it that a grid
+# step's blocks, double-buffered as the pipeline holds them, and the
+# scratch states may take; the rest is the body's own values.
+_VMEM_LIMIT = 32 * 2**20
+_BLOCKS_SHARE = 0.5
+# Heads of a block at most: each is one more copy of the chunk's
+# products in the loop's body, which every program that holds the
+# kernels lowers (``_OWN_HELD``'s note). Measured on a v5e at the
+# kimi cell's shapes (bf16, 4 heads x 256 chunks of 64, heads of 128),
+# ms a call forward / backward at (heads, chunks) a grid step: (1, 1)
+# 0.613 / 0.770, (1, 8) 0.449 / 0.546, (4, 1) 0.321 / 0.437, (4, 8)
+# 0.243 / 0.370, where the blocks' DMAs alone take 0.222 / 0.344;
+# (4, 16) and (4, 32) are no faster.
+_STATE_HEADS = 4
+
+
+class _Held(NamedTuple):
+    """What one grid step of a state kernel holds."""
+
+    heads: int  # of the call's batch x heads, walked abreast
+    chunks: int  # of a head, walked in turn
+
+
+def _state_how(bh: int, chunks: int, chunk: int, dk: int, dv: int,
+               itemsize: int, backward: bool) -> _Held:
+    """The largest divisors of ``bh`` (up to ``_STATE_HEADS``) and of
+    ``chunks`` whose blocks fit ``_BLOCKS_SHARE`` of ``_VMEM_LIMIT``:
+    a grid step's fixed cost (~0.35 us) is then shared by all of them
+    and the heads' serial chains of small products overlap. Where
+    nothing larger divides or fits: (1, 1), a chunk of a head a step."""
+    def block(rows, width, size):  # in VMEM: whole (32 B x 128) tiles
+        sub = 32 // size
+        return -(-rows // sub) * sub * -(-width // _LANES) * _LANES * size
+
+    tall, state = block(chunk, dv, itemsize), block(dv, dk, 4)
+    operands = (  # qp, kd, wk; wv; B; the decay
+        3 * block(chunk, dk, itemsize) + tall
+        + block(chunk, chunk, itemsize) + block(1, dk, 4)
+    )
+    # Forward: o and the chunk's starting state out; backward: d_o and
+    # the state in, and a gradient an operand out.
+    one = operands + tall + state + (operands if backward else 0)
+    budget = _BLOCKS_SHARE * _VMEM_LIMIT
+
+    def fits(heads, held):
+        return (2 * held * one + state) * heads <= budget
+
+    def largest(count, most, ok):
+        return next(
+            (n for n in range(min(most, count), 1, -1)
+             if count % n == 0 and ok(n)), 1,
         )
-        for ref, value in zip((d_qp, d_kd, d_wk, d_wv, d_b), grads):
-            ref[0, 0] = value.astype(ref.dtype)
-        d_dl[0, 0] = grads[5]
+
+    heads = largest(bh, _STATE_HEADS, lambda n: fits(n, 1))
+    return _Held(heads, largest(chunks, chunks, lambda n: fits(heads, n)))
 
 
-def _specs(chunk: int, dk: int, dv: int, chunks: int, backwards: bool):
+def _specs(held: _Held, chunk: int, dk: int, dv: int, chunks: int,
+           backwards: bool):
     """Block specs of (a [bh, chunks, C, dk] operand, a [.., C, dv] one,
     B [.., C, C], the decay [bh, chunks, 1, dk], the states [bh,
-    chunks, dv, dk]): a chunk's rows as the chunks' own work leaves
-    them, so that no operand is laid out again on its way in."""
+    chunks, dv, dk]), ``held`` of each a grid step: a chunk's rows as
+    the chunks' own work leaves them, so that no operand is laid out
+    again on its way in."""
+    blocks = chunks // held.chunks
 
-    def at(ci):
-        return chunks - 1 - ci if backwards else ci
-
-    def rows(width):
+    def rows(height, width):
         return pl.BlockSpec(
-            (1, 1, chunk, width), lambda bh, ci: (bh, at(ci), 0, 0)
+            (held.heads, held.chunks, height, width),
+            lambda bh, ci: (bh, blocks - 1 - ci if backwards else ci, 0, 0),
         )
 
-    return (
-        rows(dk), rows(dv), rows(chunk),
-        pl.BlockSpec((1, 1, 1, dk), lambda bh, ci: (bh, at(ci), 0, 0)),
-        pl.BlockSpec((1, 1, dv, dk), lambda bh, ci: (bh, at(ci), 0, 0)),
-    )
+    return (rows(chunk, dk), rows(chunk, dv), rows(chunk, chunk),
+            rows(1, dk), rows(dv, dk))
 
 
-_PARAMS = dict(dimension_semantics=("parallel", "arbitrary"))
+_PARAMS = dict(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT,
+)
 
 
-def _fwd_pallas(qp, kd, wk, wv, b, dl):
+# (Functions jitted by themselves, as the chunks' own work is and for
+# its reason: a model traces the kernels at every call site of every
+# program, and four heads abreast are four times the body.)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _fwd_call(held: _Held, interpret: bool, qp, kd, wk, wv, b, dl):
     bh, chunks, chunk, dk = qp.shape
     dv = wv.shape[3]
-    wide, tall, square, decay, states = _specs(chunk, dk, dv, chunks, False)
+    wide, tall, square, decay, states = _specs(
+        held, chunk, dk, dv, chunks, False
+    )
     vma = jax.typeof(qp).vma
     return pl.pallas_call(
         _fwd_kernel,
-        grid=(bh, chunks),
+        grid=(bh // held.heads, chunks // held.chunks),
         in_specs=[wide, wide, wide, tall, square, decay],
         out_specs=[tall, states],
         out_shape=[
@@ -810,35 +896,47 @@ def _fwd_pallas(qp, kd, wk, wv, b, dl):
                 (bh, chunks, dv, dk), jnp.float32, vma=vma
             ),
         ],
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((held.heads, dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(**_PARAMS),
-        interpret=_use_interpret(),
+        interpret=interpret,
         name=FWD_KERNEL_NAME,
     )(qp, kd, wk, wv, b, dl)
 
 
-def _bwd_pallas(qp, kd, wk, wv, b, dl, st, d_o):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _bwd_call(held: _Held, interpret: bool, qp, kd, wk, wv, b, dl, st, d_o):
     bh, chunks, chunk, dk = qp.shape
     dv = wv.shape[3]
-    wide, tall, square, decay, states = _specs(chunk, dk, dv, chunks, True)
+    wide, tall, square, decay, states = _specs(
+        held, chunk, dk, dv, chunks, True
+    )
     vma = jax.typeof(qp).vma
-
-    def like(x, dtype=None):
-        return jax.ShapeDtypeStruct(x.shape, dtype or x.dtype, vma=vma)
-
     return pl.pallas_call(
         _bwd_kernel,
-        grid=(bh, chunks),
+        grid=(bh // held.heads, chunks // held.chunks),
         in_specs=[wide, wide, wide, tall, square, decay, states, tall],
         out_specs=[wide, wide, wide, tall, square, decay],
         out_shape=[
-            like(qp), like(kd), like(wk), like(wv), like(b), like(dl),
+            jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+            for x in (qp, kd, wk, wv, b, dl)
         ],
-        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((held.heads, dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(**_PARAMS),
-        interpret=_use_interpret(),
+        interpret=interpret,
         name=BWD_KERNEL_NAME,
     )(qp, kd, wk, wv, b, dl, st, d_o)
+
+
+def _fwd_pallas(qp, kd, wk, wv, b, dl):
+    held = _state_how(*qp.shape, wv.shape[3], qp.dtype.itemsize, False)
+    return _fwd_call(held, _use_interpret(), qp, kd, wk, wv, b, dl)
+
+
+def _bwd_pallas(qp, kd, wk, wv, b, dl, st, d_o):
+    held = _state_how(*qp.shape, wv.shape[3], qp.dtype.itemsize, True)
+    return tuple(
+        _bwd_call(held, _use_interpret(), qp, kd, wk, wv, b, dl, st, d_o)
+    )
 
 
 def _by_chunk(x):
@@ -970,6 +1068,11 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     chunks = -(-seq_len // chunk)
     kernel = kernel_fits(dk, dv, chunk) if use_kernel is None else use_kernel
     groups = head_groups(batch * seq_len, heads, dk)
+    bh = batch * heads // groups  # of one call of the state kernels
+    held, held_bwd = (
+        _state_how(bh, chunks, chunk, dk, dv, q.dtype.itemsize, backward)
+        if kernel else _Held(0, 0) for backward in (False, True)
+    )
     trace.event(
         "kda.schedule",
         heads=heads,
@@ -980,6 +1083,11 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         chunks=chunks,
         sub_block=_sub_block(chunk),
         head_groups=groups,
+        state_heads_a_step=held.heads,
+        state_chunks_a_step=held.chunks,
+        state_chunks_a_step_bwd=held_bwd.chunks,
+        state_grid_steps=(bh // held.heads) * (chunks // held.chunks)
+        if kernel else 0,
         padded=chunks * chunk - seq_len,
         dtype=q.dtype.name,
         path="kernel" if kernel else "fallback",

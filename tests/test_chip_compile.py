@@ -163,17 +163,24 @@ def test_flash_kernels_at_unequal_widths_compile_for_v5e(
         assert [g.shape[-1] for g in grads] == [192, 192, 128]
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("what", ["fwd", "grad"])
-def test_kda_kernels_compile_for_v5e(v5e, chip_compile, monkeypatch, what):
-    """The gated delta rule at the published widths (a group of four
-    heads of 128, chunks of 64, a row of 16 384, bf16): the state
+def test_kda_kernels_compile_for_v5e(
+    v5e, chip_compile, monkeypatch, what, dtype
+):
+    """The gated delta rule at the cell's real shape (a group of four
+    heads of 128, 256 chunks of 64 — a row of 16 384 —, bf16; and
+    float32 operands, whose blocks are twice as large): the state
     kernels are in the program under the names a device trace shows,
-    ``kda_fwd`` and, in a gradient's, ``kda_bwd``."""
+    ``kda_fwd`` and, in a gradient's, ``kda_bwd``, and the chip's
+    compiler takes the VMEM of the blocks ``_state_how`` chose (the
+    group's heads abreast, several chunks a grid step)."""
     kda = importlib.import_module("adaptdl_tpu.ops.kda")
+    trace = importlib.import_module("adaptdl_tpu.trace")
     monkeypatch.setattr(kda, "_use_interpret", lambda: False)
     one = SingleDeviceSharding(v5e.devices[0])
 
-    def arg(shape, dtype=jnp.bfloat16):
+    def arg(shape, dtype=jnp.dtype(dtype)):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     args = (
@@ -192,6 +199,13 @@ def test_kda_kernels_compile_for_v5e(v5e, chip_compile, monkeypatch, what):
     text = jax.jit(fn).lower(*args).compile().as_text()
     found = set(re.findall(r"%[\w\-]*?(kda_(?:fwd|bwd))[\w\-]*[.\d]* = ", text))
     assert found == ({"kda_fwd", "kda_bwd"} if what == "grad" else {"kda_fwd"})
+    attrs = [
+        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
+    ][-1]["attrs"]
+    held = 8 if dtype == "bfloat16" else 4
+    assert (attrs["state_heads_a_step"], attrs["state_chunks_a_step"],
+            attrs["state_chunks_a_step_bwd"]) == (4, held, held)
+    assert attrs["state_grid_steps"] == 256 // held  # 1024 at (1, 1)
 
 
 @pytest.mark.parametrize("what", ["fwd", "grad"])

@@ -218,6 +218,122 @@ def test_kda_schedule_is_journalled():
     assert attrs["path"] == "kernel" and "kda_fwd" in attrs["product"]
     assert attrs["saved_names"] == "kda_out"
     assert attrs["head_groups"] == 1
+    # What a grid step of the state kernels holds: here everything,
+    # both of the batch's rows x both heads and all three chunks.
+    assert (attrs["state_heads_a_step"], attrs["state_chunks_a_step"],
+            attrs["state_chunks_a_step_bwd"]) == (4, 3, 3)
+    assert attrs["state_grid_steps"] == 1
+
+
+# ---- what a grid step of the state kernels holds ----------------------
+
+
+def _state_operands(bh, chunks, dtype, chunk=16, width=8):
+    """What the chunks' own work hands the state kernels for ``bh``
+    heads of ``chunks`` chunks, and an output's cotangent."""
+    args = _kda_inputs(
+        11, batch=1, seq=chunks * chunk, heads=bh, dk=width, dv=width,
+        dtype=dtype,
+    )
+    operands = jax.jit(lambda *a: _prepare_blocks(a, chunk))(
+        *_chunked(args, chunk)
+    )
+    d_o = jnp.cos(jnp.arange(operands[3].size, dtype=jnp.float32))
+    return operands, d_o.reshape(operands[3].shape).astype(dtype)
+
+
+def _state_kernels(operands, d_o):
+    """-> (output, chunk states, the six gradients) of the kernels as
+    ``_state_how`` now schedules them."""
+    out, states = jax.jit(lambda *a: kda_op._fwd_pallas(*a))(*operands)
+    grads = jax.jit(lambda *a: kda_op._bwd_pallas(*a))(*operands, states, d_o)
+    return (out, states) + tuple(grads)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize(
+    "bh,chunks", [(4, 16), (4, 12), (3, 8), (1, 1), (2, 7), (8, 32)]
+)
+def test_state_kernels_in_blocks_are_the_kernels_a_chunk_a_step(
+    monkeypatch, bh, chunks, dtype
+):
+    """Several chunks and several heads a grid step (blocks of both,
+    of either, of neither; a prime chunk count; a head count that 4
+    does not divide) give, BIT FOR BIT, what one chunk of one head a
+    step gives: output, every chunk state, all six gradients; and
+    what the scans over the same two functions give."""
+    operands, d_o = _state_operands(bh, chunks, dtype)
+    # A sixteenth of the kernels' VMEM, so that these tiny blocks do
+    # not all fit one grid step.
+    monkeypatch.setattr(kda_op, "_BLOCKS_SHARE", 1 / 16)
+    size = jnp.dtype(dtype).itemsize
+    held, held_bwd = (
+        kda_op._state_how(bh, chunks, 16, 8, 8, size, backward)
+        for backward in (False, True)
+    )
+    assert bh % held.heads == 0 and chunks % held.chunks == 0
+    assert held_bwd.chunks <= held.chunks
+    assert (held == (1, 1)) == ((bh, chunks) == (1, 1))
+    if (bh, chunks) == (4, 16):  # the state crosses grid steps
+        assert (held, held_bwd.chunks) == ((4, 4), 2 if size == 4 else 4)
+    if (bh, chunks, size) == (2, 7, 4):  # seven do not fit: one
+        assert (held, held_bwd) == ((2, 7), (2, 1))
+    blocked = _state_kernels(operands, d_o)
+    monkeypatch.setattr(
+        kda_op, "_state_how", lambda *a: kda_op._Held(1, 1)
+    )
+    for got, want in zip(blocked, _state_kernels(operands, d_o)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32)
+        )
+    out, states = jax.jit(kda_op._fwd_scan)(*operands)
+    grads = jax.jit(kda_op._bwd_scan)(*operands, states, d_o)
+    assert _rel(blocked[0], out) < 1e-2 and _rel(blocked[1], states) < 1e-2
+    for got, want in zip(blocked[2:], grads):
+        assert _rel(got, want) < 2e-2
+
+
+def test_kda_in_blocks_on_a_padded_row_is_the_recurrence(monkeypatch):
+    """``kda`` end to end on a row whose last chunk is padded, the
+    state kernels in blocks of four heads and four (backward: two)
+    chunks: the recurrence token by token, forward and gradient."""
+    monkeypatch.setattr(kda_op, "_BLOCKS_SHARE", 1 / 16)
+    args = _kda_inputs(12, batch=1, seq=120, heads=4)
+    run = functools.partial(kda_op.kda, chunk=16)
+    want = kda_op.kda_recurrent(*args)
+    assert _rel(jax.jit(run)(*args), want) < 1e-5
+    got = _grads(run, want.shape, args)
+    for a, b in zip(got, _grads(kda_op.kda_recurrent, want.shape, args)):
+        assert _rel(a, b) < 2e-5
+    attrs = [
+        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
+    ][-1]["attrs"]
+    assert (attrs["chunks"], attrs["padded"]) == (8, 8)
+    assert (attrs["state_heads_a_step"], attrs["state_chunks_a_step"],
+            attrs["state_chunks_a_step_bwd"]) == (4, 4, 2)
+    assert attrs["state_grid_steps"] == 2
+
+
+def test_the_state_schedule_is_a_pure_function_of_its_shapes():
+    """At the cell's shapes (four heads of 128, 256 chunks of 64,
+    bf16) a grid step holds the group's four heads and eight chunks;
+    wider operands take fewer, the backward never more than the
+    forward; where nothing larger divides or fits, (1, 1)."""
+    how, held = kda_op._state_how, kda_op._Held
+    cell = (4, 256, 64, 128, 128)
+    for _ in range(2):
+        assert how(*cell, 2, False) == held(4, 8) == how(*cell, 2, True)
+    assert how(*cell, 4, False) == held(4, 4) == how(*cell, 4, True)
+    assert how(4, 256, 64, 256, 256, 2, False) == held(4, 4)
+    assert how(4, 256, 64, 256, 256, 2, True) == held(4, 2)
+    assert how(6, 256, 64, 128, 128, 2, False) == held(3, 16)
+    assert how(6, 256, 64, 128, 128, 2, True) == held(3, 8)
+    assert how(32, 12, 64, 128, 128, 2, False) == held(4, 12)
+    assert how(1, 1, 64, 128, 128, 2, False) == held(1, 1)
+    assert how(1, 7, 64, 128, 128, 2, True) == held(1, 7)
+    for backward in (False, True):  # neither 5 nor 257 has a divisor
+        assert how(5, 257, 64, 128, 128, 2, backward) == held(1, 1)
 
 
 # ---- the chunks' own work as a kernel pair ----------------------------
@@ -240,24 +356,30 @@ def _chunked(args, chunk):
     return rows(q), rows(k), rows(v), rows(g), rows(beta[..., None])[..., 0]
 
 
+def _prepare_blocks(operands, chunk, scale=0.3):
+    """The XLA ``_prepare`` on ``_chunked``'s blocks, its six results
+    as the state kernels take them: [b * h, chunks, ...]."""
+    bh, chunks = operands[0].shape[:2]
+    outs = kda_op._prepare(
+        *(x.reshape((bh * chunks,) + x.shape[2:]) for x in operands),
+        chunk, scale,
+    )
+    return tuple(
+        x.reshape((bh, chunks) + x.shape[1:]) for x in outs[:5]
+    ) + (outs[5].reshape(bh, chunks, 1, -1),)
+
+
 def _own_work_both_ways(args, chunk, scale=0.3, xla=True):
     """-> ((results, gradients) of the kernel pair, of ``_prepare``
     unless ``xla`` is false), the gradients under random cotangents of
     all six results."""
     q, k, v, g, beta = _chunked(args, chunk)
-    bh, chunks = q.shape[:2]
 
     def kernels(q, k, v, g, beta):
         return kda_op._own_work(scale, q, k, v, g, beta[:, :, None, :])
 
     def prepare(*operands):
-        outs = kda_op._prepare(
-            *(x.reshape((bh * chunks,) + x.shape[2:]) for x in operands),
-            chunk, scale,
-        )
-        return tuple(
-            x.reshape((bh, chunks) + x.shape[1:]) for x in outs[:5]
-        ) + (outs[5].reshape(bh, chunks, 1, -1),)
+        return _prepare_blocks(operands, chunk, scale)
 
     cotangents = [
         jax.random.normal(jax.random.key(30 + i), x.shape)
@@ -387,6 +509,7 @@ def test_kda_falls_back_where_the_kernels_do_not_fit(monkeypatch):
         r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
     ][-1]["attrs"]
     assert (attrs["path"], attrs["own_work"]) == ("fallback", "xla")
+    assert attrs["state_grid_steps"] == attrs["state_heads_a_step"] == 0
 
 
 # ---- latent attention -------------------------------------------------
