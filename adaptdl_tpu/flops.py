@@ -69,9 +69,11 @@ def transformer_train_flops(
     ``top_k`` expert FFNs plus the router per token — the capacity
     padding all_to_all moves is communication, not model FLOPs. A
     looped model (``loop_passes`` > 1) runs every block and, in
-    training over all its exits, the head once a pass. A "kda" or
-    "mla" layer (``layer_types``) is counted at its own projections
-    and mixing (``_kda_layer`` / ``_mla_layer``); a layer with routed
+    training over all its exits, the head once a pass. A "kda", "gdn"
+    or "mla" layer (``layer_types``), and a "full_attention" layer of
+    a model with ``attention_gate``, is counted at its own projections
+    and mixing (``_kda_layer`` / ``_gdn_layer`` / ``_mla_layer`` /
+    ``_gated_attention_layer``); a layer with routed
     experts at its router, the HELD share of the routed experts under
     even routing, and the shared expert.
     """
@@ -103,18 +105,18 @@ def transformer_train_flops(
     proj = 2 * (4 * d * d)  # fused QKV (3 d^2) + output (d^2), per token
     head = 2 * d * config.vocab_size  # LM head, per token
     kinds = getattr(config, "layer_types", None) or ()
-    new_kinds = [k for k in kinds if k in ("kda", "mla")]
+    apart = {"kda": _kda_layer, "mla": _mla_layer, "gdn": _gdn_layer}
+    if getattr(config, "attention_gate", False):
+        apart["full_attention"] = _gated_attention_layer
+    new_kinds = [k for k in kinds if k in apart]
     extra_matmul = extra_attn = 0.0
     if new_kinds:
         # These layers' mixers are not ``proj`` + softmax attention at
         # d_model, and their FFN may be routed: counted apart.
         for layer, kind in enumerate(kinds):
-            if kind not in ("kda", "mla"):
+            if kind not in apart:
                 continue
-            mix, attn = (
-                _kda_layer(config) if kind == "kda"
-                else _mla_layer(config, seq_len)
-            )
+            mix, attn = apart[kind](config, seq_len)
             extra_matmul += mix - proj
             extra_attn += attn
             if config.routed(layer):
@@ -142,7 +144,7 @@ def transformer_train_flops(
     )
 
 
-def _kda_layer(config) -> tuple[float, float]:
+def _kda_layer(config, seq_len: int = 0) -> tuple[float, float]:
     """(projection FLOPs, mixing FLOPs) a token of one "kda" layer:
     q, k, v and out at ``heads * head_dim``, the two low-rank gate
     pairs and beta; the chunked delta rule's products a chunk (its own
@@ -158,6 +160,41 @@ def _kda_layer(config) -> tuple[float, float]:
     )
     mixing = 2 * heads * (4 * chunk * hd + 3 * hd * hd + chunk * hd)
     return float(proj), float(mixing)
+
+
+def _gdn_layer(config, seq_len: int = 0) -> tuple[float, float]:
+    """(projection FLOPs, mixing FLOPs) a token of one "gdn" layer:
+    q and k of the key heads, v and z of the value heads, b and a, and
+    out; the chunked delta rule's products a chunk and value head, as
+    ``_kda_layer`` counts them."""
+    from adaptdl_tpu.ops.kda import CHUNK as chunk  # where it is used
+
+    d, heads = config.d_model, config.linear_value_heads
+    dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
+    k_width, v_width = config.linear_key_heads * dk, heads * dv
+    proj = 2 * (
+        d * (2 * k_width + 2 * v_width) + d * 2 * heads + v_width * d
+    )
+    mixing = 2 * heads * (
+        3 * chunk * dk + chunk * dv + 3 * dk * dv + chunk * dv
+    )
+    return float(proj), float(mixing)
+
+
+def _gated_attention_layer(config, seq_len: int) -> tuple[float, float]:
+    """(projection FLOPs, attention FLOPs) a token of a grouped-query
+    attention layer with an output gate: q twice as wide (the gate), k
+    and v of the kv heads, out; QK^T and PV at ``head_dim``, the causal
+    half."""
+    d, heads, hd = config.d_model, config.num_heads, config.attention_head_dim
+    kv_heads = config.num_kv_heads or heads
+    proj = 2 * (
+        d * heads * 2 * hd + d * 2 * kv_heads * hd + heads * hd * d
+    )
+    attn = 2 * seq_len * heads * 2 * hd
+    if getattr(config, "causal", True):
+        attn /= 2
+    return float(proj), float(attn)
 
 
 def _mla_layer(config, seq_len: int) -> tuple[float, float]:
@@ -182,7 +219,7 @@ def _mla_layer(config, seq_len: int) -> tuple[float, float]:
 def _routed_ffn(config) -> float:
     """FLOPs a token of a routed layer's FFN: the router over all
     experts, ``top_k * held / total`` gated experts (even routing),
-    the shared expert."""
+    the shared expert and its gate."""
     d = config.d_model
     held = config.experts_held or config.experts_total
     experts = config.experts_top_k * held / config.experts_total
@@ -190,6 +227,7 @@ def _routed_ffn(config) -> float:
         2 * d * config.experts_total
         + experts * 2 * 3 * d * config.d_expert
         + 2 * 3 * d * config.d_shared_expert
+        + (2 * d if getattr(config, "shared_expert_gate", False) else 0)
     )
 
 

@@ -532,6 +532,35 @@ def _gather_rows(buffer, dest):
     return jnp.where(inside, taken, 0)
 
 
+# Rows back to tokens gathers ``[tokens, top_k, d]`` in one array
+# while that is at most this many bytes (the accepted cells: 256 to 576
+# MiB); beyond, a choice at a time into a float32 sum (16 384 tokens x
+# 10 choices x 2048 in bfloat16 are 640 MiB, 1 GiB with the ten choices
+# padded to a sublane tile of sixteen, and the compiler refused the
+# step that held it beside 626 M parameters by 882 MiB).
+_CHOICES_AT_ONCE_BYTES = 600 * 2**20
+
+
+def _tokens_from_rows(buffer, dest, weights=None):
+    """``sum_j weights[t, j] * buffer[dest[t, j]]`` (``weights`` None:
+    ones), rows outside the buffer as zeros: ``[tokens, d]`` in the
+    buffer's dtype."""
+    tokens, top_k = dest.shape
+    size = tokens * top_k * buffer.shape[1] * buffer.dtype.itemsize
+    if size <= _CHOICES_AT_ONCE_BYTES:
+        taken = _gather_rows(buffer, dest)
+        if weights is None:
+            return taken.sum(axis=1)
+        return jnp.einsum("tjd,tj->td", taken, weights.astype(buffer.dtype))
+    total = jnp.zeros((tokens, buffer.shape[1]), jnp.float32)
+    for j in range(top_k):
+        taken = _gather_rows(buffer, dest[:, j]).astype(jnp.float32)
+        if weights is not None:
+            taken = taken * weights[:, j, None].astype(jnp.float32)
+        total = total + taken
+    return total.astype(buffer.dtype)
+
+
 def _tile(plan: RowPlan) -> int:
     return plan.row_token.shape[0] // plan.tile_expert.shape[0]
 
@@ -566,10 +595,7 @@ def _forward_rows(start, rows: int, x, weights, w_gate, w_up, w_down, plan):
     up = gmm.grouped_matmul(taken, w_up, *groups)
     hidden = jax.nn.silu(gate) * up
     y_rows = gmm.grouped_matmul(hidden, w_down, *groups)
-    y = jnp.einsum(
-        "tjd,tj->td", _gather_rows(y_rows, plan.dest - start),
-        weights.astype(y_rows.dtype),
-    )
+    y = _tokens_from_rows(y_rows, plan.dest - start, weights)
     return y, (taken, gate, up, hidden, y_rows)
 
 
@@ -609,7 +635,7 @@ def _backward_rows(
     d_taken_up, d_w_up = gmm.grouped_matmul_transposes(
         taken, w_up, *groups, d_up
     )
-    d_x = _gather_rows(d_taken_gate + d_taken_up, dest).sum(axis=1)
+    d_x = _tokens_from_rows(d_taken_gate + d_taken_up, dest)
     return d_x, d_weights.astype(weights.dtype), d_w_gate, d_w_up, d_w_down
 
 
@@ -705,6 +731,7 @@ def routed_experts(
     norm_eps: float = 1e-20,
     scale: float = 1.0,
     router_kind: str = "sigmoid",
+    shared_gate: str = "none",
 ):
     """One chip's share of a dropless top-k expert layer.
 
@@ -725,7 +752,9 @@ def routed_experts(
     or the pieces of ``rows_bound`` rows it took)
     and ``fell_back`` (1 where it did not), beside the router's own
     result, ``experts`` and ``weights`` ``[tokens,
-    top_k]``, for whoever checks the routing itself.
+    top_k]``, for whoever checks the routing itself. ``shared_gate``:
+    what the caller multiplies its shared expert by ("sigmoid", or
+    "none": no gate, or no shared expert), said in ``moe.schedule``.
     """
     tokens, _ = x.shape
     experts_held = w_gate.shape[0]
@@ -737,7 +766,9 @@ def routed_experts(
         )
     assert (bias is None) == (router_kind == "softmax")
     assert 0 <= first_expert <= experts_total - experts_held
-    tile = gmm.tile_rows(tokens * min(top_k, experts_held))
+    tile = gmm.tile_rows(
+        tokens * min(top_k, experts_held), tokens * top_k / experts_total
+    )
     capacity = rows_planned(
         tokens, top_k, experts_held, experts_total, tile
     )
@@ -757,6 +788,7 @@ def routed_experts(
         dtype=x.dtype.name,
         product="pallas:" + gmm.GMM_KERNEL_NAME + "," + gmm.TGMM_KERNEL_NAME,
         router=router_kind,
+        shared_gate=shared_gate,
     )
     if router_kind == "softmax":
         experts, weights = softmax_top_k(x, router, top_k, norm_eps, scale)

@@ -195,9 +195,53 @@ class TransformerConfig:
     # width on EVERY token of a routed layer, unweighted, added to the
     # routed experts' result. 0 = none.
     d_shared_expert: int = 0
+    # The shared expert's result multiplied by ``sigmoid(x w_s)``, one
+    # learned gate ``d_model -> 1`` a routed layer (``shared_gate``).
+    shared_expert_gate: bool = False
+    # Rotary over the first ``rotary_dims`` lanes of a head only (the
+    # others pass untouched); None = every lane.
+    rotary_dims: int | None = None
+    # An output gate on softmax attention: ``q`` is projected twice as
+    # wide, a head's second half the gate, and ``out`` takes ``o *
+    # sigmoid(gate)``. Selects ``GroupedQueryAttention``.
+    attention_gate: bool = False
+    # With ``norm="rmsnorm"``: every norm of the model (block norms,
+    # final norm, the attention's head norms) scales by ``1 + w``, ``w``
+    # initialised 0 (``ZeroCentredRMSNorm``).
+    norm_zero_centred: bool = False
+    # The "gdn" mixer kind: the gated delta rule with ONE decay a value
+    # head and token (``GatedDeltaNet``): ``linear_key_heads`` heads of
+    # ``linear_key_head_dim`` for q and k serve ``linear_value_heads``
+    # heads of ``linear_value_head_dim`` for v, one depthwise causal
+    # convolution of ``conv_kernel`` taps over q, k and v.
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
 
     def __post_init__(self):
         kinds = self.layer_types or ()
+        if "gdn" in kinds:
+            if self.seq_axis is not None:
+                raise ValueError(
+                    "seq_axis: a 'gdn' layer has no sequence-parallel "
+                    "path (its state crosses the whole row)"
+                )
+            for name in ("linear_key_heads", "linear_value_heads",
+                         "linear_key_head_dim", "linear_value_head_dim"):
+                if getattr(self, name) <= 0:
+                    raise ValueError(f"{name} must be set for 'gdn' layers")
+            if self.linear_value_heads % self.linear_key_heads:
+                raise ValueError(
+                    f"{self.linear_key_heads} key heads do not divide "
+                    f"{self.linear_value_heads} value heads"
+                )
+        if self.norm_zero_centred and self.norm != "rmsnorm":
+            raise ValueError("norm_zero_centred takes norm='rmsnorm'")
+        if self.shared_expert_gate and self.d_shared_expert <= 0:
+            raise ValueError(
+                "shared_expert_gate: no shared expert (d_shared_expert is 0)"
+            )
         if "kda" in kinds and self.seq_axis is not None:
             raise ValueError(
                 "seq_axis: a 'kda' layer has no sequence-parallel path "
@@ -248,11 +292,14 @@ class TransformerConfig:
 
 
 def rope(
-    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+    rotary_dims: int | None = None,
 ) -> jnp.ndarray:
     """Rotary position embedding over the last (head_dim) axis:
     adjacent pairs ``(x[2i], x[2i + 1])`` turned by ``positions *
-    theta ** (-2i / head_dim)``.
+    theta ** (-2i / head_dim)``. With ``rotary_dims`` only the first
+    that many lanes turn (by ``theta ** (-2i / rotary_dims)``); the
+    others have cosine 1 and sine 0, exactly.
 
     x: [batch, seq, heads, head_dim], the projection's own layout;
     positions: [seq].
@@ -267,7 +314,14 @@ def rope(
     values are the same to the bit.
     """
     lane = jnp.arange(x.shape[-1])
-    freqs = 1.0 / (theta ** ((lane // 2 * 2) / x.shape[-1]))
+    if rotary_dims is None:
+        freqs = 1.0 / (theta ** ((lane // 2 * 2) / x.shape[-1]))
+    else:
+        assert rotary_dims % 2 == 0 and rotary_dims <= x.shape[-1]
+        freqs = jnp.where(
+            lane < rotary_dims,
+            1.0 / (theta ** ((lane // 2 * 2) / rotary_dims)), 0.0,
+        )
     angles = positions[:, None] * freqs[None, :]  # [seq, head_dim]
     sin = jnp.sin(angles).astype(x.dtype)
     sin = jnp.where(lane % 2 == 0, -sin, sin)[:, None, :]
@@ -363,11 +417,37 @@ class Attention(nn.Module):
         )(out)
 
 
+class ZeroCentredRMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + epsilon) * (1 + w)`` over the last
+    axis, ``w`` initialised 0; statistics and the scaling in float32,
+    the result in ``dtype``."""
+
+    epsilon: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.zeros, (x.shape[-1],), jnp.float32
+        )
+        x32 = x.astype(jnp.float32)
+        normed = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, -1, keepdims=True) + self.epsilon
+        )
+        return (normed * (1.0 + scale)).astype(self.dtype)
+
+
+def _rms_norm(cfg: TransformerConfig, name: str | None = None):
+    """The config's RMSNorm (a block's, the final one, a head's)."""
+    kind = ZeroCentredRMSNorm if cfg.norm_zero_centred else nn.RMSNorm
+    return kind(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+
 def make_norm(cfg: TransformerConfig):
     """The config's normalisation, scale only: LayerNorm or RMSNorm
     with ``norm_eps`` (statistics in float32, result in ``dtype``)."""
     if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+        return _rms_norm(cfg)
     if cfg.norm != "layernorm":
         raise ValueError(
             f"norm must be 'layernorm' or 'rmsnorm', got {cfg.norm!r}"
@@ -377,13 +457,58 @@ def make_norm(cfg: TransformerConfig):
     )
 
 
+def _heads_a_call(attn, heads, seq_len, qk_width, v_width, itemsize):
+    """How many heads one call of ``attn`` is given: as many as the
+    flash kernels want at once (``ops.flash_attention.heads_a_call``,
+    at the attention's own blocks where it says them: past 16k keys
+    the backward writes a float32 dQ a key chunk for the heads it is
+    given). Any ``attention_fn`` is asked so: a ``functools.partial``
+    of the kernel says nothing of itself but its blocks. All of them
+    for plain attention (``attn`` None)."""
+    if attn is None:
+        return heads
+    from adaptdl_tpu.ops.flash_attention import heads_a_call
+
+    blocks = {
+        k: v for k, v in getattr(attn, "keywords", {}).items()
+        if k in ("block_q", "block_k")
+    }
+    return getattr(attn, "heads_a_call", heads_a_call)(
+        heads, seq_len, qk_width, v_width, itemsize, **blocks,
+    )
+
+
+def _attend_in_runs(attn, q, k, v, run, kv_of=None):
+    """``attn`` on ``[b, s, h, d]`` operands, ``run`` heads a call, a
+    run's result let go before the next run's: ``[b, h, s, d_v]``.
+    ``kv_of(t, at)``: the heads ``at .. at + run`` of k or v where they
+    are not a slice of it (kv heads shared by several query heads)."""
+    def own(t, at):
+        return t[:, :, at:at + run]
+
+    parts = ((q, own), (k, kv_of or own), (v, kv_of or own))
+    return jnp.concatenate(
+        [
+            attn(*(jnp.swapaxes(part(t, at), 1, 2) for t, part in parts))
+            for at in range(0, q.shape[2], run)
+        ],
+        axis=1,
+    )
+
+
 class GroupedQueryAttention(nn.Module):
     """Causal attention with fewer key/value heads than query heads,
     optional per-head RMSNorm on q and k, and the config's rotary
-    base. ``attention_fn`` keeps its ``[b, h, s, d]`` contract with
+    base (over the first ``rotary_dims`` lanes where that is set).
+    ``attention_fn`` keeps its ``[b, h, s, d]`` contract with
     equal head counts: each kv head is repeated ``group`` times on the
     way in, and autodiff sums dK / dV over the group on the way out
-    (a kv index inside the flash kernels is later work)."""
+    (a kv index inside the flash kernels is later work). With
+    ``attention_gate`` the ``q`` projection is twice as wide, a head's
+    second half a gate, and ``out`` takes ``o * sigmoid(gate)``; the
+    heads then go in runs of as many as the flash kernels want at
+    once, and ``gated_attn.schedule`` is journalled where the module
+    is traced."""
 
     config: TransformerConfig
 
@@ -401,39 +526,75 @@ class GroupedQueryAttention(nn.Module):
             f"{cfg.num_heads} query heads on {kv_heads} kv heads"
         )
         q = nn.DenseGeneral(
-            (cfg.num_heads, head_dim), axis=-1, dtype=cfg.dtype,
-            use_bias=False, name="q",
+            (cfg.num_heads, (2 if cfg.attention_gate else 1) * head_dim),
+            axis=-1, dtype=cfg.dtype, use_bias=False, name="q",
         )(x)
+        if cfg.attention_gate:
+            q, gate = q[..., :head_dim], q[..., head_dim:]
         kv = nn.DenseGeneral(
             (2, kv_heads, head_dim), axis=-1, dtype=cfg.dtype,
             use_bias=False, name="kv",
         )(x)
         k, v = jnp.moveaxis(kv, -3, 0)  # each [b, s, kv_heads, d]
         if cfg.qk_norm:
-            q = nn.RMSNorm(
-                epsilon=cfg.norm_eps, dtype=cfg.dtype, name="q_norm"
-            )(q)
-            k = nn.RMSNorm(
-                epsilon=cfg.norm_eps, dtype=cfg.dtype, name="k_norm"
-            )(k)
+            q = _rms_norm(cfg, "q_norm")(q)
+            k = _rms_norm(cfg, "k_norm")(k)
         if cfg.rope:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
+            q = rope(q, positions, cfg.rope_theta, cfg.rotary_dims)
+            k = rope(k, positions, cfg.rope_theta, cfg.rotary_dims)
         attn = cfg.attention_fn
+        run = cfg.num_heads
+        if cfg.attention_gate:
+            run = _heads_a_call(
+                attn, cfg.num_heads, x.shape[1], head_dim, head_dim,
+                jnp.dtype(cfg.dtype).itemsize,
+            )
+            trace.event(
+                "gated_attn.schedule",
+                heads=cfg.num_heads,
+                kv_heads=kv_heads,
+                head_dim=head_dim,
+                rotary_dims=(cfg.rotary_dims or head_dim) if cfg.rope else 0,
+                gate="sigmoid",
+                heads_a_call=run,
+                seq_len=x.shape[1],
+                dtype=jnp.dtype(cfg.dtype).name,
+                attention="attention_fn" if attn is not None
+                else "plain causal attention",
+            )
         if attn is None:
             from functools import partial
 
             attn = partial(causal_attention, causal=cfg.causal)
-        out = attn(
-            jnp.swapaxes(q, 1, 2),
-            jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2),
-        )  # [b, h, s, d]
-        out = jnp.swapaxes(out, 1, 2).reshape(
-            x.shape[:-1] + (cfg.num_heads * head_dim,)
-        )
+        if run < cfg.num_heads:
+            # A run of heads a call, and each run's kv heads repeated
+            # for that run alone: at 16 heads of 256 on 2 kv heads and
+            # 16 384 keys, k and v repeated for all heads at once are
+            # 128 MiB each, and as much again their gradients.
+            assert run % group == 0 or group % run == 0, (run, group)
+
+            def kv_of(t, at):  # the run's heads of k or v
+                if run <= group:
+                    one = t[:, :, at // group:at // group + 1]
+                    return jnp.repeat(one, run, axis=2)
+                some = t[:, :, at // group:(at + run) // group]
+                return jnp.repeat(some, group, axis=2)
+
+            out = _attend_in_runs(attn, q, k, v, run, kv_of)
+        else:
+            k = jnp.repeat(k, group, axis=2)
+            v = jnp.repeat(v, group, axis=2)
+            out = attn(
+                jnp.swapaxes(q, 1, 2),
+                jnp.swapaxes(k, 1, 2),
+                jnp.swapaxes(v, 1, 2),
+            )  # [b, h, s, d]
+        out = jnp.swapaxes(out, 1, 2)
+        if cfg.attention_gate:
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                cfg.dtype
+            )
+        out = out.reshape(x.shape[:-1] + (cfg.num_heads * head_dim,))
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
         )(out)
@@ -613,6 +774,17 @@ def _dense_f32(x, kernel):
 KDA_L2_EPS = 1e-6
 
 
+def _l2_unit(t, dtype):
+    """``t`` L2-normalised over its last axis (a head), statistics in
+    float32, the result in ``dtype``."""
+    t32 = t.astype(jnp.float32)
+    return (
+        t32 * jax.lax.rsqrt(
+            jnp.sum(t32 * t32, -1, keepdims=True) + KDA_L2_EPS
+        )
+    ).astype(dtype)
+
+
 class KDA(nn.Module):
     """Gated delta-rule linear attention with a per-channel decay
     (``ops.kda``). ``q, k, v = silu(conv(x W))`` (depthwise causal
@@ -683,14 +855,6 @@ class KDA(nn.Module):
             )
         )
 
-        def unit(t):  # L2-normalised a head, statistics in float32
-            t32 = t.astype(jnp.float32)
-            return (
-                t32 * jax.lax.rsqrt(
-                    jnp.sum(t32 * t32, -1, keepdims=True) + KDA_L2_EPS
-                )
-            ).astype(cfg.dtype)
-
         def prepare(q, k, v, _, beta, taps, decay_out, dt_bias, a_log):
             """A group of heads: the projections' [b, s, h, d] into the
             rule's operands; ``taps`` [h, 3, taps, d], ``decay_out``
@@ -712,7 +876,8 @@ class KDA(nn.Module):
                     preferred_element_type=jnp.float32,
                 ) + dt_bias
             )
-            return unit(conv(q, 0)), unit(conv(k, 1)), conv(v, 2), decay, beta
+            q, k = (_l2_unit(conv(t, i), cfg.dtype) for i, t in enumerate((q, k)))
+            return q, k, conv(v, 2), decay, beta
 
         def per_head(t, axis):  # the heads' axis first, heads apart
             shape = t.shape[:axis] + (heads, head_dim) + t.shape[axis + 1:]
@@ -733,6 +898,109 @@ class KDA(nn.Module):
         out = (out * gate.astype(cfg.dtype)).reshape(
             x.shape[:2] + (width,)
         )
+        return nn.DenseGeneral(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
+        )(out)
+
+
+class GatedDeltaNet(nn.Module):
+    """The gated delta rule with ONE decay a value head and token
+    (``ops.kda`` with ``g`` a head): ``[q, k, v, z] = x W`` (key
+    heads' q and k, value heads' v and z), ``[b, a] = x W_ba``; ``[q,
+    k, v] = silu(conv([q, k, v]))``, one depthwise causal convolution
+    over all their channels (zero history, no bias); q and k
+    L2-normalised a key head, key head j on the value heads ``j *
+    value_heads / key_heads ..``; ``beta = sigmoid(b)`` and the
+    log-decay ``g = -exp(A_log[h]) softplus(a + dt_bias[h])`` a value
+    head, float32; the state is zero at a row's start and crosses the
+    whole row. ``y = (rmsnorm_head(o) * scale * silu(z)) Wo``, the head
+    norm's scale plain (initialised 1, shared by the heads). Journals
+    ``kda.schedule`` where it is traced."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from adaptdl_tpu.ops import kda as kda_op
+
+        del positions  # the recurrence orders the tokens
+        cfg = self.config
+        k_heads, v_heads = cfg.linear_key_heads, cfg.linear_value_heads
+        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        k_width, v_width = k_heads * dk, v_heads * dv
+        fan_in = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+        qkvz = nn.Dense(
+            2 * k_width + 2 * v_width, dtype=cfg.dtype, use_bias=False,
+            name="in_proj",
+        )(x)
+        # taps[j] multiplies the channel's value at t - (taps - 1 - j).
+        taps = self.param(
+            "conv",
+            nn.initializers.variance_scaling(
+                1.0, "fan_in", "normal", in_axis=-2, out_axis=-1
+            ),
+            (cfg.conv_kernel, 2 * k_width + v_width), jnp.float32,
+        )
+        a_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(
+                16.0 * (1.0 - jax.random.uniform(key, shape, jnp.float32))
+            ),  # log of uniform (0, 16]
+            (v_heads,),
+        )
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (v_heads,))
+        b, a = jnp.moveaxis(
+            jnp.einsum(
+                "...d,dgh->...gh", x,
+                self.param(
+                    "ba", fan_in, (cfg.d_model, 2, v_heads), jnp.float32
+                ).astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ),
+            -2, 0,
+        )
+        beta = jax.nn.sigmoid(b)
+        decay = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)  # [b, s, h]
+
+        def prepare(q, k, v, decay, beta, *taps):
+            """A group of heads: the projections' [b, s, h, d] into the
+            rule's operands; ``taps`` of q, k and v, each [h, taps, d]."""
+
+            def conv(z, w):
+                w = w.astype(cfg.dtype)
+                n, seq_len = w.shape[1], z.shape[1]
+                padded = jnp.pad(z, ((0, 0), (n - 1, 0), (0, 0), (0, 0)))
+                return nn.silu(sum(
+                    w[:, j] * padded[:, j:j + seq_len] for j in range(n)
+                ))
+
+            q, k, v = (conv(z, w) for z, w in zip((q, k, v), taps))
+            q, k = (_l2_unit(t, cfg.dtype) for t in (q, k))
+            return q, k, v, decay, beta
+
+        def heads_of(t, at, heads, width):  # channels at.. as heads
+            return t[..., at:at + heads * width].reshape(
+                t.shape[:-1] + (heads, width)
+            )
+
+        parts = (
+            (0, k_heads, dk), (k_width, k_heads, dk),
+            (2 * k_width, v_heads, dv),
+        )
+        q, k, v = (heads_of(qkvz, *part) for part in parts)
+        out = kda_op.kda(
+            q, k, v, decay, beta, prepare=prepare,
+            per_head=tuple(
+                jnp.moveaxis(heads_of(taps, *part), 0, 1) for part in parts
+            ),
+        )  # [b, s, v_heads, dv]
+        out = nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=cfg.dtype, name="o_norm"
+        )(out)
+        z = heads_of(qkvz, 2 * k_width + v_width, v_heads, dv)
+        out = (
+            out * jax.nn.silu(z.astype(jnp.float32)).astype(cfg.dtype)
+        ).reshape(x.shape[:2] + (v_width,))
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
         )(out)
@@ -784,26 +1052,11 @@ class LatentAttention(nn.Module):
             )
         v = kv[..., nope:]
         attn = cfg.attention_fn
-        # Heads in runs, one call a run, as many as the flash kernels
-        # want at once (``ops.flash_attention.heads_a_call``, at the
-        # attention's own blocks where it says them: past 16k keys the
-        # backward writes a float32 dQ a key chunk for the heads it is
-        # given; at 16 384 keys of 192: four), and a run's is let go
-        # before the next run's. Any ``attention_fn`` is asked so: a
-        # ``functools.partial`` of the kernel says nothing of itself
-        # but its blocks.
-        run = heads
-        if attn is not None:
-            from adaptdl_tpu.ops.flash_attention import heads_a_call
-
-            blocks = {
-                k: v for k, v in getattr(attn, "keywords", {}).items()
-                if k in ("block_q", "block_k")
-            }
-            run = getattr(attn, "heads_a_call", heads_a_call)(
-                heads, x.shape[1], nope + pe, v_dim,
-                jnp.dtype(cfg.dtype).itemsize, **blocks,
-            )
+        # Heads in runs, one call a run (at 16 384 keys of 192: four).
+        run = _heads_a_call(
+            attn, heads, x.shape[1], nope + pe, v_dim,
+            jnp.dtype(cfg.dtype).itemsize,
+        )
         trace.event(
             "mla.schedule",
             heads=heads,
@@ -823,16 +1076,7 @@ class LatentAttention(nn.Module):
             attn = partial(causal_attention, causal=cfg.causal)
         # The scope the flash kernels' readers know the forward by.
         with jax.named_scope("attention"):
-            out = jnp.concatenate(
-                [
-                    attn(*(
-                        jnp.swapaxes(t[:, :, at:at + run], 1, 2)
-                        for t in (q, k, v)
-                    ))
-                    for at in range(0, heads, run)
-                ],
-                axis=1,
-            )  # [b, h, s, v_dim]
+            out = _attend_in_runs(attn, q, k, v, run)  # [b, h, s, v_dim]
         out = jnp.swapaxes(out, 1, 2).reshape(
             x.shape[:-1] + (heads * v_dim,)
         )
@@ -869,8 +1113,9 @@ class RoutedFFN(nn.Module):
     ``d_expert`` for the ``experts_held`` held here. The expert bias
     shifts the selection only and no gradient reaches it. With
     ``d_shared_expert`` a shared expert (``GatedFFN`` of that width,
-    ``shared``) runs on every token and is added unweighted; the
-    tokens it multiplied are sown as ``shared_rows``. The layer's
+    ``shared``) runs on every token and is added unweighted, or with
+    ``shared_expert_gate`` times ``sigmoid(x w_s)`` (``shared_gate``);
+    the tokens it multiplied are sown as ``shared_rows``. The layer's
     load counters are sown into the "moe_load" collection, the
     router's choice (``experts``, ``weights``) into "moe_routing"."""
 
@@ -923,6 +1168,7 @@ class RoutedFFN(nn.Module):
             norm_eps=cfg.expert_weight_eps,
             scale=cfg.routed_scaling_factor,
             router_kind=cfg.experts_router,
+            shared_gate="sigmoid" if cfg.shared_expert_gate else "none",
         )
         for name, value in load.items():
             self.sow(
@@ -936,7 +1182,15 @@ class RoutedFFN(nn.Module):
                 "moe_load", "shared_rows",
                 jnp.int32(math.prod(x.shape[:-1])),
             )
-            y = y + GatedFFN(cfg, cfg.d_shared_expert, name="shared")(x)
+            shared = GatedFFN(cfg, cfg.d_shared_expert, name="shared")(x)
+            if cfg.shared_expert_gate:
+                gate = nn.Dense(
+                    1, dtype=cfg.dtype, use_bias=False, name="shared_gate"
+                )(x)
+                shared = shared * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)
+                ).astype(cfg.dtype)
+            y = y + shared
         return y
 
 
@@ -1017,8 +1271,8 @@ def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
     are the two options that change the attention's parameter tree
     (``qkv`` becomes ``q`` and ``kv``, plus the head norms' scales)
     and give up the sequence-parallel paths: they select
-    ``GroupedQueryAttention``. Everything else, ``rope_theta``
-    included, is the plain ``Attention``'s."""
+    ``GroupedQueryAttention``, as ``attention_gate`` does. Everything
+    else, ``rope_theta`` included, is the plain ``Attention``'s."""
     kind = cfg.mixer(layer)
     if kind == "conv":
         return ShortConv(cfg, name="short_conv")
@@ -1026,14 +1280,19 @@ def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
         return SparseAttention(cfg, name="attention")
     if kind == "kda":
         return KDA(cfg, name="kda")
+    if kind == "gdn":
+        return GatedDeltaNet(cfg, name="gdn")
     if kind == "mla":
         return LatentAttention(cfg, name="mla")
     if kind != "full_attention":
         raise ValueError(
             f"layer_types[{layer}] must be 'full_attention', 'conv', "
-            f"'sparse_attention', 'kda' or 'mla', got {kind!r}"
+            f"'sparse_attention', 'kda', 'gdn' or 'mla', got {kind!r}"
         )
-    if cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm:
+    if (
+        cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm
+        or cfg.attention_gate
+    ):
         return GroupedQueryAttention(cfg, name="attention")
     return Attention(cfg, name="attention")
 
@@ -1202,7 +1461,7 @@ def block_remat(config: TransformerConfig, tokens_shape=None):
         from adaptdl_tpu.ops.sparse_attention import SAVED_NAMES
 
         saved_names += SAVED_NAMES
-    if "kda" in (config.layer_types or ()):
+    if {"kda", "gdn"} & set(config.layer_types or ()):
         # The delta rule's output (one activation of heads x head_dim a
         # layer): the block's recomputation then does not run the rule
         # again before the rule's own backward does.

@@ -66,14 +66,21 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tile_rows(assignments: int) -> int:
+def tile_rows(assignments: int, rows_a_group: float | None = None) -> int:
     """Rows of one tile for a buffer that may hold ``assignments``
     rows: ``TILE_ROWS`` at real sizes, smaller (a power of two, at
     least 8) where the whole buffer is smaller than a few tiles, as in
-    the CPU tests."""
+    the CPU tests. ``rows_a_group``: the rows an even router sends one
+    group; at real sizes a tile is halved (not below 128) while that is
+    under three quarters of it — a group of 320 rows in tiles of 512
+    is 37% padding in every buffer its layer holds, which a row bound
+    counts a group (32 groups: 8192 rows of 41 984)."""
     rows = TILE_ROWS
     while rows > 8 and rows * 8 > assignments:
         rows //= 2
+    if rows == TILE_ROWS and rows_a_group is not None:
+        while rows > 128 and rows_a_group < 0.75 * rows:
+            rows //= 2
     return rows
 
 

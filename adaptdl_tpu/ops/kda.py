@@ -72,6 +72,11 @@ again in its backward: what is alive in HBM at once (a group's
 operands, its chunk states and their cotangents) is then a group's,
 not the layer's.
 
+A rule with ONE decay a head (``g`` [batch, seq, heads]) and fewer
+key heads than value heads is this rule with every channel's decay
+equal and q, k repeated: ``kda`` broadcasts and repeats, a group of
+heads at a time, and runs the same kernels.
+
 ``kda`` names its output (``SAVED_OUT``): a remat'd block keeps it
 (``models.transformer.block_remat``), so the block's recomputation
 does not run the rule a second time before its backward does.
@@ -1042,9 +1047,17 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         prepare=None, per_head=()):
     """Chunked gated delta rule.
 
-    q, k: ``[batch, seq, heads, dk]`` (the caller L2-normalises them),
-    v: ``[batch, seq, heads, dv]``, g: ``[batch, seq, heads, dk]`` the
-    log-decay (<= 0; summed in float32), beta: ``[batch, seq, heads]``.
+    q, k: ``[batch, seq, key_heads, dk]`` (the caller L2-normalises
+    them), v: ``[batch, seq, heads, dv]``, g: the log-decay (<= 0;
+    summed in float32), ``[batch, seq, heads, dk]`` one a channel or
+    ``[batch, seq, heads]`` ONE a head, beta: ``[batch, seq, heads]``.
+    ``key_heads`` divides ``heads``: key head j serves the value heads
+    ``j * heads / key_heads ..`` (q and k repeated, a group of heads at
+    a time, after ``prepare``). One decay a head is the same rule with
+    every channel's decay equal: it is broadcast over the ``dk``
+    channels into the per-channel kernels (``decay`` =
+    ``head_as_channel`` in the ``kda.schedule`` event; ``channel``
+    where ``g`` came a channel).
     ``scale`` multiplies q (default ``dk ** -0.5``). A row whose
     length ``chunk`` does not divide is padded with tokens that leave
     the state as it is (``chunk``: ``CHUNK`` unless a test asks for
@@ -1054,13 +1067,18 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     norms, the decay's activation) is as large as the rule's, it hands
     over what it has BEFORE that work and ``prepare(q, k, v, g, beta,
     *per_head)`` is called on one group of heads at a time (the five
-    arrays sliced to the group's heads on axis 2, g then as the caller
-    pleases, ``None`` where it brings the decay itself; ``per_head``
-    arrays, heads leading, sliced on axis 0) and
-    returns the five operands described above. Its work is then a
-    group's too, and is done again in the group's backward."""
-    batch, seq_len, heads, dk = q.shape
-    dv = v.shape[-1]
+    arrays sliced to the group's heads on axis 2 — its share of the
+    key heads for q and k —, g then as the caller pleases, ``None``
+    where it brings a decay a channel itself; ``per_head`` arrays,
+    heads or key heads leading, sliced on axis 0) and returns the five
+    operands described above. Its work is then a group's too, and is
+    done again in the group's backward."""
+    batch, seq_len, key_heads, dk = q.shape
+    heads, dv = v.shape[2], v.shape[-1]
+    assert heads % key_heads == 0, (
+        f"{key_heads} key heads for {heads} value heads"
+    )
+    a_head = g is not None and g.ndim == 3
     scale = dk**-0.5 if scale is None else float(scale)
     # A power of two (the solve halves a chunk down to single rows),
     # no longer than the row.
@@ -1068,6 +1086,10 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     chunks = -(-seq_len // chunk)
     kernel = kernel_fits(dk, dv, chunk) if use_kernel is None else use_kernel
     groups = head_groups(batch * seq_len, heads, dk)
+    groups = next(  # a group holds whole key heads
+        n for n in range(groups, 0, -1)
+        if heads % n == 0 and key_heads % n == 0
+    )
     bh = batch * heads // groups  # of one call of the state kernels
     held, held_bwd = (
         _state_how(bh, chunks, chunk, dk, dv, q.dtype.itemsize, backward)
@@ -1076,6 +1098,9 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     trace.event(
         "kda.schedule",
         heads=heads,
+        key_heads=key_heads,
+        value_heads=heads,
+        decay="head_as_channel" if a_head else "channel",
         head_dim=dk,
         v_dim=dv,
         seq_len=seq_len,
@@ -1111,7 +1136,13 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         if prepare is not None:
             per_token = prepare(*per_token, *per_head)
         q, k, v, g, beta = per_token  # [b, s, heads of the group, w]
-        held = q.shape[2]
+        held = v.shape[2]
+        if q.shape[2] != held:  # each key head on its value heads
+            q, k = (jnp.repeat(x, held // x.shape[2], axis=2) for x in (q, k))
+        if g.ndim == 3:  # one decay a head, on every channel
+            g = jnp.broadcast_to(
+                g.astype(jnp.float32)[..., None], g.shape + (dk,)
+            )
 
         bh = batch * held
 
@@ -1151,11 +1182,11 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     # chunk states, cotangents: ``_GROUP_ELEMENTS``) is a group's and
     # not the layer's.
     def token_groups(x):  # [b, s, h, ...] -> [groups, b, s, h / groups, ...]
-        shape = x.shape[:2] + (groups, heads // groups) + x.shape[3:]
+        shape = x.shape[:2] + (groups, x.shape[2] // groups) + x.shape[3:]
         return jnp.moveaxis(x.reshape(shape), 2, 0)
 
     def head_groups_of(x):  # [h, ...] -> [groups, h / groups, ...]
-        return x.reshape((groups, heads // groups) + x.shape[1:])
+        return x.reshape((groups, x.shape[0] // groups) + x.shape[1:])
 
     out = lax.map(
         jax.checkpoint(lambda operands: some_heads(*operands)),
@@ -1175,6 +1206,12 @@ def kda_recurrent(q, k, v, g, beta, scale: float | None = None):
     dk = q.shape[-1]
     scale = dk**-0.5 if scale is None else float(scale)
     f32 = jnp.float32
+    if q.shape[2] != v.shape[2]:
+        q, k = (
+            jnp.repeat(x, v.shape[2] // x.shape[2], axis=2) for x in (q, k)
+        )
+    if g.ndim == 3:
+        g = g[..., None]
 
     def step(state, token):  # state [b, h, dk, dv]
         q_t, k_t, v_t, g_t, beta_t = token

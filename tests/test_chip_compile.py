@@ -138,14 +138,18 @@ def test_flash_kernel_compiles_for_v5e(v5e, chip_compile, what, shape, ndev):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
+@pytest.mark.parametrize("qk,v", [(192, 128), (256, 256)])
 @pytest.mark.parametrize("what", ["fwd", "grad"])
 def test_flash_kernels_at_unequal_widths_compile_for_v5e(
-    v5e, chip_compile, what
+    v5e, chip_compile, what, qk, v
 ):
     """Latent attention's call at the published widths (four heads a
     call, q and k 192 wide, v 128, 16 384 keys, bf16): past the VMEM
     budget, so the K-blocked schedule, forward and the one backward
-    kernel."""
+    kernel. And the gated attention's of PR 49: heads of 256, twice
+    the VMEM a key (four chunks of 4096 keys), two heads a call."""
+    heads, a_call = (32, 4) if v == 128 else (16, 2)
+    assert flash_mod.heads_a_call(heads, 16384, qk, v, 2) == a_call
     one = SingleDeviceSharding(v5e.devices[0])
 
     def arg(width):
@@ -154,13 +158,13 @@ def test_flash_kernels_at_unequal_widths_compile_for_v5e(
         )
 
     fn = jax.grad(_attend_loss, argnums=(0, 1, 2)) if what == "grad" else _attend
-    compiled = jax.jit(fn).lower(arg(192), arg(192), arg(128)).compile()
+    compiled = jax.jit(fn).lower(arg(qk), arg(qk), arg(v)).compile()
     text = compiled.as_text()
     assert text.count(flash_mod.MOSAIC_CALL) == (2 if what == "grad" else 1)
     if what == "grad":
         assert flash_mod.BWD_KERNEL_NAME in text
-        grads = jax.eval_shape(fn, arg(192), arg(192), arg(128))
-        assert [g.shape[-1] for g in grads] == [192, 192, 128]
+        grads = jax.eval_shape(fn, arg(qk), arg(qk), arg(v))
+        assert [g.shape[-1] for g in grads] == [qk, qk, v]
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])

@@ -693,6 +693,10 @@ def test_shared_expert_is_added_unweighted(monkeypatch):
         ("lfm2-8b-a1b-steady", (16384, 4, 8, 32), 45056, 69632),
         ("keye-vl-2.0-30b-a3b-steady", (16384, 8, 16, 128), 139264, 139264),
         ("kimi-linear-48b-a3b-steady", (16384, 8, 8, 256), 14336, 143360),
+        # PR 49: 320 rows an even router sends a group are under three
+        # quarters of a tile of 512, so the tile is 256 (in tiles of
+        # 512 the bound was 41 984 rows).
+        ("qwen3-next-80b-a3b-steady", (16384, 10, 32, 512), 33792, 202752),
     ],
 )
 def test_the_row_bounds_of_the_routed_cells_at_their_real_sizes(
@@ -700,13 +704,15 @@ def test_the_row_bounds_of_the_routed_cells_at_their_real_sizes(
 ):
     """The piece-walk's threshold moves neither routed cell the
     benchmark had: lfm2's two passes and keye's one are the rows of
-    before; only a plan of four bounds or more is cut in pieces."""
+    before; only a plan of four bounds or more is cut in pieces. Nor
+    does the tile that follows the rows a group (PR 49) move them."""
     from adaptdl_tpu.models import moe
     from adaptdl_tpu.ops import grouped_matmul as gmm
 
     tokens, top_k, held, total = shape
-    tile = gmm.tile_rows(tokens * min(top_k, held))
-    assert tile == 512
+    tile = gmm.tile_rows(tokens * min(top_k, held), tokens * top_k / total)
+    assert tile == (256 if cell.startswith("qwen3") else 512)
+    assert tile == gmm.tile_rows(tokens * min(top_k, held)) or tile == 256
     assert moe.rows_bound(tokens, top_k, held, total, tile) == bound
     assert moe.rows_planned(tokens, top_k, held, total, tile) == planned
 
@@ -714,14 +720,15 @@ def test_the_row_bounds_of_the_routed_cells_at_their_real_sizes(
 @pytest.mark.parametrize("boost", [0.0, 50.0])
 def test_a_plan_many_times_its_bound_is_walked_in_pieces(boost):
     """2 of 256 experts held, top 4 of 8192 tokens: the worst case is
-    8.5 bounds long, so the plan is 9 pieces of the bound and the usual
+    many bounds long, so the plan is pieces of the bound and the usual
     step walks one; a router that sends every token to the held
-    experts walks eight, drops nothing, and gives the same layer."""
+    experts walks as many as hold its rows, drops nothing, and gives
+    the same layer."""
     from adaptdl_tpu.models import moe
     from adaptdl_tpu.ops import grouped_matmul as gmm
 
     tokens, d, f, total, held, top_k = 8192, 16, 8, 256, 2, 4
-    tile = gmm.tile_rows(tokens * min(top_k, held))
+    tile = gmm.tile_rows(tokens * min(top_k, held), tokens * top_k / total)
     capacity = moe.rows_capacity(tokens, top_k, held, tile)
     bound = moe.rows_bound(tokens, top_k, held, total, tile)
     planned = moe.rows_planned(tokens, top_k, held, total, tile)
@@ -759,7 +766,10 @@ def test_a_plan_many_times_its_bound_is_walked_in_pieces(boost):
     assert int(load["dropped"]) == 0
     pieces = -(-int(load["rows_active"]) // bound)
     assert int(load["rows_walked"]) == max(pieces, 1) * bound
-    assert pieces == (1 if boost == 0 else 8)
+    # (Every token on both held experts: tokens x held rows; 8 pieces
+    # in tiles of 512, 19 since PR 49 gave this shape's 128 rows a
+    # group tiles of 128 and a bound of 896.)
+    assert pieces == (1 if boost == 0 else -(-tokens * held // bound))
     assert int(load["fell_back"]) == (boost > 0)
     grads = jax.grad(
         lambda *a: jnp.sum(layer(*a)[0] ** 2), (0, 1, 2, 3)
