@@ -74,8 +74,28 @@ not the layer's.
 
 A rule with ONE decay a head (``g`` [batch, seq, heads]) and fewer
 key heads than value heads is this rule with every channel's decay
-equal and q, k repeated: ``kda`` broadcasts and repeats, a group of
-heads at a time, and runs the same kernels.
+equal and q, k repeated (a group of heads at a time). The decay then
+leaves the sums over channels,
+
+    A_ti = e^{G_t - G_i} (k_t . k_i)     (i < t)
+    B_ti = e^{G_t - G_i} (q_t . k_i)     (i <= t)
+
+``A = tril(K K^T, -1) * D`` and ``B = tril(Q K^T) * D`` with ``D`` ONE
+[C, C] float32 matrix a head and chunk: C^2 exponents, none positive,
+and ``[K; Q] K^T`` one product of the operands as they came, where the
+per-channel body walks a sub-block's pairs channel by channel. The
+chunks' own work of such a rule has a kernel pair of its own
+(``delta_chunk_head_fwd`` / ``delta_chunk_head_bwd``; ``_head_work``),
+chosen by the RANK of ``g`` and by nothing else: it takes ``g`` as the
+head's ``[.., 1, C]`` row, never broadcast to the channels, writes
+exactly what ``_own_work`` writes (``e^{G_C}`` as the ``[.., 1, dk]``
+row, constant along it), so the state kernels are the same, and hands
+g's gradient back a head's. The solve is the same float32 arithmetic
+(forward substitution, which no pair walk pins to a sub-block of 16
+here: over the whole chunk, so no level is left), written out by the
+forward rule for the backward. Where ``kernel_fits`` says no, the
+decay is broadcast into ``_prepare`` (``decay`` = ``head_as_channel``
+in the ``kda.schedule`` event; ``head`` on the kernel path).
 
 ``kda`` names its output (``SAVED_OUT``): a remat'd block keeps it
 (``models.transformer.block_remat``), so the block's recomputation
@@ -102,6 +122,11 @@ BWD_KERNEL_NAME = "kda_bwd"
 # are the state kernels alone).
 OWN_FWD_KERNEL_NAME = "delta_chunk_fwd"
 OWN_BWD_KERNEL_NAME = "delta_chunk_bwd"
+# The same work where a head has ONE decay (``g`` [batch, seq, heads]):
+# names that still hold ``delta_chunk``, which a device trace's readers
+# of the chunks' own work match.
+OWN_HEAD_FWD_KERNEL_NAME = "delta_chunk_head_fwd"
+OWN_HEAD_BWD_KERNEL_NAME = "delta_chunk_head_bwd"
 # What ``kda`` names (``jax.ad_checkpoint.checkpoint_name``) of what it
 # produces: its output. A remat'd block keeps it by this name
 # (``models.transformer.block_remat``), so the block's recomputation
@@ -703,6 +728,322 @@ def _own_work_bwd(scale, operands, cts):
 _own_work.defvjp(_own_work_fwd, _own_work_bwd)
 
 
+# ---- the chunk's own work with ONE decay a head -----------------------
+#
+# The same mathematics where every channel of a head decays alike:
+# ``e^{G_t - G_i}`` no longer depends on the channel and leaves the sum
+# over channels, so ``A = tril(K K^T, -1) * D`` and ``B = tril(Q K^T) *
+# D`` with ``D_ti = e^{G_t - G_i}`` ONE [C, C] float32 matrix a chunk:
+# C^2 exponents where the per-channel body takes C^2 dk / 2 (sub-block
+# by sub-block), none positive (``i <= t`` and ``g <= 0``; the other
+# triangle is clamped, then masked), and ``[K; Q] K^T`` one product of
+# the operands as they came, accumulated in float32 — exact for bf16
+# operands: nothing scaled by a decay is rounded on its way into ``A``
+# or ``B``. ``q e^G``, ``k e^{G_C - G}`` and ``k e^G`` scale rows by a
+# [C, 1] column. The inverse is the same float32 arithmetic, forward
+# substitution a column at a time, now over a matrix that is already
+# there and over the WHOLE chunk (``_substituted_inverse``): no pair
+# walk pins a sub-block to 16 tokens here, and what the chip says is
+# that no level of ``_unit_lower_inverse_chunk`` is worth its two
+# ``HIGHEST`` products. Measured on a v5e (a call of 4 heads x 256
+# chunks of 64, heads of 128, bf16, device time of 16 calls chained
+# in one program; the per-channel pair 1.229 ms forward): the blocks'
+# DMAs alone 0.19 ms, everything but the inverse 0.29, and the inverse
+# the rest — 0.42 ms a level, ~2 ns a [8, C] tile and column of the
+# substitution. Forward with substitution up to 16 / 32 / 64 tokens,
+# one chunk at a time: 1.32 / 1.01 / ~1.0; two chunks abreast in one
+# basic block (their chains interleave): 0.89 at 32, **0.78 at 64**
+# (four abreast gain ~5% more and double the body); the inverse
+# written out by the forward rule for the backward rather than formed
+# again (16 KiB a chunk in float32, alive from a group's forward rule
+# to its backward): the two together 1.48 where a backward that forms
+# the forward again took 1.55 alone.
+
+# Chunks in one basic block of the one-decay body's loop.
+_HEAD_ABREAST = 2
+
+
+def _substituted_inverse(lower, unrolled: bool):
+    """``(I + lower)^{-1}`` for strictly lower triangular ``lower``
+    [C, C] by forward substitution a column at a time: with row i
+    final, every later row takes ``-lower[t, i]`` of it. Compiled, the
+    rows are walked a float32 sublane tile at a time and past the
+    tiles before row i (``_octet``)."""
+    chunk = lower.shape[0]
+    octet = _octet(chunk, unrolled)
+    lane = lax.broadcasted_iota(jnp.int32, (octet, chunk), 1)
+    down = lax.broadcasted_iota(jnp.int32, (octet, chunk), 0)
+    octets = range(0, chunk, octet)
+    l_rows = [lower[o:o + octet] for o in octets]
+    x_rows = tuple(jnp.where(lane == down + o, 1.0, 0.0) for o in octets)
+    for lo in octets:
+
+        def column(i, x_rows, lo=lo):
+            x_rows = list(x_rows)
+            solved = _one(x_rows[lo // octet], i - lo, 0)  # row i, final
+            for o in range(lo // octet, chunk // octet):
+                # (Column i as a sum over lanes: 9% of a forward call
+                # faster on the chip than a lane's slice broadcast.)
+                step = jnp.sum(
+                    jnp.where(lane == i, l_rows[o], 0.0),
+                    axis=1, keepdims=True,
+                )
+                x_rows[o] = x_rows[o] - step * solved
+            return tuple(x_rows)
+
+        # (The last column holds nothing below the diagonal.)
+        x_rows = _each(
+            lo, min(lo + octet, chunk - 1), column, x_rows, unrolled
+        )
+    return jnp.concatenate(x_rows, axis=0)
+
+
+def _in_parts(x, low):
+    """float32 ``x`` as a sum of arrays in ``low``, the second what
+    the first's rounding left: a cotangent that multiplies operands in
+    ``low`` then loses nothing to their dtype (the per-channel body
+    keeps a sub-block's pairs in float32 on the VPU)."""
+    if low == x.dtype:
+        return (x,)
+    first = x.astype(low)
+    return first, (x - first.astype(x.dtype)).astype(low)
+
+
+def _head_shared(q, k, g, beta, scale, unrolled, inv=None):
+    """One chunk's own work up to ``T`` where the head has one decay,
+    on values: q, k [C, dk] in the input dtype, g and beta [1, C]
+    float32; ``inv``: the inverse where the forward kept it. Returns
+    what the forward forms its results from and the backward its
+    gradients."""
+    low, f32 = q.dtype, jnp.float32
+    chunk = k.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    same = row == col
+
+    def column(x):  # [1, C] -> [C, 1], the same numbers
+        return jnp.sum(jnp.where(same, x, 0.0), axis=1, keepdims=True)
+
+    def across(x):  # [C, 1] -> [1, C]
+        return jnp.sum(jnp.where(same, x, 0.0), axis=0, keepdims=True)
+
+    # G_t, the running sum of g: a column, and the same numbers a row.
+    big_g = jnp.sum(jnp.where(col <= row, g, 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.minimum(big_g - across(big_g), 0.0))  # D
+    both = jnp.concatenate([k, q], axis=0)
+    pairs = _dot(both, k, _NT)  # [K; Q] K^T, [2 C, C]
+    kk, qk = pairs[:chunk], pairs[chunk:]
+    a_full = jnp.where(row > col, kk * decay, 0.0)
+    b_full = jnp.where(row >= col, qk * (decay * scale), 0.0)
+    beta_col = column(beta)
+    if inv is None:
+        inv = _substituted_inverse(beta_col * a_full, unrolled)
+    k32, q32 = k.astype(f32), q.astype(f32) * scale
+    grown = jnp.exp(big_g)  # [C, 1]
+    last = big_g[chunk - 1:]  # [1, 1]
+    return dict(
+        k32=k32, q32=q32, both=both, kk=kk, qk=qk, decay=decay,
+        a_full=a_full, b_full=b_full, beta_col=beta_col, inv=inv, row=row,
+        col=col, column=column, across=across,
+        solve=(inv * beta).astype(low),  # T
+        grown=grown, k_plus=(k32 * grown).astype(low),
+        last=last, left=jnp.exp(last - big_g),  # e^{G_C - G}
+    )
+
+
+def _abreast(held: int, one):
+    """``one(j)`` for each of a grid step's ``held`` chunks,
+    ``_HEAD_ABREAST`` of them in one basic block of the loop: their
+    serial chains (a product, the substitution, the levels, a product)
+    are independent and the scheduler interleaves them."""
+    n = _HEAD_ABREAST if held % _HEAD_ABREAST == 0 else 1
+
+    def some(j, carry):
+        for a in range(n):
+            one(j * n + a)
+        return carry
+
+    lax.fori_loop(0, held // n, some, 0)
+
+
+def _head_fwd_kernel(q, k, v, g, beta, qp, kd, wk, wv, b, dl, *kept, scale,
+                     unrolled):
+    @pl.when(pl.program_id(1) >= 0)
+    def _chunks():
+        def one(j):
+            low = q.dtype
+            at = _head_shared(
+                q[0, j], k[0, j], g[0, j], beta[0, j], scale, unrolled
+            )
+            qp[0, j] = (at["q32"] * at["grown"]).astype(low)
+            kd[0, j] = (at["k32"] * at["left"]).astype(low)
+            wk[0, j] = _dot(at["solve"], at["k_plus"]).astype(low)
+            wv[0, j] = _dot(at["solve"], v[0, j]).astype(low)
+            b[0, j] = at["b_full"].astype(low)
+            dl[0, j] = jnp.broadcast_to(jnp.exp(at["last"]), dl.shape[2:])
+            for ref in kept:  # the inverse, for the backward
+                ref[0, j] = at["inv"]
+
+        _abreast(q.shape[1], one)
+
+
+def _head_backward(q, k, v, g, beta, inv, cts, scale, unrolled):
+    """One chunk's gradients where the head has one decay, from the
+    six cotangents of what the forward wrote and the inverse it kept:
+    the forward's other values are formed again, then every step's
+    transpose by hand as ``_own_backward`` does, float32 throughout
+    except where the forward's products take the input dtype. With
+    ``dS = dA * D`` and ``dR = dB * D`` (what reaches ``K K^T`` and
+    ``Q K^T``), q's and k's gradients are ``[dS; dR] K`` and ``[dS;
+    dR]^T [K; Q]``, and G's is a row sum less a column sum of ``dS * K
+    K^T + dR * Q K^T``. Returns (dq, dk, dv, dg [1, C], dbeta [1, C])."""
+    low, f32 = q.dtype, jnp.float32
+    chunk = k.shape[0]
+    at = _head_shared(q, k, g, beta, scale, unrolled, inv)
+    k32, q32, grown, left = at["k32"], at["q32"], at["grown"], at["left"]
+    solve, decay = at["solve"], at["decay"]
+    row, col, column, across = at["row"], at["col"], at["column"], at["across"]
+    d_qp, d_kd, d_wk, d_wv, d_b, d_dl = cts
+    d_qp, d_kd = d_qp.astype(f32), d_kd.astype(f32)
+    # W_k = T K+, W_v = T V, T = X Diag(beta), X = (I + Diag(beta) A)^-1.
+    d_solve = _dot(d_wk, at["k_plus"], _NT) + _dot(d_wv, v, _NT)
+    d_kplus = _dot(solve, d_wk, _TN)
+    d_v = _dot(solve, d_wv, _TN)
+    d_beta = jnp.sum(d_solve * inv, axis=0, keepdims=True)  # [1, C]
+    d_lower = -_dot(
+        inv, _dot(d_solve * beta, inv, _NT, _HIGHEST), _TN, _HIGHEST
+    )  # -X^T dX X^T
+    d_lower = jnp.where(row > col, d_lower, 0.0)
+    d_beta += across(jnp.sum(d_lower * at["a_full"], axis=1, keepdims=True))
+    # A = tril(K K^T, -1) * D, B = tril(Q K^T) * D * scale.
+    d_s = at["beta_col"] * d_lower * decay
+    d_r = jnp.where(row >= col, d_b.astype(f32), 0.0) * (decay * scale)
+    later = earlier = 0.0
+    for part in _in_parts(jnp.concatenate([d_s, d_r], axis=0), low):
+        # [2 C, dk]: to k_t and q_t from A_t., B_t.
+        later += _dot(part, k)
+        # [C, dk]: to k_i from A_.i, B_.i
+        earlier += _dot(part, at["both"], _TN)
+    # G: through D (up at the later token, down at the earlier one),
+    # q e^G, k e^G (inside W_k), k e^{G_C - G}, e^{G_C}.
+    moved = d_s * at["kk"] + d_r * at["qk"]
+    to_last = d_kd * k32 * left
+    d_big = (
+        jnp.sum(moved, axis=1, keepdims=True)
+        - column(jnp.sum(moved, axis=0, keepdims=True))
+        + jnp.sum(
+            (d_qp * q32 + d_kplus * k32) * grown - to_last,
+            axis=1, keepdims=True,
+        )
+    )  # [C, 1]
+    at_last = jnp.sum(
+        jnp.sum(to_last, axis=0, keepdims=True), axis=1, keepdims=True
+    ) + jnp.sum(d_dl, axis=1, keepdims=True) * jnp.exp(at["last"])
+    d_big = d_big + jnp.where(row[:, :1] == chunk - 1, at_last, 0.0)
+    return (
+        (d_qp * grown * scale + later[chunk:]).astype(low),
+        (d_kplus * grown + d_kd * left + later[:chunk] + earlier).astype(low),
+        d_v.astype(low),
+        # g_j is in every G_t from t = j on.
+        jnp.sum(jnp.where(row >= col, d_big, 0.0), axis=0, keepdims=True),
+        d_beta,
+    )
+
+
+def _head_bwd_kernel(q, k, v, g, beta, inv, d_qp, d_kd, d_wk, d_wv, d_b,
+                     d_dl, d_q, d_k, d_v, d_g, d_beta, *, scale, unrolled):
+    @pl.when(pl.program_id(1) >= 0)
+    def _chunks():
+        def one(j):
+            grads = _head_backward(
+                q[0, j], k[0, j], v[0, j], g[0, j], beta[0, j], inv[0, j],
+                tuple(x[0, j] for x in (d_qp, d_kd, d_wk, d_wv, d_b, d_dl)),
+                scale, unrolled,
+            )
+            for ref, value in zip((d_q, d_k, d_v, d_g, d_beta), grads):
+                ref[0, j] = value
+
+        _abreast(q.shape[1], one)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _head_fwd_pallas(scale: float, how: _How, keep: bool, q, k, v, g, beta):
+    """-> the six results and, with ``keep``, the inverse ``X`` [bh,
+    chunks, C, C] float32 for the backward."""
+    bh, chunks, chunk, dk = q.shape
+    dv = v.shape[3]
+    wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
+    vma = jax.typeof(q).vma
+
+    def out(shape, dtype=q.dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+    matrix = (bh, chunks, chunk, chunk)
+    return pl.pallas_call(
+        functools.partial(
+            _head_fwd_kernel, scale=scale, unrolled=how.unrolled
+        ),
+        grid=(bh, chunks // how.held),
+        in_specs=[wide, wide, tall, steps, steps],
+        out_specs=[wide, wide, wide, tall, square, decay] + [square] * keep,
+        out_shape=[
+            out(q.shape), out(q.shape), out(q.shape), out(v.shape),
+            out(matrix), out((bh, chunks, 1, dk), jnp.float32),
+        ] + [out(matrix, jnp.float32)] * keep,
+        compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
+        interpret=how.interpret,
+        name=OWN_HEAD_FWD_KERNEL_NAME,
+    )(q, k, v, g, beta)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _head_bwd_pallas(scale: float, how: _How, q, k, v, g, beta, inv, *cts):
+    bh, chunks, chunk, dk = q.shape
+    dv = v.shape[3]
+    wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
+    vma = jax.typeof(q).vma
+    return pl.pallas_call(
+        functools.partial(
+            _head_bwd_kernel, scale=scale, unrolled=how.unrolled
+        ),
+        grid=(bh, chunks // how.held),
+        in_specs=[wide, wide, tall, steps, steps, square,
+                  wide, wide, wide, tall, square, decay],
+        out_specs=[wide, wide, tall, steps, steps],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
+            for x in (q, k, v, g, beta)
+        ],
+        compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
+        interpret=how.interpret,
+        name=OWN_HEAD_BWD_KERNEL_NAME,
+    )(q, k, v, g, beta, inv, *cts)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _head_work(scale: float, q, k, v, g, beta):
+    """``_own_work`` for ONE decay a head: g as beta comes, [bh,
+    chunks, 1, C] float32, never a channel's; the same six results
+    (``e^{G_C}`` the [.., 1, dk] row the state kernels take, constant
+    along it) and g's gradient a head's."""
+    how = _own_how(q.shape[1])
+    return tuple(_head_fwd_pallas(scale, how, False, q, k, v, g, beta))
+
+
+def _head_work_fwd(scale, *operands):
+    how = _own_how(operands[0].shape[1])
+    *results, inv = _head_fwd_pallas(scale, how, True, *operands)
+    return tuple(results), (*operands, inv)
+
+
+def _head_work_bwd(scale, saved, cts):
+    how = _own_how(saved[0].shape[1])
+    return tuple(_head_bwd_pallas(scale, how, *saved, *cts))
+
+
+_head_work.defvjp(_head_work_fwd, _head_work_bwd)
+
+
 def _chunk_forward(state, qp, kd, wk, wv, b, dl):
     """One chunk of the recurrence on values: ``state`` [dv, dk]
     float32 (the state transposed: the decay runs along lanes), the
@@ -1054,10 +1395,14 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
     ``key_heads`` divides ``heads``: key head j serves the value heads
     ``j * heads / key_heads ..`` (q and k repeated, a group of heads at
     a time, after ``prepare``). One decay a head is the same rule with
-    every channel's decay equal: it is broadcast over the ``dk``
-    channels into the per-channel kernels (``decay`` =
-    ``head_as_channel`` in the ``kda.schedule`` event; ``channel``
-    where ``g`` came a channel).
+    every channel's decay equal, ``A = tril(K K^T, -1) * D``, ``B =
+    tril(Q K^T) * D``, ``D_ti = e^{G_t - G_i}``: on the kernel path the
+    chunks' own work then runs in the body that takes the head's decay
+    as such (``_head_work``; ``decay`` = ``head`` in the
+    ``kda.schedule`` event, g's gradient summed over no channel), on
+    the fallback it is broadcast over the ``dk`` channels into
+    ``_prepare`` (``head_as_channel``); ``channel`` where ``g`` came a
+    channel. The rank of ``g`` alone decides.
     ``scale`` multiplies q (default ``dk ** -0.5``). A row whose
     length ``chunk`` does not divide is padded with tokens that leave
     the state as it is (``chunk``: ``CHUNK`` unless a test asks for
@@ -1095,18 +1440,25 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         _state_how(bh, chunks, chunk, dk, dv, q.dtype.itemsize, backward)
         if kernel else _Held(0, 0) for backward in (False, True)
     )
+    # One decay a head has a chunk body of its own: what the rank of
+    # ``g`` says, nothing else.
+    own_fwd, own_bwd = (
+        (OWN_HEAD_FWD_KERNEL_NAME, OWN_HEAD_BWD_KERNEL_NAME) if a_head
+        else (OWN_FWD_KERNEL_NAME, OWN_BWD_KERNEL_NAME)
+    )
     trace.event(
         "kda.schedule",
         heads=heads,
         key_heads=key_heads,
         value_heads=heads,
-        decay="head_as_channel" if a_head else "channel",
+        decay=(("head" if kernel else "head_as_channel") if a_head
+               else "channel"),
         head_dim=dk,
         v_dim=dv,
         seq_len=seq_len,
         chunk=chunk,
         chunks=chunks,
-        sub_block=_sub_block(chunk),
+        sub_block=chunk if a_head and kernel else _sub_block(chunk),
         head_groups=groups,
         state_heads_a_step=held.heads,
         state_chunks_a_step=held.chunks,
@@ -1118,12 +1470,13 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         path="kernel" if kernel else "fallback",
         product="pallas:" + FWD_KERNEL_NAME + "," + BWD_KERNEL_NAME
         if kernel else "scan",
-        own_work="pallas:" + OWN_FWD_KERNEL_NAME + "," + OWN_BWD_KERNEL_NAME
-        if kernel else "xla",
+        own_work="pallas:" + own_fwd + "," + own_bwd if kernel else "xla",
         backward=(
             BWD_KERNEL_NAME + " over the forward's chunk states, then "
-            + OWN_BWD_KERNEL_NAME + " (a chunk's own work formed again in "
-            "VMEM, its transpose by hand)" if kernel else
+            + own_bwd + " (" + (
+                "the chunk's inverse as the forward rule wrote it out, the "
+                "rest of its own work" if a_head else "a chunk's own work"
+            ) + " formed again in VMEM, its transpose by hand)" if kernel else
             "a reversed scan over the forward's chunk states; the chunks' "
             "own work by autodiff"
         ) + (", a group of heads at a time, done again in its backward"
@@ -1139,11 +1492,12 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         held = v.shape[2]
         if q.shape[2] != held:  # each key head on its value heads
             q, k = (jnp.repeat(x, held // x.shape[2], axis=2) for x in (q, k))
-        if g.ndim == 3:  # one decay a head, on every channel
-            g = jnp.broadcast_to(
-                g.astype(jnp.float32)[..., None], g.shape + (dk,)
-            )
-
+        g = g.astype(jnp.float32)
+        one_decay = g.ndim == 3
+        if one_decay:  # [b, s, h, 1]; a channel's where no body takes it
+            g = g[..., None]
+            if not kernel:
+                g = jnp.broadcast_to(g, g.shape[:3] + (dk,))
         bh = batch * held
 
         def rows(x):  # [b, s, h, w] -> [b * h, chunks, C, w]
@@ -1152,14 +1506,19 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
                 x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
             return x.reshape(bh, chunks, chunk, x.shape[-1])
 
+        def across(x):  # a number a token: [b * h, chunks, 1, C]
+            return x.reshape(bh, chunks, 1, chunk)
+
         operands = (
-            rows(q), rows(k), rows(v), rows(g.astype(jnp.float32)),
+            rows(q), rows(k), rows(v), rows(g),
             rows(beta.astype(jnp.float32)[..., None]),
         )
-        if kernel:
-            prepared = _own_work(
-                scale, *operands[:4], operands[4].reshape(bh, chunks, 1, chunk)
+        if kernel and one_decay:
+            prepared = _head_work(
+                scale, *operands[:3], across(operands[3]), across(operands[4])
             )
+        elif kernel:
+            prepared = _own_work(scale, *operands[:4], across(operands[4]))
         else:
             prepared = _prepare(
                 *(x.reshape((bh * chunks,) + x.shape[2:])

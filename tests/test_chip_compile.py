@@ -213,6 +213,55 @@ def test_kda_kernels_compile_for_v5e(
 
 
 @pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_delta_chunk_head_kernels_compile_for_v5e(
+    v5e, chip_compile, monkeypatch, what
+):
+    """The chunks' own work where a head has ONE decay, at the
+    qwen3-next cell's call (four value heads of 128, 256 chunks of 64,
+    bf16, g and beta ``[bh, chunks, 1, C]`` float32): the pair lowers
+    through Mosaic under names that hold ``delta_chunk`` (what the
+    accepted readers match) and ``_head_``, the gradient's program
+    holds the forward too (it writes the inverse out for the
+    backward), and g's gradient comes back a head's."""
+    kda = importlib.import_module("adaptdl_tpu.ops.kda")
+    monkeypatch.setattr(kda, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(width, dtype=jnp.bfloat16, rows=64):
+        return jax.ShapeDtypeStruct(
+            (4, 256, rows, width), dtype, sharding=one
+        )
+
+    args = (
+        arg(128), arg(128), arg(128), arg(64, jnp.float32, rows=1),
+        arg(64, jnp.float32, rows=1),
+    )
+
+    def forward(*a):
+        return kda._head_work(128**-0.5, *a)
+
+    def loss(*a):
+        return sum(x.astype(jnp.float32).sum() for x in forward(*a))
+
+    fn = jax.grad(loss, argnums=tuple(range(5))) if what == "grad" else forward
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    found = set(
+        re.findall(r"%[\w\-]*?(delta_chunk_\w+?)[.\d]* = ", text)
+    )
+    assert found == {"delta_chunk_head_fwd"} | (
+        {"delta_chunk_head_bwd"} if what == "grad" else set()
+    )
+    assert "kda_" not in (
+        kda.OWN_HEAD_FWD_KERNEL_NAME + kda.OWN_HEAD_BWD_KERNEL_NAME
+    )
+    if what == "grad":
+        grads = jax.eval_shape(fn, *args)
+        assert [(g.shape, g.dtype) for g in grads] == [
+            (a.shape, a.dtype) for a in args
+        ]
+
+
+@pytest.mark.parametrize("what", ["fwd", "grad"])
 def test_delta_chunk_kernels_compile_for_v5e(
     v5e, chip_compile, monkeypatch, what
 ):

@@ -109,18 +109,30 @@ def test_key_heads_in_head_groups_is_the_rule(monkeypatch):
 
 
 def test_kda_schedule_says_which_decay_ran():
+    """One decay a head runs the chunk body that takes it as such
+    (``head``) on the kernel path and, broadcast over the channels,
+    the XLA ``_prepare`` on the fallback (``head_as_channel``); a
+    decay a channel says ``channel``."""
     q, k, v, g, beta = _rule_inputs(3, key_heads=2, heads=4)
     since = len(trace.snapshot_spans())
     kda_op.kda(q, k, v, g, beta, chunk=16)
+    kda_op.kda(q, k, v, g, beta, chunk=16, use_kernel=False)
     kda_op.kda(
         jnp.repeat(q, 2, axis=2), jnp.repeat(k, 2, axis=2), v,
         jnp.broadcast_to(g[..., None], v.shape), beta, chunk=16,
     )
-    head, channel = _events("kda.schedule", since)
+    head, fallback, channel = _events("kda.schedule", since)
     assert (head["decay"], head["key_heads"], head["value_heads"]) == (
-        "head_as_channel", 2, 4
+        "head", 2, 4
+    )
+    assert head["own_work"] == (
+        "pallas:delta_chunk_head_fwd,delta_chunk_head_bwd"
+    )
+    assert (fallback["decay"], fallback["own_work"]) == (
+        "head_as_channel", "xla"
     )
     assert (channel["decay"], channel["key_heads"]) == ("channel", 4)
+    assert channel["own_work"] == "pallas:delta_chunk_fwd,delta_chunk_bwd"
     assert head["heads"] == channel["heads"] == 4
 
 
