@@ -891,6 +891,11 @@ def _cmd_trace(args) -> int:  # wire: consumes=trace_payload,trace_span
         print("\nevents:")
         for name in sorted(events):
             print(f"  {name:<28} x{events[name]}")
+    if any(rec["name"] == "step.cycle" for rec in selected):
+        # One row a pull: which cycle was long, and which phase, CPU
+        # share, context switches, faults or collection held it.
+        print("\ncycles (ms):")
+        print(trace.render_cycles(selected))
     if args.perfetto:
         with open(args.perfetto, "w", encoding="utf-8") as f:
             json.dump(trace.to_perfetto(selected), f)

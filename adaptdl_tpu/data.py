@@ -521,6 +521,10 @@ class AdaptiveDataLoader:
             # applies to the epoch it was saved in).
             self.sampler.set_position(epoch, 0)
         _current_dataloader = self
+        # The loader's own work, ``__next__`` entered -> batch yielded,
+        # is the phase ``data_next`` of the step cycle (``trace.
+        # StepCycle``); between two of them the caller has the clock.
+        trace.step_cycle.mark(trace.DATA_NEXT)
         try:
             self._optimize_batch_size()
             steps = 0
@@ -566,9 +570,11 @@ class AdaptiveDataLoader:
                 batch = _gather(self.dataset, indices)
                 config = (self._atomic_bsz, self._accum_steps)
                 restore_gen = self._restore_gen
+                trace.step_cycle.mark(trace.OUTSIDE)
                 start = time.monotonic()
                 yield batch
                 elapsed = time.monotonic() - start
+                trace.step_cycle.mark(trace.DATA_NEXT)
                 if self._restore_gen != restore_gen:
                     # A rollback restored the loader mid-step: the
                     # restored position/shape is authoritative, and
@@ -601,6 +607,7 @@ class AdaptiveDataLoader:
             self.sampler.index = 0
         finally:
             _current_dataloader = None
+            trace.step_cycle.leave(trace.DATA_NEXT)
 
 
 def _loop_epoch() -> int:

@@ -14,6 +14,14 @@ CheckFreq's (FAST'21) snapshot/write/stall breakdowns are built on:
   attributes. ``trace.event(...)`` records a zero-duration point (and
   bumps a Prometheus counter). Disabled (``ADAPTDL_TRACE=off``) both
   cost one global read and an immediate return.
+- **The step cycle** — the step path marks its phase boundaries on
+  one clock (:class:`StepCycle`: the loader, ``shard_batch``, the
+  dispatch, the gated pull, what follows it, the caller's loop) and
+  every pull writes ONE span, ``step.cycle``, with each phase's sum
+  and largest, and what tells whose second a stall was (CPU seconds,
+  involuntary context switches, major faults, full collections). The
+  same marks are ``jax.profiler`` annotations ``adaptdl.step.<phase>``
+  on the device trace's clock.
 - **Trace context** — W3C-style ``traceparent``
   (``00-<32hex>-<16hex>-01``). The allocator mints a fresh context per
   rescale decision; it propagates through ``rpc.py`` request headers
@@ -49,10 +57,12 @@ rescale from the signal to its first step, journal or no journal.
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import os
 import random
+import resource
 import threading
 import time
 import zlib
@@ -436,6 +446,170 @@ def end_pending(name: str, **attrs) -> bool:
         **open_attrs,
     )
     return True
+
+
+# ---- the step cycle: the host's phases of the step path --------------
+
+# The step path (``AdaptiveDataLoader``'s iterator -> ``ElasticTrainer.
+# run_step``) pulls from the device every ``metrics_every``-th step and
+# runs ahead of it in between, so a span a step would be ten records
+# where one says as much. The path marks its phase boundaries instead
+# (a clock read and an add each), and the pull writes ONE span,
+# ``step.cycle``, from the previous pull's return to this one's, with
+# where the host spent it. The same marks enter and leave a
+# ``jax.profiler.TraceAnnotation`` ``adaptdl.step.<phase>``, so a
+# profile of any job shows the host's phases over the device's ops on
+# the profiler's own clock.
+CYCLE_PHASES = (
+    "data_next",  # the loader: ``__next__`` entered -> batch yielded
+    "shard",  # ``shard_batch``: the batch's ``device_put``
+    "dispatch",  # the call of the step program (a full queue's
+    # back-pressure too: the per-step list shows which)
+    "pull",  # ``block_until_ready``: the device finishing its queue
+    "after_pull",  # the ``float()``s, GNS, progress, guard, counters
+    "calibrate",  # a NEW batch size's first-time work in ``run_step``:
+    # calibration (or its reuse) and the step program's build
+    "outside",  # the caller's loop, ``run_step`` returned -> loader asked
+)
+(
+    DATA_NEXT, SHARD, DISPATCH, PULL, AFTER_PULL, CALIBRATE, OUTSIDE,
+) = range(len(CYCLE_PHASES))
+_PHASE_ANNOTATIONS = tuple(
+    None if name == "outside" else f"adaptdl.step.{name}"
+    for name in CYCLE_PHASES
+)
+
+_clock = time.perf_counter
+
+
+def _first_annotation(name: str):
+    """jax's ``TraceAnnotation``, looked up at the first mark (the
+    control plane imports this module without jax); it starts at its
+    construction and costs ~0.3 us outside a profiler session."""
+    global _new_annotation
+    try:
+        from jax.profiler import TraceAnnotation as made
+    except ImportError:
+        def made(_name):
+            return None
+    _new_annotation = made
+    return made(name)
+
+
+_new_annotation = _first_annotation
+
+
+def _process_counts() -> tuple[float, int, int, int]:
+    """(CPU seconds, involuntary context switches, major faults,
+    generation-2 collections) of this process so far."""
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return (
+        usage.ru_utime + usage.ru_stime,
+        usage.ru_nivcsw,
+        usage.ru_majflt,
+        gc.get_stats()[2]["collections"],
+    )
+
+
+class StepCycle:
+    """The step path's clock. ``mark(phase)`` ends the running phase
+    and starts ``phase``; ``mark(AFTER_PULL)`` — the pull has returned
+    — also closes the cycle and records its ``step.cycle`` span. One
+    thread marks (the training loop's); no lock. With tracing off a
+    mark is one global read."""
+
+    __slots__ = (
+        "steps_total", "phase", "_since", "_start", "_annotation",
+        "_sums", "_maxes", "_dispatch", "_data_next", "_steps",
+        "_exposed", "_exposed_outside", "_counts",
+    )
+
+    def __init__(self):
+        # ``run_step`` calls of this process so far; a cycle's
+        # ``first_step`` counts in the same numbers, so a reader can
+        # tell the cycles of its last N steps from those before.
+        self.steps_total = 0
+        self.phase = None  # the running phase; None before the first mark
+        self._annotation = None
+        self._reset(0.0)
+
+    def _reset(self, now: float) -> None:
+        self._start = now
+        self._sums = [0.0] * len(CYCLE_PHASES)
+        self._maxes = [0.0] * len(CYCLE_PHASES)
+        self._dispatch = []
+        self._data_next = []
+        self._steps = 0
+        self._exposed = None
+        self._exposed_outside = 0.0
+
+    def mark(self, phase: int) -> None:
+        if not enabled():
+            return
+        now = _clock()
+        running = self.phase
+        if running is None:  # the first mark starts the clock
+            self._reset(now)
+            self._counts = _process_counts()
+        else:
+            spent = now - self._since
+            self._sums[running] += spent
+            if spent > self._maxes[running]:
+                self._maxes[running] = spent
+            if running == DISPATCH:
+                self._dispatch.append(spent)
+                if self._exposed is None:
+                    self._exposed = now - self._start
+                    self._exposed_outside = self._sums[OUTSIDE]
+            elif running == DATA_NEXT:
+                self._data_next.append(spent)
+        self.phase = phase
+        self._since = now
+        if phase == DISPATCH:
+            self._steps += 1
+            self.steps_total += 1
+        annotation = self._annotation
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        name = _PHASE_ANNOTATIONS[phase]
+        self._annotation = (
+            None if name is None else _new_annotation(name)
+        )
+        if phase == AFTER_PULL:
+            self._close(now)
+
+    def leave(self, phase: int) -> None:
+        """``mark(OUTSIDE)`` if ``phase`` still runs: for an exit that
+        may come late (a generator's ``finally``), when the path has
+        long marked something else."""
+        if self.phase == phase:
+            self.mark(OUTSIDE)
+
+    def _close(self, now: float) -> None:
+        counts = _process_counts()
+        attrs = {
+            "steps": self._steps,
+            "first_step": self.steps_total - self._steps + 1,
+            "exposed_s": self._exposed or 0.0,
+            "exposed_outside_s": self._exposed_outside,
+            "dispatch_steps_s": self._dispatch,
+            "data_next_steps_s": self._data_next,
+            "cpu_s": counts[0] - self._counts[0],
+            "nivcsw": counts[1] - self._counts[1],
+            "majflt": counts[2] - self._counts[2],
+            "gc2": counts[3] - self._counts[3],
+            "threads": threading.active_count(),
+        }
+        for index, name in enumerate(CYCLE_PHASES):
+            attrs[f"{name}_s"] = self._sums[index]
+            attrs[f"{name}_max_s"] = self._maxes[index]
+        dur = now - self._start
+        self._reset(now)
+        self._counts = counts
+        record_span("step.cycle", dur, **attrs)
+
+
+step_cycle = StepCycle()
 
 
 # ---- jax.monitoring bridge: jit.trace / jit.lower / jit.compile ------
@@ -1085,6 +1259,54 @@ def render_waterfall(records: list[dict], width: int = 32) -> str:
     return "\n".join(lines)
 
 
+def render_cycles(records: list[dict], limit: int = 20) -> str:
+    """The ``step.cycle`` spans as a table, one row a pull, in time
+    order (of more than ``limit`` the longest ``limit``); ``*`` marks
+    the longest, ``xMED`` is ``dur`` over the median cycle's.
+    Milliseconds a cycle; ``WORST`` is the phase that held
+    the longest single stretch, which is where a lost second shows
+    (``adaptdl-tpu trace`` prints this)."""
+    cycles = [r for r in records if r.get("name") == "step.cycle"]
+    if not cycles:
+        return "(no cycles)"
+    longest = max(cycles, key=lambda r: float(r.get("dur", 0.0)))
+    shown = sorted(
+        cycles, key=lambda r: -float(r.get("dur", 0.0))
+    )[:limit]
+    shown.sort(key=lambda r: r.get("attrs", {}).get("first_step", 0))
+    median = phase_summary(cycles)["step.cycle"] or 1e-9
+    lines = [
+        f"{len(cycles)} cycle(s), {len(shown)} shown; dur median "
+        f"{median * 1e3:.1f} ms, longest "
+        f"{float(longest.get('dur', 0.0)) * 1e3:.1f} ms",
+        f"  {'FIRST':>7} {'STEPS':>5} {'DUR':>9} {'xMED':>6} "
+        + " ".join(f"{name[:9]:>9}" for name in CYCLE_PHASES)
+        + f" {'exposed':>8} {'cpu':>8} {'nivcsw':>6} {'majflt':>6} "
+        f"{'gc2':>3} {'thr':>3}  WORST",
+    ]
+    for rec in shown:
+        attrs = rec.get("attrs", {})
+        worst = max(
+            CYCLE_PHASES, key=lambda n: attrs.get(f"{n}_max_s", 0.0)
+        )
+        lines.append(
+            f"{'*' if rec is longest else ' '} "
+            f"{attrs.get('first_step', 0):>7} {attrs.get('steps', 0):>5} "
+            f"{float(rec.get('dur', 0.0)) * 1e3:>9.1f} "
+            f"{float(rec.get('dur', 0.0)) / median:>6.2f} "
+            + " ".join(
+                f"{attrs.get(f'{name}_s', 0.0) * 1e3:>9.2f}"
+                for name in CYCLE_PHASES
+            )
+            + f" {attrs.get('exposed_s', 0.0) * 1e3:>8.2f} "
+            f"{attrs.get('cpu_s', 0.0) * 1e3:>8.1f} "
+            f"{attrs.get('nivcsw', 0):>6} {attrs.get('majflt', 0):>6} "
+            f"{attrs.get('gc2', 0):>3} {attrs.get('threads', 0):>3}  "
+            f"{worst} {attrs.get(f'{worst}_max_s', 0.0) * 1e3:.2f}"
+        )
+    return "\n".join(lines)
+
+
 # ---- test isolation --------------------------------------------------
 
 
@@ -1093,7 +1315,8 @@ def _reset_state() -> None:
     journal handle, enablement cache."""
     global _buffer, _seq, _flushed_seq, _enabled, _incarnation
     global _trace_id, _root_span_id, _journal_fh, _journal_target
-    global _journal_disabled
+    global _journal_disabled, step_cycle
+    step_cycle = StepCycle()
     with _buffer_lock:
         _buffer = None
         _seq = 0

@@ -1094,6 +1094,9 @@ class ElasticTrainer:
         atomic_bsz = dataloader.current_atomic_bsz
         accum_steps = dataloader.current_accum_steps
         if atomic_bsz not in self._calibrated:
+            # A new batch size's first-time work is no phase of a
+            # steady cycle: named, through the step program's build.
+            trace.step_cycle.mark(trace.CALIBRATE)
             # A predecessor that ran this layout at this batch size
             # left its measurement in the restored profile: the
             # successor does not build and time the program again.
@@ -1104,7 +1107,11 @@ class ElasticTrainer:
                 )
             self._calibrated.add(atomic_bsz)
         step_fn = self.train_step(atomic_bsz, accum_steps)
+        # The host's phases of a step, marked on the step cycle's clock
+        # (``trace.StepCycle``): around the jitted call, never in it.
+        trace.step_cycle.mark(trace.SHARD)
         batch = self.shard_batch(host_batch)
+        trace.step_cycle.mark(trace.DISPATCH)
         if self.has_aux:
             state, metrics_out = step_fn(state, batch, aux)
         else:
@@ -1118,13 +1125,15 @@ class ElasticTrainer:
         self._steps_since_pull += 1
         if self._steps_since_pull >= self.metrics_every:
             self._steps_since_pull = 0
-            # The span is the wait for the device to finish what was
-            # queued; from its end to the next one's start is the
-            # host's own time for ``metrics_every`` steps.
-            with trace.span("step.pull", steps=self.metrics_every):
-                # graftcheck: disable=GC202 (deliberate gated pull:
-                # drains once every metrics_every steps, not per step)
-                jax.block_until_ready(metrics_out["loss"])
+            # The wait for the device to finish what was queued is the
+            # cycle's ``pull_s``; its return closes the cycle, whose
+            # one ``step.cycle`` span says where the host spent the
+            # ``metrics_every`` steps since the last.
+            trace.step_cycle.mark(trace.PULL)
+            # graftcheck: disable=GC202 (deliberate gated pull:
+            # drains once every metrics_every steps, not per step)
+            jax.block_until_ready(metrics_out["loss"])
+            trace.step_cycle.mark(trace.AFTER_PULL)
             loss_val = float(metrics_out["loss"])  # graftcheck: disable=GC202 (gated above)
             grad_sqr = float(metrics_out["grad_sqr"])  # graftcheck: disable=GC202 (gated above)
             grad_var = float(metrics_out["grad_var"])  # graftcheck: disable=GC202 (gated above)
@@ -1147,6 +1156,7 @@ class ElasticTrainer:
             )
             if "counters" in metrics_out:
                 _journal_counters(metrics_out["counters"])
+        trace.step_cycle.mark(trace.OUTSIDE)
         return state, metrics_out
 
     # ---- checkpoint integration -------------------------------------
