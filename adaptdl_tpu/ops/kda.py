@@ -29,27 +29,34 @@ a unit lower triangular solve a chunk. Two stages:
   ``e^{G_t - G_i}`` is taken exactly, pair by pair and channel by
   channel; between sub-blocks the product is split at the later
   sub-block's first token, ``e^{G_t - R} e^{R - G_i}``, both factors
-  at most 1. The inverse is block forward substitution from single
-  rows up, which does not cancel as a Neumann series does. Products
-  take operands in the input dtype and accumulate in float32; sums of
-  ``g``, the exponents and the inverse (its products at
-  ``Precision.HIGHEST``) are float32. Two programs of that one
-  arithmetic, the same rounding points:
+  at most 1. The inverse ``X = (I + Diag(beta) A)^{-1}`` is block
+  forward substitution from single rows up, which does not cancel as
+  a Neumann series does. Products take operands in the input dtype
+  and accumulate in float32; sums of ``g``, the exponents and the
+  inverse (its products at ``Precision.HIGHEST``) are float32. Two
+  programs of that one arithmetic, the same rounding points:
 
   - a Pallas kernel pair (``delta_chunk_fwd``, ``delta_chunk_bwd``;
-    ``_own_work``, a ``jax.custom_vjp``), a few chunks a grid step,
-    every float32 array of a chunk in VMEM and none of them in HBM:
-    the forward reads q, k, v, g, beta and writes what the recurrence
-    takes (``q e^G``, ``k e^{G_C - G}``, ``W_k``, ``W_v``, ``B`` in the
-    input dtype, ``e^{G_C}``); the backward forms the chunk's forward
-    again (``_own_shared``, the one function both bodies trace) and
-    then every step's transpose by hand, float32 where autodiff would
-    round a cotangent to the operand's dtype. Tokens lie on sublanes
-    and channels on lanes; a sub-block's pairs are walked one earlier
-    token at a time against the later rows, and each column of ``A``
-    so formed is at once one step of the forward substitution inside
-    the sub-block; above a sub-block the block substitution is two
-    whole-chunk products a level;
+    ``_own_work``, a ``jax.custom_vjp``), a few chunks a grid step
+    (the backward walks two of them abreast in one basic block), every
+    float32 array of a chunk in VMEM: the forward reads q, k, v, g,
+    beta and writes what the recurrence takes (``q e^G``, ``k e^{G_C -
+    G}``, ``W_k``, ``W_v``, ``B`` in the input dtype, ``e^{G_C}``).
+    Tokens lie on
+    sublanes and channels on lanes; a sub-block's pairs are walked one
+    earlier token at a time against the later rows, and each column of
+    ``A`` so formed is at once one step of the forward substitution
+    inside the sub-block; above a sub-block the block substitution is
+    two whole-chunk products a level. The inverse is formed ONCE for a
+    group's backward: the forward RULE's kernel writes ``X`` and ``A``
+    out beside the six results (float32 [bh, chunks, C, C] each, 32
+    KiB a chunk, alive from a group's forward rule to its backward),
+    the primal's (the forward pass's run) writes neither, and the
+    backward takes both, forms only the decay sums and the scaled
+    operands again (``_own_shared``, the one function both bodies
+    trace: handed ``X`` and ``A`` it walks no pair and solves nothing)
+    and then every step's transpose by hand, float32 where autodiff
+    would round a cotangent to the operand's dtype;
   - ``_prepare``, for all chunks at once in XLA and differentiated by
     autodiff (``_unit_lower_inverse``): where ``kernel_fits`` says no,
     and what the kernels are tested against.
@@ -93,7 +100,9 @@ row, constant along it), so the state kernels are the same, and hands
 g's gradient back a head's. The solve is the same float32 arithmetic
 (forward substitution, which no pair walk pins to a sub-block of 16
 here: over the whole chunk, so no level is left), written out by the
-forward rule for the backward. Where ``kernel_fits`` says no, the
+forward rule for the backward as the per-channel pair's is (``A`` is
+one cheap product here and is formed again). Where ``kernel_fits``
+says no, the
 decay is broadcast into ``_prepare`` (``decay`` = ``head_as_channel``
 in the ``kda.schedule`` event; ``head`` on the kernel path).
 
@@ -360,12 +369,15 @@ def _octet(sub: int, unrolled: bool) -> int:
     return 8 if sub % 8 == 0 and unrolled else sub
 
 
-def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows):
+def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows, kept=None):
     """One chunk's own work up to ``T``, on values: q, k [C, dk] in
     the input dtype, g [C, dk] float32, beta [1, C] float32.
     ``g_rows``, ``k_rows``: float32 [C, dk] scratch that holds ``G``
-    and k, from which single rows are read. Returns what the forward
-    forms its results from and the backward its gradients."""
+    and k, from which single rows are read. ``kept``: ``(X, A)``, the
+    inverse and the matrix it was formed from as the forward rule wrote
+    them out — the backward's: no pair is then walked here, nothing is
+    solved and ``B`` is not formed. Returns what the forward forms its
+    results from and the backward its gradients."""
     low, f32 = q.dtype, jnp.float32
     chunk, dk = k.shape
     sub = _sub_block(chunk)
@@ -393,9 +405,12 @@ def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows):
             both = jnp.concatenate(
                 [(k_sub * rel).astype(low), (q_sub * rel).astype(low)], axis=0
             )
+            between[at] = (rel, shrunk, back, both)
+        if kept is not None:
+            continue
+        if at:
             off = _dot(both, back, _NT)  # [2 sub, C]
             a_rows, b_rows = off[:sub], off[sub:]
-            between[at] = (rel, shrunk, back, both)
         else:
             a_rows = b_rows = jnp.zeros((sub, chunk), f32)
         octets = range(0, sub, octet)
@@ -436,11 +451,14 @@ def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows):
         a_parts += a_sub
         b_parts += b_sub
         x_parts += x_sub
-    a_full = jnp.where(row > col, jnp.concatenate(a_parts, axis=0), 0.0)
-    b_full = jnp.where(row >= col, jnp.concatenate(b_parts, axis=0), 0.0)
-    inv = _unit_lower_inverse_chunk(
-        jnp.concatenate(x_parts, axis=0), beta_col * a_full, masks
-    )
+    if kept is None:
+        a_full = jnp.where(row > col, jnp.concatenate(a_parts, axis=0), 0.0)
+        b_full = jnp.where(row >= col, jnp.concatenate(b_parts, axis=0), 0.0)
+        inv = _unit_lower_inverse_chunk(
+            jnp.concatenate(x_parts, axis=0), beta_col * a_full, masks
+        )
+    else:
+        (inv, a_full), b_full = kept, None
     grown = jnp.exp(big_g)
     last = g_rows[pl.ds(chunk - 1, 1), :]
     return dict(
@@ -452,8 +470,12 @@ def _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows):
     )
 
 
-def _own_fwd_kernel(q, k, v, g, beta, qp, kd, wk, wv, b, dl, *rows, scale,
-                    unrolled):
+def _own_fwd_kernel(q, k, v, g, beta, qp, kd, wk, wv, b, dl, *rest, scale,
+                    unrolled, keep):
+    # ``rest``: with ``keep`` the blocks of the inverse and of A, for
+    # the backward; then the two scratch rows.
+    kept, rows = rest[:2 * keep], rest[2 * keep:]
+
     # (Every block access inside a ``when``, as in the state kernels.)
     @pl.when(pl.program_id(1) >= 0)
     def _chunks():
@@ -468,25 +490,28 @@ def _own_fwd_kernel(q, k, v, g, beta, qp, kd, wk, wv, b, dl, *rows, scale,
             wv[0, j] = _dot(at["solve"], v[0, j]).astype(low)
             b[0, j] = at["b_full"].astype(low)
             dl[0, j] = jnp.exp(at["last"])
+            for ref, name in zip(kept, ("inv", "a_full")):
+                ref[0, j] = at[name]
             return carry
 
         lax.fori_loop(0, q.shape[1], one, 0)
 
 
-def _own_backward(q, k, v, g, beta, cts, scale, unrolled, rows):
+def _own_backward(q, k, v, g, beta, kept, cts, scale, unrolled, rows):
     """One chunk's gradients on values, from the six cotangents of
     what the forward wrote (the first five in the input dtype, the
-    decay's [1, dk] float32): the forward's values are formed again,
-    then every step's transpose by hand, float32 throughout except
-    where the forward's products take the input dtype. ``rows``: four
-    float32 [C, dk] scratch (G, k, and the sums of dG and dk that
+    decay's [1, dk] float32) and ``kept``, the inverse and ``A`` as the
+    forward rule wrote them out: the forward's other values are formed
+    again, then every step's transpose by hand, float32 throughout
+    except where the forward's products take the input dtype. ``rows``:
+    four float32 [C, dk] scratch (G, k, and the sums of dG and dk that
     single rows are added to). Returns (dq, dk, dv, dg, dbeta)."""
     g_rows, k_rows, dg_rows, dk_rows = rows
     low, f32 = q.dtype, jnp.float32
     chunk, dk = k.shape
     sub = _sub_block(chunk)
     octet = _octet(sub, unrolled)
-    at = _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows)
+    at = _own_shared(q, k, g, beta, scale, unrolled, g_rows, k_rows, kept)
     big_g, k32, q32 = at["big_g"], at["k32"], at["q32"]
     grown, left, inv, solve = at["grown"], at["left"], at["inv"], at["solve"]
     row, col = at["row"], at["col"]
@@ -586,21 +611,31 @@ def _own_backward(q, k, v, g, beta, cts, scale, unrolled, rows):
     )
 
 
-def _own_bwd_kernel(q, k, v, g, beta, d_qp, d_kd, d_wk, d_wv, d_b, d_dl,
-                    d_q, d_k, d_v, d_g, d_beta, *rows, scale, unrolled):
+def _own_bwd_kernel(q, k, v, g, beta, *rest, scale, unrolled):
+    # ``rest``: the inverse and A as the forward rule wrote them out,
+    # the six cotangents, the five gradients, and four scratch rows
+    # for each chunk of a basic block.
+    kept, cts, grads, rows = rest[:2], rest[2:8], rest[8:13], rest[13:]
+    abreast = len(rows) // 4
+
     @pl.when(pl.program_id(1) >= 0)
     def _chunks():
-        def one(j, carry):
-            grads = _own_backward(
+        def one(j, rows):
+            values = _own_backward(
                 q[0, j], k[0, j], v[0, j], g[0, j], beta[0, j],
-                tuple(x[0, j] for x in (d_qp, d_kd, d_wk, d_wv, d_b, d_dl)),
+                tuple(x[0, j] for x in kept), tuple(x[0, j] for x in cts),
                 scale, unrolled, rows,
             )
-            for ref, value in zip((d_q, d_k, d_v, d_g, d_beta), grads):
+            for ref, value in zip(grads, values):
                 ref[0, j] = value
+
+        # ``_abreast``'s loop, each chunk with scratch rows of its own.
+        def some(j, carry):
+            for a in range(abreast):
+                one(j * abreast + a, rows[4 * a:4 * a + 4])
             return carry
 
-        lax.fori_loop(0, q.shape[1], one, 0)
+        lax.fori_loop(0, q.shape[1] // abreast, some, 0)
 
 
 def _own_specs(held: int, chunk: int, dk: int, dv: int):
@@ -618,11 +653,7 @@ def _own_specs(held: int, chunk: int, dk: int, dv: int):
 
 
 # Chunks a grid step of the chunks' own work: a step's fixed cost
-# (~0.35 us) is then a small part of a chunk's ~1 us. (Four of them
-# abreast in one basic block ran 1.24 / 2.23 ms a call of 4 heads
-# forward / backward for 1.48 / 2.58 on a v5e, and cost every program
-# that holds the kernels four times the lowering: 137 s of
-# ``trace_lower_s`` in a run of the kimi cell for the parent's 96.)
+# (~0.35 us) is then a small part of a chunk's ~1 us.
 _OWN_HELD = 8
 
 
@@ -648,12 +679,43 @@ def _own_rows(count: int, chunk: int, dk: int):
 _OWN_PARAMS = dict(dimension_semantics=("parallel", "parallel"))
 
 
+# Chunks in one basic block of the BACKWARD kernel's loop, where a grid
+# step holds a multiple of it (``_abreast``'s note); the forward walks
+# one at a time. Measured on a v5e (PR 53: a call of 4 heads x 256
+# chunks of 64, heads of 128, bf16, device time of 16 calls chained in
+# one program), ms a call forward / backward. One chunk at a time, the
+# backward forming the forward again, inverse included: 1.204 / 2.356.
+# The backward handed ``X`` (it solves nothing) 1.521, handed ``A`` too
+# (it walks no pair for it either, only its own transpose's) 1.350,
+# that two abreast **1.168**; the forward that writes both out (32 KiB
+# a chunk) + 0.003. The forward two abreast 1.034 — one chunk's levels
+# on the MXU beside the other's pair walk on the VPU —, four 0.916;
+# with the inverse by ``_substituted_inverse`` over the whole chunk in
+# place of the in-walk steps and the two levels 1.176 one chunk at a
+# time and 1.071 two abreast, for a body two thirds longer: no gain,
+# the levels stay. What a chunk abreast costs: one more copy of the
+# body in every program that holds the kernel — ~1.7 ms of ``setup_s``
+# an equation of the three bodies (primal forward, keeping forward,
+# backward) in a cached run of the kimi cell, where eight programs hold
+# them: both kernels two abreast 16 763 equations for the 10 957 of
+# before, ``setup_s`` 128.9 -> 138.8 (+7.7% of a 10% bound) for 21.7 ms
+# of a 1450 ms step; the backward alone 11 293 (it no longer holds the
+# forward's walk), ``setup_s`` unmoved. So the forward stays at one.
+_OWN_BWD_ABREAST = 2
+
+
+def _abreast_of(held: int, most: int) -> int:
+    return most if held % most == 0 else 1
+
+
 # (Both calls are functions jitted by themselves: a kernel's body is
 # some thousands of operations, unrolled, and a model traces it at
 # every call site of every program. As jitted functions the bodies are
 # traced once a process and shape, and lowered once a program.)
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _own_fwd_pallas(scale: float, how: _How, q, k, v, g, beta):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _own_fwd_pallas(scale: float, how: _How, keep: bool, q, k, v, g, beta):
+    """-> the six results and, with ``keep``, the inverse ``X`` and
+    ``A`` [bh, chunks, C, C] float32 for the backward."""
     bh, chunks, chunk, dk = q.shape
     dv = v.shape[3]
     wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
@@ -662,18 +724,18 @@ def _own_fwd_pallas(scale: float, how: _How, q, k, v, g, beta):
     def out(shape, dtype=q.dtype):
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
+    matrix = (bh, chunks, chunk, chunk)
     return pl.pallas_call(
         functools.partial(
-            _own_fwd_kernel, scale=scale, unrolled=how.unrolled
+            _own_fwd_kernel, scale=scale, unrolled=how.unrolled, keep=keep
         ),
         grid=(bh, chunks // how.held),
         in_specs=[wide, wide, tall, wide, steps],
-        out_specs=[wide, wide, wide, tall, square, decay],
+        out_specs=[wide, wide, wide, tall, square, decay] + [square] * 2 * keep,
         out_shape=[
             out(q.shape), out(q.shape), out(q.shape), out(v.shape),
-            out((bh, chunks, chunk, chunk)),
-            out((bh, chunks, 1, dk), jnp.float32),
-        ],
+            out(matrix), out((bh, chunks, 1, dk), jnp.float32),
+        ] + [out(matrix, jnp.float32)] * 2 * keep,
         scratch_shapes=_own_rows(2, chunk, dk),
         compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
         interpret=how.interpret,
@@ -682,7 +744,9 @@ def _own_fwd_pallas(scale: float, how: _How, q, k, v, g, beta):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _own_bwd_pallas(scale: float, how: _How, q, k, v, g, beta, *cts):
+def _own_bwd_pallas(scale: float, how: _How, q, k, v, g, beta, inv, a_full,
+                    *cts):
+    """``inv``, ``a_full``: what the forward rule kept."""
     bh, chunks, chunk, dk = q.shape
     dv = v.shape[3]
     wide, tall, square, decay, steps = _own_specs(how.held, chunk, dk, dv)
@@ -692,18 +756,20 @@ def _own_bwd_pallas(scale: float, how: _How, q, k, v, g, beta, *cts):
             _own_bwd_kernel, scale=scale, unrolled=how.unrolled
         ),
         grid=(bh, chunks // how.held),
-        in_specs=[wide, wide, tall, wide, steps,
+        in_specs=[wide, wide, tall, wide, steps, square, square,
                   wide, wide, wide, tall, square, decay],
         out_specs=[wide, wide, tall, wide, steps],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma)
             for x in (q, k, v, g, beta)
         ],
-        scratch_shapes=_own_rows(4, chunk, dk),
+        scratch_shapes=_own_rows(
+            4 * _abreast_of(how.held, _OWN_BWD_ABREAST), chunk, dk
+        ),
         compiler_params=pltpu.CompilerParams(**_OWN_PARAMS),
         interpret=how.interpret,
         name=OWN_BWD_KERNEL_NAME,
-    )(q, k, v, g, beta, *cts)
+    )(q, k, v, g, beta, inv, a_full, *cts)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -711,18 +777,19 @@ def _own_work(scale: float, q, k, v, g, beta):
     """``_prepare`` as a kernel pair: q, k, g [bh, chunks, C, dk], v
     [bh, chunks, C, dv], beta [bh, chunks, 1, C]; the six results as
     the state kernels' blocks take them."""
-    return tuple(
-        _own_fwd_pallas(scale, _own_how(q.shape[1]), q, k, v, g, beta)
-    )
+    how = _own_how(q.shape[1])
+    return tuple(_own_fwd_pallas(scale, how, False, q, k, v, g, beta))
 
 
 def _own_work_fwd(scale, *operands):
-    return _own_work(scale, *operands), operands
-
-
-def _own_work_bwd(scale, operands, cts):
     how = _own_how(operands[0].shape[1])
-    return tuple(_own_bwd_pallas(scale, how, *operands, *cts))
+    results = _own_fwd_pallas(scale, how, True, *operands)
+    return tuple(results[:6]), (*operands, *results[6:])
+
+
+def _own_work_bwd(scale, saved, cts):
+    how = _own_how(saved[0].shape[1])
+    return tuple(_own_bwd_pallas(scale, how, *saved, *cts))
 
 
 _own_work.defvjp(_own_work_fwd, _own_work_bwd)
@@ -744,14 +811,17 @@ _own_work.defvjp(_own_work_fwd, _own_work_bwd)
 # substitution a column at a time, now over a matrix that is already
 # there and over the WHOLE chunk (``_substituted_inverse``): no pair
 # walk pins a sub-block to 16 tokens here, and what the chip says is
-# that no level of ``_unit_lower_inverse_chunk`` is worth its two
-# ``HIGHEST`` products. Measured on a v5e (a call of 4 heads x 256
-# chunks of 64, heads of 128, bf16, device time of 16 calls chained
-# in one program; the per-channel pair 1.229 ms forward): the blocks'
-# DMAs alone 0.19 ms, everything but the inverse 0.29, and the inverse
-# the rest — 0.42 ms a level, ~2 ns a [8, C] tile and column of the
-# substitution. Forward with substitution up to 16 / 32 / 64 tokens,
-# one chunk at a time: 1.32 / 1.01 / ~1.0; two chunks abreast in one
+# that ONE chunk at a time no level of ``_unit_lower_inverse_chunk`` is
+# worth its two ``HIGHEST`` products. Measured on a v5e (a call of 4
+# heads x 256 chunks of 64, heads of 128, bf16, device time of 16
+# calls chained in one program; the per-channel pair one chunk at a
+# time 1.229 ms forward): the blocks' DMAs alone 0.19 ms, everything
+# but the inverse 0.29, and the inverse the rest — 0.42 ms a level, ~2
+# ns a [8, C] tile and column of the substitution. (Two chunks abreast
+# the per-channel body's levels run beside the other chunk's pair
+# walk, MXU beside VPU, and the substitution is no faster there:
+# ``_OWN_BWD_ABREAST``'s note.) Forward with substitution up to 16 / 32
+# / 64 tokens, one chunk at a time: 1.32 / 1.01 / ~1.0; two abreast in one
 # basic block (their chains interleave): 0.89 at 32, **0.78 at 64**
 # (four abreast gain ~5% more and double the body); the inverse
 # written out by the forward rule for the backward rather than formed
@@ -1446,6 +1516,7 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         (OWN_HEAD_FWD_KERNEL_NAME, OWN_HEAD_BWD_KERNEL_NAME) if a_head
         else (OWN_FWD_KERNEL_NAME, OWN_BWD_KERNEL_NAME)
     )
+    own_held = _own_how(chunks).held
     trace.event(
         "kda.schedule",
         heads=heads,
@@ -1471,12 +1542,24 @@ def kda(q, k, v, g, beta, *, chunk: int | None = None,
         product="pallas:" + FWD_KERNEL_NAME + "," + BWD_KERNEL_NAME
         if kernel else "scan",
         own_work="pallas:" + own_fwd + "," + own_bwd if kernel else "xla",
+        # The chunk's inverse: levels of block products above a
+        # sub-block, or forward substitution a column at a time over the
+        # whole chunk (the one-decay body's); whether the forward rule
+        # writes it out for the backward; chunks in one basic block of
+        # the chunk kernels.
+        inverse="substituted" if a_head and kernel else "levels",
+        inverse_kept=kernel,
+        chunks_abreast=(
+            _abreast_of(own_held, _HEAD_ABREAST) if a_head else 1
+        ) if kernel else 0,
+        chunks_abreast_bwd=_abreast_of(
+            own_held, _HEAD_ABREAST if a_head else _OWN_BWD_ABREAST
+        ) if kernel else 0,
         backward=(
             BWD_KERNEL_NAME + " over the forward's chunk states, then "
-            + own_bwd + " (" + (
-                "the chunk's inverse as the forward rule wrote it out, the "
-                "rest of its own work" if a_head else "a chunk's own work"
-            ) + " formed again in VMEM, its transpose by hand)" if kernel else
+            + own_bwd + " (the chunk's inverse as the forward rule wrote it "
+            "out, the rest of its own work formed again in VMEM, its "
+            "transpose by hand)" if kernel else
             "a reversed scan over the forward's chunk states; the chunks' "
             "own work by autodiff"
         ) + (", a group of heads at a time, done again in its backward"

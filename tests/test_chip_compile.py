@@ -268,9 +268,11 @@ def test_delta_chunk_kernels_compile_for_v5e(
     """The chunks' own work of the gated delta rule at the published
     widths (four heads of 128, 256 chunks of 64 — a row of 16 384 —,
     bf16): the kernel pair lowers through Mosaic under the names a
-    device trace shows, ``delta_chunk_fwd`` and, in a gradient's,
-    ``delta_chunk_bwd``; neither name holds ``kda_`` (the state
-    kernels' readers match that)."""
+    device trace shows, ``delta_chunk_fwd`` and, in a gradient's, both:
+    the forward rule's kernel writes the inverse and ``A`` out (eight
+    results) and ``delta_chunk_bwd`` takes them among its operands;
+    neither name holds ``kda_`` (the state kernels' readers match
+    that)."""
     kda = importlib.import_module("adaptdl_tpu.ops.kda")
     monkeypatch.setattr(kda, "_use_interpret", lambda: False)
     one = SingleDeviceSharding(v5e.devices[0])
@@ -294,12 +296,23 @@ def test_delta_chunk_kernels_compile_for_v5e(
     assert kda.kernel_fits(128, 128, 64)
     fn = jax.grad(loss, argnums=tuple(range(5))) if what == "grad" else forward
     text = jax.jit(fn).lower(*args).compile().as_text()
-    found = set(
-        re.findall(r"%[\w\-]*?(delta_chunk_(?:fwd|bwd))[\w\-]*[.\d]* = ", text)
+    calls = dict(
+        re.findall(
+            r"%[\w\-]*?(delta_chunk_(?:fwd|bwd))[\w\-]*[.\d]* = (.*)", text
+        )
     )
-    assert found == {"delta_chunk_bwd" if what == "grad" else "delta_chunk_fwd"}
+    assert set(calls) == (
+        {"delta_chunk_fwd", "delta_chunk_bwd"} if what == "grad"
+        else {"delta_chunk_fwd"}
+    )
     assert "kda_" not in kda.OWN_FWD_KERNEL_NAME + kda.OWN_BWD_KERNEL_NAME
+    kept = "f32[4,256,64,64]"  # the inverse, and A beside it
+    results = calls["delta_chunk_fwd"].split(" custom-call(")[0]
+    assert results.count(kept) == (2 if what == "grad" else 0)
+    assert results.count("[4,256,") == (8 if what == "grad" else 6)
     if what == "grad":
+        operands = calls["delta_chunk_bwd"].split(" custom-call(")[1]
+        assert operands.count(kept) == 2
         grads = jax.eval_shape(fn, *args)
         assert [(g.shape, g.dtype) for g in grads] == [
             (a.shape, a.dtype) for a in args

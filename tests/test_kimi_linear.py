@@ -201,16 +201,46 @@ def test_kda_mixer_in_head_groups_is_the_mixer(monkeypatch):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
-def test_kda_schedule_is_journalled():
+@pytest.mark.parametrize("decay", ["channel", "head"])
+def test_kda_schedule_is_journalled(decay):
     before = len(
         [r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"]
     )
-    kda_op.kda(*_kda_inputs(4, seq=40), chunk=16)
+    q, k, v, g, beta = _kda_inputs(4, seq=40)
+    kda_op.kda(q, k, v, g if decay == "channel" else g[..., 0], beta, chunk=16)
     events = [
         r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
     ]
     assert len(events) == before + 1
     attrs = events[-1]["attrs"]
+    assert attrs["decay"] == decay
+    # How the chunk's inverse is formed (the per-channel body: levels
+    # of block products above a sub-block; the one-decay body: forward
+    # substitution over the whole chunk), that the forward rule writes
+    # it out for the backward, chunks in one basic block of the two
+    # chunk kernels (three chunks a grid step here: one).
+    assert (attrs["inverse"], attrs["inverse_kept"]) == (
+        "levels" if decay == "channel" else "substituted", True
+    )
+    assert (attrs["chunks_abreast"], attrs["chunks_abreast_bwd"]) == (1, 1)
+    # Two chunks a grid step: the one-decay pair walks them abreast,
+    # the per-channel pair its backward alone.
+    kda_op.kda(*(x[:, :32] for x in (q, k, v, g, beta)), chunk=16)
+    kda_op.kda(*(x[:, :32] for x in (q, k, v, g[..., 0], beta)), chunk=16)
+    channel, head = [
+        r["attrs"] for r in trace.snapshot_spans()
+        if r["name"] == "kda.schedule"
+    ][-2:]
+    assert (channel["chunks_abreast"], channel["chunks_abreast_bwd"]) == (1, 2)
+    assert (head["chunks_abreast"], head["chunks_abreast_bwd"]) == (2, 2)
+    assert "as the forward rule wrote it out" in attrs["backward"]
+    fallback = kda_op.kda(q, k, v, g, beta, chunk=16, use_kernel=False)
+    assert fallback.shape == v.shape
+    xla = [
+        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
+    ][-1]["attrs"]
+    assert (xla["inverse"], xla["inverse_kept"], xla["chunks_abreast"],
+            xla["chunks_abreast_bwd"]) == ("levels", False, 0, 0)
     assert (attrs["heads"], attrs["head_dim"], attrs["chunk"]) == (2, 8, 16)
     assert attrs["chunks"] == 3 and attrs["padded"] == 8
     short = kda_op.kda(*_kda_inputs(4, seq=12), chunk=64)  # chunks of 8
@@ -409,12 +439,19 @@ def _own_work_both_ways(args, chunk, scale=0.3, xla=True):
         ("float32", 64, 100, 2e-5),  # four, the last chunk padded
         ("bfloat16", 32, 64, 2e-2),
         ("bfloat16", 64, 100, 2e-2),
+        # An odd number of chunks (one chunk a basic block), the last
+        # one padded.
+        ("float32", 16, 40, 2e-5),
+        ("bfloat16", 16, 40, 2e-2),
+        ("float32", 64, 150, 2e-5),
     ],
 )
 def test_chunk_kernels_equal_the_xla_own_work(dtype, chunk, seq, limit):
     """``delta_chunk_fwd`` / ``delta_chunk_bwd`` (interpret mode)
     against ``_prepare`` and its autodiff: every result, and every
-    operand's gradient under random cotangents of all six. In bfloat16
+    operand's gradient under random cotangents of all six — the
+    backward through the inverse and ``A`` that the forward rule wrote
+    out, where ``_prepare``'s autodiff keeps its own. In bfloat16
     the forward rounds where ``_prepare`` rounds (nearly the same
     bits); the hand-written backward keeps float32 where autodiff
     rounds a cotangent to the operand's bfloat16."""
@@ -450,6 +487,115 @@ def test_chunk_kernels_at_the_edges(case):
         assert _rel(a, b) < 1e-5 * loose
     for a, b in zip(got_grads, want_grads):
         assert _rel(a, b) < 2e-5 * loose
+
+
+@pytest.mark.parametrize(
+    "dtype,chunk,decay",
+    [
+        ("float32", 16, 0.5), ("float32", 32, 0.5), ("float32", 64, 0.5),
+        ("bfloat16", 16, 0.5), ("bfloat16", 32, 0.5), ("bfloat16", 64, 0.5),
+        ("float32", 64, 40.0),  # no float32 inverse of e^G holds
+    ],
+)
+def test_the_forward_rule_writes_out_the_inverse(
+    monkeypatch, dtype, chunk, decay
+):
+    """What ``_own_work``'s forward rule keeps for ``delta_chunk_bwd``:
+    ``X = (I + Diag(beta) A)^-1`` and ``A``, float32 whatever the
+    operands' dtype — the matrices the XLA ``_prepare`` hands its
+    ``_unit_lower_inverse`` and gets back on the same chunks, the last
+    chunk padded."""
+    args = _kda_inputs(
+        13, batch=1, seq=chunk + chunk // 2, dtype=jnp.dtype(dtype),
+        decay=decay,
+    )
+    q, k, v, g, beta = _chunked(args, chunk)
+    seen = {}
+    block_products = kda_op._unit_lower_inverse
+
+    def spy(lower):
+        seen["lower"], seen["inv"] = lower, block_products(lower)
+        return seen["inv"]
+
+    monkeypatch.setattr(kda_op, "_unit_lower_inverse", spy)
+    want = _prepare_blocks((q, k, v, g, beta), chunk)
+    results, saved = kda_op._own_work_fwd(
+        0.3, q, k, v, g, beta[:, :, None, :]
+    )
+    assert len(results) == 6 and len(saved) == 7
+    # (At e^-40 a token what is left of a product is differences of
+    # terms many times its size, in either program.)
+    loose = 50 if decay > 1 else 1
+    for a, b in zip(results, want):
+        assert _rel(a, b) < (1e-5 * loose if dtype == "float32" else 4e-3)
+    inv, a_full = saved[5:]
+    shape = q.shape[:2] + (chunk, chunk)
+    assert inv.dtype == a_full.dtype == jnp.float32
+    assert inv.shape == a_full.shape == shape
+    limit = 1e-5 if dtype == "float32" else 2e-3
+    assert _rel(inv, seen["inv"].reshape(shape)) < limit
+    lower = beta[..., None] * a_full
+    assert _rel(lower, seen["lower"].reshape(shape)) < limit * loose
+    # Unit lower triangular, and the inverse of what it is said to be.
+    upper = np.triu(np.ones((chunk, chunk), bool), 1)
+    assert not np.asarray(inv)[..., upper].any()
+    assert (np.diagonal(inv, axis1=-2, axis2=-1) == 1.0).all()
+    both = jnp.matmul(
+        inv, jnp.eye(chunk) + lower, precision=jax.lax.Precision.HIGHEST
+    )
+    assert _rel(both, jnp.broadcast_to(jnp.eye(chunk), shape)) < 1e-5
+
+
+def _pallas_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn``'s jaxpr by kernel name,
+    jitted functions and custom rules opened."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.setdefault(eqn.params["name"], []).append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("what", ["primal", "gradient"])
+def test_only_the_forward_rule_writes_the_inverse_out(what):
+    """The primal ``_own_work`` (a group's run in the forward pass) is
+    one ``delta_chunk_fwd`` of six results: it writes no ``X``. A
+    gradient's program holds the keeping forward (eight: ``X`` and
+    ``A`` float32 [bh, chunks, C, C] beside the six) and a
+    ``delta_chunk_bwd`` that takes both among its operands and
+    nothing else of the forward's."""
+    q, k, v, g, beta = _chunked(_kda_inputs(14, batch=1, seq=64), 32)
+    operands = (q, k, v, g, beta[:, :, None, :])
+    matrix = q.shape[:2] + (32, 32)
+
+    def primal(*a):
+        return kda_op._own_work(0.3, *a)
+
+    def loss(*a):
+        return sum(x.astype(jnp.float32).sum() for x in primal(*a))
+
+    if what == "primal":
+        calls = _pallas_calls(primal, *operands)
+        assert set(calls) == {"delta_chunk_fwd"}
+        (call,) = calls["delta_chunk_fwd"]
+        assert len(call.outvars) == 6
+        assert [x.aval.shape for x in call.outvars].count(matrix) == 1
+        return
+    calls = _pallas_calls(jax.grad(loss, tuple(range(5))), *operands)
+    assert set(calls) == {"delta_chunk_fwd", "delta_chunk_bwd"}
+    (forward,), (backward,) = calls["delta_chunk_fwd"], calls["delta_chunk_bwd"]
+    kept = [x.aval for x in forward.outvars[6:]]
+    assert len(forward.outvars) == 8
+    assert [(x.shape, x.dtype) for x in kept] == [(matrix, jnp.float32)] * 2
+    # The five operands, X and A, the six cotangents.
+    assert len(backward.invars) == 13
+    assert [x.aval for x in backward.invars[5:7]] == kept
 
 
 def test_the_unrolled_walk_of_a_sub_block_is_the_loop(monkeypatch):
