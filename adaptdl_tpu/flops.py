@@ -15,6 +15,7 @@ available to user code for their own reporting.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 # Peak dense bf16 FLOP/s per chip by TPU generation. Keyed by
@@ -73,7 +74,9 @@ def transformer_train_flops(
     or "mla" layer (``layer_types``), and a "full_attention" layer of
     a model with ``attention_gate``, is counted at its own projections
     and mixing (``_kda_layer`` / ``_gdn_layer`` / ``_mla_layer`` /
-    ``_gated_attention_layer``); a layer with routed
+    ``_gated_attention_layer``), a layer of a kind that
+    ``attention_kinds`` describes at the kind's own heads and, under a
+    window, over its band (``_kind_attention_layer``); a layer with routed
     experts at its router, the HELD share of the routed experts under
     even routing, and the shared expert.
     """
@@ -108,6 +111,8 @@ def transformer_train_flops(
     apart = {"kda": _kda_layer, "mla": _mla_layer, "gdn": _gdn_layer}
     if getattr(config, "attention_gate", False):
         apart["full_attention"] = _gated_attention_layer
+    for kind, _ in getattr(config, "attention_kinds", ()):
+        apart[kind] = functools.partial(_kind_attention_layer, kind=kind)
     new_kinds = [k for k in kinds if k in apart]
     extra_matmul = extra_attn = 0.0
     if new_kinds:
@@ -195,6 +200,35 @@ def _gated_attention_layer(config, seq_len: int) -> tuple[float, float]:
     if getattr(config, "causal", True):
         attn /= 2
     return float(proj), float(attn)
+
+
+def _kind_attention_layer(
+    config, seq_len: int, kind: str
+) -> tuple[float, float]:
+    """(projection FLOPs, attention FLOPs) a token of a grouped-query
+    attention layer of a kind ``attention_kinds`` describes: the
+    kind's own number of query heads (q, a per-head gate, out), k and
+    v of the kv heads; QK^T and PV over the causal half, or, where the
+    kind has a window, over the BAND: a query's keys are ``min(i + 1,
+    window)``, their mean over the row what a token is counted at."""
+    own = config.attention_kind(kind)
+    d, heads, hd = config.d_model, own.num_heads, config.attention_head_dim
+    kv_heads = config.num_kv_heads or heads
+    gate = (
+        heads * hd if getattr(config, "attention_gate", False)
+        else heads if getattr(config, "attention_head_gate", False) else 0
+    )
+    proj = 2 * (
+        d * heads * hd + d * gate + d * 2 * kv_heads * hd + heads * hd * d
+    )
+    from adaptdl_tpu.ops.flash_attention import keys_in_window
+
+    keys = float(seq_len)
+    if getattr(config, "causal", True):
+        keys = seq_len / 2 if own.window is None else (
+            keys_in_window(seq_len, own.window) / seq_len
+        )
+    return float(proj), float(2 * keys * heads * 2 * hd)
 
 
 def _mla_layer(config, seq_len: int) -> tuple[float, float]:

@@ -31,6 +31,43 @@ from adaptdl_tpu import device_budget, trace
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN's frequencies for a rotary of base ``theta`` over ``D``
+    lanes (``yarn_frequencies``): pair ``i`` turns by ``f_i (1 - r_i)
+    + (f_i / factor) r_i``, ``r`` a ramp from the pair that makes
+    ``beta_fast`` turns in ``original_max_position`` positions to the
+    one that makes ``beta_slow``; cosine and sine of the rotated lanes
+    are multiplied by ``attention_factor`` (None: ``0.1 ln(factor) +
+    1``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+    @property
+    def scale(self) -> float:
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 0.1 * math.log(self.factor) + 1.0
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What ONE softmax-attention mixer kind of ``layer_types`` has of
+    its own (``TransformerConfig.attention_kinds``); None = the
+    config's. ``window``: query ``i`` sees the keys ``j <= i`` with
+    ``i - j < window``."""
+
+    num_heads: int | None = None
+    rope_theta: float | None = None
+    rotary_dims: int | None = None
+    yarn: Yarn | None = None
+    window: int | None = None
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     num_layers: int = 12
@@ -218,9 +255,63 @@ class TransformerConfig:
     linear_value_heads: int = 0
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
+    # What differs between the softmax-attention kinds of
+    # ``layer_types``, said once a kind: ``(("full_attention",
+    # AttentionKind(...)), ("sliding_attention", AttentionKind(...,
+    # window=512)))`` — the number of query heads (on the config's
+    # ``num_kv_heads``), the rotary base, the rotated lanes, YaRN's
+    # frequencies, the window. A kind with an entry runs
+    # ``GroupedQueryAttention``; "sliding_attention" needs one with a
+    # ``window``. Empty = every attention layer as the config says.
+    attention_kinds: tuple[tuple[str, AttentionKind], ...] = ()
+    # A per-head output gate on softmax attention: ``sigmoid(x W_g)``,
+    # ``W_g: d_model -> heads`` (``gate``), ONE gate a head and token,
+    # multiplied into the head's output before ``out``
+    # (``attention_gate`` is the full-rank one). Selects
+    # ``GroupedQueryAttention``.
+    attention_head_gate: bool = False
 
     def __post_init__(self):
         kinds = self.layer_types or ()
+        of_kind = dict(self.attention_kinds)
+        if len(of_kind) != len(self.attention_kinds):
+            raise ValueError("attention_kinds names a kind twice")
+        for name, kind in of_kind.items():
+            if name not in ("full_attention", "sliding_attention"):
+                raise ValueError(
+                    f"attention_kinds: {name!r} is no softmax-attention "
+                    "kind ('full_attention', 'sliding_attention')"
+                )
+            heads = kind.num_heads or self.num_heads
+            if heads % (self.num_kv_heads or heads):
+                raise ValueError(
+                    f"attention_kinds[{name!r}]: {heads} query heads on "
+                    f"{self.num_kv_heads} kv heads"
+                )
+            if kind.window is not None and kind.window < 1:
+                raise ValueError(
+                    f"attention_kinds[{name!r}]: window {kind.window}"
+                )
+            if kind.yarn is not None and not self.rope:
+                raise ValueError(
+                    f"attention_kinds[{name!r}]: yarn without rope"
+                )
+        if "sliding_attention" in kinds:
+            if getattr(of_kind.get("sliding_attention"), "window", None) is None:
+                raise ValueError(
+                    "a 'sliding_attention' layer needs attention_kinds to "
+                    "give the kind its window"
+                )
+            if self.seq_axis is not None:
+                raise ValueError(
+                    "seq_axis: a 'sliding_attention' layer has no "
+                    "sequence-parallel path (ring attention does not "
+                    "fold a window)"
+                )
+        if self.attention_head_gate and self.attention_gate:
+            raise ValueError(
+                "attention_head_gate and attention_gate: one output gate"
+            )
         if "gdn" in kinds:
             if self.seq_axis is not None:
                 raise ValueError(
@@ -290,16 +381,63 @@ class TransformerConfig:
     def routed(self, layer: int) -> bool:
         return self.experts_total > 0 and layer >= self.num_dense_layers
 
+    def attention_kind(self, kind: str) -> AttentionKind:
+        """The softmax-attention kind ``kind`` with the config's values
+        where ``attention_kinds`` says nothing (``rotary_dims`` None =
+        every lane, as the config's)."""
+        own = dict(self.attention_kinds).get(kind, AttentionKind())
+        return AttentionKind(
+            num_heads=own.num_heads or self.num_heads,
+            rope_theta=own.rope_theta or self.rope_theta,
+            rotary_dims=own.rotary_dims or self.rotary_dims,
+            yarn=own.yarn,
+            window=own.window,
+        )
+
+    def layer_heads(self, layer: int) -> int:
+        """Query heads of layer ``layer``'s softmax attention."""
+        return self.attention_kind(self.mixer(layer)).num_heads
+
+
+def yarn_frequencies(theta: float, rotary_dims: int, yarn: Yarn):
+    """The ``rotary_dims / 2`` pair frequencies of a YaRN rotary
+    (``Yarn``), float32: ``f_i = theta ** (-2i / D)`` blended with
+    ``f_i / factor`` by the ramp ``clip((i - lo) / (hi - lo), 0, 1)``,
+    ``lo`` / ``hi`` the (floored / ceiled, clamped) pairs that make
+    ``beta_fast`` / ``beta_slow`` turns in the original context.
+    Computed in float64 on the host: a table, not part of the
+    program."""
+    import numpy as np
+
+    def pair_of(turns: float) -> float:
+        return (
+            rotary_dims
+            * math.log(yarn.original_max_position / (turns * 2 * math.pi))
+            / (2 * math.log(theta))
+        )
+
+    lo = max(math.floor(pair_of(yarn.beta_fast)), 0)
+    hi = min(math.ceil(pair_of(yarn.beta_slow)), rotary_dims - 1)
+    pair = np.arange(rotary_dims // 2, dtype=np.float64)
+    base = theta ** (-2.0 * pair / rotary_dims)
+    ramp = np.clip((pair - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (base * (1.0 - ramp) + base / yarn.factor * ramp).astype(
+        np.float32
+    )
+
 
 def rope(
     x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
-    rotary_dims: int | None = None,
+    rotary_dims: int | None = None, freqs=None, scale: float = 1.0,
 ) -> jnp.ndarray:
     """Rotary position embedding over the last (head_dim) axis:
     adjacent pairs ``(x[2i], x[2i + 1])`` turned by ``positions *
     theta ** (-2i / head_dim)``. With ``rotary_dims`` only the first
     that many lanes turn (by ``theta ** (-2i / rotary_dims)``); the
-    others have cosine 1 and sine 0, exactly.
+    others have cosine 1 and sine 0, exactly. ``freqs``: the pairs'
+    frequencies as a table (``yarn_frequencies``; two lanes a pair,
+    the first lanes of a head) in place of ``theta``'s, and ``scale``
+    what the rotated lanes' cosine and sine are multiplied by.
 
     x: [batch, seq, heads, head_dim], the projection's own layout;
     positions: [seq].
@@ -314,7 +452,15 @@ def rope(
     values are the same to the bit.
     """
     lane = jnp.arange(x.shape[-1])
-    if rotary_dims is None:
+    if freqs is not None:
+        table = jnp.asarray(freqs, jnp.float32)
+        rotary_dims = 2 * table.shape[0]
+        assert rotary_dims <= x.shape[-1]
+        freqs = jnp.where(
+            lane < rotary_dims,
+            table[jnp.minimum(lane // 2, table.shape[0] - 1)], 0.0,
+        )
+    elif rotary_dims is None:
         freqs = 1.0 / (theta ** ((lane // 2 * 2) / x.shape[-1]))
     else:
         assert rotary_dims % 2 == 0 and rotary_dims <= x.shape[-1]
@@ -323,9 +469,15 @@ def rope(
             1.0 / (theta ** ((lane // 2 * 2) / rotary_dims)), 0.0,
         )
     angles = positions[:, None] * freqs[None, :]  # [seq, head_dim]
-    sin = jnp.sin(angles).astype(x.dtype)
+    sin, cos = jnp.sin(angles), None
+    if scale != 1.0:  # of the rotated lanes: the others keep 0 and 1
+        turned = lane < (x.shape[-1] if rotary_dims is None else rotary_dims)
+        sin = sin * scale
+        cos = jnp.where(turned, jnp.cos(angles) * scale, 1.0)
+    sin = sin.astype(x.dtype)
     sin = jnp.where(lane % 2 == 0, -sin, sin)[:, None, :]
-    cos = jnp.cos(angles).astype(x.dtype)[:, None, :]
+    cos = (jnp.cos(angles) if cos is None else cos).astype(x.dtype)
+    cos = cos[:, None, :]
     swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
     partner = jnp.einsum(
         "...d,de->...e", x, swap, precision=jax.lax.Precision.HIGHEST
@@ -333,9 +485,11 @@ def rope(
     return x * cos + partner * sin
 
 
-def causal_attention(q, k, v, axis_name=None, causal=True):
+def causal_attention(q, k, v, axis_name=None, causal=True, window=None):
     """Plain attention; q/k/v: [batch, heads, seq, head_dim].
-    ``causal=False`` attends bidirectionally (encoder-style)."""
+    ``causal=False`` attends bidirectionally (encoder-style).
+    ``window`` (causal only): a query sees itself and the ``window -
+    1`` keys before it."""
     del axis_name
     seq_len = q.shape[2]
     scale = q.shape[-1] ** -0.5
@@ -345,7 +499,11 @@ def causal_attention(q, k, v, axis_name=None, causal=True):
     logits = logits * scale
     if causal:
         mask = jnp.tril(jnp.ones((seq_len, seq_len), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((seq_len, seq_len), bool), -window)
         logits = jnp.where(mask[None, None], logits, -1e30)
+    elif window is not None:
+        raise ValueError("window: causal attention only")
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
@@ -457,24 +615,29 @@ def make_norm(cfg: TransformerConfig):
     )
 
 
-def _heads_a_call(attn, heads, seq_len, qk_width, v_width, itemsize):
+def _heads_a_call(
+    attn, heads, seq_len, qk_width, v_width, itemsize, window=None
+):
     """How many heads one call of ``attn`` is given: as many as the
     flash kernels want at once (``ops.flash_attention.heads_a_call``,
     at the attention's own blocks where it says them: past 16k keys
     the backward writes a float32 dQ a key chunk for the heads it is
     given). Any ``attention_fn`` is asked so: a ``functools.partial``
     of the kernel says nothing of itself but its blocks. All of them
-    for plain attention (``attn`` None)."""
+    for plain attention (``attn`` None). ``window``: the layer's,
+    where it has one."""
     if attn is None:
         return heads
     from adaptdl_tpu.ops.flash_attention import heads_a_call
 
-    blocks = {
+    said = {
         k: v for k, v in getattr(attn, "keywords", {}).items()
         if k in ("block_q", "block_k")
     }
+    if window is not None:
+        said["window"] = window
     return getattr(attn, "heads_a_call", heads_a_call)(
-        heads, seq_len, qk_width, v_width, itemsize, **blocks,
+        heads, seq_len, qk_width, v_width, itemsize, **said,
     )
 
 
@@ -508,9 +671,15 @@ class GroupedQueryAttention(nn.Module):
     second half a gate, and ``out`` takes ``o * sigmoid(gate)``; the
     heads then go in runs of as many as the flash kernels want at
     once, and ``gated_attn.schedule`` is journalled where the module
-    is traced."""
+    is traced. ``kind``: the layer's mixer kind; what
+    ``attention_kinds`` says of it (query heads, rotary, YaRN, the
+    window) is this layer's, ``attention_fn`` is handed the window as
+    ``window=`` (a function given to a model with sliding layers takes
+    the keyword), and ``attn_kind.schedule`` is journalled. With
+    ``attention_head_gate`` one sigmoid gate a head (``gate``)."""
 
     config: TransformerConfig
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, x, positions):
@@ -520,17 +689,23 @@ class GroupedQueryAttention(nn.Module):
                 "grouped-query attention has no sequence-parallel path"
             )
         head_dim = cfg.attention_head_dim
-        kv_heads = cfg.num_kv_heads or cfg.num_heads
-        group = cfg.num_heads // kv_heads
-        assert group * kv_heads == cfg.num_heads, (
-            f"{cfg.num_heads} query heads on {kv_heads} kv heads"
+        own = cfg.attention_kind(self.kind)
+        heads, by_kind = own.num_heads, self.kind in dict(cfg.attention_kinds)
+        kv_heads = cfg.num_kv_heads or heads
+        group = heads // kv_heads
+        assert group * kv_heads == heads, (
+            f"{heads} query heads on {kv_heads} kv heads"
         )
         q = nn.DenseGeneral(
-            (cfg.num_heads, (2 if cfg.attention_gate else 1) * head_dim),
+            (heads, (2 if cfg.attention_gate else 1) * head_dim),
             axis=-1, dtype=cfg.dtype, use_bias=False, name="q",
         )(x)
         if cfg.attention_gate:
             q, gate = q[..., :head_dim], q[..., head_dim:]
+        elif cfg.attention_head_gate:
+            gate = nn.DenseGeneral(
+                heads, dtype=cfg.dtype, use_bias=False, name="gate"
+            )(x)[..., None]  # [b, s, h, 1]: one a head and token
         kv = nn.DenseGeneral(
             (2, kv_heads, head_dim), axis=-1, dtype=cfg.dtype,
             use_bias=False, name="kv",
@@ -540,33 +715,53 @@ class GroupedQueryAttention(nn.Module):
             q = _rms_norm(cfg, "q_norm")(q)
             k = _rms_norm(cfg, "k_norm")(k)
         if cfg.rope:
-            q = rope(q, positions, cfg.rope_theta, cfg.rotary_dims)
-            k = rope(k, positions, cfg.rope_theta, cfg.rotary_dims)
+            table = {}
+            if own.yarn is not None:
+                table = {
+                    "freqs": yarn_frequencies(
+                        own.rope_theta, own.rotary_dims or head_dim, own.yarn
+                    ),
+                    "scale": own.yarn.scale,
+                }
+            q = rope(q, positions, own.rope_theta, own.rotary_dims, **table)
+            k = rope(k, positions, own.rope_theta, own.rotary_dims, **table)
         attn = cfg.attention_fn
-        run = cfg.num_heads
-        if cfg.attention_gate:
+        run = heads
+        gated = cfg.attention_gate or cfg.attention_head_gate
+        if gated or by_kind:
             run = _heads_a_call(
-                attn, cfg.num_heads, x.shape[1], head_dim, head_dim,
-                jnp.dtype(cfg.dtype).itemsize,
+                attn, heads, x.shape[1], head_dim, head_dim,
+                jnp.dtype(cfg.dtype).itemsize, own.window,
             )
+            of_kind = {}
+            if by_kind:
+                of_kind = {
+                    "kind": self.kind, "window": own.window or 0,
+                    "rope_theta": own.rope_theta,
+                    "yarn_factor": own.yarn.factor if own.yarn else 0,
+                }
             trace.event(
-                "gated_attn.schedule",
-                heads=cfg.num_heads,
+                "attn_kind.schedule" if by_kind else "gated_attn.schedule",
+                heads=heads,
                 kv_heads=kv_heads,
                 head_dim=head_dim,
-                rotary_dims=(cfg.rotary_dims or head_dim) if cfg.rope else 0,
-                gate="sigmoid",
+                rotary_dims=(own.rotary_dims or head_dim) if cfg.rope else 0,
+                gate="head" if cfg.attention_head_gate
+                else "sigmoid" if cfg.attention_gate else "none",
+                **of_kind,
                 heads_a_call=run,
                 seq_len=x.shape[1],
                 dtype=jnp.dtype(cfg.dtype).name,
                 attention="attention_fn" if attn is not None
                 else "plain causal attention",
             )
-        if attn is None:
-            from functools import partial
+        from functools import partial
 
+        if attn is None:
             attn = partial(causal_attention, causal=cfg.causal)
-        if run < cfg.num_heads:
+        if own.window is not None:
+            attn = partial(attn, window=own.window)
+        if run < heads:
             # A run of heads a call, and each run's kv heads repeated
             # for that run alone: at 16 heads of 256 on 2 kv heads and
             # 16 384 keys, k and v repeated for all heads at once are
@@ -590,11 +785,11 @@ class GroupedQueryAttention(nn.Module):
                 jnp.swapaxes(v, 1, 2),
             )  # [b, h, s, d]
         out = jnp.swapaxes(out, 1, 2)
-        if cfg.attention_gate:
+        if gated:
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
                 cfg.dtype
             )
-        out = out.reshape(x.shape[:-1] + (cfg.num_heads * head_dim,))
+        out = out.reshape(x.shape[:-1] + (heads * head_dim,))
         return nn.DenseGeneral(
             cfg.d_model, dtype=cfg.dtype, use_bias=False, name="out"
         )(out)
@@ -1284,14 +1479,18 @@ def _mixer(cfg: TransformerConfig, layer: int) -> nn.Module:
         return GatedDeltaNet(cfg, name="gdn")
     if kind == "mla":
         return LatentAttention(cfg, name="mla")
-    if kind != "full_attention":
+    if kind not in ("full_attention", "sliding_attention"):
         raise ValueError(
-            f"layer_types[{layer}] must be 'full_attention', 'conv', "
-            f"'sparse_attention', 'kda', 'gdn' or 'mla', got {kind!r}"
+            f"layer_types[{layer}] must be 'full_attention', "
+            "'sliding_attention', 'conv', 'sparse_attention', 'kda', "
+            f"'gdn' or 'mla', got {kind!r}"
         )
+    if kind in dict(cfg.attention_kinds):
+        # The kind has values of its own (heads, rotary, a window).
+        return GroupedQueryAttention(cfg, kind, name="attention")
     if (
         cfg.num_kv_heads not in (None, cfg.num_heads) or cfg.qk_norm
-        or cfg.attention_gate
+        or cfg.attention_gate or cfg.attention_head_gate
     ):
         return GroupedQueryAttention(cfg, name="attention")
     return Attention(cfg, name="attention")
@@ -1402,16 +1601,24 @@ def _remat_ladder(config: TransformerConfig, tokens_shape):
 
     tokens = math.prod(tokens_shape)
     left = budget.free_bytes - 2 * tokens * config.vocab_size * 4
+    # A rung's width summed over the layers: blocks of unequal size
+    # (``attention_kinds``: a kind's own number of query heads) are
+    # each priced at their own.
+    layers = range(config.num_layers)
     ladder = [
         ("qkv", (FLASH_QKV, SAVED_QKV),
-         3 * config.num_heads * config.attention_head_dim),
-        ("mixed", (SAVED_MIXED,), config.d_model),
+         sum(
+             3 * config.layer_heads(at) * config.attention_head_dim
+             for at in layers
+         )),
+        ("mixed", (SAVED_MIXED,), config.num_layers * config.d_model),
     ]
     if config.ffn == "gelu":
-        ladder.append(("ff_up", (SAVED_FF_UP,), config.d_ff))
+        ladder.append(
+            ("ff_up", (SAVED_FF_UP,), config.num_layers * config.d_ff)
+        )
     per_width = (
-        config.num_layers * config.loop_passes * tokens
-        * jnp.dtype(config.dtype).itemsize
+        config.loop_passes * tokens * jnp.dtype(config.dtype).itemsize
     )
     priced = [
         (rung, rung_names, per_width * width)

@@ -104,6 +104,17 @@ is not computed a second time (``models.transformer.block_remat``).
   float32 partial per chunk, summed outside. Correct and 7 x the scan
   it replaced at 16k x 128 (PERF.md, PR 26); tuned in no cell.
 
+A lower bound on the keys (``window``, sliding-window attention): a
+query sees itself and the ``window - 1`` keys before it, so a query
+tile needs its own K/V block and the few before it whatever the row's
+length, and the walk is the BAND's alone — neither resident K/V nor
+key chunks, no partial dQ, the shape and the window decide
+(``_band_schedule``; ``flash.schedule*`` carry ``window``,
+``tiles_visited``, ``tiles_in_band``, and ``window.keys`` the logit
+columns computed against the band's pairs). The section "a lower bound
+on the keys" below holds both kernels; ``window`` None or ``>= seq``
+is the schedule above, the same program.
+
 On CPU the kernel runs in interpret mode (bit-accurate semantics,
 Python speed) so the whole path is testable without hardware — and a program lowered that way carries no ``MOSAIC_CALL``,
 which is what the chip-path checks look for. The mesh-sharded
@@ -481,7 +492,7 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
 def flash_attention(
     q,
@@ -491,6 +502,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
+    window: int | None = None,
 ):
     """Blockwise exact attention.
 
@@ -503,12 +515,19 @@ def flash_attention(
         seq; ``min(block, seq)`` is used). Both kernels fuse adjacent
         tiles and decide themselves how much a grid step and an
         update cover (module docstring).
+      window: a lower bound on the keys (sliding-window attention,
+        causal only): query ``i`` sees the keys ``j <= i`` with ``i -
+        j < window``, itself and the ``window - 1`` before it. None,
+        or a window that reaches the row's start from its last query
+        (``window >= seq``), is plain causal attention: the same
+        program. A shorter one takes the band schedule (module
+        docstring, "A lower bound on the keys").
 
     Returns:
       ``[batch, heads, seq, v's head_dim]``, dtype of ``q``.
     """
     *_, out, _ = _flash_fwd(
-        q, k, v, causal, scale, block_q, block_k, with_lse=False
+        q, k, v, causal, scale, block_q, block_k, False, window
     )
     return _from_kernel(out, v.shape)
 
@@ -530,7 +549,22 @@ def _from_kernel(x, shape):
     return jnp.swapaxes(x.reshape(batch, heads, head_dim, seq_len), 2, 3)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
+def _band(window, seq_len: int, causal: bool) -> int | None:
+    """The window where it bounds the walk: None for no window and for
+    one that every query's keys fit (today's program)."""
+    if window is None or window >= seq_len:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"window={window}: a window is a lower bound on a CAUSAL "
+            "query's keys, at least the query itself"
+        )
+    return int(window)
+
+
+def _flash_fwd(
+    q, k, v, causal, scale, block_q, block_k, with_lse, window=None
+):
     """-> (q, k, v, out, lse) as the kernels index them."""
     head_dim = q.shape[-1]
     resolved_scale = (
@@ -539,13 +573,19 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, with_lse):
     operands = tuple(_to_kernel(x) for x in (q, k, v))
     if with_lse:  # the forward rule: what a remat around it may keep
         operands = tuple(checkpoint_name(x, SAVED_QKV) for x in operands)
-    out, lse = _fwd_pallas(
-        *operands, causal, resolved_scale, block_q, block_k, with_lse
-    )
+    band = _band(window, q.shape[2], causal)
+    if band is None:
+        out, lse = _fwd_pallas(
+            *operands, causal, resolved_scale, block_q, block_k, with_lse
+        )
+    else:
+        out, lse = _window_fwd_pallas(
+            *operands, resolved_scale, block_q, block_k, band, with_lse
+        )
     return (*operands, out, lse)
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     """Residuals ``(q, k, v, out, lse)`` as the backward kernel reads
     them (a copy here would be a copy a step): the first four in the
     kernels' layout, ``lse`` as ``[batch * heads, 1, seq]``. All five
@@ -554,7 +594,7 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k):
     call can keep them: a name inside a ``custom_vjp``'s forward rule
     is seen by the enclosing remat."""
     *operands, out, lse = _flash_fwd(
-        q, k, v, causal, scale, block_q, block_k, with_lse=True
+        q, k, v, causal, scale, block_q, block_k, True, window
     )
     out = checkpoint_name(out, SAVED_OUT)
     lse = checkpoint_name(lse, SAVED_LSE)
@@ -803,7 +843,7 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
     return dq, dk, dv
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, window, residuals, g):
     """The flash-attention backward identities, on the forward's
     schedule in one Pallas kernel (:func:`_bwd_kernel`): P is
     recomputed from the saved log-sum-exp, never stored.
@@ -817,10 +857,17 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
     head_dim = q.shape[1]
     resolved_scale = head_dim**-0.5 if scale is None else float(scale)
-    grads = _bwd_pallas(
-        q, k, v, _to_kernel(g), out, lse,
-        causal, resolved_scale, block_q, block_k,
-    )
+    band = _band(window, q.shape[2], causal)
+    if band is None:
+        grads = _bwd_pallas(
+            q, k, v, _to_kernel(g), out, lse,
+            causal, resolved_scale, block_q, block_k,
+        )
+    else:
+        grads = _window_bwd_pallas(
+            q, k, v, _to_kernel(g), out, lse,
+            resolved_scale, block_q, block_k, band,
+        )
     return tuple(
         _from_kernel(x, g.shape[:3] + (x.shape[1],)) for x in grads
     )
@@ -829,9 +876,475 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, residuals, g):
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+# ---- a lower bound on the keys: the band schedule ---------------------
+#
+# With ``window`` < ``seq_len`` a query's keys are the ``window`` ending
+# at itself, so a query tile needs its own K/V block and the
+# ``ceil((window - 1) / tile)`` before it, whatever the row's length:
+# K and V are blocks that FOLLOW the query tile (the same array handed
+# to the call once a block, each with its own index map), never a
+# head's whole K and V and never a third grid axis. Inside a tile the
+# band is walked in ``piece``-key updates, each with exactly the query
+# blocks that see one of its keys; the block the diagonal crosses and
+# the block(s) the band's LOWER edge crosses are masked, every other
+# one is multiplied whole.
+
+# Query rows of a grid step (= keys of one K/V block), and the keys of
+# one update: whole pieces multiply (window + piece) / window times the
+# band's pairs (512: 2.0 x, 256: 1.5 x, 128: 1.25 x) and smaller ones
+# fill the MXU worse. Measured on a v5e at (1, 16, 16384, 128) bf16,
+# window 512, 16 chained calls, ms a call forward / forward + backward
+# (PERF.md, PR 54), tile, forward piece, backward piece: 1024 256 256
+# 1.055 / 2.744; 1024 128 128 1.048 / 2.710; 1024 512 256 1.083 /
+# 2.775; 1024 512 512 1.081 / 3.162; 512 256 256 1.331 / 3.202; 512
+# 128 128 1.320 / 3.336; 512 512 256 1.232 / 3.109; 256 128 128 2.210
+# / 5.243; 256 256 256 2.316 / 5.012; the same heads walked causally
+# whole 10.10 / 26.23. The tile decides (grid steps cost more than the
+# half K/V block a tile of 1024 fetches for nothing); between pieces of
+# 128 and 256 the time is the same to 1% and 256 is half the code.
+_WINDOW_TILE = 1024
+_WINDOW_PIECE = 256
+_WINDOW_PIECE_BWD = 256
+# The band kernels' names in a lowered program and in a device trace
+# (``%window_attn_fwd.<n>``): neither ``%attention`` nor ``flash_bwd``,
+# so the readers of the full layers' kernels keep reading those alone.
+WINDOW_FWD_NAME = "window_attn_fwd"
+WINDOW_BWD_NAME = "window_attn_bwd"
+
+
+class _BandSchedule(NamedTuple):
+    """How a windowed call is laid on the grid (forward and backward
+    alike, but for ``piece``)."""
+
+    tile: int  # query rows of a grid step = keys of one K/V block
+    piece: int  # keys of one update = queries of one (un)masked block
+    before: int  # K/V blocks before the tile's own that hold its keys
+
+
+def _band_schedule(
+    seq_len: int, window: int, block_q: int, block_k: int, piece_rows: int
+) -> _BandSchedule:
+    block_q, block_k = min(block_q, seq_len), min(block_k, seq_len)
+    assert seq_len % block_q == 0 and seq_len % block_k == 0, (
+        f"seq_len {seq_len} must divide into blocks "
+        f"({block_q}, {block_k})"
+    )
+    tile = _fuse(math.gcd(block_q, block_k), seq_len, _WINDOW_TILE)
+    piece = tile
+    if tile % _LANES == 0:
+        piece = _fuse(_LANES, tile, piece_rows)
+    return _BandSchedule(tile, piece, -(-(window - 1) // tile))
+
+
+def _band_stop(a: int, piece: int, window: int) -> int:
+    """Where the queries that see a key of the piece at ``a`` stop:
+    query block ``b`` (a whole piece) sees one iff ``a <= b`` (causal)
+    and ``b - (a + piece - 1) < window``."""
+    return (a + piece + window - 2) // piece * piece + piece
+
+
+def _band_updates(sched: _BandSchedule, window: int):
+    """The updates of ONE query tile, static: ``[(block, at, first,
+    stop)]`` — the ``piece`` keys at ``at`` of K/V block ``block`` (0
+    the tile's own, 1 the one before it, ...) against the tile's
+    queries ``first .. stop`` (whole ``piece`` blocks: those that see
+    at least one of these keys). In the order the forward's online
+    softmax needs: keys descending, so the first update a query is in
+    holds its own position, a visible key."""
+    tile, piece, before = sched
+    updates = []
+    for a in range(tile - piece, -before * tile - 1, -piece):
+        # ``a``: the piece's first key, from the tile's first query.
+        first, stop = max(a, 0), min(tile, _band_stop(a, piece, window))
+        if stop > first:
+            updates.append((-(a // tile), a % tile, first, stop))
+    return updates
+
+
+def _band_mask(s, a: int, first: int, piece: int, window: int):
+    """Mask transposed logits ``[piece keys from a, queries from
+    first]`` (positions from the tile's first query, static) to the
+    band ``0 <= query - key < window``, block of ``piece`` queries by
+    block: only a block an edge of the band crosses is touched."""
+    blocks = []
+    for b in range(first, first + s.shape[1], piece):
+        block = s[:, b - first:b - first + piece]
+        # query - key over the block: from b - (a + piece - 1) to
+        # b + piece - 1 - a.
+        above, below = b - (a + piece - 1) < 0, b + piece - 1 - a >= window
+        if above or below:
+            ahead = (
+                lax.broadcasted_iota(jnp.int32, block.shape, 1)
+                - lax.broadcasted_iota(jnp.int32, block.shape, 0)
+                + (b - a)
+            )
+            visible = ahead >= 0 if above else ahead < window
+            if above and below:
+                visible = (ahead >= 0) & (ahead < window)
+            block = jnp.where(visible, block, NEG_INF)
+        blocks.append(block)
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
+def _band_tiles(
+    sched: _BandSchedule, seq_len: int, window: int
+) -> tuple[int, int]:
+    """(``piece`` x ``piece``) blocks of logits one (batch, head)
+    computes under the band schedule (summed over its query tiles'
+    updates), and how many such blocks of the whole row hold a pair of
+    the band at all (counted a key piece at a time over the row, not
+    from the updates: the least a kernel of whole blocks can do)."""
+    tile, piece, _ = sched
+    updates = _band_updates(sched, window)
+    visited = sum(
+        (stop - first) // piece
+        for qi in range(seq_len // tile)
+        for block, _at, first, stop in updates
+        if qi >= block
+    )
+    in_band = sum(
+        min(seq_len, _band_stop(a, piece, window)) - a
+        for a in range(0, seq_len, piece)
+    ) // piece
+    return visited, in_band
+
+
+def _operand_precision(dtype):
+    """The MXU's passes for a band kernel's products: the default for
+    bfloat16 operands (exact in float32), ``HIGHEST`` for float32
+    operands — by default the chip multiplies those in ONE bfloat16
+    pass (measured, PERF.md PR 54: 3e-3 of the output), which "float32
+    in means float32 operands" does not mean."""
+    return lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def keys_in_window(seq_len: int, window: int) -> int:
+    """``sum_i min(i + 1, window)``: the pairs of one row's band."""
+    reach = min(window, seq_len)
+    return reach * (reach + 1) // 2 + (seq_len - reach) * reach
+
+
+def _window_fwd_kernel(
+    q_ref, *refs, scale: float, sched: _BandSchedule, window: int,
+    with_lse: bool,
+):
+    """One grid step of the band's forward: one (batch, head), one
+    query tile, its own K/V block and the ``before`` ahead of it.
+    Blocks ``[head_dim, positions]``, logits ``[keys, queries]``, the
+    online softmax of :func:`_fwd_kernel`; every update's key piece and
+    query range are static (``_band_updates``), and a block before the
+    row's start is skipped whole."""
+    tile, piece, before = sched
+    k_refs, v_refs = refs[:before + 1], refs[before + 1:2 * before + 2]
+    o_ref = refs[2 * before + 2]
+    lse_ref = refs[2 * before + 3] if with_lse else None
+    v_dim = o_ref.shape[1]
+    qi = pl.program_id(1)
+    precision = _operand_precision(q_ref.dtype)
+
+    def update(block, at, first, stop, carry):
+        m_prev, l_prev, acc = (x[:, first:stop] for x in carry)
+        q = q_ref[0, :, first:stop]
+        k = k_refs[block][0, :, at:at + piece]
+        v = v_refs[block][0, :, at:at + piece]
+        s = scale * lax.dot_general(
+            k, q, (((0,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32,
+        )  # [keys, queries]
+        s = _band_mask(s, at - block * tile, first, piece, window)
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_next)
+        rescale = jnp.exp(m_prev - m_next)
+        l_next = l_prev * rescale + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * rescale + jnp.dot(
+            v, p.astype(v.dtype), precision=precision,
+            preferred_element_type=jnp.float32,
+        )
+        def put(x, new):  # the tile's value with first .. stop replaced
+            parts = [x[:, :first]] * (first > 0) + [new]
+            parts += [x[:, stop:]] * (stop < tile)
+            return parts[0] if len(parts) == 1 else jnp.concatenate(
+                parts, axis=1
+            )
+
+        return tuple(map(put, carry, (m_next, l_next, acc)))
+
+    updates = _band_updates(sched, window)
+
+    def with_block(block):
+        def run(carry):
+            for at_block, at, first, stop in updates:
+                if at_block == block:
+                    carry = update(block, at, first, stop, carry)
+            return carry
+
+        return run
+
+    # (Inside a ``when``, as every block access of this file.)
+    @pl.when(qi >= 0)
+    def _tile():
+        carry = (
+            jnp.full((1, tile), NEG_INF, jnp.float32),
+            jnp.zeros((1, tile), jnp.float32),
+            jnp.zeros((v_dim, tile), jnp.float32),
+        )
+        carry = with_block(0)(carry)
+        for block in range(1, before + 1):
+            # The first ``block`` tiles have no such block before them.
+            carry = lax.cond(
+                qi >= block, with_block(block), lambda c: c, carry
+            )
+        m, l, acc = carry
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        if with_lse:
+            lse_ref[0] = m + jnp.log(l)
+
+
+def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
+    """q/k: [bh, d, seq], v: [bh, dv, seq] -> (out [bh, dv, seq], lse
+    [bh, 1, seq] or None), each query over its ``window`` keys."""
+    bh, head_dim, seq_len = q.shape
+    v_dim = v.shape[1]
+    sched = _band_schedule(seq_len, window, block_q, block_k, _WINDOW_PIECE)
+    tile, piece, before = sched
+    grid = (bh, seq_len // tile)
+    visited, in_band = _band_tiles(sched, seq_len, window)
+    trace.event(
+        "flash.schedule",
+        seq_len=seq_len,
+        head_dim=head_dim,
+        dtype=q.dtype.name,
+        causal=True,
+        layout=LAYOUT,
+        kv_resident=False,  # blocks that follow the query tile
+        tile=tile,
+        diag_tile=piece,
+        grid_steps=math.prod(grid),
+        k_tiles_visited=visited,
+        k_tiles_total=(seq_len // piece) ** 2,
+        window=window,
+        kv_blocks=before + 1,
+        tiles_visited=visited,
+        tiles_in_band=in_band,
+    )
+    # What the pair of kernels multiplies a row's queries with, against
+    # the band itself: static, so journalled here where the schedule is
+    # chosen (the backward's from its own schedule, which is a function
+    # of the same shapes).
+    bwd = _band_schedule(seq_len, window, block_q, block_k, _WINDOW_PIECE_BWD)
+    columns = (
+        visited * piece**2 + _band_tiles(bwd, seq_len, window)[0] * bwd.piece**2
+    )
+    trace.event(
+        "window.keys",
+        seq_len=seq_len,
+        window=window,
+        batch_heads=bh,
+        keys_visited=columns / 2,  # a query row set, mean of the two
+        keys_visited_fwd=visited * piece**2,
+        keys_visited_bwd=columns - visited * piece**2,
+        keys_in_window=keys_in_window(seq_len, window),
+    )
+
+    def block_spec(width, back):
+        return pl.BlockSpec(
+            (1, width, tile),
+            lambda b, qi: (b, 0, jnp.maximum(qi - back, 0)),
+        )
+
+    vma = jax.typeof(q).vma
+    out_specs = [block_spec(v_dim, 0)]
+    out_shape = [jax.ShapeDtypeStruct((bh, v_dim, seq_len), q.dtype, vma=vma)]
+    if with_lse:
+        out_specs.append(block_spec(1, 0))
+        out_shape.append(
+            jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32, vma=vma)
+        )
+    blocks = range(before + 1)
+    out, *lse = pl.pallas_call(
+        functools.partial(
+            _window_fwd_kernel, scale=scale, sched=sched, window=window,
+            with_lse=with_lse,
+        ),
+        grid=grid,
+        in_specs=[block_spec(head_dim, 0)]
+        + [block_spec(head_dim, back) for back in blocks]
+        + [block_spec(v_dim, back) for back in blocks],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_use_interpret(),
+        name=WINDOW_FWD_NAME,
+    )(q, *(k for _ in blocks), *(v for _ in blocks))
+    return out, (lse[0] if with_lse else None)
+
+
+def _window_bwd_kernel(
+    q_ref, do_ref, o_ref, lse_ref, *refs, scale: float,
+    sched: _BandSchedule, window: int, num_q: int,
+):
+    """One grid step of the band's backward: one (batch, head), one
+    query tile with its own K/V block and the ``before`` ahead of it —
+    the forward's walk, so dQ of the tile is whole when the step ends
+    (no partial a chunk). dK and dV of a K/V block gather over the
+    ``before + 1`` query tiles that see it, in a ring of as many
+    float32 slots; a block's slot is written out, and free again, in
+    the step of the last tile that sees it, and ``before`` steps past
+    the last query tile flush the ring. The identities and the layout
+    are :func:`_bwd_kernel`'s."""
+    tile, piece, before = sched
+    ring = before + 1
+    k_refs, v_refs = refs[:ring], refs[ring:2 * ring]
+    dq_ref, dk_ref, dv_ref = refs[2 * ring:2 * ring + 3]
+    delta_ref, dq_acc, dk_ring, dv_ring = refs[2 * ring + 3:]
+    qi = pl.program_id(1)
+    nt = (((1,), (1,)), ((), ()))  # a @ b.T
+    tn = (((0,), (0,)), ((), ()))  # a.T @ b
+    dot = functools.partial(
+        lax.dot_general, precision=_operand_precision(q_ref.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+    def update(block, at, first, stop, slot):
+        q, do = q_ref[0, :, first:stop], do_ref[0, :, first:stop]
+        keys = slice(at, at + piece)
+        k, v = k_refs[block][0, :, keys], v_refs[block][0, :, keys]
+        s = scale * dot(k, q, tn)  # [keys, queries]
+        s = _band_mask(s, at - block * tile, first, piece, window)
+        p = jnp.exp(s - lse_ref[0, :, first:stop])
+        dp = dot(v, do, tn)
+        ds = (p * (dp - delta_ref[:, first:stop])).astype(q.dtype)
+        dv_ring[slot, :, keys] += dot(do, p.astype(do.dtype), nt)
+        dk_ring[slot, :, keys] += dot(q, ds, nt)
+        dq_acc[:, first:stop] += dot(k, ds, (((1,), (0,)), ((), ())))
+
+    updates = _band_updates(sched, window)
+
+    def with_block(block):
+        def run():
+            slot = lax.rem(qi - block, ring)
+            for at_block, at, first, stop in updates:
+                if at_block == block:
+                    update(block, at, first, stop, slot)
+
+        return run
+
+    @pl.when(qi < num_q)
+    def _tile():
+        # The tile's own K/V block enters the ring: its slot was
+        # written out in the step before.
+        own = lax.rem(qi, ring)
+        dk_ring[own] = jnp.zeros(dk_ring.shape[1:], dk_ring.dtype)
+        dv_ring[own] = jnp.zeros(dv_ring.shape[1:], dv_ring.dtype)
+        d_o = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        delta_ref[...] = jnp.sum(d_o, axis=0, keepdims=True)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        with_block(0)()
+        for block in range(1, before + 1):
+            pl.when(qi >= block)(with_block(block))
+        dq_ref[0] = (scale * dq_acc[...]).astype(dq_ref.dtype)
+
+    @pl.when(qi >= before)
+    def _flush():  # K/V block qi - before: no later tile sees it
+        slot = lax.rem(qi - before, ring)
+        dk_ref[0] = (scale * dk_ring[slot]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_ring[slot].astype(dv_ref.dtype)
+
+
+def _window_bwd_pallas(q, k, v, do, out, lse, scale, block_q, block_k, window):
+    """As :func:`_bwd_pallas`, each query over its ``window`` keys."""
+    bh, head_dim, seq_len = q.shape
+    v_dim = v.shape[1]
+    sched = _band_schedule(
+        seq_len, window, block_q, block_k, _WINDOW_PIECE_BWD
+    )
+    tile, piece, before = sched
+    num_q = seq_len // tile
+    grid = (bh, num_q + before)
+    visited, in_band = _band_tiles(sched, seq_len, window)
+    trace.event(
+        "flash.schedule_bwd",
+        seq_len=seq_len,
+        head_dim=head_dim,
+        dtype=q.dtype.name,
+        causal=True,
+        layout=LAYOUT,
+        kv_resident=False,
+        tile=tile,
+        diag_tile=piece,
+        grid_steps=math.prod(grid),
+        kernels=1,
+        dkv_tiles_visited=visited,
+        dq_tiles_visited=visited,
+        k_tiles_total=(seq_len // piece) ** 2,
+        window=window,
+        kv_blocks=before + 1,
+        tiles_visited=visited,
+        tiles_in_band=in_band,
+    )
+
+    def block_spec(width, back):
+        # ``back`` blocks before the step's query tile, held inside the
+        # row: a step before the row's start or past its end repeats an
+        # index, and nothing is fetched for it.
+        return pl.BlockSpec(
+            (1, width, tile),
+            lambda b, qi: (b, 0, jnp.clip(qi - back, 0, num_q - 1)),
+        )
+
+    vma = jax.typeof(q).vma
+    blocks = range(before + 1)
+    return pl.pallas_call(
+        functools.partial(
+            _window_bwd_kernel, scale=scale, sched=sched, window=window,
+            num_q=num_q,
+        ),
+        grid=grid,
+        in_specs=[
+            block_spec(head_dim, 0), block_spec(v_dim, 0),
+            block_spec(v_dim, 0), block_spec(1, 0),
+        ]
+        + [block_spec(head_dim, back) for back in blocks]
+        + [block_spec(v_dim, back) for back in blocks],
+        out_specs=[
+            block_spec(head_dim, 0), block_spec(head_dim, before),
+            block_spec(v_dim, before),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((1, tile), jnp.float32),  # delta of the tile
+            pltpu.VMEM((head_dim, tile), jnp.float32),  # dQ of the tile
+            pltpu.VMEM((before + 1, head_dim, tile), jnp.float32),  # dK ring
+            pltpu.VMEM((before + 1, v_dim, tile), jnp.float32),  # dV ring
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BWD,
+        ),
+        interpret=_use_interpret(),
+        name=WINDOW_BWD_NAME,
+    )(q, do, out, lse, *(k for _ in blocks), *(v for _ in blocks))
+
+
+# Bytes of ONE operand a windowed call is given at most: the band
+# kernels write no partial, so what a call costs beyond its work is
+# what its caller builds for it (grouped-query attention repeats k and
+# v for the heads of a call, and autodiff holds their gradients as
+# wide): at 16 384 keys and head 128 in bfloat16, 16 heads.
+_WINDOW_CALL_BYTES = 64 * 2**20
+
+
 def heads_a_call(
     heads: int, seq_len: int, head_dim: int, v_dim: int, itemsize: int,
-    block_q: int = 128, block_k: int = 128,
+    block_q: int = 128, block_k: int = 128, window: int | None = None,
 ) -> int:
     """How many heads (a divisor of ``heads``) a caller that can
     split them should give one call. All of them while K and V of a
@@ -839,7 +1352,18 @@ def heads_a_call(
     key chunk (``_bwd_pallas``), ``chunks * 4 / itemsize`` times q for
     the heads it is given: a call's partials are held to the bytes of
     q itself, all heads. (Blocks that do not divide the row are cut
-    to ones that do: this asks about memory and refuses no shape.)"""
+    to ones that do: this asks about memory and refuses no shape.)
+    Under a ``window`` shorter than the row nothing is chunked and no
+    partial written: as many heads as keep one operand of the call
+    within ``_WINDOW_CALL_BYTES``."""
+    if window is not None and window < seq_len:
+        at_once = _WINDOW_CALL_BYTES // (
+            seq_len * max(head_dim, v_dim) * itemsize
+        )
+        return next(
+            n for n in range(min(heads, max(1, at_once)), 0, -1)
+            if heads % n == 0
+        )
     sched = _schedule(
         seq_len, head_dim, itemsize, math.gcd(block_q, seq_len),
         math.gcd(block_k, seq_len), diag_rows=_BWD_DIAG_ROWS, v_dim=v_dim,
@@ -855,12 +1379,13 @@ def make_flash_attention(
     causal: bool = True, block_q: int = 128, block_k: int = 128
 ):
     """Partial suitable for ``TransformerConfig.attention_fn``
-    (signature ``attn(q, k, v) -> out``); its ``heads_a_call`` is
-    :func:`heads_a_call` at these blocks."""
+    (signature ``attn(q, k, v) -> out``, and ``window=`` on a sliding
+    layer); its ``heads_a_call`` is :func:`heads_a_call` at these
+    blocks."""
 
-    def attn(q, k, v):
+    def attn(q, k, v, window=None):
         return flash_attention(
-            q, k, v, causal, None, block_q, block_k
+            q, k, v, causal, None, block_q, block_k, window
         )
 
     attn.heads_a_call = functools.partial(

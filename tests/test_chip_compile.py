@@ -167,6 +167,50 @@ def test_flash_kernels_at_unequal_widths_compile_for_v5e(
         assert [g.shape[-1] for g in grads] == [qk, qk, v]
 
 
+SLIDING_RUN = (1, 16, 16384, 128)  # laguna-xs.2: a run of a sliding layer's heads
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("what", ["fwd", "grad"])
+def test_band_kernels_compile_for_v5e(v5e, chip_compile, what, dtype):
+    """The sliding layers' call of the laguna-xs.2 cell (16 heads of
+    128 a call, 16 384 keys, a window of 512): the band schedule, K
+    and V as blocks that follow the query tile, forward and the one
+    backward kernel with its ring of dK / dV slots — under names of
+    their own, which neither ``%attention`` nor ``flash_bwd`` reads. In
+    float32 too: the cell's reference check runs the kernels so."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    arg = jax.ShapeDtypeStruct(SLIDING_RUN, jnp.dtype(dtype), sharding=one)
+
+    def attend(q, k, v):
+        return flash_mod.flash_attention(q, k, v, True, None, 128, 128, 512)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if what == "grad" else attend
+    text = jax.jit(fn).lower(arg, arg, arg).compile().as_text()
+
+    def named(name):
+        return re.findall(
+            rf"%[\w\-]*{name}[\w\-]*[.\d]* = .*{flash_mod.MOSAIC_CALL}", text
+        )
+
+    assert len(named(flash_mod.WINDOW_FWD_NAME)) == 1
+    assert len(named(flash_mod.WINDOW_BWD_NAME)) == (what == "grad")
+    assert text.count(flash_mod.MOSAIC_CALL) == (2 if what == "grad" else 1)
+    assert not named(flash_mod.BWD_KERNEL_NAME)
+    assert not re.findall(rf"%attention[.\d]* = .*{flash_mod.MOSAIC_CALL}", text)
+    # The schedule the cell's shape gets: what the chip run measured.
+    sched = flash_mod._band_schedule(16384, 512, 128, 128, 256)
+    assert sched == (flash_mod._WINDOW_TILE, flash_mod._WINDOW_PIECE, 1)
+    visited, in_band = flash_mod._band_tiles(sched, 16384, 512)
+    assert visited == in_band
+    assert visited * sched.piece**2 / flash_mod.keys_in_window(
+        16384, 512
+    ) < 3.0
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("what", ["fwd", "grad"])
 def test_kda_kernels_compile_for_v5e(

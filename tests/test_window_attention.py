@@ -1,0 +1,240 @@
+"""A lower bound on the keys in both flash kernels (PR 54): the band
+schedule of ``flash_attention(..., window=)`` in interpret mode against
+a masked plain softmax, forward and gradients, across windows shorter
+than, equal to and longer than a tile, windows that are no multiple of
+the update's piece, windows that reach the row's start (today's
+program), groups of 6 and 8 query heads a key/value head, float32 and
+bfloat16; and the schedule's static counts against brute force."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from adaptdl_tpu import trace
+
+# ``import adaptdl_tpu.ops.flash_attention as m`` yields the FUNCTION
+# the package re-exports under the module's name.
+fm = importlib.import_module("adaptdl_tpu.ops.flash_attention")
+
+
+def _plain(q, k, v, window):
+    """Masked softmax in float32: ``j <= i`` and ``i - j < window``."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    i = jnp.arange(q.shape[2])[:, None]
+    j = jnp.arange(q.shape[2])[None, :]
+    seen = j <= i
+    if window is not None:
+        seen &= i - j < window
+    s = jnp.where(seen, s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+def _operands(shape, dtype, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return tuple(jax.random.normal(k, shape, dtype) for k in keys)
+
+
+def _both(fn, q, k, v, g):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out, *vjp(g.astype(out.dtype)))
+
+
+@pytest.fixture
+def tiles(monkeypatch):
+    """Set the band schedule's tile and piece (constants of the
+    module, chosen on the chip for rows of 16 384) to sizes a CPU test
+    can walk several of."""
+    def set_(tile, piece):
+        monkeypatch.setattr(fm, "_WINDOW_TILE", tile)
+        monkeypatch.setattr(fm, "_WINDOW_PIECE", piece)
+        monkeypatch.setattr(fm, "_WINDOW_PIECE_BWD", piece)
+
+    return set_
+
+
+# (tile, piece, seq, block): tiles of 32 are walked whole (no lane-
+# aligned piece divides them); tiles of 256 in pieces of 128.
+SMALL = (32, 32, 128, 16)
+PIECES = (256, 128, 1024, 128)
+
+
+@pytest.mark.parametrize(
+    "shape, window",
+    [
+        (SMALL, 5),  # shorter than a tile
+        (SMALL, 32),  # a tile
+        (SMALL, 33),  # a tile and one key: three blocks a query tile
+        (SMALL, 70),  # longer than two tiles
+        (SMALL, 127),  # all but the first key of the row's last query
+        (PIECES, 100),  # shorter than a piece
+        (PIECES, 128),  # a piece
+        (PIECES, 200),  # no multiple of the piece: two edge blocks
+        (PIECES, 256),  # a tile
+        (PIECES, 300),  # longer than a tile, no multiple of the piece
+    ],
+)
+def test_band_kernels_equal_the_masked_softmax(tiles, shape, window):
+    tile, piece, seq, block = shape
+    tiles(tile, piece)
+    q, k, v, g = _operands((1, 2, seq, 16), jnp.float32)
+    got = _both(
+        lambda q, k, v: fm.flash_attention(
+            q, k, v, True, None, block, block, window
+        ),
+        q, k, v, g,
+    )
+    want = _both(lambda q, k, v: _plain(q, k, v, window), q, k, v, g)
+    sched = fm._band_schedule(seq, window, block, block, piece)
+    assert sched.tile == tile and sched.before == -(-(window - 1) // tile)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 128, 500])
+def test_a_window_that_reaches_the_rows_start_is_todays_program(window):
+    """``window >= seq_len`` and None take the schedule of before: the
+    same lowered text, and no band kernel in it."""
+    q, k, v, _ = _operands((1, 2, 128, 16), jnp.float32)
+
+    def text(*window_arg):
+        fn = lambda q, k, v: fm.flash_attention(  # noqa: E731
+            q, k, v, True, None, 32, 32, *window_arg
+        ).sum()
+        return jax.jit(jax.grad(fn, (0, 1, 2))).lower(q, k, v).as_text()
+
+    before = len(trace.snapshot_spans())
+    assert text(window) == text()
+    names = [r["name"] for r in trace.snapshot_spans()[before:]]
+    assert "window.keys" not in names and "flash.schedule" in names
+
+
+@pytest.mark.parametrize("group", [6, 8])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_groups_of_query_heads_on_repeated_kv_heads(tiles, group, dtype):
+    """Laguna's groups (6 on a full layer, 8 on a sliding one): each
+    kv head repeated for its group on the way in, as the model hands
+    them over; dK and dV summed over the group by autodiff."""
+    tiles(32, 32)
+    dtype = jnp.dtype(dtype)
+    seq, window = 96, 40
+    keys = jax.random.split(jax.random.key(group), 4)
+    q = jax.random.normal(keys[0], (1, 2 * group, seq, 16), dtype)
+    k, v = (jax.random.normal(x, (1, 2, seq, 16), dtype) for x in keys[1:3])
+    g = jax.random.normal(keys[3], q.shape, dtype)
+
+    def attend(fn):
+        def run(q, k, v):
+            return fn(
+                q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+            )
+
+        return run
+
+    got = _both(
+        attend(lambda q, k, v: fm.flash_attention(
+            q, k, v, True, None, 16, 16, window
+        )),
+        q, k, v, g,
+    )
+    want = _both(attend(lambda q, k, v: _plain(q, k, v, window)), q, k, v, g)
+    assert got[0].dtype == dtype and got[2].shape == k.shape
+    tol = 2e-5 if dtype == jnp.float32 else 4e-2
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b.astype(jnp.float32), rtol=tol, atol=tol
+        )
+
+
+def _brute(seq, piece, window):
+    i = np.arange(seq)[:, None]
+    j = np.arange(seq)[None, :]
+    seen = (j <= i) & (i - j < window)
+    blocks = seen.reshape(seq // piece, piece, seq // piece, piece)
+    return int(blocks.any(axis=(1, 3)).sum()), int(seen.sum())
+
+
+@pytest.mark.parametrize(
+    "tile, piece, seq, window",
+    [
+        (32, 32, 128, 5), (32, 32, 128, 32), (32, 32, 128, 33),
+        (32, 32, 128, 100), (256, 128, 1024, 100), (256, 128, 1024, 128),
+        (256, 128, 1024, 200), (256, 128, 1024, 513),
+        (512, 256, 4096, 512), (512, 128, 4096, 512),
+        (512, 512, 4096, 512), (1024, 256, 4096, 512),
+    ],
+)
+def test_tiles_visited_against_a_count_by_brute_force(
+    tile, piece, seq, window
+):
+    """The blocks the band's walk computes are exactly those that hold
+    a pair of the band, and the pairs are ``sum_i min(i + 1, W)``."""
+    sched = fm._BandSchedule(tile, piece, -(-(window - 1) // tile))
+    visited, in_band = fm._band_tiles(sched, seq, window)
+    blocks, pairs = _brute(seq, piece, window)
+    assert visited == in_band == blocks
+    assert fm.keys_in_window(seq, window) == pairs
+    # Every update's queries see at least one of its keys, every
+    # visible pair is in exactly one update: the walk is the band.
+    covered = np.zeros((tile, (sched.before + 1) * tile), bool)
+    for block, at, first, stop in fm._band_updates(sched, window):
+        keys = slice((sched.before - block) * tile + at,
+                     (sched.before - block) * tile + at + piece)
+        assert not covered[first:stop, keys].any()
+        covered[first:stop, keys] = True
+    i = np.arange(tile)[:, None]
+    j = np.arange(-sched.before * tile, tile)[None, :]
+    assert covered[(j <= i) & (i - j < window)].all()
+
+
+def test_the_schedule_is_journalled(tiles):
+    tiles(32, 32)
+    q, k, v, g = _operands((2, 3, 128, 16), jnp.float32)
+    before = len(trace.snapshot_spans())
+    _both(
+        lambda q, k, v: fm.flash_attention(q, k, v, True, None, 16, 16, 40),
+        q, k, v, g,
+    )
+    events = {}
+    for rec in trace.snapshot_spans()[before:]:
+        events.setdefault(rec["name"], rec["attrs"])
+    blocks, pairs = _brute(128, 32, 40)
+    for name in ("flash.schedule", "flash.schedule_bwd"):
+        attrs = events[name]
+        assert (attrs["window"], attrs["tile"], attrs["diag_tile"]) == (
+            40, 32, 32
+        )
+        assert attrs["tiles_visited"] == attrs["tiles_in_band"] == blocks
+        assert attrs["kv_blocks"] == 3 and not attrs["kv_resident"]
+    assert events["flash.schedule"]["grid_steps"] == 6 * 4
+    assert events["flash.schedule_bwd"]["grid_steps"] == 6 * (4 + 2)
+    keys = events["window.keys"]
+    assert keys["keys_in_window"] == pairs and keys["batch_heads"] == 6
+    assert keys["keys_visited"] == blocks * 32 * 32
+    assert keys["keys_visited_fwd"] == keys["keys_visited_bwd"]
+
+
+def test_heads_a_call_under_a_window():
+    """No chunk and no partial under a window: as many heads as keep
+    one operand of the call within 64 MiB (16 at 16 384 keys of 128 in
+    bfloat16), a divisor of the layer's heads."""
+    assert fm.heads_a_call(64, 16384, 128, 128, 2, window=512) == 16
+    assert fm.heads_a_call(48, 16384, 128, 128, 2, window=512) == 16
+    assert fm.heads_a_call(6, 16384, 128, 128, 2, window=512) == 6
+    assert fm.heads_a_call(64, 4096, 128, 128, 2, window=512) == 64
+    # A window that reaches the row's start is the full schedule's
+    # answer: the K-blocked backward's partials.
+    assert fm.heads_a_call(48, 16384, 128, 128, 2, window=16384) == (
+        fm.heads_a_call(48, 16384, 128, 128, 2)
+    ) == 12
+    made = fm.make_flash_attention(block_q=16, block_k=16)
+    assert made.heads_a_call(64, 16384, 128, 128, 2, window=512) == 16
+
+
+def test_a_window_is_causal_only():
+    q, k, v, _ = _operands((1, 1, 64, 16), jnp.float32)
+    with pytest.raises(ValueError, match="CAUSAL"):
+        fm.flash_attention(q, k, v, False, None, 16, 16, 8)
