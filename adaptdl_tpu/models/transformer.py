@@ -641,11 +641,26 @@ def _heads_a_call(
     )
 
 
+def _takes_kv_heads(attn) -> bool:
+    """Whether ``attn`` takes k and v with FEWER heads than q, each kv
+    head serving ``heads // kv_heads`` query heads in
+    ``jnp.repeat``'s order: the flash kernels do, and say so of
+    themselves (``ops.flash_attention``: ``flash_attention`` and
+    ``make_flash_attention``'s result carry ``takes_kv_heads``). Read
+    behind any ``functools.partial``, which says nothing of itself
+    but its keywords; any other function, and plain attention
+    (``attn`` None), is handed equal head counts."""
+    while attn is not None and not hasattr(attn, "takes_kv_heads"):
+        attn = getattr(attn, "func", None)
+    return bool(getattr(attn, "takes_kv_heads", False))
+
+
 def _attend_in_runs(attn, q, k, v, run, kv_of=None):
     """``attn`` on ``[b, s, h, d]`` operands, ``run`` heads a call, a
     run's result let go before the next run's: ``[b, h, s, d_v]``.
-    ``kv_of(t, at)``: the heads ``at .. at + run`` of k or v where they
-    are not a slice of it (kv heads shared by several query heads)."""
+    ``kv_of(t, at)``: what the heads ``at .. at + run`` are handed of k
+    or v where that is not the same slice of it (kv heads shared by
+    several query heads)."""
     def own(t, at):
         return t[:, :, at:at + run]
 
@@ -663,10 +678,14 @@ class GroupedQueryAttention(nn.Module):
     """Causal attention with fewer key/value heads than query heads,
     optional per-head RMSNorm on q and k, and the config's rotary
     base (over the first ``rotary_dims`` lanes where that is set).
-    ``attention_fn`` keeps its ``[b, h, s, d]`` contract with
-    equal head counts: each kv head is repeated ``group`` times on the
-    way in, and autodiff sums dK / dV over the group on the way out
-    (a kv index inside the flash kernels is later work). With
+    The flash kernels (``takes_kv_heads``) are handed a call's query
+    heads and THEIR kv heads, unrepeated: the kernels index k and v by
+    kv head and sum dK / dV over a group's query heads themselves.
+    Any other ``attention_fn``, and plain attention, keeps its
+    ``[b, h, s, d]`` contract with equal head counts: each kv head is
+    repeated ``group`` times on the way in, and autodiff sums dK / dV
+    over the group on the way out (``kv_repeat`` in the journal: how
+    many copies of a kv head a call is made). With
     ``attention_gate`` the ``q`` projection is twice as wide, a head's
     second half a gate, and ``out`` takes ``o * sigmoid(gate)``; the
     heads then go in runs of as many as the flash kernels want at
@@ -727,6 +746,7 @@ class GroupedQueryAttention(nn.Module):
             k = rope(k, positions, own.rope_theta, own.rotary_dims, **table)
         attn = cfg.attention_fn
         run = heads
+        grouped = _takes_kv_heads(attn)
         gated = cfg.attention_gate or cfg.attention_head_gate
         if gated or by_kind:
             run = _heads_a_call(
@@ -750,6 +770,7 @@ class GroupedQueryAttention(nn.Module):
                 else "sigmoid" if cfg.attention_gate else "none",
                 **of_kind,
                 heads_a_call=run,
+                kv_repeat=1 if grouped else min(run, group),
                 seq_len=x.shape[1],
                 dtype=jnp.dtype(cfg.dtype).name,
                 attention="attention_fn" if attn is not None
@@ -762,23 +783,25 @@ class GroupedQueryAttention(nn.Module):
         if own.window is not None:
             attn = partial(attn, window=own.window)
         if run < heads:
-            # A run of heads a call, and each run's kv heads repeated
-            # for that run alone: at 16 heads of 256 on 2 kv heads and
-            # 16 384 keys, k and v repeated for all heads at once are
-            # 128 MiB each, and as much again their gradients.
+            # A run of heads a call with the kv heads that serve it:
+            # the one the run is part of (16 heads of 256 on 2 kv
+            # heads go two a call: autodiff adds the runs' dK / dV of
+            # a kv head), or its whole groups'. Repeated for the run's
+            # heads only for a function that wants equal counts.
             assert run % group == 0 or group % run == 0, (run, group)
 
             def kv_of(t, at):  # the run's heads of k or v
-                if run <= group:
-                    one = t[:, :, at // group:at // group + 1]
-                    return jnp.repeat(one, run, axis=2)
-                some = t[:, :, at // group:(at + run) // group]
-                return jnp.repeat(some, group, axis=2)
+                first = at // group
+                some = t[:, :, first:max(first + 1, (at + run) // group)]
+                if grouped:
+                    return some
+                return jnp.repeat(some, min(run, group), axis=2)
 
             out = _attend_in_runs(attn, q, k, v, run, kv_of)
         else:
-            k = jnp.repeat(k, group, axis=2)
-            v = jnp.repeat(v, group, axis=2)
+            if not grouped:
+                k = jnp.repeat(k, group, axis=2)
+                v = jnp.repeat(v, group, axis=2)
             out = attn(
                 jnp.swapaxes(q, 1, 2),
                 jnp.swapaxes(k, 1, 2),

@@ -16,7 +16,9 @@ says what was chosen):
 
 - **Positions along lanes.** Both kernels index ``[batch * heads,
   head_dim, seq]``: a (batch, head) is ``head_dim`` rows of whole
-  sequences. That is how XLA itself lays out what the QKV projection
+  sequences; K and V are ``[batch * kv_heads, head_dim, seq]``, ONCE
+  a kv head ("fewer kv heads than query heads" below). That is how
+  XLA itself lays out what the QKV projection
   and rotary write and what the out projection and their gradients
   read on the chip (``[batch, seq, heads, head_dim]`` with ``seq``
   minor-most: a minor dimension of 64 would leave half of every lane
@@ -77,10 +79,12 @@ rule names ``out`` and ``lse`` (``SAVED_OUT``, ``SAVED_LSE``) and a
 remat'd block keeps them by name, so under remat the kernel's output
 is not computed a second time (``models.transformer.block_remat``).
 
-- **One grid step per (batch, head), key chunk and query tile.** The
+- **One grid step per (batch, kv head), key chunk, query head of the
+  kv head's group and query tile.** The
   chunk's K and V (all of them while they fit the budget above) stay
-  in VMEM while the query tiles pass; its dK and dV accumulate in
-  float32 scratch over the query tiles at or after it and the tile's
+  in VMEM while the group's query tiles pass; its dK and dV accumulate
+  in float32 scratch over the query tiles at or after it, of every
+  query head of the group, and the tile's
   dQ over the chunk's keys at or before it, so P and dS are computed
   once for all three gradients: five matmuls a block of logits, where
   the usual pair of kernels (dK/dV, then dQ) pays seven.
@@ -103,6 +107,40 @@ is not computed a second time (``models.transformer.block_remat``).
   before a chunk fetches and computes nothing, and dQ leaves as one
   float32 partial per chunk, summed outside. Correct and 7 x the scan
   it replaced at 16k x 128 (PERF.md, PR 26); tuned in no cell.
+
+Fewer kv heads than query heads (grouped-query attention): ``k`` and
+``v`` may have ``kv_heads`` heads with ``heads % kv_heads == 0``, and
+``group = heads // kv_heads`` is read from the shapes (``_kv_group``;
+``flash.schedule*`` carry ``kv_group`` and ``kv_heads``). Nothing is
+repeated in HBM, on the way in or out:
+
+- **Forward**: q, the output and the log-sum-exp stay ``[batch *
+  heads, ...]``; the K / V index maps take ``row // group``
+  (``_kv_row``), so while K / V are resident the heads of a group
+  repeat one block's index in turn and it is fetched once. On the band
+  the group's heads are the INNERMOST grid axis (``_band_grid``:
+  ``(batch * kv_heads, query tile, group)``), under one tile's K / V
+  blocks.
+- **Backward**: one more ``arbitrary`` grid axis over the group's query
+  heads INSIDE a kv head — ``(batch * kv_heads, chunk, group, query
+  tile)`` on the chunked schedule, so the chunk's K, V, their turned
+  copies and the float32 dK / dV scratch are set up once a kv head and
+  chunk and written out after the group's last query tile; ``(batch *
+  kv_heads, query tile [+ before], group)`` on the band, so a ring
+  slot gathers every query head of a K / V block before it is flushed.
+  dQ (and its float32 partial a chunk) stays a query head's. dK and dV
+  leave ``[batch * kv_heads, head_dim, seq]``, summed over the group in
+  float32 and rounded ONCE.
+- **Equal head counts are the degenerate case, decided in Python**:
+  ``group == 1`` builds the grids, index maps and kernels of before
+  there were groups — the same lowered program
+  (``tests/flash_digests.py``; ``tools/lowered_step_diff.py`` at real
+  sizes).
+- **The caller** recognises these functions by ``takes_kv_heads`` (set
+  on :func:`flash_attention` and on :func:`make_flash_attention`'s
+  result; ``models.transformer.GroupedQueryAttention`` looks behind
+  any ``functools.partial``) and repeats k and v only for a function
+  without it.
 
 A lower bound on the keys (``window``, sliding-window attention): a
 query sees itself and the ``window - 1`` keys before it, so a query
@@ -202,6 +240,23 @@ def _use_interpret() -> bool:
     """Interpret mode (Python-speed reference semantics) off the TPU,
     so the CPU tests can run the kernel; the compiled kernel on it."""
     return jax.default_backend() != "tpu"
+
+
+def _kv_group(q, k) -> int:
+    """Query heads a kv head serves in a call, from the kernels' own
+    operands (``[batch * heads, ...]`` against ``[batch * kv_heads,
+    ...]``): query row ``r`` reads kv row ``r // group``, the order
+    ``jnp.repeat(k, group, axis=heads)`` gives."""
+    group, rest = divmod(q.shape[0], k.shape[0])
+    assert group >= 1 and rest == 0, (q.shape, k.shape)
+    return group
+
+
+def _kv_row(row, group: int):
+    """The kv row of query row ``row`` in an index map. Decided in
+    Python: with equal head counts it is ``row`` itself and the
+    program is the one of before there were groups."""
+    return row if group == 1 else lax.div(row, group)
 
 
 class _Schedule(NamedTuple):
@@ -404,10 +459,11 @@ def _fwd_kernel(
 
 
 def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
-    """q/k: [bh, d, seq], v: [bh, dv, seq] -> (out [bh, dv, seq], lse
-    [bh, 1, seq] or None)."""
+    """q: [bh, d, seq], k: [bh / group, d, seq], v: [bh / group, dv,
+    seq] -> (out [bh, dv, seq], lse [bh, 1, seq] or None)."""
     bh, head_dim, seq_len = q.shape
     v_dim = v.shape[1]
+    group = _kv_group(q, k)
     sched = _schedule(
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
         v_dim=v_dim,
@@ -428,6 +484,8 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         grid_steps=math.prod(grid),
         k_tiles_visited=_tiles_visited(sched, seq_len, causal),
         k_tiles_total=(seq_len // diag) ** 2,
+        kv_group=group,
+        kv_heads=bh // group,
     )
     kernel = functools.partial(
         _fwd_kernel,
@@ -444,7 +502,9 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
             # the diagonal is in: nothing is fetched for it, and the
             # kernel does nothing in it.
             ci = jnp.minimum(ci, qi * tile // chunk_k)
-        return (b, 0, ci)
+        # The head's kv head: while K/V are resident, the heads of a
+        # group repeat one index in turn and it is fetched once.
+        return (_kv_row(b, group), 0, ci)
 
     q_spec = pl.BlockSpec(
         (1, head_dim, tile), lambda b, qi, ci: (b, 0, qi)
@@ -508,7 +568,14 @@ def flash_attention(
 
     Args:
       q, k, v: ``[batch, heads, seq, head_dim]``; v's heads may have
-        another width than q's and k's, and the result has v's.
+        another width than q's and k's, and the result has v's. k and
+        v may have FEWER heads than q, ``kv_heads`` with ``heads %
+        kv_heads == 0`` (grouped-query attention): query head ``h``
+        sees kv head ``h // (heads // kv_heads)``, what
+        ``jnp.repeat(k, heads // kv_heads, axis=1)`` would hand it,
+        and nothing is repeated: the kernels index K and V by kv
+        head, and dK / dV come back ``kv_heads`` wide, summed over a
+        group's query heads in float32 and rounded once.
       causal: apply the causal mask.
       scale: logit scale; default ``head_dim ** -0.5``.
       block_q / block_k: the caller's tile granularity (must divide
@@ -529,7 +596,7 @@ def flash_attention(
     *_, out, _ = _flash_fwd(
         q, k, v, causal, scale, block_q, block_k, False, window
     )
-    return _from_kernel(out, v.shape)
+    return _from_kernel(out, q.shape[:3] + v.shape[3:])
 
 
 def _to_kernel(x):
@@ -567,6 +634,12 @@ def _flash_fwd(
 ):
     """-> (q, k, v, out, lse) as the kernels index them."""
     head_dim = q.shape[-1]
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads on {k.shape[1]} key and "
+            f"{v.shape[1]} value heads: kv heads must divide the query "
+            "heads"
+        )
     resolved_scale = (
         head_dim**-0.5 if scale is None else float(scale)
     )
@@ -599,7 +672,7 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     out = checkpoint_name(out, SAVED_OUT)
     lse = checkpoint_name(lse, SAVED_LSE)
     residuals = (*operands, out, lse)
-    return _from_kernel(out, v.shape), residuals
+    return _from_kernel(out, q.shape[:3] + v.shape[3:]), residuals
 
 
 def _bwd_kernel(
@@ -621,12 +694,15 @@ def _bwd_kernel(
     scale: float,
     diag: int,
     num_chunks: int,
+    group: int,
 ):
-    """One grid step of the backward: one (batch, head), one chunk of
-    keys (all of them when K/V are resident), one query tile. The
+    """One grid step of the backward: one (batch, kv head), one chunk
+    of keys (all of them when K/V are resident), one of the kv head's
+    ``group`` query heads, one query tile. The
     forward's schedule with the loops swapped: the chunk's K and V stay
-    while the query tiles pass, its dK and dV accumulate in float32
-    scratch over the query tiles at or after it, and the query tile's
+    while the group's query tiles pass, its dK and dV accumulate in
+    float32 scratch over the query tiles at or after it of EVERY query
+    head of the group, and the query tile's
     dQ over the chunk's keys at or before it. Blocks are ``[head_dim,
     positions]`` and logits ``[keys, queries]``, so ``lse`` and
     ``delta`` broadcast along sublanes, dV = dO P^T and dK = Q dS^T
@@ -637,7 +713,20 @@ def _bwd_kernel(
     _, head_dim, tile = q_ref.shape  # v and dO may be wider or narrower
     chunk_k = k_ref.shape[2]
     chunk_tiles, per_tile = chunk_k // tile, tile // diag
-    ci, qi = pl.program_id(1), pl.program_id(2)
+    # The grid: (batch * kv heads, chunk, [query head of the group,]
+    # query tile); with equal head counts there is no third axis.
+    tiles = 2 if group == 1 else 3
+    ci, qi = pl.program_id(1), pl.program_id(tiles)
+
+    def at_group(tile_index, head_index):
+        """Whether the step is the kv head's and chunk's first (0, 0)
+        or last: dK / dV are set up in the one and written out in the
+        other."""
+        here = qi == tile_index
+        if group > 1:
+            here &= pl.program_id(2) == head_index
+        return here
+
     first_tile = ci * chunk_tiles  # of this chunk, among all key tiles
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
     lead = (0,) * (len(dq_ref.shape) - 2)  # [chunk,] batch-head
@@ -689,7 +778,7 @@ def _bwd_kernel(
 
     # (Every block access sits inside a ``when``, as in the forward:
     # interpret mode under a shard_map needs it.)
-    @pl.when(qi == 0)
+    @pl.when(at_group(0, 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -722,24 +811,39 @@ def _bwd_kernel(
         def _no_keys():
             dq_ref[lead] = jnp.zeros((head_dim, tile), dq_ref.dtype)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(at_group(pl.num_programs(tiles) - 1, group - 1))
     def _finish():
         dk_ref[0] = (scale * dk_acc[...]).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
-    """q/k: [bh, d, seq]; v/do/out: [bh, dv, seq]; lse: [bh, 1, seq]
-    float32 -> (dq, dk, dv), each as its primal."""
+    """q: [bh, d, seq]; k: [bh / group, d, seq]; v: [bh / group, dv,
+    seq]; do/out: [bh, dv, seq]; lse: [bh, 1, seq] float32 -> (dq, dk,
+    dv), each as its primal."""
     bh, head_dim, seq_len = q.shape
     v_dim = v.shape[1]
+    group = _kv_group(q, k)
     sched = _schedule(
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
         diag_rows=_BWD_DIAG_ROWS, v_dim=v_dim,
     )
     tile, diag, chunk_k = sched
     num_chunks = seq_len // chunk_k
-    grid = (bh, num_chunks, seq_len // tile)
+    # ``at``: a grid step's (query row, kv row, chunk, query tile).
+    # The group's query heads pass INSIDE a kv head and chunk, so the
+    # chunk's K, V, dK and dV stay while all of them gather.
+    if group == 1:
+        grid = (bh, num_chunks, seq_len // tile)
+
+        def at(b, ci, qi):
+            return b, b, ci, qi
+    else:
+        grid = (bh // group, num_chunks, group, seq_len // tile)
+
+        def at(b, ci, gi, qi):
+            return b * group + gi, b, ci, qi
+
     visited = _tiles_visited(sched, seq_len, causal)
     trace.event(
         "flash.schedule_bwd",
@@ -756,43 +860,46 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
         dkv_tiles_visited=visited,
         dq_tiles_visited=visited,
         k_tiles_total=(seq_len // diag) ** 2,
+        kv_group=group,
+        kv_heads=bh // group,
     )
 
-    def q_index(b, ci, qi):
+    def q_index(*step):
+        row, _, ci, qi = at(*step)
         if causal:
             # A query tile before the chunk repeats the index of the
             # first that sees it: nothing is fetched for it.
             qi = jnp.maximum(qi, ci * chunk_k // tile)
-        return qi
+        return (row, 0, qi)
 
-    q_spec = pl.BlockSpec(
-        (1, head_dim, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
-    )
-    lse_spec = pl.BlockSpec(
-        (1, 1, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
-    )
-    kv_spec = pl.BlockSpec(
-        (1, head_dim, chunk_k), lambda b, ci, qi: (b, 0, ci)
-    )
-    v_spec = pl.BlockSpec(
-        (1, v_dim, chunk_k), lambda b, ci, qi: (b, 0, ci)
-    )
-    do_spec = pl.BlockSpec(
-        (1, v_dim, tile), lambda b, ci, qi: (b, 0, q_index(b, ci, qi))
-    )
+    def kv_index(*step):
+        _, kv_row, ci, _ = at(*step)
+        return (kv_row, 0, ci)
+
+    q_spec = pl.BlockSpec((1, head_dim, tile), q_index)
+    lse_spec = pl.BlockSpec((1, 1, tile), q_index)
+    kv_spec = pl.BlockSpec((1, head_dim, chunk_k), kv_index)
+    v_spec = pl.BlockSpec((1, v_dim, chunk_k), kv_index)
+    do_spec = pl.BlockSpec((1, v_dim, tile), q_index)
     vma = jax.typeof(q).vma
     # dQ of a query tile is summed over the key chunks: the gradient
     # itself while K/V are resident; beyond, one float32 partial per
-    # chunk, added up outside.
+    # chunk, added up outside. A query head's either way.
     if num_chunks == 1:
-        dq_spec = pl.BlockSpec(
-            (1, head_dim, tile), lambda b, ci, qi: (b, 0, qi)
-        )
+
+        def dq_index(*step):
+            row, _, _, qi = at(*step)
+            return (row, 0, qi)
+
+        dq_spec = pl.BlockSpec((1, head_dim, tile), dq_index)
         dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma)
     else:
-        dq_spec = pl.BlockSpec(
-            (1, 1, head_dim, tile), lambda b, ci, qi: (ci, b, 0, qi)
-        )
+
+        def dq_index(*step):
+            row, _, ci, qi = at(*step)
+            return (ci, row, 0, qi)
+
+        dq_spec = pl.BlockSpec((1, 1, head_dim, tile), dq_index)
         dq_shape = jax.ShapeDtypeStruct(
             (num_chunks, *q.shape), jnp.float32, vma=vma
         )
@@ -813,6 +920,7 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
             scale=scale,
             diag=diag,
             num_chunks=num_chunks,
+            group=group,
         ),
         grid=grid,
         in_specs=[q_spec, kv_spec, v_spec, do_spec, do_spec, lse_spec],
@@ -830,7 +938,8 @@ def _bwd_pallas(q, k, v, do, out, lse, causal, scale, block_q, block_k):
         ]
         + turned,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",)
+            + ("arbitrary",) * (len(grid) - 1),
             vmem_limit_bytes=_VMEM_LIMIT_BWD,
         ),
         interpret=_use_interpret(),
@@ -868,12 +977,19 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, window, residuals, g):
             q, k, v, _to_kernel(g), out, lse,
             resolved_scale, block_q, block_k, band,
         )
+    batch, _, seq_len, _ = g.shape
     return tuple(
-        _from_kernel(x, g.shape[:3] + (x.shape[1],)) for x in grads
+        _from_kernel(x, (batch, x.shape[0] // batch, seq_len, x.shape[1]))
+        for x in grads
     )
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+# What a caller with fewer kv heads than query heads looks for on the
+# function it was handed, behind any ``functools.partial``
+# (``models.transformer._takes_kv_heads``): k and v go in ``kv_heads``
+# wide. On :func:`make_flash_attention`'s result too.
+flash_attention.takes_kv_heads = True
 
 
 # ---- a lower bound on the keys: the band schedule ---------------------
@@ -1018,6 +1134,29 @@ def _operand_precision(dtype):
     return lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
+def _band_grid(bh: int, group: int, tiles: int, tail: int = 0):
+    """The band kernels' grid over ``bh`` query rows, ``group`` of
+    them a kv row, and ``tiles`` query tiles (``tail`` more steps, the
+    backward's that flush its ring): ``(grid, at)``, ``at`` a grid
+    step's (query row, kv row, tile). The group's query heads are the
+    INNERMOST axis, so the K/V blocks of a tile are fetched once for
+    all of them, and the
+    backward's ring gathers every query head's dK / dV of a block
+    before it is written. With equal head counts there is no such
+    axis: the program of before there were groups."""
+    if group == 1:
+        return (bh, tiles + tail), lambda b, qi: (b, b, qi)
+
+    def at(b, qi, gi):
+        if tail:
+            # A step past the last tile fetches and gathers nothing:
+            # it repeats the indices of the step before it.
+            gi = jnp.where(qi < tiles, gi, group - 1)
+        return b * group + gi, b, qi
+
+    return (bh // group, tiles + tail, group), at
+
+
 def keys_in_window(seq_len: int, window: int) -> int:
     """``sum_i min(i + 1, window)``: the pairs of one row's band."""
     reach = min(window, seq_len)
@@ -1029,7 +1168,9 @@ def _window_fwd_kernel(
     with_lse: bool,
 ):
     """One grid step of the band's forward: one (batch, head), one
-    query tile, its own K/V block and the ``before`` ahead of it.
+    query tile, its own K/V block and the ``before`` ahead of it (the
+    heads of a kv head's group pass in turn under one tile's blocks:
+    ``_band_grid``).
     Blocks ``[head_dim, positions]``, logits ``[keys, queries]``, the
     online softmax of :func:`_fwd_kernel`; every update's key piece and
     query range are static (``_band_updates``), and a block before the
@@ -1102,13 +1243,15 @@ def _window_fwd_kernel(
 
 
 def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
-    """q/k: [bh, d, seq], v: [bh, dv, seq] -> (out [bh, dv, seq], lse
-    [bh, 1, seq] or None), each query over its ``window`` keys."""
+    """q: [bh, d, seq], k: [bh / group, d, seq], v: [bh / group, dv,
+    seq] -> (out [bh, dv, seq], lse [bh, 1, seq] or None), each query
+    over its ``window`` keys."""
     bh, head_dim, seq_len = q.shape
     v_dim = v.shape[1]
+    group = _kv_group(q, k)
     sched = _band_schedule(seq_len, window, block_q, block_k, _WINDOW_PIECE)
     tile, piece, before = sched
-    grid = (bh, seq_len // tile)
+    grid, at = _band_grid(bh, group, seq_len // tile)
     visited, in_band = _band_tiles(sched, seq_len, window)
     trace.event(
         "flash.schedule",
@@ -1127,6 +1270,8 @@ def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
         kv_blocks=before + 1,
         tiles_visited=visited,
         tiles_in_band=in_band,
+        kv_group=group,
+        kv_heads=bh // group,
     )
     # What the pair of kernels multiplies a row's queries with, against
     # the band itself: static, so journalled here where the schedule is
@@ -1147,11 +1292,12 @@ def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
         keys_in_window=keys_in_window(seq_len, window),
     )
 
-    def block_spec(width, back):
-        return pl.BlockSpec(
-            (1, width, tile),
-            lambda b, qi: (b, 0, jnp.maximum(qi - back, 0)),
-        )
+    def block_spec(width, back, of_kv=False):
+        def index(*step):
+            row, kv_row, qi = at(*step)
+            return (kv_row if of_kv else row, 0, jnp.maximum(qi - back, 0))
+
+        return pl.BlockSpec((1, width, tile), index)
 
     vma = jax.typeof(q).vma
     out_specs = [block_spec(v_dim, 0)]
@@ -1169,12 +1315,13 @@ def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
         ),
         grid=grid,
         in_specs=[block_spec(head_dim, 0)]
-        + [block_spec(head_dim, back) for back in blocks]
-        + [block_spec(v_dim, back) for back in blocks],
+        + [block_spec(head_dim, back, True) for back in blocks]
+        + [block_spec(v_dim, back, True) for back in blocks],
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "parallel")
+            + ("arbitrary",) * (len(grid) - 2),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=_use_interpret(),
@@ -1185,15 +1332,18 @@ def _window_fwd_pallas(q, k, v, scale, block_q, block_k, window, with_lse):
 
 def _window_bwd_kernel(
     q_ref, do_ref, o_ref, lse_ref, *refs, scale: float,
-    sched: _BandSchedule, window: int, num_q: int,
+    sched: _BandSchedule, window: int, num_q: int, group: int,
 ):
-    """One grid step of the band's backward: one (batch, head), one
+    """One grid step of the band's backward: one (batch, kv head), one
     query tile with its own K/V block and the ``before`` ahead of it —
     the forward's walk, so dQ of the tile is whole when the step ends
-    (no partial a chunk). dK and dV of a K/V block gather over the
-    ``before + 1`` query tiles that see it, in a ring of as many
+    (no partial a chunk) —, one of the kv head's ``group`` query heads
+    (``_band_grid``). dK and dV of a K/V block gather over the
+    ``before + 1`` query tiles that see it, of every query head of the
+    group, in a ring of as many
     float32 slots; a block's slot is written out, and free again, in
-    the step of the last tile that sees it, and ``before`` steps past
+    the step of the last tile and query head that see it, and
+    ``before`` tiles past
     the last query tile flush the ring. The identities and the layout
     are :func:`_bwd_kernel`'s."""
     tile, piece, before = sched
@@ -1202,6 +1352,18 @@ def _window_bwd_kernel(
     dq_ref, dk_ref, dv_ref = refs[2 * ring:2 * ring + 3]
     delta_ref, dq_acc, dk_ring, dv_ring = refs[2 * ring + 3:]
     qi = pl.program_id(1)
+    # (Read out here: interpret mode knows a grid index in the
+    # kernel's own straight line alone.)
+    gi = pl.program_id(2) if group > 1 else None
+
+    def of_group(head_index, then):
+        """``then`` where the step's query head is the group's
+        ``head_index``-th: always, with equal head counts."""
+        if group == 1:
+            then()
+        else:
+            pl.when(gi == head_index)(then)
+
     nt = (((1,), (1,)), ((), ()))  # a @ b.T
     tn = (((0,), (0,)), ((), ()))  # a.T @ b
     dot = functools.partial(
@@ -1236,10 +1398,14 @@ def _window_bwd_kernel(
     @pl.when(qi < num_q)
     def _tile():
         # The tile's own K/V block enters the ring: its slot was
-        # written out in the step before.
+        # written out in the tile before.
         own = lax.rem(qi, ring)
-        dk_ring[own] = jnp.zeros(dk_ring.shape[1:], dk_ring.dtype)
-        dv_ring[own] = jnp.zeros(dv_ring.shape[1:], dv_ring.dtype)
+
+        def _enter():
+            dk_ring[own] = jnp.zeros(dk_ring.shape[1:], dk_ring.dtype)
+            dv_ring[own] = jnp.zeros(dv_ring.shape[1:], dv_ring.dtype)
+
+        of_group(0, _enter)
         d_o = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
         delta_ref[...] = jnp.sum(d_o, axis=0, keepdims=True)
         dq_acc[...] = jnp.zeros_like(dq_acc)
@@ -1249,22 +1415,26 @@ def _window_bwd_kernel(
         dq_ref[0] = (scale * dq_acc[...]).astype(dq_ref.dtype)
 
     @pl.when(qi >= before)
-    def _flush():  # K/V block qi - before: no later tile sees it
-        slot = lax.rem(qi - before, ring)
-        dk_ref[0] = (scale * dk_ring[slot]).astype(dk_ref.dtype)
-        dv_ref[0] = dv_ring[slot].astype(dv_ref.dtype)
+    def _past():  # K/V block qi - before: no later tile sees it
+        def _flush():
+            slot = lax.rem(qi - before, ring)
+            dk_ref[0] = (scale * dk_ring[slot]).astype(dk_ref.dtype)
+            dv_ref[0] = dv_ring[slot].astype(dv_ref.dtype)
+
+        of_group(group - 1, _flush)
 
 
 def _window_bwd_pallas(q, k, v, do, out, lse, scale, block_q, block_k, window):
     """As :func:`_bwd_pallas`, each query over its ``window`` keys."""
     bh, head_dim, seq_len = q.shape
     v_dim = v.shape[1]
+    group = _kv_group(q, k)
     sched = _band_schedule(
         seq_len, window, block_q, block_k, _WINDOW_PIECE_BWD
     )
     tile, piece, before = sched
     num_q = seq_len // tile
-    grid = (bh, num_q + before)
+    grid, at = _band_grid(bh, group, num_q, tail=before)
     visited, in_band = _band_tiles(sched, seq_len, window)
     trace.event(
         "flash.schedule_bwd",
@@ -1285,34 +1455,40 @@ def _window_bwd_pallas(q, k, v, do, out, lse, scale, block_q, block_k, window):
         kv_blocks=before + 1,
         tiles_visited=visited,
         tiles_in_band=in_band,
+        kv_group=group,
+        kv_heads=bh // group,
     )
 
-    def block_spec(width, back):
+    def block_spec(width, back, of_kv=False):
         # ``back`` blocks before the step's query tile, held inside the
         # row: a step before the row's start or past its end repeats an
         # index, and nothing is fetched for it.
-        return pl.BlockSpec(
-            (1, width, tile),
-            lambda b, qi: (b, 0, jnp.clip(qi - back, 0, num_q - 1)),
-        )
+        def index(*step):
+            row, kv_row, qi = at(*step)
+            return (
+                kv_row if of_kv else row, 0,
+                jnp.clip(qi - back, 0, num_q - 1),
+            )
+
+        return pl.BlockSpec((1, width, tile), index)
 
     vma = jax.typeof(q).vma
     blocks = range(before + 1)
     return pl.pallas_call(
         functools.partial(
             _window_bwd_kernel, scale=scale, sched=sched, window=window,
-            num_q=num_q,
+            num_q=num_q, group=group,
         ),
         grid=grid,
         in_specs=[
             block_spec(head_dim, 0), block_spec(v_dim, 0),
             block_spec(v_dim, 0), block_spec(1, 0),
         ]
-        + [block_spec(head_dim, back) for back in blocks]
-        + [block_spec(v_dim, back) for back in blocks],
+        + [block_spec(head_dim, back, True) for back in blocks]
+        + [block_spec(v_dim, back, True) for back in blocks],
         out_specs=[
-            block_spec(head_dim, 0), block_spec(head_dim, before),
-            block_spec(v_dim, before),
+            block_spec(head_dim, 0), block_spec(head_dim, before, True),
+            block_spec(v_dim, before, True),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma),
@@ -1326,20 +1502,13 @@ def _window_bwd_pallas(q, k, v, do, out, lse, scale, block_q, block_k, window):
             pltpu.VMEM((before + 1, v_dim, tile), jnp.float32),  # dV ring
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",)
+            + ("arbitrary",) * (len(grid) - 1),
             vmem_limit_bytes=_VMEM_LIMIT_BWD,
         ),
         interpret=_use_interpret(),
         name=WINDOW_BWD_NAME,
     )(q, do, out, lse, *(k for _ in blocks), *(v for _ in blocks))
-
-
-# Bytes of ONE operand a windowed call is given at most: the band
-# kernels write no partial, so what a call costs beyond its work is
-# what its caller builds for it (grouped-query attention repeats k and
-# v for the heads of a call, and autodiff holds their gradients as
-# wide): at 16 384 keys and head 128 in bfloat16, 16 heads.
-_WINDOW_CALL_BYTES = 64 * 2**20
 
 
 def heads_a_call(
@@ -1353,17 +1522,17 @@ def heads_a_call(
     the heads it is given: a call's partials are held to the bytes of
     q itself, all heads. (Blocks that do not divide the row are cut
     to ones that do: this asks about memory and refuses no shape.)
-    Under a ``window`` shorter than the row nothing is chunked and no
-    partial written: as many heads as keep one operand of the call
-    within ``_WINDOW_CALL_BYTES``."""
+    Under a ``window`` shorter than the row nothing is chunked, no
+    partial is written and, since the kernels index k and v by kv
+    head, nothing is repeated for a call either: all of them.
+    (Measured on a v5e, PERF.md PR 55: 64 heads of 128 on 8 kv heads
+    at 16 384 keys, window 512, ONE call 4.74 ms forward / 11.10
+    forward + backward with 256 / 516 MiB of temporaries; four calls
+    of 16 and their concatenate 5.49 / 11.98 with 448 / 585. Until PR
+    55 a call was held to 16 such heads, 64 MiB an operand, because
+    its caller repeated k and v for it.)"""
     if window is not None and window < seq_len:
-        at_once = _WINDOW_CALL_BYTES // (
-            seq_len * max(head_dim, v_dim) * itemsize
-        )
-        return next(
-            n for n in range(min(heads, max(1, at_once)), 0, -1)
-            if heads % n == 0
-        )
+        return heads
     sched = _schedule(
         seq_len, head_dim, itemsize, math.gcd(block_q, seq_len),
         math.gcd(block_k, seq_len), diag_rows=_BWD_DIAG_ROWS, v_dim=v_dim,
@@ -1391,4 +1560,5 @@ def make_flash_attention(
     attn.heads_a_call = functools.partial(
         heads_a_call, block_q=block_q, block_k=block_k
     )
+    attn.takes_kv_heads = True
     return attn

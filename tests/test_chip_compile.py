@@ -211,6 +211,52 @@ def test_band_kernels_compile_for_v5e(v5e, chip_compile, what, dtype):
     ) < 3.0
 
 
+# (q heads, kv heads, keys, head width, window) of ONE call as the
+# grouped-query cells make it since PR 55: a sliding layer's 64 heads
+# on 8 kv heads, a full layer's run of 12 on 2 (K-blocked, two
+# chunks), qwen3-next's run of 2 on 1 at head 256 (four chunks),
+# lfm2's 32 on 8 at head 64 (K / V resident).
+GROUPED_CALLS = {
+    "laguna_sliding": (64, 8, 16384, 128, 512),
+    "laguna_full": (12, 2, 16384, 128, None),
+    "qwen3_next": (2, 1, 16384, 256, None),
+    "lfm2": (32, 8, 8192, 64, None),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_CALLS))
+def test_kernels_with_fewer_kv_heads_compile_for_v5e(v5e, chip_compile, case):
+    """k and v ``kv_heads`` wide, indexed by ``query head // group``
+    inside all four kernels (the group's heads one more grid axis of
+    the backwards and of the band forward): forward and backward
+    compile at the cells' shapes, and the gradients of k and v come
+    back ``kv_heads`` wide."""
+    heads, kv_heads, seq, width, window = GROUPED_CALLS[case]
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(n):
+        return jax.ShapeDtypeStruct(
+            (1, n, seq, width), jnp.bfloat16, sharding=one
+        )
+
+    def loss(q, k, v):
+        out = flash_mod.flash_attention(q, k, v, True, None, 128, 128, window)
+        return out.astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2))
+    args = (arg(heads), arg(kv_heads), arg(kv_heads))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count(flash_mod.MOSAIC_CALL) == 2
+    names = (
+        (flash_mod.WINDOW_FWD_NAME, flash_mod.WINDOW_BWD_NAME)
+        if window else ("attention", flash_mod.BWD_KERNEL_NAME)
+    )
+    assert all(name in text for name in names)
+    assert [g.shape[1] for g in jax.eval_shape(fn, *args)] == [
+        heads, kv_heads, kv_heads
+    ]
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("what", ["fwd", "grad"])
 def test_kda_kernels_compile_for_v5e(

@@ -6,6 +6,9 @@ interpret mode on CPU — same semantics the compiled kernel executes
 on TPU."""
 
 import importlib
+import json
+import os
+import sys
 
 import numpy as np
 import pytest
@@ -255,6 +258,138 @@ def test_bf16_gradients_match_plain_attention(monkeypatch, case, reference):
     for got_x, want_x, name in zip(got, want, "qkv"):
         assert got_x.dtype == jnp.bfloat16
         assert _relative(got_x, want_x) < bound, f"d{name}"
+
+
+# ---- fewer kv heads than query heads (PR 55) --------------------------
+#
+# k and v go in ``kv_heads`` wide and the kernels index them by
+# ``query head // group``; the backward sums dK / dV over a group's
+# query heads in its float32 scratch. Against the SAME kernels on
+# ``jnp.repeat``ed operands, whose dK / dV autodiff sums.
+
+# Tiles of 16 of 64 keys (four query tiles a head); ``k_blocked``: 32
+# keys' K and V of 16, double-buffered, so two chunks of two tiles.
+KV_GROUP_SCHEDULES = {
+    "resident": lambda itemsize: {"_TILE_ROWS": 16},
+    "k_blocked": lambda itemsize: {
+        "_TILE_ROWS": 16, "_KV_VMEM_BUDGET": 2 * 2 * 32 * 16 * itemsize,
+    },
+}
+
+
+def _grouped(group, dtype, seed=11, kv_heads=2, seq=64):
+    """q and a cotangent of ``kv_heads * group`` heads, k and v of
+    ``kv_heads``."""
+    q, g, _ = _qkv(heads=kv_heads * group, seq=seq, seed=seed, dtype=dtype)
+    _, k, v = _qkv(heads=kv_heads, seq=seq, seed=seed + 1, dtype=dtype)
+    return q, k, v, g
+
+
+def _against_repeated(attend, group, q, k, v, g):
+    """(out, dq, dk, dv) of ``attend`` on k and v as they are, and on
+    each kv head repeated for its group."""
+    def both(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out, *vjp(g))
+
+    return both(attend), both(
+        lambda q, k, v: attend(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        )
+    )
+
+
+def _assert_the_repeated_calls(got, want, dtype):
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(
+                a, b, rtol=1e-5, atol=1e-5, err_msg=name
+            )
+        else:  # the bound of the bf16 gradients against plain attention
+            assert _relative(a, b) < 5.5e-3, name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [1, 2, 4, 8])
+@pytest.mark.parametrize("schedule", list(KV_GROUP_SCHEDULES))
+def test_kv_heads_are_indexed_inside_the_kernels(
+    monkeypatch, schedule, group, dtype
+):
+    dtype = jnp.dtype(dtype)
+    for name, value in KV_GROUP_SCHEDULES[schedule](dtype.itemsize).items():
+        monkeypatch.setattr(flash_mod, name, value)
+    q, k, v, g = _grouped(group, dtype)
+    before = len(trace.snapshot_spans())
+    got, want = _against_repeated(
+        lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16),
+        group, q, k, v, g,
+    )
+    _assert_the_repeated_calls(got, want, dtype)
+    # The first two events are the unrepeated call's: 2 x 2 kv rows,
+    # ``group`` query rows each, the group's heads INSIDE a chunk.
+    events = {
+        r["name"]: r["attrs"]
+        for r in reversed(trace.snapshot_spans()[before:])
+        if r["attrs"].get("kv_group") == group
+    }
+    fwd, bwd = events["flash.schedule"], events["flash.schedule_bwd"]
+    chunks = 1 if schedule == "resident" else 2
+    assert (fwd["kv_heads"], bwd["kv_heads"]) == (4, 4)
+    assert fwd["kv_resident"] == bwd["kv_resident"] == (chunks == 1)
+    assert fwd["grid_steps"] == bwd["grid_steps"] == 4 * group * 4 * chunks
+
+
+@pytest.mark.parametrize("schedule", list(KV_GROUP_SCHEDULES))
+def test_kv_heads_under_bidirectional_attention(monkeypatch, schedule):
+    for name, value in KV_GROUP_SCHEDULES[schedule](4).items():
+        monkeypatch.setattr(flash_mod, name, value)
+    q, k, v, g = _grouped(4, jnp.float32, seed=13)
+    got, want = _against_repeated(
+        lambda q, k, v: flash_attention(q, k, v, False, None, 16, 16),
+        4, q, k, v, g,
+    )
+    _assert_the_repeated_calls(got, want, jnp.float32)
+    np.testing.assert_allclose(
+        got[0],
+        _dense(q, jnp.repeat(k, 4, axis=1), jnp.repeat(v, 4, axis=1), False),
+        rtol=2e-5, atol=2e-5,
+    )
+
+
+def test_kv_heads_must_divide_the_query_heads():
+    q, k, v = _qkv(heads=3)
+    with pytest.raises(ValueError, match="kv heads must divide"):
+        flash_attention(q, k[:, :2], v[:, :2])
+    with pytest.raises(ValueError, match="kv heads must divide"):
+        flash_attention(q, k, v[:, :1])
+
+
+with open(
+    os.path.join(os.path.dirname(__file__), "data",
+                 "flash_equal_heads_digests.json")
+) as _f:
+    _EQUAL_HEADS = json.load(_f)
+
+
+@pytest.mark.parametrize("case", list(_EQUAL_HEADS))
+def test_equal_head_counts_lower_to_the_program_of_before(monkeypatch, case):
+    """``group == 1`` is decided in Python: the lowered text of every
+    kernel pair with equal head counts is what the commit before the
+    kv index gave (``tests/flash_digests.py``, run there)."""
+    sys.path.insert(0, os.path.dirname(__file__))
+    import flash_digests
+
+    name, dtype = case.split("/")
+    assert flash_digests.digest(
+        name, dtype, monkeypatch.setattr
+    ) == _EQUAL_HEADS[case]
+
+
+def test_the_functions_say_they_take_kv_heads():
+    """What ``GroupedQueryAttention`` looks for behind a partial."""
+    assert flash_attention.takes_kv_heads is True
+    assert make_flash_attention(block_q=16, block_k=16).takes_kv_heads is True
 
 
 def _kernel_calls(jaxpr):

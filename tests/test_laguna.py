@@ -303,29 +303,109 @@ def test_mixer_equals_the_reference(monkeypatch, name):
         assert _rel(wrong, want) > 1e-3, variant
 
 
-def test_heads_go_in_runs_where_one_call_would_repeat_too_much(monkeypatch):
-    """A sliding layer's 8 heads in runs of 4 (two kv heads' worth of
-    repeats a call, not all of them at once): the same numbers."""
+def _sliding_mixer(monkeypatch):
     config, sizes = _config_module(), _sizes()
     built = _built(monkeypatch, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
-    mixer_params = params["layer_2"]["attention"]
     u = jax.random.normal(jax.random.key(8), (1, 64, 32))
-    whole = GroupedQueryAttention(
-        config.model_config(sizes, FLASH), "sliding_attention"
-    ).apply({"params": mixer_params}, u, jnp.arange(64))
+
+    def mixer(attn):
+        return GroupedQueryAttention(
+            config.model_config(sizes, attn), "sliding_attention"
+        )
+
+    return mixer, {"params": params["layer_2"]["attention"]}, u
+
+
+@pytest.mark.parametrize("run", [2, 4])  # half a group of 4, and a group
+@pytest.mark.parametrize("kernel", [False, True])
+def test_heads_go_in_runs_where_the_function_asks_for_fewer_a_call(
+    monkeypatch, kernel, run
+):
+    """A sliding layer's 8 heads on 2 kv heads in runs (what a
+    function's ``heads_a_call`` says: the K-blocked backward's
+    partials; until PR 55 also what one call would have had repeated
+    for it): the same numbers, forward and gradients. A function that
+    does not say ``takes_kv_heads`` is handed equal head counts, each
+    run's kv head repeated for that run alone; one that says it (the
+    kernels' own mark) the run's kv head ONCE."""
+    mixer, variables, u = _sliding_mixer(monkeypatch)
+
+    def loss(module):
+        def of(variables, u):
+            return jnp.sum(jnp.sin(module.apply(variables, u, jnp.arange(64))))
+
+        return jax.value_and_grad(of, (0, 1))
+
+    want = loss(mixer(FLASH))(variables, u)
     asked = []
 
     def attn(q, k, v, window=None):
-        asked.append((q.shape[1], window))
+        asked.append((q.shape[1], k.shape[1], v.shape[1], window))
         return FLASH(q, k, v, window=window)
 
-    attn.heads_a_call = lambda heads, *a, **kw: 4
-    in_runs = GroupedQueryAttention(
-        config.model_config(sizes, attn), "sliding_attention"
-    ).apply({"params": mixer_params}, u, jnp.arange(64))
-    assert asked == [(4, 24), (4, 24)]
-    np.testing.assert_allclose(in_runs, whole, rtol=1e-5, atol=1e-6)
+    attn.heads_a_call = lambda heads, *a, **kw: run
+    if kernel:
+        attn.takes_kv_heads = True
+    before = len(trace.snapshot_spans())
+    got = loss(mixer(attn))(variables, u)
+    kv = 1 if kernel else run
+    assert asked == [(run, kv, kv, 24)] * (8 // run)
+    (event,) = [
+        r["attrs"] for r in trace.snapshot_spans()[before:]
+        if r["name"] == "attn_kind.schedule"
+    ]
+    assert (event["heads_a_call"], event["kv_repeat"]) == (run, kv)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_the_kernels_are_handed_each_kv_head_once(monkeypatch, kind):
+    """On the flash path no broadcast of k or v to the query heads is
+    left in a mixer's lowered gradient, nor the sum of dK / dV over a
+    group that undoes it (``jnp.repeat`` lowers to a broadcast into
+    ``[b, s, kv_heads, group, d]`` and its transpose reduces that
+    shape); a function that is not the kernel still gets both, and
+    plain attention too."""
+    config, sizes = _config_module(), _sizes()
+    built = _built(monkeypatch, sizes)
+    params = built["trainer"].params_tree(built["trainer"].init_state())
+    at = {"sliding_attention": 2, "full_attention": 0}[kind]
+    variables = {"params": params[f"layer_{at}"]["attention"]}
+    u = jax.random.normal(jax.random.key(9), (1, 64, 32))
+    heads = 8 if kind == "sliding_attention" else 6
+    repeated = (
+        "dims = [0, 1, 2, 4] : (tensor<1x64x2x16xf32>) -> "
+        f"tensor<1x64x2x{heads // 2}x16xf32>"
+    )
+
+    def lowered(attn):
+        module = GroupedQueryAttention(config.model_config(sizes, attn), kind)
+        before = len(trace.snapshot_spans())
+        text = jax.jit(jax.grad(
+            lambda variables, u: jnp.sum(
+                module.apply(variables, u, jnp.arange(64))
+            ),
+            (0, 1),
+        )).lower(variables, u).as_text()
+        (event,) = [
+            r["attrs"] for r in trace.snapshot_spans()[before:]
+            if r["name"] == "attn_kind.schedule"
+        ]
+        return text, event
+
+    text, event = lowered(FLASH)
+    assert repeated not in text and event["kv_repeat"] == 1
+    assert event["heads_a_call"] == heads
+
+    def wrapped(q, k, v, window=None):  # says nothing of itself
+        assert q.shape[1] == k.shape[1] == v.shape[1] == heads
+        return FLASH(q, k, v, window=window)
+
+    for other in (wrapped, None):
+        text, event = lowered(other)
+        assert repeated in text and event["kv_repeat"] == heads // 2
 
 
 # ---- the share ------------------------------------------------------------

@@ -250,6 +250,51 @@ def test_gated_attention_in_runs_of_heads_is_the_attention(monkeypatch, runs):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("run", [1, 2, 4, 8])
+def test_gated_attention_hands_the_kernels_a_runs_own_kv_heads(run):
+    """8 gated heads on 4 kv heads (groups of 2) through the flash
+    kernels in runs shorter than a group (1: the cell's case, 2 of 8),
+    of one group, of two groups, and all at once: a call gets its
+    run's kv heads ONCE each (PR 55), and the numbers are plain
+    attention's on repeated heads, forward and every gradient (the
+    runs' dK / dV of one kv head are added by autodiff)."""
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=1, num_heads=8, num_kv_heads=4,
+        d_model=64, d_ff=64, dtype=jnp.float32, attention_gate=True,
+    )
+    u = jax.random.normal(jax.random.key(21), (2, 64, 64))
+    positions = jnp.arange(64)
+    plain = GroupedQueryAttention(cfg)
+    variables = plain.init(jax.random.key(22), u, positions)
+    asked = []
+
+    def attn(q, k, v):
+        asked.append((q.shape[1], k.shape[1], v.shape[1]))
+        return flash_attention(q, k, v, True, None, 16, 16)
+
+    attn.heads_a_call = lambda heads, *_a, **_k: run
+    attn.takes_kv_heads = True
+    flash = GroupedQueryAttention(dataclasses.replace(cfg, attention_fn=attn))
+
+    def both(module):
+        return jax.value_and_grad(
+            lambda variables, u: jnp.sum(
+                jnp.sin(module.apply(variables, u, positions))
+            ),
+            (0, 1),
+        )(variables, u)
+
+    since = len(trace.snapshot_spans())
+    got, want = both(flash), both(plain)
+    kv = max(1, run // 2)
+    assert asked == [(run, kv, kv)] * (8 // run)
+    flash_event, plain_event = _events("gated_attn.schedule", since)
+    assert (flash_event["heads_a_call"], flash_event["kv_repeat"]) == (run, 1)
+    assert (plain_event["heads_a_call"], plain_event["kv_repeat"]) == (8, 2)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
 # ---- the routed layer --------------------------------------------------
 
 

@@ -25,7 +25,10 @@ from adaptdl_tpu.models.transformer import (
     routed_lm_loss_fn,
 )
 from adaptdl_tpu.ops import grouped_matmul as gmm
-from adaptdl_tpu.ops.flash_attention import flash_attention
+from adaptdl_tpu.ops.flash_attention import (
+    flash_attention,
+    make_flash_attention,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = {
@@ -713,6 +716,46 @@ def test_gqa_with_head_norms_through_the_flash_kernel():
     ]
     for a, b in zip(*map(jax.tree.leaves, grads)):
         np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+@pytest.mark.parametrize(
+    "attention", ["flash", "made", "wrapped", "causal_attention", "none"]
+)
+def test_only_the_flash_kernels_are_handed_fewer_kv_heads(attention):
+    """lfm2's attention (4 query heads on 2 kv heads, all in one call):
+    a ``functools.partial`` of the kernel and ``make_flash_attention``'s
+    result get k and v two heads wide — no broadcast to the query
+    heads and no sum of dK / dV over a group in the lowered gradient
+    (``jnp.repeat`` and its transpose go through ``[b, s, 2, 2, d]``)
+    —; any other function, and plain attention, equal head counts."""
+    x = jax.random.normal(jax.random.key(0), (2, 32, 32))
+    positions = jnp.arange(32)
+    seen = []
+    kernel = functools.partial(flash_attention, block_q=16, block_k=16)
+
+    def wrapped(q, k, v):
+        seen.append((q.shape[1], k.shape[1], v.shape[1]))
+        return kernel(q, k, v)
+
+    attention_fn = {
+        "flash": kernel,
+        "made": make_flash_attention(block_q=16, block_k=16),
+        "wrapped": wrapped,
+        "causal_attention": functools.partial(causal_attention),
+        "none": None,
+    }[attention]
+    module = GroupedQueryAttention(_mixer_config(attention_fn=attention_fn))
+    params = module.init(jax.random.key(1), x, positions)["params"]
+    text = jax.jit(jax.grad(
+        lambda p, x: module.apply({"params": p}, x, positions).sum(), (0, 1)
+    )).lower(params, x).as_text()
+    repeated = (
+        "dims = [0, 1, 2, 4] : (tensor<2x32x2x8xf32>) -> "
+        "tensor<2x32x2x2x8xf32>"
+    )
+    assert (repeated in text) == (attention not in ("flash", "made"))
+    if attention == "wrapped":
+        assert set(seen) == {(4, 4, 4)}
 
 
 def test_default_blocks_keep_their_parameter_tree():

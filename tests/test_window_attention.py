@@ -3,8 +3,10 @@ schedule of ``flash_attention(..., window=)`` in interpret mode against
 a masked plain softmax, forward and gradients, across windows shorter
 than, equal to and longer than a tile, windows that are no multiple of
 the update's piece, windows that reach the row's start (today's
-program), groups of 6 and 8 query heads a key/value head, float32 and
-bfloat16; and the schedule's static counts against brute force."""
+program), groups of 6 and 8 query heads a key/value head — repeated on
+the way in, and (PR 55) handed over once a kv head and indexed inside
+the kernels —, float32 and bfloat16; and the schedule's static counts
+against brute force."""
 
 import importlib
 
@@ -112,12 +114,17 @@ def test_a_window_that_reaches_the_rows_start_is_todays_program(window):
     assert "window.keys" not in names and "flash.schedule" in names
 
 
+@pytest.mark.parametrize("repeated", [True, False])
 @pytest.mark.parametrize("group", [6, 8])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_groups_of_query_heads_on_repeated_kv_heads(tiles, group, dtype):
+def test_groups_of_query_heads_on_repeated_kv_heads(
+    tiles, group, dtype, repeated
+):
     """Laguna's groups (6 on a full layer, 8 on a sliding one): each
-    kv head repeated for its group on the way in, as the model hands
-    them over; dK and dV summed over the group by autodiff."""
+    kv head repeated for its group on the way in, as the model handed
+    them over before PR 55, dK and dV summed over the group by
+    autodiff; and handed over ONCE a kv head (``repeated`` False), the
+    group's heads indexed and dK / dV summed inside the kernels."""
     tiles(32, 32)
     dtype = jnp.dtype(dtype)
     seq, window = 96, 40
@@ -134,12 +141,10 @@ def test_groups_of_query_heads_on_repeated_kv_heads(tiles, group, dtype):
 
         return run
 
-    got = _both(
-        attend(lambda q, k, v: fm.flash_attention(
-            q, k, v, True, None, 16, 16, window
-        )),
-        q, k, v, g,
-    )
+    def flash(q, k, v):
+        return fm.flash_attention(q, k, v, True, None, 16, 16, window)
+
+    got = _both(attend(flash) if repeated else flash, q, k, v, g)
     want = _both(attend(lambda q, k, v: _plain(q, k, v, window)), q, k, v, g)
     assert got[0].dtype == dtype and got[2].shape == k.shape
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
@@ -147,6 +152,58 @@ def test_groups_of_query_heads_on_repeated_kv_heads(tiles, group, dtype):
         np.testing.assert_allclose(
             a.astype(jnp.float32), b.astype(jnp.float32), rtol=tol, atol=tol
         )
+
+
+@pytest.mark.parametrize(
+    "window, group, dtype",
+    # Three K/V blocks before a tile's own (a ring of four), and one.
+    [(70, n, t) for n in (1, 2, 4, 8) for t in ("float32", "bfloat16")]
+    + [(5, 4, "float32")],
+)
+def test_kv_heads_are_indexed_inside_the_band_kernels(
+    tiles, window, group, dtype
+):
+    """k and v ``kv_heads`` wide against the same kernels on repeated
+    operands (float32: to 1e-5): a group's query heads pass under one
+    tile's K/V blocks, and the backward's ring gathers all of them
+    before a block's dK / dV are written, rounded once."""
+    tiles(32, 32)
+    dtype = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.key(window + group), 4)
+    q = jax.random.normal(keys[0], (2, 2 * group, 128, 16), dtype)
+    k, v = (jax.random.normal(x, (2, 2, 128, 16), dtype) for x in keys[1:3])
+    g = jax.random.normal(keys[3], q.shape, dtype)
+
+    def flash(q, k, v):
+        return fm.flash_attention(q, k, v, True, None, 16, 16, window)
+
+    before = len(trace.snapshot_spans())
+    got = _both(flash, q, k, v, g)
+    events = {}
+    for rec in trace.snapshot_spans()[before:]:
+        events.setdefault(rec["name"], rec["attrs"])
+    want = _both(
+        lambda q, k, v: flash(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        ),
+        q, k, v, g,
+    )
+    tol = 1e-5 if dtype == jnp.float32 else 4e-2
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == dtype
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b.astype(jnp.float32), rtol=tol, atol=tol
+        )
+    blocks_before = -(-(window - 1) // 32)
+    for name, steps in (
+        ("flash.schedule", 4), ("flash.schedule_bwd", 4 + blocks_before)
+    ):
+        attrs = events[name]
+        assert (attrs["kv_group"], attrs["kv_heads"]) == (group, 4)
+        assert attrs["grid_steps"] == 4 * group * steps
+    # batch x QUERY heads, whatever the kv heads: what the reader of
+    # ``window_keys_visited_over_window`` weighs by.
+    assert events["window.keys"]["batch_heads"] == 4 * group
 
 
 def _brute(seq, piece, window):
@@ -209,6 +266,7 @@ def test_the_schedule_is_journalled(tiles):
         )
         assert attrs["tiles_visited"] == attrs["tiles_in_band"] == blocks
         assert attrs["kv_blocks"] == 3 and not attrs["kv_resident"]
+        assert (attrs["kv_group"], attrs["kv_heads"]) == (1, 6)
     assert events["flash.schedule"]["grid_steps"] == 6 * 4
     assert events["flash.schedule_bwd"]["grid_steps"] == 6 * (4 + 2)
     keys = events["window.keys"]
@@ -218,11 +276,12 @@ def test_the_schedule_is_journalled(tiles):
 
 
 def test_heads_a_call_under_a_window():
-    """No chunk and no partial under a window: as many heads as keep
-    one operand of the call within 64 MiB (16 at 16 384 keys of 128 in
-    bfloat16), a divisor of the layer's heads."""
-    assert fm.heads_a_call(64, 16384, 128, 128, 2, window=512) == 16
-    assert fm.heads_a_call(48, 16384, 128, 128, 2, window=512) == 16
+    """No chunk and no partial under a window, and (PR 55) nothing
+    repeated for a call: all the heads, whatever the row. (Until PR 55:
+    as many as kept one operand within 64 MiB, 16 at 16 384 keys of 128
+    in bfloat16, because the caller repeated k and v for a call.)"""
+    assert fm.heads_a_call(64, 16384, 128, 128, 2, window=512) == 64
+    assert fm.heads_a_call(48, 16384, 128, 128, 2, window=512) == 48
     assert fm.heads_a_call(6, 16384, 128, 128, 2, window=512) == 6
     assert fm.heads_a_call(64, 4096, 128, 128, 2, window=512) == 64
     # A window that reaches the row's start is the full schedule's
@@ -231,7 +290,7 @@ def test_heads_a_call_under_a_window():
         fm.heads_a_call(48, 16384, 128, 128, 2)
     ) == 12
     made = fm.make_flash_attention(block_q=16, block_k=16)
-    assert made.heads_a_call(64, 16384, 128, 128, 2, window=512) == 16
+    assert made.heads_a_call(64, 16384, 128, 128, 2, window=512) == 64
 
 
 def test_a_window_is_causal_only():
