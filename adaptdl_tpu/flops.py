@@ -78,7 +78,10 @@ def transformer_train_flops(
     ``attention_kinds`` describes at the kind's own heads and, under a
     window, over its band (``_kind_attention_layer``); a layer with routed
     experts at its router, the HELD share of the routed experts under
-    even routing, and the shared expert.
+    even routing, and the shared expert. A prediction module
+    (``mtp_depth``) is one more block of the last trunk layer's kind
+    (its mixer, its routed or dense FFN), the ``[2 d, d]`` projection
+    into it and a second pass of the head over its rows.
     """
     d = config.d_model
     d_ff = config.d_ff
@@ -126,6 +129,25 @@ def transformer_train_flops(
             extra_attn += attn
             if config.routed(layer):
                 extra_matmul += _routed_ffn(config) - dense_ffn
+    # Attention contractions: QK^T and PV are each 2*S*d_model FLOPs
+    # per token (summed over heads); the causal mask discards half the
+    # rectangle, and backward recomputes both contractions twice.
+    attn_per_token = 2 * (2 * seq_len * d)
+    if getattr(config, "causal", True):
+        attn_per_token /= 2
+    if getattr(config, "mtp_depth", 0):
+        # The module's block as the layer it is, its projection and
+        # its pass of the head.
+        at, kind = config.num_layers, config.mixer(config.num_layers)
+        mix, attn = (
+            apart[kind](config, seq_len) if kind in apart
+            else (proj, attn_per_token)
+        )
+        extra_matmul += (
+            mix + 2 * (2 * d) * d + head
+            + (_routed_ffn(config) if config.routed(at) else dense_ffn)
+        )
+        extra_attn += attn
     fwd_matmul = tokens * passes * (
         config.num_layers * proj
         + num_dense * dense_ffn
@@ -133,13 +155,6 @@ def transformer_train_flops(
         + head
         + extra_matmul
     )
-
-    # Attention contractions: QK^T and PV are each 2*S*d_model FLOPs
-    # per token (summed over heads); the causal mask discards half the
-    # rectangle, and backward recomputes both contractions twice.
-    attn_per_token = 2 * (2 * seq_len * d)
-    if getattr(config, "causal", True):
-        attn_per_token /= 2
     fwd_attn = tokens * passes * (
         (config.num_layers - len(new_kinds)) * attn_per_token + extra_attn
     )
@@ -233,12 +248,14 @@ def _kind_attention_layer(
 
 def _mla_layer(config, seq_len: int) -> tuple[float, float]:
     """(projection FLOPs, attention FLOPs) a token of one "mla"
-    layer: q, kv_a, kv_b and out; QK^T at the q/k width and PV at the
-    v width, the causal half."""
+    layer: q (through its bottleneck where ``q_lora_rank`` is set),
+    kv_a, kv_b and out; QK^T at the q/k width and PV at the v width,
+    the causal half."""
     d, heads = config.d_model, config.num_heads
     qk = config.qk_nope_head_dim + config.qk_rope_head_dim
+    q_rank = getattr(config, "q_lora_rank", 0)
     proj = 2 * (
-        d * heads * qk
+        (d * q_rank + q_rank * heads * qk if q_rank else d * heads * qk)
         + d * (config.kv_lora_rank + config.qk_rope_head_dim)
         + config.kv_lora_rank * heads
         * (config.qk_nope_head_dim + config.v_head_dim)

@@ -409,13 +409,20 @@ ROWS_BOUND_FACTOR = 2.5
 # loop around three times the kernel calls for the rows of one pass,
 # each call over a third of the rows, which the accepted reader of
 # the grouped products' roofline prices at all of them (PERF.md
-# section 7, PR 46). Between 2.8 and 9.4 nothing has been measured.
+# section 7, PR 46). Between 2.8 and 9.4 nothing has been measured —
+# but at 2.8 itself a second share has (8 of 64, top 4, under 600 M
+# parameters: PERF.md section 6, PR 56): its router, sigmoid at a
+# fine-tuning rate, stays near even routing, and the chip's compiler
+# refuses the worst case's buffers (1.1 GiB a layer pass) beside its
+# state. Which of the two a share is the program cannot observe: the
+# threshold is what ``routed_experts`` is told (``pieces_from``,
+# ``TransformerConfig.experts_pieces_from``), this its default.
 ROWS_PIECES_FROM = 4
 
 
 def rows_bound(
     tokens: int, top_k: int, experts_held: int, experts_total: int,
-    tile: int,
+    tile: int, pieces_from: float = ROWS_PIECES_FROM,
 ) -> int:
     """Rows of the buffer a step usually walks: ``ROWS_BOUND_FACTOR``
     times the assignments an even router sends to the held experts,
@@ -428,28 +435,30 @@ def rows_bound(
     bound, a router that leaves even routing would pay that on every
     step (one chip's share of 16 of 128 experts does, within twenty
     steps: PERF.md section 6, PR 40), so there the layer keeps the one
-    pass — unless the worst case is ``ROWS_PIECES_FROM`` times the
-    bound or more: then the plan (``rows_planned`` long) is walked in
-    pieces of the bound, and a router that leaves even routing pays
-    for the pieces it fills."""
+    pass — unless the worst case is ``pieces_from`` times the bound or
+    more: then the plan (``rows_planned`` long) is walked in pieces of
+    the bound, and a router that leaves even routing pays for the
+    pieces it fills."""
     capacity = rows_capacity(tokens, top_k, experts_held, tile)
     expected = tokens * top_k * experts_held / experts_total
     usual = math.ceil(ROWS_BOUND_FACTOR * expected) + experts_held * (tile - 1)
     bound = -(-usual // tile) * tile
     if capacity - bound <= bound < capacity:
         return bound
-    return bound if capacity >= ROWS_PIECES_FROM * bound else capacity
+    return bound if capacity >= pieces_from * bound else capacity
 
 
 def rows_planned(
     tokens: int, top_k: int, experts_held: int, experts_total: int,
-    tile: int,
+    tile: int, pieces_from: float = ROWS_PIECES_FROM,
 ) -> int:
     """How long the plan's row arrays are: ``rows_capacity``, or,
     where the plan is walked in pieces of ``rows_bound`` rows, the
     next whole number of pieces."""
     capacity = rows_capacity(tokens, top_k, experts_held, tile)
-    bound = rows_bound(tokens, top_k, experts_held, experts_total, tile)
+    bound = rows_bound(
+        tokens, top_k, experts_held, experts_total, tile, pieces_from
+    )
     if capacity - bound <= bound:
         return capacity
     return -(-capacity // bound) * bound
@@ -732,6 +741,7 @@ def routed_experts(
     scale: float = 1.0,
     router_kind: str = "sigmoid",
     shared_gate: str = "none",
+    pieces_from: float | None = None,
 ):
     """One chip's share of a dropless top-k expert layer.
 
@@ -755,7 +765,12 @@ def routed_experts(
     top_k]``, for whoever checks the routing itself. ``shared_gate``:
     what the caller multiplies its shared expert by ("sigmoid", or
     "none": no gate, or no shared expert), said in ``moe.schedule``.
+    ``pieces_from``: from how many bounds of worst case on the plan is
+    walked in pieces of ``rows_bound`` rows (``rows_bound``; None:
+    ``ROWS_PIECES_FROM``).
     """
+    if pieces_from is None:
+        pieces_from = ROWS_PIECES_FROM
     tokens, _ = x.shape
     experts_held = w_gate.shape[0]
     assert router.shape[1] == experts_total
@@ -770,9 +785,11 @@ def routed_experts(
         tokens * min(top_k, experts_held), tokens * top_k / experts_total
     )
     capacity = rows_planned(
-        tokens, top_k, experts_held, experts_total, tile
+        tokens, top_k, experts_held, experts_total, tile, pieces_from
     )
-    bound = rows_bound(tokens, top_k, experts_held, experts_total, tile)
+    bound = rows_bound(
+        tokens, top_k, experts_held, experts_total, tile, pieces_from
+    )
     trace.event(
         "moe.schedule",
         experts_total=experts_total,

@@ -177,6 +177,16 @@ class TransformerConfig:
     # (probabilities over all experts, no bias buffer); either way the
     # chosen weights are divided by their sum.
     experts_router: str = "sigmoid"
+    # From how many times the rows an even router places (with their
+    # room: ``models.moe.rows_bound``) of WORST case on a routed layer
+    # walks its plan in pieces of the bound, instead of holding and
+    # walking buffers of the worst case on every step; None = the
+    # layer's own threshold (``models.moe.ROWS_PIECES_FROM``, 4). The
+    # knob to lower for a share whose router stays near even routing
+    # and whose device has no room for the worst case: what pieces
+    # cost a share whose router settles on its held experts is in
+    # ``models/moe.py``.
+    experts_pieces_from: float | None = None
     # Width of one attention head where it is not ``d_model //
     # num_heads`` (the q / kv projections then map ``d_model`` to
     # ``heads * head_dim`` and ``out`` maps back).
@@ -218,16 +228,21 @@ class TransformerConfig:
     # recurrence carried between chunks of ``ops.kda.CHUNK`` tokens. No
     # sequence-parallel path: the state crosses the whole row.
     kda_gate_rank: int = 0
-    # The "mla" mixer kind: latent attention without a query
-    # bottleneck. ``q`` is ``num_heads`` heads of ``qk_nope_head_dim +
-    # qk_rope_head_dim``; ``kv_a`` maps to ``kv_lora_rank`` (normed)
-    # and ONE shared key part of ``qk_rope_head_dim``; ``kv_b`` expands
-    # the latent to each head's ``qk_nope_head_dim`` of key and
-    # ``v_head_dim`` of value. Takes ``rope=False`` only.
+    # The "mla" mixer kind: latent attention. ``q`` is ``num_heads``
+    # heads of ``qk_nope_head_dim + qk_rope_head_dim``; ``kv_a`` maps
+    # to ``kv_lora_rank`` (normed) and ONE shared key part of
+    # ``qk_rope_head_dim``; ``kv_b`` expands the latent to each head's
+    # ``qk_nope_head_dim`` of key and ``v_head_dim`` of value. Under
+    # ``rope`` the ``qk_rope_head_dim`` lanes (and no other) are turned
+    # by rotary at ``rope_theta``: every head's of q, the shared key
+    # part once, before it goes onto the heads. ``q_lora_rank`` > 0:
+    # the query bottleneck, ``q = rmsnorm(x W_qa) W_qb`` through that
+    # many channels (``q_a``, ``q_norm``, ``q_b`` for ``q``).
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0
     # A shared expert beside the routed ones: one gated FFN of this
     # width on EVERY token of a routed layer, unweighted, added to the
     # routed experts' result. 0 = none.
@@ -270,6 +285,18 @@ class TransformerConfig:
     # (``attention_gate`` is the full-rank one). Selects
     # ``GroupedQueryAttention``.
     attention_head_gate: bool = False
+    # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437, section
+    # 2.2), depth 0 or 1: one module after the trunk (``mtp``) that
+    # predicts the token after the next. It has no tables of its own:
+    # ``u_i = W_eh [norm_e(Emb(t_{i+1})) ; norm_h(h_i)]`` with the
+    # MODEL's embedding and the trunk's state ``h`` before the final
+    # norm, one whole block on ``u`` (index ``num_layers``: the last
+    # trunk layer's mixer kind, routed where the trunk's layers are),
+    # a final norm of its own, and the model's output table.
+    # ``routed_lm_loss_fn`` adds ``mtp_loss_weight`` times its mean
+    # cross-entropy against the targets one place further on.
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.1
 
     def __post_init__(self):
         kinds = self.layer_types or ()
@@ -345,14 +372,33 @@ class TransformerConfig:
                 "rope: the 'sparse_attention' mixer always turns q and k"
             )
         if "mla" in kinds:
-            if self.rope:
+            if self.rope and (
+                self.qk_rope_head_dim <= 0 or self.qk_rope_head_dim % 2
+            ):
                 raise ValueError(
-                    "rope: the 'mla' mixer runs without positions only "
-                    "(set rope=False)"
+                    "rope: the 'mla' mixer turns its qk_rope_head_dim "
+                    f"lanes ({self.qk_rope_head_dim}: set an even number "
+                    "of them, or rope=False)"
                 )
             for name in ("kv_lora_rank", "qk_nope_head_dim", "v_head_dim"):
                 if getattr(self, name) <= 0:
                     raise ValueError(f"{name} must be set for 'mla' layers")
+        if self.q_lora_rank and "mla" not in kinds:
+            raise ValueError(
+                "q_lora_rank: the query bottleneck is the 'mla' mixer's"
+            )
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(
+                f"mtp_depth {self.mtp_depth}: one prediction module or none"
+            )
+        if self.mtp_depth and (
+            self.loop_passes > 1 or self.seq_axis is not None
+            or self.dropout_rate > 0
+        ):
+            raise ValueError(
+                "mtp_depth: the prediction module takes neither "
+                "loop_passes, seq_axis nor dropout_rate"
+            )
         if self.d_shared_expert > 0 and self.experts_total <= 0:
             raise ValueError(
                 "d_shared_expert: a shared expert stands beside routed "
@@ -372,10 +418,12 @@ class TransformerConfig:
         )
 
     def mixer(self, layer: int) -> str:
+        """The mixer kind of block ``layer``; the prediction module's
+        block (``num_layers``) has the last trunk layer's."""
         return (
             "full_attention"
             if self.layer_types is None
-            else self.layer_types[layer]
+            else self.layer_types[min(layer, self.num_layers - 1)]
         )
 
     def routed(self, layer: int) -> bool:
@@ -601,17 +649,17 @@ def _rms_norm(cfg: TransformerConfig, name: str | None = None):
     return kind(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
 
 
-def make_norm(cfg: TransformerConfig):
+def make_norm(cfg: TransformerConfig, name: str | None = None):
     """The config's normalisation, scale only: LayerNorm or RMSNorm
     with ``norm_eps`` (statistics in float32, result in ``dtype``)."""
     if cfg.norm == "rmsnorm":
-        return _rms_norm(cfg)
+        return _rms_norm(cfg, name)
     if cfg.norm != "layernorm":
         raise ValueError(
             f"norm must be 'layernorm' or 'rmsnorm', got {cfg.norm!r}"
         )
     return nn.LayerNorm(
-        epsilon=cfg.norm_eps, dtype=cfg.dtype, use_bias=False
+        epsilon=cfg.norm_eps, dtype=cfg.dtype, use_bias=False, name=name
     )
 
 
@@ -1225,20 +1273,22 @@ class GatedDeltaNet(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Latent attention without a query bottleneck and without
-    positions: ``q = x Wq`` as heads of ``qk_nope_head_dim +
+    """Latent attention: ``q = x Wq`` (or, with ``q_lora_rank``,
+    ``rmsnorm(x Wq_a) Wq_b``) as heads of ``qk_nope_head_dim +
     qk_rope_head_dim``; ``[c, k_pe] = x Wkv_a``; ``[k_nope, v] =
     rmsnorm(c) Wkv_b`` a head; ``k = [k_nope, k_pe]``, the one
     ``k_pe`` on every head; causal softmax attention at ``q``'s width,
-    values and output at ``v_head_dim``. ``attention_fn`` must take a
-    v narrower than q and k (``ops.flash_attention`` does). Journals
+    values and output at ``v_head_dim``. Under ``rope`` every head's
+    ``q_pe`` and the one ``k_pe`` (before it goes onto the heads) are
+    turned by rotary over their ``qk_rope_head_dim`` lanes; the nope
+    lanes never are. ``attention_fn`` must take a v of another width
+    than q and k (``ops.flash_attention`` does). Journals
     ``mla.schedule`` where it is traced."""
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x, positions):
-        del positions
         cfg = self.config
         if cfg.seq_axis is not None:
             raise ValueError(
@@ -1246,10 +1296,20 @@ class LatentAttention(nn.Module):
             )
         heads, nope, pe = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         rank, v_dim = cfg.kv_lora_rank, cfg.v_head_dim
+        q_in = x
+        if cfg.q_lora_rank:
+            q_in = nn.RMSNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype, name="q_norm"
+            )(
+                nn.Dense(
+                    cfg.q_lora_rank, dtype=cfg.dtype, use_bias=False,
+                    name="q_a",
+                )(x)
+            )
         q = nn.DenseGeneral(
             (heads, nope + pe), axis=-1, dtype=cfg.dtype, use_bias=False,
-            name="q",
-        )(x)
+            name="q_b" if cfg.q_lora_rank else "q",
+        )(q_in)
         kv_a = nn.Dense(
             rank + pe, dtype=cfg.dtype, use_bias=False, name="kv_a"
         )(x)
@@ -1262,11 +1322,16 @@ class LatentAttention(nn.Module):
         )(latent)
         k = kv[..., :nope]
         if pe:
+            k_pe = kv_a[:, :, None, rank:]  # [b, s, 1, pe]: ONE a token
+            if cfg.rope:
+                k_pe = rope(k_pe, positions, cfg.rope_theta)
+                q = jnp.concatenate(
+                    [q[..., :nope],
+                     rope(q[..., nope:], positions, cfg.rope_theta)],
+                    axis=-1,
+                )
             k = jnp.concatenate(
-                [k, jnp.broadcast_to(
-                    kv_a[:, :, None, rank:], k.shape[:3] + (pe,)
-                )],
-                axis=-1,
+                [k, jnp.broadcast_to(k_pe, k.shape[:3] + (pe,))], axis=-1
             )
         v = kv[..., nope:]
         attn = cfg.attention_fn
@@ -1282,8 +1347,10 @@ class LatentAttention(nn.Module):
             qk_width=nope + pe,
             v_width=v_dim,
             latent_rank=rank,
+            q_lora_rank=cfg.q_lora_rank,
             seq_len=x.shape[1],
-            positions="none",
+            positions="rotary" if cfg.rope else "none",
+            rotary_dims=pe if cfg.rope else 0,
             dtype=jnp.dtype(cfg.dtype).name,
             attention="attention_fn" if attn is not None
             else "plain causal attention",
@@ -1387,6 +1454,7 @@ class RoutedFFN(nn.Module):
             scale=cfg.routed_scaling_factor,
             router_kind=cfg.experts_router,
             shared_gate="sigmoid" if cfg.shared_expert_gate else "none",
+            pieces_from=cfg.experts_pieces_from,
         )
         for name, value in load.items():
             self.sow(
@@ -1557,6 +1625,38 @@ class Block(nn.Module):
         return x + y
 
 
+class PredictionModule(nn.Module):
+    """The multi-token-prediction depth (``TransformerConfig.
+    mtp_depth``; DeepSeek-V3, arXiv:2412.19437, equations 21-23):
+    ``u_i = W_eh [enorm(e_i) ; hnorm(h_i)]``, ``h`` the trunk's state
+    before its final norm and ``e_i`` the MODEL's embedding of the
+    token after position ``i``; one whole block on ``u`` at the same
+    positions (``layer_<num_layers>``, of ``block``: the trunk's class,
+    so under the same remat rule); its own final ``norm``. Neither an
+    embedding nor an output table: the model's are the caller's to
+    apply on either side."""
+
+    config: TransformerConfig
+    block: Any
+
+    @nn.compact
+    def __call__(self, h, e, positions):
+        cfg = self.config
+        u = nn.Dense(
+            cfg.d_model, dtype=cfg.dtype, use_bias=False, name="eh_proj"
+        )(
+            jnp.concatenate(
+                [make_norm(cfg, "enorm")(e), make_norm(cfg, "hnorm")(h)],
+                axis=-1,
+            )
+        )
+        layer = cfg.num_layers
+        g = self.block(
+            cfg, cfg.switch_moe(layer), layer, name=f"layer_{layer}"
+        )(u, positions, None)
+        return make_norm(cfg, "norm")(g)
+
+
 # Only the zero-config policies are valid by NAME — the other
 # jax.checkpoint_policies attributes are factories (they build a
 # policy from arguments) and passing one where a policy is expected
@@ -1627,19 +1727,18 @@ def _remat_ladder(config: TransformerConfig, tokens_shape):
     # A rung's width summed over the layers: blocks of unequal size
     # (``attention_kinds``: a kind's own number of query heads) are
     # each priced at their own.
-    layers = range(config.num_layers)
+    blocks = config.num_layers + config.mtp_depth
+    layers = range(blocks)
     ladder = [
         ("qkv", (FLASH_QKV, SAVED_QKV),
          sum(
              3 * config.layer_heads(at) * config.attention_head_dim
              for at in layers
          )),
-        ("mixed", (SAVED_MIXED,), config.num_layers * config.d_model),
+        ("mixed", (SAVED_MIXED,), blocks * config.d_model),
     ]
     if config.ffn == "gelu":
-        ladder.append(
-            ("ff_up", (SAVED_FF_UP,), config.num_layers * config.d_ff)
-        )
+        ladder.append(("ff_up", (SAVED_FF_UP,), blocks * config.d_ff))
     per_width = (
         config.loop_passes * tokens * jnp.dtype(config.dtype).itemsize
     )
@@ -1710,7 +1809,7 @@ def block_remat(config: TransformerConfig, tokens_shape=None):
         "remat.policy",
         saved_names=",".join(saved_names),
         policy=config.remat_policy or "none",
-        blocks=config.num_layers * config.loop_passes,
+        blocks=config.num_layers * config.loop_passes + config.mtp_depth,
         **ladder_attrs,
     )
     return nn.remat(Block, static_argnums=(), policy=policy)
@@ -1728,7 +1827,14 @@ class TransformerLM(nn.Module):
         rng=None,
         return_hidden: bool = False,
         return_exits: bool = False,
+        next_tokens=None,
     ):
+        """``next_tokens`` [b, s] (a model with ``mtp_depth``): the
+        token after each position, the prediction module's input. With
+        it the result is a pair, the trunk's and the module's (logits,
+        or with ``return_hidden`` each one's normed final state: both
+        go through the ONE output table); without it the module does
+        not run."""
         cfg = self.config
         embed = nn.Embed(
             cfg.vocab_size,
@@ -1767,12 +1873,22 @@ class TransformerLM(nn.Module):
                     cfg, cfg.switch_moe(layer), layer,
                     name=f"layer_{layer}",
                 )(x, positions, dropout_rng)
+            trunk = x  # the state the prediction module reads
             x = make_norm(cfg)(x)
+        predicted = None
+        if next_tokens is not None:
+            if not cfg.mtp_depth:
+                raise ValueError("next_tokens: the model has no mtp_depth")
+            predicted = PredictionModule(cfg, block_cls, name="mtp")(
+                trunk, embed(next_tokens), positions
+            )
         if return_hidden:
             # For losses that stream the output head themselves (the
             # chunked cross-entropy, ops/chunked_xent.py): no
             # [tokens, vocab] logits tensor is ever built.
-            return x
+            return x if predicted is None else (x, predicted)
+        if predicted is not None:
+            x = jnp.stack([x, predicted])  # both through the one table
         if not cfg.tie_embeddings:
             # An output table of its own: operands in the compute
             # dtype, accumulation and logits in float32.
@@ -1784,10 +1900,12 @@ class TransformerLM(nn.Module):
                 (cfg.vocab_size, cfg.d_model),
                 jnp.float32,
             )
-            return untied_logits(x, table)
-        # Tied output head through the embedding table keeps the only
-        # O(vocab x d_model) matmul single-sourced.
-        return embed.attend(x).astype(jnp.float32)
+            logits = untied_logits(x, table)
+        else:
+            # Tied output head through the embedding table keeps the
+            # only O(vocab x d_model) matmul single-sourced.
+            logits = embed.attend(x).astype(jnp.float32)
+        return logits if predicted is None else (logits[0], logits[1])
 
     def _looped(self, block_cls, x, positions):
         """The stack applied ``loop_passes`` times with one set of
@@ -1879,7 +1997,10 @@ def init_transformer(config: TransformerConfig, rng=None, seq_len=None):
     rng = rng if rng is not None else jax.random.key(0)
     seq_len = seq_len or min(config.max_seq_len, 128)
     dummy = jnp.zeros((1, seq_len), jnp.int32)
-    params = init_model.init(rng, dummy, train=False)["params"]
+    params = init_model.init(
+        rng, dummy, train=False,
+        next_tokens=dummy if config.mtp_depth else None,
+    )["params"]
     return model, params
 
 
@@ -1928,16 +2049,17 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
     [layers], "dropped": [layers], "rows_active": [layers],
     "rows_walked": [layers], "fell_back": [layers]}``, and
     ``"shared_rows": [layers]`` where the layers have a shared
-    expert."""
+    expert. A prediction module that ran (``mtp_depth``) is the last
+    layer."""
     sown = mutated["moe_load"]
+    blocks = [
+        sown[f"layer_{i}"] for i in range(config.num_layers)
+        if config.routed(i)
+    ]
+    if "mtp" in sown:
+        blocks.append(sown["mtp"][f"layer_{config.num_layers}"])
     return {
-        name: jnp.stack(
-            [
-                sown[f"layer_{i}"]["moe"][name][0]
-                for i in range(config.num_layers)
-                if config.routed(i)
-            ]
-        )
+        name: jnp.stack([block["moe"][name][0] for block in blocks])
         for name in (
             "held_rows", "left_out", "dropped", "rows_active",
             "rows_walked", "fell_back",
@@ -1996,10 +2118,23 @@ def routed_lm_loss_fn(
     is streamed that many rows at a time (``ops.chunked_xent.
     weighted_xent_sum``: the same operands and float32 accumulation,
     its gradients formed in the forward pass) and no ``[tokens,
-    vocab]`` array exists in the step."""
+    vocab]`` array exists in the step.
+
+    A model with a prediction module (``mtp_depth``) is handed
+    ``targets`` as the module's input and the loss is ``mean_i
+    CE(logits_i, targets_i) + mtp_loss_weight x mean_{i < s - 1}
+    CE(logits'_i, targets_{i + 1})``: the module's stream has no
+    target at a row's last position, which weighs 0. Streamed, both
+    streams' rows go through ONE call against the one table (twice
+    the rows, one accumulator of the table's gradient). Journals
+    ``mtp.schedule`` each time it is traced and returns the two means
+    as the ``mtp.loss`` counters (``main``, ``mtp``, ``micro_batches``)
+    beside ``moe.load``, whose last layer is the module's router."""
 
     sparse = sparse_layers(model.config)
     cfg = model.config
+    if cfg.mtp_depth:
+        return _predicting_lm_loss_fn(model, head_chunk_rows)
 
     def loss_fn(params, batch, rng):
         out, mutated = model.apply(
@@ -2030,6 +2165,94 @@ def routed_lm_loss_fn(
             loss = loss + selected["indexer.loss"]["loss"].mean()
             counters.update(selected)
         return loss, counters
+
+    loss_fn.has_counters = True
+    return loss_fn
+
+
+def _predicting_lm_loss_fn(model: TransformerLM, head_chunk_rows):
+    """``routed_lm_loss_fn`` of a model with a prediction module: two
+    streams of rows (the trunk's against ``targets``, the module's
+    against ``targets`` one place further on, a row's last position
+    at weight 0) through the one output table."""
+    cfg = model.config
+    if sparse_layers(cfg) or cfg.experts_total <= 0:
+        raise ValueError(
+            "mtp_depth: the loss takes routed layers and no sparse ones"
+        )
+    table_of = (
+        (lambda params: params["embed"]["embedding"]) if cfg.tie_embeddings
+        else (lambda params: params["lm_head"])
+    )
+
+    def loss_fn(params, batch, rng):
+        targets = batch["targets"]
+        rows, seq_len = targets.size, targets.shape[1]
+        trace.event(
+            "mtp.schedule",
+            depth=cfg.mtp_depth,
+            rows=rows,
+            tokens=rows,
+            head_rows=2 * rows,
+            head_calls=1,
+            shares_embedding=True,
+            shares_head=True,
+            loss_weight=cfg.mtp_loss_weight,
+            head="logits" if head_chunk_rows is None
+            else f"xent_sum, {head_chunk_rows} rows a chunk",
+        )
+        streams, mutated = model.apply(
+            {"params": params}, batch["inputs"], train=True, rng=rng,
+            return_hidden=head_chunk_rows is not None,
+            mutable=["moe_load"], next_tokens=targets,
+        )
+        # Each stream's targets and a position's weight in ITS mean.
+        further = jnp.roll(targets, -1, axis=1)  # the last: weight 0
+        weights = (
+            jnp.full((rows,), 1.0 / rows, jnp.float32),
+            jnp.broadcast_to(
+                (jnp.arange(seq_len) < seq_len - 1).astype(jnp.float32)
+                / (targets.shape[0] * (seq_len - 1)),
+                targets.shape,
+            ).reshape(-1),
+        )
+        if head_chunk_rows is None:
+            main, predicted = (
+                jnp.sum(
+                    optax.softmax_cross_entropy_with_integer_labels(
+                        logits, aim
+                    ).reshape(-1) * weight
+                )
+                for logits, aim, weight in zip(
+                    streams, (targets, further), weights
+                )
+            )
+            loss = main + cfg.mtp_loss_weight * predicted
+        else:
+            from adaptdl_tpu.ops.chunked_xent import weighted_xent_sum
+
+            loss, xent = weighted_xent_sum(
+                jnp.concatenate(
+                    [hidden.reshape(-1, cfg.d_model) for hidden in streams]
+                ),
+                table_of(params),
+                jnp.concatenate([targets.reshape(-1), further.reshape(-1)]),
+                jnp.concatenate(
+                    [weights[0], cfg.mtp_loss_weight * weights[1]]
+                ),
+                head_chunk_rows,
+            )
+            # The two means apart, for the counters alone (``xent``
+            # carries no gradient).
+            main = jnp.sum(xent[:rows] * weights[0])
+            predicted = jnp.sum(xent[rows:] * weights[1])
+        return loss, {
+            "moe.load": moe_load_counters(cfg, mutated),
+            "mtp.loss": {
+                "main": main, "mtp": predicted,
+                "micro_batches": jnp.int32(1),
+            },
+        }
 
     loss_fn.has_counters = True
     return loss_fn

@@ -552,6 +552,103 @@ def test_routed_layer_walks_the_bounded_buffer_on_v5e(
     ) * 2**30
 
 
+def test_glm_mixer_and_pieces_compile_for_v5e(v5e, chip_compile, monkeypatch):
+    """glm-4.7-flash's two shapes no other cell has (PR 56). (1) Its
+    latent-attention mixer and gradients at the cell's widths on one
+    row of 16 384 (20 heads of q / k 256 of which 64 lanes are rotated,
+    v 256, the query bottleneck of 768): the heads go two a call, ten
+    forward and ten backward kernels under the names a device trace
+    shows, and ``mla.schedule`` says so. (2) A routed layer of 8 held
+    of 64, top 4, width 1536, told ``pieces_from`` 2.5 as the
+    configuration tells it: the plan is walked in pieces of the bound
+    (24 576 rows), no array of the worst case's 69 632 is left (the
+    int32 row plan apart), where the layer's own threshold would keep
+    the one pass."""
+    import functools
+
+    from adaptdl_tpu import trace
+    from adaptdl_tpu.models.transformer import (
+        LatentAttention,
+        TransformerConfig,
+    )
+
+    gmm = importlib.import_module("adaptdl_tpu.ops.grouped_matmul")
+    moe = importlib.import_module("adaptdl_tpu.models.moe")
+    monkeypatch.setattr(gmm, "_use_interpret", lambda: False)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = TransformerConfig(
+        vocab_size=19360, num_layers=1, num_heads=20, d_model=2048,
+        d_ff=10240, dtype=jnp.bfloat16, norm="rmsnorm", norm_eps=1e-5,
+        rope=True, rope_theta=1e6, layer_types=("mla",), q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256,
+        attention_fn=functools.partial(
+            flash_mod.flash_attention, block_q=128, block_k=128
+        ),
+    )
+    mixer = LatentAttention(cfg)
+    x = arg((1, 16384, 2048), jnp.bfloat16)
+    params = jax.tree.map(
+        lambda leaf: arg(leaf.shape, leaf.dtype),
+        jax.eval_shape(
+            lambda: mixer.init(
+                jax.random.key(0), jnp.zeros((1, 128, 2048), jnp.bfloat16),
+                jnp.arange(128),
+            )["params"]
+        ),
+    )
+
+    def mixed(params, x):
+        out = mixer.apply({"params": params}, x, jnp.arange(16384))
+        return out.astype(jnp.float32).sum()
+
+    since = len(trace.snapshot_spans())
+    text = jax.jit(jax.grad(mixed, argnums=(0, 1))).lower(
+        params, x
+    ).compile().as_text()
+    attrs = [
+        r["attrs"] for r in trace.snapshot_spans()[since:]
+        if r["name"] == "mla.schedule"
+    ][-1]
+    assert (
+        attrs["heads"], attrs["heads_a_call"], attrs["qk_width"],
+        attrs["v_width"], attrs["q_lora_rank"], attrs["rotary_dims"],
+    ) == (20, 2, 256, 256, 768, 64)
+    assert len(re.findall(r"^\s*%attention[.\d]* = ", text, re.M)) == 10
+    assert len(
+        re.findall(rf"^\s*%{flash_mod.BWD_KERNEL_NAME}[.\d]* = ", text, re.M)
+    ) == 10
+
+    tokens, d, held, total, top_k, f = 16384, 2048, 8, 64, 4, 1536
+    assert moe.rows_bound(tokens, top_k, held, total, 512) == 69632
+    assert moe.rows_bound(tokens, top_k, held, total, 512, 2.5) == 24576
+    assert moe.rows_planned(tokens, top_k, held, total, 512, 2.5) == 73728
+
+    def loss(x, router_w, w_gate, w_up, w_down):
+        y, load = moe.routed_experts(
+            x, router_w, jnp.zeros((total,)), w_gate, w_up, w_down,
+            experts_total=total, first_expert=0, top_k=top_k,
+            pieces_from=2.5,
+        )
+        return y.astype(jnp.float32).sum(), load["fell_back"]
+
+    compiled = jax.jit(
+        jax.grad(loss, argnums=tuple(range(5)), has_aux=True)
+    ).lower(
+        arg((tokens, d), jnp.bfloat16), arg((d, total)),
+        arg((held, d, f)), arg((held, d, f)), arg((held, f, d)),
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text and " conditional(" not in text
+    assert re.search(r"(bf16|f32)\[24576,", text)
+    assert not re.search(r"(bf16|f32)\[(69632|73728),", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2**30
+
+
 def _router_products(text, tokens, experts):
     """How the compiler tiles each float32 "highest" product with a
     ``[tokens, experts]`` result in an optimized program: the
