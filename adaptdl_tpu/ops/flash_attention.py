@@ -35,6 +35,34 @@ says what was chosen):
   head, not once per query tile. A traced ``lax.fori_loop`` walks the
   key tiles below the causal diagonal unmasked and stops there: a
   masked tile costs neither a grid step nor a DMA nor a trace.
+- **A trip of that loop is one key tile, in updates of a block.** At
+  q/k and v widths that fill the MXU's rows (whole ``_LANES``: heads
+  of 128 and 256) an update is ``_LANES`` queries' with ``_LANES``
+  keys (``_fwd_block``): its float32 logits are sixteen vregs that
+  never leave the registers, a block's (max, sum, accumulator) live in
+  VMEM scratch, and a key tile is 64 such updates in ONE straight
+  line, eight blocks abreast that do not depend on each other — so the
+  scheduler runs one block's ``K^T Q`` and ``V P`` on the MXU under
+  another block's max / exp / sum, where the whole tile's update
+  formed 4 MiB of logits in VMEM and walked them pass by pass with
+  the MXU waiting (the bundle dump, PERF.md PR 57: the store slot
+  full and no product issued through each softmax phase). The kernel
+  alone on a v5e, bf16, causal, ms a call and share of the MXU's 197
+  TFLOP/s (PERF.md, PR 57): ``[2, 256/256, 16384]`` 2.163 -> 1.713
+  (64.5 -> 81.5%), ``[12 on 2 kv, 128/128, 16384]`` 7.496 -> 5.388
+  (55.8 -> 77.7%), ``[16, 128/128, 8192]`` 2.179 -> 1.769 (64.0 ->
+  78.9%). At any other width (64, 192) the small updates ran at HALF
+  the whole tile's speed (6.98 -> 17.7 ms at ``[64 on 16, 64/64,
+  8192]``, 3.37 -> 5.33 at ``[4, 192/128, 16384]``) and the block is
+  the tile: one update a key tile, the statistics in the loop's carry,
+  the program of before. Tried and slower (same PR): the next piece's
+  logits carried through the loop (a 2 MiB carry is a VMEM copy a
+  trip: 2.32 ms), blocks of 256 queries, a query tile of 512.
+  The call itself is jitted (``_fwd_call``): a model makes it once a
+  layer and run of heads, and every call after the first of a
+  signature finds the 64-update kernel traced, and lowered once a
+  program (one step program of glm-4.7-flash traces and lowers in
+  12.8 s for 18.2).
 - **Tiles are runs of the caller's tiles.** ``block_q`` / ``block_k``
   are the caller's granularity and the divisibility contract; the
   kernel fuses adjacent ones (runs of what divides both, where they
@@ -43,9 +71,10 @@ says what was chosen):
   MXU's latency (measured, PERF.md PR 25: 2.7 ms a call at
   128 x 128, 0.55 ms as shipped). What the larger
   tile would waste above the diagonal is won back inside it: the tile
-  the diagonal crosses is done in pieces of ``_DIAG_ROWS`` (512) keys,
-  each multiplied only with the queries at or after it, and only the
-  corner block of a piece is masked.
+  the diagonal crosses is done in pieces of ``_DIAG_ROWS`` (512) keys
+  (of a block, where that is smaller), each multiplied only with the
+  queries at or after it, and only the corner block of a piece is
+  masked.
 - **Logits are held** ``[keys, queries]``: a query's statistics run
   along lanes, so max and sum reduce across sublanes (plain VPU work,
   not lane rotations), the rescale of the accumulator ``[head_dim,
@@ -196,6 +225,13 @@ _VMEM_LIMIT_BWD = 64 * 2**20
 # (16, 12, 1024, 64) bf16, forward ms a call (PERF.md, PR 25): tile
 # 1024 in pieces of 512 0.55, of 256 0.57 (and a quarter more to trace
 # and lower), of 128 0.60, unsplit 0.85; tile 512 0.67; 128 x 128 2.7.
+# Again by the device trace, the kernel alone (PERF.md, PR 57): pieces
+# of 512 0.407, of 256 0.452, of 128 0.463; at (1, 64 on 16, 8192, 64)
+# 6.98 / 7.07 / 7.11; a tile of 512 0.558 and 8.92. Where a head's
+# widths fill the MXU the forward's updates are smaller than either
+# (``_fwd_block``); a tile of 512 there is a third slower than one of
+# 1024 (2.32 for 1.71 ms at [2, 256, 16384]: half as many updates in
+# a trip's straight line).
 _TILE_ROWS = 1024
 _DIAG_ROWS = 512
 # The backward's updates cover this many keys at most, in the diagonal
@@ -214,9 +250,12 @@ _LANES = 128
 # tests) asserts this string is present.
 MOSAIC_CALL = "tpu_custom_call"
 # The backward kernel's name in a lowered program and in a device
-# trace (``%flash_bwd.<n>``); the forward is unnamed inside the model's
-# ``attention`` scope, and the benchmark finds it as ``%attention.<n>``.
+# trace (``%flash_bwd.<n>``), and the scope the forward's call stands
+# in: a custom call is named after the innermost scope, the model's
+# ``attention`` before the call had one of its own, and the benchmark
+# finds the forward as ``%attention.<n>``.
 BWD_KERNEL_NAME = "flash_bwd"
+FWD_KERNEL_NAME = "attention"
 # What the forward rule names (``jax.ad_checkpoint.checkpoint_name``)
 # of what it produces: the kernel's output and log-sum-exp, as the
 # backward reads them. A remat'd block saves them by these names
@@ -352,6 +391,7 @@ def _fwd_kernel(
     causal: bool,
     scale: float,
     diag: int,
+    block: int,
     num_chunks: int,
     with_lse: bool,
 ):
@@ -362,25 +402,36 @@ def _fwd_kernel(
     along lanes too. A traced loop walks the chunk's key tiles below
     the causal diagonal unmasked; the tile the diagonal crosses comes
     last, in ``diag``-key pieces, each only for the queries at or
-    after it, and only its corner block is masked."""
+    after it, and only its corner block is masked.
+
+    An update is ``block`` queries' with ``block`` keys (``diag`` in
+    the diagonal tile). Where ``block`` is the tile, a key tile is one
+    update and the statistics are the loop's carry. Where it is a
+    piece of the tile (``_fwd_block``), a key tile is a straight line
+    of small updates, those of different blocks independent of each
+    other: one block's products run on the MXU under another's max /
+    exp / sum, a block's logits stay in registers, and the statistics
+    stay in VMEM scratch, each update reading and writing its
+    block's."""
     tile = q_ref.shape[2]
     v_dim = v_ref.shape[1]  # the accumulator's and the output's rows
     chunk_tiles = k_ref.shape[2] // tile
+    blocks = range(tile // block)
     rest = list(rest)
     lse_ref = rest.pop(0) if with_lse else None
-    state = rest  # VMEM scratch (m, l, acc) across chunks, if chunked
+    state = rest  # VMEM scratch (m, l, acc): across chunks, or blocks
     qi, ci = pl.program_id(1), pl.program_id(2)
     first_tile = ci * chunk_tiles  # of this chunk, among all key tiles
     # The last chunk with keys this query tile sees: the diagonal's.
     last = qi // chunk_tiles if causal else num_chunks - 1
 
-    def update(keys, queries, carry, mask=None):
+    def update(kv, queries, carry, mask=None):
         """One online-softmax update of the statistics and accumulator
         of ``queries`` (a static range of the tile's positions) with
-        ``keys`` (positions of the chunk). Operands in the input
+        the keys whose K and V are ``kv``. Operands in the input
         dtype."""
         m_prev, l_prev, acc = carry  # [1, n], [1, n], [d, n]
-        q, k, v = q_ref[0, :, queries], k_ref[0, :, keys], v_ref[0, :, keys]
+        q, (k, v) = q_ref[0, :, queries], kv
         s = scale * lax.dot_general(
             k, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -396,66 +447,123 @@ def _fwd_kernel(
         )
         return m_next, l_next, acc
 
+    def at_keys(keys):
+        """K and V of ``keys`` (positions of the chunk), read once for
+        all the blocks they update."""
+        return k_ref[0, :, keys], v_ref[0, :, keys]
+
+    def update_block(kv, b, carry, lo=0, mask=None):
+        """``update`` of block ``b``'s queries from its ``lo``-th on.
+        Several blocks: in place, in scratch. One: in ``carry``."""
+        queries = slice(b * block + lo, (b + 1) * block)
+        if len(blocks) > 1:
+            stats = update(
+                kv, queries, tuple(ref[:, queries] for ref in state), mask
+            )
+            for ref, value in zip(state, stats):
+                ref[:, queries] = value
+            return carry
+        done = tuple(x[:, :lo] for x in carry)
+        live = update(kv, queries, tuple(x[:, lo:] for x in carry), mask)
+        return tuple(
+            jnp.concatenate(pair, axis=1) if lo else pair[1]
+            for pair in zip(done, live)
+        )
+
     def whole_tile(t, carry):
-        keys = pl.ds(pl.multiple_of(t * tile, tile), tile)
-        return update(keys, slice(None), carry)
+        for at in range(0, tile, block):
+            kv = at_keys(pl.ds(pl.multiple_of(t * tile + at, block), block))
+            for b in blocks:
+                carry = update_block(kv, b, carry)
+        return carry
 
     corner_mask = functools.partial(_corner_mask, diag=diag)
 
     def diagonal_tile(carry):
         start = pl.multiple_of((qi - first_tile) * tile, tile)
-        for lo in range(0, tile, diag):
-            done = tuple(x[:, :lo] for x in carry)
-            live = update(
-                pl.ds(start + lo, diag),
-                slice(lo, None),
-                tuple(x[:, lo:] for x in carry),
-                corner_mask,
-            )
-            carry = tuple(
-                jnp.concatenate(pair, axis=1) if lo else pair[1]
-                for pair in zip(done, live)
-            )
+        for at in range(0, tile, diag):
+            kv = at_keys(pl.ds(start + at, diag))
+            for b in blocks[at // block:]:
+                # The block's queries at or after the piece: all of
+                # them but in the block the diagonal crosses.
+                carry = update_block(
+                    kv, b, carry, max(at - b * block, 0),
+                    corner_mask if b == at // block else None,
+                )
         return carry
 
     def finish(carry):
-        m, l, acc = diagonal_tile(carry) if causal else carry
+        carry = diagonal_tile(carry) if causal else carry
+        # Several blocks carry nothing: their statistics are the scratch.
+        m, l, acc = carry or (ref[...] for ref in state)
         l = jnp.maximum(l, 1e-30)
         o_ref[0] = (acc / l).astype(o_ref.dtype)
         if with_lse:
             lse_ref[0] = m + jnp.log(l)
+
+    def clear():
+        for ref, value in zip(state, (NEG_INF, 0.0, 0.0)):
+            ref[...] = jnp.full_like(ref, value)
 
     # (A ``when`` around the whole body even where it always holds,
     # K/V resident: interpret mode under a shard_map cannot run block
     # accesses in a kernel's own straight line, only inside regions.)
     @pl.when(ci <= last)
     def _chunk():
-        if num_chunks == 1:
+        if num_chunks > 1:
+            pl.when(ci == 0)(clear)
+        elif len(blocks) > 1:
+            clear()
+        if len(blocks) > 1:
+            carry = ()  # nothing but the scratch
+        elif num_chunks > 1:
+            carry = tuple(ref[...] for ref in state)
+        else:
             carry = (
                 jnp.full((1, tile), NEG_INF, jnp.float32),
                 jnp.zeros((1, tile), jnp.float32),
                 jnp.zeros((v_dim, tile), jnp.float32),
             )
-        else:
-
-            @pl.when(ci == 0)
-            def _init():
-                for ref, value in zip(state, (NEG_INF, 0.0, 0.0)):
-                    ref[...] = jnp.full_like(ref, value)
-
-            carry = tuple(ref[...] for ref in state)
         # Key tiles of the chunk that every query of the tile sees
         # whole: those before the diagonal's, or all.
-        whole = chunk_tiles
+        below = chunk_tiles
         if causal:
-            whole = jnp.minimum(qi - first_tile, chunk_tiles)
-        carry = lax.fori_loop(0, whole, whole_tile, carry)
+            below = jnp.minimum(qi - first_tile, chunk_tiles)
+        carry = lax.fori_loop(0, below, whole_tile, carry)
         if num_chunks == 1:
             finish(carry)
         else:
             for ref, value in zip(state, carry):
                 ref[...] = value
             pl.when(ci == last)(functools.partial(finish, carry))
+
+
+def _fwd_block(tile: int, head_dim: int, v_dim: int) -> int:
+    """Queries (= keys) of one forward update. Widths that fill the
+    MXU's rows, multiples of ``_LANES``: ``_LANES``, so a block's
+    logits are sixteen vregs that never leave the registers and a key
+    tile is ``(tile / _LANES) ** 2`` updates in one straight line.
+    Any other width: the tile, the update of before (measured, PERF.md
+    PR 57: at head 64 and at 192 the small updates run at half the
+    speed of the whole tile's)."""
+    widths_fill = head_dim % _LANES == 0 and v_dim % _LANES == 0
+    return _LANES if widths_fill and tile % _LANES == 0 else tile
+
+
+def _fwd_updates(
+    sched: _Schedule, block: int, seq_len: int, causal: bool
+) -> int:
+    """Softmax updates one (batch, head) makes forward: ``block``
+    queries' with ``block`` keys in a tile every query sees whole,
+    with ``diag`` keys in the tile the diagonal crosses, there only
+    the blocks at or after the piece."""
+    num_q, across = seq_len // sched.tile, sched.tile // block
+    if not causal:
+        return (num_q * across) ** 2
+    crossed = sum(
+        across - at // block for at in range(0, sched.tile, sched.diag)
+    )
+    return across**2 * num_q * (num_q - 1) // 2 + num_q * crossed
 
 
 def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
@@ -468,9 +576,12 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         seq_len, head_dim, q.dtype.itemsize, block_q, block_k,
         v_dim=v_dim,
     )
+    block = _fwd_block(sched.tile, head_dim, v_dim)
+    # The diagonal tile's pieces are no larger than a block.
+    sched = sched._replace(diag=min(sched.diag, block))
     tile, diag, chunk_k = sched
     num_chunks = seq_len // chunk_k
-    grid = (bh, seq_len // tile, num_chunks)
+    updates = _fwd_updates(sched, block, seq_len, causal)
     trace.event(
         "flash.schedule",
         seq_len=seq_len,
@@ -481,17 +592,52 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
         kv_resident=num_chunks == 1,
         tile=tile,
         diag_tile=diag,
-        grid_steps=math.prod(grid),
+        grid_steps=bh * (seq_len // tile) * num_chunks,
         k_tiles_visited=_tiles_visited(sched, seq_len, causal),
         k_tiles_total=(seq_len // diag) ** 2,
         kv_group=group,
         kv_heads=bh // group,
+        # An update: ``piece`` queries' with ``piece`` keys; a key
+        # tile's stand ``pieces_in_flight`` abreast in one straight
+        # line, independent of each other, and an update is
+        # ``overlapped`` where another block's stands beside it.
+        piece=block,
+        pieces_in_flight=tile // block,
+        updates_overlapped=updates if block < tile else 0,
+        updates_total=updates,
     )
+    return _fwd_call(
+        q, k, v, causal=causal, scale=scale, tile=tile, diag=diag,
+        block=block, chunk_k=chunk_k, with_lse=with_lse,
+        interpret=_use_interpret(),
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "causal", "scale", "tile", "diag", "block", "chunk_k", "with_lse",
+        "interpret",
+    ),
+)
+def _fwd_call(
+    q, k, v, *, causal, scale, tile, diag, block, chunk_k, with_lse,
+    interpret,
+):
+    """The forward kernel's call on a schedule, jitted: a model calls
+    it once a layer and run of heads, a hundred times a step program
+    (glm-4.7-flash), and every call after the first of a signature
+    finds the kernel traced, and lowered once a program."""
+    bh, head_dim, seq_len = q.shape
+    v_dim = v.shape[1]
+    group = _kv_group(q, k)
+    num_chunks = seq_len // chunk_k
     kernel = functools.partial(
         _fwd_kernel,
         causal=causal,
         scale=scale,
         diag=diag,
+        block=block,
         num_chunks=num_chunks,
         with_lse=with_lse,
     )
@@ -529,25 +675,29 @@ def _fwd_pallas(q, k, v, causal, scale, block_q, block_k, with_lse):
             jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32, vma=vma)
         )
     scratch_shapes = []
-    if num_chunks > 1:
+    if num_chunks > 1 or block < tile:
         scratch_shapes = [
             pltpu.VMEM((1, tile), jnp.float32),  # running max
             pltpu.VMEM((1, tile), jnp.float32),  # running sum
             pltpu.VMEM((v_dim, tile), jnp.float32),  # accumulator
         ]
-    out, *lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[q_spec, kv_spec, v_spec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=_use_interpret(),
-    )(q, k, v)
+    # The scope is the custom call's name in a compiled program and in
+    # a device trace (``%attention.<n>``), under a model's own scopes
+    # or none: the benchmark's readers find the forward by it.
+    with jax.named_scope(FWD_KERNEL_NAME):
+        out, *lse = pl.pallas_call(
+            kernel,
+            grid=(bh, seq_len // tile, num_chunks),
+            in_specs=[q_spec, kv_spec, v_spec],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch_shapes,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_LIMIT,
+            ),
+            interpret=interpret,
+        )(q, k, v)
     return out, (lse[0] if with_lse else None)
 
 

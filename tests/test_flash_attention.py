@@ -260,6 +260,164 @@ def test_bf16_gradients_match_plain_attention(monkeypatch, case, reference):
         assert _relative(got_x, want_x) < bound, f"d{name}"
 
 
+# ---- the forward's updates: squares of a block, or the tile (PR 57) ---
+#
+# Where a head's widths are whole ``_LANES`` the forward's updates are
+# ``_LANES`` queries' with ``_LANES`` keys, a key tile of them in one
+# straight line, the statistics a block in VMEM scratch; any other
+# width updates the whole tile at once. ``_LANES`` 8 here: tiles of 16
+# in blocks of 8 at widths 16 and 24, the tile whole at 12.
+
+BLOCKS_OF_8 = {"_TILE_ROWS": 16, "_LANES": 8, "_DIAG_ROWS": 8}
+
+
+def _kv_budget(keys, width=16, itemsize=4):
+    """``_KV_VMEM_BUDGET`` for chunks of ``keys``: K and V of them at
+    ``width``, double-buffered."""
+    return 2 * 2 * keys * width * itemsize
+
+
+# name -> (seq, q/k width, v width, group, causal, dtype, constants,
+# (block, chunks)).
+FORWARD_UPDATES = {
+    # One tile a row: the diagonal tile alone, no trip of the loop.
+    "blocks_one_tile": (16, 16, 16, 1, True, "float32", {}, (8, 1)),
+    # Two tiles: one trip, then the diagonal tile.
+    "blocks_one_trip": (32, 16, 16, 1, True, "float32", {}, (8, 1)),
+    "blocks_many_trips": (64, 16, 16, 1, True, "float32", {}, (8, 1)),
+    "blocks_bidirectional": (32, 16, 16, 1, False, "float32", {}, (8, 1)),
+    # K and V past the budget: chunks of two tiles, and of one.
+    "blocks_k_blocked": (
+        64, 16, 16, 1, True, "float32",
+        {"_KV_VMEM_BUDGET": _kv_budget(32)}, (8, 2),
+    ),
+    "blocks_chunks_of_a_tile": (
+        64, 16, 16, 1, True, "float32",
+        {"_KV_VMEM_BUDGET": _kv_budget(16)}, (8, 4),
+    ),
+    "blocks_k_blocked_bidirectional": (
+        64, 16, 16, 1, False, "float32",
+        {"_KV_VMEM_BUDGET": _kv_budget(32)}, (8, 2),
+    ),
+    "blocks_group_3": (32, 16, 16, 3, True, "float32", {}, (8, 1)),
+    "blocks_v_wider": (32, 16, 24, 1, True, "float32", {}, (8, 1)),
+    "blocks_bfloat16": (32, 16, 16, 1, True, "bfloat16", {}, (8, 1)),
+    "blocks_k_blocked_group_3_bfloat16": (
+        64, 16, 16, 3, True, "bfloat16",
+        {"_KV_VMEM_BUDGET": _kv_budget(32, itemsize=2)}, (8, 2),
+    ),
+    # A width that is no whole ``_LANES``: the tile's update of before.
+    "tile_head_12": (32, 12, 12, 1, True, "float32", {}, (16, 1)),
+    "tile_v_narrower_group_3": (
+        32, 16, 12, 3, True, "float32", {}, (16, 1)
+    ),
+    "tile_k_blocked_bfloat16": (
+        64, 12, 12, 1, True, "bfloat16",
+        {"_KV_VMEM_BUDGET": _kv_budget(32, width=12, itemsize=2)}, (16, 2),
+    ),
+}
+
+
+def _plain(q, k, v, g, causal):
+    """Masked softmax attention and its gradients for the cotangent
+    ``g`` in numpy float32, k and v repeated for their groups (dK and
+    dV summed over a group's query heads) -> (out, log-sum-exp a row,
+    (dq, dk, dv))."""
+    q, k, v, g = (np.asarray(x, np.float32) for x in (q, k, v, g))
+    group, scale = q.shape[1] // k.shape[1], q.shape[-1] ** -0.5
+    k, v = (np.repeat(x, group, axis=1) for x in (k, v))
+    logits = np.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        seq = q.shape[2]
+        logits = np.where(np.tril(np.ones((seq, seq), bool)), logits, -np.inf)
+    top = logits.max(-1, keepdims=True)
+    lse = top + np.log(np.exp(logits - top).sum(-1, keepdims=True))
+    p = np.exp(logits - lse)
+    out = np.einsum("bhqk,bhkd->bhqd", p, v)
+    dp = np.einsum("bhqd,bhkd->bhqk", g, v)
+    ds = p * (dp - (dp * p).sum(-1, keepdims=True))
+
+    def a_kv_head(x):
+        batch, heads, seq, width = x.shape
+        return x.reshape(batch, heads // group, group, seq, width).sum(2)
+
+    return out, lse[..., 0], (
+        np.einsum("bhqk,bhkd->bhqd", ds, k) * scale,
+        a_kv_head(np.einsum("bhqk,bhqd->bhkd", ds, q) * scale),
+        a_kv_head(np.einsum("bhqk,bhqd->bhkd", p, g)),
+    )
+
+
+@pytest.mark.parametrize("case", list(FORWARD_UPDATES))
+def test_forward_updates_match_plain_attention(monkeypatch, case):
+    """Output (with and without the log-sum-exp output), log-sum-exp
+    and the three gradients against plain attention, in blocks and
+    whole tiles, resident and K-blocked, and the ``flash.schedule``
+    event's account of the updates."""
+    seq, d, dv, group, causal, dtype, constants, engaged = (
+        FORWARD_UPDATES[case]
+    )
+    for name, value in {**BLOCKS_OF_8, **constants}.items():
+        monkeypatch.setattr(flash_mod, name, value)
+    rng = np.random.default_rng(57)
+
+    def normal(heads, width):
+        x = rng.normal(size=(1, heads, seq, width)).astype(np.float32)
+        return jnp.asarray(x).astype(dtype)
+
+    q, k, v = normal(2 * group, d), normal(2, d), normal(2, dv)
+    g = normal(2 * group, dv)  # the cotangent
+    # The kernel without a log-sum-exp output, then with one, and the
+    # backward on what that left.
+    primal = flash_attention(q, k, v, causal, None, 16, 16)
+    out, residuals = flash_mod._flash_vjp_fwd(q, k, v, causal, None, 16, 16)
+    got = flash_mod._flash_vjp_bwd(causal, None, 16, 16, None, residuals, g)
+    attrs = _schedule_events(
+        seq_len=seq, head_dim=d, dtype=dtype, causal=causal
+    )[-1]
+    block, chunks = engaged
+    tile = 16
+    assert (attrs["tile"], attrs["piece"]) == (tile, block)
+    assert attrs["kv_resident"] == (chunks == 1)
+    assert attrs["grid_steps"] == 2 * group * (seq // tile) * chunks
+    assert attrs["pieces_in_flight"] == tile // block
+    # Every update counted from the walk itself: a key tile below the
+    # diagonal is (tile / block) ** 2 of them, the diagonal tile's
+    # pieces take the blocks at or after them.
+    across, tiles = tile // block, seq // tile
+    pieces = range(0, tile, attrs["diag_tile"])
+    updates = sum(
+        across**2 * (qi if causal else tiles)
+        + (sum(across - at // block for at in pieces) if causal else 0)
+        for qi in range(tiles)
+    )
+    assert attrs["updates_total"] == updates
+    assert attrs["updates_overlapped"] == (updates if block < tile else 0)
+    if block < tile:  # squares of a block: the blocks of logits visited
+        assert updates == attrs["k_tiles_visited"]
+    want, lse, want_grads = _plain(q, k, v, g, causal)
+    if dtype == "float32":
+        for x in (primal, out):
+            np.testing.assert_allclose(
+                np.asarray(x), np.asarray(want), atol=2e-5, rtol=2e-5
+            )
+        for got_x, want_x, name in zip(got, want_grads, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(got_x), np.asarray(want_x), atol=5e-5,
+                rtol=5e-4, err_msg=f"d{name}",
+            )
+    else:
+        for x in (primal, out):
+            assert x.dtype == jnp.bfloat16
+            assert _relative(x, want) < 4.5e-3
+        for got_x, want_x, name in zip(got, want_grads, "qkv"):
+            assert _relative(got_x, want_x) < 4.5e-3, f"d{name}"
+    np.testing.assert_allclose(
+        np.asarray(residuals[-1]).reshape(lse.shape), lse,
+        atol=2e-5 if dtype == "float32" else 2e-2, rtol=2e-5,
+    )
+
+
 # ---- fewer kv heads than query heads (PR 55) --------------------------
 #
 # k and v go in ``kv_heads`` wide and the kernels index them by
@@ -537,6 +695,16 @@ def schedule_case(request, monkeypatch):
     return shape, block_q, block_k, expected
 
 
+def _forward(expected, q):
+    """The forward's schedule beside the backward's ``expected``:
+    where the head's width is whole ``_LANES`` (as patched) its
+    updates are squares of ``_LANES`` and so are the diagonal tile's
+    pieces; any other width leaves it the backward's."""
+    tile, diag, resident = expected
+    block = flash_mod._fwd_block(tile, q.shape[3], q.shape[3])
+    return tile, min(diag, block), resident
+
+
 def _engaged(name, q, causal):
     """(tile, diag_tile, kv_resident) of the newest ``name`` event of
     a call with ``q``'s shape and dtype."""
@@ -556,7 +724,7 @@ def test_forward_across_schedules(schedule_case, causal):
     shape, block_q, block_k, expected = schedule_case
     q, k, v = _qkv(seed=5, **shape)
     out = flash_attention(q, k, v, causal, None, block_q, block_k)
-    assert _engaged("flash.schedule", q, causal) == expected
+    assert _engaged("flash.schedule", q, causal) == _forward(expected, q)
     np.testing.assert_allclose(
         np.asarray(out),
         np.asarray(_dense(q, k, v, causal)),
@@ -583,8 +751,9 @@ def test_gradients_across_schedules(schedule_case, causal):
         argnums=(0, 1, 2),
     )(q, k, v)
     # Both passes run the schedule the shape chose (as shipped the
-    # backward's updates cover 256 keys, the forward's 512).
-    assert _engaged("flash.schedule", q, causal) == expected
+    # backward's updates cover 256 keys, the forward's 512 at this
+    # width).
+    assert _engaged("flash.schedule", q, causal) == _forward(expected, q)
     tile, diag, resident = expected
     if expected == (1024, 512, True):
         diag = 256
@@ -685,7 +854,8 @@ TILE_128 = {"_TILE_ROWS": 128, "_DIAG_ROWS": 128, "_BWD_DIAG_ROWS": 128}
             CELL, jnp.bfloat16, True, {},
             dict(kv_resident=True, tile=1024, diag_tile=512,
                  k_tiles_visited=3, k_tiles_total=4, grid_steps=192,
-                 layout="bhds"),
+                 layout="bhds", piece=1024, pieces_in_flight=1,
+                 updates_total=2, updates_overlapped=0),
         ),
         (
             # Any head count, any head width: one layout.
@@ -716,9 +886,14 @@ TILE_128 = {"_TILE_ROWS": 128, "_DIAG_ROWS": 128, "_BWD_DIAG_ROWS": 128}
         (
             # Long context as shipped: 8k keys of 32k resident.
             (1, 1, 32768, 128), jnp.bfloat16, True, {},
-            dict(kv_resident=False, tile=1024, diag_tile=512,
-                 k_tiles_visited=4 * 32 * 31 // 2 + 32 * 3,
-                 k_tiles_total=64 * 64, grid_steps=32 * 4),
+            # At head 128 the forward's updates are squares of 128:
+            # 64 a key tile, 36 in the tile the diagonal crosses.
+            dict(kv_resident=False, tile=1024, diag_tile=128,
+                 k_tiles_visited=64 * 32 * 31 // 2 + 32 * 36,
+                 k_tiles_total=256 * 256, grid_steps=32 * 4,
+                 piece=128, pieces_in_flight=8,
+                 updates_total=64 * 32 * 31 // 2 + 32 * 36,
+                 updates_overlapped=64 * 32 * 31 // 2 + 32 * 36),
         ),
     ],
 )
