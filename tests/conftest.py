@@ -81,6 +81,48 @@ def _compile_cache_config_restored():
         compilation_cache.reset_cache()
 
 
+# ---- the chip's compiler without a chip -------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A DESCRIBED (not attached) v5e:2x2 for the TPU's own compiler
+    (``tests/test_chip_compile*.py``, ``tests/test_lowered_step_diff.py``)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+
+
+@pytest.fixture
+def chip_compile(monkeypatch):
+    """Steer the code the way the chip would (the program itself asks
+    ``jax.default_backend()``, which is the CPU here), and keep the
+    persistent compile cache out of it: a compile for a described chip
+    is written to the cache but cannot be read back without a chip, so
+    the next one would warn and compile again."""
+    import importlib
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # ``import adaptdl_tpu.ops.flash_attention as m`` yields the FUNCTION
+    # (the package re-exports it under the module's name).
+    flash_mod = importlib.import_module("adaptdl_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash_mod, "_use_interpret", lambda: False)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
 # ---- per-test resource-leak canary ----------------------------------
 #
 # The GC14xx lifecycle passes prove every spawn in adaptdl_tpu/ has a

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import rel
 
 from adaptdl_tpu import trace
 from adaptdl_tpu.ops import kda as kda_op
@@ -105,11 +106,6 @@ def _cotangents(operands):
     ]
 
 
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
-
-
 def _rms(got, want):
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     return float(
@@ -150,10 +146,10 @@ def test_head_kernels_equal_the_own_work_on_a_broadcast_decay(
                                   (head, channel, xla))):
         assert got.dtype == other.dtype and got.shape == want.shape
         if dtype == "float32":
-            assert _rel(got, want) < 2e-5 and _rel(got, other) < 2e-5
+            assert rel(got, want) < 2e-5 and rel(got, other) < 2e-5
         else:
             assert _rms(got, want) <= 1.02 * _rms(other, want) + 1e-6
-            assert _rel(got, want) < 2e-2
+            assert rel(got, want) < 2e-2
     assert head[1][3].shape == operands[3].shape  # dg: a head's
 
 
@@ -183,7 +179,7 @@ def test_kda_with_a_decay_a_head_runs_the_head_kernels(monkeypatch):
     got, want = weighted(run), weighted(kda_op.kda_recurrent)
     assert got[1][3].shape == args[3].shape
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert a.shape == b.shape and _rel(a, b) < 2e-5
+        assert a.shape == b.shape and rel(a, b) < 2e-5
     attrs = [
         r for r in trace.snapshot_spans()[since:]
         if r["name"] == "kda.schedule"
@@ -214,7 +210,7 @@ def test_head_kernels_survive_a_decay_no_float32_inverse_holds():
     # (At e^-40 a token what is left of a product is differences of
     # terms many times its size, in either program.)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert _rel(a, b) < 1e-3
+        assert rel(a, b) < 1e-3
 
 
 def _kernel_names(fn, *args):

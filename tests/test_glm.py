@@ -12,14 +12,14 @@ the program of before. (The whole model, its gradients and a job:
 import dataclasses
 import functools
 import hashlib
-import json
-import os
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from configurations import rel
 
 from adaptdl_tpu import trace
 from adaptdl_tpu.flops import transformer_train_flops
@@ -34,40 +34,8 @@ from adaptdl_tpu.models.transformer import (
 )
 from adaptdl_tpu.ops.flash_attention import flash_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "glm-4.7-flash"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "num_attention_heads": 4, "num_key_value_heads": 4,
-    "q_lora_rank": 16, "kv_lora_rank": 12, "qk_nope_head_dim": 12,
-    "qk_rope_head_dim": 4, "v_head_dim": 16,
-    "router_width": 16, "experts_held": 4, "n_routed_experts": 4,
-    "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
-    "head_chunk_rows": 32, "compute_dtype": "float32",
-}
 FLASH = functools.partial(flash_attention, block_q=16, block_k=16)
-
-
-@functools.cache
-def _config_module(name=NAME):
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", name + ".py")
-    )
-
-
-def _sizes(**changes):
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
 def _events(name, since=0):
@@ -102,7 +70,7 @@ def test_mixer_equals_the_reference(attn):
     4-wide part and on the ONE shared key part, 12 nope lanes untouched,
     v 16 wide: output and the gradient of every leaf and of the input,
     through plain attention and through the flash kernels."""
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     cfg = config.model_config(sizes, attn)
     params, x, positions = _mixer_case(cfg)
     layer = config.mla_weights(params)
@@ -121,8 +89,8 @@ def test_mixer_equals_the_reference(attn):
     (want_w, want_x), want = jax.jit(
         jax.grad(reference, argnums=(0, 1), has_aux=True)
     )(layer, x)
-    assert _rel(got, want) < 2e-5
-    assert _rel(got_x, want_x) < 5e-5
+    assert rel(got, want) < 2e-5
+    assert rel(got_x, want_x) < 5e-5
     errors = config.mixer_leaf_errors(got_w, want_w)
     assert set(errors) == {
         "w_qa", "q_norm", "w_qb", "w_kva", "kv_norm", "w_kvb", "w_out"
@@ -134,7 +102,8 @@ def test_mixer_equals_the_reference(attn):
 def test_the_rotary_is_in_the_numbers(variant):
     """A reference without the turn, or with its angles in bfloat16,
     is another function: neither hides inside a tolerance."""
-    config, sizes = _config_module(), _sizes(rope_theta=100.0)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(NAME, rope_theta=100.0)
     cfg = config.model_config(sizes)
     params, x, _ = _mixer_case(cfg)
     layer = config.mla_weights(params)
@@ -148,7 +117,7 @@ def test_only_the_rope_part_turns_and_the_key_part_is_shared():
     the others are not; without it the mixer is the one of before
     (``no_rotary``). The nope lanes never see a position: a model whose
     rope part is all zeros gives the same numbers with and without."""
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     cfg = config.model_config(sizes)
     params, x, positions = _mixer_case(cfg)
     turned = LatentAttention(cfg).apply({"params": params}, x, positions)
@@ -156,10 +125,10 @@ def test_only_the_rope_part_turns_and_the_key_part_is_shared():
         {"params": params}, x, positions
     )
     layer = config.mla_weights(params)
-    assert _rel(
+    assert rel(
         plain, config.reference_mixer(layer, x, sizes, "no_rotary")
     ) < 2e-5
-    assert _rel(turned, plain) > 1e-3
+    assert rel(turned, plain) > 1e-3
     np.testing.assert_allclose(turned[:, 0], plain[:, 0], rtol=1e-5, atol=1e-6)
     # Zero the rope part of q_b's and kv_a's columns: positions vanish.
     nope, rank = sizes["qk_nope_head_dim"], sizes["kv_lora_rank"]
@@ -179,7 +148,7 @@ def test_without_the_bottleneck_it_is_kimis_mixer():
     """``q_lora_rank`` 0 and ``rope`` False: the parameter tree has
     ``q`` (no ``q_a`` / ``q_norm`` / ``q_b``) and the numbers are those
     of kimi-linear-48b-a3b's reference."""
-    kimi = _config_module("kimi-linear-48b-a3b")
+    kimi = configurations.module("kimi-linear-48b-a3b")
     cfg = TransformerConfig(
         vocab_size=64, num_layers=1, num_heads=4, d_model=32, d_ff=48,
         dtype=jnp.float32, norm="rmsnorm", rope=False,
@@ -195,7 +164,7 @@ def test_without_the_bottleneck_it_is_kimis_mixer():
             {"kv_lora_rank": 12, "qk_nope_head_dim": 12,
              "rms_norm_eps": cfg.norm_eps},
         )
-    assert _rel(got, want) < 2e-5
+    assert rel(got, want) < 2e-5
 
 
 def _kimi_form_lowered() -> str:
@@ -239,7 +208,7 @@ def test_kimis_form_lowers_to_the_program_of_before():
 
 
 def test_mla_schedule_says_the_bottleneck_and_the_rotated_lanes():
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     cfg = config.model_config(sizes)
     params, x, positions = _mixer_case(cfg)
     since = len(trace.snapshot_spans())
@@ -286,8 +255,8 @@ def test_config_refuses_at_build_with_the_fields_name(changes, match):
 def _tiny_model():
     """Two trunk layers (dense, routed) and the module, rows of 32;
     initialised under ``jit`` (an eager init is most of a test)."""
-    config = _config_module()
-    sizes = _sizes(num_hidden_layers=2, sequence_length=32)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(NAME, num_hidden_layers=2, sequence_length=32)
     cfg = config.model_config(sizes)
     model = TransformerLM(cfg)
     dummy = jnp.zeros((1, 32), jnp.int32)
@@ -494,7 +463,7 @@ def test_the_shares_add_up_to_the_whole_layer(where):
         {**whole, "bias": jnp.zeros((16,))}, ffn_in,
         {**sizes, "first_expert": 0}, variant="no_scale",
     )
-    assert _rel(unscaled, want) > 0.1
+    assert rel(unscaled, want) > 0.1
 
 
 # ---- the count of operations ---------------------------------------------------
@@ -505,9 +474,8 @@ def test_the_module_and_the_second_head_pass_are_counted():
     sizes is the benchmark's own count (``forward_flops_per_token``, the
     numbers of ISSUE 56: 1472 MFLOP a token forward, attention 839, the
     module 336), three times forward; without the module 336 fewer."""
-    config = _config_module()
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
+    config = configurations.module(NAME)
+    sizes = configurations.published(NAME)
     parts = config.forward_flops_per_token(sizes)
     forward = sum(parts.values())
     assert forward / 1e6 == pytest.approx(1471.9, abs=0.1)

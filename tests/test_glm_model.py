@@ -6,52 +6,18 @@ of every leaf (the two shared tables' among them), the cell's own
 loss. (The mixer, the module, the loss and the share:
 ``tests/test_glm.py``.)"""
 
-import functools
-import json
-import os
 import re
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import loader_stub
 
 from adaptdl_tpu import trace
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "glm-4.7-flash"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "num_attention_heads": 4, "num_key_value_heads": 4,
-    "q_lora_rank": 16, "kv_lora_rank": 12, "qk_nope_head_dim": 12,
-    "qk_rope_head_dim": 4, "v_head_dim": 16,
-    "router_width": 16, "experts_held": 4, "n_routed_experts": 4,
-    "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
-    "head_chunk_rows": 32, "compute_dtype": "float32",
-}
-
-
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", NAME + ".py")
-    )
-
-
-def _sizes(**changes):
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 4, "atomic_bsz": 2, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
 
 
 def test_loss_logits_and_gradients_equal_the_reference(monkeypatch):
@@ -61,8 +27,9 @@ def test_loss_logits_and_gradients_equal_the_reference(monkeypatch):
     over both streams: the loss (both terms), both streams' logits and
     the gradient of every leaf. (The cell's own ``reference_check`` on
     such a build: ``benchmark/tests/test_glm_cell.py``.)"""
-    config, sizes = _config_module(), _sizes(num_hidden_layers=3)
-    built = _built(monkeypatch, sizes)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(NAME, num_hidden_layers=3)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     data = config.make_dataset(sizes, 5, 4)
     batch = {k: jnp.asarray(v[:2]) for k, v in data.items()}
@@ -121,14 +88,6 @@ def test_loss_logits_and_gradients_equal_the_reference(monkeypatch):
     )
 
 
-def _loader_stub(atomic, accum):
-    class Loader:
-        current_atomic_bsz = atomic
-        current_accum_steps = accum
-
-    return Loader()
-
-
 def test_a_job_trains_checkpoints_restores_and_continues(
     tmp_path, monkeypatch
 ):
@@ -140,10 +99,11 @@ def test_a_job_trains_checkpoints_restores_and_continues(
     from adaptdl_tpu import checkpoint
 
     monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
-    config, sizes = _config_module(), _sizes(num_hidden_layers=2)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(NAME, num_hidden_layers=2)
     data = config.make_dataset(sizes, 5, 8)
     batch = {k: v[:4] for k, v in data.items()}
-    built = _built(monkeypatch, sizes)
+    built = configurations.built(monkeypatch, NAME, sizes)
     trainer = built["trainer"]
     holder = {"state": trainer.init_state()}
     ck = trainer.make_checkpoint_state(
@@ -152,7 +112,7 @@ def test_a_job_trains_checkpoints_restores_and_continues(
     trainer._calibrated.add(2)
     since = len(trace.snapshot_spans())
     holder["state"], metrics = trainer.run_step(
-        holder["state"], batch, _loader_stub(2, 1)
+        holder["state"], batch, loader_stub(2, 1)
     )
     assert np.isfinite(float(metrics["loss"]))
     load = metrics["counters"]["moe.load"]
@@ -173,11 +133,11 @@ def test_a_job_trains_checkpoints_restores_and_continues(
     checkpoint.save_all_states()
     saved = jax.tree.map(np.asarray, trainer.params_tree(holder["state"]))
     holder["state"], after = trainer.run_step(
-        holder["state"], batch, _loader_stub(2, 1)
+        holder["state"], batch, loader_stub(2, 1)
     )
     ck.unregister()
 
-    again = _built(monkeypatch, sizes, seed=11)["trainer"]
+    again = configurations.built(monkeypatch, NAME, sizes, seed=11)["trainer"]
     holder2 = {"state": again.init_state()}
     ck2 = again.make_checkpoint_state(
         lambda: holder2["state"], lambda s: holder2.__setitem__("state", s)
@@ -190,7 +150,7 @@ def test_a_job_trains_checkpoints_restores_and_continues(
         np.testing.assert_array_equal(a, np.asarray(b))
     again._calibrated.add(2)
     holder2["state"], resumed = again.run_step(
-        holder2["state"], batch, _loader_stub(2, 1)
+        holder2["state"], batch, loader_stub(2, 1)
     )
     assert float(resumed["loss"]) == float(after["loss"])
     ck2.unregister()

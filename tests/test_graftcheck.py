@@ -451,13 +451,16 @@ def test_package_is_clean_or_baselined():
     <8s budget (re-pinned with the GC12xx/GC13xx/GC14xx whole-program
     passes aboard) that keeps graftcheck in `make lint` and CI on
     every push (one timed analysis serves both assertions; the suite
-    pays for a full-package run exactly once)."""
+    pays for a full-package run exactly once). The budget is on the
+    analysing thread's CPU time, as ``tests/test_watchgate.py`` reads
+    its own: what the analysis costs, not how long the suite's other
+    workers kept this thread off a core."""
     ctx = Context(root=REPO, docs_dir=os.path.join(REPO, "docs"))
-    start = time.monotonic()
+    start = time.thread_time()
     findings = analyze_paths(
         [os.path.join(REPO, "adaptdl_tpu")], ALL_PASSES, ctx
     )
-    elapsed = time.monotonic() - start
+    elapsed = time.thread_time() - start
     baseline = load_baseline(
         os.path.join(REPO, "graftcheck_baseline.json")
     )

@@ -1,19 +1,19 @@
 """What the kimi-linear-48b-a3b configuration forced (PR 46), at small
 sizes against the configuration's own plain reference
 (``benchmark/configs/kimi-linear-48b-a3b.py``, which imports nothing
-from ``adaptdl_tpu``): the chunked gated delta rule and its Pallas
-kernels, latent attention at a q/k width that is not v's, the shared
-expert, the share of an expert-parallel layer, and that the four
-configurations the benchmark had are the programs of before."""
+from ``adaptdl_tpu``): the ``kda`` mixer, latent attention at a q/k width
+that is not v's, the shared expert, the share of an expert-parallel
+layer, the whole model and its save / restore round trip. (The chunked
+gated delta rule and its Pallas kernels: ``tests/test_kda_op.py``.)"""
 
 import functools
-import json
-import os
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import loader_stub, rel
 
 from adaptdl_tpu import trace
 from adaptdl_tpu.models.transformer import (
@@ -26,161 +26,26 @@ from adaptdl_tpu.models.transformer import (
 from adaptdl_tpu.ops import kda as kda_op
 from adaptdl_tpu.ops.flash_attention import flash_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "kimi-linear-48b-a3b"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "num_attention_heads": 2, "num_key_value_heads": 2,
-    "linear_attn_config": {
-        "full_attn_layers": [4], "head_dim": 8, "kda_layers": [1, 2, 3, 5],
-        "num_heads": 2, "short_conv_kernel_size": 4,
-    },
-    "kv_lora_rank": 12, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4,
-    "v_head_dim": 8, "router_width": 16, "experts_held": 4,
-    "num_experts": 4, "num_experts_per_token": 2, "num_experts_per_tok": 2,
-    "vocab_size": 97, "sequence_length": 64, "kda_gate_rank": 8,
-    "kda_chunk": 16, "head_chunk_rows": 32, "compute_dtype": "float32",
-}
 
 
 @pytest.fixture(autouse=True)
-def _rows_of_several_chunks(monkeypatch):
+def _rows_of_several_chunks():
     """The rule's chunk is a constant of ``ops/kda.py`` (64); the
     models of these tests run rows of 64 tokens, several chunks at
-    TINY's."""
-    monkeypatch.setattr(kda_op, "CHUNK", TINY["kda_chunk"])
+    the tiny sizes' ``kda_chunk``."""
+    with configurations.rows_of_several_chunks(NAME):
+        yield
 
 
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", NAME + ".py")
-    )
-
-
-def _sizes(**changes):
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 4, "atomic_bsz": 2, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
-
-
-# ---- the chunked delta rule -------------------------------------------
-
-
-def _kda_inputs(seed, batch=2, seq=40, heads=2, dk=8, dv=8,
-                dtype=jnp.float32, decay=0.5):
-    keys = jax.random.split(jax.random.key(seed), 5)
-    q = jax.random.normal(keys[0], (batch, seq, heads, dk))
-    k = jax.random.normal(keys[1], (batch, seq, heads, dk))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True)
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(keys[2], (batch, seq, heads, dv))
-    g = -decay * jnp.exp(jax.random.normal(keys[3], (batch, seq, heads, dk)))
-    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, seq, heads)))
-    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
-
-
-def _weighted(fn, shape):
-    cotangent = jnp.cos(jnp.arange(np.prod(shape))).reshape(shape)
-    return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * cotangent)
-
-
-def _grads(fn, shape, args):
-    """Every operand's gradient of ``fn`` under ``_weighted``'s
-    cotangent, as one compiled program (op by op, a chunk's hundreds
-    of small operations are each dispatched by themselves)."""
-    return jax.jit(jax.grad(_weighted(fn, shape), (0, 1, 2, 3, 4)))(*args)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
-
-
-@pytest.mark.parametrize("use_kernel", [True, False])
-@pytest.mark.parametrize(
-    "chunk,seq", [(8, 40), (16, 40), (32, 64), (64, 128), (64, 100)]
-)
-def test_chunked_kda_is_the_recurrence(chunk, seq, use_kernel):
-    """Forward and the gradient of every operand against the
-    recurrence token by token, at chunk lengths that do and do not
-    divide the row, through the Pallas kernels (interpret mode) and
-    through the scan."""
-    args = _kda_inputs(0, seq=seq)
-    want = kda_op.kda_recurrent(*args)
-    run = functools.partial(kda_op.kda, chunk=chunk, use_kernel=use_kernel)
-    assert _rel(jax.jit(run)(*args), want) < 1e-5
-    got = _grads(run, want.shape, args)
-    ref = _grads(kda_op.kda_recurrent, want.shape, args)
-    for a, b in zip(got, ref):
-        assert _rel(a, b) < 2e-5
-
-
-def test_kda_kernels_equal_the_scan_on_bf16_operands():
-    """The kernels against the ``jax.numpy`` chunked form on bfloat16
-    operands: the same arithmetic, so nearly the same bits; and both
-    within bfloat16's rounding of the float32 recurrence."""
-    args = _kda_inputs(1, seq=64, dtype=jnp.bfloat16)
-    want = kda_op.kda_recurrent(*args)
-    outs, grads = {}, {}
-    for use_kernel in (True, False):
-        run = functools.partial(kda_op.kda, chunk=16, use_kernel=use_kernel)
-        outs[use_kernel] = jax.jit(run)(*args)
-        grads[use_kernel] = _grads(run, want.shape, args)
-    assert outs[True].dtype == jnp.bfloat16
-    assert _rel(outs[True], outs[False]) < 1e-2
-    assert _rel(outs[True], want) < 3e-2
-    for a, b in zip(grads[True], grads[False]):
-        assert _rel(a, b) < 2e-2
-    ref = _grads(kda_op.kda_recurrent, want.shape, args)
-    for a, b in zip(grads[True], ref):
-        assert _rel(a, b) < 6e-2
-
-
-def test_kda_survives_a_decay_no_float32_inverse_holds():
-    """A decay of e^-40 a token: ``e^{-G}`` of a chunk would overflow
-    float32; no exponent here is positive."""
-    args = _kda_inputs(2, seq=64, decay=40.0)
-    run = functools.partial(kda_op.kda, chunk=64)
-    got = jax.jit(run)(*args)
-    assert bool(jnp.isfinite(got).all())
-    assert _rel(got, kda_op.kda_recurrent(*args)) < 1e-5
-    grads = _grads(run, got.shape, args)
-    assert all(bool(jnp.isfinite(x).all()) for x in grads)
-
-
-def test_kda_in_head_groups_is_kda(monkeypatch):
-    """One head at a time (what a long row forces) gives what all
-    heads at once give, forward and backward."""
-    args = _kda_inputs(3, seq=40, heads=4)
-    run = functools.partial(kda_op.kda, chunk=16)
-    whole = jax.jit(run)(*args)
-    whole_grads = _grads(run, whole.shape, args)
-    monkeypatch.setattr(kda_op, "_GROUP_ELEMENTS", 2 * 40 * 8)
-    assert kda_op.head_groups(80, 4, 8) == 4
-    np.testing.assert_allclose(
-        jax.jit(lambda *a: run(*a))(*args), whole, rtol=1e-6, atol=1e-7
-    )
-    grads = _grads(lambda *a: run(*a), whole.shape, args)
-    for a, b in zip(grads, whole_grads):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+# ---- the kda mixer ------------------------------------------------------
 
 
 def test_kda_mixer_in_head_groups_is_the_mixer(monkeypatch):
     """The module's own work on a head (convolutions, norms, the
     decay) done a group of heads at a time, as a long row forces:
     what all heads at once give, forward and backward."""
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     cfg = config.model_config(sizes)
     u = jax.random.normal(jax.random.key(9), (2, 64, 32))
     module = KDA(cfg)
@@ -199,463 +64,6 @@ def test_kda_mixer_in_head_groups_is_the_mixer(monkeypatch):
     grads = jax.grad(loss, (0, 1))(params, u)
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(whole_grads)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("decay", ["channel", "head"])
-def test_kda_schedule_is_journalled(decay):
-    before = len(
-        [r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"]
-    )
-    q, k, v, g, beta = _kda_inputs(4, seq=40)
-    kda_op.kda(q, k, v, g if decay == "channel" else g[..., 0], beta, chunk=16)
-    events = [
-        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
-    ]
-    assert len(events) == before + 1
-    attrs = events[-1]["attrs"]
-    assert attrs["decay"] == decay
-    # How the chunk's inverse is formed (the per-channel body: levels
-    # of block products above a sub-block; the one-decay body: forward
-    # substitution over the whole chunk), that the forward rule writes
-    # it out for the backward, chunks in one basic block of the two
-    # chunk kernels (three chunks a grid step here: one).
-    assert (attrs["inverse"], attrs["inverse_kept"]) == (
-        "levels" if decay == "channel" else "substituted", True
-    )
-    assert (attrs["chunks_abreast"], attrs["chunks_abreast_bwd"]) == (1, 1)
-    # Two chunks a grid step: the one-decay pair walks them abreast,
-    # the per-channel pair its backward alone.
-    kda_op.kda(*(x[:, :32] for x in (q, k, v, g, beta)), chunk=16)
-    kda_op.kda(*(x[:, :32] for x in (q, k, v, g[..., 0], beta)), chunk=16)
-    channel, head = [
-        r["attrs"] for r in trace.snapshot_spans()
-        if r["name"] == "kda.schedule"
-    ][-2:]
-    assert (channel["chunks_abreast"], channel["chunks_abreast_bwd"]) == (1, 2)
-    assert (head["chunks_abreast"], head["chunks_abreast_bwd"]) == (2, 2)
-    assert "as the forward rule wrote it out" in attrs["backward"]
-    fallback = kda_op.kda(q, k, v, g, beta, chunk=16, use_kernel=False)
-    assert fallback.shape == v.shape
-    xla = [
-        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
-    ][-1]["attrs"]
-    assert (xla["inverse"], xla["inverse_kept"], xla["chunks_abreast"],
-            xla["chunks_abreast_bwd"]) == ("levels", False, 0, 0)
-    assert (attrs["heads"], attrs["head_dim"], attrs["chunk"]) == (2, 8, 16)
-    assert attrs["chunks"] == 3 and attrs["padded"] == 8
-    short = kda_op.kda(*_kda_inputs(4, seq=12), chunk=64)  # chunks of 8
-    assert _rel(short, kda_op.kda_recurrent(*_kda_inputs(4, seq=12))) < 1e-5
-    assert attrs["path"] == "kernel" and "kda_fwd" in attrs["product"]
-    assert attrs["saved_names"] == "kda_out"
-    assert attrs["head_groups"] == 1
-    # What a grid step of the state kernels holds: here everything,
-    # both of the batch's rows x both heads and all three chunks.
-    assert (attrs["state_heads_a_step"], attrs["state_chunks_a_step"],
-            attrs["state_chunks_a_step_bwd"]) == (4, 3, 3)
-    assert attrs["state_grid_steps"] == 1
-
-
-# ---- what a grid step of the state kernels holds ----------------------
-
-
-def _state_operands(bh, chunks, dtype, chunk=16, width=8):
-    """What the chunks' own work hands the state kernels for ``bh``
-    heads of ``chunks`` chunks, and an output's cotangent."""
-    args = _kda_inputs(
-        11, batch=1, seq=chunks * chunk, heads=bh, dk=width, dv=width,
-        dtype=dtype,
-    )
-    operands = jax.jit(lambda *a: _prepare_blocks(a, chunk))(
-        *_chunked(args, chunk)
-    )
-    d_o = jnp.cos(jnp.arange(operands[3].size, dtype=jnp.float32))
-    return operands, d_o.reshape(operands[3].shape).astype(dtype)
-
-
-def _state_kernels(operands, d_o):
-    """-> (output, chunk states, the six gradients) of the kernels as
-    ``_state_how`` now schedules them."""
-    out, states = jax.jit(lambda *a: kda_op._fwd_pallas(*a))(*operands)
-    grads = jax.jit(lambda *a: kda_op._bwd_pallas(*a))(*operands, states, d_o)
-    return (out, states) + tuple(grads)
-
-
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-@pytest.mark.parametrize(
-    "bh,chunks", [(4, 16), (4, 12), (3, 8), (1, 1), (2, 7), (8, 32)]
-)
-def test_state_kernels_in_blocks_are_the_kernels_a_chunk_a_step(
-    monkeypatch, bh, chunks, dtype
-):
-    """Several chunks and several heads a grid step (blocks of both,
-    of either, of neither; a prime chunk count; a head count that 4
-    does not divide) give, BIT FOR BIT, what one chunk of one head a
-    step gives: output, every chunk state, all six gradients; and
-    what the scans over the same two functions give."""
-    operands, d_o = _state_operands(bh, chunks, dtype)
-    # A sixteenth of the kernels' VMEM, so that these tiny blocks do
-    # not all fit one grid step.
-    monkeypatch.setattr(kda_op, "_BLOCKS_SHARE", 1 / 16)
-    size = jnp.dtype(dtype).itemsize
-    held, held_bwd = (
-        kda_op._state_how(bh, chunks, 16, 8, 8, size, backward)
-        for backward in (False, True)
-    )
-    assert bh % held.heads == 0 and chunks % held.chunks == 0
-    assert held_bwd.chunks <= held.chunks
-    assert (held == (1, 1)) == ((bh, chunks) == (1, 1))
-    if (bh, chunks) == (4, 16):  # the state crosses grid steps
-        assert (held, held_bwd.chunks) == ((4, 4), 2 if size == 4 else 4)
-    if (bh, chunks, size) == (2, 7, 4):  # seven do not fit: one
-        assert (held, held_bwd) == ((2, 7), (2, 1))
-    blocked = _state_kernels(operands, d_o)
-    monkeypatch.setattr(
-        kda_op, "_state_how", lambda *a: kda_op._Held(1, 1)
-    )
-    for got, want in zip(blocked, _state_kernels(operands, d_o)):
-        assert got.dtype == want.dtype
-        np.testing.assert_array_equal(
-            np.asarray(got, np.float32), np.asarray(want, np.float32)
-        )
-    out, states = jax.jit(kda_op._fwd_scan)(*operands)
-    grads = jax.jit(kda_op._bwd_scan)(*operands, states, d_o)
-    assert _rel(blocked[0], out) < 1e-2 and _rel(blocked[1], states) < 1e-2
-    for got, want in zip(blocked[2:], grads):
-        assert _rel(got, want) < 2e-2
-
-
-def test_kda_in_blocks_on_a_padded_row_is_the_recurrence(monkeypatch):
-    """``kda`` end to end on a row whose last chunk is padded, the
-    state kernels in blocks of four heads and four (backward: two)
-    chunks: the recurrence token by token, forward and gradient."""
-    monkeypatch.setattr(kda_op, "_BLOCKS_SHARE", 1 / 16)
-    args = _kda_inputs(12, batch=1, seq=120, heads=4)
-    run = functools.partial(kda_op.kda, chunk=16)
-    want = kda_op.kda_recurrent(*args)
-    assert _rel(jax.jit(run)(*args), want) < 1e-5
-    got = _grads(run, want.shape, args)
-    for a, b in zip(got, _grads(kda_op.kda_recurrent, want.shape, args)):
-        assert _rel(a, b) < 2e-5
-    attrs = [
-        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
-    ][-1]["attrs"]
-    assert (attrs["chunks"], attrs["padded"]) == (8, 8)
-    assert (attrs["state_heads_a_step"], attrs["state_chunks_a_step"],
-            attrs["state_chunks_a_step_bwd"]) == (4, 4, 2)
-    assert attrs["state_grid_steps"] == 2
-
-
-def test_the_state_schedule_is_a_pure_function_of_its_shapes():
-    """At the cell's shapes (four heads of 128, 256 chunks of 64,
-    bf16) a grid step holds the group's four heads and eight chunks;
-    wider operands take fewer, the backward never more than the
-    forward; where nothing larger divides or fits, (1, 1)."""
-    how, held = kda_op._state_how, kda_op._Held
-    cell = (4, 256, 64, 128, 128)
-    for _ in range(2):
-        assert how(*cell, 2, False) == held(4, 8) == how(*cell, 2, True)
-    assert how(*cell, 4, False) == held(4, 4) == how(*cell, 4, True)
-    assert how(4, 256, 64, 256, 256, 2, False) == held(4, 4)
-    assert how(4, 256, 64, 256, 256, 2, True) == held(4, 2)
-    assert how(6, 256, 64, 128, 128, 2, False) == held(3, 16)
-    assert how(6, 256, 64, 128, 128, 2, True) == held(3, 8)
-    assert how(32, 12, 64, 128, 128, 2, False) == held(4, 12)
-    assert how(1, 1, 64, 128, 128, 2, False) == held(1, 1)
-    assert how(1, 7, 64, 128, 128, 2, True) == held(1, 7)
-    for backward in (False, True):  # neither 5 nor 257 has a divisor
-        assert how(5, 257, 64, 128, 128, 2, backward) == held(1, 1)
-
-
-# ---- the chunks' own work as a kernel pair ----------------------------
-
-
-def _chunked(args, chunk):
-    """``kda``'s operands as its two stages take them: [b * h, chunks,
-    C, w] blocks (beta [b * h, chunks, C]), the row padded with tokens
-    that leave the state as it is."""
-    q = args[0]
-    batch, seq, heads, _ = q.shape
-    chunks = -(-seq // chunk)
-
-    def rows(x):
-        x = jnp.swapaxes(x, 1, 2)
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, chunks * chunk - seq), (0, 0)))
-        return x.reshape(batch * heads, chunks, chunk, x.shape[-1])
-
-    q, k, v, g, beta = args
-    return rows(q), rows(k), rows(v), rows(g), rows(beta[..., None])[..., 0]
-
-
-def _prepare_blocks(operands, chunk, scale=0.3):
-    """The XLA ``_prepare`` on ``_chunked``'s blocks, its six results
-    as the state kernels take them: [b * h, chunks, ...]."""
-    bh, chunks = operands[0].shape[:2]
-    outs = kda_op._prepare(
-        *(x.reshape((bh * chunks,) + x.shape[2:]) for x in operands),
-        chunk, scale,
-    )
-    return tuple(
-        x.reshape((bh, chunks) + x.shape[1:]) for x in outs[:5]
-    ) + (outs[5].reshape(bh, chunks, 1, -1),)
-
-
-def _own_work_both_ways(args, chunk, scale=0.3, xla=True):
-    """-> ((results, gradients) of the kernel pair, of ``_prepare``
-    unless ``xla`` is false), the gradients under random cotangents of
-    all six results."""
-    q, k, v, g, beta = _chunked(args, chunk)
-
-    def kernels(q, k, v, g, beta):
-        return kda_op._own_work(scale, q, k, v, g, beta[:, :, None, :])
-
-    def prepare(*operands):
-        return _prepare_blocks(operands, chunk, scale)
-
-    cotangents = [
-        jax.random.normal(jax.random.key(30 + i), x.shape)
-        for i, x in enumerate(jax.eval_shape(kernels, q, k, v, g, beta))
-    ]
-
-    def both(fn):
-        def loss(*operands):
-            return sum(
-                jnp.sum(x.astype(jnp.float32) * c)
-                for x, c in zip(fn(*operands), cotangents)
-            )
-
-        return jax.jit(
-            lambda *operands: (
-                fn(*operands), jax.grad(loss, tuple(range(5)))(*operands)
-            )
-        )(q, k, v, g, beta)
-
-    return both(kernels), both(prepare) if xla else None
-
-
-@pytest.mark.parametrize(
-    "dtype,chunk,seq,limit",
-    [
-        ("float32", 32, 64, 2e-5),  # two sub-blocks, the chunk divides
-        ("float32", 64, 100, 2e-5),  # four, the last chunk padded
-        ("bfloat16", 32, 64, 2e-2),
-        ("bfloat16", 64, 100, 2e-2),
-        # An odd number of chunks (one chunk a basic block), the last
-        # one padded.
-        ("float32", 16, 40, 2e-5),
-        ("bfloat16", 16, 40, 2e-2),
-        ("float32", 64, 150, 2e-5),
-    ],
-)
-def test_chunk_kernels_equal_the_xla_own_work(dtype, chunk, seq, limit):
-    """``delta_chunk_fwd`` / ``delta_chunk_bwd`` (interpret mode)
-    against ``_prepare`` and its autodiff: every result, and every
-    operand's gradient under random cotangents of all six — the
-    backward through the inverse and ``A`` that the forward rule wrote
-    out, where ``_prepare``'s autodiff keeps its own. In bfloat16
-    the forward rounds where ``_prepare`` rounds (nearly the same
-    bits); the hand-written backward keeps float32 where autodiff
-    rounds a cotangent to the operand's bfloat16."""
-    args = _kda_inputs(5, batch=1, seq=seq, dtype=jnp.dtype(dtype))
-    (got, got_grads), (want, want_grads) = _own_work_both_ways(args, chunk)
-    for a, b in zip(got, want):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        assert _rel(a, b) < (1e-5 if dtype == "float32" else 4e-3)
-    for a, b in zip(got_grads, want_grads):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        assert _rel(a, b) < limit
-
-
-@pytest.mark.parametrize(
-    "case", ["decay_e-40_a_token", "beta_near_0", "beta_near_1"]
-)
-def test_chunk_kernels_at_the_edges(case):
-    """A decay of e^-40 a token (``e^{-G}`` of a chunk overflows
-    float32; no exponent in the kernels is positive), a step ``beta``
-    of 1e-6 (``T`` ~ 0) and of 1 - 1e-6 (the inverse at its
-    largest): finite, and what ``_prepare`` gives."""
-    args = _kda_inputs(6, batch=1, seq=64,
-                       decay=40.0 if case.startswith("decay") else 0.5)
-    if case.startswith("beta"):
-        near = 1e-6 if case == "beta_near_0" else 1.0 - 1e-6
-        args = args[:4] + (jnp.full_like(args[4], near),)
-    (got, got_grads), (want, want_grads) = _own_work_both_ways(args, 32)
-    assert all(bool(jnp.isfinite(x).all()) for x in got + got_grads)
-    # (At e^-40 a token what is left of a product is differences of
-    # terms many times its size, in either program.)
-    loose = 50 if case.startswith("decay") else 1
-    for a, b in zip(got, want):
-        assert _rel(a, b) < 1e-5 * loose
-    for a, b in zip(got_grads, want_grads):
-        assert _rel(a, b) < 2e-5 * loose
-
-
-@pytest.mark.parametrize(
-    "dtype,chunk,decay",
-    [
-        ("float32", 16, 0.5), ("float32", 32, 0.5), ("float32", 64, 0.5),
-        ("bfloat16", 16, 0.5), ("bfloat16", 32, 0.5), ("bfloat16", 64, 0.5),
-        ("float32", 64, 40.0),  # no float32 inverse of e^G holds
-    ],
-)
-def test_the_forward_rule_writes_out_the_inverse(
-    monkeypatch, dtype, chunk, decay
-):
-    """What ``_own_work``'s forward rule keeps for ``delta_chunk_bwd``:
-    ``X = (I + Diag(beta) A)^-1`` and ``A``, float32 whatever the
-    operands' dtype — the matrices the XLA ``_prepare`` hands its
-    ``_unit_lower_inverse`` and gets back on the same chunks, the last
-    chunk padded."""
-    args = _kda_inputs(
-        13, batch=1, seq=chunk + chunk // 2, dtype=jnp.dtype(dtype),
-        decay=decay,
-    )
-    q, k, v, g, beta = _chunked(args, chunk)
-    seen = {}
-    block_products = kda_op._unit_lower_inverse
-
-    def spy(lower):
-        seen["lower"], seen["inv"] = lower, block_products(lower)
-        return seen["inv"]
-
-    monkeypatch.setattr(kda_op, "_unit_lower_inverse", spy)
-    want = _prepare_blocks((q, k, v, g, beta), chunk)
-    results, saved = kda_op._own_work_fwd(
-        0.3, q, k, v, g, beta[:, :, None, :]
-    )
-    assert len(results) == 6 and len(saved) == 7
-    # (At e^-40 a token what is left of a product is differences of
-    # terms many times its size, in either program.)
-    loose = 50 if decay > 1 else 1
-    for a, b in zip(results, want):
-        assert _rel(a, b) < (1e-5 * loose if dtype == "float32" else 4e-3)
-    inv, a_full = saved[5:]
-    shape = q.shape[:2] + (chunk, chunk)
-    assert inv.dtype == a_full.dtype == jnp.float32
-    assert inv.shape == a_full.shape == shape
-    limit = 1e-5 if dtype == "float32" else 2e-3
-    assert _rel(inv, seen["inv"].reshape(shape)) < limit
-    lower = beta[..., None] * a_full
-    assert _rel(lower, seen["lower"].reshape(shape)) < limit * loose
-    # Unit lower triangular, and the inverse of what it is said to be.
-    upper = np.triu(np.ones((chunk, chunk), bool), 1)
-    assert not np.asarray(inv)[..., upper].any()
-    assert (np.diagonal(inv, axis1=-2, axis2=-1) == 1.0).all()
-    both = jnp.matmul(
-        inv, jnp.eye(chunk) + lower, precision=jax.lax.Precision.HIGHEST
-    )
-    assert _rel(both, jnp.broadcast_to(jnp.eye(chunk), shape)) < 1e-5
-
-
-def _pallas_calls(fn, *args):
-    """The ``pallas_call`` equations of ``fn``'s jaxpr by kernel name,
-    jitted functions and custom rules opened."""
-    found = {}
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.setdefault(eqn.params["name"], []).append(eqn)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
-
-
-@pytest.mark.parametrize("what", ["primal", "gradient"])
-def test_only_the_forward_rule_writes_the_inverse_out(what):
-    """The primal ``_own_work`` (a group's run in the forward pass) is
-    one ``delta_chunk_fwd`` of six results: it writes no ``X``. A
-    gradient's program holds the keeping forward (eight: ``X`` and
-    ``A`` float32 [bh, chunks, C, C] beside the six) and a
-    ``delta_chunk_bwd`` that takes both among its operands and
-    nothing else of the forward's."""
-    q, k, v, g, beta = _chunked(_kda_inputs(14, batch=1, seq=64), 32)
-    operands = (q, k, v, g, beta[:, :, None, :])
-    matrix = q.shape[:2] + (32, 32)
-
-    def primal(*a):
-        return kda_op._own_work(0.3, *a)
-
-    def loss(*a):
-        return sum(x.astype(jnp.float32).sum() for x in primal(*a))
-
-    if what == "primal":
-        calls = _pallas_calls(primal, *operands)
-        assert set(calls) == {"delta_chunk_fwd"}
-        (call,) = calls["delta_chunk_fwd"]
-        assert len(call.outvars) == 6
-        assert [x.aval.shape for x in call.outvars].count(matrix) == 1
-        return
-    calls = _pallas_calls(jax.grad(loss, tuple(range(5))), *operands)
-    assert set(calls) == {"delta_chunk_fwd", "delta_chunk_bwd"}
-    (forward,), (backward,) = calls["delta_chunk_fwd"], calls["delta_chunk_bwd"]
-    kept = [x.aval for x in forward.outvars[6:]]
-    assert len(forward.outvars) == 8
-    assert [(x.shape, x.dtype) for x in kept] == [(matrix, jnp.float32)] * 2
-    # The five operands, X and A, the six cotangents.
-    assert len(backward.invars) == 13
-    assert [x.aval for x in backward.invars[5:7]] == kept
-
-
-def test_the_unrolled_walk_of_a_sub_block_is_the_loop(monkeypatch):
-    """Compiled, the kernels walk a sub-block's tokens by unrolled
-    code, a tile of rows at a time and past the tiles before the
-    token (``_unrolled``: static rows and lanes, what
-    ``tests/test_chip_compile.py`` lowers for the chip); interpreted,
-    by a loop over all rows. One body, the same numbers."""
-    args = _kda_inputs(9, batch=1, heads=1, seq=32)
-    rolled, _ = _own_work_both_ways(args, 32, xla=False)
-    monkeypatch.setattr(kda_op, "_unrolled", lambda: True)
-    unrolled, _ = _own_work_both_ways(args, 32, xla=False)
-    for a, b in zip(jax.tree.leaves(unrolled), jax.tree.leaves(rolled)):
-        assert _rel(a, b) < 1e-6
-
-
-def test_kda_runs_its_own_work_in_the_kernels(monkeypatch):
-    """On the kernel path ``kda`` never calls the XLA ``_prepare``,
-    and is still the recurrence token by token, forward and
-    gradient; the ``kda.schedule`` event says what ran."""
-    def refuse(*_):
-        raise AssertionError("the XLA _prepare on the kernel path")
-
-    monkeypatch.setattr(kda_op, "_prepare", refuse)
-    args = _kda_inputs(7, seq=40)
-    run = functools.partial(kda_op.kda, chunk=32)
-    want = kda_op.kda_recurrent(*args)
-    assert _rel(jax.jit(run)(*args), want) < 1e-5
-    got = _grads(run, want.shape, args)
-    ref = _grads(kda_op.kda_recurrent, want.shape, args)
-    for a, b in zip(got, ref):
-        assert _rel(a, b) < 2e-5
-    attrs = [
-        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
-    ][-1]["attrs"]
-    assert attrs["own_work"] == "pallas:delta_chunk_fwd,delta_chunk_bwd"
-    assert "delta_chunk_bwd" in attrs["backward"]
-
-
-def test_kda_falls_back_where_the_kernels_do_not_fit(monkeypatch):
-    """Widths that are not whole lane tiles on the chip: neither
-    kernel pair is built; the scan and the XLA ``_prepare`` run."""
-    def refuse(*_, **__):
-        raise AssertionError("a kernel where the widths do not fit")
-
-    monkeypatch.setattr(kda_op, "_use_interpret", lambda: False)
-    assert not kda_op.kernel_fits(8, 8, 16)
-    assert kda_op.kernel_fits(128, 128, 64)
-    for name in ("_own_work", "_fwd_pallas", "_bwd_pallas"):
-        monkeypatch.setattr(kda_op, name, refuse)
-    args = _kda_inputs(8, seq=40)
-    run = functools.partial(kda_op.kda, chunk=16)
-    want = kda_op.kda_recurrent(*args)
-    assert _rel(jax.jit(run)(*args), want) < 1e-5
-    _grads(run, want.shape, args)
-    attrs = [
-        r for r in trace.snapshot_spans() if r["name"] == "kda.schedule"
-    ][-1]["attrs"]
-    assert (attrs["path"], attrs["own_work"]) == ("fallback", "xla")
-    assert attrs["state_grid_steps"] == attrs["state_heads_a_step"] == 0
 
 
 # ---- latent attention -------------------------------------------------
@@ -687,8 +95,8 @@ def test_flash_kernels_take_a_v_narrower_than_q(seq, qk, v):
 
 
 def _mixer_case(monkeypatch, kind):
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     at = config.checked_mixers(sizes)[kind]
     layer = config.reference_weights(params, sizes)["layers"][at][kind]
@@ -801,8 +209,8 @@ def test_any_attention_fn_is_asked_how_many_heads_a_call(monkeypatch, made):
 
 
 def test_shared_expert_is_added_unweighted(monkeypatch):
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     moe = params["layer_1"]["moe"]
     layer = config.routed_weights(moe)
@@ -822,7 +230,7 @@ def test_shared_expert_is_added_unweighted(monkeypatch):
     assert float(jnp.abs(shared).max()) > 0.1
     assert int(sown["moe_load"]["shared_rows"][0]) == 96
     no_shared = RoutedFFN(
-        config.model_config(_sizes(num_shared_experts=0))
+        config.model_config(configurations.sizes(NAME, num_shared_experts=0))
     ).apply(
         {"params": {k: v for k, v in moe.items() if k != "shared"}}, x,
         mutable=["moe_load", "moe_routing"],
@@ -926,7 +334,7 @@ def test_a_plan_many_times_its_bound_is_walked_in_pieces(boost):
     # (The input's gradient also passes through the router's weights,
     # which ``plain`` holds fixed: the experts' leaves are compared.)
     for a, b in zip(grads[1:], want[1:]):
-        assert _rel(a, b) < 1e-4
+        assert rel(a, b) < 1e-4
 
 
 def test_the_shares_add_up_to_the_whole_layer(monkeypatch):
@@ -934,9 +342,11 @@ def test_the_shares_add_up_to_the_whole_layer(monkeypatch):
     compute of the routed result, with the shared expert (which every
     chip computes alike) counted ONCE, adds up to the uncut
     reference's layer."""
-    config = _config_module()
-    sizes = _sizes(router_width=32, experts_held=8, num_experts=8,
-                   num_experts_per_token=4, num_experts_per_tok=4)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(
+        NAME, router_width=32, experts_held=8, num_experts=8,
+        num_experts_per_token=4, num_experts_per_tok=4,
+    )
     keys = jax.random.split(jax.random.key(11), 9)
     d, f = 32, 16
     whole = {
@@ -992,8 +402,8 @@ def test_loss_and_gradients_equal_the_reference(monkeypatch):
     mla, kda; four routed with a shared expert), remat on, the flash
     kernels, the delta rule's kernels, a share of 4 of 16 experts, the
     untied head."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     data = config.make_dataset(sizes, 5, 4)
     batch = {k: jnp.asarray(v[:2]) for k, v in data.items()}
@@ -1022,14 +432,6 @@ def test_loss_and_gradients_equal_the_reference(monkeypatch):
     assert report["ok"], report
 
 
-def _loader_stub(atomic, accum):
-    class Loader:
-        current_atomic_bsz = atomic
-        current_accum_steps = accum
-
-    return Loader()
-
-
 def test_run_step_save_restore_round_trip(tmp_path, monkeypatch):
     """One ``ElasticTrainer.run_step`` of the tiny model, ``moe.load``
     journalled with ``shared_rows``, a save through ``checkpoint.py``,
@@ -1037,10 +439,10 @@ def test_run_step_save_restore_round_trip(tmp_path, monkeypatch):
     from adaptdl_tpu import checkpoint
 
     monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     data = config.make_dataset(sizes, 5, 8)
     batch = {k: v[:4] for k, v in data.items()}
-    built = _built(monkeypatch, sizes)
+    built = configurations.built(monkeypatch, NAME, sizes)
     trainer = built["trainer"]
     holder = {"state": trainer.init_state()}
     ck = trainer.make_checkpoint_state(
@@ -1048,7 +450,7 @@ def test_run_step_save_restore_round_trip(tmp_path, monkeypatch):
     )
     trainer._calibrated.add(2)
     holder["state"], metrics = trainer.run_step(
-        holder["state"], batch, _loader_stub(2, 1)
+        holder["state"], batch, loader_stub(2, 1)
     )
     assert np.isfinite(float(metrics["loss"]))
     load = metrics["counters"]["moe.load"]
@@ -1060,11 +462,11 @@ def test_run_step_save_restore_round_trip(tmp_path, monkeypatch):
     checkpoint.save_all_states()
     saved = jax.tree.map(np.asarray, trainer.params_tree(holder["state"]))
     holder["state"], after = trainer.run_step(
-        holder["state"], batch, _loader_stub(2, 1)
+        holder["state"], batch, loader_stub(2, 1)
     )
     ck.unregister()
 
-    again = _built(monkeypatch, sizes, seed=11)["trainer"]
+    again = configurations.built(monkeypatch, NAME, sizes, seed=11)["trainer"]
     holder2 = {"state": again.init_state()}
     ck2 = again.make_checkpoint_state(
         lambda: holder2["state"], lambda s: holder2.__setitem__("state", s)
@@ -1077,7 +479,7 @@ def test_run_step_save_restore_round_trip(tmp_path, monkeypatch):
         np.testing.assert_array_equal(a, np.asarray(b))
     again._calibrated.add(2)
     holder2["state"], resumed = again.run_step(
-        holder2["state"], batch, _loader_stub(2, 1)
+        holder2["state"], batch, loader_stub(2, 1)
     )
     assert float(resumed["loss"]) == float(after["loss"])
     for a, b in zip(
@@ -1130,33 +532,9 @@ def test_a_remat_block_keeps_the_rules_output_by_name(monkeypatch):
         ]
         return events[-1]["attrs"]["saved_names"].split(",")
 
-    config = _config_module().model_config(_sizes())
+    config = configurations.module(NAME).model_config(
+        configurations.sizes(NAME)
+    )
     assert saved(config) == ["flash_out", "flash_lse", "kda_out"]
     plain = TransformerConfig(**_BASE, layer_types=("full_attention",))
     assert saved(plain) == ["flash_out", "flash_lse"]
-
-
-# ---- the configurations of before --------------------------------------
-
-
-@functools.cache
-def _digests_now():
-    import step_digests
-
-    return step_digests.digests()
-
-
-with open(os.path.join(ROOT, "tests", "data", "step_digests.json")) as _f:
-    _DIGESTS = json.load(_f)
-
-
-@pytest.mark.parametrize("case", sorted(_DIGESTS))
-def test_the_configurations_of_before_are_untouched(case):
-    """Parameter tree and lowered gradient program of each of the four
-    configurations the benchmark had, at its tiny size, against what
-    the parent commit gives (``tests/step_digests.py``): no AOT-cache
-    key and no ``trace_lower_s`` of an accepted cell moves."""
-    import sys
-
-    sys.path.insert(0, os.path.join(ROOT, "tests"))
-    assert _digests_now()[case] == _DIGESTS[case]

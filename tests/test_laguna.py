@@ -8,14 +8,14 @@ one, and that a configuration without the new fields is the program of
 before."""
 
 import functools
-import json
 import math
-import os
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import rel
 
 from adaptdl_tpu import trace
 from adaptdl_tpu.flops import transformer_train_flops
@@ -32,47 +32,8 @@ from adaptdl_tpu.models.transformer import (
 )
 from adaptdl_tpu.ops.flash_attention import flash_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "laguna-xs.2"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "shared_expert_intermediate_size": 16,
-    "num_attention_heads": 6, "num_key_value_heads": 2, "head_dim": 16,
-    "num_attention_heads_per_layer": [6, 8, 8, 8, 6],
-    "sliding_window": 24,
-    "router_width": 16, "experts_held": 4, "num_experts": 4,
-    "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
-    "head_chunk_rows": 32, "compute_dtype": "float32",
-}
 FLASH = functools.partial(flash_attention, block_q=16, block_k=16)
-
-
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", NAME + ".py")
-    )
-
-
-def _sizes(**changes):
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 4, "atomic_bsz": 2, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
 # ---- YaRN ---------------------------------------------------------------
@@ -82,7 +43,7 @@ def test_yarn_table_is_the_formulas():
     """The program's table (float64 on the host) against the
     reference's (float32 ``jax.numpy``, from the formulas) at the
     published parameters, and the formulas' landmarks by hand."""
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     said = sizes["rope_parameters"]["full_attention"]
     yarn = Yarn(64.0, 4096, 64.0, 1.0, said["attention_factor"])
     table = yarn_frequencies(500000.0, 64, yarn)
@@ -112,7 +73,7 @@ def test_rope_takes_a_table_and_its_scale():
     """A table of ``theta``'s own frequencies turns as ``theta`` does;
     a scale multiplies cosine and sine of the rotated lanes only; the
     program's turn is the reference's."""
-    config = _config_module()
+    config = configurations.module(NAME)
     x = jax.random.normal(jax.random.key(0), (2, 24, 3, 16))
     positions = jnp.arange(24)
     freqs = (1e4 ** (-2.0 * np.arange(4) / 8)).astype(np.float32)
@@ -136,7 +97,7 @@ def test_rope_takes_a_table_and_its_scale():
 
 
 def test_kinds_give_each_layer_its_heads_rotary_and_window():
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     cfg = config.model_config(sizes)
     assert [cfg.layer_heads(i) for i in range(5)] == [6, 8, 8, 8, 6]
     full = cfg.attention_kind("full_attention")
@@ -258,8 +219,8 @@ def test_mixer_equals_the_reference(monkeypatch, name):
     band or the full kernels, the per-head gate) against the
     reference's, forward and the gradient of every leaf and of the
     input; and the schedule's event says what was traced."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     at = config.checked_mixers(sizes)[name]
     layer = config.reference_weights(params, sizes)["layers"][at]["attention"]
@@ -272,7 +233,7 @@ def test_mixer_equals_the_reference(monkeypatch, name):
     want = jax.jit(
         lambda layer, u: config.reference_mixer(name, layer, u, sizes)
     )(layer, u)
-    assert _rel(got, want) < 2e-5
+    assert rel(got, want) < 2e-5
     (event,) = [
         r["attrs"] for r in trace.snapshot_spans()[before:]
         if r["name"] == "attn_kind.schedule"
@@ -290,9 +251,9 @@ def test_mixer_equals_the_reference(monkeypatch, name):
     want_w, want_u = jax.jit(
         lambda layer, u: config.reference_mixer_vjp(name, layer, u, u, sizes)
     )(layer, u[:1])
-    assert _rel(got_u, want_u) < 1e-4
+    assert rel(got_u, want_u) < 1e-4
     for path, leaf in config.MIXER_LEAVES.items():
-        assert _rel(config._leaf(got_w, path), want_w[leaf]) < 1e-4, leaf
+        assert rel(config._leaf(got_w, path), want_w[leaf]) < 1e-4, leaf
     # A fault of the reference's differs: the comparison can tell.
     for variant in ("band_511", "band_ahead") if name == "sliding" else (
         "no_attention_factor",
@@ -300,12 +261,12 @@ def test_mixer_equals_the_reference(monkeypatch, name):
         wrong = jax.jit(functools.partial(
             config.reference_mixer, name, sizes=sizes, variant=variant
         ))(layer, u)
-        assert _rel(wrong, want) > 1e-3, variant
+        assert rel(wrong, want) > 1e-3, variant
 
 
 def _sliding_mixer(monkeypatch):
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     u = jax.random.normal(jax.random.key(8), (1, 64, 32))
 
@@ -368,8 +329,8 @@ def test_the_kernels_are_handed_each_kv_head_once(monkeypatch, kind):
     ``[b, s, kv_heads, group, d]`` and its transpose reduces that
     shape); a function that is not the kernel still gets both, and
     plain attention too."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     at = {"sliding_attention": 2, "full_attention": 0}[kind]
     variables = {"params": params[f"layer_{at}"]["attention"]}
@@ -416,8 +377,8 @@ def test_the_shares_add_up_to_the_whole_layer():
     compute of the routed result (scaled by 2.5), with the shared
     expert (which every chip computes alike) counted ONCE, adds up to
     the uncut reference's layer."""
-    config = _config_module()
-    sizes = _sizes()
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(NAME)
     keys = jax.random.split(jax.random.key(11), 8)
     d, f = 32, 16
     whole = {
@@ -469,4 +430,4 @@ def test_the_shares_add_up_to_the_whole_layer():
     unscaled, _ = config.reference_routed_ffn(
         whole, x, {**sizes, "first_expert": 0}, variant="no_scale"
     )
-    assert _rel(unscaled, want) > 0.1
+    assert rel(unscaled, want) > 0.1

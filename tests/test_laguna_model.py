@@ -1,21 +1,18 @@
 """The whole laguna-xs.2 model at a small size against its plain
 reference (PR 54): loss, final hidden states, the gradient of every
-kind of leaf and the cell's own ``reference_check``; and one dense and
-one grouped-query configuration of before, which this PR's new
-config fields must leave the programs they were. (The mixers, YaRN,
-the gate and the share: ``tests/test_laguna.py``.)"""
+kind of leaf and the cell's own ``reference_check``. (The mixers, YaRN,
+the gate and the share: ``tests/test_laguna.py``; that the
+configurations of before are the programs they were:
+``tests/test_step_digests.py``.)"""
 
-
-import functools
-import json
-import os
 import re
-import sys
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import rel
 
 from adaptdl_tpu.models.transformer import (
     AttentionKind,
@@ -23,46 +20,7 @@ from adaptdl_tpu.models.transformer import (
     TransformerConfig,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "laguna-xs.2"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "shared_expert_intermediate_size": 16,
-    "num_attention_heads": 6, "num_key_value_heads": 2, "head_dim": 16,
-    "num_attention_heads_per_layer": [6, 8, 8, 8, 6],
-    "sliding_window": 24,
-    "router_width": 16, "experts_held": 4, "num_experts": 4,
-    "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
-    "head_chunk_rows": 32, "compute_dtype": "float32",
-}
-
-
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", NAME + ".py")
-    )
-
-
-def _sizes(**changes):
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 4, "atomic_bsz": 2, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
-
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-30))
 
 
 # ---- the whole model -------------------------------------------------------
@@ -73,8 +31,8 @@ def test_loss_hidden_states_and_gradients_equal_the_reference(monkeypatch):
     full; four routed with a shared expert), remat on, both kinds of
     kernel, a share of 4 of 16 experts, the untied head: the loss, the
     final hidden states and the gradient of every leaf."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     data = config.make_dataset(sizes, 5, 4)
     batch = {k: jnp.asarray(v[:2]) for k, v in data.items()}
@@ -111,32 +69,12 @@ def test_loss_hidden_states_and_gradients_equal_the_reference(monkeypatch):
         want_hidden, _ = config.reference_hidden(
             config.reference_weights(params, sizes), batch["inputs"], sizes
         )
-    assert _rel(hidden, want_hidden) < 5e-5
+    assert rel(hidden, want_hidden) < 5e-5
     report = config.reference_check(built, params, data, sizes)
     assert report["ok"], report
 
 
-# ---- the configurations of before --------------------------------------------
-
-
-with open(os.path.join(ROOT, "tests", "data", "step_digests.json")) as _f:
-    _DIGESTS = json.load(_f)
-
-
-@pytest.mark.parametrize(
-    "name, dtype", [("gpt2-124m", "float32"), ("lfm2-8b-a1b", "bfloat16")]
-)
-def test_a_config_without_the_new_fields_is_the_program_of_before(
-    name, dtype
-):
-    """One dense and one grouped-query configuration at their tiny
-    sizes: parameter tree and lowered gradient program against what the
-    commit before the window gave (``tests/step_digests.py``; all four
-    in ``tests/test_kimi_linear.py``)."""
-    sys.path.insert(0, os.path.join(ROOT, "tests"))
-    import step_digests
-
-    assert step_digests.digest(name, dtype) == _DIGESTS[f"{name}/{dtype}"]
+# ---- a kind that restates the config -----------------------------------
 
 
 def test_a_kind_that_restates_the_config_is_the_same_mixer():

@@ -5,15 +5,13 @@ pass, the loss over all exits. At small sizes in float32 against the
 configuration's own plain reference (``benchmark/configs/ouro-2.6b.py``,
 which imports nothing from ``adaptdl_tpu``)."""
 
-import functools
-import json
-import os
-
+import configurations
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from configurations import loader_stub
 
 from adaptdl_tpu import device_budget, trace
 from adaptdl_tpu.models import transformer
@@ -25,39 +23,9 @@ from adaptdl_tpu.models.transformer import (
     looped_lm_loss_fn,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TINY = {
-    "hidden_size": 32, "head_dim": 8, "num_attention_heads": 4,
-    "num_key_value_heads": 4, "intermediate_size": 48, "vocab_size": 97,
-    "num_hidden_layers": 2, "layer_types": ["full_attention"] * 2,
-    "sequence_length": 32, "head_chunk_columns": 32,
-    "compute_dtype": "float32",
-}
-
-
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b.py")
-    )
-
-
-def _sizes(**changes):
-    with open(
-        os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b.json")
-    ) as f:
-        sizes = json.load(f)
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 2, "atomic_bsz": 1, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
+NAME = "ouro-2.6b"
+# One row a micro-batch: the looped model's tests build at this geometry.
+GEOMETRY = {"global_batch": 2, "atomic_bsz": 1, "accum_steps": 1}
 
 
 def _seeded(params, seed=7, scale=0.3):
@@ -77,7 +45,7 @@ def _seeded(params, seed=7, scale=0.3):
 
 def _system(built, sizes, seed=5, rows=2):
     """(params, batch) of the tiny model on rows of its own data."""
-    config = _config_module()
+    config = configurations.module(NAME)
     trainer = built["trainer"]
     params = _seeded(trainer.params_tree(trainer.init_state()))
     data = config.make_dataset(sizes, seed, 4)
@@ -92,8 +60,8 @@ def test_exits_loss_and_gradients_equal_the_reference(monkeypatch):
     the streamed head: every exit's state and per-token cross-entropy,
     the gate, the exit distribution, the loss and the gradient of
     every leaf."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     params, batch = _system(built, sizes)
     key = jax.random.key(0)
     weights = config.reference_weights(params, sizes)
@@ -138,9 +106,9 @@ def test_exits_loss_and_gradients_equal_the_reference(monkeypatch):
 def test_reference_check_passes_and_holds_every_comparison(monkeypatch):
     """The on-chip check itself at the small size: ok, and every limit
     it names is one it compared."""
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     monkeypatch.setattr(config, "GRADIENT_TOKENS", 16)
-    built = _built(monkeypatch, sizes)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     params, _ = _system(built, sizes)
     result = config.reference_check(
         built, params, config.make_dataset(sizes, 5, 4), sizes
@@ -161,8 +129,8 @@ def test_gate_gradient_is_held_to_its_terms_not_to_their_sum(monkeypatch):
     ``g [z; 1]``; the check's ``gate_grad_err`` is a share of the
     terms' root-sum-square, which a seed on which they cancel does not
     shrink, and takes weight and bias as one leaf."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     params, batch = _system(built, sizes, rows=1)
     weights = config.reference_weights(params, sizes)
     args = (weights, batch["inputs"], batch["targets"], sizes)
@@ -212,9 +180,9 @@ CONTROLS = {
 
 @pytest.mark.parametrize("variant", sorted(CONTROLS))
 def test_a_control_fails_its_comparison(variant, monkeypatch):
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     assert set(CONTROLS) == set(config.VARIANTS)
-    built = _built(monkeypatch, sizes)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     params, batch = _system(built, sizes, rows=1)
     weights = config.reference_weights(params, sizes)
     args = (weights, batch["inputs"], batch["targets"], sizes)
@@ -279,10 +247,10 @@ def test_looped_equals_unlooped_copies_and_sums_their_gradients(monkeypatch):
     """``T`` passes over ``n`` blocks = an unlooped model of ``T x n``
     blocks given copies of the weights; a shared leaf's gradient is
     the SUM of the copies' gradients."""
-    sizes = _sizes()
-    built = _built(monkeypatch, sizes)
+    sizes = configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     params, batch = _system(built, sizes, rows=1)
-    cfg = _config_module().model_config(sizes)
+    cfg = configurations.module(NAME).model_config(sizes)
     looped, unrolled = TransformerLM(cfg), _Unrolled(cfg, 4)
     copies = {"embed": params["embed"]}
     for t in range(4):
@@ -312,9 +280,9 @@ def test_looped_equals_unlooped_copies_and_sums_their_gradients(monkeypatch):
         for a, b in zip(
             jax.tree.leaves(grad[f"layer_{layer}"]), jax.tree.leaves(summed)
         ):
-            assert float(_config_module().leaf_error(a, b)) < 1e-5
+            assert float(configurations.module(NAME).leaf_error(a, b)) < 1e-5
     assert float(
-        _config_module().leaf_error(
+        configurations.module(NAME).leaf_error(
             grad["RMSNorm_0"]["scale"],
             sum(grad_copies[f"norm_{t}"]["scale"] for t in range(4)),
         )
@@ -322,7 +290,7 @@ def test_looped_equals_unlooped_copies_and_sums_their_gradients(monkeypatch):
     # One pass's own share is NOT the whole: the sum is held, not a copy.
     one = jax.tree.leaves(grad_copies["pass_3_layer_0"])[-1]
     whole = jax.tree.leaves(grad["layer_0"])[-1]
-    assert float(_config_module().leaf_error(one, whole)) > 0.1
+    assert float(configurations.module(NAME).leaf_error(one, whole)) > 0.1
 
 
 def test_one_pass_without_sandwich_is_the_model_of_before():
@@ -400,7 +368,9 @@ def test_a_looped_stack_refuses_what_has_no_pass_axis(option):
 
 def test_exit_log_probs_are_the_plain_products():
     gate = jax.random.normal(jax.random.key(2), (4, 3, 5)) * 3.0
-    want = _config_module().reference_exit_probs(jax.nn.sigmoid(gate))
+    want = configurations.module(NAME).reference_exit_probs(
+        jax.nn.sigmoid(gate)
+    )
     np.testing.assert_allclose(
         jnp.exp(exit_log_probs(gate)), want, rtol=1e-5, atol=1e-7
     )
@@ -413,9 +383,12 @@ def test_exit_log_probs_are_the_plain_products():
 
 
 def test_remat_on_and_off_agree(monkeypatch):
-    sizes = _sizes()
-    on = _built(monkeypatch, sizes)
-    off = _built(monkeypatch, _sizes(remat=False))
+    sizes = configurations.sizes(NAME)
+    on = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
+    off = configurations.built(
+        monkeypatch, NAME, configurations.sizes(NAME, remat=False),
+        geometry=GEOMETRY,
+    )
     params, batch = _system(on, sizes)
     key = jax.random.key(0)
 
@@ -468,14 +441,6 @@ def test_the_ladder_prices_applications(passes):
 # ---- through the trainer ----------------------------------------------
 
 
-def _loader_stub(atomic, accum):
-    class Loader:
-        current_atomic_bsz = atomic
-        current_accum_steps = accum
-
-    return Loader()
-
-
 def test_run_step_journals_the_loop_and_restores_exactly(
     tmp_path, monkeypatch
 ):
@@ -487,10 +452,10 @@ def test_run_step_journals_the_loop_and_restores_exactly(
     from adaptdl_tpu import checkpoint
 
     monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     data = config.make_dataset(sizes, 5, 8)
     batch = {k: v[:2] for k, v in data.items()}
-    built = _built(monkeypatch, sizes)
+    built = configurations.built(monkeypatch, NAME, sizes, geometry=GEOMETRY)
     trainer = built["trainer"]
     holder = {"state": trainer.init_state()}
     ck = trainer.make_checkpoint_state(
@@ -499,7 +464,7 @@ def test_run_step_journals_the_loop_and_restores_exactly(
     before = len(trace.snapshot_spans())
     trainer._calibrated.add(1)
     holder["state"], metrics = trainer.run_step(
-        holder["state"], batch, _loader_stub(1, 1)
+        holder["state"], batch, loader_stub(1, 1)
     )
     assert np.isfinite(float(metrics["loss"]))
     exits = metrics["counters"]["loop.exit"]
@@ -527,11 +492,13 @@ def test_run_step_journals_the_loop_and_restores_exactly(
         "embed", "layer_0", "layer_1", "RMSNorm_0", "exit_gate", "lm_head"
     }
     holder["state"], after = trainer.run_step(
-        holder["state"], batch, _loader_stub(1, 1)
+        holder["state"], batch, loader_stub(1, 1)
     )
     ck.unregister()
 
-    again = _built(monkeypatch, sizes, seed=11)["trainer"]
+    again = configurations.built(
+        monkeypatch, NAME, sizes, seed=11, geometry=GEOMETRY
+    )["trainer"]
     holder2 = {"state": again.init_state()}
     ck2 = again.make_checkpoint_state(
         lambda: holder2["state"], lambda s: holder2.__setitem__("state", s)
@@ -545,7 +512,7 @@ def test_run_step_journals_the_loop_and_restores_exactly(
     assert int(holder2["state"].step) == 1
     again._calibrated.add(1)
     holder2["state"], resumed = again.run_step(
-        holder2["state"], batch, _loader_stub(1, 1)
+        holder2["state"], batch, loader_stub(1, 1)
     )
     assert float(resumed["loss"]) == float(after["loss"])
     ck2.unregister()
@@ -562,7 +529,7 @@ def test_the_step_applies_the_gradient_summed_over_the_passes(monkeypatch):
     from adaptdl_tpu.trainer import ElasticTrainer
 
     monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    config, sizes = _config_module(), _sizes()
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
     model = TransformerLM(config.model_config(sizes))
     loss_fn = looped_lm_loss_fn(model, beta=0.1, chunk_size=32)
     data = config.make_dataset(sizes, 5, 4)
@@ -576,7 +543,7 @@ def test_the_step_applies_the_gradient_summed_over_the_passes(monkeypatch):
     )
     state = trainer.init_state()
     trainer._calibrated.add(1)
-    state, _ = trainer.run_step(state, batch, _loader_stub(1, 0))
+    state, _ = trainer.run_step(state, batch, loader_stub(1, 0))
     moved = jax.tree.map(
         lambda a, b: a - b, params, trainer.params_tree(state)
     )

@@ -15,19 +15,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 flash_mod = importlib.import_module("adaptdl_tpu.ops.flash_attention")
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as exc:  # noqa: BLE001 - no TPU compiler here
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
-
-
 @pytest.fixture
 def tool(monkeypatch):
     monkeypatch.syspath_prepend(os.path.join(REPO, "tools"))

@@ -10,9 +10,8 @@ the whole model, the counts)."""
 
 import dataclasses
 import functools
-import json
-import os
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,59 +25,18 @@ from adaptdl_tpu.models.transformer import (
     TransformerConfig,
     causal_attention,
 )
-from adaptdl_tpu.ops import kda as kda_op
 from adaptdl_tpu.ops.flash_attention import flash_attention
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = "qwen3-next-80b-a3b"
-TINY = {
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "shared_expert_intermediate_size": 16,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
-    "linear_key_head_dim": 8, "linear_value_head_dim": 8,
-    "linear_attn_config": {
-        "num_heads": 4, "head_dim": 8, "kda_layers": [1, 2, 3],
-    },
-    "router_width": 16, "experts_held": 4, "num_experts": 4,
-    "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
-    "kda_chunk": 16, "head_chunk_rows": 32, "compute_dtype": "float32",
-}
 
 
 @pytest.fixture(autouse=True)
-def _rows_of_several_chunks(monkeypatch):
+def _rows_of_several_chunks():
     """The rule's chunk is a constant of ``ops/kda.py`` (64); the
     models of these tests run rows of 64 tokens, several chunks at
-    TINY's."""
-    monkeypatch.setattr(kda_op, "CHUNK", TINY["kda_chunk"])
-
-
-@functools.cache
-def _config_module():
-    from benchmark import manifest
-
-    return manifest.load_module(
-        os.path.join(ROOT, "benchmark", "configs", NAME + ".py")
-    )
-
-
-def _real_sizes():
-    with open(os.path.join(ROOT, "benchmark", "configs", NAME + ".json")) as f:
-        return json.load(f)
-
-
-def _sizes(**changes):
-    sizes = _real_sizes()
-    sizes.update(TINY)
-    sizes.update(changes)
-    return sizes
-
-
-def _built(monkeypatch, sizes, seed=3):
-    monkeypatch.setenv("ADAPTDL_NUM_REPLICAS", "1")
-    geometry = {"global_batch": 4, "atomic_bsz": 2, "accum_steps": 1}
-    return _config_module().build(sizes, geometry, seed)
+    the tiny sizes' ``kda_chunk``."""
+    with configurations.rows_of_several_chunks(NAME):
+        yield
 
 
 def _events(name, since=0):
@@ -98,8 +56,8 @@ def _close(got, want, tol=2e-4):
 
 
 def _mixer_case(monkeypatch, kind):
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     at = config.checked_mixers(sizes)[kind]
     layer = config.reference_weights(params, sizes)["layers"][at][kind]
@@ -299,8 +257,8 @@ def test_gated_attention_hands_the_kernels_a_runs_own_kv_heads(run):
 
 
 def test_shared_expert_is_gated(monkeypatch):
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     moe = params["layer_1"]["moe"]
     layer = config.routed_weights(moe)
@@ -343,7 +301,7 @@ def test_shared_expert_is_gated(monkeypatch):
 
 
 def test_bf16_router_scores_choose_other_sets():
-    config, sizes = _config_module(), _sizes(
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME, 
         router_width=64, num_experts_per_tok=6
     )
     keys = jax.random.split(jax.random.key(0), 2)
@@ -360,9 +318,11 @@ def test_the_shares_add_up_to_the_whole_layer():
     chips compute of the routed result, with the gated shared expert
     (which every chip computes alike) counted ONCE, adds up to the
     uncut reference's layer."""
-    config = _config_module()
-    sizes = _sizes(router_width=32, experts_held=2, num_experts=2,
-                   num_experts_per_tok=5)
+    config = configurations.module(NAME)
+    sizes = configurations.sizes(
+        NAME, router_width=32, experts_held=2, num_experts=2,
+        num_experts_per_tok=5,
+    )
     keys = jax.random.split(jax.random.key(11), 9)
     d, f = 32, 16
     whole = {

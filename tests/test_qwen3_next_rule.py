@@ -8,18 +8,16 @@ refuses."""
 
 import functools
 
+import configurations
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from test_qwen3_next import (  # noqa: F401 (the fixture is autouse)
-    _built,
+    NAME,
     _close,
-    _config_module,
     _events,
-    _real_sizes,
     _rows_of_several_chunks,
-    _sizes,
 )
 
 from adaptdl_tpu import trace
@@ -146,7 +144,7 @@ def test_zero_centred_norm_scales_by_one_plus_w():
     assert not np.asarray(params["scale"]).any()  # initialised 0
     w = jax.random.normal(jax.random.key(2), (16,))
     got = norm.apply({"params": {"scale": w}}, x)
-    want = _config_module()._rms_norm(x, w, 1e-6)
+    want = configurations.module(NAME)._rms_norm(x, w, 1e-6)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     plain = x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + 1e-6)
     np.testing.assert_allclose(
@@ -159,7 +157,7 @@ def test_rotary_over_the_first_lanes_only(lanes):
     x = jax.random.normal(jax.random.key(0), (2, 24, 3, 16))
     positions = jnp.arange(24)
     got = rope(x, positions, 1e7, lanes)
-    want = _config_module()._rotary(x, 1e7, lanes)
+    want = configurations.module(NAME)._rotary(x, 1e7, lanes)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(got[..., lanes:], x[..., lanes:])
     turned = got[:, 1:, :, :lanes] - x[:, 1:, :, :lanes]
@@ -176,8 +174,8 @@ def test_loss_and_gradients_equal_the_reference(monkeypatch):
     attention; all routed with a gated shared expert), remat on, the
     flash kernels, the delta rule's kernels, a share of 4 of 16
     experts, the untied head."""
-    config, sizes = _config_module(), _sizes()
-    built = _built(monkeypatch, sizes)
+    config, sizes = configurations.module(NAME), configurations.sizes(NAME)
+    built = configurations.built(monkeypatch, NAME, sizes)
     params = built["trainer"].params_tree(built["trainer"].init_state())
     data = config.make_dataset(sizes, 5, 4)
     batch = {k: jnp.asarray(v[:2]) for k, v in data.items()}
@@ -208,7 +206,7 @@ def test_the_parameters_are_the_files_sum(monkeypatch):
     """The published widths give the count the configuration's file
     states: 625.7 M."""
     monkeypatch.setattr(kda_op, "CHUNK", 64)  # the real sizes' chunk
-    config, sizes = _config_module(), _real_sizes()
+    config, sizes = configurations.module(NAME), configurations.published(NAME)
     model = transformer.TransformerLM(config.model_config(sizes))
     shapes = jax.eval_shape(
         lambda: model.init(
@@ -235,7 +233,7 @@ def test_flops_count_agrees_with_the_configurations(monkeypatch):
     from adaptdl_tpu.flops import transformer_train_flops
 
     monkeypatch.setattr(kda_op, "CHUNK", 64)  # the real sizes' chunk
-    config, sizes = _config_module(), _real_sizes()
+    config, sizes = configurations.module(NAME), configurations.published(NAME)
     seq = sizes["sequence_length"]
     got = transformer_train_flops(config.model_config(sizes), 1, seq)
     want = config.train_flops_per_unit(sizes) * seq
@@ -287,7 +285,10 @@ def test_a_remat_block_keeps_the_rules_output_by_name():
     """``block_remat`` of a model with gdn layers saves ``kda_out``
     beside the flash kernel's names."""
     since = len(trace.snapshot_spans())
-    transformer.block_remat(_config_module().model_config(_sizes()), (2, 64))
+    config = configurations.module(NAME).model_config(
+        configurations.sizes(NAME)
+    )
+    transformer.block_remat(config, (2, 64))
     (attrs,) = _events("remat.policy", since)
     assert attrs["saved_names"].split(",") == [
         "flash_out", "flash_lse", "kda_out"

@@ -67,15 +67,30 @@ def _run(trainer, loader, state, steps, after_step=None):
     return state
 
 
+# What float64 loses over a cycle's few dozen differences of a clock
+# that reads up to 1e7 s: a bound on rounding, not on elapsed time.
+ROUNDING_S = 1e-6
+
+
 def _identity_gap(rec):
+    """The phases against ``dur``: both are sums of differences of
+    the SAME clock reads (every mark ends one phase and starts the
+    next), so they differ by rounding alone, whatever the load."""
     attrs = rec["attrs"]
     return abs(sum(attrs[f"{p}_s"] for p in PHASES) - rec["dur"])
 
 
-def test_a_loop_of_25_steps_writes_two_whole_cycles():
+def test_a_loop_of_25_steps_writes_two_whole_cycles(monkeypatch):
     """The trainer pulls early once, then every tenth step: pulls at
     steps 1, 11 and 21, so one cycle of the first step alone and two
     whole ones; steps 22-25 close none."""
+    reads, clock = [], trace._clock
+
+    def read():
+        reads.append(clock())
+        return reads[-1]
+
+    monkeypatch.setattr(trace, "_clock", read)
     trainer, loader, state = _job()
     _run(trainer, loader, state, 25)
     cycles = _cycles()
@@ -84,7 +99,7 @@ def test_a_loop_of_25_steps_writes_two_whole_cycles():
     assert trace.step_cycle.steps_total == 25
     for rec in cycles:
         attrs = rec["attrs"]
-        assert _identity_gap(rec) < 1e-3, rec
+        assert _identity_gap(rec) < ROUNDING_S, rec
         for name in PHASES:
             assert 0.0 <= attrs[f"{name}_max_s"] <= attrs[f"{name}_s"]
         assert len(attrs["dispatch_steps_s"]) == attrs["steps"]
@@ -105,10 +120,17 @@ def test_a_loop_of_25_steps_writes_two_whole_cycles():
     # The batch size's first-time work (calibration, the program's
     # build) is named, and is the first cycle's alone.
     assert cycles[0]["attrs"]["calibrate_s"] > 0.0
-    # A cycle runs from the previous pull's return to this one's: the
-    # spans abut on the wall clock.
-    for prev, rec in zip(cycles, cycles[1:]):
-        assert rec["ts"] == pytest.approx(prev["ts"] + prev["dur"], abs=5e-3)
+    # A cycle runs from the previous pull's return to this one's: laid
+    # end to end from the first mark, each cycle ends ON a read of the
+    # marks' clock (the mark that closed it and started the next), so
+    # no time between two cycles is lost or counted twice. (``ts`` is
+    # another clock, read when the record is written: it places a
+    # cycle for a viewer and is held to nothing here.)
+    end = reads[0]
+    for rec in cycles:
+        assert rec["dur"] > 0.0
+        end += rec["dur"]
+        assert min(abs(end - at) for at in reads) < ROUNDING_S
     # One record a pull: the wait is the cycle's pull_s, no span of
     # its own.
     assert not [
@@ -140,7 +162,7 @@ def test_a_sleep_in_the_callers_loop_is_outside_and_one_in_the_loader_data_next(
     assert 0.03 <= third["attrs"]["exposed_outside_s"] < 0.05
     assert second["attrs"]["exposed_outside_s"] < 0.03
     for rec in (second, third):
-        assert _identity_gap(rec) < 1e-3
+        assert _identity_gap(rec) < ROUNDING_S
         hosts = sum(
             rec["attrs"][f"{p}_s"]
             for p in ("shard", "dispatch", "after_pull")
