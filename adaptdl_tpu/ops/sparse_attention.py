@@ -70,16 +70,39 @@ leaves as ``[batch, heads, head_dim, seq]`` and is turned outside.
 All query heads of a query tile are one grid step, so the mask of a
 tile is computed once for the 32 heads and dK / dV sum over a kv head's
 group inside the kernel: no ``repeat`` of K and V. Inside the step
-every attention kernel walks the kv heads in a loop and a kv head's
-group of query heads as straight-line code in the loop's body, heads 0
-.. ``heads - 1`` in order, so every sum keeps its order (the body is
-``group`` copies, 8 at the published widths): only inside one body
-does the scheduler run one head's matrix products under another's
-softmax. The forward and the loss's second pass take the group's
-logits as one product ahead of the heads (``_over_heads``;
-``sparse.schedule`` says ``head_loop="kv_groups_unrolled"`` and
-``group``); the backward multiplies them head by head
-(``_backward_tile``).
+every kernel walks the heads 0 .. ``heads - 1`` in order, so every sum
+keeps its order. The forward and the loss's second pass
+(``_over_heads``; ``sparse.schedule``:
+``head_loop="heads_abreast_in_pieces"``) stand as many heads as keep
+within ``_LINE_UPDATES`` updates — all 32 at the published widths —
+abreast as straight-line code, because only inside one straight line
+does the scheduler run one update's matrix products under another's
+max / exp / sum, and every line has a head and a tail where nothing
+does. The forward makes a head's update of a tile in pieces of 128 keys
+where ``head_dim`` and both tiles are whole 128s (``_piece``; the key
+tile elsewhere): a piece's float32 logits ``[128 keys, 128 queries]``
+are sixteen vregs that are produced, masked by a slice of the tile's
+ONE mask, exponentiated and consumed without a store to VMEM, and a
+head's (max, sum, accumulator) are read once and written once a tile.
+By the TPU compiler's bundle listing (PERF.md, PR 59; a bundle holds
+one store, three loads, four VALU ops, a ``vmatmul`` every fourth): a
+tile of the forward was 2088 bundles of mask (sixteen index products
+whose K = 64 costs a ``vmatmul`` all the same: at the MXU's rate alone)
+and 4 trips of 2589 over the kv heads with a group's 8 heads and its
+logits as ONE ``[512, 8 x 128]`` product in the body — 1503 stores a
+trip, two runs of ~400 bundles with the store slot full and no product
+issuing, 12 700 bundles for the 10 240 its 2560 ``vmatmul`` need — and
+is 10 513 as one line. Pieces in the same loop over kv heads moved
+nothing (a ~500-bundle head and tail a trip at half the MXU's rate took
+what the store slot gave back), one line without pieces little: 21.31
+ms a call as it was, 21.28 / 20.28 / **16.65** (v5e, row of 16 384).
+The second pass keeps no running statistics and the compiler already
+held its logits and its ``[512, 128]`` sum in registers (139 stores a
+trip); it takes a head's whole tile an update and gains from the one
+line alone, 11.41 -> 9.91 ms. The two calls are jitted (a line is
+thousands of equations; traced once a signature). The backward
+multiplies head by head in a loop over the kv heads with a group's
+heads in its body (``_backward_tile``), untouched.
 
 MXU operands in the input dtype with float32 accumulation; index
 scores, thresholds, softmax statistics and accumulators in float32.
@@ -126,7 +149,15 @@ SAVED_NAMES = (
 PATH = "causal_tiles_masked"
 # How the forward and the loss's second pass walk a tile's query heads
 # (``_over_heads``), as ``sparse.schedule`` reports it.
-HEAD_LOOP = "kv_groups_unrolled"
+HEAD_LOOP = "heads_abreast_in_pieces"
+_LANES = 128
+# Updates (a head's with one piece of keys) that one straight line of
+# ``_over_heads`` holds at most: the published widths' 32 heads x 4
+# pieces stand in ONE line. Each line pays its head and tail once (an
+# update's chain product -> max -> exp -> product -> rescale is ~450
+# bundles long and nothing runs under the first's or the last's), ~1.2
+# ms a call at a row of 16 384, and the kernels' code grows with it.
+_LINE_UPDATES = 128
 
 
 def _use_interpret() -> bool:
@@ -232,40 +263,64 @@ def _first_query_tile(kb, tq: int, tk: int):
     return (kb * tk) // tq
 
 
-def _over_heads(q_ref, k_ref, scale: float, head, carry):
-    """``carry = head(h, g, s, carry)`` for the query heads ``h = 0 ..
-    heads - 1`` in that order: ``g = h // group`` is the head's kv head
-    and ``s`` its logits ``k[g] . q[h] * scale``, ``[keys, queries]``
-    float32. How the forward and the loss's second pass walk a tile's
-    heads (``sparse.schedule``: ``head_loop``, ``group``).
+def _piece(tq: int, tk: int, head_dim: int) -> int:
+    """Keys of one update of the forward (the loss's second pass keeps
+    no running statistics and takes a head's whole tile: ``_kl_kernel``).
+    Where ``head_dim`` and both tiles are whole ``_LANES``: ``_LANES``,
+    so an update's float32 logits ``[128 keys, 128 queries]`` are
+    sixteen vregs that are produced, masked, exponentiated and consumed
+    without a store to VMEM. Any other width: the key tile, one update
+    a head and tile (the flash forward's small updates ran at HALF
+    speed at widths 64 and 192: PERF.md, PR 57)."""
+    whole = all(n % _LANES == 0 for n in (tq, tk, head_dim))
+    return _LANES if whole else tk
 
-    A loop over the kv heads whose body is the group's ``heads //
-    kv_heads`` query heads as straight-line code (``group`` copies of
-    ``head``'s body, so the compiled kernel grows with the group;
-    ``heads == kv_heads`` is one head a step), with the group's logits
-    as ONE product at the top of the body: the kv head's keys against
-    the group's queries viewed ``[group * queries, head_dim]`` (a merge
-    of leading dimensions), a head's share a slice of lanes. Across the
-    iterations of a loop the scheduler starts nothing of one head under
-    the previous one's softmax; inside one body it does, but a product
-    issued after a store to scratch at a dynamic index (a head's
-    statistics and accumulator) waits for that store, so the products
-    go ahead of the first head's stores (PERF.md, PR 37)."""
-    heads, tq, d = q_ref.shape[1:]
-    kv_heads = k_ref.shape[1]
+
+def _abreast(heads: int, kv_heads: int, pieces: int) -> int:
+    """Query heads that stand in one straight line of ``_over_heads``:
+    the heads of as many kv heads as divide ``kv_heads`` and keep the
+    line within ``_LINE_UPDATES`` updates, of one kv head at least."""
     group = heads // kv_heads
+    fit = max(1, _LINE_UPDATES // (group * pieces))
+    return group * max(
+        n for n in range(1, fit + 1) if kv_heads % n == 0
+    )
 
-    def group_of(g, carry):
-        queries = q_ref[0, pl.ds(g * group, group)].reshape(group * tq, d)
-        logits = _nt(k_ref[0, g], queries)
-        for r in range(group):
-            carry = head(
-                g * group + r, g,
-                logits[:, r * tq:(r + 1) * tq] * scale, carry,
-            )
+
+def _over_heads(heads: int, kv_heads: int, pieces: int, head, carry):
+    """``carry = head(h, g, carry)`` for the query heads ``h = 0 ..
+    heads - 1`` in that order, ``g = h // group`` the head's kv head;
+    ``pieces`` updates a head makes. How the forward and the loss's
+    second pass walk a tile's heads (``sparse.schedule``: ``head_loop``,
+    ``piece``, ``pieces_in_flight``).
+
+    ``_abreast`` heads are straight-line code: as many copies of
+    ``head``'s body, in the forward itself a head's updates with the
+    tile's pieces of keys (``_piece``), none of them depending on
+    another head's. Only inside one straight line does the scheduler
+    run one update's matrix products under another's max / exp / sum,
+    and each line pays for its head and tail, where nothing does: at
+    the published widths (32 heads on 4 kv heads, 4 pieces) all 128
+    updates are ONE line with static indices, 10 513 bundles a tile for
+    the 10 240 its 2560 ``vmatmul`` need, where a loop over the kv
+    heads with a group's 8 heads in its body took 12 700 (PERF.md, PR
+    59: the bundle listing). Heads past one line are a loop over lines,
+    and then a head's statistics sit at a dynamic index, behind whose
+    stores the scheduler moves no product (PERF.md, PR 37)."""
+    group = heads // kv_heads
+    abreast = _abreast(heads, kv_heads, pieces)
+
+    def line(first, carry):
+        for r in range(abreast):
+            h = first + r
+            carry = head(h, h // group, carry)
         return carry
 
-    return lax.fori_loop(0, kv_heads, group_of, carry)
+    if abreast == heads:
+        return line(0, carry)
+    return lax.fori_loop(
+        0, heads // abreast, lambda i, carry: line(i * abreast, carry), carry
+    )
 
 
 # ---- index scores + selection ---------------------------------------
@@ -438,7 +493,10 @@ def _fwd_kernel(
     q_ref, k_ref, vt_ref, qi_ref, ki_ref, wt_ref, thr_ref, cut_ref,
     ot_ref, lse_ref, m_ref, l_ref, acc_ref, *, scale: float,
 ):
-    tq, tk = q_ref.shape[2], k_ref.shape[2]
+    heads, tq, head_dim = q_ref.shape[1:]
+    kv_heads, tk = k_ref.shape[1:3]
+    piece = _piece(tq, tk, head_dim)
+    pieces = [slice(at, at + piece) for at in range(0, tk, piece)]
     qb, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -453,25 +511,38 @@ def _fwd_kernel(
             ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, kb * tk, qb * tq
         )
 
-        def head(h, g, s, carry):
+        def update(stats, s, vt):
+            """One online-softmax update of a head's (max, sum,
+            accumulator) with a piece's masked logits ``s``."""
+            m_prev, l_prev, acc = stats
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
             # One masked copy of the logits serves the maximum and the
             # probabilities: exp(NEG_INF - m) is 0.0, unless the query
-            # has selected nothing yet and m is NEG_INF itself (every
-            # masked pair would count 1 until the first selected key's
-            # alpha = 0 wiped it).
-            s = jnp.where(sel, s, NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            # has selected nothing yet, in this piece or before it, and
+            # m is NEG_INF itself (every masked pair would count 1
+            # until the first selected key's alpha = 0 wiped it).
             p = jnp.exp(s - jnp.where(m_new == NEG_INF, 0.0, m_new))
             alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=0, keepdims=True)
-            acc_ref[h] = alpha * acc_ref[h] + _nn(
-                vt_ref[0, g], p.astype(vt_ref.dtype)
+            return (
+                m_new,
+                alpha * l_prev + jnp.sum(p, axis=0, keepdims=True),
+                alpha * acc + _nn(vt, p.astype(vt.dtype)),
             )
-            m_ref[h] = m_new
+
+        def head(h, g, carry):
+            # Read once and written once a tile; between, the head's
+            # pieces are as many updates in registers.
+            stats = m_ref[h], l_ref[h], acc_ref[h]
+            for keys in pieces:
+                s = _nt(k_ref[0, g, keys, :], q_ref[0, h]) * scale
+                stats = update(
+                    stats, jnp.where(sel[keys], s, NEG_INF),
+                    vt_ref[0, g, :, keys],
+                )
+            m_ref[h], l_ref[h], acc_ref[h] = stats
             return carry
 
-        _over_heads(q_ref, k_ref, scale, head, 0)
+        _over_heads(heads, kv_heads, len(pieces), head, 0)
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _done():
@@ -539,59 +610,65 @@ def _by_key(tq, tk):
 def _attention_forward(
     q, k, vt, qi, ki, wt, thr, cut, scale, tq, tk, out_dtype=None
 ):
+    return _forward_call(
+        q, k, vt, qi, ki, wt, thr, cut, scale, tq, tk, out_dtype,
+        _use_interpret(),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
+def _forward_call(
+    q, k, vt, qi, ki, wt, thr, cut, scale, tq, tk, out_dtype, interpret
+):
+    """The forward kernel's call, jitted: a model makes it once a
+    layer in every program, a line of ``_LINE_UPDATES`` updates is
+    thousands of equations, and every call after the first of a
+    signature finds the kernel traced. The scope is the custom call's
+    name in a compiled program and a device trace."""
     batch, heads, seq_len, d = q.shape
     kv_heads = k.shape[1]
     hi, di = qi.shape[1], qi.shape[3]
     qs = _query_specs(heads, hi, tq, d, di, _by_query(tq, tk)[0])
     ks = _key_specs(kv_heads, tk, d, di, _by_query(tq, tk)[1])
     vma = _vma(q, k, vt, qi, ki, wt)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale),
-        grid=(batch, seq_len // tq, seq_len // tk),
-        in_specs=[
-            qs["q"], ks["k"], ks["kt"], qs["qi"], ks["ki"], qs["wt"],
-            qs["row"], qs["row"],
-        ],
-        out_specs=[qs["ot"], qs["stat"]],
-        out_shape=[
-            jax.ShapeDtypeStruct(
-                (batch, heads, d, seq_len), out_dtype or q.dtype, vma=vma
-            ),
-            jax.ShapeDtypeStruct(
-                (batch, heads, 1, seq_len), jnp.float32, vma=vma
-            ),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((heads, 1, tq), jnp.float32),
-            pltpu.VMEM((heads, 1, tq), jnp.float32),
-            pltpu.VMEM((heads, d, tq), jnp.float32),
-        ],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=_use_interpret(),
-        name=FWD_KERNEL_NAME,
-    )(q, k, vt, qi, ki, wt, thr, cut)
+    with jax.named_scope(FWD_KERNEL_NAME):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=scale),
+            grid=(batch, seq_len // tq, seq_len // tk),
+            in_specs=[
+                qs["q"], ks["k"], ks["kt"], qs["qi"], ks["ki"], qs["wt"],
+                qs["row"], qs["row"],
+            ],
+            out_specs=[qs["ot"], qs["stat"]],
+            out_shape=[
+                jax.ShapeDtypeStruct(
+                    (batch, heads, d, seq_len), out_dtype or q.dtype,
+                    vma=vma,
+                ),
+                jax.ShapeDtypeStruct(
+                    (batch, heads, 1, seq_len), jnp.float32, vma=vma
+                ),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((heads, 1, tq), jnp.float32),
+                pltpu.VMEM((heads, 1, tq), jnp.float32),
+                pltpu.VMEM((heads, d, tq), jnp.float32),
+            ],
+            compiler_params=_params("parallel", "parallel", "arbitrary"),
+            interpret=interpret,
+            name=FWD_KERNEL_NAME,
+        )(q, k, vt, qi, ki, wt, thr, cut)
 
 
 # ---- the indexer's loss, forward ------------------------------------
-
-
-def _mean_probs(q_ref, k_ref, lse_ref, sel, scale: float):
-    """``p^T [keys, queries]``: the mean over the query heads of the
-    selected pairs' probabilities."""
-    def head(h, g, s, total):
-        return total + jnp.where(sel, jnp.exp(s - lse_ref[0, h]), 0.0)
-
-    total = _over_heads(
-        q_ref, k_ref, scale, head, jnp.zeros(sel.shape, jnp.float32)
-    )
-    return total / q_ref.shape[1]
 
 
 def _kl_kernel(
     q_ref, k_ref, qi_ref, ki_ref, wt_ref, thr_ref, cut_ref, lse_ref,
     ilse_ref, li_ref, acc_ref, *, scale: float,
 ):
-    tq, tk = q_ref.shape[2], k_ref.shape[2]
+    heads, tq = q_ref.shape[1:3]
+    kv_heads, tk = k_ref.shape[1:3]
     qb, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -603,7 +680,19 @@ def _kl_kernel(
         sel, scores = _tile_mask(
             ki_ref, qi_ref, wt_ref, thr_ref, cut_ref, kb * tk, qb * tq
         )
-        p = _mean_probs(q_ref, k_ref, lse_ref, sel, scale)
+
+        def head(h, g, total):
+            # No running statistics, so no pieces: an update is a head's
+            # with the whole key tile, whose logits the compiler hands
+            # from the product's results through exp into the sum,
+            # vreg by vreg, and which it keeps in registers (the bundle
+            # listing: 602 stores in a tile's 6280 bundles).
+            s = _nt(k_ref[0, g], q_ref[0, h]) * scale
+            return total + jnp.where(sel, jnp.exp(s - lse_ref[0, h]), 0.0)
+
+        p = _over_heads(
+            heads, kv_heads, 1, head, jnp.zeros(sel.shape, jnp.float32)
+        ) / heads  # p^T [keys, queries]: the mean over the query heads
         term = p * (
             jnp.log(jnp.where(p > 0, p, 1.0)) - scores + ilse_ref[0]
         )
@@ -617,27 +706,39 @@ def _kl_kernel(
 
 
 def _index_loss(q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk):
+    return _index_loss_call(
+        q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk,
+        _use_interpret(),
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12))
+def _index_loss_call(
+    q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk, interpret
+):
+    """The second pass's call, jitted as ``_forward_call`` is."""
     batch, heads, seq_len, d = q.shape
     hi, di = qi.shape[1], qi.shape[3]
     qs = _query_specs(heads, hi, tq, d, di, _by_query(tq, tk)[0])
     ks = _key_specs(k.shape[1], tk, d, di, _by_query(tq, tk)[1])
-    return pl.pallas_call(
-        functools.partial(_kl_kernel, scale=scale),
-        grid=(batch, seq_len // tq, seq_len // tk),
-        in_specs=[
-            qs["q"], ks["k"], qs["qi"], ks["ki"], qs["wt"], qs["row"],
-            qs["row"], qs["stat"], qs["row"],
-        ],
-        out_specs=qs["row"],
-        out_shape=jax.ShapeDtypeStruct(
-            (batch, 1, seq_len), jnp.float32,
-            vma=_vma(q, k, qi, ki, wt),
-        ),
-        scratch_shapes=[pltpu.VMEM((1, tq), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
-        interpret=_use_interpret(),
-        name=KL_KERNEL_NAME,
-    )(q, k, qi, ki, wt, thr, cut, lse, ilse)
+    with jax.named_scope(KL_KERNEL_NAME):
+        return pl.pallas_call(
+            functools.partial(_kl_kernel, scale=scale),
+            grid=(batch, seq_len // tq, seq_len // tk),
+            in_specs=[
+                qs["q"], ks["k"], qs["qi"], ks["ki"], qs["wt"], qs["row"],
+                qs["row"], qs["stat"], qs["row"],
+            ],
+            out_specs=qs["row"],
+            out_shape=jax.ShapeDtypeStruct(
+                (batch, 1, seq_len), jnp.float32,
+                vma=_vma(q, k, qi, ki, wt),
+            ),
+            scratch_shapes=[pltpu.VMEM((1, tq), jnp.float32)],
+            compiler_params=_params("parallel", "parallel", "arbitrary"),
+            interpret=interpret,
+            name=KL_KERNEL_NAME,
+        )(q, k, qi, ki, wt, thr, cut, lse, ilse)
 
 
 # ---- backward -------------------------------------------------------
@@ -671,10 +772,10 @@ def _backward_tile(
     head ``g`` (a loop) its query heads ``h`` in turn (unrolled: the
     scheduler overlaps one head's products with the next one's
     softmax; a head's logits are its own product here, because ONE
-    product a group as in ``_over_heads`` reads 47.5 ms a call for
-    45.2 where five products a head already fill the MXU: PERF.md, PR
-    37), ``on_head(h, g, p, ds)`` with ``p`` and ``ds`` ``[keys,
-    queries]`` float32; then ``dL_I / dI`` and per indexer head
+    product a group, as the forward took it until PR 59, reads 47.5 ms
+    a call for 45.2 where five products a head already fill the MXU:
+    PERF.md, PR 37), ``on_head(h, g, p, ds)`` with ``p`` and ``ds``
+    ``[keys, queries]`` float32; then ``dL_I / dI`` and per indexer head
     ``on_index_head(j, dI * relu_j summed over keys [1, queries], dI *
     w_j where the head is live [keys, queries])``."""
     heads, kv_heads = q_ref.shape[1], k_ref.shape[1]
@@ -1098,6 +1199,7 @@ def sparse_attention(
     backward, held = backward_schedule(
         k.shape[1], seq_len, head_dim, qi.shape[3]
     )
+    piece = _piece(tq, tk, head_dim)
     trace.event(
         "sparse.schedule",
         rows=batch,
@@ -1117,6 +1219,11 @@ def sparse_attention(
         backward_vmem_bytes=held,
         head_loop=HEAD_LOOP,
         group=heads // k.shape[1],
+        # An update of the forward: one head's with ``piece`` keys;
+        # ``pieces_in_flight`` heads stand abreast in one straight
+        # line, each with the tile's pieces.
+        piece=piece,
+        pieces_in_flight=_abreast(heads, k.shape[1], tk // piece),
     )
     return _sparse_attention(
         q, k, v, qi, ki, w, topk, scale, tq, tk, out_dtype

@@ -154,7 +154,9 @@ def _operands(
         # Index scores that grow with the key's position: a query
         # selects the keys just before it and none further back.
         qi, w = jnp.abs(qi), jnp.abs(w) + 0.1
-        ki = jnp.ones_like(ki) * (1.0 + jnp.arange(seq))[None, :, None] / 16
+        ki = jnp.ones_like(ki) * (
+            (1.0 + jnp.arange(seq))[None, :, None] * 4 / seq
+        )
     return (q, k, v, qi, ki, w), 128 if case == "shorter_than_topk" else 8
 
 
@@ -222,34 +224,86 @@ def test_the_two_backward_schedules_agree(case, monkeypatch):
             )
 
 
+# (seq, head_dim, queries a tile, keys a tile): the small tiles of the
+# tests above (head_dim 32: an update is a head's whole key tile); the
+# published tile and head_dim, where an update is one of the tile's
+# four pieces of 128 keys; head_dim 64 on the published tile, which
+# must take the whole tile again.
+_TILES = {
+    "tiles_of_16_x_32": (64, 32, 16, 32),
+    "four_pieces_a_tile": (1024, 128, 128, 512),
+    "head_dim_64": (1024, 64, 128, 512),
+    # ... and the same with a kv head's group a line of its own: more
+    # heads than one straight line holds, a loop over lines.
+    "four_pieces_a_tile_in_lines": (1024, 128, 128, 512),
+}
+
+
+@pytest.mark.parametrize("tiles", list(_TILES))
 @pytest.mark.parametrize("picks", ["scattered", "only_late_keys"])
 @pytest.mark.parametrize("group", [1, 2, 4])
-def test_the_forward_equals_the_reference_for_every_group(group, picks):
+def test_the_forward_equals_the_reference_for_every_group(
+    group, picks, tiles, monkeypatch
+):
     """``out``, ``lse`` and ``L_I`` of the forward kernels alone, four
-    query heads on 4, 2 and 1 kv heads (``heads == kv_heads`` is one
-    head a step of ``_over_heads``), on several tiles against the plain
-    reference. ``only_late_keys``: index scores that grow with the
-    key's position, so a query past the first key tile selects nothing
-    in it and meets its first selected key with its running maximum
-    still at NEG_INF, where the forward's one masked copy of the
-    logits reads ``NEG_INF - NEG_INF``."""
+    query heads on 4, 2 and 1 kv heads, on several tiles against the
+    plain reference, and ``count`` / ``tied`` and the mask the kernels
+    apply against its selection exactly. ``only_late_keys``: index
+    scores that grow with the key's position, so a query past the
+    first key tile selects nothing in it — nor, on a tile of four
+    pieces, in the pieces before its own — and meets its first selected
+    key in a LATE piece with its running maximum still at NEG_INF,
+    where the forward's one masked copy of the logits reads ``NEG_INF
+    - NEG_INF``; ``scattered`` leaves whole pieces empty for some
+    queries and not for their neighbours."""
     config = configurations.module(NAME)
-    tq, tk = 16, 32
-    operands, topk = _operands(picks, kv_heads=4 // group)
+    seq, dim, tq, tk = _TILES[tiles]
+    operands, topk = _operands(picks, seq=seq, dim=dim, kv_heads=4 // group)
     q, k, v, qi, ki, w = operands
-    _, heads, seq, dim = q.shape
-    _, member = _plain_sets(qi, ki, w, topk)
+    heads = q.shape[1]
+    scores, member = _plain_sets(qi, ki, w, topk)
     late = np.asarray(member)[tk + topk:, :tk]
     assert late.any() == (picks == "scattered")
 
+    piece = sparse._piece(tq, tk, dim)
+    assert piece == (128 if tiles.startswith("four_pieces") else tk)
+    if tiles.endswith("in_lines"):
+        monkeypatch.setattr(sparse, "_LINE_UPDATES", group * tk // piece)
+    assert sparse._abreast(heads, 4 // group, tk // piece) == (
+        group if tiles.endswith("in_lines") else heads
+    )
+    empty = ~np.asarray(member).reshape(seq, seq // piece, piece).any(-1)
+    causal = np.arange(seq)[:, None] >= np.arange(seq // piece) * piece
+    if piece < tk:
+        # Some query finds a piece at or before its own empty, and,
+        # with ``only_late_keys``, every piece before the last two.
+        assert (empty & causal).any()
+        assert picks == "scattered" or empty[seq - 1, :-2].all()
+
     scale = dim**-0.5
     wt = jnp.swapaxes(w, 1, 2)
-    thr, cut, ilse, _, _ = sparse.index_select(qi, ki, wt, topk, tq, tk)
+    thr, cut, ilse, count, tied = sparse.index_select(
+        qi, ki, wt, topk, tq, tk
+    )
     out_t, lse = sparse._attention_forward(
         q, k, jnp.swapaxes(v, 2, 3), qi, ki, wt, thr, cut, scale, tq, tk
     )
     index_loss = sparse._index_loss(
         q, k, qi, ki, wt, thr, cut, lse, ilse, scale, tq, tk
+    )
+
+    # The selection, which no piece moved: the mask the kernels apply
+    # (``_tile_mask`` on the whole tile), the keys a query and whether
+    # position split the keys at its threshold.
+    pairs, _, _, _ = sparse.selected_pairs(qi, ki, w, topk, tq, tk)
+    np.testing.assert_array_equal(np.asarray(pairs[0]) == 1, member)
+    np.testing.assert_array_equal(count[0, 0], member.sum(-1))
+    visible = np.tri(seq, dtype=bool)
+    kth = np.where(member, scores, np.inf).min(-1, keepdims=True)
+    at_threshold = (np.asarray(scores) == kth) & visible
+    np.testing.assert_array_equal(
+        np.asarray(tied[0, 0]) == 1,
+        at_threshold.sum(-1) > (at_threshold & member).sum(-1),
     )
 
     sizes = {"sa_config": {"topk": topk}}
@@ -291,8 +345,17 @@ def test_schedule_event_and_kernel_names():
     assert attrs["path"] == "causal_tiles_masked"
     assert (attrs["topk"], attrs["heads"], attrs["head_dim"]) == (8, 4, 32)
     assert attrs["keys_visited"] == 32 * 32
-    # How the forward-side kernels walk the 4 query heads on 2 kv heads.
-    assert (attrs["head_loop"], attrs["group"]) == ("kv_groups_unrolled", 2)
+    # How the forward-side kernels walk the 4 query heads on 2 kv
+    # heads: all four abreast in one straight line, and at head_dim 32
+    # an update is a head's whole key tile; at the published widths it
+    # is 128 keys, and the 32 heads' 4 pieces each are one line.
+    assert (attrs["head_loop"], attrs["group"]) == (
+        "heads_abreast_in_pieces", 2
+    )
+    assert (attrs["piece"], attrs["pieces_in_flight"]) == (32, 4)
+    assert sparse._piece(128, 512, 128) == 128
+    assert sparse._abreast(32, 4, 4) == 32
+    assert sparse._abreast(64, 8, 4) == 32  # two lines of 4 kv heads
     # dK and dV [2, 32, 32 -> 128 lanes] and dkI [32, 16 -> 128] in
     # float32 fit; the published widths at a row of 16 384 do too, a
     # row of 32 768 or 8 kv heads do not.
