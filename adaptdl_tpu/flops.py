@@ -78,7 +78,11 @@ def transformer_train_flops(
     ``attention_kinds`` describes at the kind's own heads and, under a
     window, over its band (``_kind_attention_layer``); a layer with routed
     experts at its router, the HELD share of the routed experts under
-    even routing, and the shared expert. A prediction module
+    even routing, and the shared expert (the same products whatever
+    the experts' gate function, ``experts_activation``, and whatever
+    the router reads, ``experts_routed_on``; a kind without rotary,
+    ``AttentionKind(rope=False)``, saves nothing counted here). A
+    prediction module
     (``mtp_depth``) is one more block of the last trunk layer's kind
     (its mixer, its routed or dense FFN), the ``[2 d, d]`` projection
     into it and a second pass of the head over its rows.

@@ -590,27 +590,44 @@ def _groups(start, rows: int, plan: RowPlan):
     return tile_expert, active, visited
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _forward_rows(start, rows: int, x, weights, w_gate, w_up, w_down, plan):
+# What a gated expert puts its gate's product through before it
+# multiplies the up product: SwiGLU's or ReGLU's (``routed_experts``:
+# ``activation``). Said once: the forward, the backward and the forward
+# run again in the backward all read it here.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _forward_rows(
+    start, rows: int, activation: str, x, weights, w_gate, w_up, w_down, plan
+):
     """The held experts over rows ``start .. start + rows`` of the
     plan: tokens into the rows (``x[row_token]``, a gather), the three
     grouped products, and rows back to tokens, ``y[t] = sum_j
     weights[t, j] * y_rows[dest[t, j]]`` over the choices whose row
-    lies there. Returns ``(y, residuals)``, the residuals all ``[rows,
-    .]``."""
+    lies there. Returns ``(out, residuals)``, the residuals all
+    ``[rows, .]``; ``out`` is ``y``, or under "relu" ``(y,
+    hidden_zero)``: the exact zeros among the gated hidden values of
+    the rows PLACED there (int32; one compare-and-sum beside the
+    gate's elementwise pass)."""
     groups = _groups(start, rows, plan)
     taken = x[lax.dynamic_slice_in_dim(plan.row_token, start, rows)]
     gate = gmm.grouped_matmul(taken, w_gate, *groups)
     up = gmm.grouped_matmul(taken, w_up, *groups)
-    hidden = jax.nn.silu(gate) * up
+    hidden = ACTIVATIONS[activation](gate) * up
     y_rows = gmm.grouped_matmul(hidden, w_down, *groups)
-    y = _tokens_from_rows(y_rows, plan.dest - start, weights)
-    return y, (taken, gate, up, hidden, y_rows)
+    out = _tokens_from_rows(y_rows, plan.dest - start, weights)
+    if activation == "relu":
+        assignment = lax.dynamic_slice_in_dim(plan.row_assignment, start, rows)
+        zero = (hidden == 0) & (assignment >= 0)[:, None]
+        out = out, jnp.sum(zero, dtype=jnp.int32)
+    return out, (taken, gate, up, hidden, y_rows)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def _backward_rows(
-    start, rows: int, residuals, d_y, x, weights, w_gate, w_up, w_down, plan
+    start, rows: int, activation: str, residuals, d_y, x, weights, w_gate,
+    w_up, w_down, plan,
 ):
     """The transpose of ``_forward_rows`` over the same rows; every
     direction is a gather. The combine's weight gradient is taken on
@@ -636,7 +653,7 @@ def _backward_rows(
     d_hidden, d_w_down = gmm.grouped_matmul_transposes(
         hidden, w_down, *groups, d_y_rows
     )
-    _, gated = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up)
+    _, gated = jax.vjp(lambda g, u: ACTIVATIONS[activation](g) * u, gate, up)
     d_gate, d_up = gated(d_hidden)
     d_taken_gate, d_w_gate = gmm.grouped_matmul_transposes(
         taken, w_gate, *groups, d_gate
@@ -680,8 +697,10 @@ def _past_the_bound(bound: int, plan: RowPlan, first, rest):
     )[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def expert_rows(bound: int, x, weights, w_gate, w_up, w_down, plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def expert_rows(
+    bound: int, activation: str, x, weights, w_gate, w_up, w_down, plan
+):
     """The held experts' part of the layer for a router's ``weights``
     and their ``plan``: ``_forward_rows`` over the first ``bound`` rows
     and, only where an active tile lies past them, over the plan's
@@ -691,34 +710,42 @@ def expert_rows(bound: int, x, weights, w_gate, w_up, w_down, plan):
     row it is given (the kernels skip inactive tiles by themselves), so
     the usual step is given ``bound`` rows; they are the same rows in
     the same tiles as in a buffer of any length, so the same bits.
+    Under ``activation`` "relu" the result is ``(y, hidden_zero)``
+    (``_forward_rows``), the count summed over the rows walked.
 
     One ``custom_vjp`` for the whole section: differentiated, either
     kind of control flow would write zeros in the shapes of the path
     not taken. The forward rule hands over the first rows' residuals;
     the forward of the rest, where it ran, runs again in the
     backward."""
-    return _expert_rows_fwd(bound, x, weights, w_gate, w_up, w_down, plan)[0]
+    return _expert_rows_fwd(
+        bound, activation, x, weights, w_gate, w_up, w_down, plan
+    )[0]
 
 
-def _expert_rows_fwd(bound: int, *operands):
-    y, residuals = _forward_rows(0, bound, *operands)
-    y = _past_the_bound(
-        bound, operands[-1], y,
-        lambda start, rows: _forward_rows(start, rows, *operands)[0],
+def _expert_rows_fwd(bound: int, activation: str, *operands):
+    out, residuals = _forward_rows(0, bound, activation, *operands)
+    out = _past_the_bound(
+        bound, operands[-1], out,
+        lambda start, rows: _forward_rows(
+            start, rows, activation, *operands
+        )[0],
     )
-    return y, (residuals, operands)
+    return out, (residuals, operands)
 
 
-def _expert_rows_bwd(bound: int, saved, d_y):
+def _expert_rows_bwd(bound: int, activation: str, saved, d_out):
     residuals, operands = saved
+    # (The count's cotangent is no number: an integer's.)
+    d_y = d_out[0] if activation == "relu" else d_out
 
     def rest(start, rows):
-        _, again = _forward_rows(start, rows, *operands)
-        return _backward_rows(start, rows, again, d_y, *operands)
+        _, again = _forward_rows(start, rows, activation, *operands)
+        return _backward_rows(start, rows, activation, again, d_y, *operands)
 
     grads = _past_the_bound(
         bound, operands[-1],
-        _backward_rows(0, bound, residuals, d_y, *operands), rest,
+        _backward_rows(0, bound, activation, residuals, d_y, *operands), rest,
     )
     return (*grads, None)
 
@@ -742,6 +769,8 @@ def routed_experts(
     router_kind: str = "sigmoid",
     shared_gate: str = "none",
     pieces_from: float | None = None,
+    routed_on=None,
+    activation: str = "silu",
 ):
     """One chip's share of a dropless top-k expert layer.
 
@@ -751,7 +780,7 @@ def routed_experts(
     w_down: [experts_held, f, d] — the held experts are
     ``first_expert .. first_expert + experts_held``. Returns
     ``(y [tokens, d], load)``: ``y[t] = sum over chosen AND held e of
-    weight_e * w_down[e] (silu(w_gate[e] x) * (w_up[e] x))``, and
+    weight_e * w_down[e] (act(w_gate[e] x) * (w_up[e] x))``, and
     ``load`` the int32 counters ``held_rows [experts_held]``,
     ``left_out`` (assignments to experts not held here) and
     ``dropped`` (assignments to held experts that found no row among
@@ -767,8 +796,21 @@ def routed_experts(
     "none": no gate, or no shared expert), said in ``moe.schedule``.
     ``pieces_from``: from how many bounds of worst case on the plan is
     walked in pieces of ``rows_bound`` rows (``rows_bound``; None:
-    ``ROWS_PIECES_FROM``).
+    ``ROWS_PIECES_FROM``). ``routed_on`` [tokens, d]: what the router
+    reads where that is not ``x``, what the experts multiply (a block
+    whose router stands on its INPUT, ahead of the mixer): the scores,
+    the choice and the weights come from it, and the router's gradient
+    goes to it and to the ``router`` leaf, none of it to ``x``.
+    ``activation``: a held expert's gate function, "silu" or "relu"
+    (``ACTIVATIONS``); under "relu" ``load`` gains ``hidden_zero``, the
+    exact zeros among the ``held_rows.sum() x f`` gated hidden values
+    of the rows placed.
     """
+    if activation not in ACTIVATIONS:
+        raise ValueError(
+            f"activation must be one of {sorted(ACTIVATIONS)}, got "
+            f"{activation!r}"
+        )
     if pieces_from is None:
         pieces_from = ROWS_PIECES_FROM
     tokens, _ = x.shape
@@ -806,17 +848,23 @@ def routed_experts(
         product="pallas:" + gmm.GMM_KERNEL_NAME + "," + gmm.TGMM_KERNEL_NAME,
         router=router_kind,
         shared_gate=shared_gate,
+        routed_on="ffn_input" if routed_on is None else "block_input",
+        activation=activation,
     )
+    read = x if routed_on is None else routed_on
+    assert read.shape == x.shape, (read.shape, x.shape)
     if router_kind == "softmax":
-        experts, weights = softmax_top_k(x, router, top_k, norm_eps, scale)
+        experts, weights = softmax_top_k(read, router, top_k, norm_eps, scale)
     else:
         experts, weights = sigmoid_top_k(
-            x, router, bias, top_k, norm_eps, scale
+            read, router, bias, top_k, norm_eps, scale
         )
     plan = lax.stop_gradient(
         plan_rows(experts, first_expert, experts_held, tile, capacity)
     )
-    y = expert_rows(bound, x, weights, w_gate, w_up, w_down, plan)
+    y = expert_rows(bound, activation, x, weights, w_gate, w_up, w_down, plan)
+    if activation == "relu":
+        y, hidden_zero = y
     # Counted from the plan and the rows the step walked, not assumed.
     rows_active = (plan.active_tiles[0] * tile).astype(jnp.int32)
     if capacity - bound > bound:  # walked in pieces of ``bound`` rows
@@ -838,4 +886,6 @@ def routed_experts(
         "experts": experts,
         "weights": lax.stop_gradient(weights),
     }
+    if activation == "relu":
+        load["hidden_zero"] = hidden_zero
     return y, load

@@ -58,13 +58,16 @@ class AttentionKind:
     """What ONE softmax-attention mixer kind of ``layer_types`` has of
     its own (``TransformerConfig.attention_kinds``); None = the
     config's. ``window``: query ``i`` sees the keys ``j <= i`` with
-    ``i - j < window``."""
+    ``i - j < window``. ``rope``: whether the kind's q and k are turned
+    by rotary at all (False: a layer without positional encoding
+    beside layers that have one)."""
 
     num_heads: int | None = None
     rope_theta: float | None = None
     rotary_dims: int | None = None
     yarn: Yarn | None = None
     window: int | None = None
+    rope: bool | None = None
 
 
 @dataclass(frozen=True)
@@ -187,6 +190,17 @@ class TransformerConfig:
     # cost a share whose router settles on its held experts is in
     # ``models/moe.py``.
     experts_pieces_from: float | None = None
+    # What a routed layer's router reads: "ffn_input", the normed
+    # state its experts multiply (after the mixer), or "block_input",
+    # the residual stream as the block RECEIVES it, un-normed, ahead
+    # of the mixer — the choice then depends on nothing the block
+    # computes, and the router's gradient reaches the block's input
+    # directly (``models.moe.routed_experts``: ``routed_on``).
+    experts_routed_on: str = "ffn_input"
+    # A routed expert's gate function: "silu" (SwiGLU) or "relu"
+    # (ReGLU: ``down(relu(gate x) * up x)``); the shared expert and the
+    # dense FFNs stay SwiGLU.
+    experts_activation: str = "silu"
     # Width of one attention head where it is not ``d_model //
     # num_heads`` (the q / kv projections then map ``d_model`` to
     # ``heads * head_dim`` and ``out`` maps back).
@@ -319,9 +333,14 @@ class TransformerConfig:
                 raise ValueError(
                     f"attention_kinds[{name!r}]: window {kind.window}"
                 )
-            if kind.yarn is not None and not self.rope:
+            turned = self.rope if kind.rope is None else kind.rope
+            if kind.yarn is not None and not turned:
                 raise ValueError(
                     f"attention_kinds[{name!r}]: yarn without rope"
+                )
+            if kind.rotary_dims is not None and not turned:
+                raise ValueError(
+                    f"attention_kinds[{name!r}]: rotary_dims without rope"
                 )
         if "sliding_attention" in kinds:
             if getattr(of_kind.get("sliding_attention"), "window", None) is None:
@@ -338,6 +357,16 @@ class TransformerConfig:
         if self.attention_head_gate and self.attention_gate:
             raise ValueError(
                 "attention_head_gate and attention_gate: one output gate"
+            )
+        if self.experts_routed_on not in ("ffn_input", "block_input"):
+            raise ValueError(
+                "experts_routed_on must be 'ffn_input' or 'block_input', "
+                f"got {self.experts_routed_on!r}"
+            )
+        if self.experts_activation not in ("silu", "relu"):
+            raise ValueError(
+                "experts_activation must be 'silu' or 'relu', got "
+                f"{self.experts_activation!r}"
             )
         if "gdn" in kinds:
             if self.seq_axis is not None:
@@ -432,14 +461,17 @@ class TransformerConfig:
     def attention_kind(self, kind: str) -> AttentionKind:
         """The softmax-attention kind ``kind`` with the config's values
         where ``attention_kinds`` says nothing (``rotary_dims`` None =
-        every lane, as the config's)."""
+        every lane, as the config's; of a kind without rotary, none)."""
         own = dict(self.attention_kinds).get(kind, AttentionKind())
+        turned = self.rope if own.rope is None else own.rope
         return AttentionKind(
             num_heads=own.num_heads or self.num_heads,
             rope_theta=own.rope_theta or self.rope_theta,
-            rotary_dims=own.rotary_dims or self.rotary_dims,
+            rotary_dims=(own.rotary_dims or self.rotary_dims) if turned
+            else None,
             yarn=own.yarn,
             window=own.window,
+            rope=turned,
         )
 
     def layer_heads(self, layer: int) -> int:
@@ -664,7 +696,7 @@ def make_norm(cfg: TransformerConfig, name: str | None = None):
 
 
 def _heads_a_call(
-    attn, heads, seq_len, qk_width, v_width, itemsize, window=None
+    attn, heads, seq_len, qk_width, v_width, itemsize, window=None, group=1
 ):
     """How many heads one call of ``attn`` is given: as many as the
     flash kernels want at once (``ops.flash_attention.heads_a_call``,
@@ -673,7 +705,8 @@ def _heads_a_call(
     given). Any ``attention_fn`` is asked so: a ``functools.partial``
     of the kernel says nothing of itself but its blocks. All of them
     for plain attention (``attn`` None). ``window``: the layer's,
-    where it has one."""
+    where it has one. ``group``: query heads a kv head, where that is
+    more than one: a run is then whole groups or a divisor of one."""
     if attn is None:
         return heads
     from adaptdl_tpu.ops.flash_attention import heads_a_call
@@ -684,6 +717,8 @@ def _heads_a_call(
     }
     if window is not None:
         said["window"] = window
+    if group > 1:
+        said["group"] = group
     return getattr(attn, "heads_a_call", heads_a_call)(
         heads, seq_len, qk_width, v_width, itemsize, **said,
     )
@@ -781,7 +816,7 @@ class GroupedQueryAttention(nn.Module):
         if cfg.qk_norm:
             q = _rms_norm(cfg, "q_norm")(q)
             k = _rms_norm(cfg, "k_norm")(k)
-        if cfg.rope:
+        if own.rope:
             table = {}
             if own.yarn is not None:
                 table = {
@@ -799,7 +834,7 @@ class GroupedQueryAttention(nn.Module):
         if gated or by_kind:
             run = _heads_a_call(
                 attn, heads, x.shape[1], head_dim, head_dim,
-                jnp.dtype(cfg.dtype).itemsize, own.window,
+                jnp.dtype(cfg.dtype).itemsize, own.window, group,
             )
             of_kind = {}
             if by_kind:
@@ -813,7 +848,7 @@ class GroupedQueryAttention(nn.Module):
                 heads=heads,
                 kv_heads=kv_heads,
                 head_dim=head_dim,
-                rotary_dims=(own.rotary_dims or head_dim) if cfg.rope else 0,
+                rotary_dims=(own.rotary_dims or head_dim) if own.rope else 0,
                 gate="head" if cfg.attention_head_gate
                 else "sigmoid" if cfg.attention_gate else "none",
                 **of_kind,
@@ -1402,12 +1437,14 @@ class RoutedFFN(nn.Module):
     ``shared_expert_gate`` times ``sigmoid(x w_s)`` (``shared_gate``);
     the tokens it multiplied are sown as ``shared_rows``. The layer's
     load counters are sown into the "moe_load" collection, the
-    router's choice (``experts``, ``weights``) into "moe_routing"."""
+    router's choice (``experts``, ``weights``) into "moe_routing".
+    ``routed_on``: what the router reads where the config places it on
+    the block's input (``experts_routed_on``), in ``x``'s shape."""
 
     config: TransformerConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, routed_on=None):
         from adaptdl_tpu.models.moe import routed_experts
 
         cfg = self.config
@@ -1455,6 +1492,9 @@ class RoutedFFN(nn.Module):
             router_kind=cfg.experts_router,
             shared_gate="sigmoid" if cfg.shared_expert_gate else "none",
             pieces_from=cfg.experts_pieces_from,
+            routed_on=None if routed_on is None
+            else routed_on.reshape(-1, cfg.d_model),
+            activation=cfg.experts_activation,
         )
         for name, value in load.items():
             self.sow(
@@ -1595,6 +1635,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, dropout_rng=None):
         cfg = self.config
+        received = x  # what a router on the block's input reads
         y = make_norm(cfg)(x)
         y = _mixer(cfg, self.layer)(y, positions)
         if cfg.sandwich_norm:
@@ -1608,7 +1649,8 @@ class Block(nn.Module):
         if self.use_moe:
             y = MoEFFN(cfg, name="moe")(y)
         elif cfg.routed(self.layer):
-            y = RoutedFFN(cfg, name="moe")(y)
+            on_input = cfg.experts_routed_on == "block_input"
+            y = RoutedFFN(cfg, name="moe")(y, received if on_input else None)
         elif cfg.ffn == "swiglu":
             y = GatedFFN(cfg, name="ffn")(y)
         else:
@@ -2049,7 +2091,8 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
     [layers], "dropped": [layers], "rows_active": [layers],
     "rows_walked": [layers], "fell_back": [layers]}``, and
     ``"shared_rows": [layers]`` where the layers have a shared
-    expert. A prediction module that ran (``mtp_depth``) is the last
+    expert, ``"hidden_zero": [layers]`` where the experts' gate is
+    "relu". A prediction module that ran (``mtp_depth``) is the last
     layer."""
     sown = mutated["moe_load"]
     blocks = [
@@ -2064,6 +2107,7 @@ def moe_load_counters(config: TransformerConfig, mutated) -> dict:
             "held_rows", "left_out", "dropped", "rows_active",
             "rows_walked", "fell_back",
         ) + (("shared_rows",) if config.d_shared_expert > 0 else ())
+        + (("hidden_zero",) if config.experts_activation == "relu" else ())
     }
 
 

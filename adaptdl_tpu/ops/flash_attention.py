@@ -1664,10 +1664,13 @@ def _window_bwd_pallas(q, k, v, do, out, lse, scale, block_q, block_k, window):
 def heads_a_call(
     heads: int, seq_len: int, head_dim: int, v_dim: int, itemsize: int,
     block_q: int = 128, block_k: int = 128, window: int | None = None,
+    group: int = 1,
 ) -> int:
     """How many heads (a divisor of ``heads``) a caller that can
-    split them should give one call. All of them while K and V of a
-    head stay in VMEM; beyond, the backward writes one float32 dQ a
+    split them should give one call; with ``group`` query heads a kv
+    head, whole groups or a divisor of one group, so that a call's kv
+    heads are whole (a group of 7 goes 7, 14, 28 or 1 a call). All of
+    them while K and V of a head stay in VMEM; beyond, the backward writes one float32 dQ a
     key chunk (``_bwd_pallas``), ``chunks * 4 / itemsize`` times q for
     the heads it is given: a call's partials are held to the bytes of
     q itself, all heads. (Blocks that do not divide the row are cut
@@ -1691,7 +1694,10 @@ def heads_a_call(
     if chunks == 1:
         return heads
     at_once = max(1, heads * itemsize // (4 * chunks))
-    return next(n for n in range(at_once, 0, -1) if heads % n == 0)
+    return next(
+        n for n in range(at_once, 0, -1)
+        if heads % n == 0 and (n % group == 0 or group % n == 0)
+    )
 
 
 def make_flash_attention(

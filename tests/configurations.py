@@ -1,4 +1,4 @@
-"""The benchmark's eight configurations as the CPU suite runs them: each
+"""The benchmark's nine configurations as the CPU suite runs them: each
 one's tiny sizes, said ONCE, and the scaffolding every configuration's
 tests share (the configuration's module, its sizes, a build, a loader's
 stand-in, a relative error). ``tests/step_digests.py`` takes its sizes
@@ -104,6 +104,18 @@ TINY = {
         "router_width": 16, "experts_held": 4, "n_routed_experts": 4,
         "num_experts_per_tok": 3, "vocab_size": 97, "sequence_length": 64,
         "head_chunk_rows": 32, "compute_dtype": "float32",
+    },
+    # (The readers' names restate the published keys: both are said.)
+    "smallthinker-21b-a3b": {
+        "hidden_size": 32, "moe_ffn_hidden_size": 16,
+        "moe_intermediate_size": 16,
+        "num_attention_heads": 14, "num_key_value_heads": 2, "head_dim": 8,
+        "num_attention_heads_per_layer": [14] * 4,
+        "sliding_window_size": 24, "sliding_window": 24,
+        "router_width": 16, "experts_held": 4, "moe_num_primary_experts": 4,
+        "moe_num_active_primary_experts": 3, "num_experts_per_tok": 3,
+        "vocab_size": 97, "sequence_length": 64, "head_chunk_rows": 32,
+        "compute_dtype": "float32",
     },
 }
 NAMES = tuple(TINY)

@@ -1,4 +1,4 @@
-"""Digests of the benchmark's eight configurations at their tiny sizes
+"""Digests of the benchmark's nine configurations at their tiny sizes
 (``tests/configurations.py``): the parameter tree (paths, shapes,
 dtypes) and the lowered text of the gradient of each configuration's
 own loss, on the CPU, in float32 and in bfloat16. A PR that edits the

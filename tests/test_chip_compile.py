@@ -2,7 +2,8 @@
 
 1. Whole STEPS compiled by the TPU's own compiler for a DESCRIBED (not
    attached) v5e (``conftest.py``: ``v5e``, ``chip_compile``): the routed
-   cells' steps, the reduce-overlap options and the dp=4 step that
+   cells' steps, smallthinker-21b-a3b's step under the chip's memory
+   limit, the reduce-overlap options and the dp=4 step that
    reduces under its last backward, the one-chip step without a tail. A
    compile that passes is not a chip run and says nothing about results
    or speed. (The kernels alone: ``tests/test_chip_compile_kernels.py``;
@@ -95,6 +96,53 @@ def test_routed_cells_steps_are_the_programs_before_the_ladder(
         ]
         assert policies and all(p["rungs"] == "" for p in policies)
     assert texts[0] == texts[1]
+
+
+def test_smallthinker_step_fits_the_chip(
+    v5e, chip_compile, monkeypatch, tmp_path
+):
+    """``smallthinker-21b-a3b-steady``'s step at real size as the
+    trainer would run it on a 16 GB v5e (PR 60): 370.5 M parameters
+    under the full AdaptDL recipe (``precondition`` "adam", 16 B a
+    parameter of arguments) fit TWICE, so the step does not donate, and
+    the compiler's count stays under the chip's 15.75 GiB (13.79 at PR
+    60) — a later PR that pushes it over is told here and not by a chip
+    call. The kernels under the names a device trace shows: the full
+    layer's 28 heads in four runs of one kv head's group of SEVEN, the
+    three sliding layers' bands (four K/V blocks before the tile's own)
+    ONE call a layer."""
+    from tools import compile_step_v5e as rehearsal
+
+    gmm = importlib.import_module("adaptdl_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gmm, "_use_interpret", lambda: False)
+    monkeypatch.setenv("ADAPTDL_CHECKPOINT_PATH", str(tmp_path))
+    rehearsal.inject_limit(rehearsal.BYTES_LIMIT, monkeypatch.setattr)
+    lower, facts = rehearsal.step_program(
+        "smallthinker-21b-a3b-steady", bytes_limit=rehearsal.BYTES_LIMIT,
+        topo=v5e,
+    )
+    assert not facts["donated"] and facts["chips"] == 1
+    # (20 B a parameter, and a few scalars of state.)
+    assert 0 <= facts["held_bytes"] - 20 * 370_547_200 < 4096
+    compiled = lower().compile()
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert total < rehearsal.BYTES_LIMIT, total / 2**30
+    text = compiled.as_text()
+
+    def calls(name):
+        return len(re.findall(
+            rf"^\s*%[\w\-]*{name}[\w\-]*[.\d]* = .*{flash_mod.MOSAIC_CALL}",
+            text, re.M,
+        ))
+
+    assert calls(flash_mod.WINDOW_FWD_NAME) == 3
+    assert calls(flash_mod.WINDOW_BWD_NAME) == 3
+    assert calls(flash_mod.BWD_KERNEL_NAME) == 4
+    assert len(re.findall(r"^\s*%attention[.\d]* = ", text, re.M)) == 4
 
 
 def test_the_tpu_compiler_has_the_reduce_overlap_options(v5e, chip_compile):

@@ -1,5 +1,5 @@
 """The witness that a PR left a configuration's program alone: parameter
-tree and lowered gradient program of each of the benchmark's eight
+tree and lowered gradient program of each of the benchmark's nine
 configurations, at its tiny size (``tests/configurations.py``) in float32
 and in bfloat16, against what the parent commit gave
 (``tests/step_digests.py`` -> ``tests/data/step_digests.json``). No

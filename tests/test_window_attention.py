@@ -206,6 +206,78 @@ def test_kv_heads_are_indexed_inside_the_band_kernels(
     assert events["window.keys"]["batch_heads"] == 4 * group
 
 
+@pytest.mark.parametrize(
+    "before, group",
+    # smallthinker-21b-a3b's walk (PR 60): a window of 4096 keys at
+    # tiles of 1024 is FOUR K/V blocks before the tile's own (a ring
+    # of five) under groups of SEVEN query heads; every cell before it
+    # had one block before and groups of 6 or 8.
+    [(before, group) for before in (2, 4) for group in (1, 4, 7)],
+)
+def test_band_kernels_past_one_block_of_reach(tiles, before, group):
+    """The band kernels at ``before`` K/V blocks ahead of a tile's
+    own, k and v handed over once a kv head for ``group`` query heads,
+    against plain ``causal_attention(window=)`` on repeated operands:
+    forward and dq / dk / dv, dK / dV of a block gathered in the ring
+    over ``before + 1`` tiles and the group's heads."""
+    from adaptdl_tpu.models.transformer import causal_attention
+
+    tiles(32, 32)
+    window = 32 * before  # the last key of the block ``before`` back
+    keys = jax.random.split(jax.random.key(10 * before + group), 4)
+    q = jax.random.normal(keys[0], (1, 2 * group, 256, 16))
+    k, v = (jax.random.normal(x, (1, 2, 256, 16)) for x in keys[1:3])
+    g = jax.random.normal(keys[3], q.shape)
+    sched = fm._band_schedule(256, window, 16, 16, 32)
+    assert (sched.tile, sched.before) == (32, before)
+    got = _both(
+        lambda q, k, v: fm.flash_attention(
+            q, k, v, True, None, 16, 16, window
+        ),
+        q, k, v, g,
+    )
+    want = _both(
+        lambda q, k, v: causal_attention(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
+            window=window,
+        ),
+        q, k, v, g,
+    )
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "heads, kv_heads", [(28, 4), (48, 8), (16, 2), (64, 8)]
+)
+@pytest.mark.parametrize("seq, chunks", [(8192, 1), (16384, 2), (32768, 4)])
+def test_a_run_of_heads_is_whole_groups_or_a_divisor_of_one(
+    heads, kv_heads, seq, chunks
+):
+    """``heads_a_call`` under ``group`` query heads a kv head gives a
+    divisor of the heads that is whole groups or divides one, whatever
+    the K-blocked backward's chunks (a group of 7 at four chunks: 1,
+    where the bytes alone would say 2, which straddles two groups)."""
+    group = heads // kv_heads
+    sched = fm._schedule(
+        seq, 128, 2, 128, 128, diag_rows=fm._BWD_DIAG_ROWS, v_dim=128
+    )
+    assert seq // sched.chunk_k == chunks
+    run = fm.heads_a_call(heads, seq, 128, 128, 2, group=group)
+    assert heads % run == 0 and (run % group == 0 or group % run == 0)
+    free = fm.heads_a_call(heads, seq, 128, 128, 2)
+    assert run <= free
+    if free % group == 0 or group % free == 0:
+        assert run == free  # the rule moves no run that was whole
+    if chunks == 1:
+        assert run == heads
+    if (heads, chunks) == (28, 2):
+        assert run == 7  # the cell's full layer: one kv head's group
+    if (heads, chunks) == (28, 4):
+        assert (free, run) == (2, 1)
+
+
 def _brute(seq, piece, window):
     i = np.arange(seq)[:, None]
     j = np.arange(seq)[None, :]
