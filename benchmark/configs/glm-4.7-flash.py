@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # What decides ``correct`` (reference_check), on the run's own weights
 # at the published widths on ONE row of the timed length. "first" is
 # what the system gave over its seeds (the cell's own runs print them:
@@ -435,16 +437,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             targets.reshape(-1), sizes["head_chunk_rows"],
         ).reshape(targets.shape)
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     def mixer_vjp(mixer_params, x, cotangent):
         """The system's latent-attention mixer alone on ``x`` [1, seq,
@@ -832,12 +843,17 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The published router alone on ``x`` [..., d]: float32 sigmoid
     scores over all experts, the top 4 of ``score + bias``, weights =
     the chosen scores WITHOUT the bias over their sum (+ epsilon) times
     ``routed_scaling_factor``. Returns (experts [..., top_k] in
-    ascending order, their weights in that order)."""
+    ascending order, their weights in that order). With
+    ``system``, the sets the system chose: a near-tied token's experts
+    are the system's (``near_ties.settle``), and a third result, the
+    ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -852,29 +868,37 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
     _, chosen = jax.lax.top_k(
         scores + layer["bias"], sizes["num_experts_per_tok"]
     )
+    if system is not None:
+        chosen, ties = near_ties.settle(
+            scores + layer["bias"], chosen, system
+        )
     picked = jnp.take_along_axis(scores, chosen, -1)
     weights = picked / (
         picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"]
     )
     if variant != "no_scale":
         weights = weights * sizes["routed_scaling_factor"]
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    shared: bool = True, variant: str = "",
+    shared: bool = True, variant: str = "", system=None,
 ):
     """The published routed FFN, this share of it: the router over all
     experts, the sum over the experts chosen AND held (``first_expert
     ..`` + the number of expert weights the layer has) of weight x
     gated FFN, and (``shared``) the shared expert on every token,
-    unweighted. Returns (y, rows each of ALL experts was chosen for)."""
+    unweighted. Returns (y, rows each of ALL experts was chosen for),
+    and with ``system`` the router's ``Ties``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
-    chosen, weights = reference_router(layer, x, sizes, variant)
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant, system
+    )
     # (A scan whose body is checkpointed: one expert after another,
     # and a gradient holds one expert's float32 intermediates at a
     # time — as a Python loop the compiler runs the eight backwards
@@ -896,21 +920,29 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(sizes["router_width"]),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
-def reference_routed_vjp(layer: dict, x, cotangent, sizes: dict):
+def reference_routed_vjp(
+    layer: dict, x, cotangent, sizes: dict, system=None
+):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to (its weights, x), by ``jax.grad``."""
+    respect to (its weights, x), by ``jax.grad``; with ``system``
+    (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn({**layer, **weights}, x, sizes)
-        return jnp.sum(y * cotangent)
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, system=system
+        )
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ROUTED_LEAVES}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_mixer(layer: dict, u, sizes: dict, variant: str = ""):
@@ -1313,23 +1345,35 @@ def module_leaf_errors(got, want, sizes: dict) -> dict:
 def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
-    system's input x [tokens, d], its output y)``."""
+    system's input x [tokens, d], its output y, the experts its router
+    chose)``. Without the experts the reference routes for itself
+    alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, y):
+    def check(layer, moe_params, x, y, experts=None):
         first = x[: sizes["sequence_length"]]
         first32 = first.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), sizes, system=experts
             )
-            grads = reference_routed_vjp(layer, first32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(layer, first32, first32, sizes)
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -1583,10 +1627,13 @@ def layer_checks(
         for i in routed_layers(sizes)
     ] + [(weights["mtp"]["block"], params["mtp"][f"layer_{module_at}"]["moe"])]
     found = [
-        routed(layer, moe, load["inputs"][i], load["outputs"][i])
+        routed(
+            layer, moe, load["inputs"][i], load["outputs"][i],
+            load["experts"][i],
+        )
         for i, (layer, moe) in enumerate(blocks)
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for name in ("inputs", "outputs"):
         del load[name]
     at = checked_mixer(sizes)
@@ -1775,6 +1822,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS],
         mixer_grad_limits=list(MIXER_GRAD_LIMITS),
@@ -1784,7 +1832,11 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         kernel_limits=[KERNEL_RMS_LIMIT, KERNEL_ROW_SCALE_LIMIT],
     )
     result["ok"] = bool(
-        np.isfinite(result["system_loss"]) and within_limits(result)
+        np.isfinite(result["system_loss"])
+        and within_limits(result)
+        and near_ties.within(
+            result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+        )
     )
     return result
 

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # Seven comparisons decide ``correct`` (reference_check), on the run's
 # own weights at the published widths on ONE row of the timed length
 # (16 384 tokens) of the run's own data. FORWARD, every one is of the
@@ -408,16 +410,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             untied_logits(hidden, params["lm_head"]), targets
         )
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     def positions(x):
         return jnp.arange(x.shape[1])
@@ -592,10 +603,15 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The published router alone on ``x`` [..., d]: float32 logits
     over all experts, softmax over all of them, the 8 largest, divided
-    by their sum. Returns (experts [..., top_k] ascending, weights)."""
+    by their sum. Returns (experts [..., top_k] ascending, weights).
+    With ``system``, the sets the system chose: a near-tied token's
+    experts are the system's (``near_ties.settle``), and a third result,
+    the ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -609,27 +625,32 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
             logits = x @ layer["router"]
     probs = jax.nn.softmax(logits, axis=-1)
     picked, chosen = jax.lax.top_k(probs, sizes["num_experts_per_tok"])
+    if system is not None:
+        chosen, ties = near_ties.settle(probs, chosen, system)
+        picked = jnp.take_along_axis(probs, chosen, -1)
     weights = picked / (
         picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"]
     )
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    variant: str = "",
+    variant: str = "", system=None,
 ):
     """The published routed FFN, this share of it: the router over all
     experts, and the sum over the experts chosen AND held
     (``first_expert ..`` + the number of expert weights the layer has)
     of weight x gated FFN. Returns (y, rows each of ALL experts was
-    chosen for)."""
+    chosen for), and with ``system`` the router's ``Ties``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
-    chosen, weights = reference_router(
-        layer, x, sizes, variant if variant in ROUTER_FAULTS else ""
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant if variant in ROUTER_FAULTS else "",
+        system,
     )
 
     # One held expert a step of a scan whose body is rematerialised:
@@ -655,7 +676,7 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(layer["router"].shape[1]),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
 def operands_as_stated(layer: dict, dtype) -> dict:
@@ -679,21 +700,26 @@ def operands_as_stated(layer: dict, dtype) -> dict:
 
 
 def reference_routed_vjp(
-    layer: dict, x, cotangent, sizes: dict, variant: str = ""
+    layer: dict, x, cotangent, sizes: dict, variant: str = "",
+    system=None,
 ):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to ({w1, w3, w2, router}, x), by ``jax.grad``."""
+    respect to ({w1, w3, w2, router}, x), by ``jax.grad``; with
+    ``system`` (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn(
-            {**layer, **weights}, x, sizes, variant=variant
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, variant=variant, system=system
         )
-        return jnp.sum(y * cotangent)
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ("w1", "w3", "w2", "router")}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def _rotary(x, theta: float):
@@ -1445,11 +1471,17 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
     def routed(layer, moe_params, x, y, experts, weights):
         x = x.reshape(-1, x.shape[-1])
         x32 = x.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, x, x32)
+        got, own = built["routed_vjp"](moe_params, x, x32, sets=True)
         layer = operands_as_stated(layer, x.dtype)
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(layer, x32, sizes)
-            grads = reference_routed_vjp(layer, x32, x32, sizes)
+            # (Each on the sets its system side chose where a token is
+            # near-tied: ``near_ties``.)
+            want, _, forward = reference_routed_ffn(
+                layer, x32, sizes, system=experts
+            )
+            grads, backward = reference_routed_vjp(
+                layer, x32, x32, sizes, system=own
+            )
         token, rms = layer_error(y, want)
         set_mismatch, weight_err = router_disagreement(
             in_expert_order(experts, weights),
@@ -1460,6 +1492,7 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
             "router_weight_err": weight_err,
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(forward, backward),
         }
 
     @jax.jit
@@ -1531,7 +1564,7 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
         found.append({k: float(v) for k, v in errors.items()})
         for name in CAPTURED:
             load[name][i] = None
-    return {k: max(f[k] for f in found) for k in found[0]}
+    return near_ties.worst_layer(found)
 
 
 # Every reading that has a limit, beside it: (reading, limit name).
@@ -1694,10 +1727,14 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             )
     judged = verdict(result)
     result["limits"] = {name: row[1] for name, row in judged.items()}
+    result["near_tie_margin"] = near_ties.NEAR_TIE_MARGIN
     result["ok"] = bool(
         np.isfinite(result["system_loss"])
         and np.isfinite(result["system_index_loss"])
         and all(row[2] for row in judged.values())
+        and near_ties.within(
+            result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+        )
     )
     if not result["ok"]:
         for name, (value, limit, ok) in judged.items():

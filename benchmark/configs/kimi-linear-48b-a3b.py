@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # What decides ``correct`` (reference_check), on the run's own weights
 # at the published widths on ONE row of the timed length. Readings: my
 # chip runs, PR 46, TPU v5 lite (PERF.md section 6). "first" is the
@@ -384,16 +386,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             targets.reshape(-1), sizes["head_chunk_rows"],
         ).reshape(targets.shape)
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     def mixer_vjp(kind, mixer_params, x, cotangent):
         """The system's kda or mla mixer alone on ``x`` [1, seq, d]:
@@ -720,12 +731,17 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The published router alone on ``x`` [..., d]: float32 sigmoid
     scores over all experts, the top 8 of ``score + bias``, weights =
     the chosen scores WITHOUT the bias over their sum (+ epsilon) times
     ``routed_scaling_factor``. Returns (experts [..., top_k] in
-    ascending order, their weights in that order)."""
+    ascending order, their weights in that order). With
+    ``system``, the sets the system chose: a near-tied token's experts
+    are the system's (``near_ties.settle``), and a third result, the
+    ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -740,29 +756,37 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
     _, chosen = jax.lax.top_k(
         scores + layer["bias"], sizes["num_experts_per_token"]
     )
+    if system is not None:
+        chosen, ties = near_ties.settle(
+            scores + layer["bias"], chosen, system
+        )
     picked = jnp.take_along_axis(scores, chosen, -1)
     weights = (
         picked
         / (picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"])
         * sizes["routed_scaling_factor"]
     )
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    shared: bool = True, variant: str = "",
+    shared: bool = True, variant: str = "", system=None,
 ):
     """The published routed FFN, this share of it: the router over all
     experts, the sum over the experts chosen AND held (``first_expert
     ..`` + the number of expert weights the layer has) of weight x
     gated FFN, and (``shared``) the shared expert on every token,
-    unweighted. Returns (y, rows each of ALL experts was chosen for)."""
+    unweighted. Returns (y, rows each of ALL experts was chosen for),
+    and with ``system`` the router's ``Ties``."""
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
     total = sizes["router_width"]
-    chosen, weights = reference_router(layer, x, sizes, variant)
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant, system
+    )
     y = jnp.zeros_like(x)
     for held in range(layer["w1"].shape[0]):
         mask = chosen == first + held  # [..., top_k]
@@ -776,7 +800,7 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(total),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
 ROUTED_LEAVES = {  # the reference's names -> the system's leaves
@@ -787,18 +811,26 @@ ROUTED_LEAVES = {  # the reference's names -> the system's leaves
 }
 
 
-def reference_routed_vjp(layer: dict, x, cotangent, sizes: dict):
+def reference_routed_vjp(
+    layer: dict, x, cotangent, sizes: dict, system=None
+):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to (its weights, x), by ``jax.grad``."""
+    respect to (its weights, x), by ``jax.grad``; with ``system``
+    (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn({**layer, **weights}, x, sizes)
-        return jnp.sum(y * cotangent)
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, system=system
+        )
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ROUTED_LEAVES}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_mixer(kind: str, layer: dict, u, sizes: dict, variant=""):
@@ -989,23 +1021,35 @@ def mixer_grad_errors(kind: str, got, want) -> dict:
 def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
-    system's input x [tokens, d], its output y)``."""
+    system's input x [tokens, d], its output y, the experts its router
+    chose)``. Without the experts the reference routes for itself
+    alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, y):
+    def check(layer, moe_params, x, y, experts=None):
         first = x[: sizes["sequence_length"]]
         first32 = first.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), sizes, system=experts
             )
-            grads = reference_routed_vjp(layer, first32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(layer, first32, first32, sizes)
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -1057,13 +1101,13 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
     found = [
         routed(
             weights[at], params[f"layer_{at}"]["moe"],
-            load["inputs"][i], load["outputs"][i],
+            load["inputs"][i], load["outputs"][i], load["experts"][i],
         )
         for i, at in enumerate(
             range(sizes["first_k_dense_replace"], sizes["num_hidden_layers"])
         )
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for kind, at in checked_mixers(sizes).items():
         u, y = load[kind]
         errors = mixer_check(built, sizes, kind)(
@@ -1190,6 +1234,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS],
         mixer_grad_limits=MIXER_GRAD_LIMITS,
@@ -1200,6 +1245,9 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             and result["router_set_mismatch_share"]
             <= ROUTER_SET_MISMATCH_SHARE
             and result["router_weight_err"] <= ROUTER_WEIGHT_ATOL
+            and near_ties.within(
+                result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+            )
             and result["routing_l1_share"] <= ROUTING_L1_SHARE
             and result["rows_dropped"] == 0
             and result["rows_unaccounted"] == 0

@@ -25,6 +25,8 @@ import math
 
 import numpy as np
 
+from benchmark import near_ties
+
 # What decides ``correct`` (reference_check), on the run's own weights
 # at the published widths on ONE row of the timed length. Readings: my
 # chip runs, PR 54, TPU v5 lite (PERF.md section 6). "first" is the
@@ -462,16 +464,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             targets.reshape(-1), sizes["head_chunk_rows"],
         ).reshape(targets.shape)
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     def mixer_vjp(name, mixer_params, x, cotangent):
         """The system's sliding or full mixer alone on ``x`` [1, seq,
@@ -797,12 +808,17 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The router alone on ``x`` [..., d]: float32 sigmoid scores over
     all experts, the 8 largest, weights = the chosen scores over their
     sum (+ epsilon) times ``moe_routed_scaling_factor``. Returns
     (experts [..., top_k] in ascending order, their weights in that
-    order)."""
+    order). With
+    ``system``, the sets the system chose: a near-tied token's experts
+    are the system's (``near_ties.settle``), and a third result, the
+    ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -815,31 +831,37 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
         with jax.default_matmul_precision("highest"):
             scores = jax.nn.sigmoid(x @ layer["router"])
     picked, chosen = jax.lax.top_k(scores, sizes["num_experts_per_tok"])
+    if system is not None:
+        chosen, ties = near_ties.settle(scores, chosen, system)
+        picked = jnp.take_along_axis(scores, chosen, -1)
     weights = picked / (
         picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"]
     )
     if variant != "no_scale":
         weights = weights * sizes["moe_routed_scaling_factor"]
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    shared: bool = True, variant: str = "",
+    shared: bool = True, variant: str = "", system=None,
 ):
     """The routed FFN, this share of it: the router over all experts,
     the sum over the experts chosen AND held (``first_expert ..`` + the
     number of expert weights the layer has) of weight x gated FFN (the
     weight on the expert's OUTPUT), and (``shared``) the shared expert
     on every token, unweighted. Returns (y, rows each of ALL experts
-    was chosen for). ``variant``: of ``ROUTER_FAULTS`` or
-    ``ROUTED_FAULTS``."""
+    was chosen for), and with ``system`` the router's ``Ties``.
+    ``variant``: of ``ROUTER_FAULTS`` or ``ROUTED_FAULTS``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
     total = sizes["router_width"]
-    chosen, weights = reference_router(layer, x, sizes, variant)
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant, system
+    )
     # (Checkpointed: a gradient holds one expert's float32
     # intermediates at a time, not those of all 16.)
     weighted = jax.checkpoint(
@@ -859,21 +881,29 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(total),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
-def reference_routed_vjp(layer: dict, x, cotangent, sizes: dict):
+def reference_routed_vjp(
+    layer: dict, x, cotangent, sizes: dict, system=None
+):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to (its weights, x), by ``jax.grad``."""
+    respect to (its weights, x), by ``jax.grad``; with ``system``
+    (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn({**layer, **weights}, x, sizes)
-        return jnp.sum(y * cotangent)
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, system=system
+        )
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ROUTED_LEAVES}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_mixer(name: str, layer: dict, u, sizes: dict, variant=""):
@@ -1075,23 +1105,35 @@ def token_ranges(sizes: dict) -> dict:
 def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
-    system's input x [tokens, d], its output y)``."""
+    system's input x [tokens, d], its output y, the experts its router
+    chose)``. Without the experts the reference routes for itself
+    alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, y):
+    def check(layer, moe_params, x, y, experts=None):
         first = x[: sizes["sequence_length"]]
         first32 = first.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), sizes, system=experts
             )
-            grads = reference_routed_vjp(layer, first32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(layer, first32, first32, sizes)
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -1209,11 +1251,11 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
         routed(
             {k: v for k, v in weights[at].items() if k in ROUTED_LEAVES},
             params[f"layer_{at}"]["moe"],
-            load["inputs"][n], load["outputs"][n],
+            load["inputs"][n], load["outputs"][n], load["experts"][n],
         )
         for n, at in enumerate(routed_layers(sizes))
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for name, at in checked_mixers(sizes).items():
         u, y = load[name]
         errors = mixer_check(built, sizes, name)(
@@ -1343,6 +1385,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS],
         mixer_grad_limits=MIXER_GRAD_LIMITS,
@@ -1355,6 +1398,9 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             and result["router_set_mismatch_share"]
             <= ROUTER_SET_MISMATCH_SHARE
             and result["router_weight_err"] <= ROUTER_WEIGHT_ATOL
+            and near_ties.within(
+                result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+            )
             and result["routing_l1_share"] <= ROUTING_L1_SHARE
             and result["rows_dropped"] == 0
             and result["rows_unaccounted"] == 0

@@ -18,6 +18,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # Six comparisons decide ``correct`` (reference_check), on the run's
 # own weights at the published widths on 2 rows of the timed length.
 # Readings (my chip runs, PR 30, TPU v5 lite; benchmark/tests/
@@ -321,16 +323,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             load[kind] = (seen(i, "RMSNorm_0"), seen(i, mixer))
         return hidden, losses, load
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(model.config).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(model.config).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     recipe = sizes["recipe"]
     loss_fn = routed_lm_loss_fn(model)
@@ -440,21 +451,23 @@ def _product(a, b, variant: str):
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    variant: str = "",
+    variant: str = "", system=None,
 ):
     """The published routed FFN, this share of it: the router
     (``reference_router``) over all experts, and the sum over the
     experts chosen AND held (``first_expert ..`` + the number of
     expert weights the layer has) of weight x gated FFN. Returns (y,
-    rows each of ALL experts was chosen for). ``variant``: one of
-    ``ROUTER_FAULTS`` or ``ROUTED_FAULTS``."""
+    rows each of ALL experts was chosen for), and with ``system`` the
+    router's ``Ties``. ``variant``: one of ``ROUTER_FAULTS`` or
+    ``ROUTED_FAULTS``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
     total = sizes["num_experts"]
-    chosen, weights = reference_router(
-        layer, x, sizes, variant if variant in ROUTER_FAULTS else ""
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant if variant in ROUTER_FAULTS else "",
+        system,
     )
 
     def expert(held):
@@ -480,25 +493,30 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(total),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
 def reference_routed_vjp(
-    layer: dict, x, cotangent, sizes: dict, variant: str = ""
+    layer: dict, x, cotangent, sizes: dict, variant: str = "",
+    system=None,
 ):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to ({w1, w3, w2, router}, x), by ``jax.grad``."""
+    respect to ({w1, w3, w2, router}, x), by ``jax.grad``; with
+    ``system`` (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn(
-            {**layer, **weights}, x, sizes, variant=variant
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, variant=variant, system=system
         )
-        return jnp.sum(y * cotangent)
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ("w1", "w3", "w2", "router")}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_short_conv(layer: dict, u, variant: str = ""):
@@ -654,12 +672,16 @@ def reference_head(hidden, embedding, targets):
         return logits, -picked[..., 0]
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The published router alone on ``x`` [..., d]: float32 sigmoid
     scores over all experts, the top 4 of ``score + bias``, weights =
     the chosen scores WITHOUT the bias over their sum (+ epsilon) times
     ``routed_scaling_factor``. Returns (experts [..., top_k] in
-    ascending order, their weights in that order).
+    ascending order, their weights in that order). With ``system``,
+    the sets the system chose: a near-tied token's experts are the
+    system's (``near_ties.settle``), and a third result, the ``Ties``.
 
     ``variant``: one of ``ROUTER_FAULTS``."""
     import jax
@@ -681,6 +703,10 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
     _, chosen = jax.lax.top_k(
         scores + layer["bias"], sizes["num_experts_per_tok"]
     )
+    if system is not None:
+        chosen, ties = near_ties.settle(
+            scores + layer["bias"], chosen, system
+        )
     picked = jnp.take_along_axis(
         scores + layer["bias"] if variant == "weights_with_bias"
         else scores,
@@ -691,7 +717,8 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
         / (picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"])
         * sizes["routed_scaling_factor"]
     )
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def in_expert_order(experts, weights):
@@ -789,24 +816,35 @@ def mixer_reference(kind: str, layer: dict, u, sizes: dict, variant=""):
 def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
-    system's input x [tokens, d], its output y)``. The backward runs
-    on the first row only."""
+    system's input x [tokens, d], its output y, the experts its router
+    chose)``. The backward runs on the first row only. Without the
+    experts the reference routes for itself alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, y):
+    def check(layer, moe_params, x, y, experts=None):
         first = x[: sizes["sequence_length"]]
         first32 = first.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), sizes, system=experts
             )
-            grads = reference_routed_vjp(layer, first32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(layer, first32, first32, sizes)
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -833,13 +871,13 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
     found = [
         routed(
             weights[at], params[f"layer_{at}"]["moe"],
-            load["inputs"][i], load["outputs"][i],
+            load["inputs"][i], load["outputs"][i], load["experts"][i],
         )
         for i, at in enumerate(
             range(sizes["num_dense_layers"], sizes["num_hidden_layers"])
         )
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for kind, name in (("conv", "conv"), ("attention", "full_attention")):
         token, rms = mixer(weights[kinds.index(name)], *load[kind], kind)
         worst[f"{kind}_token_err"] = float(token)
@@ -931,6 +969,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS],
         ok=bool(
@@ -940,6 +979,9 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             and result["router_set_mismatch_share"]
             <= ROUTER_SET_MISMATCH_SHARE
             and result["router_weight_err"] <= ROUTER_WEIGHT_ATOL
+            and near_ties.within(
+                result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+            )
             and result["routing_l1_share"] <= ROUTING_L1_SHARE
             and result["rows_dropped"] == 0
             and result["rows_unaccounted"] == 0

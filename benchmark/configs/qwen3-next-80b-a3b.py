@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # What decides ``correct`` (reference_check), on the run's own weights
 # at the published widths on ONE row of the timed length. Readings: my
 # chip runs, PR 49, TPU v5 lite (PERF.md section 6). "first" is the
@@ -416,16 +418,25 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             targets.reshape(-1), sizes["head_chunk_rows"],
         ).reshape(targets.shape)
 
-    def routed_vjp(moe_params, x, cotangent):
+    def routed_vjp(moe_params, x, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters
-        and its input ``x`` [tokens, d]."""
+        and its input ``x`` [tokens, d]; with ``sets`` also the experts
+        ITS router chose [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1))(moe_params, x)
+        grads, chosen = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+            moe_params, x
+        )
+        return (grads, chosen) if sets else grads
 
     def mixer_vjp(kind, mixer_params, x, cotangent):
         """The system's gdn or attention mixer alone on ``x`` [1, seq,
@@ -791,11 +802,16 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, x, sizes: dict, variant: str = "", system=None
+):
     """The published router alone on ``x`` [..., d]: float32 softmax
     over all experts, the top 10, weights = the chosen probabilities
     over their sum (``norm_topk_prob``; + epsilon). Returns (experts
-    [..., top_k] in ascending order, their weights in that order)."""
+    [..., top_k] in ascending order, their weights in that order). With
+    ``system``, the sets the system chose: a near-tied token's experts
+    are the system's (``near_ties.settle``), and a third result, the
+    ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -809,29 +825,35 @@ def reference_router(layer: dict, x, sizes: dict, variant: str = ""):
             logits = x @ layer["router"]
     probs = jax.nn.softmax(logits, axis=-1)
     picked, chosen = jax.lax.top_k(probs, sizes["num_experts_per_tok"])
+    if system is not None:
+        chosen, ties = near_ties.settle(probs, chosen, system)
+        picked = jnp.take_along_axis(probs, chosen, -1)
     weights = picked / (
         picked.sum(-1, keepdims=True) + sizes["expert_weight_eps"]
     )
-    return in_expert_order(chosen, weights)
+    found = in_expert_order(chosen, weights)
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, sizes: dict, first_expert: int | None = None,
-    shared: bool = True, variant: str = "",
+    shared: bool = True, variant: str = "", system=None,
 ):
     """The published routed FFN, this share of it: the router over all
     experts, the sum over the experts chosen AND held (``first_expert
     ..`` + the number of expert weights the layer has) of weight x
     gated FFN, and (``shared``) the shared expert on every token times
     ``sigmoid(x w_s)``. Returns (y, rows each of ALL experts was chosen
-    for). ``variant``: of ``ROUTER_FAULTS`` or ``ROUTED_FAULTS``."""
+    for), and with ``system`` the router's ``Ties``. ``variant``: of
+    ``ROUTER_FAULTS`` or ``ROUTED_FAULTS``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
     total = sizes["router_width"]
-    chosen, weights = reference_router(
-        layer, x, sizes, variant if variant in ROUTER_FAULTS else ""
+    chosen, weights, *ties = reference_router(
+        layer, x, sizes, variant if variant in ROUTER_FAULTS else "",
+        system,
     )
     # (Checkpointed: a gradient holds one expert's float32
     # intermediates at a time, not those of all 32.)
@@ -855,21 +877,29 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(total),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
-def reference_routed_vjp(layer: dict, x, cotangent, sizes: dict):
+def reference_routed_vjp(
+    layer: dict, x, cotangent, sizes: dict, system=None
+):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to (its weights, x), by ``jax.grad``."""
+    respect to (its weights, x), by ``jax.grad``; with ``system``
+    (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x):
-        y, _ = reference_routed_ffn({**layer, **weights}, x, sizes)
-        return jnp.sum(y * cotangent)
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, sizes, system=system
+        )
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ROUTED_LEAVES}
-    return jax.grad(objective, argnums=(0, 1))(weights, x)
+    grads, ties = jax.grad(objective, argnums=(0, 1), has_aux=True)(
+        weights, x
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_mixer(kind: str, layer: dict, u, sizes: dict, variant=""):
@@ -1063,23 +1093,35 @@ def mixer_grad_errors(kind: str, got, want) -> dict:
 def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
-    system's input x [tokens, d], its output y)``."""
+    system's input x [tokens, d], its output y, the experts its router
+    chose)``. Without the experts the reference routes for itself
+    alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, y):
+    def check(layer, moe_params, x, y, experts=None):
         first = x[: sizes["sequence_length"]]
         first32 = first.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), sizes, system=experts
             )
-            grads = reference_routed_vjp(layer, first32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(layer, first32, first32, sizes)
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -1131,11 +1173,11 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
     found = [
         routed(
             weights[at], params[f"layer_{at}"]["moe"],
-            load["inputs"][at], load["outputs"][at],
+            load["inputs"][at], load["outputs"][at], load["experts"][at],
         )
         for at in range(sizes["num_hidden_layers"])
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for kind, at in checked_mixers(sizes).items():
         u, y = load[kind]
         errors = mixer_check(built, sizes, kind)(
@@ -1260,6 +1302,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS],
         mixer_grad_limits=MIXER_GRAD_LIMITS,
@@ -1270,6 +1313,9 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             and result["router_set_mismatch_share"]
             <= ROUTER_SET_MISMATCH_SHARE
             and result["router_weight_err"] <= ROUTER_WEIGHT_ATOL
+            and near_ties.within(
+                result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+            )
             and result["routing_l1_share"] <= ROUTING_L1_SHARE
             and result["rows_dropped"] == 0
             and result["rows_unaccounted"] == 0

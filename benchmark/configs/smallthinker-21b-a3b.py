@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import near_ties
+
 # What decides ``correct`` (reference_check), on the run's own weights
 # at the published widths on ONE row of the timed length. Readings: my
 # chip runs, PR 60, TPU v5 lite (PERF.md section 6). "first" is the
@@ -461,17 +463,26 @@ def build(sizes: dict, geometry: dict, seed: int) -> dict:
             targets.reshape(-1), sizes["head_chunk_rows"],
         ).reshape(targets.shape)
 
-    def routed_vjp(moe_params, x, h, cotangent):
+    def routed_vjp(moe_params, x, h, cotangent, sets=False):
         """The system's routed layer alone, backward: the gradients of
         ``sum(y * cotangent)`` with respect to the layer's parameters,
         the experts' input ``x`` and the router's input ``h`` (each
-        [tokens, d])."""
+        [tokens, d]); with ``sets`` also the experts ITS router chose
+        [tokens, top_k] (``near_ties``)."""
 
         def objective(moe_params, x, h):
-            y = RoutedFFN(cfg).apply({"params": moe_params}, x, h)
-            return jnp.sum(y.astype(jnp.float32) * cotangent)
+            y, sown = RoutedFFN(cfg).apply(
+                {"params": moe_params}, x, h, mutable=["moe_routing"]
+            )
+            return (
+                jnp.sum(y.astype(jnp.float32) * cotangent),
+                sown["moe_routing"]["experts"][0],
+            )
 
-        return jax.grad(objective, argnums=(0, 1, 2))(moe_params, x, h)
+        grads, chosen = jax.grad(
+            objective, argnums=(0, 1, 2), has_aux=True
+        )(moe_params, x, h)
+        return (grads, chosen) if sets else grads
 
     def mixer_vjp(name, mixer_params, x, cotangent):
         """The system's sliding or full mixer alone on ``x`` [1, seq,
@@ -751,11 +762,15 @@ def in_expert_order(experts, weights):
     )
 
 
-def reference_router(layer: dict, h, sizes: dict, variant: str = ""):
+def reference_router(
+    layer: dict, h, sizes: dict, variant: str = "", system=None
+):
     """The router alone on the block's input ``h`` [..., d]: float32
     logits over all 64 experts, the 6 largest, weights = the softmax
     over those six logits. Returns (experts [..., top_k] in ascending
-    order, their weights in that order)."""
+    order, their weights in that order). With ``system``, the sets the
+    system chose: a near-tied token's experts are the system's
+    (``near_ties.settle``), and a third result, the ``Ties``."""
     import jax
     import jax.numpy as jnp
 
@@ -768,26 +783,31 @@ def reference_router(layer: dict, h, sizes: dict, variant: str = ""):
         with jax.default_matmul_precision("highest"):
             logits = h @ layer["router"]
     picked, chosen = jax.lax.top_k(logits, sizes["num_experts_per_tok"])
-    return in_expert_order(chosen, jax.nn.softmax(picked, axis=-1))
+    if system is not None:
+        chosen, ties = near_ties.settle(logits, chosen, system)
+        picked = jnp.take_along_axis(logits, chosen, -1)
+    found = in_expert_order(chosen, jax.nn.softmax(picked, axis=-1))
+    return found if system is None else (*found, ties)
 
 
 def reference_routed_ffn(
     layer: dict, x, h, sizes: dict, first_expert: int | None = None,
-    variant: str = "",
+    variant: str = "", system=None,
 ):
     """The routed FFN, this share of it: the router on the block's
     input ``h`` over all experts, the sum over the experts chosen AND
     held (``first_expert ..`` + the number of expert weights the layer
     has) of weight x ReGLU expert of ``x``, the normed state after the
-    mixer. Returns (y, rows each of ALL experts was chosen for).
+    mixer. Returns (y, rows each of ALL experts was chosen for), and
+    with ``system`` the router's ``Ties`` (``reference_router``).
     ``variant``: of ``ROUTER_FAULTS`` or ``ROUTED_FAULTS``."""
     import jax
     import jax.numpy as jnp
 
     first = sizes["first_expert"] if first_expert is None else first_expert
     total = sizes["router_width"]
-    chosen, weights = reference_router(
-        layer, x if variant == "router_on_x" else h, sizes, variant
+    chosen, weights, *ties = reference_router(
+        layer, x if variant == "router_on_x" else h, sizes, variant, system
     )
     # (Checkpointed: a gradient holds one expert's float32
     # intermediates at a time, not those of all 8.)
@@ -806,25 +826,31 @@ def reference_routed_ffn(
         chosen[..., None] == jnp.arange(total),
         axis=tuple(range(chosen.ndim)),
     )
-    return y, counts
+    return (y, counts, *ties)
 
 
 def reference_routed_vjp(
-    layer: dict, x, h, cotangent, sizes: dict, variant: str = ""
+    layer: dict, x, h, cotangent, sizes: dict, variant: str = "",
+    system=None,
 ):
     """Gradients of ``sum(y * cotangent)`` of the routed FFN with
-    respect to (its weights, x, h), by ``jax.grad``."""
+    respect to (its weights, x, h), by ``jax.grad``; with ``system``
+    (those gradients, the router's ``Ties``)."""
     import jax
     import jax.numpy as jnp
 
     def objective(weights, x, h):
-        y, _ = reference_routed_ffn(
-            {**layer, **weights}, x, h, sizes, variant=variant
+        y, _, *ties = reference_routed_ffn(
+            {**layer, **weights}, x, h, sizes, variant=variant,
+            system=system,
         )
-        return jnp.sum(y * cotangent)
+        return jnp.sum(y * cotangent), ties
 
     weights = {k: layer[k] for k in ROUTED_LEAVES}
-    return jax.grad(objective, argnums=(0, 1, 2))(weights, x, h)
+    grads, ties = jax.grad(objective, argnums=(0, 1, 2), has_aux=True)(
+        weights, x, h
+    )
+    return grads if system is None else (grads, *ties)
 
 
 def reference_mixer(name: str, layer: dict, u, sizes: dict, variant=""):
@@ -1023,23 +1049,37 @@ def routed_check(built: dict, sizes: dict):
     """The program of comparisons 5 and 6 for ONE routed layer:
     ``check(reference layer, the system's layer parameters, the
     system's x [tokens, d], the block input h its router read, its
-    output y)``."""
+    output y, the experts its router chose)``. Without the experts the
+    reference routes for itself alone, as before PR 62."""
     import jax
     import jax.numpy as jnp
 
-    def check(layer, moe_params, x, h, y):
+    def check(layer, moe_params, x, h, y, experts=None):
         first, first_h = (t[: sizes["sequence_length"]] for t in (x, h))
         first32, h32 = first.astype(jnp.float32), first_h.astype(jnp.float32)
-        got = built["routed_vjp"](moe_params, first, first_h, first32)
+        got = built["routed_vjp"](
+            moe_params, first, first_h, first32, sets=experts is not None
+        )
         with jax.default_matmul_precision("highest"):
-            want, _ = reference_routed_ffn(
-                layer, x.astype(jnp.float32), h.astype(jnp.float32), sizes
+            want, _, *ties = reference_routed_ffn(
+                layer, x.astype(jnp.float32), h.astype(jnp.float32), sizes,
+                system=experts,
             )
-            grads = reference_routed_vjp(layer, first32, h32, first32, sizes)
+            if experts is None:
+                grads = reference_routed_vjp(
+                    layer, first32, h32, first32, sizes
+                )
+            else:  # the backward on the sets ITS system side chose
+                got, own = got
+                grads, back = reference_routed_vjp(
+                    layer, first32, h32, first32, sizes, system=own
+                )
+                ties.append(back)
         token, rms = layer_error(y, want)
         return {
             "routed_token_err": token, "routed_rms_err": rms,
             **routed_grad_errors(got, grads),
+            **near_ties.worst(*ties),
         }
 
     return check
@@ -1172,10 +1212,11 @@ def layer_checks(built: dict, params, load: dict, sizes: dict) -> dict:
             {k: v for k, v in weights[at].items() if k in ROUTED_LEAVES},
             params[f"layer_{at}"]["moe"],
             load["inputs"][at], load["routed_on"][at], load["outputs"][at],
+            load["experts"][at],
         )
         for at in range(sizes["num_hidden_layers"])
     ]
-    worst = {k: max(float(f[k]) for f in found) for k in found[0]}
+    worst = near_ties.worst_layer(found)
     for name, at in checked_mixers(sizes).items():
         u, y = load[name]
         errors = mixer_check(built, sizes, name)(
@@ -1306,6 +1347,7 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
         routing_tol=ROUTING_L1_SHARE,
         router_set_tol=ROUTER_SET_MISMATCH_SHARE,
         router_weight_atol=ROUTER_WEIGHT_ATOL,
+        near_tie_margin=near_ties.NEAR_TIE_MARGIN,
         layer_limits=LAYER_LIMITS,
         grad_limits=[
             EXPERT_GRAD_RTOL, ROUTER_GRAD_RTOL, INPUT_GRAD_RMS,
@@ -1321,6 +1363,9 @@ def reference_check(built: dict, params, dataset: dict, sizes: dict) -> dict:
             and result["router_set_mismatch_share"]
             <= ROUTER_SET_MISMATCH_SHARE
             and result["router_weight_err"] <= ROUTER_WEIGHT_ATOL
+            and near_ties.within(
+                result, ROUTER_SET_MISMATCH_SHARE, sample["inputs"].size
+            )
             and result["routing_l1_share"] <= ROUTING_L1_SHARE
             and result["rows_dropped"] == 0
             and result["rows_unaccounted"] == 0

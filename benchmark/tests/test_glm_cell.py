@@ -115,12 +115,15 @@ def test_manifest_loads_the_cell():
     } <= set(sizes["assumed"])
     assert sizes["recipe"]["learning_rate"] == 2e-5
     assert sizes["recipe"]["precondition"] is None
-    # The two new readers read this cell alone, and it is the last.
+    # The two new readers read this cell alone, one after the other,
+    # and the cell is there (later PRs append cells and readers).
     for metric in bench["per_layer"]:
         if metric["name"] in NEW:
             assert metric["workloads"] == [CELL]
-    assert bench["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in bench["per_layer"][-2:]] == list(NEW)
+    assert CELL in [w["name"] for w in bench["workloads"]]
+    readers = [m["name"] for m in bench["per_layer"]]
+    at = readers.index(NEW[0])
+    assert readers[at:at + len(NEW)] == list(NEW)
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

@@ -103,11 +103,13 @@ def test_manifest_loads_the_cell():
     assert entry["source"] == sizes["source"]
     for key in ("deployment", "assumed", "departures", "recipe"):
         assert sizes[key]
-    # The three new readers read this cell alone.
+    # The three new readers read this cell (and whatever later cell
+    # has a window to read), and the cell is there: what the case
+    # means, not how many cells came after it.
     for metric in bench["per_layer"]:
         if metric["name"] in NEW:
-            assert metric["workloads"] == [CELL]
-    assert len(bench["workloads"]) == 9
+            assert CELL in metric["workloads"]
+    assert CELL in [w["name"] for w in bench["workloads"]]
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
 
 

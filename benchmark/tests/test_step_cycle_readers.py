@@ -337,7 +337,7 @@ def test_attaching_only_appends(attached):
     }
     assert len(json.dumps(after)) < 64 * 1024
     assert cells == [w["name"] for w in before["workloads"]]
-    assert len(cells) == 8
+    assert len(cells) >= 8  # every cell there is, however many
     for cell in cells:
         old = manifest.load_json(
             manifest.bench_path(ROOT, "workloads", f"{cell}.json")
